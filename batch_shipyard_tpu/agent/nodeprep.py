@@ -34,11 +34,14 @@ logger = util.get_logger(__name__)
 
 
 def detect_tpu_chips() -> int:
-    """Count locally visible TPU accelerator devices."""
-    count = 0
-    for idx in range(16):
-        if os.path.exists(f"/dev/accel{idx}"):
-            count += 1
+    """Count locally visible TPU accelerator devices from /dev alone
+    (no JAX: the agent must never take the chip its tasks need).
+    Older TPU VMs expose one /dev/accelN per chip; v5e hosts expose
+    none of those, only one numbered VFIO group per chip under
+    /dev/vfio (next to the /dev/vfio/vfio control node)."""
+    count = sum(os.path.exists(f"/dev/accel{idx}") for idx in range(16))
+    if count == 0 and os.path.isdir("/dev/vfio"):
+        count = sum(name.isdigit() for name in os.listdir("/dev/vfio"))
     return count
 
 

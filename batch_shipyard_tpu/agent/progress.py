@@ -1,8 +1,8 @@
 """Task progress beats: the liveness contract behind the wedge watchdog.
 
-The TPU failure mode that motivates this (TPU_WEDGE_REPORT.md) is a
-process that stays ALIVE but makes no progress forever — `jax.devices()`
-blocked in the runtime, a collective stuck on a dead ICI peer. Wall-time
+The failure mode that motivates this is a process that stays ALIVE but
+makes no progress forever — a device-runtime call that blocks, a
+collective stuck on a dead ICI peer. Wall-time
 limits catch runaways, heartbeats catch dead nodes; neither catches a
 wedged-but-breathing task. Progress beats do: the agent exports
 $SHIPYARD_PROGRESS_FILE into every task env, instrumented workloads

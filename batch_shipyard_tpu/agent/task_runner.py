@@ -416,8 +416,8 @@ def run_task(execution: TaskExecution,
                          else time.time() - beat)
                 if stale > watchdog:
                     # Wedged: alive but no progress. SIGKILL straight
-                    # away — the motivating hangs (TPU_WEDGE_REPORT.md)
-                    # sit inside the runtime and never honor SIGTERM.
+                    # away — the motivating hangs sit inside the
+                    # device runtime and never honor SIGTERM.
                     wedged = True
                     logger.warning(
                         "task %s/%s/%s made no progress for %.1fs "

@@ -210,7 +210,7 @@ def check_sleep_in_sweep(ctx: AnalysisContext) -> list[Finding]:
     node's own liveness signal — long enough, and the orphan-reclaim
     path judges the node dead and steals its running tasks.
 
-    Provenance: the TPU_WEDGE_REPORT.md hang class — the progress
+    Provenance: the alive-but-stuck hang class — the progress
     watchdog exists because blocked control loops turn into
     silently-dead nodes. Waiting belongs in the poll loops (which
     sleep poll_interval between EMPTY polls), never in sweep

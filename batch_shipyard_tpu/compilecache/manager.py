@@ -5,10 +5,17 @@ computation + backend fingerprint, so a stale or foreign entry can
 never produce a wrong executable — it just misses. The manager adds
 the operational layer the cache itself doesn't have:
 
-  * ``enable`` points ``jax_compilation_cache_dir`` at a directory
-    (with the min-entry-size / min-compile-time thresholds dropped to
-    zero so even fast CPU-test compiles land) and stamps the dir with
-    a sidecar ``identity.json``.
+  * ``enable`` decides where the cache lives and tracks it. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, the cache was placed from
+    outside: JAX already reads that directory, the manager tracks it
+    as it is and never touches ``jax_compilation_cache_dir``. Where
+    it is unset, the cache is an identity-namespaced subdir of a
+    root — the node agent's ``$SHIPYARD_COMPILE_CACHE_DIR`` on pools,
+    else one fixed git-ignored directory in the checkout
+    (``DEFAULT_CACHE_ROOT``) — stamped with a sidecar
+    ``identity.json``. Either way the min-entry-size /
+    min-compile-time thresholds drop to zero so every compile lands,
+    fast CPU-test ones included.
   * ``identity_key`` is the *transport* key for pool-wide seeding
     (compilecache/seeding.py): jax/jaxlib versions, device kind,
     topology, and an optional model-config digest. Shipping a cache
@@ -35,6 +42,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import pathlib
 import re
 import time
 from typing import Any, Iterator, Optional
@@ -47,6 +55,16 @@ logger = util.get_logger(__name__)
 # persistent cache directory (seeded from / exported to the pool's
 # state store around tasks).
 CACHE_DIR_ENV = "SHIPYARD_COMPILE_CACHE_DIR"
+
+# JAX's own variable. Set, it places the cache from outside the
+# program and nothing here may move it (the path is part of what makes
+# a later run hit).
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+# The cache root when nobody chose one: fixed, inside the checkout,
+# git-ignored. Never a temporary name — a cache that moves never hits.
+DEFAULT_CACHE_ROOT = str(pathlib.Path(__file__).resolve().parents[2]
+                         / ".jax_compile_cache")
 
 # Sidecar files the manager owns inside the cache dir. They are not
 # cache entries (snapshot() excludes them) but they DO travel with the
@@ -279,44 +297,62 @@ def list_identity_dirs(cache_root: str) -> dict[str, str]:
     return out
 
 
-def enable(cache_root: str, *,
+def resolve_root(cache_root: Optional[str] = None
+                 ) -> tuple[str, bool]:
+    """(directory, placed_from_outside): ``JAX_COMPILATION_CACHE_DIR``
+    when set — the cache itself, used flat — else ``cache_root`` or
+    ``DEFAULT_CACHE_ROOT``, under which caches are identity
+    subdirs."""
+    placed = os.environ.get(JAX_CACHE_DIR_ENV)
+    if placed:
+        return os.path.abspath(placed), True
+    return os.path.abspath(cache_root or DEFAULT_CACHE_ROOT), False
+
+
+def enable(cache_root: Optional[str] = None, *,
            min_entry_size_bytes: int = 0,
            min_compile_time_secs: float = 0.0,
            identity: Optional[str] = None,
            mesh_shape: Optional[dict] = None,
            model_digest: Optional[str] = None,
            configure_jax: bool = True) -> CompileCacheManager:
-    """Point the persistent XLA compilation cache at ``cache_root``'s
-    identity-namespaced subdir and install the process-global manager.
-    Idempotent. Namespacing is what lets MIXED pools share one node
-    dir: a transformer task and a resnet task (different identities)
-    each warm their own subdir instead of clobbering each other's —
-    XLA entries are self-keying, but cold-time metas and export
-    artifacts are not. ``configure_jax=False`` skips the jax.config
-    writes (tests and agent-side tooling that never compile)."""
+    """Install the process-global manager on the persistent XLA
+    compilation cache. Idempotent.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: the cache stays exactly there
+    (``cache_root`` is ignored) and ``jax_compilation_cache_dir`` is
+    not written. Unset: the cache is ``cache_root``'s
+    identity-namespaced subdir (``DEFAULT_CACHE_ROOT`` when no root is
+    given). Namespacing is what lets MIXED pools share one node dir: a
+    transformer task and a resnet task (different identities) each
+    warm their own subdir instead of clobbering each other's — XLA
+    entries are self-keying, but cold-time metas and export artifacts
+    are not. ``configure_jax=False`` is for tests and agent-side
+    tooling that never compile: no jax.config writes, and the root is
+    always namespaced."""
     global _current
     if identity is None:
         identity = identity_key(mesh_shape=mesh_shape,
                                 model_digest=model_digest)
-    cache_dir = identity_subdir(cache_root, identity)
+    root, placed_outside = (
+        resolve_root(cache_root) if configure_jax
+        else (cache_root or DEFAULT_CACHE_ROOT, False))
+    cache_dir = (root if placed_outside
+                 else identity_subdir(root, identity))
     os.makedirs(cache_dir, exist_ok=True)
-    if read_identity(cache_dir) != identity:
-        try:
-            with open(os.path.join(cache_dir, IDENTITY_FILE), "w",
-                      encoding="utf-8") as fh:
-                json.dump({"identity": identity,
-                           "written_at": util.datetime_utcnow_iso()},
-                          fh)
-        except OSError:
-            logger.debug("identity write failed", exc_info=True)
+    if not placed_outside and read_identity(cache_dir) != identity:
+        with open(os.path.join(cache_dir, IDENTITY_FILE), "w",
+                  encoding="utf-8") as fh:
+            json.dump({"identity": identity,
+                       "written_at": util.datetime_utcnow_iso()}, fh)
     if configure_jax:
         import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                           int(min_entry_size_bytes))
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time_secs))
-        try:
+        if not placed_outside:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
             # Any compile that ran BEFORE enable latches the cache
             # module to its initialized-disabled state for the process
             # (config updates alone don't un-latch it); reset so the
@@ -324,9 +360,6 @@ def enable(cache_root: str, *,
             from jax.experimental.compilation_cache import (
                 compilation_cache as jax_cc)
             jax_cc.reset_cache()
-        except Exception:  # noqa: BLE001 - experimental jax API
-            logger.debug("compilation cache reset unavailable",
-                         exc_info=True)
     _current = CompileCacheManager(cache_dir, identity)
     return _current
 
@@ -374,13 +407,14 @@ def add_compile_cache_args(parser) -> None:
     group.add_argument(
         "--compile-cache-dir",
         default=os.environ.get(CACHE_DIR_ENV) or None,
-        help="persistent XLA compilation cache dir (default: "
+        help="persistent XLA compilation cache ROOT (default: "
              f"${CACHE_DIR_ENV}, which the node agent exports on "
-             "pools; unset = cold compiles)")
+             f"pools, else {DEFAULT_CACHE_ROOT}); ignored where "
+             f"${JAX_CACHE_DIR_ENV} is set — the cache then stays "
+             "in that directory")
     group.add_argument(
         "--no-compile-cache", action="store_true",
-        help="opt out of the persistent compile cache even when "
-             f"${CACHE_DIR_ENV} is set")
+        help="opt out of the persistent compile cache")
     group.add_argument(
         "--aot-precompile", action="store_true",
         help="AOT lower+compile the hot functions against abstract "
@@ -393,16 +427,12 @@ def enable_from_args(args, *, mesh_shape: Optional[dict] = None,
                      ) -> Optional[CompileCacheManager]:
     """The workload-side enable hook (the AST check in
     tests/test_names_consistency.py requires every parallel.train
-    workload to call this): enables the persistent cache when a dir is
-    configured, returns None when disabled. Never raises — a broken
-    cache dir must not fail the work it would have sped up."""
-    cache_dir = getattr(args, "compile_cache_dir", None)
-    if not cache_dir or getattr(args, "no_compile_cache", False):
+    workload to call this): enables the persistent cache where
+    ``enable`` resolves it, or returns None under
+    ``--no-compile-cache``. A cache directory that cannot be used
+    raises: a run that silently compiles cold every time is not the
+    run that was asked for."""
+    if getattr(args, "no_compile_cache", False):
         return None
-    try:
-        return enable(cache_dir, mesh_shape=mesh_shape,
-                      model_digest=model_digest)
-    except Exception:  # noqa: BLE001 - warm start is best-effort
-        logger.warning("compile cache enable failed for %s",
-                       cache_dir, exc_info=True)
-        return None
+    return enable(getattr(args, "compile_cache_dir", None),
+                  mesh_shape=mesh_shape, model_digest=model_digest)

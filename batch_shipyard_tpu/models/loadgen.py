@@ -47,8 +47,9 @@ def _exact_percentile(values: list, q: float) -> float:
     return ordered[k]
 
 
-def _post_generate(base_url: str, payload: dict,
-                   timeout: float) -> dict:
+def post_generate(base_url: str, payload: dict,
+                  timeout: float = 300.0) -> dict:
+    """One blocking POST /v1/generate; returns the decoded reply."""
     req = urllib.request.Request(
         f"{base_url}/v1/generate",
         data=json.dumps(payload).encode(),
@@ -122,7 +123,7 @@ def run_load(base_url: Union[str, Sequence[str]],
 
     def _one(k: int, url: str, payload: dict) -> None:
         try:
-            result = _post_generate(url, payload, request_timeout)
+            result = post_generate(url, payload, request_timeout)
             result["_replica"] = url
             results[k] = result
         except urllib.error.HTTPError as exc:

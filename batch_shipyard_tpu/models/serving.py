@@ -21,6 +21,7 @@ host-side Python, compute is two compiled functions (prefill, step).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -462,8 +463,15 @@ class ContinuousBatcher:
                  speculative: Optional[SpeculativeConfig] = None,
                  prefix_cache: bool = True,
                  slo_shed_grace_ms: Optional[float] = None,
-                 tpot_stall_factor: float = 4.0):
-        """kv_page_size enables the PAGED KV cache (vLLM-style): K/V
+                 tpot_stall_factor: float = 4.0,
+                 device=None):
+        """device pins the engine to one jax device: the params are
+        committed there (a copy, if they live elsewhere) and the KV
+        cache is created there, so every step runs on that device —
+        how N replicas in one process each get a chip of their own.
+        None leaves everything on the default device.
+
+        kv_page_size enables the PAGED KV cache (vLLM-style): K/V
         live in a shared kv_num_pages-page pool and slots hold block
         tables covering only their live tokens, so HBM is sized for
         aggregate active context instead of
@@ -640,11 +648,27 @@ class ContinuousBatcher:
         self._timed_buckets: set = set()
         self._step_samples = 0
         self.model = tfm.TransformerLM(self.config)
+        self.device = device
+        on_device = (jax.default_device(device) if device is not None
+                     else contextlib.nullcontext())
+        if device is not None:
+            params = jax.device_put(params, device)
         self.params = params
         self.num_slots = num_slots
         self.max_decode_len = max_decode_len
         self.sampling = sampling
-        self.cache = inf.init_cache(self.model, params, num_slots)
+        # Created on the engine's device, then committed to it (a
+        # no-op move): committed state keeps the eager bookkeeping
+        # ops between steps (key splits, .at[].set) on that device
+        # too.
+        with on_device:
+            (self.cache, self._tokens, self._positions, self._active,
+             self._key) = self._put((
+                 inf.init_cache(self.model, params, num_slots),
+                 jnp.zeros((num_slots, 1), jnp.int32),
+                 jnp.zeros((num_slots,), jnp.int32),
+                 jnp.zeros((num_slots,), jnp.bool_),
+                 jax.random.PRNGKey(seed)))
         if self.paged:
             # Fresh caches default block tables to zeros (a REAL
             # page); point every slot at the scratch page before any
@@ -652,10 +676,6 @@ class ContinuousBatcher:
             self._push_tables()
         self._slots = [_Slot() for _ in range(num_slots)]
         self._queue: list[_QueueEntry] = []
-        self._tokens = jnp.zeros((num_slots, 1), jnp.int32)
-        self._positions = jnp.zeros((num_slots,), jnp.int32)
-        self._active = jnp.zeros((num_slots,), jnp.bool_)
-        self._key = jax.random.PRNGKey(seed)
 
         self._decode_step = functools.partial(
             _decode_step, self.model, self.sampling)
@@ -686,9 +706,12 @@ class ContinuousBatcher:
             draft_model = tfm.TransformerLM(inf.decode_config(
                 speculative.draft_config,
                 max_decode_len + self.gamma + 1))
-            self._draft_params = speculative.draft_params
-            self._draft_cache = inf.init_cache(
-                draft_model, speculative.draft_params, num_slots)
+            self._draft_params = (
+                speculative.draft_params if device is None else
+                jax.device_put(speculative.draft_params, device))
+            with on_device:
+                self._draft_cache = self._put(inf.init_cache(
+                    draft_model, self._draft_params, num_slots))
             self._draft_prefill = functools.partial(
                 _prefill_dense, draft_model, self.prefill_chunk)
             self._spec_step = functools.partial(
@@ -1227,10 +1250,15 @@ class ContinuousBatcher:
         self._free_slot(victim)
         return victim
 
+    def _put(self, host_array):
+        """Host array -> the engine's device (the default device when
+        the engine is not pinned)."""
+        return jax.device_put(host_array, self.device)
+
     def _push_tables(self) -> None:
         """Write the canonical block table into every layer's cache
         copy."""
-        table = jnp.asarray(self._table)
+        table = self._put(self._table)
 
         def push(leaf_dict):
             if isinstance(leaf_dict, dict) and \
@@ -1466,7 +1494,7 @@ class ContinuousBatcher:
             tokens = req.prompt + entry.resumed
             bucket = self._bucket_length(len(tokens))
             padded = tokens + [0] * (bucket - len(tokens))
-            prompt = jnp.asarray([padded], jnp.int32)
+            prompt = self._put(np.asarray([padded], np.int32))
             t0 = time.monotonic()
             timed_key = ("dense", bucket)
             timed_tokens = bucket
@@ -1536,10 +1564,10 @@ class ContinuousBatcher:
                     sbucket = self._bucket_length(len(suffix_tokens))
                     timed_key = ("shared", sbucket)
                     timed_tokens = sbucket
-                    suffix = jnp.asarray(
+                    suffix = self._put(np.asarray(
                         [suffix_tokens +
                          [0] * (sbucket - len(suffix_tokens))],
-                        jnp.int32)
+                        np.int32))
                     prefix_ids = np.full(
                         (self.max_decode_len // self.page_size,),
                         self._scratch_page, np.int32)
@@ -1550,14 +1578,14 @@ class ContinuousBatcher:
                     suffix_row[:blocks_needed - m] = fresh
                     self.cache, last_logits = self._prefill_shared(
                         self.params, self.cache, i, suffix,
-                        jnp.asarray(prefix_ids), jnp.asarray(row),
-                        jnp.asarray(suffix_row), prefix_len,
+                        self._put(prefix_ids), self._put(row),
+                        self._put(suffix_row), prefix_len,
                         len(tokens))
                 else:
                     timed_key = ("paged", bucket)
                     self.cache, last_logits = self._prefill_paged(
                         self.params, self.cache, i, prompt,
-                        jnp.asarray(row), len(tokens))
+                        self._put(row), len(tokens))
                 if self.prefix_cache:
                     self._publish_pages(i, keys, m, row, len(tokens))
             else:

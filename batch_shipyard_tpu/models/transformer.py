@@ -99,8 +99,8 @@ class TransformerConfig:
     # int8 cache + per-(position, head) scales dequantized in VMEM
     # per tile — HBM holds int8 + scales only), 'xla' (dequant
     # multiply outside the kernel, fused — or not — by XLA), or
-    # None = auto, gated on the dense_decode_int8 silicon-validation
-    # marker (ops/decode_attention.resolve_dense_decode_impl).
+    # None = kernel on TPU and xla elsewhere
+    # (ops/decode_attention.resolve_dense_decode_impl).
     decode_attention_impl: Optional[str] = None
     # Megatron-style tensor parallelism INSIDE a shard_map body (the
     # pipeline path): q/k/v/gate/up are column-sharded and
@@ -335,8 +335,8 @@ class Attention(nn.Module):
             # ops/decode_attention: impl='kernel' dequantizes the
             # int8 rows + scales in VMEM tile by tile (no dequantized
             # cache ever exists in HBM — the dense_decode_hlo check
-            # pins that on the compiled step); 'xla'/auto-fallback is
-            # the dequant+einsum reference formulation. lengths =
+            # pins that on the compiled step); 'xla' is the
+            # dequant+einsum reference formulation. lengths =
             # keys visible to the query = idx + 1 (the key_pos <= idx
             # mask below, as a count).
             from batch_shipyard_tpu.ops import decode_attention as dd
@@ -684,9 +684,8 @@ def lm_loss_chunked(hidden, embedding, targets, ignore_id: int = -1,
     precision — the chunked path is the more accurate one).
 
     Delegates to ops/chunked_loss.chunked_softmax_xent: impl='auto'
-    runs the scan-chunked XLA path everywhere, upgrading to the fused
-    Pallas kernel on a TPU backend once tools/tpu_checks.py has
-    silicon-validated it (KERNEL_VALIDATION.json marker).
+    is the fused Pallas kernel on a TPU backend (lane-aligned
+    d_model), the scan-chunked XLA path elsewhere.
     """
     from batch_shipyard_tpu.ops import chunked_loss
     # chunk_size here means time-steps per batch row (the historical

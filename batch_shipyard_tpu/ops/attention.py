@@ -511,18 +511,30 @@ def masked_attention_block(q):
             jnp.full((batch * heads, t_len, 1), _NEG_INF, jnp.float32))
 
 
+def resolve_attention_impl(impl: Optional[str], t_q: int,
+                           t_kv: int) -> str:
+    """None -> 'flash' on a TPU backend when the default flash blocks
+    tile the sequence lengths, else 'blockwise' (untileable lengths
+    on TPU, and every other backend)."""
+    if impl is None:
+        if jax.default_backend() == "tpu" and flash_shapes_ok(t_q,
+                                                              t_kv):
+            return "flash"
+        return "blockwise"
+    if impl not in ("flash", "blockwise", "reference"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    return impl
+
+
 def attention(q, k, v, causal: bool = True,
               impl: Optional[str] = None, block_size: int = 512):
-    """Dispatch: 'flash' (pallas fwd), 'blockwise', or 'reference'.
-    Default: flash on TPU (falling back to blockwise for shapes the
-    kernel can't tile), blockwise elsewhere."""
+    """Dispatch: 'flash' (pallas fwd+bwd), 'blockwise', or
+    'reference'; None resolves via resolve_attention_impl."""
     if impl is None:
-        impl = ("flash" if jax.default_backend() == "tpu"
-                else "blockwise")
-        if impl == "flash" and not flash_shapes_ok(q.shape[1],
-                                                   k.shape[1]):
-            impl = "blockwise"
-            block_size = math.gcd(k.shape[1], block_size) or k.shape[1]
+        impl = resolve_attention_impl(None, q.shape[1], k.shape[1])
+        if impl == "blockwise" and k.shape[1] % min(block_size,
+                                                    k.shape[1]):
+            block_size = math.gcd(k.shape[1], block_size)
     if impl == "flash":
         return flash_attention(q, k, v, causal)
     if impl == "blockwise":

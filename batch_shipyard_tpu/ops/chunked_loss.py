@@ -18,9 +18,8 @@ path goes further and never materializes logits in HBM at all:
   run of consecutive grid steps and logits are never stored.
 
 Convention matches ops/fused_norm.py: impl 'pallas' | 'xla' |
-'interpret' | 'auto' (validation-marker-gated via ops/kernel_select —
-the kernel only self-enables after tools/tpu_checks.py proves it on
-silicon; ROADMAP.md names this the next transformer-MFU lever).
+'interpret' | 'auto' (the kernel on a TPU backend when the model dim
+is lane-aligned, the XLA scan elsewhere).
 
 No reference counterpart (the reference has no ML compute); the fused
 pattern follows public chunked-loss kernels (e.g. Liger) re-derived
@@ -36,8 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from batch_shipyard_tpu.ops import kernel_select
 
 # Finite -inf stand-in: keeps every intermediate finite (inf - inf is
 # nan; exp(-1e30 - m) underflows to exactly 0 for any real m).
@@ -289,6 +286,20 @@ def _xent_xla(h2, e, tgt, ignore_id, chunk):
     return total / jnp.maximum(cnt, 1.0)
 
 
+def resolve_xent_impl(impl: str, d_model: int) -> str:
+    """'auto' -> 'pallas' on a TPU backend, 'xla' elsewhere. Either
+    Pallas spelling gives way to 'xla' for a lane-misaligned model
+    dim (d_model % 128), which the kernel's [bt, D] blocks cannot
+    tile."""
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl not in ("pallas", "interpret", "xla"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if impl != "xla" and d_model % 128:
+        return "xla"
+    return impl
+
+
 def chunked_softmax_xent(hidden, embedding, targets,
                          ignore_id: int = -1, impl: str = "auto",
                          chunk_size: int = 128,
@@ -299,22 +310,15 @@ def chunked_softmax_xent(hidden, embedding, targets,
 
     hidden: [B, T, D] or [N, D]; embedding: [V, D]; targets matches
     hidden's leading shape. impl: 'pallas' | 'interpret' | 'xla' |
-    'auto' (Pallas on TPU once silicon-validated — see module doc).
+    'auto' (resolve_xent_impl).
     """
     if hidden.ndim == 3:
         hidden = hidden.reshape(-1, hidden.shape[-1])
         targets = targets.reshape(-1)
-    if impl == "auto":
-        impl = kernel_select.resolve_auto("chunked_cross_entropy")
+    impl = resolve_xent_impl(impl, hidden.shape[1])
     if impl in ("pallas", "interpret"):
-        d = hidden.shape[1]
-        if d % 128:
-            impl = "xla"  # lane-misaligned model dim: not worth it
-        else:
-            bv = v_chunk or _pick_v_chunk(d)
-            return _xent_pallas(hidden, embedding, targets, ignore_id,
-                                t_chunk, bv, impl == "interpret")
-    if impl != "xla":
-        raise ValueError(f"unknown impl {impl!r}")
+        bv = v_chunk or _pick_v_chunk(hidden.shape[1])
+        return _xent_pallas(hidden, embedding, targets, ignore_id,
+                            t_chunk, bv, impl == "interpret")
     return _xent_xla(hidden, embedding, targets, ignore_id,
                      chunk_size)

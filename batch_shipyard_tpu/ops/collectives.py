@@ -16,9 +16,8 @@ from typing import Callable, Iterable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from batch_shipyard_tpu.utils.compat import shard_map
 
 
 def _timeit(fn: Callable, arg, warmup: int = 2, iters: int = 10) -> float:

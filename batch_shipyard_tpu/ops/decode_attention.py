@@ -6,24 +6,23 @@ elementwise multiply outside any kernel and bet peak HBM on XLA fusing
 it into the attention dots — the paged path (ops/paged_attention.py)
 already dequantizes per tile inside its kernel. This kernel closes the
 gap for the dense cache: the int8 K/V rows and their per-(position,
-head) fp32 scales stream through VMEM tile by tile, the dequant
-multiply happens on the tile right before the dots, and HBM holds
-int8 + scales only — the entire 2x-HBM claim of kv_cache_dtype='int8'
-(arxiv 2605.25645 makes that headroom the serving-throughput lever).
-tools/tpu_checks.py asserts the claim on the COMPILED step: no
-full-cache-sized f32/bf16 buffer in the HLO, kernel custom-call
-present (check names dense_decode_int8 / dense_decode_hlo).
+head) fp32 scales stream through VMEM tile by tile, the scales are
+applied to the tile's scores/probabilities right around the dots, and
+HBM holds int8 + scales only — the entire 2x-HBM claim of
+kv_cache_dtype='int8' (arxiv 2605.25645 makes that headroom the
+serving-throughput lever). tools/tpu_checks.py asserts the claim on
+the COMPILED step: no full-cache-sized f32/bf16 buffer in the HLO,
+kernel custom-call present (check names dense_decode_int8 /
+dense_decode_hlo).
 
-Shares the online-softmax block recurrence with the paged kernel
-(_accumulate_page / _init_and_emit) — a fix there lands here too. The
-grid is (batch, heads, length-blocks): blocks wholly past a slot's
-live length are skipped (@pl.when) and their DMAs clamped to the last
-live block, exactly the paged kernel's dead-step discipline.
+The per-program body is the paged kernel's (decode_block_step: all
+heads of one key block, block-diagonal query) — a fix there lands
+here too. The grid is (batch, length-blocks): blocks wholly past a
+slot's live length are skipped (@pl.when) and their DMAs clamped to
+the last live block, exactly the paged kernel's dead-step discipline.
 
-impl='auto' (None) resolution is gated by silicon validation: the
-kernel turns on only when KERNEL_VALIDATION.json records an on-chip
-pass for 'dense_decode_int8' (ops/kernel_select), the XLA
-dequant+einsum formulation remaining the reference/fallback path.
+impl=None resolves from the backend alone: the kernel on TPU, the XLA
+dequant+einsum formulation (the reference) elsewhere.
 """
 
 from __future__ import annotations
@@ -36,34 +35,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from batch_shipyard_tpu.ops import kernel_select
-from batch_shipyard_tpu.ops.paged_attention import (_accumulate_page,
-                                                    _init_and_emit)
+from batch_shipyard_tpu.ops.paged_attention import (
+    decode_block_step, decode_scratch_shapes, resolve_kernel_or_xla)
 
 _NEG_INF = -1e30
 
 
 def _dense_decode_kernel_int8(len_ref, q_ref, k_ref, ks_ref, v_ref,
-                              vs_ref, o_ref, o_acc, m_acc, l_acc, *,
-                              block: int, scale: float):
-    """One (slot, head, length-block) program: dequantize the int8
-    K/V tile in VMEM ([block, D] int8 * [block, 1] fp32 scales) right
-    before the dots, then run the shared online-softmax recurrence."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    num_blocks = pl.num_programs(2)
-    length = len_ref[b]
-    emit = _init_and_emit(j, num_blocks, o_ref, o_acc, m_acc, l_acc)
-
-    @pl.when(j * block < length)
-    def _accumulate():
-        k_tile = k_ref[...].astype(jnp.float32) * ks_ref[...]
-        v_tile = v_ref[...].astype(jnp.float32) * vs_ref[...]
-        _accumulate_page(q_ref[...].astype(jnp.float32), k_tile,
-                         v_tile, j, length, o_acc, m_acc, l_acc,
-                         page=block, scale=scale)
-
-    pl.when(j == num_blocks - 1)(emit)
+                              vs_ref, o_ref, *scratch, **static):
+    decode_block_step(len_ref[pl.program_id(0)], q_ref, k_ref, ks_ref,
+                      v_ref, vs_ref, o_ref, *scratch, **static)
 
 
 def _largest_block(length: int, preferred: int = 128) -> int:
@@ -90,51 +71,40 @@ def dense_decode_attention_kernel(q, cache_k, cache_v, k_scales,
     if t_len % block:
         raise ValueError(
             f"cache length {t_len} not divisible by block {block}")
-    num_blocks = t_len // block
-    scale = 1.0 / (depth ** 0.5)
-    q_r = q.reshape(batch, heads, 1, depth)
+    width = heads * depth
 
-    def tile_index(b, h, j, ln):
+    def tile_index(b, j, ln):
         # Clamp dead steps to the slot's LAST live block: blocks past
         # the length are skipped by @pl.when, so don't spend HBM
         # bandwidth DMA-ing rows nobody reads (the paged kernel's
         # discipline; here every row exists, so this is thrift, not
         # correctness).
         live = jnp.maximum((ln[b] + block - 1) // block - 1, 0)
-        return (b, jnp.minimum(j, live), h, 0)
+        return (b, jnp.minimum(j, live), 0)
 
-    tile_spec = pl.BlockSpec((None, block, None, depth), tile_index)
-    scale_spec = pl.BlockSpec((None, block, None, 1), tile_index)
+    row_spec = pl.BlockSpec((None, 1, width),
+                            lambda b, j, ln: (b, 0, 0))
+    tile_spec = pl.BlockSpec((None, block, width), tile_index)
+    scale_spec = pl.BlockSpec((None, block, heads), tile_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(batch, heads, num_blocks),
-        in_specs=[
-            pl.BlockSpec((None, None, 1, depth),
-                         lambda b, h, j, ln: (b, h, 0, 0)),
-            tile_spec,
-            scale_spec,
-            tile_spec,
-            scale_spec,
-        ],
-        out_specs=pl.BlockSpec((None, None, 1, depth),
-                               lambda b, h, j, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, depth), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
+        grid=(batch, t_len // block),
+        in_specs=[row_spec, tile_spec, scale_spec, tile_spec,
+                  scale_spec],
+        out_specs=row_spec,
+        scratch_shapes=decode_scratch_shapes(heads, depth),
     )
     out = pl.pallas_call(
         functools.partial(_dense_decode_kernel_int8, block=block,
-                          scale=scale),
+                          heads=heads, depth=depth,
+                          scale=1.0 / (depth ** 0.5)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, heads, 1, depth),
-                                       q.dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, width), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q_r, cache_k,
-      k_scales.reshape(*k_scales.shape, 1), cache_v,
-      v_scales.reshape(*v_scales.shape, 1))
-    return out.transpose(0, 2, 1, 3)  # [B, 1, H, D]
+    )(lengths.astype(jnp.int32), q.reshape(batch, 1, width),
+      cache_k.reshape(batch, t_len, width), k_scales,
+      cache_v.reshape(batch, t_len, width), v_scales)
+    return out.reshape(batch, 1, heads, depth)
 
 
 def dense_decode_attention_xla(q, cache_k, cache_v, k_scales,
@@ -163,18 +133,9 @@ def dense_decode_attention_xla(q, cache_k, cache_v, k_scales,
 
 
 def resolve_dense_decode_impl(impl: Optional[str] = None) -> str:
-    """'kernel' | 'xla' | None (auto). Auto stays on the XLA path
-    until tools/tpu_checks.py records an on-chip pass for
-    dense_decode_int8 in KERNEL_VALIDATION.json AND the current
-    backend is tpu (ops/kernel_select)."""
-    if impl is not None:
-        if impl not in ("kernel", "xla"):
-            raise ValueError(
-                f"unknown dense decode attention impl {impl!r}")
-        return impl
-    return kernel_select.resolve_auto("dense_decode_int8",
-                                      pallas_impl="kernel",
-                                      fallback="xla")
+    """'kernel' | 'xla' | None (auto: the kernel on a TPU backend,
+    the XLA formulation elsewhere)."""
+    return resolve_kernel_or_xla(impl, "dense decode attention")
 
 
 def dense_decode_attention(q, cache_k, cache_v, k_scales, v_scales,

@@ -17,6 +17,19 @@ compute). Online softmax accumulates across the (sequential) page grid
 dimension in VMEM scratch — the flash-attention recurrence over the
 page list.
 
+One program handles ALL heads of one page: the pool stays
+[P, page, H, D] in HBM and is blocked as its free reshape
+[P, page, H*D], so the block's last two dims are (page, H*D) — lane
+dense, and legal under Mosaic's (8, 128) block rule for bf16 and int8
+alike (a per-head block would squeeze the second-minor H dimension,
+which Mosaic refuses). Per-head scores come from ONE matmul against a
+block-diagonal query (row h holds q_h in columns h*D..(h+1)*D, zeros
+elsewhere): K[page, H*D] x q_bd[H, H*D]^T -> [page, H]. That puts
+positions on sublanes and heads on lanes, which is exactly the layout
+of the int8 pool's [page, H] scale tile, so dequantization is an
+elementwise multiply on the scores (and on the probabilities for V)
+instead of on the page.
+
 Reference analog: none — the reference (Azure batch-shipyard) has no
 serving runtime; this is net-new TPU compute-path work alongside
 ops/attention.py. The block-table design follows the public
@@ -36,93 +49,115 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _accumulate_page(q_row, k_tile, v_tile, j, length, o_acc, m_acc,
-                     l_acc, *, page: int, scale: float):
-    """ONE online-softmax block update over a (pre-dequantized) page
-    tile — the recurrence shared by the fp and int8 kernels (a fix to
-    the mask/correction/denominator logic lands in both)."""
-    scores = jax.lax.dot_general(
-        q_row, k_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # [1, page]
-    pos = j * page + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page), 1)
-    scores = jnp.where(pos < length, scores, _NEG_INF)
-    m_blk = jnp.max(scores, axis=-1, keepdims=True)       # [1, 1]
-    m_new = jnp.maximum(m_acc[...], m_blk)
-    correction = jnp.exp(m_acc[...] - m_new)
-    p = jnp.exp(scores - m_new)                            # [1, page]
-    l_new = (l_acc[...] * correction +
-             jnp.sum(p, axis=-1, keepdims=True))
-    pv = jax.lax.dot_general(
-        p.astype(v_tile.dtype), v_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [1, D]
-    o_acc[...] = o_acc[...] * correction + pv
-    m_acc[...] = m_new
-    l_acc[...] = l_new
+def _head_block_mask(heads: int, depth: int):
+    """[H, H*D] True on head h's own D columns — the block diagonal."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (heads, heads * depth), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, heads * depth), 1)
+    return (col >= row * depth) & (col < (row + 1) * depth)
 
 
-def _init_and_emit(j, num_blocks, o_ref, o_acc, m_acc, l_acc):
+def _heads_to_sublanes(row, heads: int):
+    """[1, H] (heads on lanes) -> [H, 1] (heads on sublanes) with
+    iota/select/lane-reduce only: Mosaic has no cheap general
+    lane->sublane relayout, and this one is a single (H, H) tile."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (heads, heads), 0) ==
+           jax.lax.broadcasted_iota(jnp.int32, (heads, heads), 1))
+    return jnp.sum(
+        jnp.where(eye, jnp.broadcast_to(row, (heads, heads)), 0.0),
+        axis=1, keepdims=True)
+
+
+def decode_block_step(length, q_ref, k_ref, ks_ref, v_ref, vs_ref,
+                      o_ref, o_acc, m_acc, l_acc, *, block: int,
+                      heads: int, depth: int, scale: float):
+    """One (slot, key-block) program of single-token decode attention
+    over all heads — the body shared by the paged kernel here and the
+    dense int8 kernel (ops/decode_attention.py); a fix to the
+    mask/correction/denominator logic lands in both.
+
+    q_ref/o_ref: [1, H*D]. k_ref/v_ref: [block, H*D] (fp or int8).
+    ks_ref/vs_ref: [block, H] fp32 scales for int8 tiles, else None.
+    Scratch persists across the sequential key-block grid dimension
+    (program_id(1)): o_acc [H, H*D] fp32 numerator (only its block
+    diagonal is meaningful), m_acc/l_acc [1, H] running max /
+    denominator. ``length`` counts the slot's valid keys."""
+    j = pl.program_id(1)
+    num_blocks = pl.num_programs(1)
+
     @pl.when(j == 0)
     def _init():
         o_acc[...] = jnp.zeros_like(o_acc)
         m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
+    @pl.when(j * block < length)
+    def _accumulate():
+        q = q_ref[...]
+        # The select runs in fp32: a bf16 operand under an i1 mask of
+        # 32-bit layout is a relayout Mosaic rejects.
+        q_bd = jnp.where(
+            _head_block_mask(heads, depth),
+            jnp.broadcast_to(q.astype(jnp.float32),
+                             (heads, heads * depth)),
+            0.0).astype(q.dtype)
+        scores = jax.lax.dot_general(
+            k_ref[...].astype(q.dtype), q_bd,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [block, H]
+        if ks_ref is not None:
+            scores = scores * ks_ref[...]
+        scores = scores * scale
+        pos = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 0)
+        scores = jnp.where(pos < length, scores, _NEG_INF)
+        m_prev = m_acc[...]                              # [1, H]
+        m_new = jnp.maximum(
+            m_prev, jnp.max(scores, axis=0, keepdims=True))
+        correction = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)                      # [block, H]
+        l_acc[...] = (l_acc[...] * correction +
+                      jnp.sum(p, axis=0, keepdims=True))
+        m_acc[...] = m_new
+        if vs_ref is not None:
+            p = p * vs_ref[...]
+        pv = jax.lax.dot_general(
+            p.astype(q.dtype), v_ref[...].astype(q.dtype),
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [H, H*D]
+        o_acc[...] = (o_acc[...] *
+                      _heads_to_sublanes(correction, heads) + pv)
+
+    @pl.when(j == num_blocks - 1)
     def _emit():
         l_final = l_acc[...]
-        denom = jnp.where(l_final == 0.0, 1.0, l_final)
-        o_ref[...] = (o_acc[...] / denom).astype(o_ref.dtype)
-    return _emit
+        denom = _heads_to_sublanes(
+            jnp.where(l_final == 0.0, 1.0, l_final), heads)
+        out = jnp.where(_head_block_mask(heads, depth),
+                        o_acc[...] / denom, 0.0)
+        # Each column has exactly one live row (its head's): the
+        # sublane sum folds the block diagonal into [1, H*D].
+        o_ref[...] = jnp.sum(out, axis=0,
+                             keepdims=True).astype(o_ref.dtype)
+
+
+def decode_scratch_shapes(heads: int, depth: int) -> list:
+    """VMEM scratch decode_block_step expects, in argument order."""
+    return [pltpu.VMEM((heads, heads * depth), jnp.float32),
+            pltpu.VMEM((1, heads), jnp.float32),
+            pltpu.VMEM((1, heads), jnp.float32)]
 
 
 def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
-                         o_ref, o_acc, m_acc, l_acc, *,
-                         page: int, scale: float):
-    """One (slot, head, page-step) program.
-
-    q_ref: [1, D] this slot+head's query row.
-    k_ref/v_ref: [page, D] the physical page selected by the BlockSpec
-    index map (table_ref[b, j]).
-    Scratch persists across the sequential page dimension: o_acc [1, D]
-    fp32 numerator, m_acc/l_acc [1, 1] running max / denominator.
-    """
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    num_blocks = pl.num_programs(2)
-    length = len_ref[b]
-    emit = _init_and_emit(j, num_blocks, o_ref, o_acc, m_acc, l_acc)
-
-    @pl.when(j * page < length)
-    def _accumulate():
-        _accumulate_page(q_ref[...], k_ref[...], v_ref[...], j,
-                         length, o_acc, m_acc, l_acc, page=page,
-                         scale=scale)
-
-    pl.when(j == num_blocks - 1)(emit)
+                         o_ref, *scratch, **static):
+    decode_block_step(len_ref[pl.program_id(0)], q_ref, k_ref, None,
+                      v_ref, None, o_ref, *scratch, **static)
 
 
 def _paged_decode_kernel_int8(table_ref, len_ref, q_ref, k_ref,
-                              ks_ref, v_ref, vs_ref, o_ref, o_acc,
-                              m_acc, l_acc, *, page: int,
-                              scale: float):
-    """int8-page variant: the same recurrence with the K/V tiles
-    dequantized in VMEM (k int8 [page, D] * scale [page, 1]) right
-    before the dots — HBM traffic stays int8."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    num_blocks = pl.num_programs(2)
-    length = len_ref[b]
-    emit = _init_and_emit(j, num_blocks, o_ref, o_acc, m_acc, l_acc)
-
-    @pl.when(j * page < length)
-    def _accumulate():
-        k_tile = k_ref[...].astype(jnp.float32) * ks_ref[...]
-        v_tile = v_ref[...].astype(jnp.float32) * vs_ref[...]
-        _accumulate_page(q_ref[...].astype(jnp.float32), k_tile,
-                         v_tile, j, length, o_acc, m_acc, l_acc,
-                         page=page, scale=scale)
-
-    pl.when(j == num_blocks - 1)(emit)
+                              ks_ref, v_ref, vs_ref, o_ref, *scratch,
+                              **static):
+    decode_block_step(len_ref[pl.program_id(0)], q_ref, k_ref, ks_ref,
+                      v_ref, vs_ref, o_ref, *scratch, **static)
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
@@ -135,64 +170,54 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     here but softmax-of-all-masked garbage from the XLA path; the
     decode contract never attends an unwritten slot).
     k_scales/v_scales: [P, page, H] fp32 when the pages are int8
-    (dequantized in-kernel per tile). Returns [B, 1, H, D] in
-    q.dtype."""
+    (applied in-kernel per tile). Returns [B, 1, H, D] in q.dtype."""
     batch, seq, heads, depth = q.shape
     assert seq == 1, "decode consumes one token per call"
-    _pages, page, _heads, _depth = k_pages.shape
+    num_pages, page, _heads, _depth = k_pages.shape
     max_blocks = block_table.shape[1]
-    scale = 1.0 / (depth ** 0.5)
-    q_r = q.reshape(batch, heads, 1, depth)
+    width = heads * depth
     int8_pages = k_scales is not None
 
-    def page_index(b, h, j, tbl, ln):
+    def page_index(b, j, tbl, ln):
         # Clamp dead steps to the slot's LAST live page: the prefetch
         # pipeline fetches block j+1 while computing block j, and an
         # unclamped map would DMA whatever stale id sits in the dead
         # tail of the table row. Page 0 fallback covers length == 0.
         live = jnp.maximum((ln[b] + page - 1) // page - 1, 0)
-        return (tbl[b, jnp.minimum(j, live)], 0, h, 0)
+        return (tbl[b, jnp.minimum(j, live)], 0, 0)
 
-    page_spec = pl.BlockSpec((None, page, None, depth), page_index)
-    in_specs = [
-        pl.BlockSpec((None, None, 1, depth),
-                     lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-        page_spec,
-    ]
-    operands = [q_r, k_pages]
+    row_spec = pl.BlockSpec((None, 1, width),
+                            lambda b, j, tbl, ln: (b, 0, 0))
+    page_spec = pl.BlockSpec((None, page, width), page_index)
+    scale_spec = pl.BlockSpec((None, page, heads), page_index)
+    in_specs = [row_spec, page_spec]
+    operands = [q.reshape(batch, 1, width),
+                k_pages.reshape(num_pages, page, width)]
     if int8_pages:
-        scale_spec = pl.BlockSpec((None, page, None, 1), page_index)
         in_specs.append(scale_spec)
-        operands.append(
-            k_scales.reshape(*k_scales.shape, 1))
+        operands.append(k_scales)
     in_specs.append(page_spec)
-    operands.append(v_pages)
+    operands.append(v_pages.reshape(num_pages, page, width))
     if int8_pages:
         in_specs.append(scale_spec)
-        operands.append(
-            v_scales.reshape(*v_scales.shape, 1))
+        operands.append(v_scales)
     kern = (_paged_decode_kernel_int8 if int8_pages
             else _paged_decode_kernel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(batch, heads, max_blocks),
+        grid=(batch, max_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, 1, depth),
-                               lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, depth), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
+        out_specs=row_spec,
+        scratch_shapes=decode_scratch_shapes(heads, depth),
     )
     out = pl.pallas_call(
-        functools.partial(kern, page=page, scale=scale),
+        functools.partial(kern, block=page, heads=heads, depth=depth,
+                          scale=1.0 / (depth ** 0.5)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, heads, 1, depth),
-                                       q.dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, width), q.dtype),
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
-    return out.transpose(0, 2, 1, 3)  # [B, 1, H, D]
+    return out.reshape(batch, 1, heads, depth)
 
 
 def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
@@ -233,20 +258,27 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
     return out.astype(q.dtype)
 
 
+def resolve_kernel_or_xla(impl: Optional[str], what: str) -> str:
+    """The decode kernels' selection rule: None -> 'kernel' (Pallas)
+    on a TPU backend, 'xla' elsewhere; a named impl passes through."""
+    if impl is None:
+        return "kernel" if jax.default_backend() == "tpu" else "xla"
+    if impl not in ("kernel", "xla"):
+        raise ValueError(f"unknown {what} impl {impl!r}")
+    return impl
+
+
+def resolve_paged_impl(impl: Optional[str] = None) -> str:
+    return resolve_kernel_or_xla(impl, "paged attention")
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            impl: Optional[str] = None,
                            k_scales=None, v_scales=None):
-    """Dispatch: 'kernel' (Pallas) or 'xla'. Default: kernel on TPU,
-    xla elsewhere (mirrors ops/attention.attention's dispatch).
+    """Dispatch: 'kernel' (Pallas) or 'xla' (resolve_paged_impl).
     k_scales/v_scales switch both paths to int8-page dequant."""
-    if impl is None:
-        impl = "kernel" if jax.default_backend() == "tpu" else "xla"
-    if impl == "kernel":
-        return paged_decode_attention_kernel(
-            q, k_pages, v_pages, block_table, lengths,
-            k_scales=k_scales, v_scales=v_scales)
-    if impl == "xla":
-        return paged_decode_attention_xla(
-            q, k_pages, v_pages, block_table, lengths,
-            k_scales=k_scales, v_scales=v_scales)
-    raise ValueError(f"unknown paged attention impl {impl!r}")
+    fn = (paged_decode_attention_kernel
+          if resolve_paged_impl(impl) == "kernel"
+          else paged_decode_attention_xla)
+    return fn(q, k_pages, v_pages, block_table, lengths,
+              k_scales=k_scales, v_scales=v_scales)

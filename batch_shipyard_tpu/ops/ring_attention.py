@@ -23,50 +23,36 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from batch_shipyard_tpu.utils.compat import shard_map
-
 from batch_shipyard_tpu.ops import attention as attn_ops
-from batch_shipyard_tpu.ops import kernel_select
 
 
 RING_IMPLS = ("pallas_dma", "flash", "xla")
 
 
-def resolve_ring_impl(impl: str = "auto") -> str:
-    """Resolve 'auto' to a concrete ring implementation.
-
-    Priority: explicit impl > SHIPYARD_RING_IMPL env > the
-    KERNEL_VALIDATION.json marker via ops/kernel_select
-    ('pallas_dma' — flash kernels + async-DMA ring permute — only
-    when BOTH the ring_collectives and flash_ring checks passed on a
-    TPU backend; 'flash' when flash_ring alone passed; both require
-    the current backend to be tpu) > 'xla'. CPU always resolves to
+def resolve_ring_impl(impl: str = "auto", t_local: int = 0) -> str:
+    """Resolve 'auto' to a concrete ring implementation from what the
+    code can observe: 'flash' (Pallas kernels per rotation,
+    lax.ppermute rotation) on a TPU backend when the local shard
+    length tiles the flash blocks, else 'xla'. CPU always resolves to
     'xla' — pallas interpret mode aborts inside shard_map there.
-    """
-    if impl != "auto":
-        return impl
-    env = os.environ.get("SHIPYARD_RING_IMPL")
-    if env:
-        if env not in RING_IMPLS:
-            raise ValueError(
-                f"SHIPYARD_RING_IMPL={env!r}: must be one of "
-                f"{', '.join(RING_IMPLS)}")
-        return env
-    resolved = kernel_select.resolve_auto("flash_ring",
-                                          pallas_impl="flash")
-    if resolved == "flash":
-        # The DMA-permute tier needs its own silicon proof on top of
-        # the flash one (tools/tpu_checks.py check 'ring_collectives').
-        return kernel_select.resolve_auto("ring_collectives",
-                                          pallas_impl="pallas_dma",
-                                          fallback="flash")
-    return resolved
+    'pallas_dma' (flash + the async-remote-DMA KV permute) is reached
+    by naming it."""
+    if impl == "auto":
+        if (jax.default_backend() == "tpu"
+                and attn_ops.flash_shapes_ok(t_local, t_local)):
+            return "flash"
+        return "xla"
+    if impl not in RING_IMPLS:
+        raise ValueError(
+            f"unknown ring attention impl {impl!r}: must be one of "
+            f"{', '.join(RING_IMPLS)} or 'auto'")
+    return impl
 
 
 def _flash_ring_rotation(q, k_cur, v_cur, my_idx, src, causal: bool):
@@ -106,7 +92,7 @@ def _ring_attention_local_flash(q, k, v, axis_name: str, causal: bool,
     kv_permute: 'ppermute' rotates KV shards with lax.ppermute (XLA
     schedules the transfer); 'dma' uses the async-remote-DMA Pallas
     permute kernel (ops/ring_collectives.ring_permute_pair) — the
-    impl='pallas_dma' tier, TPU silicon only.
+    impl='pallas_dma' tier, TPU only.
     """
     axis_size = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
@@ -216,18 +202,16 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     impl: 'pallas_dma' (flash kernels per rotation + async-remote-DMA
     KV permute — the deepest on-chip tier), 'flash' (Pallas kernels
     per rotation, lax.ppermute rotation), 'xla' (pure-XLA online
-    softmax — runs anywhere), or 'auto' (resolved by
-    resolve_ring_impl: the validated Pallas tiers on a TPU backend
-    once the KERNEL_VALIDATION.json marker records their on-chip
-    passes, else xla).
+    softmax — runs anywhere), or 'auto' (resolve_ring_impl: flash on
+    a TPU backend when the shard length tiles, else xla).
     """
-    impl = resolve_ring_impl(impl)
-    if impl in ("flash", "pallas_dma"):
-        t_local = q.shape[1] // mesh.shape[axis_name]
-        if not attn_ops.flash_shapes_ok(t_local, t_local):
-            raise ValueError(
-                f"local shard length {t_local} does not tile the "
-                f"flash blocks; use impl='xla'")
+    t_local = q.shape[1] // mesh.shape[axis_name]
+    impl = resolve_ring_impl(impl, t_local)
+    if impl in ("flash", "pallas_dma") and \
+            not attn_ops.flash_shapes_ok(t_local, t_local):
+        raise ValueError(
+            f"local shard length {t_local} does not tile the "
+            f"flash blocks; use impl='xla'")
     if impl == "pallas_dma":
         body = functools.partial(
             _ring_attention_local_flash, kv_permute="dma",

@@ -43,11 +43,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
-
-from batch_shipyard_tpu.utils.compat import shard_map
 
 # Distinct barrier-semaphore ids per collective kernel family (the
 # Mosaic barrier semaphore is global per collective_id; these kernels
@@ -137,13 +136,13 @@ def _ring_permute_call(k, v, axis_name: str, mesh_axis_names,
             shift=shift),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,))],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
     )(k, v)
 
@@ -253,14 +252,14 @@ def _ring_all_gather_local(x, *, axis_name: str, mesh_axis_names,
                                  x.dtype),
             jax.ShapeDtypeStruct((2, chunk) + x.shape[1:], x.dtype),
         ),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.REGULAR],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_CID_ALL_GATHER),
     )(x)
     return out
@@ -360,16 +359,16 @@ def _ring_reduce_scatter_local(x, *, axis_name: str, mesh_axis_names,
             jax.ShapeDtypeStruct((chunk,) + x.shape[1:], x.dtype),
             jax.ShapeDtypeStruct((2, chunk) + x.shape[1:], x.dtype),
         ),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.REGULAR,
                         pltpu.VMEM((chunk,) + x.shape[1:], x.dtype),
                         pltpu.VMEM((chunk,) + x.shape[1:], x.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_CID_REDUCE_SCATTER),
     )(x)
     return out

@@ -25,9 +25,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from batch_shipyard_tpu.utils.compat import shard_map
 
 
 def stack_stage_params(per_stage_params: list[Any]) -> Any:

@@ -172,10 +172,11 @@ def is_tpu_accelerator(accelerator_type: str) -> bool:
         return False
 
 
-# jax device_kind substrings -> generation key. Checked in order, so
-# more specific strings ("v5p", "v5 lite") precede bare version
-# matches. Covers the public PJRT device_kind spellings ("TPU v4",
-# "TPU v5 lite", "TPU v5p", "TPU v6 lite" / "TPU v6e" aka Trillium).
+# jax device_kind substrings -> generation key, covering the public
+# PJRT device_kind spellings ("TPU v4", "TPU v5 lite", "TPU v5p",
+# "TPU v6 lite" / "TPU v6e" aka Trillium). No bare "v5"/"v6"
+# catch-all: a spelling this table does not know is an error, not a
+# guessed peak.
 _DEVICE_KIND_PATTERNS: tuple[tuple[str, str], ...] = (
     ("v5 lite", "v5litepod"),
     ("v5lite", "v5litepod"),
@@ -187,8 +188,6 @@ _DEVICE_KIND_PATTERNS: tuple[tuple[str, str], ...] = (
     ("v2", "v2"),
     ("v3", "v3"),
     ("v4", "v4"),
-    ("v5", "v5p"),
-    ("v6", "v6e"),
 )
 
 
@@ -196,15 +195,18 @@ def generation_for_device_kind(device_kind: str
                                ) -> Optional[TpuGeneration]:
     """Map a jax ``device.device_kind`` string (e.g. ``"TPU v5 lite"``)
     to its generation table entry, or None for non-TPU backends (cpu
-    "cpu", gpu device names). Used by bench MFU accounting to pick the
-    peak-FLOPs denominator for whatever chip answered."""
+    "cpu", gpu device names). A TPU kind the table does not list
+    raises: its peak would be a guess. Used by bench MFU accounting to
+    pick the peak-FLOPs denominator for whatever chip answered."""
     kind = device_kind.strip().lower()
     if "tpu" not in kind:
         return None
     for pattern, gen_name in _DEVICE_KIND_PATTERNS:
         if pattern in kind:
             return _GENERATIONS[gen_name]
-    return None
+    raise ValueError(
+        f"unknown TPU device_kind {device_kind!r}: add it to "
+        f"parallel/topology.py with its published peaks")
 
 
 def peak_bf16_tflops_for_device_kind(device_kind: str

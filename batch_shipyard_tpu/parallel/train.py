@@ -16,6 +16,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from batch_shipyard_tpu.agent import progress as progress_mod
@@ -23,6 +24,7 @@ from batch_shipyard_tpu.compilecache import manager as cc_manager
 from batch_shipyard_tpu.goodput import events as goodput_events
 from batch_shipyard_tpu.models import resnet as resnet_mod
 from batch_shipyard_tpu.models import transformer as tfm
+from batch_shipyard_tpu.ops import attention as attn_ops
 from batch_shipyard_tpu.ops import ring_attention as ring
 from batch_shipyard_tpu.parallel import sharding as shard_rules
 
@@ -47,31 +49,69 @@ class TrainHarness:
 
 
 def _aot_step(compiled: dict, step: Callable, *args):
-    """Dispatch through the AOT executable when one is installed.
-    Signature/layout mismatches (an abstract-shape guess that doesn't
-    match the real batch) raise at call validation, BEFORE any donated
-    buffer is consumed — drop the executable and fall back to the jit
-    path, which compiles for the true signature."""
-    fn = compiled.get("step")
-    if fn is not None:
-        try:
-            return fn(*args)
-        except (TypeError, ValueError):
-            compiled.pop("step", None)
-    return step(*args)
+    """Dispatch through the AOT executable when one is installed. A
+    signature/layout mismatch (an abstract-shape guess that doesn't
+    match the real batch) raises at call validation, before any
+    donated buffer is consumed — it is the precompile's bug to fix,
+    not something to hide behind a silent second compile."""
+    return compiled.get("step", step)(*args)
 
 
 def make_transformer_config(mesh: Optional[Mesh] = None,
                             **overrides) -> tfm.TransformerConfig:
     """Build a config whose attention_fn matches the mesh: ring
-    attention when sp > 1, flash/blockwise otherwise."""
+    attention when sp > 1; on any other multi-device mesh the
+    single-chip dispatch (flash/blockwise) under a shard_map over the
+    batch and head axes — inside a global-view jit XLA refuses to
+    partition an opaque Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned"), and attention needs no communication
+    across batch rows or heads anyway."""
     attention_fn = overrides.pop("attention_fn", None)
     if attention_fn is None and mesh is not None and \
             mesh.shape.get("sp", 1) > 1:
         def attention_fn(q, k, v, causal):
             return ring.ring_attention(q, k, v, mesh, axis_name="sp",
                                        causal=causal)
+    elif attention_fn is None and mesh is not None and mesh.size > 1:
+        spec = P(("dp", "fsdp"), None, "tp", None)
+
+        def attention_fn(q, k, v, causal):
+            return shard_map(
+                functools.partial(attn_ops.attention, causal=causal),
+                mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False)(q, k, v)
     return tfm.TransformerConfig(attention_fn=attention_fn, **overrides)
+
+
+def sharded_lm_loss(mesh: Mesh) -> Callable:
+    """tfm.lm_loss_chunked for a global-view jit over ``mesh``: each
+    (batch, sequence) shard runs the chunked loss on its own rows
+    against the whole embedding and hands back its (loss sum, token
+    count); the mean is taken outside. The per-shard call is what
+    lets the Pallas loss kernel run on a multi-device mesh at all
+    (XLA does not partition Mosaic calls), and returning sums instead
+    of reducing inside keeps every collective — and its transpose —
+    XLA's."""
+    if mesh.size == 1:
+        return tfm.lm_loss_chunked
+    shards = ("dp", "fsdp", "sp")
+
+    def local(hidden, embedding, targets):
+        count = jnp.sum(targets != -1).astype(jnp.float32)
+        mean = tfm.lm_loss_chunked(hidden, embedding, targets)
+        return (mean * jnp.maximum(count, 1.0))[None], count[None]
+
+    per_shard = shard_map(
+        local, mesh=mesh,
+        in_specs=(P(("dp", "fsdp"), "sp", None), P(),
+                  P(("dp", "fsdp"), "sp")),
+        out_specs=(P(shards), P(shards)), check_vma=False)
+
+    def loss(hidden, embedding, targets):
+        sums, counts = per_shard(hidden, embedding, targets)
+        return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1.0)
+
+    return loss
 
 
 def build_transformer_train(
@@ -100,16 +140,15 @@ def build_transformer_train(
     with goodput_events.phase(goodput_events.PROGRAM_COMPILE,
                               what="init") as init_attrs, \
             cc_manager.tracked(init_attrs, "transformer_init"):
-        # Sharding-invariant init draws (utils/compat): the same seed
-        # must produce the same parameters on a dp-only and a tp/sp
-        # mesh, or the parallelism configs can never agree.
-        from batch_shipyard_tpu.utils import compat
-        with compat.threefry_partitionable():
-            params = jax.jit(init_fn,
-                             out_shardings=param_shardings)(rng)
+        # jax_threefry_partitionable (on by default) makes these
+        # draws sharding-invariant: the same seed gives the same
+        # parameters on a dp-only and a tp/sp mesh.
+        params = jax.jit(init_fn, out_shardings=param_shardings)(rng)
         opt_state = jax.jit(
             optimizer.init,
             out_shardings=None)(params)
+
+    lm_loss = sharded_lm_loss(mesh)
 
     def loss_fn(params, tokens, targets):
         # Chunked tied-embedding loss: the full [B, T, vocab] fp32
@@ -117,8 +156,7 @@ def build_transformer_train(
         hidden, variables = model.apply(
             {"params": params}, tokens, return_hidden=True,
             mutable=["losses"])
-        loss = tfm.lm_loss_chunked(
-            hidden, params["embed"]["embedding"], targets)
+        loss = lm_loss(hidden, params["embed"]["embedding"], targets)
         # MoE load-balancing auxiliary losses (if any blocks sowed).
         aux_leaves = jax.tree_util.tree_leaves(
             variables.get("losses", {}))
@@ -485,7 +523,7 @@ def build_resnet_train(mesh: Mesh,
 
     def precompile():
         # bf16 images are what both the bench and the train_resnet
-        # loader feed; a different real dtype falls back to jit.
+        # loader feed.
         images_abs = jax.ShapeDtypeStruct(
             (batch_size, image_size, image_size, 3), jnp.bfloat16,
             sharding=batch_sharding)

@@ -5,7 +5,7 @@ Reference analog: the resize-error classification of
 typed `resize_errors` list; gcloud surfaces stderr text and JSON error
 bodies, so the table below maps the payload shapes observed from real
 `gcloud compute tpus tpu-vm create` / queued-resource failures onto a
-stable taxonomy the pool manager can act on:
+stable classification the pool manager can act on:
 
   kind    — quota | stockout | permission | invalid_argument |
             conflict | not_found | unavailable | internal | unknown

@@ -37,7 +37,7 @@ from batch_shipyard_tpu.utils import util
 
 logger = util.get_logger(__name__)
 
-# Allocation-error taxonomy lives in substrate/gcloud_errors.py — a
+# Allocation-error classification lives in substrate/gcloud_errors.py — a
 # table-driven classifier tested against captured real gcloud payloads
 # (the resize error classification of the reference, batch.py:661-672).
 from batch_shipyard_tpu.substrate import gcloud_errors  # noqa: E402

@@ -15,7 +15,6 @@ import os
 import socket
 import subprocess
 import sys
-import tempfile
 import time
 from typing import Optional
 
@@ -27,6 +26,20 @@ from batch_shipyard_tpu.substrate import base
 from batch_shipyard_tpu.utils import util
 
 logger = util.get_logger(__name__)
+
+
+def default_work_root(credentials: CredentialsSettings) -> str:
+    """Where node work dirs live when the caller names no work_root:
+    a FIXED path — beside a localfs state store, else under the home
+    directory. Each node's persistent compile cache lives under its
+    work dir (node_agent._compile_cache_dir), and a cache that moves
+    between runs never hits, so this is never a temporary name."""
+    storage = credentials.storage
+    if storage.backend == "localfs" and storage.root:
+        return os.path.join(
+            os.path.dirname(os.path.abspath(storage.root)), "localnode")
+    return os.path.join(os.path.expanduser("~"), ".shipyard",
+                        "localnode")
 
 
 class LocalhostSubstrate(base.ComputeSubstrate):
@@ -41,7 +54,7 @@ class LocalhostSubstrate(base.ComputeSubstrate):
                 "(localfs or gcs), not memory")
         self.store = store
         self.credentials = credentials
-        self.work_root = work_root or tempfile.mkdtemp(prefix="localnode-")
+        self.work_root = work_root or default_work_root(credentials)
         self.pool_config = pool_config or {}
         self.run_nodeprep = run_nodeprep
         self._procs: dict[str, dict[str, subprocess.Popen]] = {}

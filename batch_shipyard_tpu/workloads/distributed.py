@@ -32,6 +32,26 @@ def setup() -> dict:
     }
 
 
+def device_info() -> dict:
+    """What this process runs on, as JAX reports it — every workload
+    result names it, so a CPU run is never read as a chip run."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
+def device_memory_mib() -> list:
+    """bytes_in_use per local device in MiB (None where the backend
+    reports no memory stats, as XLA's CPU client does)."""
+    out = []
+    for device in jax.local_devices():
+        stats = device.memory_stats()
+        out.append(None if not stats else
+                   round(stats["bytes_in_use"] / 2 ** 20, 1))
+    return out
+
+
 def log(ctx: dict, message: str) -> None:
     print(f"[proc {ctx['process_index']}/{ctx['process_count']}] "
           f"{message}", flush=True)

@@ -23,9 +23,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from batch_shipyard_tpu.utils.compat import shard_map
 
 
 def p2p_pingpong(mesh: Mesh, axis: str, size_bytes: int,

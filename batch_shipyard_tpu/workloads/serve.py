@@ -27,6 +27,7 @@ from batch_shipyard_tpu.models import inference as inf
 from batch_shipyard_tpu.models import serving
 from batch_shipyard_tpu.models import transformer as tfm
 from batch_shipyard_tpu.models.server import ServingFrontEnd
+from batch_shipyard_tpu.workloads import distributed
 
 
 def warm_engine(args, engine: serving.ContinuousBatcher) -> None:
@@ -143,7 +144,8 @@ def build_slo(args):
 
 
 def build_engine(args, config=None, params=None,
-                 speculative=None, slo=None) -> serving.ContinuousBatcher:
+                 speculative=None, slo=None,
+                 device=None) -> serving.ContinuousBatcher:
     if config is None:
         config = build_config(args)
     if params is None:
@@ -163,10 +165,10 @@ def build_engine(args, config=None, params=None,
         prefix_cache=not args.no_prefix_cache,
         slo_shed_grace_ms=slo.shed_grace_ms if slo else None,
         tpot_stall_factor=(slo.tpot_stall_factor if slo else 4.0),
-        speculative=speculative)
+        speculative=speculative, device=device)
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser()
     parser.add_argument("--d-model", type=int, default=256)
     parser.add_argument("--n-layers", type=int, default=4)
@@ -272,7 +274,11 @@ def main() -> int:
                              "(models/router.py); the router binds "
                              "--host/--port")
     compilecache.add_compile_cache_args(parser)
-    args = parser.parse_args()
+    return parser
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     # Persistent compile cache before any engine construction: the
     # engine __init__ compiles nothing, but warm-up / precompile and
     # the first requests do, and pool restarts should hit warm.
@@ -293,9 +299,14 @@ def main() -> int:
         # Like the target params, the draft tree is built once and
         # shared across every replica engine.
         speculative = build_draft(args) if args.speculative else None
+        # One engine per device, round-robin: each gets its own copy
+        # of the weights and its own KV pool on its own chip (more
+        # replicas than devices share).
+        devices = jax.devices()
         engines = [build_engine(args, config, params, speculative,
-                                slo=slo)
-                   for _ in range(args.replicas)]
+                                slo=slo,
+                                device=devices[i % len(devices)])
+                   for i in range(args.replicas)]
         # Warm every replica BEFORE it starts taking traffic (jit
         # compiles recorded as engine warm-up goodput; must run before
         # the front's engine thread owns the stepping). Same-config
@@ -363,6 +374,14 @@ def main() -> int:
         shared_prefix_groups=args.shared_prefix_groups,
         shared_prefix_len=args.shared_prefix_len,
         slo_classes=slo_classes)
+    report["device"] = distributed.device_info()
+    report["replica_devices"] = [
+        str(f.engine.device or jax.devices()[0]) for f in fronts]
+    report["hbm_in_use_mib"] = distributed.device_memory_mib()
+    if args.kv_page_size:
+        from batch_shipyard_tpu.ops import paged_attention
+        report["paged_decode_impl"] = paged_attention.resolve_paged_impl(
+            fronts[0].engine.config.paged_attention_impl)
     if router is not None:
         report["router"] = router.stats()
     prefix = [f.engine.prefix_stats() for f in fronts]

@@ -28,7 +28,7 @@ from batch_shipyard_tpu.workloads import checkpoint
 from batch_shipyard_tpu.workloads import distributed
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser()
     parser.add_argument("--d-model", type=int, default=1024)
     parser.add_argument("--n-layers", type=int, default=12)
@@ -55,9 +55,13 @@ def main() -> int:
     parser.add_argument("--no-remat", action="store_true")
     checkpoint.add_checkpoint_args(parser)
     compilecache.add_compile_cache_args(parser)
-    args = parser.parse_args()
+    return parser
 
-    ctx = distributed.setup()
+
+def build(args):
+    """(mesh, config, harness) for the parsed flags: the mesh over
+    every visible device, the model config matched to it, the
+    persistent compile cache enabled, the train step built."""
     n_dev = jax.device_count()
     mesh = mesh_mod.make_mesh(mesh_mod.auto_axis_sizes(
         n_dev, tp=args.tp, sp=args.sp, fsdp=args.fsdp, ep=args.ep))
@@ -84,14 +88,33 @@ def main() -> int:
         model_digest=compilecache.config_digest(config))
     harness = train_mod.build_transformer_train(
         mesh, config, batch_size=args.batch, seq_len=args.seq_len)
-    # --aot-precompile: the step compiles on a background thread while
-    # the host builds the data pipeline below; joined before warm-up.
-    join_aot = (compilecache.aot.precompile_async(harness)
-                if args.aot_precompile else None)
+    return mesh, config, harness
+
+
+def resolved_kernels(args, mesh) -> dict:
+    """Which implementation each dispatch on the step resolves to for
+    these flags on this backend — so a give-way (an untileable
+    sequence length dropping flash for blockwise, a lane-misaligned
+    d_model dropping the Pallas loss) is printed, not silent."""
+    from batch_shipyard_tpu.ops import attention as attn_ops
+    from batch_shipyard_tpu.ops import chunked_loss, ring_attention
+    sp = mesh.shape["sp"]
+    t_local = args.seq_len // sp
+    attention = (
+        f"ring:{ring_attention.resolve_ring_impl('auto', t_local)}"
+        if sp > 1 else
+        attn_ops.resolve_attention_impl(None, t_local, t_local))
+    return {"attention": attention,
+            "loss": chunked_loss.resolve_xent_impl("auto",
+                                                   args.d_model)}
+
+
+def synthetic_batch(args, harness):
+    """One seeded random token batch, placed as the step expects."""
     from batch_shipyard_tpu.data import loader
     rng = np.random.RandomState(jax.process_index())
     local_batch = args.batch // jax.process_count()
-    batch = loader.place_global({
+    return loader.place_global({
         "tokens": np.asarray(
             rng.randint(0, args.vocab, (local_batch, args.seq_len)),
             np.int32),
@@ -99,6 +122,17 @@ def main() -> int:
             rng.randint(0, args.vocab, (local_batch, args.seq_len)),
             np.int32),
     }, harness.batch_sharding)
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    ctx = distributed.setup()
+    mesh, _config, harness = build(args)
+    # --aot-precompile: the step compiles on a background thread while
+    # the host builds the data pipeline below; joined before warm-up.
+    join_aot = (compilecache.aot.precompile_async(harness)
+                if args.aot_precompile else None)
+    batch = synthetic_batch(args, harness)
     params, opt_state = harness.params, harness.opt_state
     ckpt = checkpoint.TrainCheckpointer.from_args(args)
     params, opt_state, start_step = ckpt.restore(params, opt_state)
@@ -171,10 +205,15 @@ def main() -> int:
     # step, then drains any in-flight async persist.
     ckpt.finalize(start_step + args.steps, params, opt_state)
     tokens_per_sec = args.batch * args.seq_len * args.steps / elapsed
+    device = distributed.device_info()
     distributed.log(ctx, (
-        f"transformer: mesh={dict(mesh.shape)} "
+        f"transformer: platform={device['platform']} "
+        f"device_kind={device['kind']!r} devices={device['count']} "
+        f"mesh={dict(mesh.shape)} "
+        f"kernels={resolved_kernels(args, mesh)} "
         f"{tokens_per_sec:.0f} tok/s, loss={loss:.4f}, "
-        f"{elapsed / args.steps * 1000:.1f} ms/step"))
+        f"{elapsed / args.steps * 1000:.1f} ms/step, "
+        f"hbm_in_use_mib={distributed.device_memory_mib()}"))
     return 0
 
 

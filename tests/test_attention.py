@@ -209,34 +209,18 @@ def test_dense_decode_int8_kernel_matches_dequant_einsum():
     assert rel_fp < 0.02, rel_fp
 
 
-def test_dense_decode_impl_resolution():
-    """auto stays on the XLA path until the dense_decode_int8 check
-    passes on a TPU backend; explicit impls pass through; unknown
-    impls fail fast."""
-    import json
+def test_dense_decode_impl_resolution(monkeypatch):
+    """auto resolves from the backend alone (kernel on TPU, xla
+    elsewhere); explicit impls pass through; unknown impls fail
+    fast."""
     from batch_shipyard_tpu.ops import decode_attention as dd
-    from batch_shipyard_tpu.ops import kernel_select
     assert dd.resolve_dense_decode_impl("kernel") == "kernel"
     assert dd.resolve_dense_decode_impl("xla") == "xla"
     with pytest.raises(ValueError):
         dd.resolve_dense_decode_impl("bogus")
-    # CPU backend: even a tpu-backed marker leaves auto on xla.
-    import os
-    import tempfile
-    with tempfile.TemporaryDirectory() as tmp:
-        marker = os.path.join(tmp, "KERNEL_VALIDATION.json")
-        with open(marker, "w", encoding="utf-8") as fh:
-            json.dump({"dense_decode_int8":
-                       {"ok": True, "backend": "tpu"}}, fh)
-        old = os.environ.get(kernel_select.MARKER_ENV)
-        os.environ[kernel_select.MARKER_ENV] = marker
-        try:
-            assert dd.resolve_dense_decode_impl(None) == "xla"
-        finally:
-            if old is None:
-                os.environ.pop(kernel_select.MARKER_ENV, None)
-            else:
-                os.environ[kernel_select.MARKER_ENV] = old
+    assert dd.resolve_dense_decode_impl(None) == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dd.resolve_dense_decode_impl(None) == "kernel"
 
 
 def test_dense_decode_kernel_through_transformer():

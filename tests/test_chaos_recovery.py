@@ -83,7 +83,7 @@ def test_chaos_plan_rejects_unknown_kind():
 # -------------------------- wedge watchdog -----------------------------
 
 def test_wedge_watchdog_kills_and_retry_completes(tmp_path):
-    """The TPU-wedge shape (TPU_WEDGE_REPORT.md): a task that stays
+    """The wedge shape: a task that stays
     alive but emits no progress beats is killed by the watchdog at
     its progress deadline, requeued with backoff, and completes on
     the retry — an unbounded hang became one bounded retry."""

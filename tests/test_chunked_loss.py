@@ -1,7 +1,7 @@
 """Chunked cross-entropy tests: Pallas kernel (interpret mode) and
 scan-chunked XLA path vs the dense oracle — forward and gradients —
-plus the lm_loss_chunked delegation, validation-marker-gated auto
-dispatch (ops/kernel_select), and the silicon-proof dry-run."""
+plus the lm_loss_chunked delegation, impl='auto' resolution, and the
+silicon-proof dry-run."""
 
 import json
 import os
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from batch_shipyard_tpu.ops import chunked_loss as cl
-from batch_shipyard_tpu.ops import kernel_select, ring_attention
+from batch_shipyard_tpu.ops import ring_attention
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
@@ -101,46 +101,28 @@ def test_lane_misaligned_dim_falls_back_to_xla():
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
 
-# -- validation-marker dispatch (ops/kernel_select) -----------------
+# -- impl='auto' resolution: backend and shape only -----------------
 
-def test_auto_resolves_xla_on_cpu_even_with_marker(tmp_path,
-                                                   monkeypatch):
-    marker = tmp_path / "KERNEL_VALIDATION.json"
-    marker.write_text(json.dumps({
-        "flash_ring": {"ok": True, "backend": "tpu"},
-        "chunked_cross_entropy": {"ok": True, "backend": "tpu"}}))
-    monkeypatch.setenv(kernel_select.MARKER_ENV, str(marker))
-    # kernel_validated sees the tpu-backed pass...
-    assert kernel_select.kernel_validated("flash_ring")
-    # ...but auto still refuses Pallas paths on the cpu backend.
-    assert kernel_select.resolve_auto("flash_ring",
-                                      pallas_impl="flash") == "xla"
-    assert ring_attention.resolve_ring_impl("auto") == "xla"
+def test_auto_resolves_from_backend_and_shape(monkeypatch):
+    assert cl.resolve_xent_impl("auto", 128) == "xla"
+    assert ring_attention.resolve_ring_impl("auto", 1024) == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert cl.resolve_xent_impl("auto", 128) == "pallas"
+    # Lane-misaligned model dim: the kernel's blocks cannot tile it.
+    assert cl.resolve_xent_impl("auto", 96) == "xla"
+    assert ring_attention.resolve_ring_impl("auto", 1024) == "flash"
+    # A shard length the flash blocks cannot tile stays on xla.
+    assert ring_attention.resolve_ring_impl("auto", 100) == "xla"
 
 
-def test_cpu_backed_marker_does_not_validate(tmp_path, monkeypatch):
-    marker = tmp_path / "KERNEL_VALIDATION.json"
-    marker.write_text(json.dumps({
-        "flash_ring": {"ok": True, "backend": "cpu"}}))
-    monkeypatch.setenv(kernel_select.MARKER_ENV, str(marker))
-    assert not kernel_select.kernel_validated("flash_ring")
-
-
-def test_ring_impl_env_override_and_priority(monkeypatch):
-    monkeypatch.setenv("SHIPYARD_RING_IMPL", "flash")
-    assert ring_attention.resolve_ring_impl("auto") == "flash"
-    # Explicit impl beats the env var.
+def test_explicit_impls_pass_through_and_unknown_fails():
+    assert ring_attention.resolve_ring_impl("pallas_dma") == \
+        "pallas_dma"
     assert ring_attention.resolve_ring_impl("xla") == "xla"
-    monkeypatch.setenv("SHIPYARD_RING_IMPL", "bogus")
     with pytest.raises(ValueError):
-        ring_attention.resolve_ring_impl("auto")
-
-
-def test_missing_marker_means_not_validated(monkeypatch, tmp_path):
-    monkeypatch.setenv(kernel_select.MARKER_ENV,
-                       str(tmp_path / "absent.json"))
-    assert kernel_select.kernel_validation() == {}
-    assert not kernel_select.kernel_validated("flash_ring")
+        ring_attention.resolve_ring_impl("bogus")
+    with pytest.raises(ValueError):
+        cl.resolve_xent_impl("bogus", 128)
 
 
 # -- silicon-proof pipeline dry run ---------------------------------
@@ -156,15 +138,15 @@ def test_silicon_proof_dry_run_writes_full_skeleton(tmp_path):
         (tmp_path / "SILICON_PROOF.json").read_text())
     assert report["dry_run"] is True
     names = [p["phase"] for p in report["phases"]]
-    assert names == ["probe", "kernel_checks", "flash_flip",
+    assert names == ["kernel_checks",
                      "ring_collectives", "tuning_ab", "final_bench",
                      "serving_speculative", "checkpoint_overhead",
                      "goodput", "compile_warm", "chaos_drill"]
     assert all(p["status"] == "dry_run" for p in report["phases"])
     # The ring-collectives kernel phase's skeleton names every metric
-    # and carries the explicit unreachable marker benchgen renders
+    # and carries the explicit not-measured marker benchgen renders
     # (claims are labeled, not implied).
-    ring = report["phases"][3]
+    ring = report["phases"][1]
     assert "bench.py" in ring["command"]
     assert "ring_collectives" in ring["command"]
     assert "dry-run skeleton" in ring["note"]
@@ -173,7 +155,7 @@ def test_silicon_proof_dry_run_writes_full_skeleton(tmp_path):
         "best_all_gather_gbps", "best_reduce_scatter_gbps"}
     # The speculative serving phase's skeleton names every metric it
     # will emit, for both KV layouts.
-    spec = report["phases"][6]
+    spec = report["phases"][4]
     assert "bench.py" in spec["command"]
     assert "serving_speculative" in spec["command"]
     for variant in ("dense", "paged"):
@@ -182,14 +164,14 @@ def test_silicon_proof_dry_run_writes_full_skeleton(tmp_path):
             "acceptance_rate"}
     # The warm-start compilation phase's skeleton names every metric
     # benchgen binds to.
-    compile_warm = report["phases"][9]
+    compile_warm = report["phases"][7]
     assert "compile_warm" in compile_warm["command"]
     assert set(compile_warm["metrics"]) == {
         "cold_ms", "warm_ms", "speedup", "cache_hits",
         "aot_first_step_ms", "steady_step_ms"}
     # The chaos-drill phase's skeleton names the recovery invariants
     # benchgen binds to (docs/30-fault-tolerance.md).
-    chaos = report["phases"][10]
+    chaos = report["phases"][8]
     assert "chaos_drill.py" in chaos["command"]
     assert set(chaos["metrics"]) == {"determinism",
                                      "injections_applied",
@@ -198,7 +180,7 @@ def test_silicon_proof_dry_run_writes_full_skeleton(tmp_path):
         "tasks", "orphaned_gang_rows", "queue_depth", "retries",
         "backoff_seconds"}
     # The tuning plan must cover every profile with a runnable command.
-    plan = report["phases"][4]["plan"]
+    plan = report["phases"][2]["plan"]
     from batch_shipyard_tpu.parallel.tuning import PROFILES
     assert set(plan) == set(PROFILES)
     assert all("bench.py --quick" in cmd for cmd in plan.values())

@@ -37,7 +37,7 @@ def test_hierarchical_all_to_all_matches_transpose():
     (src <-> dst) transpose a flat all-to-all would, on a factored
     2 x 4 expert mesh."""
     import numpy as np
-    from batch_shipyard_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     n_out, n_in, d = 2, 4, 8
@@ -70,7 +70,7 @@ def test_hierarchical_all_to_all_roundtrip():
     """Applying the exchange twice returns the original blocks (the
     transpose is an involution) — the combine path of MoE dispatch."""
     import numpy as np
-    from batch_shipyard_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     n_out, n_in, d = 2, 4, 4

@@ -151,7 +151,7 @@ def test_manager_fails_fast_on_stockout(tmp_path, monkeypatch):
     """A dry zone (retry=other_zone) must fail the pool wait
     immediately — the zone is fixed by credentials, so waiting out
     max_wait_time_seconds cannot help (review follow-up: the old
-    marker list treated stockout as fatal; the taxonomy keeps it
+    marker list treated stockout as fatal; the classification keeps it
     non-fatal but the manager still fails fast on it)."""
     import time
 

@@ -80,3 +80,10 @@ def test_non_tpu_device_kind_maps_to_none():
     assert topology.generation_for_device_kind(
         "NVIDIA A100-SXM4-40GB") is None
     assert topology.peak_bf16_tflops_for_device_kind("cpu") is None
+
+
+def test_unknown_tpu_device_kind_is_an_error():
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        topology.generation_for_device_kind("TPU v5")
+    with pytest.raises(ValueError):
+        topology.peak_bf16_tflops_for_device_kind("TPU v9x")

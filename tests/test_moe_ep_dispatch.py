@@ -7,9 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from flax import linen as nn
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from batch_shipyard_tpu.utils.compat import shard_map
 
 from batch_shipyard_tpu.models import moe
 

@@ -523,61 +523,6 @@ def test_train_workloads_enable_the_compile_cache():
     assert not findings, _fail_lines(findings)
 
 
-def _tpu_checks_names():
-    """CHECKS keys from tools/tpu_checks.py, by AST (dict literal
-    keys plus CHECKS["..."] = ... assignments) — no import of the
-    TPU harness."""
-    path = PACKAGE.parent / "tools" / "tpu_checks.py"
-    tree = ast.parse(path.read_text(encoding="utf-8"),
-                     filename=str(path))
-    out = set()
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign):
-            for target in node.targets:
-                if isinstance(target, ast.Name) and \
-                        target.id == "CHECKS" and \
-                        isinstance(node.value, ast.Dict):
-                    out |= {k.value for k in node.value.keys
-                            if isinstance(k, ast.Constant)}
-                if isinstance(target, ast.Subscript) and \
-                        isinstance(target.value, ast.Name) and \
-                        target.value.id == "CHECKS" and \
-                        isinstance(target.slice, ast.Constant):
-                    out.add(target.slice.value)
-    return out
-
-
-def test_kernel_select_names_are_backed_by_tpu_checks():
-    """Every validation name the package consults for impl='auto'
-    dispatch (kernel_select.resolve_auto / kernel_validated) must be
-    a tools/tpu_checks.py CHECKS entry — a typo'd gate name would
-    keep a Pallas fast path off forever with no failing check to say
-    why (stays native: tpu_checks.py lives outside the analyzer's
-    package scope)."""
-    check_names = _tpu_checks_names()
-    assert check_names, "could not parse tpu_checks.CHECKS"
-    problems = []
-    for src in _CTX.python_files:
-        for node in ast.walk(src.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            func = node.func
-            name = (func.attr if isinstance(func, ast.Attribute)
-                    else func.id if isinstance(func, ast.Name)
-                    else None)
-            if name not in ("resolve_auto", "kernel_validated"):
-                continue
-            if node.args and isinstance(node.args[0], ast.Constant) \
-                    and isinstance(node.args[0].value, str):
-                check = node.args[0].value
-                if check not in check_names:
-                    problems.append(
-                        f"{src.rel}:{node.lineno}: kernel_select "
-                        f"gate {check!r} has no tools/tpu_checks.py "
-                        f"CHECKS entry")
-    assert not problems, "\n".join(problems)
-
-
 def test_benchgen_phase_and_workload_names_exist():
     """Every silicon-proof phase name tools/benchgen.py binds to
     (p.get("phase") == "X") must be record()-ed by

@@ -13,12 +13,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from batch_shipyard_tpu.ops import ring_attention, ring_collectives as rc
-from batch_shipyard_tpu.ops import kernel_select
 from batch_shipyard_tpu.parallel import mesh as mesh_mod
-from batch_shipyard_tpu.utils.compat import shard_map
 
 
 def _shards(ring, chunk, feat, seed=0):
@@ -117,48 +116,11 @@ def test_virtual_all_gather_non_contiguous_values():
 
 # ---------------- pallas_dma tier resolution --------------------------
 
-def test_resolve_ring_impl_accepts_pallas_dma(monkeypatch):
-    monkeypatch.setenv("SHIPYARD_RING_IMPL", "pallas_dma")
-    assert ring_attention.resolve_ring_impl("auto") == "pallas_dma"
-    # Explicit impl still beats the env var.
-    assert ring_attention.resolve_ring_impl("xla") == "xla"
-    monkeypatch.setenv("SHIPYARD_RING_IMPL", "bogus")
-    with pytest.raises(ValueError):
-        ring_attention.resolve_ring_impl("auto")
-
-
-def test_pallas_dma_auto_stays_off_on_cpu(tmp_path, monkeypatch):
-    """Even a tpu-backed ring_collectives pass does not flip auto on
-    a cpu backend — the gate is backend AND marker (kernel_select)."""
-    import json
-    marker = tmp_path / "KERNEL_VALIDATION.json"
-    marker.write_text(json.dumps({
-        "flash_ring": {"ok": True, "backend": "tpu"},
-        "ring_collectives": {"ok": True, "backend": "tpu"}}))
-    monkeypatch.setenv(kernel_select.MARKER_ENV, str(marker))
-    assert kernel_select.kernel_validated("ring_collectives")
-    assert ring_attention.resolve_ring_impl("auto") == "xla"
-
-
-def test_pallas_dma_auto_needs_both_markers(tmp_path, monkeypatch):
-    """On a TPU backend (simulated), auto climbs the tiers exactly as
-    far as the markers allow: nothing -> xla, flash_ring -> flash,
-    flash_ring + ring_collectives -> pallas_dma."""
-    import json
-    marker = tmp_path / "KERNEL_VALIDATION.json"
-    monkeypatch.setenv(kernel_select.MARKER_ENV, str(marker))
+def test_pallas_dma_is_reached_only_by_name(monkeypatch):
+    """auto never picks the remote-DMA tier — not on cpu, and not on
+    a (simulated) TPU backend; naming it passes through."""
+    assert ring_attention.resolve_ring_impl("pallas_dma") == \
+        "pallas_dma"
+    assert ring_attention.resolve_ring_impl("auto", 1024) == "xla"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    marker.write_text(json.dumps({}))
-    assert ring_attention.resolve_ring_impl("auto") == "xla"
-    marker.write_text(json.dumps({
-        "flash_ring": {"ok": True, "backend": "tpu"}}))
-    assert ring_attention.resolve_ring_impl("auto") == "flash"
-    marker.write_text(json.dumps({
-        "flash_ring": {"ok": True, "backend": "tpu"},
-        "ring_collectives": {"ok": True, "backend": "tpu"}}))
-    assert ring_attention.resolve_ring_impl("auto") == "pallas_dma"
-    # A ring_collectives pass WITHOUT the flash one must not skip a
-    # tier: the DMA path builds on the flash rotation kernels.
-    marker.write_text(json.dumps({
-        "ring_collectives": {"ok": True, "backend": "tpu"}}))
-    assert ring_attention.resolve_ring_impl("auto") == "xla"
+    assert ring_attention.resolve_ring_impl("auto", 1024) == "flash"

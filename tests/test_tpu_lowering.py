@@ -1,0 +1,282 @@
+"""Every Pallas kernel in ops/, lowered FOR THE TPU on this CPU box.
+
+Two layers, both without a chip:
+
+  * ``jax.export`` with ``platforms=['tpu']`` runs Pallas's TPU lowering
+    — where the block-shape class of refusal lives ("the last two
+    dimensions of your block shape [must be] divisible by 8 and 128 …
+    or equal to the respective dimensions of the overall array"). The
+    paged and dense-int8 decode kernels failed exactly here until their
+    blocks took all heads of a page.
+  * an ahead-of-time compile against a v5e topology description, which
+    runs libtpu's real compiler — Mosaic included — on the kernel:
+    VMEM limits, unsupported relayouts, tiling. Needs no device, only
+    the installed libtpu; skipped (visibly) where libtpu cannot
+    describe a topology.
+
+Shapes: the ones chip_smoke.py serves and trains at, and the ones
+tools/tpu_checks.py checks numerics at; forward and backward where
+there is one. What neither layer can say is whether the kernel's
+NUMBERS are right on the chip — that is tools/tpu_checks.py there.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import export
+
+from batch_shipyard_tpu.ops import attention as attn
+from batch_shipyard_tpu.ops import chunked_loss as cl
+from batch_shipyard_tpu.ops import decode_attention as dd
+from batch_shipyard_tpu.ops import fused_norm as fn
+from batch_shipyard_tpu.ops import paged_attention as pa
+from batch_shipyard_tpu.ops import quantization as qz
+from batch_shipyard_tpu.ops import ring_collectives as rc
+
+bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+def _sq_sum(fn_):
+    return lambda *a: jnp.sum(fn_(*a).astype(f32) ** 2)
+
+
+def _flash(causal):
+    return lambda q, k, v: attn.flash_attention(q, k, v, causal)
+
+
+def _paged(q, k, v, table, lengths, ks=None, vs=None):
+    return pa.paged_decode_attention_kernel(q, k, v, table, lengths,
+                                            k_scales=ks, v_scales=vs)
+
+
+def _xent(h, e, t):
+    return cl.chunked_softmax_xent(h, e, t, impl="pallas")
+
+
+def _fused(x, s, w):
+    return fn.rmsnorm_matmul(x, s, w, impl="pallas")
+
+
+def _paged_args(dtype, heads, page, int8=False, batch=8, depth=64,
+                max_blocks=8):
+    pages = batch * max_blocks + 1
+    pool = ((pages, page, heads, depth), i8 if int8 else dtype)
+    args = [((batch, 1, heads, depth), dtype), pool, pool,
+            ((batch, max_blocks), i32), ((batch,), i32)]
+    if int8:
+        args += [((pages, page, heads), f32)] * 2
+    return args
+
+
+def _dense_args(dtype, heads, t_len=512, batch=8, depth=64):
+    cache = ((batch, t_len, heads, depth), i8)
+    scales = ((batch, t_len, heads), f32)
+    return [((batch, 1, heads, depth), dtype), cache, cache, scales,
+            scales, ((batch,), i32)]
+
+
+def _qkv(shape, dtype):
+    return [(shape, dtype)] * 3
+
+
+# (id, fn, [(shape, dtype), ...]). "smoke" = chip_smoke.py's shapes
+# (bf16, 16 heads x 64, page 64, T 2048, d_model 1024, vocab 32000);
+# "checks" = tools/tpu_checks.py's.
+CASES = [
+    ("flash_fwd_smoke", _flash(True), _qkv((8, 2048, 16, 64), bf16)),
+    ("flash_bwd_smoke", jax.grad(_sq_sum(_flash(True)), (0, 1, 2)),
+     _qkv((8, 2048, 16, 64), bf16)),
+    ("flash_fwd_checks", _flash(True), _qkv((2, 1024, 4, 64), f32)),
+    ("flash_bwd_checks", jax.grad(_sq_sum(_flash(True)), (0, 1, 2)),
+     _qkv((2, 1024, 4, 64), f32)),
+    ("flash_fwd_noncausal_T128", _flash(False),
+     _qkv((2, 128, 4, 64), f32)),
+    ("flash_bwd_noncausal_T128",
+     jax.grad(_sq_sum(_flash(False)), (0, 1, 2)),
+     _qkv((2, 128, 4, 64), f32)),
+    ("paged_smoke", _paged, _paged_args(bf16, 16, 64)),
+    ("paged_int8_smoke", _paged, _paged_args(bf16, 16, 64, int8=True)),
+    ("paged_checks", _paged, _paged_args(f32, 4, 16)),
+    ("paged_int8_checks", _paged, _paged_args(f32, 4, 16, int8=True)),
+    ("dense_int8_smoke", dd.dense_decode_attention_kernel,
+     _dense_args(bf16, 16)),
+    ("dense_int8_checks", dd.dense_decode_attention_kernel,
+     _dense_args(f32, 4)),
+    ("fused_norm_smoke", _fused,
+     [((16384, 1024), bf16), ((1024,), f32), ((1024, 3072), bf16)]),
+    ("fused_norm_checks", _fused,
+     [((512, 1024), f32), ((1024,), f32), ((1024, 1536), f32)]),
+    ("int8_matmul_smoke", qz.quantized_linear,
+     [((16384, 1024), bf16), ((1024, 2816), bf16)]),
+    ("int8_matmul_checks", qz.quantized_linear,
+     [((256, 512), f32), ((512, 384), f32)]),
+    ("xent_fwd_smoke", _xent,
+     [((8, 2048, 1024), bf16), ((32000, 1024), f32),
+      ((8, 2048), i32)]),
+    ("xent_bwd_smoke", jax.grad(_xent, (0, 1)),
+     [((8, 2048, 1024), bf16), ((32000, 1024), f32),
+      ((8, 2048), i32)]),
+    ("xent_fwd_checks", _xent,
+     [((2, 256, 128), f32), ((1024, 128), f32), ((2, 256), i32)]),
+    ("xent_bwd_checks", jax.grad(_xent, (0, 1)),
+     [((2, 256, 128), f32), ((1024, 128), f32), ((2, 256), i32)]),
+    ("ring_all_gather_virtual", rc.ring_all_gather_virtual,
+     [((4, 128, 128), f32)]),
+    ("ring_reduce_scatter_virtual", rc.ring_reduce_scatter_virtual,
+     [((4, 512, 128), f32)]),
+]
+_IDS = [case[0] for case in CASES]
+
+
+@pytest.mark.parametrize("name,fn_,args", CASES, ids=_IDS)
+def test_pallas_tpu_lowering(name, fn_, args):
+    """Pallas's own TPU lowering accepts the kernel (block shapes,
+    primitives with a TPU rule) — and the result really is a Mosaic
+    call."""
+    abstract = [jax.ShapeDtypeStruct(shape, dtype)
+                for shape, dtype in args]
+    exported = export.export(jax.jit(fn_), platforms=["tpu"])(*abstract)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """Compile-only v5e devices from libtpu's topology description —
+    no chip is opened."""
+    from jax.experimental import topologies
+    settings = {"TPU_ACCELERATOR_TYPE": "v5litepod-4",
+                "TPU_WORKER_HOSTNAMES": "localhost",
+                "TPU_SKIP_MDS_QUERY": "1"}
+    saved = {key: os.environ.get(key) for key in settings}
+    os.environ.update({k: v for k, v in settings.items()
+                       if saved[k] is None})
+    try:
+        topology = topologies.get_topology_desc(
+            topology_name="v5e:2x2", platform="tpu")
+    except Exception as exc:  # noqa: BLE001 - libtpu/env dependent
+        pytest.skip(f"libtpu cannot describe a v5e topology here: "
+                    f"{exc}")
+    finally:
+        for key, value in saved.items():
+            if value is None:
+                os.environ.pop(key, None)
+    return topology.devices
+
+
+def _aot_compile(fn_, *abstract):
+    """Compile for the (compile-only) devices the abstract arguments'
+    shardings name."""
+    return jax.jit(fn_).trace(*abstract).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+def _compile_for(devices, fn_, args):
+    sharding = jax.sharding.SingleDeviceSharding(devices[0])
+    return _aot_compile(fn_, *[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in args])
+
+
+@pytest.mark.parametrize("name,fn_,args", CASES, ids=_IDS)
+def test_mosaic_compiles_for_v5e(v5e_devices, name, fn_, args):
+    """libtpu's compiler (Mosaic) takes the kernel for a v5e."""
+    compiled = _compile_for(v5e_devices, fn_, args)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mosaic_refusal_is_visible(v5e_devices):
+    """The compile-only path really runs Mosaic: a block that cannot
+    fit VMEM is refused, not waved through."""
+    from jax.experimental import pallas as pl
+
+    def too_big(x):
+        def kernel(x_ref, o_ref):
+            o_ref[...] = x_ref[...] * 2.0
+        return pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
+
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile_for(v5e_devices, too_big, [((4096, 4096), f32)])
+
+
+def test_remote_dma_ring_compiles_on_four_chips(v5e_devices):
+    """The multi-chip pallas_dma tier — ring all-gather /
+    reduce-scatter and the ring-attention KV permute, forward and
+    backward — compiles for the four-chip host. (Its remote DMAs have
+    no interpreter inside shard_map on CPU, so this is the only
+    tier-1 coverage they get.)"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from batch_shipyard_tpu.ops import ring_attention as ra
+    from batch_shipyard_tpu.parallel import mesh as mesh_mod
+
+    ring = len(v5e_devices)
+    mesh = mesh_mod.make_mesh(
+        mesh_mod.auto_axis_sizes(ring, sp=ring), devices=v5e_devices)
+
+    x = jax.ShapeDtypeStruct((ring * 128, 128), f32,
+                             sharding=NamedSharding(mesh, P("sp")))
+    _aot_compile(lambda x: rc.ring_all_gather(x, mesh, "sp"), x)
+    y = jax.ShapeDtypeStruct(
+        (ring, ring * 128, 128), f32,
+        sharding=NamedSharding(mesh, P("sp", None)))
+    _aot_compile(lambda y: rc.ring_reduce_scatter(y, mesh, "sp"), y)
+    q = jax.ShapeDtypeStruct(
+        (2, ring * 1024, 4, 64), bf16,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), "sp", "tp",
+                                       None)))
+
+    def loss(q, k, v):
+        return jnp.sum(ra.ring_attention(
+            q, k, v, mesh, impl="pallas_dma").astype(f32) ** 2)
+
+    text = _aot_compile(jax.grad(loss, (0, 1, 2)), q, q, q).as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("axes", [{}, {"fsdp": 2, "tp": 2}],
+                         ids=["dp4", "fsdp2_tp2"])
+def test_train_kernels_partition_on_four_chips(v5e_devices,
+                                               monkeypatch, axes):
+    """Inside a global-view jit XLA refuses to partition a Mosaic call
+    ("Mosaic kernels cannot be automatically partitioned"): the
+    attention and the chunked loss the trainer builds for a
+    multi-device mesh must carry their own shard_map. Compiles their
+    forward+backward for the four-chip host the way the train step
+    shards them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from batch_shipyard_tpu.parallel import mesh as mesh_mod
+    from batch_shipyard_tpu.parallel import train as train_mod
+
+    # Dispatch as on the chip: flash attention, Pallas loss.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = mesh_mod.make_mesh(
+        mesh_mod.auto_axis_sizes(len(v5e_devices), **axes),
+        devices=v5e_devices)
+    config = train_mod.make_transformer_config(mesh, n_heads=16,
+                                               d_head=64)
+    lm_loss = train_mod.sharded_lm_loss(mesh)
+
+    def objective(q, k, v, hidden, embedding, targets):
+        attended = config.attention_fn(q, k, v, causal=True)
+        return (jnp.sum(attended.astype(f32) ** 2) +
+                lm_loss(hidden, embedding, targets))
+
+    def on(spec, shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    batch = P(("dp", "fsdp"))
+    qkv = on(P(("dp", "fsdp"), None, "tp", None), (8, 2048, 16, 64),
+             bf16)
+    text = _aot_compile(
+        jax.grad(objective, (0, 1, 2, 3, 4)), qkv, qkv, qkv,
+        on(batch, (8, 2048, 1024), bf16), on(P(), (32000, 1024), f32),
+        on(batch, (8, 2048), i32)).as_text()
+    # Partitioned, not gathered: the flash call sees one device's
+    # share of batch x heads (8*16/4 = 32 rows), never all 128.
+    assert "bf16[32,2048,64]" in text
+    assert "bf16[128,2048,64]" not in text

@@ -1,12 +1,12 @@
 #!/usr/bin/env python3
 """Measured-numbers page generator: bench artifacts -> markdown.
 
-VERDICT r4 next #8: a numbers page that CANNOT rot — it is rendered
-from the JSON the bench pipeline actually produced (BENCH_r*.json,
-BENCH_LATEST.json, BENCH_DETAILS.json, SILICON_PROOF.json), never
-hand-written. tools/silicon_proof.py re-runs this after every
-successful bench so docs/26-benchmarks.md always shows the latest
-silicon truth, including the honest "accelerator unreachable" state.
+A numbers page that CANNOT rot — it is rendered from the JSON the
+bench pipeline actually produced (BENCH_r*.json, BENCH_LATEST.json,
+BENCH_DETAILS.json, SILICON_PROOF.json), never hand-written.
+tools/silicon_proof.py re-runs this after every successful bench so
+docs/26-benchmarks.md always shows the latest records, including an
+explicit "not measured" where a phase has only its dry-run skeleton.
 
 Usage: python tools/benchgen.py [--out docs/26-benchmarks.md]
 """
@@ -23,8 +23,8 @@ import sys
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 if str(REPO_ROOT) not in sys.path:
     sys.path.insert(0, str(REPO_ROOT))
-# Where the live bench artifacts (BENCH_DETAILS/LATEST, SILICON_PROOF,
-# KERNEL_VALIDATION) are read from; silicon_proof passes its --out-dir
+# Where the live bench artifacts (BENCH_DETAILS/LATEST, SILICON_PROOF)
+# are read from; silicon_proof passes its --out-dir
 # so a non-repo-root run still renders ITS fresh numbers. Round
 # history (BENCH_r*.json) always comes from the repo root.
 ARTIFACTS = REPO_ROOT
@@ -242,9 +242,8 @@ _RING_KEYS = (("mode", "mode (remote_dma = multi-chip ICI ring; "
 def _ring_collectives(out: list[str], data: dict) -> None:
     """Async-DMA ring collective kernels section
     (docs/31-pallas-kernels.md). Falls back to the silicon-proof
-    phase's skeleton metrics; when nothing was measured (the relay is
-    down), the section says so explicitly — claims are labeled, not
-    implied."""
+    phase's skeleton metrics; when nothing was measured the section
+    says so explicitly — claims are labeled, not implied."""
     skeleton_note = None
     if not isinstance(data, dict) or not data:
         proof = _load(ARTIFACTS / "SILICON_PROOF.json") or {}
@@ -265,8 +264,7 @@ def _ring_collectives(out: list[str], data: dict) -> None:
                "checks, never timings) "
                "([31-pallas-kernels.md](31-pallas-kernels.md)).\n")
     if skeleton_note or data.get("numeric_ok") is None:
-        out.append("**accelerator unreachable — dry-run skeleton** "
-                   "(no chip has answered since round 2; the values "
+        out.append("**not measured — dry-run skeleton** (the values "
                    "below are unmeasured placeholders, not claims).\n")
     out.append("| metric | value |")
     out.append("|---|---|")
@@ -733,17 +731,6 @@ def _silicon_proof(out: list[str]) -> None:
         out.append(f"| {phase.get('phase')} | "
                    f"{phase.get('status')} |")
     out.append("")
-    marker = _load(ARTIFACTS / "KERNEL_VALIDATION.json")
-    if marker:
-        out.append("Kernel validation marker "
-                   "(gates `impl='auto'` Pallas dispatch):\n")
-        out.append("| kernel | on-chip pass |")
-        out.append("|---|---|")
-        for name, record in sorted(marker.items()):
-            ok = (record.get("ok") and
-                  record.get("backend") == "tpu")
-            out.append(f"| {name} | {'yes' if ok else 'no'} |")
-        out.append("")
 
 
 def render() -> str:
@@ -841,8 +828,8 @@ def main(argv=None) -> int:
                         default=str(REPO_ROOT /
                                     "docs/26-benchmarks.md"))
     parser.add_argument("--artifacts-dir", default=str(REPO_ROOT),
-                        help="where BENCH_DETAILS/LATEST, "
-                        "SILICON_PROOF and KERNEL_VALIDATION live")
+                        help="where BENCH_DETAILS/LATEST and "
+                        "SILICON_PROOF live")
     args = parser.parse_args(argv)
     ARTIFACTS = pathlib.Path(args.artifacts_dir)
     content = render()
