@@ -1,0 +1,111 @@
+"""run.py end to end at a tiny size on the CPU for each cell
+(--rehearse-tiny): the last line is one JSON object with
+exactly the contract's keys and no metric at all (a CPU run is never
+printed under a device metric's name); without a TPU run.py exits
+non-zero and prints no result; so it does where only the benchmark's
+own files are present. And one run with the timed path broken
+underneath comes out ``correct: false``."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from benchmark import spec
+
+RUN = str(spec.ROOT / "benchmark" / "run.py")
+KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def _run(args, cwd=spec.ROOT, devices=1, script=RUN):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                         f"{devices}")
+    env.pop("BENCH_RUN", None)
+    env.pop("PYTHONPATH", None)
+    return subprocess.run([sys.executable, script, *args], cwd=cwd,
+                          env=env, capture_output=True, text=True,
+                          timeout=900)
+
+
+@pytest.mark.parametrize("cell,devices,trace", [
+    ("baichuan7b.chat-online", 1, 1),
+    ("baichuan7b.batch-offline", 1, 0),
+])
+def test_rehearsal_runs_end_to_end(cell, devices, trace):
+    done = _run(["--workload", cell, "--seed", str(2**31 + 77),
+                 "--seconds", "3", "--trace", str(trace),
+                 "--rehearse-tiny"], devices=devices)
+    assert done.returncode == 0, done.stderr[-3000:]
+    lines = [l for l in done.stdout.splitlines() if l.strip()]
+    result = json.loads(lines[-1])
+    assert set(result) == KEYS          # no breakdown off the chip
+    assert result["correct"] is True, done.stdout[-3000:]
+    assert result["attempted"] > 0 and result["failed"] == 0
+    # a CPU run prints no metric, and no device time
+    assert result["metrics"] == {}
+    assert set(result["device"]) == {"platform", "kind", "count",
+                                     "memory_peak_bytes"}
+    assert result["device"]["platform"] == "cpu"
+    assert result["device"]["count"] == devices
+    assert any(l.startswith("check ") and "(limit" in l
+               for l in lines)       # each number beside its limit
+
+
+def test_without_a_tpu_it_exits_non_zero_and_prints_no_result():
+    done = _run(["--workload", "baichuan7b.chat-online", "--seed", "1",
+                 "--seconds", "3", "--trace", "0"])
+    assert done.returncode != 0
+    assert "no accelerator" in done.stderr
+    assert not any(l.startswith("{") for l in done.stdout.splitlines())
+
+
+def test_with_only_its_own_files_it_exits_non_zero(tmp_path):
+    bench = spec.load_benchmark()
+    shutil.copy(spec.ROOT / "BENCHMARK.json", tmp_path)
+    for path in bench["paths"]:
+        shutil.copytree(spec.ROOT / path, tmp_path / path,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    done = _run(["--workload", "baichuan7b.chat-online", "--seed", "1",
+                 "--seconds", "3", "--trace", "0", "--rehearse-tiny"],
+                cwd=tmp_path,
+                script=str(tmp_path / "benchmark" / "run.py"))
+    assert done.returncode != 0
+    assert not any(l.startswith("{") for l in done.stdout.splitlines())
+
+
+def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capsys):
+    """The rest of a run driven in-process (the look for a chip
+    skipped by --rehearse-tiny), with the decode step altering every
+    token it produces."""
+    import importlib.util
+    import jax.numpy as jnp
+    from batch_shipyard_tpu.models import serving
+
+    sound = serving._decode_step
+
+    def altered(model, sampling, params, cache, tokens, positions,
+                active, key):
+        cache, _next, positions, tok = sound(
+            model, sampling, params, cache, tokens, positions, active,
+            key)
+        tok = jnp.where(active, (tok + 1) % model.config.vocab_size,
+                        tok)
+        return cache, tok[:, None], positions, tok
+
+    monkeypatch.setattr(serving, "_decode_step", altered)
+    module_spec = importlib.util.spec_from_file_location("bench_run", RUN)
+    run = importlib.util.module_from_spec(module_spec)
+    module_spec.loader.exec_module(run)
+    code = run.main(["--workload", "baichuan7b.batch-offline",
+                     "--seed", "9", "--seconds", "2", "--trace", "0",
+                     "--rehearse-tiny"])
+    assert code == 0
+    lines = [l for l in capsys.readouterr().out.splitlines()
+             if l.strip()]
+    result = json.loads(lines[-1])
+    assert result["correct"] is False
+    assert any("FAILED" in l for l in lines if l.startswith("check "))
