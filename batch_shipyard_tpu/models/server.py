@@ -253,6 +253,10 @@ class ServingFrontEnd:
         engine.on_token = self._on_token
         engine.on_admit = self._on_admit
         engine.on_shed = self._on_shed
+        # The engine's serve_step rows are head-sampled like the
+        # request spans below: the head starts over with this front
+        # end.
+        engine.traced_steps = 0
         self._submit_q: "queue.Queue[_Pending]" = queue.Queue()
         self._inflight: dict[str, _Pending] = {}
         self._inflight_lock = threading.Lock()
@@ -544,6 +548,9 @@ class ServingFrontEnd:
         self._httpd.shutdown()
         self._httpd.server_close()
         self._engine_thread.join(timeout=10.0)
+        # Spans still buffered (trace/spans.py) reach the file before
+        # whoever shut this down reads it.
+        trace_spans.flush()
 
     def kill(self) -> None:
         """The SIGKILL failure shape (chaos drills): stop the engine,
@@ -898,6 +905,20 @@ class ServingFrontEnd:
             "draining": 1.0 if stats["draining"] else 0.0,
             "drain_rejections_total": stats["drain_rejections"],
         })
+        engine = stats["engine"]
+        lines.extend(prometheus_lines("shipyard_serving", {
+            "slots_active": engine["slots_active"],
+            "queue_depth": engine["queued"],
+            "kv_pages_in_use": engine.get("kv_pages_in_use"),
+            "kv_pages_total": engine.get("kv_pages_total"),
+            "steps_total": engine["steps"],
+            "compiles_total": engine["compiles"],
+        }))
+        for phase, seconds in engine["phase_seconds"].items():
+            lines.extend(prometheus_lines(
+                "shipyard_serving",
+                {"step_phase_seconds_total": seconds},
+                labels={"phase": phase}))
         for metric in ("ttft_ms", "tpot_ms"):
             for pct, value in stats[metric].items():
                 lines.extend(prometheus_lines(
@@ -1029,6 +1050,11 @@ class ServingFrontEnd:
             # and the engine's queued+active total.
             "inflight": inflight,
             "engine_backlog": self.engine.pending(),
+            # The engine from inside: slots and KV pages in use now
+            # (size --kv-num-pages by pages-in-use against
+            # slots-active, docs/15-serving.md), and where a step's
+            # time has gone since the engine was built.
+            "engine": self._engine_stats(),
             # Drain ladder visibility: the router's probe reads
             # "draining" to distinguish cooperative shutdown from
             # failure.
@@ -1066,6 +1092,23 @@ class ServingFrontEnd:
         if prefix is not None:
             out["prefix_cache"] = prefix
         return out
+
+    def _engine_stats(self) -> dict:
+        occupancy = self.engine.occupancy()
+        steps = self.engine.step_stats()
+        count = steps["steps"]
+        per_step = 1e3 / count if count else 0.0
+        return {
+            **occupancy,
+            "steps": count,
+            "step_ms_mean": steps["step_seconds"] * per_step,
+            "phase_ms_mean": {
+                name: seconds * per_step
+                for name, seconds in steps["phase_seconds"].items()},
+            "phase_seconds": steps["phase_seconds"],
+            "compiles": steps["compiles"],
+            "compile_seconds": steps["compile_seconds"],
+        }
 
     # --------------------------- engine thread -------------------------
 
@@ -1125,7 +1168,9 @@ class ServingFrontEnd:
                 try:
                     self._submit(self._submit_q.get(timeout=0.2))
                 except queue.Empty:
-                    pass
+                    # Idle: the last rows of a burst would otherwise
+                    # sit in the recorder's buffer until the next one.
+                    trace_spans.flush()
             while True:
                 try:
                     self._submit(self._submit_q.get_nowait())

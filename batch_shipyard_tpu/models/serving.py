@@ -25,6 +25,8 @@ import contextlib
 import dataclasses
 import functools
 import hashlib
+import itertools
+import random
 import time
 import uuid
 from typing import Callable, Optional
@@ -35,6 +37,13 @@ import numpy as np
 
 from batch_shipyard_tpu.models import inference as inf
 from batch_shipyard_tpu.models import transformer as tfm
+from batch_shipyard_tpu.trace import spans as trace_spans
+
+# The leaf phases of one engine step, in the order a step runs them
+# (docs/32-tracing.md): each is a ``serve:<phase>`` annotation in a
+# profiler trace and a ``<phase>_ms`` attr of the step's row.
+STEP_PHASES = ("admit", "prefill", "slot_update", "grow_pages",
+               "dispatch", "readback", "emit")
 
 
 @functools.partial(jax.jit, static_argnames=("model", "sampling"))
@@ -647,6 +656,22 @@ class ContinuousBatcher:
         self._step_ms: Optional[float] = None
         self._timed_buckets: set = set()
         self._step_samples = 0
+        # Step tracing: per-phase seconds and step counts are always
+        # on (a few float adds a step); a serve_step row is written
+        # only while the process-local span recorder is switched on
+        # ($SHIPYARD_TRACE_FILE), head-sampled by traced_steps, which
+        # a front end resets when it takes the engine over.
+        self._phases = trace_spans.PhaseTimer("serve:", STEP_PHASES)
+        self._compiles = trace_spans.compile_counter()
+        self.steps_total = 0
+        self.step_seconds_total = 0.0
+        self.traced_steps = 0
+        # Row ids count up from a random start: a uuid4 a step costs
+        # a getrandom() call, a third of a millisecond where that is
+        # slow.
+        self._row_ids = itertools.count(random.getrandbits(32))
+        self._admitted: list[dict] = []
+        self._step_tokens = 0
         self.model = tfm.TransformerLM(self.config)
         self.device = device
         on_device = (jax.default_device(device) if device is not None
@@ -962,52 +987,129 @@ class ContinuousBatcher:
                 return True
         return False
 
+    # Step-row head-sampling, the request spans' rule
+    # (server.ServingFrontEnd._SPAN_HEAD): the first _STEP_HEAD steps
+    # a front end drives are recorded in full (a 51 s window with its
+    # lead-in and drain, at 20 steps a second, four times over), then
+    # 1-in-_STEP_SAMPLE_EVERY. The cumulative counters see every step.
+    _STEP_HEAD = 4096
+    _STEP_SAMPLE_EVERY = 16
+
     def step(self) -> list[tuple[str, list[int]]]:
         """Admit queued requests into free slots, decode for every
         active slot — one token per step, or a gamma-token
         draft/verify block per slot when speculative decoding is
-        configured — and emit finished requests."""
+        configured — and emit finished requests. Every part of the
+        step runs inside one of STEP_PHASES; with the span recorder
+        on, a step that did anything also writes a serve_step row."""
+        phases = self._phases
+        phases.reset()
+        self._admitted.clear()
+        self._step_tokens = 0
+        traced = trace_spans.local_spans_path() is not None
+        wall0, t0 = time.time(), time.monotonic()
+        before = compiles0 = None
+        if traced and (self.traced_steps < self._STEP_HEAD or
+                       (self.traced_steps + 1)
+                       % self._STEP_SAMPLE_EVERY == 0):
+            before = self.occupancy()
+            compiles0 = self._compiles.read()
+        emitted = self._step()
+        seconds = time.monotonic() - t0
+        if not self._admitted and "dispatch" not in phases.step:
+            return emitted      # nothing to seat, nothing to decode
+        self.steps_total += 1
+        self.step_seconds_total += seconds
+        if traced:
+            self.traced_steps += 1
+        if before is not None:
+            self._record_step_row(wall0, t0, seconds, before,
+                                  compiles0, len(emitted))
+        return emitted
+
+    def _record_step_row(self, wall0: float, t0: float,
+                         seconds: float, before: dict,
+                         compiles0: tuple, finished: int) -> None:
+        attrs = {"mono_start": t0}
+        for name in STEP_PHASES:
+            attrs[f"{name}_ms"] = self._phases.step.get(name,
+                                                        0.0) * 1e3
+        attrs["prefills"] = len(self._admitted)
+        attrs["prefill_tokens"] = sum(a["tokens"]
+                                      for a in self._admitted)
+        attrs["admitted"] = list(self._admitted)
+        attrs["tokens_emitted"] = self._step_tokens
+        attrs["finished"] = finished
+        attrs.update(before)
+        count, compile_s = self._compiles.read()
+        if count > compiles0[0]:
+            attrs["compiles"] = count - compiles0[0]
+            attrs["compile_ms"] = (compile_s - compiles0[1]) * 1e3
+        trace_spans.record(
+            trace_spans.SPAN_SERVE_STEP, wall0, wall0 + seconds,
+            span_id=f"{next(self._row_ids) & 0xffffffff:08x}", **attrs)
+
+    def _step(self) -> list[tuple[str, list[int]]]:
+        phases = self._phases
         self._admit()
         # Slots whose prefill-sampled first token already satisfied the
         # request (max_new_tokens == 1 or immediate eos) emit without a
         # decode step.
         emitted: list[tuple[str, list[int]]] = []
-        for i, slot in enumerate(self._slots):
-            req = slot.request
-            if req is None or not slot.generated:
-                continue
-            last = slot.generated[-1]
-            if (len(slot.generated) >= req.max_new_tokens or
-                    (req.eos_id is not None and last == req.eos_id)):
-                emitted.append((req.request_id, list(slot.generated)))
-                self._free_slot(i)
+        with phases("emit"):
+            for i, slot in enumerate(self._slots):
+                req = slot.request
+                if req is None or not slot.generated:
+                    continue
+                last = slot.generated[-1]
+                if (len(slot.generated) >= req.max_new_tokens or
+                        (req.eos_id is not None and
+                         last == req.eos_id)):
+                    emitted.append((req.request_id,
+                                    list(slot.generated)))
+                    self._free_slot(i)
         if not any(s.request is not None for s in self._slots):
             return emitted
         if self.speculative is not None:
             return emitted + self._step_speculative()
         if self.paged:
-            self._grow_pages()
+            with phases("grow_pages"):
+                self._grow_pages()
         t0 = time.monotonic()
-        self._key, step_key = jax.random.split(self._key)
-        self.cache, self._tokens, self._positions, next_tok = \
-            self._decode_step(self.params, self.cache, self._tokens,
-                              self._positions, self._active, step_key)
-        next_host = np.asarray(next_tok)
+        with phases("dispatch"):
+            self._key, step_key = jax.random.split(self._key)
+            self.cache, self._tokens, self._positions, next_tok = \
+                self._decode_step(self.params, self.cache,
+                                  self._tokens, self._positions,
+                                  self._active, step_key)
+        with phases("readback"):
+            next_host = np.asarray(next_tok)
         self._record_step_time(t0)
-        for i, slot in enumerate(self._slots):
-            req = slot.request
-            if req is None:
-                continue
-            token = int(next_host[i])
-            slot.generated.append(token)
-            if self.on_token is not None:
-                self.on_token(req.request_id, token,
-                              len(slot.generated) - 1)
-            done = (len(slot.generated) >= req.max_new_tokens or
-                    (req.eos_id is not None and token == req.eos_id))
-            if done:
-                emitted.append((req.request_id, list(slot.generated)))
-                self._free_slot(i)
+        with phases("emit"):
+            for i, slot in enumerate(self._slots):
+                req = slot.request
+                if req is None:
+                    continue
+                token = int(next_host[i])
+                slot.generated.append(token)
+                self._step_tokens += 1
+                if self.on_token is not None:
+                    self.on_token(req.request_id, token,
+                                  len(slot.generated) - 1)
+                done = (len(slot.generated) >= req.max_new_tokens or
+                        (req.eos_id is not None and
+                         token == req.eos_id))
+                if done:
+                    emitted.append((req.request_id,
+                                    list(slot.generated)))
+                    self._free_slot(i)
+            # The step's device arrays die here, inside the phase:
+            # their destructor releases the GIL, and that is when the
+            # stream-writer threads on_token just woke take their
+            # turn (a millisecond or two with a dozen streams). It is
+            # emit's cost, so it is counted here and not after every
+            # phase has ended.
+            del next_tok, step_key
         return emitted
 
     def _step_speculative(self) -> list[tuple[str, list[int]]]:
@@ -1016,45 +1118,89 @@ class ContinuousBatcher:
         per step, so the host bookkeeping below is variable-stride —
         each slot appends its own 1..gamma+1 committed tokens, with
         per-token eos/max_new checks so a slot can stop mid-block."""
+        phases = self._phases
         if self.paged:
-            self._grow_pages(span=self.gamma)
+            with phases("grow_pages"):
+                self._grow_pages(span=self.gamma)
         t0 = time.monotonic()
-        (self.cache, self._draft_cache, self._tokens, self._positions,
-         block, a_slot) = self._spec_step(
-            self.params, self._draft_params, self.cache,
-            self._draft_cache, self._tokens, self._positions,
-            self._active)
-        block_host = np.asarray(block)
-        self._record_step_time(t0)
-        a_host = np.asarray(a_slot)
+        with phases("dispatch"):
+            (self.cache, self._draft_cache, self._tokens,
+             self._positions, block, a_slot) = self._spec_step(
+                self.params, self._draft_params, self.cache,
+                self._draft_cache, self._tokens, self._positions,
+                self._active)
+        with phases("readback"):
+            block_host = np.asarray(block)
+            self._record_step_time(t0)
+            a_host = np.asarray(a_slot)
         emitted: list[tuple[str, list[int]]] = []
         n_active = 0
-        for i, slot in enumerate(self._slots):
-            req = slot.request
-            if req is None:
-                continue
-            n_active += 1
-            accepted = int(a_host[i])
-            self.spec_accepted += accepted
-            for j in range(accepted + 1):
-                token = int(block_host[i, j])
-                slot.generated.append(token)
-                if self.on_token is not None:
-                    self.on_token(req.request_id, token,
-                                  len(slot.generated) - 1)
-                if (len(slot.generated) >= req.max_new_tokens or
-                        (req.eos_id is not None and
-                         token == req.eos_id)):
-                    # Stopped mid-block: the remaining committed
-                    # tokens are discarded (their cache rows recycle
-                    # with the slot).
-                    emitted.append((req.request_id,
-                                    list(slot.generated)))
-                    self._free_slot(i)
-                    break
+        with phases("emit"):
+            for i, slot in enumerate(self._slots):
+                req = slot.request
+                if req is None:
+                    continue
+                n_active += 1
+                accepted = int(a_host[i])
+                self.spec_accepted += accepted
+                for j in range(accepted + 1):
+                    token = int(block_host[i, j])
+                    slot.generated.append(token)
+                    self._step_tokens += 1
+                    if self.on_token is not None:
+                        self.on_token(req.request_id, token,
+                                      len(slot.generated) - 1)
+                    if (len(slot.generated) >= req.max_new_tokens or
+                            (req.eos_id is not None and
+                             token == req.eos_id)):
+                        # Stopped mid-block: the remaining committed
+                        # tokens are discarded (their cache rows
+                        # recycle with the slot).
+                        emitted.append((req.request_id,
+                                        list(slot.generated)))
+                        self._free_slot(i)
+                        break
+            del block, a_slot       # as in _step: the writers' turn
         self.spec_rounds += 1
         self.spec_proposed += self.gamma * n_active
         return emitted
+
+    def occupancy(self) -> dict:
+        """The engine's state at this moment, read-only: what a
+        serve_step row records as its step begins and what /stats
+        reports. Pages are counted once however many slots read them.
+        Safe to call from another thread than the stepping one (the
+        snapshot may then straddle a step). The page keys are absent
+        from a dense engine."""
+        active = tokens = 0
+        for slot in self._slots:
+            req = slot.request
+            if req is not None:
+                active += 1
+                tokens += len(req.prompt) + len(slot.generated)
+        out = {"slots_active": active, "slots_total": self.num_slots,
+               "queued": len(self._queue), "live_tokens": tokens}
+        if self.paged:
+            out["kv_pages_in_use"] = len(
+                {page for held in self._slot_pages + self._slot_shared
+                 for page in held})
+            out["kv_pages_free"] = len(self._free_pages)
+            out["kv_pages_lru"] = len(self._lru)
+            out["kv_pages_total"] = self._total_pages
+            out["prefix_index_pages"] = len(self._page_ref)
+        return out
+
+    def step_stats(self) -> dict:
+        """Cumulative step counters since the engine was built: steps
+        that admitted or decoded, their wall seconds, the seconds of
+        each phase, and the process's compile count (programs built
+        or loaded from the persistent cache, and their seconds)."""
+        compiles, compile_seconds = self._compiles.read()
+        return {"steps": self.steps_total,
+                "step_seconds": self.step_seconds_total,
+                "phase_seconds": dict(self._phases.total),
+                "compiles": compiles,
+                "compile_seconds": compile_seconds}
 
     def spec_stats(self) -> Optional[dict]:
         """Speculative-decode counters, or None when no draft model
@@ -1477,145 +1623,168 @@ class ContinuousBatcher:
             # notice lands — active slots finish, the queue was
             # already evicted by drain().
             return
+        phases = self._phases
         now = time.monotonic()
-        self._shed_expired(now)
+        with phases("admit"):
+            self._shed_expired(now)
         for i, slot in enumerate(self._slots):
             if slot.request is not None or not self._queue:
                 continue
-            entry = self._queue[0]
-            req = entry.request
-            if self._should_defer(entry, now):
-                # Head-of-line hold: admitting now would stall active
-                # decodes past their TPOT headroom.
-                self.slo_deferrals += 1
-                break
-            # Resumed (preempted) requests re-prefill prompt + what
-            # they had already generated, in one batched pass.
-            tokens = req.prompt + entry.resumed
-            bucket = self._bucket_length(len(tokens))
-            padded = tokens + [0] * (bucket - len(tokens))
-            prompt = self._put(np.asarray([padded], np.int32))
-            t0 = time.monotonic()
-            timed_key = ("dense", bucket)
-            timed_tokens = bucket
-            if self.paged:
-                blocks_needed = -(-len(tokens) // self.page_size)
-                remaining = req.max_new_tokens - len(entry.resumed)
-                worst = -(-(len(tokens) + remaining)
-                          // self.page_size)
+            # Per request: "admit" is the host's work to seat it
+            # (deferral, page keys, prefix match, allocation, the
+            # table row, the puts), "prefill" runs from the dispatch
+            # of the prefill program to its first token on the host,
+            # "slot_update" writes the slot's device-side state.
+            with phases("admit"):
+                entry = self._queue[0]
+                req = entry.request
+                if self._should_defer(entry, now):
+                    # Head-of-line hold: admitting now would stall
+                    # active decodes past their TPOT headroom.
+                    self.slo_deferrals += 1
+                    break
+                # Resumed (preempted) requests re-prefill prompt +
+                # what they had already generated, in one batched
+                # pass.
+                tokens = req.prompt + entry.resumed
+                bucket = self._bucket_length(len(tokens))
+                padded = tokens + [0] * (bucket - len(tokens))
+                prompt = self._put(np.asarray([padded], np.int32))
+                t0 = time.monotonic()
+                timed_key = ("dense", bucket)
+                timed_tokens = bucket
+                prefilled = len(tokens)
+                prefill, prefill_args = self._prefill, (
+                    i, prompt, len(tokens))
                 keys: list[bytes] = []
-                matched: list[int] = []
-                if self.prefix_cache:
-                    keys = self._page_keys(tokens)
-                    matched = self._match_prefix(keys, len(tokens))
-                m = len(matched)
-                lru_m = sum(1 for pid in matched
-                            if self._page_ref[pid] == 0)
-                if self.overcommit:
-                    # Take only the prompt's pages (+1 block of
-                    # decode headroom against immediate re-thrash);
-                    # exhaustion during decode preempts. Matched
-                    # pages cost nothing fresh; pinning an
-                    # LRU-parked page consumes one evictable unit.
-                    want = min(blocks_needed - m +
-                               (1 if remaining else 0), worst - m)
-                    if (len(self._free_pages) + len(self._lru)
-                            - lru_m) < want:
-                        break
-                else:
-                    if self._avail_pages < (worst - m) + lru_m:
-                        # Not enough budget for this request's worst
-                        # case: wait for frees rather than risking a
-                        # mid-decode exhaustion deadlock between
-                        # half-grown slots. The shared prefix
-                        # discounts the budget — reuse IS admission
-                        # headroom.
-                        break
-                    self._avail_pages -= worst - m
-                    self._slot_reserved[i] = worst - m
+                m = 0
+                if self.paged:
+                    blocks_needed = -(-len(tokens) // self.page_size)
+                    remaining = req.max_new_tokens - len(entry.resumed)
+                    worst = -(-(len(tokens) + remaining)
+                              // self.page_size)
+                    matched: list[int] = []
+                    if self.prefix_cache:
+                        keys = self._page_keys(tokens)
+                        matched = self._match_prefix(keys, len(tokens))
+                    m = len(matched)
+                    lru_m = sum(1 for pid in matched
+                                if self._page_ref[pid] == 0)
+                    if self.overcommit:
+                        # Take only the prompt's pages (+1 block of
+                        # decode headroom against immediate
+                        # re-thrash); exhaustion during decode
+                        # preempts. Matched pages cost nothing fresh;
+                        # pinning an LRU-parked page consumes one
+                        # evictable unit.
+                        want = min(blocks_needed - m +
+                                   (1 if remaining else 0), worst - m)
+                        if (len(self._free_pages) + len(self._lru)
+                                - lru_m) < want:
+                            break
+                    else:
+                        if self._avail_pages < (worst - m) + lru_m:
+                            # Not enough budget for this request's
+                            # worst case: wait for frees rather than
+                            # risking a mid-decode exhaustion deadlock
+                            # between half-grown slots. The shared
+                            # prefix discounts the budget — reuse IS
+                            # admission headroom.
+                            break
+                        self._avail_pages -= worst - m
+                        self._slot_reserved[i] = worst - m
                 self._queue.pop(0)
                 if self.on_admit is not None:
                     self.on_admit(req.request_id)
-                # Pin the matched chain: shared pages are immutable
-                # (decode writes land strictly past the last full
-                # prompt page) and never evictable while referenced.
-                for pid in matched:
-                    if self._page_ref[pid] == 0:
-                        del self._lru[pid]
-                        self._avail_pages -= 1
-                    self._page_ref[pid] += 1
-                self._slot_shared[i] = list(matched)
-                if self.prefix_cache:
-                    self.prefix_lookups += 1
-                    self.prefix_hit_pages += m
-                    self.prefix_hit_tokens += m * self.page_size
-                    self.prefix_total_tokens += len(tokens)
-                fresh = [self._alloc_page()
-                         for _ in range(blocks_needed - m)]
-                self._slot_pages[i] = fresh
-                row = np.full((self.max_blocks,), self._scratch_page,
-                              np.int32)
-                row[:m] = matched
-                row[m:blocks_needed] = fresh
-                self._table[i] = row
-                if m:
-                    prefix_len = m * self.page_size
-                    suffix_tokens = tokens[prefix_len:]
-                    sbucket = self._bucket_length(len(suffix_tokens))
-                    timed_key = ("shared", sbucket)
-                    timed_tokens = sbucket
-                    suffix = self._put(np.asarray(
-                        [suffix_tokens +
-                         [0] * (sbucket - len(suffix_tokens))],
-                        np.int32))
-                    prefix_ids = np.full(
-                        (self.max_decode_len // self.page_size,),
-                        self._scratch_page, np.int32)
-                    prefix_ids[:m] = matched
-                    suffix_row = np.full((self.max_blocks,),
-                                         self._scratch_page,
-                                         np.int32)
-                    suffix_row[:blocks_needed - m] = fresh
-                    self.cache, last_logits = self._prefill_shared(
-                        self.params, self.cache, i, suffix,
-                        self._put(prefix_ids), self._put(row),
-                        self._put(suffix_row), prefix_len,
-                        len(tokens))
-                else:
-                    timed_key = ("paged", bucket)
-                    self.cache, last_logits = self._prefill_paged(
-                        self.params, self.cache, i, prompt,
-                        self._put(row), len(tokens))
+                if self.paged:
+                    # Pin the matched chain: shared pages are
+                    # immutable (decode writes land strictly past the
+                    # last full prompt page) and never evictable while
+                    # referenced.
+                    for pid in matched:
+                        if self._page_ref[pid] == 0:
+                            del self._lru[pid]
+                            self._avail_pages -= 1
+                        self._page_ref[pid] += 1
+                    self._slot_shared[i] = list(matched)
+                    if self.prefix_cache:
+                        self.prefix_lookups += 1
+                        self.prefix_hit_pages += m
+                        self.prefix_hit_tokens += m * self.page_size
+                        self.prefix_total_tokens += len(tokens)
+                    fresh = [self._alloc_page()
+                             for _ in range(blocks_needed - m)]
+                    self._slot_pages[i] = fresh
+                    row = np.full((self.max_blocks,),
+                                  self._scratch_page, np.int32)
+                    row[:m] = matched
+                    row[m:blocks_needed] = fresh
+                    self._table[i] = row
+                    if m:
+                        prefix_len = m * self.page_size
+                        suffix_tokens = tokens[prefix_len:]
+                        sbucket = self._bucket_length(
+                            len(suffix_tokens))
+                        timed_key = ("shared", sbucket)
+                        timed_tokens = sbucket
+                        prefilled = len(suffix_tokens)
+                        suffix = self._put(np.asarray(
+                            [suffix_tokens +
+                             [0] * (sbucket - len(suffix_tokens))],
+                            np.int32))
+                        prefix_ids = np.full(
+                            (self.max_decode_len // self.page_size,),
+                            self._scratch_page, np.int32)
+                        prefix_ids[:m] = matched
+                        suffix_row = np.full((self.max_blocks,),
+                                             self._scratch_page,
+                                             np.int32)
+                        suffix_row[:blocks_needed - m] = fresh
+                        prefill, prefill_args = self._prefill_shared, (
+                            i, suffix, self._put(prefix_ids),
+                            self._put(row), self._put(suffix_row),
+                            prefix_len, len(tokens))
+                    else:
+                        timed_key = ("paged", bucket)
+                        prefill, prefill_args = self._prefill_paged, (
+                            i, prompt, self._put(row), len(tokens))
+                self._admitted.append({
+                    "request_id": req.request_id,
+                    "path": ("cold" if timed_key[0] == "paged"
+                             else timed_key[0]),
+                    "bucket": timed_tokens, "tokens": prefilled})
+            with phases("prefill"):
+                self.cache, last_logits = prefill(
+                    self.params, self.cache, *prefill_args)
                 if self.prefix_cache:
                     self._publish_pages(i, keys, m, row, len(tokens))
-            else:
-                self._queue.pop(0)
-                if self.on_admit is not None:
-                    self.on_admit(req.request_id)
-                self.cache, last_logits = self._prefill(
-                    self.params, self.cache, i, prompt, len(tokens))
-            if self.speculative is not None:
-                # The draft cache must hold the same committed prefix
-                # (the spec-step invariant); its prefill logits are
-                # discarded — the first token is always sampled from
-                # the TARGET's prefill.
-                self._draft_cache, _ = self._draft_prefill(
-                    self._draft_params, self._draft_cache, i, prompt,
+                if self.speculative is not None:
+                    # The draft cache must hold the same committed
+                    # prefix (the spec-step invariant); its prefill
+                    # logits are discarded — the first token is always
+                    # sampled from the TARGET's prefill.
+                    self._draft_cache, _ = self._draft_prefill(
+                        self._draft_params, self._draft_cache, i,
+                        prompt, len(tokens))
+                self._key, sample_key = jax.random.split(self._key)
+                first = inf._sample(
+                    last_logits[None].astype(jnp.float32), sample_key,
+                    self.sampling)
+                first_token = int(first[0])
+            with phases("slot_update"):
+                # The prefill-sampled token IS the next generated
+                # token.
+                self._slots[i] = _Slot(
+                    request=req,
+                    generated=entry.resumed + [first_token])
+                self._step_tokens += 1
+                if self.on_token is not None:
+                    self.on_token(req.request_id, first_token,
+                                  len(entry.resumed))
+                self._tokens = self._tokens.at[i, 0].set(first[0])
+                self._positions = self._positions.at[i].set(
                     len(tokens))
-            self._key, sample_key = jax.random.split(self._key)
-            first = inf._sample(
-                last_logits[None].astype(jnp.float32), sample_key,
-                self.sampling)
-            # The prefill-sampled token IS the next generated token.
-            self._slots[i] = _Slot(
-                request=req,
-                generated=entry.resumed + [int(first[0])])
-            if self.on_token is not None:
-                self.on_token(req.request_id, int(first[0]),
-                              len(entry.resumed))
-            self._tokens = self._tokens.at[i, 0].set(first[0])
-            self._positions = self._positions.at[i].set(len(tokens))
-            self._active = self._active.at[i].set(True)
-            # int(first[0]) above forced the prefill to complete, so
-            # t0..now is a faithful admission-stall sample.
-            self._record_prefill_time(timed_key, t0, timed_tokens)
+                self._active = self._active.at[i].set(True)
+                # int(first[0]) above forced the prefill to complete,
+                # so t0..now is a faithful admission-stall sample.
+                self._record_prefill_time(timed_key, t0, timed_tokens)

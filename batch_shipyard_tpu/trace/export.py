@@ -40,10 +40,13 @@ def trace_rows(store: StateStore, pool_id: str,
 
 def _track(row: dict) -> tuple[str, str]:
     """(pid, tid) for a row: one process track per node, one thread
-    track per task instance / serving request."""
+    track per task instance / serving request, and one for a serving
+    task's engine steps."""
     pid = row.get("node_id") or "client"
     attrs = row.get("attrs") or {}
-    if row.get("kind", "").startswith("serve_"):
+    if row.get("kind") == trace_spans.SPAN_SERVE_STEP:
+        tid = f"{row.get('task_id') or '-'} engine steps"
+    elif row.get("kind", "").startswith("serve_"):
         tid = f"request {attrs.get('request_id', '?')}"
     else:
         tid = row.get("task_id") or row.get("job_id") or "-"

@@ -16,7 +16,10 @@ causal chain of one submission. Two producer surfaces feed one log:
     trace/parent ids default to the task context the agent exported
     ($SHIPYARD_TRACE_* — context.TraceContext.from_env), so program
     spans parent under the task's run span with zero plumbing in the
-    workloads. With no sink configured the recorder is a no-op.
+    workloads. With no sink configured the recorder is a no-op. Rows
+    are buffered in memory and appended in batches (``flush``): a
+    span after a quiet spell is written at once, a stream of them
+    costs one file open per FLUSH_INTERVAL_S.
 
 Span dict schema (what export.py consumes)::
 
@@ -34,9 +37,11 @@ work being traced.
 
 from __future__ import annotations
 
+import atexit
 import contextlib
 import json
 import os
+import threading
 import time
 import uuid
 from typing import Any, Iterator, Optional
@@ -57,7 +62,6 @@ SPAN_CLAIM = "claim"                     # instantaneous claim marker
 SPAN_BACKOFF_WAIT = "backoff_wait"       # retry supervisor delay
 SPAN_REQUEUE = "requeue"                 # instantaneous requeue marker
 SPAN_RENDEZVOUS = "gang_rendezvous"      # gang join -> full formation
-SPAN_IMAGE_PULL = "image_pull"           # image provisioning on node
 SPAN_TASK_RUN = "task_run"               # task process start -> exit
 SPAN_CACHE_SEED = "compile_cache_seed"   # pre-task pool-cache seed
 SPAN_PREEMPT = "preempt"                 # preempt notice -> drained
@@ -81,7 +85,6 @@ SPAN_GANG_MIGRATE = "gang_migrate"       # starved in source pool ->
 
 # Program phases (process-local emitters inside the task)
 SPAN_COMPILE = "compile"                 # jit warm-up / AOT precompile
-SPAN_STEP_WINDOW = "train_step_window"   # productive step run
 SPAN_CKPT_SNAPSHOT = "checkpoint_snapshot"   # step-boundary device_get
 SPAN_CKPT_PERSIST = "checkpoint_persist"     # write-out (sync or
                                              # overlapped; attrs carry
@@ -96,16 +99,22 @@ SPAN_SERVE_PREFILL = "serve_prefill"     # admission -> first token
 SPAN_SERVE_DECODE = "serve_decode"       # first token -> last token;
                                          # speculative accept/rewind
                                          # counters annotated in attrs
+# Serving per-step rows (recorded by the engine itself)
+SPAN_SERVE_STEP = "serve_step"           # one ContinuousBatcher.step:
+                                         # per-phase milliseconds, the
+                                         # requests it admitted and the
+                                         # engine's occupancy as it
+                                         # began (docs/32-tracing.md)
 
 SPAN_KINDS = frozenset({
     SPAN_SUBMIT, SPAN_QUEUE_WAIT, SPAN_CLAIM, SPAN_BACKOFF_WAIT,
-    SPAN_REQUEUE, SPAN_RENDEZVOUS, SPAN_IMAGE_PULL, SPAN_TASK_RUN,
+    SPAN_REQUEUE, SPAN_RENDEZVOUS, SPAN_TASK_RUN,
     SPAN_CACHE_SEED, SPAN_PREEMPT, SPAN_EVICT, SPAN_GANG_RESIZE,
     SPAN_GANG_MIGRATE, SPAN_AGENT_RESTART,
-    SPAN_COMPILE, SPAN_STEP_WINDOW, SPAN_CKPT_SNAPSHOT,
+    SPAN_COMPILE, SPAN_CKPT_SNAPSHOT,
     SPAN_CKPT_PERSIST, SPAN_CKPT_RESTORE, SPAN_PROFILE,
     SPAN_SERVE_REQUEST, SPAN_SERVE_QUEUED, SPAN_SERVE_PREFILL,
-    SPAN_SERVE_DECODE,
+    SPAN_SERVE_DECODE, SPAN_SERVE_STEP,
 })
 
 
@@ -212,17 +221,67 @@ def local_spans_path() -> Optional[str]:
     return os.environ.get(trace_ctx.TRACE_FILE_ENV) or None
 
 
+# The recorder keeps rows in memory and appends them in batches: a
+# serving engine writes a row per step (twenty a second) from the
+# thread that feeds the chip, which cannot afford a file open each.
+# A row that arrives FLUSH_INTERVAL_S or more after the last write
+# goes out at once with whatever is waiting (so low-rate spans —
+# compile, checkpoint — still land immediately), and so does a
+# buffer that has reached MAX_BUFFERED_ROWS; otherwise rows wait for
+# the next such row, for flush(), or for the process to exit. Each
+# batch is one open-append-close, so the agent's rename-drain of a
+# live file (docs/32-tracing.md) keeps working: the next batch
+# re-creates the path.
+FLUSH_INTERVAL_S = 2.0
+MAX_BUFFERED_ROWS = 8192
+
+_buffer: list[tuple[str, dict]] = []     # (sink path, span dict)
+_buffer_lock = threading.Lock()
+_write_lock = threading.Lock()
+_last_flush = float("-inf")              # time.monotonic() of it
+_exit_hooked = False
+
+
+def flush() -> int:
+    """Append every buffered row to the sink it was recorded for.
+    Returns the rows written. Never raises: rows a failed write held
+    are dropped (the recorder's loss-over-duplication bias)."""
+    global _last_flush
+    with _buffer_lock:
+        rows = list(_buffer)
+        _buffer.clear()
+        _last_flush = time.monotonic()
+    if not rows:
+        return 0
+    by_path: dict[str, list[str]] = {}
+    for path, event in rows:
+        by_path.setdefault(path, []).append(json.dumps(event) + "\n")
+    written = 0
+    with _write_lock:
+        for path, lines in by_path.items():
+            try:
+                os.makedirs(os.path.dirname(path) or ".",
+                            exist_ok=True)
+                with open(path, "a", encoding="utf-8") as fh:
+                    fh.write("".join(lines))
+                written += len(lines)
+            except OSError:
+                logger.debug("trace local flush failed",
+                             exc_info=True)
+    return written
+
+
 def record(kind: str, start: float, end: Optional[float] = None,
            parent_span_id: Optional[str] = None,
            span_id: Optional[str] = None,
            **attrs: Any) -> Optional[str]:
-    """Process-local emit: append one JSONL span to
-    $SHIPYARD_TRACE_FILE. The trace id comes from the task context the
-    agent exported; ``parent_span_id`` defaults to the task's own span
-    (the run span), so flat program phases chain correctly with no
-    caller plumbing. No-op when no sink or no context is configured;
-    never raises. Returns the span id written (for parenting child
-    spans), or None."""
+    """Process-local emit: one JSONL span for $SHIPYARD_TRACE_FILE
+    (buffered, see above). The trace id comes from the task context
+    the agent exported; ``parent_span_id`` defaults to the task's own
+    span (the run span), so flat program phases chain correctly with
+    no caller plumbing. No-op when no sink or no context is
+    configured; never raises. Returns the span id recorded (for
+    parenting child spans), or None."""
     return _record(kind, start, end, attrs,
                    parent_span_id=parent_span_id, span_id=span_id)
 
@@ -235,6 +294,7 @@ def _record(kind: str, start: float, end: Optional[float],
     the positional parameters (a phase() body writing
     attrs["start"]/["end"] must degrade to data, not raise a
     TypeError out of the finally block into the traced work)."""
+    global _exit_hooked
     path = local_spans_path()
     ctx = trace_ctx.TraceContext.from_env()
     if path is None or ctx is None:
@@ -250,14 +310,16 @@ def _record(kind: str, start: float, end: Optional[float],
         "end": float(start if end is None else end),
         "attrs": dict(attrs),
     }
-    try:
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "a", encoding="utf-8") as fh:
-            fh.write(json.dumps(event) + "\n")
-        return sid
-    except OSError:
-        logger.debug("trace local record failed", exc_info=True)
-        return None
+    with _buffer_lock:
+        _buffer.append((path, event))
+        due = (len(_buffer) >= MAX_BUFFERED_ROWS or
+               time.monotonic() - _last_flush >= FLUSH_INTERVAL_S)
+        if not _exit_hooked:
+            atexit.register(flush)
+            _exit_hooked = True
+    if due:
+        flush()
+    return sid
 
 
 @contextlib.contextmanager
@@ -271,6 +333,84 @@ def phase(kind: str, **attrs: Any) -> Iterator[dict]:
         yield out_attrs
     finally:
         _record(kind, start, time.time(), out_attrs)
+
+
+class PhaseTimer:
+    """Times the leaf phases of a host loop on two clocks at once.
+
+    ``with timer("admit"):`` opens a jax.profiler.TraceAnnotation
+    named ``<prefix>admit`` — a fraction of a microsecond with no
+    profiler session, and with one the phase lies on the device
+    trace's clock, so a device-idle gap can be put down to it — and
+    adds the block's time.monotonic() seconds to ``step`` (since the
+    last reset(), what a per-step row keeps) and to ``total`` (since
+    construction, what /stats reports). Phases are leaves: do not
+    nest one in another, or its seconds count twice. ``total`` holds
+    every name in ``phases`` from the start, so another thread may
+    copy it while this one adds."""
+
+    def __init__(self, prefix: str, phases: tuple) -> None:
+        # jax is imported here, not at module scope: the agent and
+        # the CLI import this module and never import jax.
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
+        self.prefix = prefix
+        self.step: dict[str, float] = {}
+        self.total: dict[str, float] = dict.fromkeys(phases, 0.0)
+
+    def reset(self) -> None:
+        self.step.clear()
+
+    @contextlib.contextmanager
+    def __call__(self, name: str) -> Iterator[None]:
+        with self._annotate(self.prefix + name):
+            t0 = time.monotonic()
+            try:
+                yield
+            finally:
+                dt = time.monotonic() - t0
+                self.step[name] = self.step.get(name, 0.0) + dt
+                self.total[name] += dt
+
+
+class _CompileCounter:
+    """Programs the backend compiled (or loaded from the persistent
+    cache) in this process, and the seconds that took: jax reports
+    each through jax.monitoring as it happens, on whichever thread
+    asked for the program."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self) -> None:
+        from jax import monitoring
+        self._lock = threading.Lock()
+        self._count = 0
+        self._seconds = 0.0
+        monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event: str, seconds: float, **_kwargs) -> None:
+        if event == self.EVENT:
+            with self._lock:
+                self._count += 1
+                self._seconds += seconds
+
+    def read(self) -> tuple[int, float]:
+        with self._lock:
+            return self._count, self._seconds
+
+
+_compile_counter: Optional[_CompileCounter] = None
+_compile_counter_lock = threading.Lock()
+
+
+def compile_counter() -> _CompileCounter:
+    """The process-wide compile counter; the first call installs its
+    listener (what compiled before that is not counted)."""
+    global _compile_counter
+    with _compile_counter_lock:
+        if _compile_counter is None:
+            _compile_counter = _CompileCounter()
+    return _compile_counter
 
 
 def ingest_local_spans(store: StateStore, pool_id: str, path: str, *,

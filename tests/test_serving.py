@@ -410,3 +410,197 @@ def test_chunked_prefill_greedy_equivalent(params):
         for chunk in (8, 16):
             got = run(chunk, page)
             assert got == ref, (page, chunk, got, ref)
+
+
+# ----------------------- step tracing (serve_step) ----------------------
+
+import json  # noqa: E402
+import os  # noqa: E402
+
+from batch_shipyard_tpu.trace import spans as trace_spans  # noqa: E402
+
+
+@pytest.fixture()
+def recorder(tmp_path, monkeypatch):
+    """The process-local span recorder switched on, as the agent (or
+    the benchmark's traced run) switches it: by the environment."""
+    path = tmp_path / "spans.jsonl"
+    trace_spans.flush()
+    monkeypatch.setenv("SHIPYARD_TRACE_FILE", str(path))
+    monkeypatch.setenv("SHIPYARD_TRACE_ID", "trace-1")
+    monkeypatch.setenv("SHIPYARD_TRACE_SPAN_ID", "run-1")
+
+    def rows():
+        trace_spans.flush()
+        if not path.exists():
+            return []
+        with open(path, encoding="utf-8") as fh:
+            return [json.loads(line) for line in fh]
+
+    return rows
+
+
+def _traced_engine(kind, params):
+    kwargs = {
+        "dense": {},
+        "paged": {"kv_page_size": 8, "kv_num_pages": 16,
+                  "prefix_cache": False},
+        "prefix-shared": {"kv_page_size": 8, "kv_num_pages": 24},
+        "speculative": {"speculative": serving.SpeculativeConfig(
+            CFG, params, gamma=2)},
+    }[kind]
+    return serving.ContinuousBatcher(CFG, params, num_slots=2,
+                                     max_decode_len=64, **kwargs)
+
+
+def _shared_prefix_requests(count=4):
+    rng = np.random.RandomState(5)
+    prefix = list(rng.randint(1, 97, (16,)))    # two whole pages
+    return [serving.Request(
+        f"r{i}", prefix + list(rng.randint(1, 97, (3 + i,))),
+        max_new_tokens=3 + i) for i in range(count)]
+
+
+def _outside_view(engine):
+    """What benchmark/drivers/serve.py::StepRecorder reads off the
+    engine's private lists as a step starts."""
+    view = {
+        "slots_active": sum(1 for slot in engine._slots
+                            if slot.request is not None),
+        "queued": len(engine._queue),
+        "live_tokens": sum(
+            len(slot.request.prompt) + len(slot.generated)
+            for slot in engine._slots if slot.request is not None)}
+    if engine.paged:
+        view["kv_pages_in_use"] = len(
+            {page for i in range(engine.num_slots)
+             for held in (engine._slot_pages[i],
+                          engine._slot_shared[i])
+             for page in held})
+    return view
+
+
+def _drain_traced(engine, requests):
+    for req in requests:
+        engine.submit(req)
+    results, views = {}, []
+    for _ in range(200):
+        if not engine.pending():
+            break
+        views.append(_outside_view(engine))
+        for rid, toks in engine.step():
+            results[rid] = toks
+    return results, views
+
+
+@pytest.mark.parametrize(
+    "kind", ["dense", "paged", "prefix-shared", "speculative"])
+def test_every_step_writes_one_row_that_agrees_with_the_private_lists(
+        kind, params, recorder):
+    engine = _traced_engine(kind, params)
+    requests = _shared_prefix_requests()
+    results, views = _drain_traced(engine, requests)
+    rows = recorder()
+    assert [r["kind"] for r in rows] == ["serve_step"] * len(views)
+    assert engine.steps_total == len(views)
+    for row, view in zip(rows, views):
+        attrs = row["attrs"]
+        assert row["parent_span_id"] == "run-1"
+        assert {k: attrs[k] for k in view} == view
+        assert attrs["slots_total"] == 2
+        phase_ms = sum(attrs[f"{name}_ms"]
+                       for name in serving.STEP_PHASES)
+        assert 0 < phase_ms <= (row["end"] - row["start"]) * 1e3 + 1e-6
+        assert attrs["prefills"] == len(attrs["admitted"])
+    if engine.paged:
+        assert all(r["attrs"]["kv_pages_total"] == engine._total_pages
+                   and r["attrs"]["kv_pages_free"]
+                   + r["attrs"]["kv_pages_lru"]
+                   + r["attrs"]["kv_pages_in_use"]
+                   <= engine._total_pages for r in rows)
+    else:
+        assert "kv_pages_in_use" not in rows[0]["attrs"]
+    admitted = [a for r in rows for a in r["attrs"]["admitted"]]
+    assert sorted(a["request_id"] for a in admitted) == \
+        sorted(r.request_id for r in requests)
+    paths = {a["path"] for a in admitted}
+    assert paths == {"dense": {"dense"}, "paged": {"cold"},
+                     "prefix-shared": {"cold", "shared"},
+                     "speculative": {"dense"}}[kind]
+    for entry in admitted:
+        assert entry["bucket"] >= entry["tokens"] > 0
+    assert sum(r["attrs"]["prefill_tokens"] for r in rows) == \
+        sum(a["tokens"] for a in admitted)
+    # a speculative step may commit tokens past a request's end
+    emitted = sum(r["attrs"]["tokens_emitted"] for r in rows)
+    served = sum(len(toks) for toks in results.values())
+    assert emitted == served
+    assert sum(r["attrs"]["finished"] for r in rows) == len(requests)
+    assert rows[-1]["attrs"]["readback_ms"] > 0
+
+
+@pytest.mark.parametrize("kind", ["dense", "prefix-shared"])
+def test_recorder_off_writes_nothing_and_changes_no_token(
+        kind, params, recorder, monkeypatch):
+    traced, _ = _drain_traced(_traced_engine(kind, params),
+                              _shared_prefix_requests())
+    n_rows = len(recorder())
+    assert n_rows > 0
+    monkeypatch.delenv("SHIPYARD_TRACE_FILE")
+    engine = _traced_engine(kind, params)
+    plain, views = _drain_traced(engine, _shared_prefix_requests())
+    assert plain == traced
+    monkeypatch.setenv("SHIPYARD_TRACE_FILE", os.devnull)
+    assert trace_spans.flush() == 0         # nothing was buffered
+    # the cumulative counters are always on
+    stats = engine.step_stats()
+    assert stats["steps"] == len(views) and engine.traced_steps == 0
+    assert set(stats["phase_seconds"]) == set(serving.STEP_PHASES)
+    assert 0 < sum(stats["phase_seconds"].values()) <= \
+        stats["step_seconds"]
+
+
+def test_a_step_that_compiles_says_so_in_its_row(recorder):
+    """A width no other test uses: its first step has to build (or
+    load) its prefill and decode programs."""
+    cfg = tfm.TransformerConfig(
+        vocab_size=97, d_model=24, n_layers=1, n_heads=2, d_head=12,
+        d_ff=40, max_seq_len=64, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    odd = tfm.TransformerLM(cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = serving.ContinuousBatcher(cfg, odd, num_slots=3,
+                                       max_decode_len=64)
+    before = engine.step_stats()["compiles"]
+    engine.submit(serving.Request("c", [1, 2, 3], max_new_tokens=4))
+    while engine.pending():
+        engine.step()
+    rows = recorder()
+    assert rows[0]["attrs"]["compiles"] >= 1
+    assert rows[0]["attrs"]["compile_ms"] > 0
+    assert "compiles" not in rows[-1]["attrs"]
+    assert engine.step_stats()["compiles"] >= \
+        before + rows[0]["attrs"]["compiles"]
+
+
+def test_step_rows_are_head_sampled_and_the_counters_are_not(
+        params, recorder, monkeypatch):
+    monkeypatch.setattr(serving.ContinuousBatcher, "_STEP_HEAD", 3)
+    monkeypatch.setattr(serving.ContinuousBatcher,
+                        "_STEP_SAMPLE_EVERY", 4)
+    engine = _traced_engine("dense", params)
+    engine.submit(serving.Request("long", [5, 6, 7],
+                                  max_new_tokens=14))
+    steps = 0
+    while engine.pending():
+        engine.step()
+        steps += 1
+    assert steps == 13 == engine.steps_total == engine.traced_steps
+    rows = recorder()
+    # steps 1-3 in full, then every fourth: 4, 8, 12. A step adds a
+    # token; the first adds the prefill's too (3 + 2 live at step 2).
+    assert [r["attrs"]["live_tokens"] for r in rows] == \
+        [0, 5, 6, 7, 11, 15]
+    # an engine with nothing to seat or decode writes nothing
+    engine.step()
+    assert engine.steps_total == 13 and len(recorder()) == 6

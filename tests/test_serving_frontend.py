@@ -452,3 +452,96 @@ def test_loadgen_round_robins_across_replicas(params):
     finally:
         for f in fronts:
             f.shutdown()
+
+
+# --------------------- the engine from inside (ISSUE 24) ----------------
+
+def test_stats_and_metrics_carry_the_engine_block(params):
+    engine = serving.ContinuousBatcher(
+        CFG, params, num_slots=2, max_decode_len=64, kv_page_size=8,
+        kv_num_pages=12)
+    front = ServingFrontEnd(engine, port=0).start()
+    try:
+        for k in range(3):
+            _post(front.url, {"prompt": [1 + k] * 9,
+                              "max_new_tokens": 4})
+        with urllib.request.urlopen(f"{front.url}/v1/stats",
+                                    timeout=30) as resp:
+            block = json.loads(resp.read())["engine"]
+        with urllib.request.urlopen(f"{front.url}/metrics",
+                                    timeout=30) as resp:
+            metrics = resp.read().decode()
+    finally:
+        front.shutdown()
+    assert block["slots_total"] == 2 and block["kv_pages_total"] == 12
+    assert block["slots_active"] == 0 and block["queued"] == 0
+    assert block["kv_pages_in_use"] == 0
+    # finished prompts' whole pages stay indexed, parked in the LRU
+    assert block["kv_pages_lru"] == block["prefix_index_pages"] == 3
+    assert block["kv_pages_free"] == 12 - 3
+    assert block["steps"] >= 3 * 3 and block["step_ms_mean"] > 0
+    assert set(block["phase_ms_mean"]) == set(serving.STEP_PHASES)
+    assert sum(block["phase_ms_mean"].values()) <= block["step_ms_mean"]
+    assert block["compiles"] >= 0 and block["compile_seconds"] >= 0
+    values = {}
+    for line in metrics.splitlines():
+        name, _, value = line.rpartition(" ")
+        values[name] = float(value)
+    assert values["shipyard_serving_slots_active"] == 0
+    assert values["shipyard_serving_queue_depth"] == 0
+    assert values["shipyard_serving_kv_pages_in_use"] == 0
+    assert values["shipyard_serving_kv_pages_total"] == 12
+    assert values["shipyard_serving_steps_total"] == block["steps"]
+    assert "shipyard_serving_compiles_total" in values
+    for phase in serving.STEP_PHASES:
+        assert values['shipyard_serving_step_phase_seconds_total'
+                      f'{{phase="{phase}"}}'] == \
+            pytest.approx(block["phase_seconds"][phase])
+    # a dense engine has no pages to report: the lines are left out
+    dense = ServingFrontEnd(serving.ContinuousBatcher(
+        CFG, params, num_slots=2, max_decode_len=64), port=0)
+    lines = "\n".join(dense.prometheus_metrics())
+    dense._httpd.server_close()
+    assert "kv_pages" not in lines and "slots_active 0" in lines
+
+
+def test_a_request_is_in_one_step_row_and_in_its_prefill_span(
+        params, tmp_path, monkeypatch):
+    """The step that stalled for a request and the request's own
+    serve_prefill span share the request id; and what the recorder
+    buffered is in the file once shutdown() has returned."""
+    from batch_shipyard_tpu.trace import spans as trace_spans
+    path = tmp_path / "spans.jsonl"
+    trace_spans.flush()
+    monkeypatch.setenv("SHIPYARD_TRACE_FILE", str(path))
+    monkeypatch.setenv("SHIPYARD_TRACE_ID", "trace-1")
+    monkeypatch.setenv("SHIPYARD_TRACE_SPAN_ID", "run-1")
+    engine = serving.ContinuousBatcher(CFG, params, num_slots=2,
+                                       max_decode_len=64)
+    engine.traced_steps = 99999         # a front end starts the head
+    front = ServingFrontEnd(engine, port=0).start()
+    assert engine.traced_steps == 0
+    try:
+        ids = [_post(front.url, {"prompt": [2 + k] * 5,
+                                 "max_new_tokens": 3,
+                                 "request_id": f"q{k}"})["request_id"]
+               for k in range(3)]
+    finally:
+        front.shutdown()
+    with open(path, encoding="utf-8") as fh:    # no flush() here
+        rows = [json.loads(line) for line in fh]
+    steps = [r for r in rows if r["kind"] == "serve_step"]
+    prefills = {r["attrs"]["request_id"]: r for r in rows
+                if r["kind"] == "serve_prefill"}
+    assert sorted(prefills) == sorted(ids) == ["q0", "q1", "q2"]
+    for request_id in ids:
+        holders = [s for s in steps if request_id in
+                   {a["request_id"] for a in s["attrs"]["admitted"]}]
+        assert len(holders) == 1
+        step, span = holders[0], prefills[request_id]
+        assert step["attrs"]["prefill_ms"] > 0
+        # the step began before the request's prefill did and ended
+        # after its first token (both on time.time())
+        assert step["start"] <= span["start"] + 0.05
+        assert span["end"] <= step["end"] + 0.05
+    assert len(steps) == engine.steps_total == engine.traced_steps

@@ -139,6 +139,7 @@ def test_local_recorder_and_ingest(tmp_path, monkeypatch):
     with trace_spans.phase(trace_spans.SPAN_CKPT_SNAPSHOT,
                            step=4) as attrs:
         attrs["extra"] = 1
+    trace_spans.flush()     # the second span waited in the buffer
     # Junk lines must be skipped by the ingest, not raised.
     with open(path, "a", encoding="utf-8") as fh:
         fh.write("not json\n")
@@ -159,6 +160,151 @@ def test_local_recorder_and_ingest(tmp_path, monkeypatch):
     snap = next(r for r in rows
                 if r["kind"] == "checkpoint_snapshot")
     assert snap["attrs"]["step"] == 4 and snap["attrs"]["extra"] == 1
+
+
+def _spans_env(monkeypatch, path):
+    ctx = trace_ctx.TraceContext.new()
+    monkeypatch.setenv(trace_ctx.TRACE_FILE_ENV, str(path))
+    for key, value in ctx.env().items():
+        monkeypatch.setenv(key, value)
+    trace_spans.flush()         # nothing of an earlier test waiting
+    return ctx
+
+
+def _lines(path):
+    if not os.path.exists(path):
+        return []
+    with open(path, encoding="utf-8") as fh:
+        return [json.loads(line) for line in fh]
+
+
+def test_local_recorder_buffers_and_writes_in_batches(tmp_path,
+                                                      monkeypatch):
+    """A span after a quiet spell goes out at once; a stream of them
+    waits for the interval, the row cap or flush(), and a batch is
+    ONE append however many rows it holds."""
+    path = tmp_path / "spans.jsonl"
+    _spans_env(monkeypatch, path)
+    monkeypatch.setattr(trace_spans, "_last_flush", float("-inf"))
+    opens = []
+    real_open = open
+
+    def counting_open(file, *args, **kwargs):
+        if str(file) == str(path) and args and args[0] == "a":
+            opens.append(file)
+        return real_open(file, *args, **kwargs)
+
+    monkeypatch.setattr("builtins.open", counting_open)
+    trace_spans.record(trace_spans.SPAN_COMPILE, 1.0, 2.0)
+    assert len(_lines(path)) == 1 and len(opens) == 1
+    for k in range(50):
+        trace_spans.record(trace_spans.SPAN_SERVE_STEP, 2.0 + k,
+                           3.0 + k, k=k)
+    assert len(_lines(path)) == 1 and len(opens) == 1   # buffered
+    assert trace_spans.flush() == 50
+    assert len(opens) == 2
+    assert [r["attrs"]["k"] for r in _lines(path)[1:]] == \
+        list(range(50))
+    assert trace_spans.flush() == 0 and len(opens) == 2
+    # the interval has passed: the next span takes the waiting ones
+    trace_spans.record(trace_spans.SPAN_SERVE_STEP, 60.0, k=50)
+    monkeypatch.setattr(trace_spans, "_last_flush", float("-inf"))
+    trace_spans.record(trace_spans.SPAN_SERVE_STEP, 61.0, k=51)
+    assert len(_lines(path)) == 53 and len(opens) == 3
+    # bounded memory: the cap flushes whatever the clock says
+    monkeypatch.setattr(trace_spans, "MAX_BUFFERED_ROWS", 8)
+    for k in range(8):
+        trace_spans.record(trace_spans.SPAN_SERVE_STEP, 70.0 + k)
+    assert len(_lines(path)) == 61 and len(opens) == 4
+    assert not trace_spans._buffer
+
+
+def test_buffered_rows_survive_the_agents_rename_drain(tmp_path,
+                                                       monkeypatch):
+    """The heartbeat drain renames the live file away between two
+    batches: both batches are ingested, none twice, and the second
+    re-creates the path."""
+    from batch_shipyard_tpu.agent.node_agent import NodeAgent
+    path = tmp_path / "trace_spans.jsonl"
+    ctx = _spans_env(monkeypatch, path)
+    store = MemoryStateStore()
+    agent = types.SimpleNamespace(
+        store=store, identity=types.SimpleNamespace(
+            pool_id="p1", node_id="n1"))
+    for k in range(3):
+        trace_spans.record(trace_spans.SPAN_SERVE_STEP, 1.0 + k, k=k)
+    trace_spans.flush()
+    assert NodeAgent._drain_trace_file(agent, str(path), "j", "t") == 3
+    assert not path.exists()
+    for k in range(3, 5):
+        trace_spans.record(trace_spans.SPAN_SERVE_STEP, 1.0 + k, k=k)
+    assert NodeAgent._drain_trace_file(agent, str(path), "j", "t") \
+        in (0, 1)               # at most the write-through row yet
+    trace_spans.flush()
+    NodeAgent._drain_trace_file(agent, str(path), "j", "t")
+    rows = trace_spans.query(store, "p1", trace_id=ctx.trace_id)
+    assert sorted(r["attrs"]["k"] for r in rows) == [0, 1, 2, 3, 4]
+
+
+def test_buffered_rows_are_written_when_the_process_exits(tmp_path):
+    import subprocess
+    import sys
+    path = tmp_path / "spans.jsonl"
+    code = (
+        "from batch_shipyard_tpu.trace import spans\n"
+        "for k in range(20):\n"
+        "    spans.record(spans.SPAN_SERVE_STEP, 1.0 + k, k=k)\n"
+        "assert len(spans._buffer) == 19\n")
+    env = dict(os.environ, SHIPYARD_TRACE_FILE=str(path),
+               SHIPYARD_TRACE_ID="t1", SHIPYARD_TRACE_SPAN_ID="s1",
+               PYTHONPATH=REPO_ROOT)
+    done = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert [r["attrs"]["k"] for r in _lines(path)] == list(range(20))
+
+
+def test_phase_timer_sums_leaf_phases_per_step_and_in_total():
+    timer = trace_spans.PhaseTimer("test:", ("a", "b"))
+    assert timer.total == {"a": 0.0, "b": 0.0}
+    t0 = time.monotonic()
+    with timer("a"):
+        time.sleep(0.01)
+    with timer("b"):
+        pass
+    with timer("a"):
+        time.sleep(0.01)
+    wall = time.monotonic() - t0
+    assert set(timer.step) == {"a", "b"}
+    assert 0.02 <= timer.step["a"] and sum(timer.step.values()) <= wall
+    first = dict(timer.step)
+    timer.reset()
+    assert timer.step == {} and timer.total == first
+    with pytest.raises(KeyError):       # an undeclared phase is a bug
+        with timer("c"):
+            pass
+
+
+def test_serve_step_rows_export_on_a_track_of_the_task():
+    rows = {"spans": [
+        {"kind": "task_run", "trace_id": "t", "span_id": "run",
+         "parent_span_id": None, "start": 0.0, "end": 9.0,
+         "task_id": "serve-0", "node_id": "n1", "attrs": {}},
+        {"kind": "serve_step", "trace_id": "t", "span_id": "s1",
+         "parent_span_id": "run", "start": 1.0, "end": 1.05,
+         "task_id": "serve-0", "node_id": "n1",
+         "attrs": {"prefill_ms": 30.0, "slots_active": 3}},
+        {"kind": "serve_prefill", "trace_id": "t", "span_id": "p1",
+         "parent_span_id": "run", "start": 1.0, "end": 1.04,
+         "task_id": "serve-0", "node_id": "n1",
+         "attrs": {"request_id": "r1"}}], "goodput": []}
+    chrome = trace_export.to_chrome_trace(rows, "t")
+    assert trace_export.validate_parent_links(chrome) == []
+    by_name = {e["name"]: e for e in chrome["traceEvents"]}
+    assert by_name["serve_step"]["tid"] == "serve-0 engine steps"
+    assert by_name["serve_step"]["args"]["prefill_ms"] == 30.0
+    assert by_name["serve_prefill"]["tid"] == "request r1"
+    assert "serve_step" in trace_export.render_tree(rows)
 
 
 def test_goodput_record_attaches_trace_ids(tmp_path, monkeypatch):
@@ -253,6 +399,7 @@ def test_step_profiler_capture_flow(tmp_path, monkeypatch):
     profiler.tick(3)
     assert not profiler.active
     assert calls == [("start", profile_dir), ("stop",)]
+    trace_spans.flush()
     with open(spans_file, encoding="utf-8") as fh:
         spans = [json.loads(line) for line in fh]
     assert spans[-1]["kind"] == trace_spans.SPAN_PROFILE
