@@ -98,9 +98,24 @@ def check_impure_pure_fn(ctx: AnalysisContext) -> list[Finding]:
     return findings
 
 
-def _donated_positions(node: ast.Call) -> Optional[set[int]]:
+def _donated_positions(node: ast.Call,
+                       fn: Optional[ast.FunctionDef] = None
+                       ) -> Optional[set[int]]:
     """Donated arg positions of a jax.jit(...) call expression, or
-    None when it doesn't donate."""
+    None when it doesn't donate. ``donate_argnames`` (the spelling of
+    models/serving.py's step programs) resolves against the
+    positional parameters of ``fn``, the function being jitted, when
+    the module defines it."""
+    names = keyword_arg(node, "donate_argnames")
+    if names is not None and fn is not None:
+        params = [a.arg for a in fn.args.posonlyargs + fn.args.args]
+        wanted = ([names] if isinstance(names, ast.Constant)
+                  else getattr(names, "elts", []))
+        out = {params.index(elt.value) for elt in wanted
+               if isinstance(elt, ast.Constant)
+               and elt.value in params}
+        if out:
+            return out
     donate = keyword_arg(node, "donate_argnums")
     if donate is None:
         return None
@@ -120,8 +135,11 @@ def _donated_positions(node: ast.Call) -> Optional[set[int]]:
 def _collect_donating_jits(tree: ast.AST) -> dict[str, set[int]]:
     """name -> donated positions, for both idioms:
     step = jax.jit(fn, donate_argnums=(0,)) assignments and
-    @partial(jax.jit, donate_argnums=(0,)) decorators."""
+    @partial(jax.jit, donate_argnums=(0,)) decorators, with
+    donate_argnames=("cache",) in either."""
     donating: dict[str, set[int]] = {}
+    defs = {node.name: node for node in ast.walk(tree)
+            if isinstance(node, ast.FunctionDef)}
 
     def jit_call(call: ast.Call) -> Optional[ast.Call]:
         name = call_name(call)
@@ -140,7 +158,9 @@ def _collect_donating_jits(tree: ast.AST) -> dict[str, set[int]]:
                 isinstance(node.value, ast.Call):
             call = jit_call(node.value)
             if call is not None:
-                positions = _donated_positions(call)
+                jitted = call.args[0] if call.args else None
+                positions = _donated_positions(
+                    call, defs.get(getattr(jitted, "id", None)))
                 if positions:
                     for target in node.targets:
                         if isinstance(target, ast.Name):
@@ -150,7 +170,7 @@ def _collect_donating_jits(tree: ast.AST) -> dict[str, set[int]]:
                 if isinstance(dec, ast.Call):
                     call = jit_call(dec)
                     if call is not None:
-                        positions = _donated_positions(call)
+                        positions = _donated_positions(call, node)
                         if positions:
                             donating[node.name] = positions
     return donating
@@ -181,8 +201,10 @@ def _own_statements(fn: ast.FunctionDef) -> list[ast.stmt]:
 
 @rule("jax-donated-reuse", family="jax")
 def check_donated_reuse(ctx: AnalysisContext) -> list[Finding]:
-    """A variable passed at a donated position of a jit'd function is
-    read again in a LATER statement before being rebound: donation
+    """A variable passed at a donated position of a jit'd function
+    (donate_argnums, or donate_argnames resolved against the jitted
+    function's signature) is read again in a LATER statement before
+    being rebound: donation
     hands the buffer to XLA, so the old reference is garbage — a
     runtime error when you're lucky, silently corrupt numerics when
     you're not.

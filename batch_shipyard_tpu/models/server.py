@@ -1189,6 +1189,20 @@ class ServingFrontEnd:
                 finished = self.engine.step()
             except Exception:
                 logger.exception("engine step failed")
+                if self.engine.cache_lost():
+                    # The step programs consume the cache they are
+                    # given: a step that raised after that leaves
+                    # nothing to decode from, and every further step
+                    # would fail the same way. Leave rotation as a
+                    # draining replica does, with no grace: healthz
+                    # 503, the queue evicted and the active decodes
+                    # abandoned with the marker the router resumes
+                    # from on a sibling.
+                    self.drain(grace_s=0.0,
+                               reason="KV cache lost in a failed step")
+                    # An earlier drain's grace is void as well.
+                    self._drain_deadline = time.perf_counter()
+                    self._drain_tick()
                 continue
             now = time.perf_counter()
             for request_id, tokens in finished:
@@ -1214,7 +1228,6 @@ class ServingFrontEnd:
                 self._complete_draining(
                     request_id, "queued work evicted at drain")
             self._drain_engine_done = True
-            return
         if self._drain_deadline is not None and \
                 time.perf_counter() >= self._drain_deadline:
             for request_id in self.engine.active_request_ids():
@@ -1243,7 +1256,8 @@ class ServingFrontEnd:
         if pending is None:
             return
         if draining:
-            pending.error = (f"request {request_id} draining: grace "
+            pending.error = (f"request {request_id} draining "
+                             f"({self._drain_reason}): grace "
                              f"deadline, decode abandoned")
             pending.draining = True
         else:

@@ -46,14 +46,24 @@ STEP_PHASES = ("admit", "prefill", "slot_update", "grow_pages",
                "dispatch", "readback", "emit")
 
 
-@functools.partial(jax.jit, static_argnames=("model", "sampling"))
+@functools.partial(jax.jit, static_argnames=("model", "sampling"),
+                   donate_argnames=("cache",))
 def _decode_step(model, sampling, params, cache, tokens, positions,
                  active, key):
     """One token for every slot in one compiled call. MODULE-LEVEL
     with the model/sampling static so identical engines — fleet
     replicas sharing one param tree, or a test suite constructing
     many same-config engines — share ONE compilation instead of
-    re-tracing per ContinuousBatcher instance."""
+    re-tracing per ContinuousBatcher instance.
+
+    Like every step program below (the prefills, _speculative_step)
+    this one CONSUMES the cache it is given (donate_argnames): the
+    rows are written in place and the returned cache lives in the
+    same buffers, so no step copies the pool or holds two of them.
+    The cache passed in is deleted by the call — rebind it from the
+    result in the same statement, and read nothing through an older
+    reference. Parameters are shared between replicas and are not
+    donated."""
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
         positions=positions[:, None], mutable=["cache"])
@@ -73,7 +83,8 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "target_model", "draft_model", "gamma"))
+    "target_model", "draft_model", "gamma"),
+    donate_argnames=("t_cache", "d_cache"))
 def _speculative_step(target_model, draft_model, gamma, t_params,
                       d_params, t_cache, d_cache, tokens, positions,
                       active):
@@ -190,8 +201,16 @@ def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
     return cache, last
 
 
-@functools.partial(jax.jit, static_argnames=("model",
-                                             "prefill_chunk"))
+def _pool_rows(rows, pool):
+    """One page of dense-cache rows [page, H, D] as the paged pool
+    stores them: its dtype, heads folded into the row ([page, H*D],
+    transformer._decode_attend_paged)."""
+    return rows.astype(pool.dtype).reshape(pool.shape[1:])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("model", "prefill_chunk"),
+                   donate_argnames=("cache",))
 def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
                    prompt_len):
     """Fill ONE slot's cache region from a prompt [1, L] (batch-1
@@ -217,8 +236,9 @@ def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
     return cache, last
 
 
-@functools.partial(jax.jit, static_argnames=("model", "prefill_chunk",
-                                             "page"))
+@functools.partial(jax.jit,
+                   static_argnames=("model", "prefill_chunk", "page"),
+                   donate_argnames=("cache",))
 def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
                    prompt, table_row, prompt_len):
     """Paged variant: dense batch-1 prefill, rows scattered
@@ -241,8 +261,8 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
             for b in range(n_blocks):
                 krows = sm["k"][0, b * page:(b + 1) * page]
                 vrows = sm["v"][0, b * page:(b + 1) * page]
-                kp = kp.at[table_row[b]].set(krows.astype(kp.dtype))
-                vp = vp.at[table_row[b]].set(vrows.astype(vp.dtype))
+                kp = kp.at[table_row[b]].set(_pool_rows(krows, kp))
+                vp = vp.at[table_row[b]].set(_pool_rows(vrows, vp))
             out = {
                 "k_pages": kp, "v_pages": vp,
                 "block_table":
@@ -269,8 +289,9 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
     return scatter(cache, small), last
 
 
-@functools.partial(jax.jit, static_argnames=("model", "prefill_chunk",
-                                             "page"))
+@functools.partial(jax.jit,
+                   static_argnames=("model", "prefill_chunk", "page"),
+                   donate_argnames=("cache",))
 def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
                           slot, suffix, prefix_ids, table_row,
                           suffix_row, prefix_len, prompt_len):
@@ -304,10 +325,13 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
             rows = tfm.prefix_rows_from_pages(big, prefix_ids, page)
             nrows = rows["k"].shape[0]
             out = dict(sm)
+            heads_depth = sm["k"].shape[2:]
             out["k"] = sm["k"].at[0, :nrows].set(
-                rows["k"].astype(sm["k"].dtype))
+                rows["k"].astype(sm["k"].dtype).reshape(
+                    nrows, *heads_depth))
             out["v"] = sm["v"].at[0, :nrows].set(
-                rows["v"].astype(sm["v"].dtype))
+                rows["v"].astype(sm["v"].dtype).reshape(
+                    nrows, *heads_depth))
             out["index"] = jnp.full_like(sm["index"], prefix_len)
             if "k_scale" in sm:
                 out["k_scale"] = sm["k_scale"].at[0, :nrows].set(
@@ -353,8 +377,8 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
                     sm["k"][0], start, page)
                 vrows = jax.lax.dynamic_slice_in_dim(
                     sm["v"][0], start, page)
-                kp = kp.at[suffix_row[b]].set(krows.astype(kp.dtype))
-                vp = vp.at[suffix_row[b]].set(vrows.astype(vp.dtype))
+                kp = kp.at[suffix_row[b]].set(_pool_rows(krows, kp))
+                vp = vp.at[suffix_row[b]].set(_pool_rows(vrows, vp))
             out = {
                 "k_pages": kp, "v_pages": vp,
                 "block_table":
@@ -379,6 +403,14 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
         return {key: scatter(big[key], sm[key]) for key in big}
 
     return scatter(cache, small), last
+
+
+@functools.partial(jax.jit, static_argnames=("copies",))
+def _table_per_layer(table, copies):
+    """The block table ``copies`` times over, each in a buffer of its
+    own: the outputs of one program never share a buffer, so
+    returning the argument once per layer is the copy."""
+    return (table,) * copies
 
 
 @dataclasses.dataclass
@@ -963,6 +995,19 @@ class ContinuousBatcher:
         self._queue.clear()
         return evicted
 
+    def cache_lost(self) -> bool:
+        """Whether a step program consumed the cache and gave none
+        back: the step programs donate the cache they are given, so
+        one that raises after its buffers were handed over leaves
+        ``self.cache`` holding deleted arrays, and no further step
+        can run. For the caller of a step() that raised; nothing on
+        the sound path asks."""
+        caches = [self.cache]
+        if self.speculative is not None:
+            caches.append(self._draft_cache)
+        return any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(caches))
+
     def active_request_ids(self) -> list[str]:
         """Ids currently decoding in a slot (in-flight work a drain
         lets run to completion)."""
@@ -1403,13 +1448,18 @@ class ContinuousBatcher:
 
     def _push_tables(self) -> None:
         """Write the canonical block table into every layer's cache
-        copy."""
-        table = self._put(self._table)
+        copy — a device buffer of its own for each layer, because the
+        step programs donate the cache and one buffer under every
+        layer's leaf would be donated once per layer ("Attempt to
+        donate the same buffer twice"). One transfer and one small
+        program (_table_per_layer), not a transfer per layer."""
+        tables = iter(_table_per_layer(self._put(self._table),
+                                       self.config.n_layers))
 
         def push(leaf_dict):
             if isinstance(leaf_dict, dict) and \
                     "block_table" in leaf_dict:
-                return {**leaf_dict, "block_table": table}
+                return {**leaf_dict, "block_table": next(tables)}
             if isinstance(leaf_dict, dict):
                 return {k: push(v) for k, v in leaf_dict.items()}
             return leaf_dict

@@ -373,10 +373,20 @@ class Attention(nn.Module):
 
     def _decode_attend_paged(self, q, k, v):
         """Paged decode attention (vLLM-style block tables): K/V live
-        in a SHARED page pool [P, page, H, D]; each slot owns a row of
+        in a SHARED page pool [P, page, H*D]; each slot owns a row of
         page indices (block_table) covering only its actual length —
         the memory win over the dense cache is that the pool is sized
         for aggregate live tokens, not num_slots * max_decode_len.
+
+        The pool is STORED as the decode kernel blocks it
+        (ops/paged_attention.py: one [page, H*D] tile a page), heads
+        folded into the lane dimension. On a TPU a [.., H, D] array and
+        its [.., H*D] reshape are tiled differently, so a pool kept
+        [P, page, H, D] is relaid out whole, every layer, every step,
+        on its way into the kernel; kept as the kernel reads it, the
+        row scatter below and the kernel share one buffer and a step
+        that is given the cache to consume (models/serving.py donates
+        it) touches only the rows it writes and the pages it reads.
 
         block_table/length are duplicated per layer (tiny int arrays)
         so everything stays inside the flax cache collection; the
@@ -402,12 +412,13 @@ class Attention(nn.Module):
                 f"entries instead of live pages")
         max_blocks = (cfg.max_decode_len + cfg.spec_window
                       + page - 1) // page
+        width = heads * depth
         k_pages = self.variable(
             "cache", "k_pages", jnp.zeros,
-            (cfg.kv_num_pages, page, heads, depth), store_dtype)
+            (cfg.kv_num_pages, page, width), store_dtype)
         v_pages = self.variable(
             "cache", "v_pages", jnp.zeros,
-            (cfg.kv_num_pages, page, heads, depth), store_dtype)
+            (cfg.kv_num_pages, page, width), store_dtype)
         if int8_kv:
             scale_k = self.variable(
                 "cache", "k_page_scales", jnp.zeros,
@@ -440,9 +451,9 @@ class Attention(nn.Module):
             scale_k.value = scale_k.value.at[page_idx, offset].set(ks)
             scale_v.value = scale_v.value.at[page_idx, offset].set(vs)
         k_pages.value = k_pages.value.at[page_idx, offset].set(
-            k_in.astype(store_dtype))
+            k_in.astype(store_dtype).reshape(batch, seq, width))
         v_pages.value = v_pages.value.at[page_idx, offset].set(
-            v_in.astype(store_dtype))
+            v_in.astype(store_dtype).reshape(batch, seq, width))
         length.value = idx + seq
         if seq == 1:
             return paged_ops.paged_decode_attention(
@@ -503,19 +514,20 @@ def prefix_rows_from_pages(layer_cache: dict, page_ids,
     all layers), so one id list reconstructs the prefix in each layer.
 
     layer_cache: one attention layer's paged leaves (k_pages
-    [P, page, H, D], v_pages, and the int8 k_page_scales/v_page_scales
+    [P, page, H*D], v_pages, and the int8 k_page_scales/v_page_scales
     [P, page, H] when present). page_ids: [n] int32 page indices —
     entries past the true prefix may point at the scratch page; their
     garbage rows are masked-on-read by the dense cache's index leaf.
-    Returns {"k": [n*page, H, D], "v": ..., ("k_scale": [n*page, H],
+    Returns {"k": [n*page, H*D], "v": ..., ("k_scale": [n*page, H],
     "v_scale": ...)} in the pool's storage dtype (int8 rows + fp32
     scales pass through untouched, so a shared prefix dequantizes to
-    exactly the bytes the original prefill produced)."""
-    k = layer_cache["k_pages"][page_ids]          # [n, page, H, D]
+    exactly the bytes the original prefill produced); the caller
+    unfolds the heads ([n*page, H, D]) for its dense cache."""
+    k = layer_cache["k_pages"][page_ids]          # [n, page, H*D]
     rows = k.shape[0] * page
-    out = {"k": k.reshape(rows, *k.shape[2:]),
+    out = {"k": k.reshape(rows, k.shape[-1]),
            "v": layer_cache["v_pages"][page_ids].reshape(
-               rows, *k.shape[2:])}
+               rows, k.shape[-1])}
     if "k_page_scales" in layer_cache:
         ks = layer_cache["k_page_scales"][page_ids]
         out["k_scale"] = ks.reshape(rows, ks.shape[-1])

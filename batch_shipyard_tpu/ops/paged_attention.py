@@ -17,12 +17,16 @@ compute). Online softmax accumulates across the (sequential) page grid
 dimension in VMEM scratch — the flash-attention recurrence over the
 page list.
 
-One program handles ALL heads of one page: the pool stays
-[P, page, H, D] in HBM and is blocked as its free reshape
+One program handles ALL heads of one page: the pool is blocked as
 [P, page, H*D], so the block's last two dims are (page, H*D) — lane
 dense, and legal under Mosaic's (8, 128) block rule for bf16 and int8
 alike (a per-head block would squeeze the second-minor H dimension,
-which Mosaic refuses). Per-head scores come from ONE matmul against a
+which Mosaic refuses). That is also how the serving cache STORES the
+pool (models/transformer.py): the reshape from [P, page, H, D] is free
+only on paper — the TPU tiles the two shapes differently, so a pool
+kept [P, page, H, D] was relaid out whole (a read and a write of
+every page) on its way into every call. A 4-D pool is still accepted
+here and folded. Per-head scores come from ONE matmul against a
 block-diagonal query (row h holds q_h in columns h*D..(h+1)*D, zeros
 elsewhere): K[page, H*D] x q_bd[H, H*D]^T -> [page, H]. That puts
 positions on sublanes and heads on lanes, which is exactly the layout
@@ -164,7 +168,8 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                   lengths, k_scales=None,
                                   v_scales=None):
     """Pallas path. q: [B, 1, H, D]; k_pages/v_pages:
-    [P, page, H, D]; block_table: [B, max_blocks] int32; lengths: [B]
+    [P, page, H*D] (or [P, page, H, D], folded here at the cost of a
+    relayout); block_table: [B, max_blocks] int32; lengths: [B]
     int32 valid-key counts (INCLUDING the token written this step, so
     every attended slot has length >= 1 — a length-0 slot yields zeros
     here but softmax-of-all-masked garbage from the XLA path; the
@@ -173,7 +178,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     (applied in-kernel per tile). Returns [B, 1, H, D] in q.dtype."""
     batch, seq, heads, depth = q.shape
     assert seq == 1, "decode consumes one token per call"
-    num_pages, page, _heads, _depth = k_pages.shape
+    num_pages, page = k_pages.shape[:2]
     max_blocks = block_table.shape[1]
     width = heads * depth
     int8_pages = k_scales is not None

@@ -572,6 +572,39 @@ def test_jax_donated_reuse_fires_on_stale_read():
     assert not _rules_of(blessed, "jax-donated-reuse")
 
 
+def test_jax_donated_reuse_reads_donate_argnames():
+    """The spelling of the serving step programs: names, resolved
+    against the jitted function's own parameters (statics counted)."""
+    header = (
+        "import functools, jax\n"
+        "@functools.partial(jax.jit, static_argnames=('model',),\n"
+        "                   donate_argnames=('cache',))\n"
+        "def step(model, params, cache, tokens):\n"
+        "    return cache, tokens\n")
+    firing = {"batch_shipyard_tpu/mod.py": header + (
+        "def loop(model, params, cache, tokens):\n"
+        "    new, tokens = step(model, params, cache, tokens)\n"
+        "    return new, cache['k']\n")}
+    found = _rules_of(firing, "jax-donated-reuse")
+    assert len(found) == 1 and found[0].line == 8
+    blessed = {"batch_shipyard_tpu/mod.py": header + (
+        "def loop(model, params, cache, tokens):\n"
+        "    cache, tokens = step(model, params, cache, tokens)\n"
+        "    return cache, params\n")}
+    assert not _rules_of(blessed, "jax-donated-reuse")
+    # The assignment idiom over a function the module defines.
+    assigned = {"batch_shipyard_tpu/mod.py": (
+        "import jax\n"
+        "def _step(params, cache):\n"
+        "    return cache\n"
+        "step = jax.jit(_step, donate_argnames='cache')\n"
+        "def loop(params, cache):\n"
+        "    new = step(params, cache)\n"
+        "    return new, cache\n")}
+    found = _rules_of(assigned, "jax-donated-reuse")
+    assert len(found) == 1 and found[0].line == 7
+
+
 def test_jax_restore_no_drain_fires_without_wait():
     firing = {"batch_shipyard_tpu/workloads/mod.py": (
         "from batch_shipyard_tpu.workloads.checkpoint import (\n"

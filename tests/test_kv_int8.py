@@ -130,8 +130,11 @@ def test_int8_paged_pool_leaves_and_engine(params):
     assert leaves["k_pages"].dtype == jnp.int8
     assert leaves["v_pages"].dtype == jnp.int8
     assert leaves["k_page_scales"].dtype == jnp.float32
-    assert leaves["k_page_scales"].shape == \
-        leaves["k_pages"].shape[:3]
+    # The pool folds the heads into its rows ([P, page, H*D], as the
+    # decode kernel blocks it); the scales keep one per head.
+    pages, page, width = leaves["k_pages"].shape
+    assert width == CFG.n_heads * CFG.d_head
+    assert leaves["k_page_scales"].shape == (pages, page, CFG.n_heads)
     for i in range(3):
         engine.submit(serving.Request(f"p{i}", [3 + i, 7, 11],
                                       max_new_tokens=6))

@@ -62,7 +62,9 @@ def _fused(x, s, w):
 def _paged_args(dtype, heads, page, int8=False, batch=8, depth=64,
                 max_blocks=8):
     pages = batch * max_blocks + 1
-    pool = ((pages, page, heads, depth), i8 if int8 else dtype)
+    # The pool as models/transformer.py stores it: heads folded into
+    # the rows, the layout the kernel blocks.
+    pool = ((pages, page, heads * depth), i8 if int8 else dtype)
     args = [((batch, 1, heads, depth), dtype), pool, pool,
             ((batch, max_blocks), i32), ((batch,), i32)]
     if int8:
@@ -280,3 +282,90 @@ def test_train_kernels_partition_on_four_chips(v5e_devices,
     # share of batch x heads (8*16/4 = 32 rows), never all 128.
     assert "bf16[32,2048,64]" in text
     assert "bf16[128,2048,64]" not in text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "shared"])
+def test_serving_steps_update_the_pool_in_place_on_v5e(v5e_devices,
+                                                       program):
+    """The serving step programs at the benchmark configuration's
+    widths and pool (Baichuan-7B: 32 heads of 128, FFN 11008, 48
+    slots, 192 pages of 64 tokens; two layers, so that it compiles in
+    seconds), compiled for the v5e: every leaf of the donated cache
+    is aliased input to output, no second pool sits in temp, and no
+    operation but the in-place row writes (and the kernel's reads)
+    touches a whole pool leaf. The leaves are 101 MB each and there
+    are 32 of them at 16 layers: a surviving whole-leaf copy
+    (undonated cache) or reshape (a pool stored [P, page, H, D] and
+    relaid out into the kernel's [P, page, H*D]) was a third of the
+    decode step's device time."""
+    import dataclasses
+    import re
+
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import serving
+    from batch_shipyard_tpu.models import transformer as tfm
+
+    slots, max_len, page, pages = 48, 2048, 64, 192
+    chip = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    config = tfm.TransformerConfig(
+        vocab_size=64000, d_model=4096, n_layers=2, n_heads=32,
+        d_head=128, d_ff=11008, max_seq_len=max_len, dtype=bf16,
+        param_dtype=bf16)
+    dense = tfm.TransformerLM(inf.decode_config(config, max_len))
+    paged = tfm.TransformerLM(dataclasses.replace(
+        dense.config, kv_page_size=page, kv_num_pages=pages + 1,
+        paged_attention_impl="kernel"))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=chip), tree)
+
+    def arg(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: tfm.TransformerLM(config).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), i32))["params"]))
+    cache = on_chip(jax.eval_shape(
+        lambda: inf.init_cache(paged, None, slots)))
+    row = arg((max_len // page,))
+    if program == "decode":
+        lowered = serving._decode_step.lower(
+            paged, inf.SamplingConfig(temperature=0.0), params, cache,
+            arg((slots, 1)), arg((slots,)), arg((slots,), jnp.bool_),
+            arg((2,), jnp.uint32))
+    elif program == "prefill":
+        lowered = serving._prefill_paged.lower(
+            dense, None, page, params, cache, 0, arg((1, 256)), row,
+            200)
+    else:
+        lowered = serving._prefill_paged_shared.lower(
+            dense, None, page, params, cache, 0, arg((1, 256)), row,
+            row, row, 128, 328)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if program == "decode":
+        assert "tpu_custom_call" in text
+    leaves = jax.tree_util.tree_leaves(cache)
+    cache_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in leaves)
+    memory = compiled.memory_analysis()
+    # (the small integer leaves are padded to whole tiles on the chip)
+    assert 0 <= memory.alias_size_in_bytes - cache_bytes < 2 ** 20
+    assert memory.temp_size_in_bytes < cache_bytes / 2
+    whole_leaf = re.compile(
+        r"= bf16\[%d,%d,(?:4096|32,128)\]\S* ([\w-]+)\("
+        % (pages + 1, page))
+    touching = {}
+    for op in whole_leaf.findall(text):
+        touching[op] = touching.get(op, 0) + 1
+    writes = (touching.get("scatter", 0) +
+              touching.get("dynamic-update-slice", 0))
+    assert writes >= 4, touching         # K and V of both layers
+    # Each in-place write is the root of one fusion; nothing else
+    # (copy, copy-start/-done, reshape, transpose, ...) is the size
+    # of a leaf.
+    assert set(touching) <= {"parameter", "scatter", "fusion",
+                             "dynamic-update-slice"}, touching
+    assert touching.get("fusion", 0) == writes, touching
