@@ -1,5 +1,9 @@
 """The comparison that decides ``correct``, against the plain
-reference, outside the timed window.
+reference, outside the timed window. Which reference is the
+configuration's own matter: its model module
+(benchmark/models/<model_module>.py) gives ``teacher_forced_logits``
+from its file under benchmark/reference/, and nothing here names an
+architecture.
 
 The timed path yields TOKENS (greedy), not logits, and with seeded
 random weights near-ties are common, so token equality is not a test.
@@ -35,8 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference import plain
-
 SEQ_BUCKET = 256       # teacher-forced sequences are padded to these
 ROW_BUCKET = 64        # ... and the rows read out to these
 
@@ -54,13 +56,12 @@ def _row_readings(logits, picked):
     return best - at, best
 
 
-def serve_gaps(params, dims: dict, model: dict, finished: list) -> dict:
-    """Teacher-force every finished request through the reference.
+def serve_gaps(params, model_module, config: dict, dims: dict,
+               finished: list) -> dict:
+    """Teacher-force every finished request through the reference of
+    the configuration's model module.
     -> {"gaps", "best": one entry per served token, "request": the
         request's idx per token, "requests": n}."""
-    kwargs = dict(n_layers=dims["n_layers"], n_heads=dims["n_heads"],
-                  eps=float(model["rms_norm_eps"]),
-                  theta=float(model["rope_theta"]))
     out = {"gaps": [], "best": [], "request": []}
     # longest first: the few large programs compile (or load) first
     for request in sorted(finished, key=lambda r: (
@@ -76,8 +77,8 @@ def serve_gaps(params, dims: dict, model: dict, finished: list) -> dict:
         rows += [rows[-1]] * (_pad(n, ROW_BUCKET) - n)
         picked = jnp.asarray(served + [served[-1]] * (len(rows) - n),
                              jnp.int32)
-        logits = plain.teacher_forced_logits(
-            params, tokens, jnp.asarray(rows, jnp.int32), **kwargs)
+        logits = model_module.teacher_forced_logits(
+            params, tokens, jnp.asarray(rows, jnp.int32), config, dims)
         gaps, best = _row_readings(logits, picked)
         out["gaps"].extend(np.asarray(gaps)[:n].tolist())
         out["best"].extend(np.asarray(best)[:n].tolist())

@@ -7,7 +7,15 @@ own entry (workloads/serve.build_engine -> ContinuousBatcher), a
 throwaway request through every prefill bucket the cell's traffic can
 reach (cold, and as a prefix-shared suffix) and through the decode
 step, the front end, the load generator's start, and the traffic's
-lead-in. Then the window; then, outside it, the reference check."""
+lead-in. Then the window; then, outside it, the reference check.
+
+Nothing here names an architecture: the sizes, the parameter tree, the
+program's model object and the reference come from the model module
+the configuration file names (spec.load_model). And the engine is read
+through its public surface alone (warmup_buckets, occupancy, pending,
+active_request_ids, cancel, prefix_cache_clear, the attributes without
+an underscore), so that a program PR that reshapes the engine's state
+does not have to repair a file it may not edit."""
 
 from __future__ import annotations
 
@@ -18,7 +26,7 @@ import subprocess
 import sys
 import time
 
-from benchmark import (check, flops, harness, stats, tracered,
+from benchmark import (check, harness, spec, stats, tracered,
                        traffic_gen, weights)
 
 LOADGEN = os.path.join(os.path.dirname(os.path.dirname(
@@ -52,22 +60,15 @@ def lean_cache_init():
         inf.init_cache = original
 
 
-def build_engine(ctx, model: dict, params, kv_cache_dtype=None):
+def build_engine(model_module, model: dict, params,
+                 kv_cache_dtype=None):
     """The engine as a user gets it: workloads/serve's parser and
-    build_engine, with the configuration's sizes."""
-    import jax.numpy as jnp
-    from batch_shipyard_tpu.models import transformer as tfm
+    build_engine, with the configuration's sizes and the model object
+    its model module makes of them."""
     from batch_shipyard_tpu.workloads import serve
-    dims = flops.model_dims(model)
     engine_cfg = model["engine"]
-    config = tfm.TransformerConfig(
-        vocab_size=dims["vocab"], d_model=dims["d_model"],
-        n_layers=dims["n_layers"], n_heads=dims["n_heads"],
-        d_head=dims["d_head"], d_ff=dims["d_ff"],
-        max_seq_len=engine_cfg["max_decode_len"],
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-        rope_theta=float(model["rope_theta"]),
-        kv_cache_dtype=kv_cache_dtype)
+    config = model_module.program_model(
+        model, model_module.dims(model), engine_cfg, kv_cache_dtype)
     args = serve.build_parser().parse_args([
         "--num-slots", str(engine_cfg["num_slots"]),
         "--max-decode-len", str(engine_cfg["max_decode_len"]),
@@ -91,10 +92,12 @@ def reachable_buckets(engine, traffic: dict) -> tuple[list, list]:
     low = traffic["prompt_tokens"]["min"]
     high = traffic["prompt_tokens"]["max"]
 
+    buckets = engine.warmup_buckets()    # ascending, the last the cap
+
     def lengths(shortest: int, longest: int) -> list:
         out, n = [], shortest
         while True:
-            bucket = engine._bucket_length(n)
+            bucket = next((b for b in buckets if b >= n), buckets[-1])
             out.append(min(bucket, longest))
             if bucket >= longest:
                 return out
@@ -158,23 +161,16 @@ class StepRecorder:
         annotate = jax.profiler.TraceAnnotation
 
         def step():
-            active = sum(1 for slot in engine._slots
-                         if slot.request is not None)
-            queued = len(engine._queue)
-            # distinct pages: a shared prefix page counts once, not
-            # once for every slot that reads it
-            pages = len({page for i in range(engine.num_slots)
-                         for held in (engine._slot_pages[i],
-                                      engine._slot_shared[i])
-                         for page in held})
-            tokens = sum(len(slot.request.prompt) + len(slot.generated)
-                         for slot in engine._slots
-                         if slot.request is not None)
+            # the engine's own count: a shared prefix page counts
+            # once, not once for every slot that reads it
+            before = engine.occupancy()
             start = time.monotonic()
             with annotate("bench:engine.step"):
                 out = inner()
-            self.steps.append((start, time.monotonic(), active,
-                               pages, queued, tokens))
+            self.steps.append((
+                start, time.monotonic(), before["slots_active"],
+                before["kv_pages_in_use"], before["queued"],
+                before["live_tokens"]))
             return out
 
         engine.step = step
@@ -266,7 +262,7 @@ def _read_spans(path: str) -> list:
     return spans
 
 
-def _observations(ctx, rows, loaded, recorder, spans, engine, model,
+def _observations(ctx, rows, loaded, recorder, spans, engine, dims,
                   profile) -> dict:
     """What the per-layer readers read: named series (window only),
     counters, and the reduced device trace."""
@@ -301,13 +297,13 @@ def _observations(ctx, rows, loaded, recorder, spans, engine, model,
     series["kv_pages_in_use"] = [s[3] for s in steps]
     counters = {
         "num_slots": engine.num_slots,
-        "kv_pages_total": engine._total_pages,
+        "kv_pages_total": engine.occupancy()["kv_pages_total"],
         "window_requests_ok": len(window),
         "request_spans": len(series["queue_wait_ms"]),
     }
-    dims = flops.model_dims(model)
     obs = {"series": series, "counters": counters, "profile": profile,
            "dims": dims, "page_size": engine.page_size,
+           "out_dir": ctx.out_dir,
            "peaks": ctx.peaks, "chips": len(ctx.devices)}
     if profile and recorder:
         # the engine steps that ran inside the traced slice, for the
@@ -330,13 +326,15 @@ class Session:
         self.ctx = ctx
         self.model = harness.merged(ctx.cell.config, ctx.tiny)
         self.traffic = harness.merged(ctx.cell.traffic, ctx.tiny)
-        self.dims = flops.model_dims(self.model)
-        self.params = weights.make_params(self.dims, ctx.seed,
+        self.model_module = spec.load_model(self.model, ctx.root)
+        self.dims = self.model_module.dims(self.model)
+        self.leaves = self.model_module.param_leaves(self.dims)
+        self.params = weights.make_params(self.leaves, ctx.seed,
                                           jnp.bfloat16)
         jax.block_until_ready(self.params)
         t_weights = time.monotonic()
-        self.engine = build_engine(ctx, self.model, self.params,
-                                   kv_cache_dtype)
+        self.engine = build_engine(self.model_module, self.model,
+                                   self.params, kv_cache_dtype)
         warmed = warm_engine(ctx, self.engine, self.traffic,
                              self.dims["vocab"])
         ctx.note(f"set-up: weights {t_weights - t0:.2f}s, engine + "
@@ -351,17 +349,21 @@ class Session:
             self.recorder = StepRecorder(self.engine)
 
     def reseed(self, seed: int) -> None:
-        """Other weights and traffic in the same engine (calibration):
-        same shapes, so nothing compiles."""
+        """Other weights and traffic in the same engine (calibration
+        only, between windows that have drained): same shapes, so
+        nothing compiles."""
         import jax.numpy as jnp
         self.ctx.seed = seed
         self.engine.params = self.params = None
-        self.params = weights.make_params(self.dims, seed,
+        self.params = weights.make_params(self.leaves, seed,
                                           jnp.bfloat16)
         self.engine.params = self.params
         for request_id in self.engine.active_request_ids():
             self.engine.cancel(request_id)
-        self.engine._queue.clear()
+        if self.engine.pending():
+            raise RuntimeError(
+                f"reseed: {self.engine.pending()} requests are still "
+                f"queued; the window before has not drained")
         self.engine.prefix_cache_clear()
 
     def window(self) -> dict:
@@ -437,7 +439,7 @@ class Session:
             obs = _observations(
                 ctx, rows, loaded, self.recorder,
                 _read_spans(os.environ["SHIPYARD_TRACE_FILE"]),
-                engine, self.model, profile)
+                engine, dims, profile)
             obs["counters"]["memory_peak_bytes"] = peak
             if ctx.peaks:
                 obs["counters"]["hbm_bytes"] = ctx.peaks["hbm_bytes"]
@@ -449,8 +451,8 @@ class Session:
         -> numbers, and the per-token readings they were made from."""
         finished = [r for r in rows if r["in_window"] and r["ok"]]
         t_check = time.monotonic()
-        readings = check.serve_gaps(self.params, self.dims, self.model,
-                                    finished)
+        readings = check.serve_gaps(self.params, self.model_module,
+                                    self.model, self.dims, finished)
         return {"numbers": check.gap_numbers(
                     readings["gaps"],
                     float(self.model["check"]["tail_from"])),

@@ -12,11 +12,15 @@ the phases between them add up to the cell's idle share less
 ``uncovered`` and the slice's two ends.
 
 The annotations are read from the xplane file the traced run leaves
-under harness.OUT_DIR/profile: the driver's own reduction keeps only
-``bench:`` host events. No file, no ``serve:`` annotation (a program
-without them) or no device plane (the CPU rehearsal) reads None."""
+under ``profile/`` of its own output directory, which the driver names
+in ``obs["out_dir"]``: the driver's own reduction keeps only ``bench:``
+host events. No directory named, no file, no ``serve:`` annotation (a
+program without them) or no device plane (the CPU rehearsal) reads
+None."""
 
-from benchmark import harness, spec, tracered
+import os
+
+from benchmark import tracered
 
 PREFIX = "serve:"
 UNCOVERED = "uncovered"
@@ -44,8 +48,9 @@ def read(obs, params):
     if not profile:
         return None
     if "phase_idle" not in obs:
-        path = tracered.newest_xplane(
-            str(spec.ROOT / harness.OUT_DIR / "profile"))
+        out_dir = obs.get("out_dir")
+        path = out_dir and tracered.newest_xplane(
+            os.path.join(str(out_dir), "profile"))
         obs["phase_idle"] = path and split(
             tracered.from_xplane(path, keep_host_prefix=PREFIX))
         if obs["phase_idle"]:

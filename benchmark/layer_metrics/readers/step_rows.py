@@ -12,12 +12,14 @@ params {"steps": "with_prefill" | "without_prefill" | "all",
 Only rows whose step began inside the measured window count: the
 rows' ``mono_start`` and the load generator's ``window_start`` are
 both CLOCK_MONOTONIC. The rows and the window are what the traced run
-leaves under harness.OUT_DIR (spans.jsonl, loadgen.json); nothing
+leaves in its own output directory (spans.jsonl, loadgen.json), which
+the driver names in ``obs["out_dir"]``; no directory named, nothing
 there, or a program that writes no such rows, reads None."""
 
 import json
+import pathlib
 
-from benchmark import harness, spec, stats
+from benchmark import stats
 
 ROW_KIND = "serve_step"
 STEPS = {"with_prefill": lambda attrs: attrs["prefills"] > 0,
@@ -82,7 +84,9 @@ def describe(rows: list, phases: tuple) -> str:
 
 def read(obs, params):
     if "step_rows" not in obs:
-        obs["step_rows"] = window_rows(spec.ROOT / harness.OUT_DIR)
+        out_dir = obs.get("out_dir")
+        obs["step_rows"] = window_rows(pathlib.Path(out_dir)) \
+            if out_dir else ([], 0.0)
         rows = obs["step_rows"][0]
         if rows:
             phases = tuple(key[:-3] for key in rows[0]

@@ -1,9 +1,12 @@
-"""The plain reference of every configuration here: a dense decoder
-block (multi-head attention with rotary positions in the half-split
-layout, RMSNorm before attention and before the MLP, SwiGLU, no
-biases), written from the block's equations in straightforward
-jax.numpy, float32, matmuls at precision "highest". No kernels, no
-cache, no batching, and nothing imported from batch_shipyard_tpu.
+"""The plain reference of the dense decoder block (multi-head
+attention with rotary positions in the half-split layout, RMSNorm
+before attention and before the MLP, SwiGLU, no biases): the reference
+of the configurations whose ``model_module`` is ``dense_mha``
+(benchmark/models/dense_mha.py calls it); another architecture brings
+a file of its own beside this one. Written from the block's equations
+in straightforward jax.numpy, float32, matmuls at precision "highest".
+No kernels, no cache, no batching, and nothing imported from
+batch_shipyard_tpu.
 
 Departure from the published Baichuan-7B, followed here because the
 program makes it: the output head is the TRANSPOSED EMBEDDING (tied),

@@ -4,8 +4,11 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own, found by the NAME in
 BENCHMARK.json: ``configs`` entries carry their ``file``; a cell's
 ``traffic`` is ``<path>/traffic/<traffic>.json``; a per-layer metric is
-``<path>/layer_metrics/<name>.json``. A later PR adds files and
-entries and edits none of this code."""
+``<path>/layer_metrics/<name>.json``. A configuration's MODEL is found
+the same way: its file names a module under ``model_module``, which is
+``<path>/models/<model_module>.py`` (``load_model``), and that module
+is all the harness knows about an architecture. A later PR adds files
+and entries and edits none of this code."""
 
 from __future__ import annotations
 
@@ -19,6 +22,11 @@ from typing import Optional
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# what a model module has: the sizes from the file's published keys;
+# the parameter tree as a list of leaves with their rules; the
+# program's model object; the float32 reference's logits
+MODEL_FUNCTIONS = ("dims", "param_leaves", "program_model",
+                   "teacher_forced_logits")
 
 
 class SpecError(ValueError):
@@ -42,13 +50,34 @@ def _find(root: pathlib.Path, bench: dict, relative: str
 
 def load_module(root: pathlib.Path, bench: dict, relative: str):
     """The Python file ``relative`` found under ``paths`` (a driver, a
-    reader, a kernel's work function), loaded by path: such modules
-    are found by name from data, never imported by name."""
+    reader, a kernel's work function, a model), loaded by path: such
+    modules are found by name from data, never imported by name."""
     source = _find(root, bench, relative)
     module_spec = importlib.util.spec_from_file_location(
         "benchmark_" + re.sub(r"\W", "_", relative[:-3]), source)
     module = importlib.util.module_from_spec(module_spec)
     module_spec.loader.exec_module(module)
+    return module
+
+
+def load_model(config: dict, root: pathlib.Path = ROOT,
+               bench: Optional[dict] = None):
+    """The model module a configuration file names under
+    ``model_module``: ``<path>/models/<model_module>.py``, with every
+    one of MODEL_FUNCTIONS. There is no default: a file without the
+    key, a module that is not there and a module that lacks a function
+    are each a SpecError."""
+    bench = bench or load_benchmark(root)
+    name = config.get("model_module")
+    if not isinstance(name, str) or not NAME_RE.match(name):
+        raise SpecError(
+            f"the configuration file has no model_module (a name under "
+            f"models/): {name!r}")
+    module = load_module(root, bench, f"models/{name}.py")
+    missing = [fn for fn in MODEL_FUNCTIONS
+               if not callable(getattr(module, fn, None))]
+    if missing:
+        raise SpecError(f"models/{name}.py lacks {missing}")
     return module
 
 
@@ -87,6 +116,17 @@ def metric_applies(metric: dict, cell_name: str,
     return True
 
 
+def load_config(name: str, root: pathlib.Path = ROOT,
+                bench: Optional[dict] = None) -> dict:
+    """The configuration file of the ``configs`` entry ``name``."""
+    bench = bench or load_benchmark(root)
+    entries = [c for c in bench["configs"] if c["name"] == name]
+    if not entries:
+        raise SpecError(f"configuration {name!r} is not in "
+                        f"BENCHMARK.json")
+    return _load_json(root / entries[0]["file"])
+
+
 def load_cell(name: str, root: pathlib.Path = ROOT,
               bench: Optional[dict] = None) -> Cell:
     bench = bench or load_benchmark(root)
@@ -96,9 +136,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT,
             f"workload {name!r} is not in BENCHMARK.json (has: "
             f"{[w['name'] for w in bench['workloads']]})")
     entry = entries[0]
-    config_entry = next(c for c in bench["configs"]
-                        if c["name"] == entry["config"])
-    config = _load_json(root / config_entry["file"])
+    config = load_config(entry["config"], root, bench)
     traffic = _load_json(
         _find(root, bench, f"traffic/{entry['traffic']}.json"))
     end_to_end = [m for m in bench["end_to_end"]
@@ -163,6 +201,12 @@ def validate(root: pathlib.Path = ROOT) -> list[str]:
     for config in bench["configs"]:
         if not (root / config["file"]).is_file():
             problems.append(f"missing {config['file']}")
+            continue
+        try:
+            load_model(load_config(config["name"], root, bench), root,
+                       bench)
+        except SpecError as exc:
+            problems.append(f"{config['name']}: {exc}")
     cells = {}
     for workload in bench["workloads"]:
         if workload["config"] not in config_names:

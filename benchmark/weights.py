@@ -5,10 +5,21 @@ eagerly in float32: 14 GB for the serve configuration), and the plain
 reference is handed these same arrays, so neither side takes anything
 the other has made.
 
-Initialisation (listed under ``assumed`` in the configuration files):
-normal, std 1/sqrt(fan_in) for every kernel and 1/sqrt(hidden) for the
-embedding, ones for the RMSNorm scales (kept float32, as the model
-declares them)."""
+This file names no architecture. The parameter tree is a list of
+leaves, ``(path, shape, dtype rule, init rule)``, that the
+configuration's model module gives (benchmark/models/<model_module>.py,
+``param_leaves``), each leaf with its own rules from a small closed
+set:
+
+  dtype rule  "served" (the type the weights are served in) |
+              "float32"
+  init rule   "ones" | "zeros" | ("normal", fan_in): normal with
+              standard deviation 1/sqrt(fan_in), drawn in float32
+
+Leaves are numbered in the sorted order of their paths and leaf ``i``
+draws from ``fold_in(key, i)`` whatever its rule, so that a leaf's
+values depend on the seed and its place alone. Which rule each leaf of
+a model gets is listed under ``assumed`` in its configuration file."""
 
 from __future__ import annotations
 
@@ -17,7 +28,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmark import flops
+DTYPE_RULES = ("served", "float32")
 
 
 def seed_key(seed: int):
@@ -28,18 +39,24 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(low), high)
 
 
-def _leaves(dims: dict) -> list[tuple[tuple, tuple]]:
-    out = []
+def _sorted(leaves: list) -> list:
+    """The leaves in the order they are numbered in, each rule checked
+    against the closed set."""
+    leaves = sorted(leaves, key=lambda leaf: leaf[0])
+    for path, _shape, dtype_rule, init in leaves:
+        normal = (isinstance(init, (tuple, list)) and len(init) == 2
+                  and init[0] == "normal" and init[1] > 0)
+        if dtype_rule not in DTYPE_RULES or not (
+                init in ("ones", "zeros") or normal):
+            raise ValueError(f"leaf {'/'.join(path)}: no rule "
+                             f"{dtype_rule!r}, {init!r}")
+    if len({leaf[0] for leaf in leaves}) != len(leaves):
+        raise ValueError("a leaf path is listed twice")
+    return leaves
 
-    def walk(node, path):
-        if isinstance(node, tuple):
-            out.append((path, node))
-        else:
-            for name in sorted(node):
-                walk(node[name], path + (name,))
 
-    walk(flops.param_shapes(dims), ())
-    return out
+def _dtype(rule: str, served):
+    return jnp.float32 if rule == "float32" else served
 
 
 def _unflatten(pairs) -> dict:
@@ -52,34 +69,35 @@ def _unflatten(pairs) -> dict:
     return tree
 
 
-def abstract_params(dims: dict, dtype) -> dict:
+def abstract_params(leaves: list, dtype) -> dict:
     return _unflatten(
-        (path, jax.ShapeDtypeStruct(
-            shape, jnp.float32 if path[-1] == "scale" else dtype))
-        for path, shape in _leaves(dims))
+        (path, jax.ShapeDtypeStruct(tuple(shape),
+                                    _dtype(dtype_rule, dtype)))
+        for path, shape, dtype_rule, _init in _sorted(leaves))
 
 
-def make_params_fn(dims: dict, dtype):
+def make_params_fn(leaves: list, dtype):
     """key -> the whole parameter tree (to be called under jit)."""
-    leaves = _leaves(dims)
+    leaves = _sorted(leaves)
 
     def build(key):
         pairs = []
-        for i, (path, shape) in enumerate(leaves):
-            if path[-1] == "scale":
-                value = jnp.ones(shape, jnp.float32)
+        for i, (path, shape, dtype_rule, init) in enumerate(leaves):
+            leaf_dtype = _dtype(dtype_rule, dtype)
+            if init == "ones":
+                value = jnp.ones(shape, leaf_dtype)
+            elif init == "zeros":
+                value = jnp.zeros(shape, leaf_dtype)
             else:
-                fan_in = shape[1] if path[-1] == "embedding" \
-                    else shape[0]
                 value = (jax.random.normal(
                     jax.random.fold_in(key, i), shape, jnp.float32)
-                    * (1.0 / math.sqrt(fan_in))).astype(dtype)
+                    * (1.0 / math.sqrt(init[1]))).astype(leaf_dtype)
             pairs.append((path, value))
         return _unflatten(pairs)
 
     return build
 
 
-def make_params(dims: dict, seed: int, dtype) -> dict:
+def make_params(leaves: list, seed: int, dtype) -> dict:
     """The whole parameter tree in one compiled call."""
-    return jax.jit(make_params_fn(dims, dtype))(seed_key(seed))
+    return jax.jit(make_params_fn(leaves, dtype))(seed_key(seed))

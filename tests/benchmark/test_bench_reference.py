@@ -1,76 +1,103 @@
-"""The plain reference agrees with the program at a tiny size on the
-CPU (prefill then decode through the paged cache, cold and
-prefix-shared), and the comparison that decides ``correct`` has been
-shown to fail: for the control, which is the program's own int8 KV
-cache switched on, and for ONE token altered where it is produced."""
+"""The plain reference of EVERY configuration of BENCHMARK.json agrees
+with the program at a tiny size on the CPU (prefill then decode through
+the paged cache, cold and prefix-shared), and the comparison that
+decides ``correct`` has been shown to fail: for the control, which is
+the program's own lower-precision path switched on (the int8 KV
+cache), and for ONE token altered where it is produced.
 
+One case per configuration: the model module is the one its file
+names, and the sizes, the load and the limit are read from
+tests/benchmark/reference_cases/<configuration>.json, so that a
+configuration a later PR adds is tested by adding that file."""
+
+import json
+import pathlib
 import random
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import check, flops, weights
+from benchmark import check, harness, spec, weights
 from benchmark.drivers import serve
-from benchmark.reference import plain
 
-MODEL = {"hidden_size": 128, "num_attention_heads": 2,
-         "intermediate_size": 256, "num_hidden_layers": 4,
-         "vocab_size": 2048, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
-         "engine": {"num_slots": 8, "max_decode_len": 320,
-                    "kv_page_size": 16, "kv_num_pages": 200}}
-DIMS = flops.model_dims(MODEL)
-REQUESTS, NEW_TOKENS = 96, 128      # 12,288 served tokens, as a window
-TAIL_FROM = 0.03
-# Readings at this size (CPU, seeds 1-3, 12,288 tokens each): the
-# bfloat16 engine's gap_tail_mean 6.6e-6, 9.5e-6, 10.2e-6; with its
-# int8 KV cache switched on 33.0e-6, 102.4e-6, 38.5e-6. At 2,048
-# tokens the two overlap (2.6e-6-16.4e-6 against 10.2e-6-124e-6): the
-# statistic needs a window's worth of tokens to resolve an int8 cache.
-LIMITS = {"gap_tail_mean": 1.8e-5}
+CASES = pathlib.Path(__file__).resolve().parent / "reference_cases"
+CONFIGS = [c["name"] for c in spec.load_benchmark()["configs"]]
 
 
-def _serve(seed, kv_cache_dtype=None):
-    from batch_shipyard_tpu.models.serving import Request
-    params = weights.make_params(DIMS, seed, jnp.bfloat16)
-    engine = serve.build_engine(None, MODEL, params, kv_cache_dtype)
-    rng = random.Random(seed)
-    prefix = [rng.randrange(1, DIMS["vocab"]) for _ in range(32)]
-    prompts = {}
-    for i in range(REQUESTS):
-        prompts[f"r{i}"] = prefix + [
-            rng.randrange(1, DIMS["vocab"])
-            for _ in range(rng.randrange(16, 120))]
-        engine.submit(Request(request_id=f"r{i}", prompt=prompts[f"r{i}"],
-                              max_new_tokens=NEW_TOKENS))
-    done = {}
-    while engine.pending():
-        for request_id, tokens in engine.step():
-            done[request_id] = tokens
-    finished = [{"idx": i, "prompt": prompts[r], "tokens": done[r]}
-                for i, r in enumerate(sorted(done))]
-    return params, engine, finished
+class Case:
+    """A configuration at its reference case's sizes."""
+
+    def __init__(self, config_name: str) -> None:
+        path = CASES / f"{config_name}.json"
+        assert path.is_file(), (
+            f"configuration {config_name} has no reference case: add "
+            f"{path.relative_to(spec.ROOT)}")
+        with open(path, encoding="utf-8") as fh:
+            self.data = json.load(fh)
+        self.model = harness.merged(
+            dict(spec.load_config(config_name),
+                 rehearse_tiny=self.data["sizes"]), True)
+        self.module = spec.load_model(self.model)
+        self.dims = self.module.dims(self.model)
+        self.leaves = self.module.param_leaves(self.dims)
+        self.tokens = self.data["requests"] * self.data["new_tokens"]
+        self.limits = self.data["limits"]
+
+    def serve(self, kv_cache_dtype=None):
+        """-> (params, engine, finished): every request of the case
+        through the engine as a run builds it."""
+        from batch_shipyard_tpu.models.serving import Request
+        data, seed, vocab = self.data, self.data["seed"], \
+            self.dims["vocab"]
+        params = weights.make_params(self.leaves, seed, jnp.bfloat16)
+        engine = serve.build_engine(self.module, self.model, params,
+                                    kv_cache_dtype)
+        rng = random.Random(seed)
+        prefix = [rng.randrange(1, vocab)
+                  for _ in range(data["shared_prefix_tokens"])]
+        prompts = {}
+        for i in range(data["requests"]):
+            prompts[f"r{i}"] = prefix + [
+                rng.randrange(1, vocab)
+                for _ in range(rng.randrange(
+                    data["prompt_tokens"]["min"],
+                    data["prompt_tokens"]["below"]))]
+            engine.submit(Request(
+                request_id=f"r{i}", prompt=prompts[f"r{i}"],
+                max_new_tokens=data["new_tokens"]))
+        done = {}
+        while engine.pending():
+            for request_id, tokens in engine.step():
+                done[request_id] = tokens
+        finished = [{"idx": i, "prompt": prompts[r], "tokens": done[r]}
+                    for i, r in enumerate(sorted(done))]
+        return params, engine, finished
+
+    def gaps(self, params, finished) -> dict:
+        return check.serve_gaps(params, self.module, self.model,
+                                self.dims, finished)
+
+    def numbers(self, readings) -> dict:
+        return check.gap_numbers(readings["gaps"],
+                                 self.data["tail_from"])
 
 
-def _numbers(readings):
-    return check.gap_numbers(readings["gaps"], TAIL_FROM)
-
-
-@pytest.fixture(scope="module")
-def served():
-    params, engine, finished = _serve(seed=1)
-    return params, engine, finished, check.serve_gaps(
-        params, DIMS, MODEL, finished)
+@pytest.fixture(scope="module", params=CONFIGS)
+def served(request):
+    case = Case(request.param)
+    params, engine, finished = case.serve()
+    return case, params, engine, finished, case.gaps(params, finished)
 
 
 def test_paged_prefill_and_decode_agree_with_the_reference(served):
-    _params, engine, finished, readings = served
+    case, _params, engine, finished, readings = served
     # every served token of every finished request is read
-    assert len(readings["gaps"]) == REQUESTS * NEW_TOKENS
-    assert readings["requests"] == REQUESTS
+    assert len(readings["gaps"]) == case.tokens
+    assert readings["requests"] == case.data["requests"]
     assert set(readings["request"]) == {r["idx"] for r in finished}
     assert engine.prefix_stats()["hit_tokens"] > 0    # shared path too
-    ok, lines = check.judge(_numbers(readings), LIMITS)
+    ok, lines = check.judge(case.numbers(readings), case.limits)
     assert ok, lines
     # most served tokens ARE the reference's best
     assert sum(1 for g in readings["gaps"] if g == 0.0) > \
@@ -78,19 +105,19 @@ def test_paged_prefill_and_decode_agree_with_the_reference(served):
 
 
 def test_paged_prefill_logits_match_the_reference_directly(served):
-    params, engine, finished, _readings = served
+    case, params, engine, finished, _readings = served
     prompt = finished[0]["prompt"]
-    bucket = engine._bucket_length(len(prompt))
+    bucket = next(b for b in engine.warmup_buckets()
+                  if b >= len(prompt))
     padded = jnp.asarray([prompt + [0] * (bucket - len(prompt))],
                          jnp.int32)
     row = np.full((engine.max_blocks,), engine._scratch_page, np.int32)
     _cache, last = engine._prefill_paged(
         params, engine.cache, 0, padded, jnp.asarray(row), len(prompt))
     tokens = jnp.asarray(prompt + [0] * (-len(prompt) % 64), jnp.int32)
-    want = plain.teacher_forced_logits(
-        params, tokens, jnp.asarray([len(prompt) - 1]),
-        n_layers=DIMS["n_layers"], n_heads=DIMS["n_heads"], eps=1e-6,
-        theta=10000.0)[0]
+    want = case.module.teacher_forced_logits(
+        params, tokens, jnp.asarray([len(prompt) - 1]), case.model,
+        case.dims)[0]
     error = float(jnp.linalg.norm(last - want) / jnp.linalg.norm(want))
     # bfloat16 activations against float32: a few parts in a thousand
     assert error < 0.02, error
@@ -100,27 +127,31 @@ def test_paged_prefill_logits_match_the_reference_directly(served):
 
 def test_the_programs_int8_kv_cache_comes_out_not_correct(served):
     """The control: the same engine, weights and requests with the
-    program's own lower-precision path, kv_cache_dtype="int8"."""
-    sound = _numbers(served[3])
-    params, _engine, finished = _serve(seed=1, kv_cache_dtype="int8")
-    control = _numbers(check.serve_gaps(params, DIMS, MODEL, finished))
-    ok, lines = check.judge(control, LIMITS)
+    program's own lower-precision path (the case's ``control``:
+    kv_cache_dtype="int8")."""
+    case = served[0]
+    sound = case.numbers(served[4])
+    params, _engine, finished = case.serve(**case.data["control"])
+    control = case.numbers(case.gaps(params, finished))
+    ok, lines = check.judge(control, case.limits)
     assert not ok, lines
     assert control["gap_tail_mean"] > 3 * sound["gap_tail_mean"]
 
 
 def test_one_token_altered_where_it_is_produced_is_caught(served):
-    params, _engine, finished, readings = served
+    case, params, _engine, finished, readings = served
     longest = max(finished, key=lambda r: len(r["prompt"]))
     broken = dict(longest, tokens=list(longest["tokens"]))
-    broken["tokens"][5] = (broken["tokens"][5] + 977) % 2048
-    altered = check.serve_gaps(params, DIMS, MODEL, [broken])
+    broken["tokens"][5] = \
+        (broken["tokens"][5] + 977) % case.dims["vocab"]
+    altered = case.gaps(params, [broken])
     assert max(altered["gaps"]) > 1.0
     # ... as the one wrong token among the whole window's
     gaps = [g for g, idx in zip(readings["gaps"], readings["request"])
             if idx != longest["idx"]] + altered["gaps"]
-    assert len(gaps) == REQUESTS * NEW_TOKENS
-    ok, _lines = check.judge(check.gap_numbers(gaps, TAIL_FROM), LIMITS)
+    assert len(gaps) == case.tokens
+    ok, _lines = check.judge(
+        check.gap_numbers(gaps, case.data["tail_from"]), case.limits)
     assert not ok
 
 
@@ -133,8 +164,9 @@ def test_gap_numbers_by_hand():
 
 
 def test_judge_fails_a_missing_or_infinite_number():
-    assert check.judge({"gap_tail_mean": None}, LIMITS)[0] is False
-    assert check.judge({"gap_tail_mean": float("nan")}, LIMITS)[0] \
+    limits = {"gap_tail_mean": 1.8e-5}
+    assert check.judge({"gap_tail_mean": None}, limits)[0] is False
+    assert check.judge({"gap_tail_mean": float("nan")}, limits)[0] \
         is False
     assert check.judge({"gap_tail_mean": 1e-6, "gap_max": 9.0},
-                       LIMITS)[0]      # gap_max has no limit
+                       limits)[0]      # gap_max has no limit

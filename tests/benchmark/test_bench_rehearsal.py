@@ -1,5 +1,7 @@
-"""run.py end to end at a tiny size on the CPU for each cell
-(--rehearse-tiny): the last line is one JSON object with
+"""run.py end to end at a tiny size on the CPU for EVERY cell of
+BENCHMARK.json, traced and not (--rehearse-tiny; the cases are read
+from ``workloads``, so a cell that a later PR adds is rehearsed with no
+edit here): the last line is one JSON object with
 exactly the contract's keys and no metric at all (a CPU run is never
 printed under a device metric's name); without a TPU run.py exits
 non-zero and prints no result; so it does where only the benchmark's
@@ -18,6 +20,10 @@ from benchmark import spec
 
 RUN = str(spec.ROOT / "benchmark" / "run.py")
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+WORKLOADS = spec.load_benchmark()["workloads"]
+CELLS = [w["name"] for w in WORKLOADS]
+# one cell of each configuration, for what is shown once a model
+CELL_OF_CONFIG = {w["config"]: w["name"] for w in WORKLOADS}
 
 
 def _run(args, cwd=spec.ROOT, devices=1, script=RUN):
@@ -31,11 +37,11 @@ def _run(args, cwd=spec.ROOT, devices=1, script=RUN):
                           timeout=900)
 
 
-@pytest.mark.parametrize("cell,devices,trace", [
-    ("baichuan7b.chat-online", 1, 1),
-    ("baichuan7b.batch-offline", 1, 0),
-])
-def test_rehearsal_runs_end_to_end(cell, devices, trace):
+@pytest.mark.parametrize("trace", (0, 1))
+@pytest.mark.parametrize("workload", WORKLOADS,
+                         ids=lambda w: w["name"])
+def test_rehearsal_runs_end_to_end(workload, trace):
+    cell, devices = workload["name"], workload["chips"]
     done = _run(["--workload", cell, "--seed", str(2**31 + 77),
                  "--seconds", "3", "--trace", str(trace),
                  "--rehearse-tiny"], devices=devices)
@@ -56,7 +62,7 @@ def test_rehearsal_runs_end_to_end(cell, devices, trace):
 
 
 def test_without_a_tpu_it_exits_non_zero_and_prints_no_result():
-    done = _run(["--workload", "baichuan7b.chat-online", "--seed", "1",
+    done = _run(["--workload", CELLS[0], "--seed", "1",
                  "--seconds", "3", "--trace", "0"])
     assert done.returncode != 0
     assert "no accelerator" in done.stderr
@@ -69,7 +75,7 @@ def test_with_only_its_own_files_it_exits_non_zero(tmp_path):
     for path in bench["paths"]:
         shutil.copytree(spec.ROOT / path, tmp_path / path,
                         ignore=shutil.ignore_patterns("__pycache__"))
-    done = _run(["--workload", "baichuan7b.chat-online", "--seed", "1",
+    done = _run(["--workload", CELLS[0], "--seed", "1",
                  "--seconds", "3", "--trace", "0", "--rehearse-tiny"],
                 cwd=tmp_path,
                 script=str(tmp_path / "benchmark" / "run.py"))
@@ -77,10 +83,13 @@ def test_with_only_its_own_files_it_exits_non_zero(tmp_path):
     assert not any(l.startswith("{") for l in done.stdout.splitlines())
 
 
-def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capsys):
+@pytest.mark.parametrize("cell", sorted(CELL_OF_CONFIG.values()))
+def test_a_broken_timed_path_comes_out_not_correct(cell, monkeypatch,
+                                                   capsys):
     """The rest of a run driven in-process (the look for a chip
     skipped by --rehearse-tiny), with the decode step altering every
-    token it produces."""
+    token it produces: one cell of every configuration, so that each
+    model's reference is shown to catch it."""
     import importlib.util
     import jax.numpy as jnp
     from batch_shipyard_tpu.models import serving
@@ -100,7 +109,7 @@ def test_a_broken_timed_path_comes_out_not_correct(monkeypatch, capsys):
     module_spec = importlib.util.spec_from_file_location("bench_run", RUN)
     run = importlib.util.module_from_spec(module_spec)
     module_spec.loader.exec_module(run)
-    code = run.main(["--workload", "baichuan7b.batch-offline",
+    code = run.main(["--workload", cell,
                      "--seed", "9", "--seconds", "2", "--trace", "0",
                      "--rehearse-tiny"])
     assert code == 0

@@ -10,7 +10,7 @@ import shutil
 
 import pytest
 
-from benchmark import harness, layers, spec
+from benchmark import layers, spec
 
 FIXTURE = spec.ROOT / "benchmark/testdata/step_phases"
 NEW_METRICS = {
@@ -131,16 +131,17 @@ def test_both_readers_read_none_where_there_is_nothing(trace, tmp_path):
 
 @pytest.mark.parametrize("cell", sorted(NEW_METRICS))
 def test_a_traced_run_reads_the_new_metrics_from_the_out_dir(
-        cell, trace, tmp_path, monkeypatch, capsys):
+        cell, trace, tmp_path, capsys):
     """layers.read_all as run.py calls it: the readers fetch the rows
-    and the window from harness.OUT_DIR themselves. Without an xplane
-    file there the idle metrics are left out; given the split, they
-    are read against the slice the driver's profile names."""
+    and the window from the run's own output directory, which the
+    driver names in ``obs["out_dir"]``. Without an xplane file there
+    the idle metrics are left out; given the split, they are read
+    against the slice the driver's profile names."""
     for name in ("spans.jsonl", "loadgen.json"):
         shutil.copy(FIXTURE / name, tmp_path)
-    monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path))
     loaded = spec.load_cell(cell)
     obs = {"series": {}, "counters": {}, "peaks": None,
+           "out_dir": tmp_path,
            "profile": {"events": {}, "window_s": 20000e-9,
                        "busy_s": 12000e-9}}
     got = layers.read_all(loaded, obs)
@@ -154,7 +155,12 @@ def test_a_traced_run_reads_the_new_metrics_from_the_out_dir(
     assert got[idle_admit] == {"value": pytest.approx(15.0),
                                "unit": "%"}
     # an untraced-program run: nothing under the out dir
-    monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path / "empty"))
+    assert layers.read_all(loaded, {"series": {}, "counters": {},
+                                    "peaks": None, "profile": None,
+                                    "out_dir": tmp_path / "empty"}) \
+        == {}
+    # ... and no directory named: whatever another run left in the
+    # checkout's own is not this run's to read
     assert layers.read_all(loaded, {"series": {}, "counters": {},
                                     "peaks": None, "profile": None}) \
         == {}
