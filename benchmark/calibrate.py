@@ -4,8 +4,15 @@
     python benchmark/calibrate.py --workload <cell> --seconds <s> \
         [--rates r1,r2,...]       the rate sweep that finds the knee
         [--seeds a,b,c,...]       the check's numbers, seed by seed
-        [--kv-int8]               the control: the program's own int8
-                                  KV cache switched on
+        [--control [N]]           the configuration's control (its
+                                  ``check.control``, the N-th where it
+                                  lists several; 0 if N is left out):
+                                  overrides for the model module's
+                                  program_model, and under "decisions"
+                                  what is done to the engine's record
+        [--kv-int8]               the control {"kv_cache_dtype":
+                                  "int8"}: the program's own int8 KV
+                                  cache switched on
         [--dump DIR]              every served token's readings, one
                                   JSON file a window
 
@@ -55,12 +62,14 @@ def main(argv=None) -> int:
     parser.add_argument("--seconds", type=float, required=True)
     parser.add_argument("--rates", default="")
     parser.add_argument("--seeds", default="1")
+    parser.add_argument("--control", type=int, nargs="?", const=0,
+                        default=None)
     parser.add_argument("--kv-int8", action="store_true")
     parser.add_argument("--dump", default="")
     parser.add_argument("--rehearse-tiny", action="store_true")
     args = parser.parse_args(argv)
 
-    from benchmark import harness, peaks, spec
+    from benchmark import check, harness, peaks, spec
     cell = spec.load_cell(args.workload, ROOT)
     harness.place_compile_cache(ROOT)     # before anything imports jax
     from benchmark.drivers import serve
@@ -74,8 +83,13 @@ def main(argv=None) -> int:
     except (harness.NoChip, peaks.UnknownDevice) as exc:
         print(f"calibrate: {exc}", file=sys.stderr)
         return 2
-    session = serve.Session(
-        ctx, kv_cache_dtype="int8" if args.kv_int8 else None)
+    control = {"kv_cache_dtype": "int8"} if args.kv_int8 else None
+    if args.control is not None:
+        stated = cell.config["check"].get("control")
+        if stated is None:
+            parser.error(f"{cell.config_name} states no check.control")
+        control = check.controls(stated)[args.control]
+    session = serve.Session(ctx, control=control)
     rates = [float(r) for r in args.rates.split(",") if r] or [None]
     first = True
     for seed in seeds:
@@ -87,7 +101,7 @@ def main(argv=None) -> int:
                 session.traffic["arrivals"]["rate_per_s"] = rate
             measured = session.window()
             line = {"seed": seed, "rate_per_s": rate,
-                    "kv_int8": args.kv_int8,
+                    "control": control,
                     **{k: v for k, v in measured["values"].items()
                        if k != "setup_s"},
                     **_halves(measured["rows"])}
@@ -99,7 +113,8 @@ def main(argv=None) -> int:
                 line["check_seconds"] = checked["seconds"]
                 if args.dump:
                     os.makedirs(args.dump, exist_ok=True)
-                    name = (f"{args.workload}.{'int8' if args.kv_int8 else 'sound'}"
+                    name = (f"{args.workload}."
+                            f"{'control' if control else 'sound'}"
                             f".{seed}.json")
                     with open(os.path.join(args.dump, name), "w",
                               encoding="utf-8") as fh:

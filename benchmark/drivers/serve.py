@@ -14,8 +14,10 @@ program's model object and the reference come from the model module
 the configuration file names (spec.load_model). And the engine is read
 through its public surface alone (warmup_buckets, occupancy, pending,
 active_request_ids, cancel, prefix_cache_clear, the attributes without
-an underscore), so that a program PR that reshapes the engine's state
-does not have to repair a file it may not edit."""
+an underscore, and for a model that declares decisions the optional
+take_decisions, found with getattr: benchmark/check.py has its shape),
+so that a program PR that reshapes the engine's state does not have to
+repair a file it may not edit."""
 
 from __future__ import annotations
 
@@ -60,15 +62,16 @@ def lean_cache_init():
         inf.init_cache = original
 
 
-def build_engine(model_module, model: dict, params,
-                 kv_cache_dtype=None):
+def build_engine(model_module, model: dict, params, **overrides):
     """The engine as a user gets it: workloads/serve's parser and
     build_engine, with the configuration's sizes and the model object
-    its model module makes of them."""
+    its model module makes of them. ``overrides`` are a control's
+    keyword arguments for the module's program_model (the program's
+    own lower-precision path switched on)."""
     from batch_shipyard_tpu.workloads import serve
     engine_cfg = model["engine"]
     config = model_module.program_model(
-        model, model_module.dims(model), engine_cfg, kv_cache_dtype)
+        model, model_module.dims(model), engine_cfg, **overrides)
     args = serve.build_parser().parse_args([
         "--num-slots", str(engine_cfg["num_slots"]),
         "--max-decode-len", str(engine_cfg["max_decode_len"]),
@@ -249,6 +252,22 @@ def _end_to_end(rows: list, mode: str, loaded: dict) -> dict:
         arrivals = [t for r in rows for t in r["token_times"]]
         out["serve_tokens_per_s"] = stats.rate_in_window(
             arrivals, ws, we)
+    # Printed for the reader, not metrics: a far-off run (PERF.md,
+    # section 7) says by these whether it had a stall, of what kind
+    # and when: the longest wait between two tokens of one reply
+    # inside the window, and the longest a request launched inside it
+    # took to connect and send.
+    gaps = [(after - before, before - ws) for r in rows
+            for before, after in zip(r["token_times"],
+                                     r["token_times"][1:])
+            if ws <= before and after < we]
+    connects = [(r["sent"] - r["launched"], r["launched"] - ws)
+                for r in rows if r["sent"] is not None
+                and ws <= r["launched"] < we]
+    for name, pairs in (("token_gap", gaps), ("connect", connects)):
+        longest, at = max(pairs, default=(0.0, 0.0))
+        out[f"{name}_max_ms"] = longest * 1e3
+        out[f"{name}_max_at_s"] = at
     return out
 
 
@@ -314,27 +333,57 @@ def _observations(ctx, rows, loaded, recorder, spans, engine, dims,
     return obs
 
 
+def take_decisions(engine, layers: list, finished: dict,
+                   record_control=None, seed: int = 0) -> None:
+    """For a model that declares decision ``layers``: each finished
+    request's record of the timed path's own choices, asked of the
+    engine once, by the request's id ({id: row}), and kept on its row
+    under "decisions". An engine without the method gives none, and
+    the check then comes out not correct. ``record_control``
+    ({"reroute_share": s}) is a control that corrupts what was handed
+    over, before the check sees it."""
+    if not layers:
+        return
+    take = getattr(engine, "take_decisions", None)
+    share = float((record_control or {}).get("reroute_share", 0))
+    for request_id, row in finished.items():
+        record = take(request_id) if take else None
+        if record is not None and share:
+            record = check.reroute(record, layers, share,
+                                   seed + row["idx"])
+        row["decisions"] = record
+
+
 class Session:
     """One engine, warmed for the cell's traffic. ``run`` uses it for
     one window; benchmark/calibrate.py for several (other seeds, other
     rates) without paying the set-up again."""
 
-    def __init__(self, ctx, kv_cache_dtype=None) -> None:
+    def __init__(self, ctx, control=None, build=build_engine) -> None:
+        """``control``: a control's overrides (a configuration's
+        ``check.control``): keyword arguments of the module's
+        program_model, and under "decisions" what is done to the
+        engine's record before the check ({"reroute_share": s}).
+        ``build`` makes the engine (a test hands its own)."""
         import jax
         import jax.numpy as jnp
         t0 = time.monotonic()
         self.ctx = ctx
+        control = dict(control or {})
+        self.record_control = control.pop("decisions", None)
         self.model = harness.merged(ctx.cell.config, ctx.tiny)
         self.traffic = harness.merged(ctx.cell.traffic, ctx.tiny)
         self.model_module = spec.load_model(self.model, ctx.root)
         self.dims = self.model_module.dims(self.model)
+        self.decision_layers = spec.decision_layers(
+            self.model_module, self.model, self.dims)
         self.leaves = self.model_module.param_leaves(self.dims)
         self.params = weights.make_params(self.leaves, ctx.seed,
                                           jnp.bfloat16)
         jax.block_until_ready(self.params)
         t_weights = time.monotonic()
-        self.engine = build_engine(self.model_module, self.model,
-                                   self.params, kv_cache_dtype)
+        self.engine = build(self.model_module, self.model,
+                            self.params, **control)
         warmed = warm_engine(ctx, self.engine, self.traffic,
                              self.dims["vocab"])
         ctx.note(f"set-up: weights {t_weights - t0:.2f}s, engine + "
@@ -417,6 +466,11 @@ class Session:
         with open(out_path, encoding="utf-8") as fh:
             loaded = json.load(fh)
         rows = _request_rows(plan, loaded, plan["mode"])
+        take_decisions(
+            engine, self.decision_layers,
+            {f"bench-{r['idx']}": r for r in rows
+             if r["in_window"] and r["ok"]},
+            self.record_control, ctx.seed)
         values = _end_to_end(rows, plan["mode"], loaded)
         values["setup_s"] = window_start - ctx.started
         ctx.note(f"window: {json.dumps(values)}; prefix cache "
@@ -447,23 +501,31 @@ class Session:
                 "profile": profile, "obs": obs}
 
     def check(self, rows: list) -> dict:
-        """The reference over every request the window finished.
-        -> numbers, and the per-token readings they were made from."""
+        """The reference over the requests the window finished: all of
+        them, or as many as the configuration's
+        ``check.served_tokens_at_most`` takes. -> numbers, and the
+        readings they were made from."""
         finished = [r for r in rows if r["in_window"] and r["ok"]]
+        section = self.model["check"]
         t_check = time.monotonic()
-        readings = check.serve_gaps(self.params, self.model_module,
-                                    self.model, self.dims, finished)
-        return {"numbers": check.gap_numbers(
-                    readings["gaps"],
-                    float(self.model["check"]["tail_from"])),
+        readings = check.serve_gaps(
+            self.params, self.model_module, self.model, self.dims,
+            finished, self.decision_layers,
+            section.get("served_tokens_at_most"), self.ctx.seed)
+        numbers = check.gap_numbers(readings["gaps"],
+                                    float(section["tail_from"]))
+        if self.decision_layers:
+            numbers.update(check.routing_numbers(
+                readings, float(section["slack_from"])))
+        return {"numbers": numbers,
                 "requests": readings["requests"],
                 "tokens": len(readings["gaps"]),
                 "readings": readings,
                 "seconds": time.monotonic() - t_check}
 
 
-def run(ctx) -> dict:
-    session = Session(ctx)
+def run(ctx, build=build_engine) -> dict:
+    session = Session(ctx, build=build)
     measured = session.window()
     # The reference check, outside the window. The pool is dropped
     # first so that the reference fits beside the weights.
@@ -473,19 +535,41 @@ def run(ctx) -> dict:
     with open(ctx.out_dir / "check_readings.json", "w",
               encoding="utf-8") as fh:
         json.dump(checked["readings"], fh)   # every token's, to look at
-    correct, lines = check.judge(checked["numbers"],
-                                 session.model["check"]["limits"])
+    numbers, readings = checked["numbers"], checked["readings"]
+    limits = session.model["check"]["limits"]
+    correct, lines = check.judge(numbers, limits)
     for line in lines:
         ctx.note(line)
     ctx.note(f"check: {checked['requests']} requests, "
              f"{checked['tokens']} served tokens against the float32 "
              f"reference in {checked['seconds']:.2f}s; without a limit: "
-             f"gap_max {checked['numbers']['gap_max']!r}, "
-             f"gap_mean {checked['numbers']['gap_mean']!r}")
+             f"gap_max {numbers['gap_max']!r}, "
+             f"gap_mean {numbers['gap_mean']!r}")
+    if readings["requests"] != readings["requests_finished"]:
+        ctx.note(f"check: requests_checked {readings['requests']} of "
+                 f"requests_finished {readings['requests_finished']} "
+                 f"(check.served_tokens_at_most)")
+    if session.decision_layers:
+        ctx.note(f"check: the reference ran on the timed path's own "
+                 f"choices in {len(session.decision_layers)} layers: "
+                 f"{len(readings['slack'])} judged, positions_unrecorded "
+                 f"{readings['positions_unrecorded']} of "
+                 f"{readings['positions']}; without a limit: "
+                 f"routing_flip_share {numbers['routing_flip_share']!r}"
+                 f", slack_max {numbers['slack_max']!r}")
+        if readings["requests_without_record"]:
+            ctx.note(f"check: NOT CORRECT: the model declares "
+                     f"decisions and the engine gave no record of them "
+                     f"(take_decisions) for "
+                     f"{readings['requests_without_record']} finished "
+                     f"requests")
     if not checked["requests"]:
         correct = False     # nothing finished: nothing was shown
     values = measured["values"]
     return {"correct": correct, "attempted": values["attempted"],
             "failed": values["failed"], "values": values,
+            "compared": {name: {"value": numbers.get(name),
+                                "limit": limit}
+                         for name, limit in sorted(limits.items())},
             "memory_peak_bytes": measured["peak"],
             "obs": measured["obs"], "profile": measured["profile"]}

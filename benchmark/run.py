@@ -8,10 +8,12 @@ cell's chips:
 It makes its inputs and weights from --seed, warms up every shape the
 cell's traffic uses (set-up), measures for --seconds, checks what the
 window produced against the plain reference, prints each number
-compared beside its limit, and prints as its LAST line one JSON object
-with the keys correct, attempted, failed, metrics, device (and
-breakdown with --trace 1). With --trace 0 the metrics are the cell's
-end-to-end metrics; with --trace 1 its per-layer metrics.
+compared beside its limit (as the last lines of its standard error,
+and under ``check``, the last key of the result), and prints as its
+LAST line one JSON object with the keys correct, attempted, failed,
+metrics, device (and breakdown with --trace 1), then check. With
+--trace 0 the metrics are the cell's end-to-end metrics; with
+--trace 1 its per-layer metrics.
 
 Off a TPU, with fewer chips than the cell asks for, on a device_kind
 that benchmark/peaks.json does not hold, or without the rest of the
@@ -27,6 +29,7 @@ _PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import pathlib  # noqa: E402
 import sys  # noqa: E402
 
@@ -105,6 +108,18 @@ def main(argv=None) -> int:
                  f"{json.dumps(metrics)}")
     else:
         line["metrics"] = metrics
+    # each number compared beside its limit: the last lines of the
+    # standard error, and the last key of the result (a number that is
+    # not finite reads null there, so that the line stays JSON)
+    line["check"] = {
+        name: {"value": pair["value"] if pair["value"] is not None
+               and math.isfinite(pair["value"]) else None,
+               "limit": pair["limit"]}
+        for name, pair in outcome["compared"].items()}
+    for name, pair in outcome["compared"].items():
+        print(f"check {name}: {pair['value']!r} (limit <= "
+              f"{pair['limit']!r})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -27,6 +27,10 @@ UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 # program's model object; the float32 reference's logits
 MODEL_FUNCTIONS = ("dims", "param_leaves", "program_model",
                    "teacher_forced_logits")
+# ... and what it MAY have: the layers that make a discrete choice per
+# position (top-k routed experts), which the check then takes from the
+# timed path and has the reference judge (benchmark/check.py)
+DECISIONS = "decision_layers"
 
 
 class SpecError(ValueError):
@@ -79,6 +83,50 @@ def load_model(config: dict, root: pathlib.Path = ROOT,
     if missing:
         raise SpecError(f"models/{name}.py lacks {missing}")
     return module
+
+
+def decision_layers(module, config: dict, dims: dict) -> list:
+    """[(layer name, k, n)]: the layers of a configuration's model
+    that choose k of n per position, as its module declares them with
+    ``decision_layers(config, dims)``; [] for a module that has no
+    such function. A declaration of another shape is a SpecError."""
+    declare = getattr(module, DECISIONS, None)
+    if declare is None:
+        return []
+    declared = declare(config, dims)
+    layers = []
+    try:
+        for name, k, n in declared:
+            if not (isinstance(name, str) and NAME_RE.match(name)
+                    and isinstance(k, int) and isinstance(n, int)
+                    and 1 <= k < n):
+                raise ValueError
+            layers.append((name, k, n))
+    except (TypeError, ValueError):
+        raise SpecError(
+            f"{DECISIONS} has to give [(layer name, k, n)] with "
+            f"1 <= k < n, not {declared!r}") from None
+    if not layers or len({name for name, _k, _n in layers}) != \
+            len(layers):
+        raise SpecError(f"{DECISIONS} gives no layer, or one twice: "
+                        f"{declared!r}")
+    return layers
+
+
+def _check_of_decisions(config: dict) -> list:
+    """What a configuration whose module declares decisions has to
+    state under ``check``: from where a slack counts as rejected, and
+    the limit on the share that is."""
+    section = config.get("check", {})
+    slack_from = section.get("slack_from")
+    problems = []
+    if isinstance(slack_from, bool) or not isinstance(
+            slack_from, (int, float)) or slack_from < 0:
+        problems.append(f"check.slack_from is not a number >= 0: "
+                        f"{slack_from!r}")
+    if "routing_rejected_share" not in section.get("limits", {}):
+        problems.append("check.limits has no routing_rejected_share")
+    return problems
 
 
 def _load_json(path: pathlib.Path) -> dict:
@@ -203,8 +251,11 @@ def validate(root: pathlib.Path = ROOT) -> list[str]:
             problems.append(f"missing {config['file']}")
             continue
         try:
-            load_model(load_config(config["name"], root, bench), root,
-                       bench)
+            data = load_config(config["name"], root, bench)
+            module = load_model(data, root, bench)
+            if decision_layers(module, data, module.dims(data)):
+                problems.extend(f"{config['name']}: {problem}" for
+                                problem in _check_of_decisions(data))
         except SpecError as exc:
             problems.append(f"{config['name']}: {exc}")
     cells = {}

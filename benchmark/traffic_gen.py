@@ -14,7 +14,10 @@ Findings, PR 23). A traffic mix is a data file; this module is not
 edited to add one.
 
 File keys (see benchmark/traffic/*.json):
-  kind                  serve-open | serve-closed  (picks the driver)
+  kind                  serve-open | serve-closed (picks the driver,
+                        drivers/<kind>.py; a kind that begins with one
+                        of the two, as serve-closed-<x>, is that loop
+                        under a driver of its own)
   path_seed             fixes the order of sizes and gaps
   arrivals              {"rate_per_s": r}: exponential gaps on a
                         quantile grid (not a sampled Poisson stream) open
@@ -94,7 +97,7 @@ def generate(traffic: dict, seed: int, seconds: float,
     lead_in = float(traffic.get("lead_in_s", 0))
     prefix_len = int(traffic.get("shared_prefix_tokens", 0))
     prefix = _tokens(_rng(seed, "shared-prefix"), prefix_len, vocab)
-    if kind == "serve-open":
+    if kind.startswith("serve-open"):
         rate = float(traffic["arrivals"]["rate_per_s"])
         requests = []
         for phase, span, start in (("lead", lead_in, -lead_in),
@@ -111,7 +114,7 @@ def generate(traffic: dict, seed: int, seconds: float,
                 requests.append(request)
                 due += gap
         mode = "open"
-    elif kind == "serve-closed":
+    elif kind.startswith("serve-closed"):
         requests = _sized_requests(
             traffic, int(traffic["pool_requests"]), seed, "pool",
             vocab, prefix)

@@ -1,9 +1,11 @@
 """The serve driver reads the engine through its public surface alone
 (warmup_buckets, occupancy, pending, active_request_ids, cancel,
-prefix_cache_clear): shown on a stand-in engine that HAS nothing else,
-so a read of a private name would raise here. The warm-up lengths are
-worked out by hand for the two traffic files."""
+prefix_cache_clear, and the optional take_decisions): shown on a
+stand-in engine that HAS nothing else, so a read of a private name
+would raise here. The warm-up lengths are worked out by hand for the
+two traffic files."""
 
+import numpy as np
 import pytest
 
 from benchmark import spec
@@ -25,6 +27,7 @@ class PublicEngine:
         self.cancelled = []
         self.cleared = 0
         self.stepped = 0
+        self.taken = []
 
     def warmup_buckets(self):
         buckets = [16]
@@ -56,6 +59,15 @@ class PublicEngine:
     def step(self):
         self.stepped += 1
         return []
+
+    def take_decisions(self, request_id):
+        """The one method a routed model's engine adds: the record of
+        a finished request's choices, handed over once."""
+        self.taken.append(request_id)
+        if request_id == "bench-2":
+            return None
+        return {"first": 0, "layers": {
+            "layer_0": np.array([[0, 1], [2, 1], [1, 3]])}}
 
 
 @pytest.mark.parametrize("cell,cold,shared", [
@@ -118,3 +130,45 @@ def test_reseed_refuses_an_engine_that_has_not_drained():
     with pytest.raises(RuntimeError, match="still queued"):
         _session(engine).reseed(5)
     assert engine.cleared == 0
+
+
+LAYERS = [("layer_0", 2, 4)]
+
+
+def _finished():
+    return {f"bench-{i}": {"idx": i} for i in (0, 2, 5)}
+
+
+def test_decisions_are_asked_once_per_finished_request_by_its_id():
+    """... through the public method alone, and only for a model that
+    declares decisions; what the engine does not give stays None."""
+    engine, rows = PublicEngine(), _finished()
+    serve.take_decisions(engine, [], rows)
+    assert engine.taken == [] and "decisions" not in rows["bench-0"]
+    serve.take_decisions(engine, LAYERS, rows)
+    assert engine.taken == ["bench-0", "bench-2", "bench-5"]
+    assert rows["bench-2"]["decisions"] is None
+    assert rows["bench-5"]["decisions"]["layers"]["layer_0"].shape \
+        == (3, 2)
+
+
+def test_an_engine_without_the_method_gives_no_record():
+    class Silent(PublicEngine):
+        take_decisions = None
+
+    rows = _finished()
+    serve.take_decisions(Silent(), LAYERS, rows)
+    assert [row["decisions"] for row in rows.values()] == [None] * 3
+
+
+def test_the_records_control_reroutes_what_was_handed_over():
+    rows, sound = _finished(), _finished()
+    serve.take_decisions(PublicEngine(), LAYERS, sound)
+    serve.take_decisions(PublicEngine(), LAYERS, rows,
+                         {"reroute_share": 1.0}, seed=3)
+    was = sound["bench-0"]["decisions"]["layers"]["layer_0"]
+    now = rows["bench-0"]["decisions"]["layers"]["layer_0"]
+    assert (now[:, 0] == was[:, 0]).all()      # the last index alone
+    assert (now[:, 1] != was[:, 1]).all()
+    assert ((0 <= now) & (now < 4)).all()
+    assert rows["bench-2"]["decisions"] is None

@@ -1,14 +1,20 @@
 """The plain reference of EVERY configuration of BENCHMARK.json agrees
 with the program at a tiny size on the CPU (prefill then decode through
 the paged cache, cold and prefix-shared), and the comparison that
-decides ``correct`` has been shown to fail: for the control, which is
-the program's own lower-precision path switched on (the int8 KV
-cache), and for ONE token altered where it is produced.
+decides ``correct`` has been shown to fail: for the case's control
+(keyword overrides for the module's program_model: the program's own
+lower-precision path switched on, for Baichuan the int8 KV cache; or,
+under "decisions", a corruption of the engine's record of its
+choices), and for ONE token altered where it is produced.
 
 One case per configuration: the model module is the one its file
-names, and the sizes, the load and the limit are read from
-tests/benchmark/reference_cases/<configuration>.json, so that a
-configuration a later PR adds is tested by adding that file."""
+names, and the sizes, the load, the limits and the control are read
+from tests/benchmark/reference_cases/<configuration>.json, so that a
+configuration a later PR adds is tested by adding that file. A module
+that declares decisions (spec.decision_layers) is judged on the
+engine's own choices (engine.take_decisions), as a run judges it. The
+one test that reaches past the engine's public surface is generated
+only for a case that asks for it under "direct"."""
 
 import json
 import pathlib
@@ -25,34 +31,48 @@ CASES = pathlib.Path(__file__).resolve().parent / "reference_cases"
 CONFIGS = [c["name"] for c in spec.load_benchmark()["configs"]]
 
 
+def _case_data(config_name: str) -> dict:
+    path = CASES / f"{config_name}.json"
+    assert path.is_file(), (
+        f"configuration {config_name} has no reference case: add "
+        f"{path.relative_to(spec.ROOT)}")
+    with open(path, encoding="utf-8") as fh:
+        return json.load(fh)
+
+
+# the configurations whose case asks for the comparison that calls the
+# engine's prefill by its private name
+DIRECT_PREFILL = [name for name in CONFIGS if "prefill_logits"
+                  in _case_data(name).get("direct", {})]
+
+
 class Case:
     """A configuration at its reference case's sizes."""
 
     def __init__(self, config_name: str) -> None:
-        path = CASES / f"{config_name}.json"
-        assert path.is_file(), (
-            f"configuration {config_name} has no reference case: add "
-            f"{path.relative_to(spec.ROOT)}")
-        with open(path, encoding="utf-8") as fh:
-            self.data = json.load(fh)
+        self.data = _case_data(config_name)
         self.model = harness.merged(
             dict(spec.load_config(config_name),
                  rehearse_tiny=self.data["sizes"]), True)
         self.module = spec.load_model(self.model)
         self.dims = self.module.dims(self.model)
         self.leaves = self.module.param_leaves(self.dims)
+        self.layers = spec.decision_layers(self.module, self.model,
+                                           self.dims)
         self.tokens = self.data["requests"] * self.data["new_tokens"]
         self.limits = self.data["limits"]
 
-    def serve(self, kv_cache_dtype=None):
+    def serve(self, **control):
         """-> (params, engine, finished): every request of the case
-        through the engine as a run builds it."""
+        through the engine as a run builds it, with a control's
+        overrides."""
         from batch_shipyard_tpu.models.serving import Request
         data, seed, vocab = self.data, self.data["seed"], \
             self.dims["vocab"]
         params = weights.make_params(self.leaves, seed, jnp.bfloat16)
+        record_control = control.pop("decisions", None)
         engine = serve.build_engine(self.module, self.model, params,
-                                    kv_cache_dtype)
+                                    **control)
         rng = random.Random(seed)
         prefix = [rng.randrange(1, vocab)
                   for _ in range(data["shared_prefix_tokens"])]
@@ -70,24 +90,41 @@ class Case:
         while engine.pending():
             for request_id, tokens in engine.step():
                 done[request_id] = tokens
-        finished = [{"idx": i, "prompt": prompts[r], "tokens": done[r]}
-                    for i, r in enumerate(sorted(done))]
-        return params, engine, finished
+        finished = {r: {"idx": i, "prompt": prompts[r],
+                        "tokens": done[r]}
+                    for i, r in enumerate(sorted(done))}
+        serve.take_decisions(engine, self.layers, finished,
+                             record_control, seed)
+        return params, engine, list(finished.values())
 
     def gaps(self, params, finished) -> dict:
         return check.serve_gaps(params, self.module, self.model,
-                                self.dims, finished)
+                                self.dims, finished, self.layers)
 
     def numbers(self, readings) -> dict:
-        return check.gap_numbers(readings["gaps"],
-                                 self.data["tail_from"])
+        numbers = check.gap_numbers(readings["gaps"],
+                                    self.data["tail_from"])
+        if self.layers:
+            numbers.update(check.routing_numbers(
+                readings, self.data["slack_from"]))
+        return numbers
+
+
+_SERVED: dict = {}
+
+
+def _served(config_name: str):
+    if config_name not in _SERVED:
+        case = Case(config_name)
+        params, engine, finished = case.serve()
+        _SERVED[config_name] = (case, params, engine, finished,
+                                case.gaps(params, finished))
+    return _SERVED[config_name]
 
 
 @pytest.fixture(scope="module", params=CONFIGS)
 def served(request):
-    case = Case(request.param)
-    params, engine, finished = case.serve()
-    return case, params, engine, finished, case.gaps(params, finished)
+    return _served(request.param)
 
 
 def test_paged_prefill_and_decode_agree_with_the_reference(served):
@@ -104,8 +141,15 @@ def test_paged_prefill_and_decode_agree_with_the_reference(served):
         0.9 * len(readings["gaps"])
 
 
-def test_paged_prefill_logits_match_the_reference_directly(served):
-    case, params, engine, finished, _readings = served
+@pytest.mark.parametrize("config_name", DIRECT_PREFILL)
+def test_paged_prefill_logits_match_the_reference_directly(config_name):
+    """Past the engine's public surface (engine._prefill_paged with
+    engine._scratch_page), so only for a case that names it under
+    "direct", with the tolerance and its reason stated there: a
+    program PR that reshapes the prefill takes the name out of a case
+    of its own, or the next benchmark PR does."""
+    case, params, engine, finished, _readings = _served(config_name)
+    tolerance = case.data["direct"]["prefill_logits"]["relative_error"]
     prompt = finished[0]["prompt"]
     bucket = next(b for b in engine.warmup_buckets()
                   if b >= len(prompt))
@@ -119,23 +163,28 @@ def test_paged_prefill_logits_match_the_reference_directly(served):
         params, tokens, jnp.asarray([len(prompt) - 1]), case.model,
         case.dims)[0]
     error = float(jnp.linalg.norm(last - want) / jnp.linalg.norm(want))
-    # bfloat16 activations against float32: a few parts in a thousand
-    assert error < 0.02, error
+    assert error < tolerance, error
     assert int(jnp.argmax(want)) == int(jnp.argmax(last)) or \
         float(jnp.max(want) - want[int(jnp.argmax(last))]) < 0.05
 
 
 def test_the_programs_int8_kv_cache_comes_out_not_correct(served):
     """The control: the same engine, weights and requests with the
-    program's own lower-precision path (the case's ``control``:
-    kv_cache_dtype="int8")."""
+    case's ``control`` (one set of overrides, or a list of them; for
+    Baichuan the program's own lower-precision path,
+    kv_cache_dtype="int8"). Each has to fail a limit, by a number at
+    least three times the sound reading."""
     case = served[0]
     sound = case.numbers(served[4])
-    params, _engine, finished = case.serve(**case.data["control"])
-    control = case.numbers(case.gaps(params, finished))
-    ok, lines = check.judge(control, case.limits)
-    assert not ok, lines
-    assert control["gap_tail_mean"] > 3 * sound["gap_tail_mean"]
+    for overrides in check.controls(case.data["control"]):
+        params, _engine, finished = case.serve(**overrides)
+        control = case.numbers(case.gaps(params, finished))
+        ok, lines = check.judge(control, case.limits)
+        assert not ok, (overrides, lines)
+        assert any(control[name] is None or
+                   (control[name] > limit
+                    and control[name] > 3 * sound[name])
+                   for name, limit in case.limits.items()), lines
 
 
 def test_one_token_altered_where_it_is_produced_is_caught(served):

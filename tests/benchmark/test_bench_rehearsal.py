@@ -19,7 +19,7 @@ import pytest
 from benchmark import spec
 
 RUN = str(spec.ROOT / "benchmark" / "run.py")
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "check"}
 WORKLOADS = spec.load_benchmark()["workloads"]
 CELLS = [w["name"] for w in WORKLOADS]
 # one cell of each configuration, for what is shown once a model
@@ -59,6 +59,15 @@ def test_rehearsal_runs_end_to_end(workload, trace):
     assert result["device"]["count"] == devices
     assert any(l.startswith("check ") and "(limit" in l
                for l in lines)       # each number beside its limit
+    # ... as the last key of the result, and the last lines of stderr
+    assert list(result)[-1] == "check"
+    limits = spec.load_cell(cell).config["rehearse_tiny"]["check"][
+        "limits"]
+    assert {name: pair["limit"] for name, pair in
+            result["check"].items()} == limits
+    last = done.stderr.splitlines()[-len(limits):]
+    assert all(line.startswith(f"check {name}: ") and "(limit" in line
+               for line, name in zip(last, sorted(limits)))
 
 
 def test_without_a_tpu_it_exits_non_zero_and_prints_no_result():
@@ -96,14 +105,15 @@ def test_a_broken_timed_path_comes_out_not_correct(cell, monkeypatch,
 
     sound = serving._decode_step
 
-    def altered(model, sampling, params, cache, tokens, positions,
-                active, key):
-        cache, _next, positions, tok = sound(
-            model, sampling, params, cache, tokens, positions, active,
-            key)
-        tok = jnp.where(active, (tok + 1) % model.config.vocab_size,
-                        tok)
-        return cache, tok[:, None], positions, tok
+    def altered(*args, **kwargs):
+        # whatever the step takes; of what it gives, the two token
+        # results are altered (every token moves to a neighbour, still
+        # inside any vocabulary) and any further result is left as it
+        # is: a record of decisions that rides in the cache tree, or
+        # behind these four, keeps this wrapper whole
+        cache, _next, positions, tok, *rest = sound(*args, **kwargs)
+        tok = jnp.where(tok > 0, tok - 1, tok + 1)
+        return (cache, tok[:, None], positions, tok, *rest)
 
     monkeypatch.setattr(serving, "_decode_step", altered)
     module_spec = importlib.util.spec_from_file_location("bench_run", RUN)

@@ -2,9 +2,11 @@
 driven by data: a made-up cell, traffic file and per-layer metric added
 as NEW files (in a temporary copy) are picked up with no edit to an
 existing file, and so is a second MODEL (a module, its reference, a
-configuration file and entries), rehearsed to ``correct: true``. No
-file of the benchmark outside models/, reference/ and configs/ names
-an architecture, and none reads a private name off the engine."""
+configuration file and entries), rehearsed to ``correct: true``, and a
+ROUTED one (a module that declares decision_layers, judged on the
+timed path's own choices). No file of the benchmark outside models/,
+reference/ and configs/ names an architecture, and none reads a
+private name off the engine."""
 
 import json
 import os
@@ -220,6 +222,115 @@ def test_a_second_model_is_only_new_files_and_entries(tmp_path):
     assert '"decode_step_p50_ms.batch"' not in rehearsed
     # no file that was there has changed
     assert all(p.read_bytes() == data for p, data in before.items())
+
+
+def test_a_routed_model_is_only_new_files_and_entries(tmp_path):
+    """The routed twin: a model whose layers choose k of n experts
+    arrives in a copy of the tree as NEW files and entries: a module
+    with ``decision_layers``, its reference, a configuration file with
+    a ``check.slack_from``, a limit on routing_rejected_share and a
+    ``check.control``. Its cell is rehearsed to ``correct: true`` with
+    the reference run on the timed path's own choices and
+    routing_rejected_share printed beside its limit, and no file that
+    was there has changed. The model is tests/benchmark's stand-in.
+    Because the PROGRAM serves no routed architecture yet, the twin
+    also brings an engine that does (the stand-in's program behind the
+    engine's public surface, with take_decisions), as a driver and a
+    traffic kind of its own around the serve driver; a routed
+    configuration that the program serves brings neither."""
+    root, before = _copy_of_the_benchmark(tmp_path)
+    bench = spec.load_benchmark()
+    standin = spec.ROOT / "tests" / "benchmark"
+    added = ("models/routed_standin.py",
+             "models/routed_standin_program.py",
+             "reference/routed_standin_plain.py",
+             "configs/routed-standin-serve-1chip.json",
+             "drivers/serve_closed_standin.py",
+             "traffic/batch-offline-standin.json")
+    for relative in added:
+        assert not (root / "benchmark" / relative).exists()
+        shutil.copy(standin / relative, root / "benchmark" / relative)
+    config = json.load(open(
+        root / "benchmark/configs/routed-standin-serve-1chip.json"))
+    assert config["check"]["slack_from"] > 0
+    assert config["check"]["control"]
+    assert "routing_rejected_share" in config["check"]["limits"]
+    metric = json.load(open(
+        root / "benchmark/layer_metrics/decode_step_p50_ms.batch.json"))
+    metric["name"] = "decode_step_p50_ms.routed"
+    (root / "benchmark/layer_metrics/decode_step_p50_ms.routed.json"
+     ).write_text(json.dumps(metric))
+    bench["configs"].append(
+        {"name": "routed-standin-serve-1chip", "source": config["source"],
+         "file": "benchmark/configs/routed-standin-serve-1chip.json",
+         "reduced": [], "why": "a routed stand-in"})
+    bench["workloads"].append(
+        {"name": "routed.batch-offline",
+         "config": "routed-standin-serve-1chip",
+         "traffic": "batch-offline-standin", "chips": 1,
+         "why": "a routed model on the closed loop that is there"})
+    for entry in bench["end_to_end"]:
+        if entry["name"] == "serve_tokens_per_s":
+            entry["workloads"].append("routed.batch-offline")
+    bench["per_layer"].append(
+        {"name": "decode_step_p50_ms.routed", "unit": "ms",
+         "better": "lower", "source": "host_clock",
+         "layer": metric["layer"], "moves": "serve_tokens_per_s",
+         "workloads": ["routed.batch-offline"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    assert _validate(root) == []
+    done = _python(
+        root, "benchmark/run.py", "--workload", "routed.batch-offline",
+        "--seed", str(2**31 + 27), "--seconds", "3", "--trace", "0",
+        "--rehearse-tiny",
+        JAX_COMPILATION_CACHE_DIR=str(root / ".jax_compile_cache"))
+    assert done.returncode == 0, done.stderr[-3000:]
+    lines = [l for l in done.stdout.splitlines() if l.strip()]
+    result = json.loads(lines[-1])
+    assert result["correct"] is True, done.stdout[-3000:]
+    assert result["attempted"] > 0 and result["failed"] == 0
+    limits = config["check"]["limits"]
+    for name in ("gap_tail_mean", "routing_rejected_share"):
+        assert any(l.startswith(f"check {name}: ") and
+                   f"(limit <= {limits[name]!r}) ok" in l
+                   for l in lines), name
+        assert result["check"][name]["limit"] == limits[name]
+        assert result["check"][name]["value"] <= limits[name]
+    told = next(l for l in lines if "the timed path's own choices" in l)
+    assert "positions_unrecorded 0 of" in told
+    assert "routing_flip_share" in told and "slack_max" in told
+    # no file that was there has changed
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
+@pytest.mark.parametrize("declared,complaint", [
+    ("[('a', 4, 64), ('a', 4, 64)]", "one twice"),
+    ("[]", "gives no layer"),
+    ("[('a', 4)]", "has to give"),
+    ("[('a', 4, 4)]", "has to give"),
+    ("[('a b', 4, 64)]", "has to give"),
+    ("7", "has to give"),
+    ("[('a', 4, 64)]", "check.slack_from is not a number"),
+])
+def test_validate_reports_a_malformed_declaration_of_decisions(
+        tmp_path, declared, complaint):
+    """A module MAY declare decisions; one that does so in another
+    shape, or whose configuration does not say from where a slack is
+    rejected and how large a share may be, is reported."""
+    root, _before = _copy_of_the_benchmark(tmp_path)
+    source = (root / "benchmark/models/dense_mha.py").read_text()
+    (root / "benchmark/models/declaring.py").write_text(
+        source + f"\n\ndef decision_layers(config, dims):\n"
+                 f"    return {declared}\n")
+    path = root / spec.load_benchmark()["configs"][0]["file"]
+    config = json.load(open(path))
+    config["model_module"] = "declaring"
+    path.write_text(json.dumps(config))
+    problems = spec.validate(root)
+    assert problems and all(complaint in p or "routing_rejected" in p
+                            for p in problems), problems
+    assert any(complaint in p for p in problems)
 
 
 @pytest.mark.parametrize("fault,complaint", [
