@@ -123,10 +123,31 @@ def _rewind_cache(cache, steps):
     cache's per-slot write cursor is its "length" leaf; rewinding it
     un-commits the same way (pages stay allocated, the next insert
     overwrites)."""
+    return _map_cursors(lambda leaf: leaf - steps, cache)
+
+
+def _park_idle_cursors(cache, active):
+    """Set the write cursor of every slot that holds no request
+    (``active`` [B] false) to 0, in every layer. A decode kernel skips
+    key blocks by the cursor (ops/paged_attention.py,
+    ops/decode_attention.py), and an idle slot stays in the full-batch
+    step: left alone its cursor keeps the last request's length and
+    grows by one a step, and the kernel attends over that many rows of
+    the scratch page for nothing. Parked, the next step writes the
+    slot's one garbage row at offset 0 and the kernel sees length 1.
+    Admission's prefill sets the cursor to the prompt's length before
+    the slot is read again. Per-slot state without a cursor key is
+    left alone."""
+    return _map_cursors(lambda leaf: jnp.where(active, leaf, 0), cache)
+
+
+def _map_cursors(fn, cache):
+    """Apply ``fn`` to every layer's per-slot write cursor: the dense
+    cache's "index" leaf, the paged cache's "length" leaf."""
     def fix(path, leaf):
         if path and getattr(path[-1], "key", None) in ("index",
                                                        "length"):
-            return leaf - steps
+            return fn(leaf)
         return leaf
     return jax.tree_util.tree_map_with_path(fix, cache)
 

@@ -69,17 +69,21 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
         positions=positions[:, None], mutable=["cache"])
     next_tok = inf._sample(logits[:, 0].astype(jnp.float32),
                            key, sampling)
-    # Inactive slots DO write garbage into their cache rows,
-    # and that is fine: a freed row is never read (the
-    # per-slot mask excludes other rows) and _admit's prefill
-    # rewrites the whole row + index before reuse — restoring
-    # the full K/V trees here would double per-token HBM
-    # traffic for no observable effect. Only the cheap token/
-    # position bookkeeping needs masking.
+    # Inactive slots DO write one garbage row a step, and that is
+    # fine: it lands where nothing reads it (row 0 of the slot's own
+    # dense row; offset 0 of the scratch page, where a freed slot's
+    # table points) and _admit's prefill rewrites the rows + cursor
+    # before reuse — restoring the full K/V trees here would double
+    # per-token HBM traffic for no observable effect. What needs
+    # masking is the cheap bookkeeping: token, position, and the
+    # cache cursor, which the decode kernels skip key blocks by. An
+    # idle slot leaves every step program with its cursor at 0, so it
+    # costs the next step one block instead of its last request's
+    # length plus one more row every step it sits idle.
     next_tok = jnp.where(active, next_tok, tokens[:, 0])
     positions = jnp.where(active, positions + 1, positions)
-    return (mutated["cache"], next_tok[:, None], positions,
-            next_tok)
+    cache = inf._park_idle_cursors(mutated["cache"], active)
+    return cache, next_tok[:, None], positions, next_tok
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -103,8 +107,8 @@ def _speculative_step(target_model, draft_model, gamma, t_params,
     validated prefix a_i, commit d_1..d_{a_i} plus the target token
     at a_i (correction or bonus), rewind both caches by gamma - a_i
     per slot — the paged target rewinds its per-slot length the same
-    way. Inactive slots rewind the full gamma+1 so their indices
-    stay put. Module-level jit (statics as above) so same-shape
+    way. Inactive slots leave with their cursors parked at 0, like
+    _decode_step's. Module-level jit (statics as above) so same-shape
     engines share the compilation."""
     d_embed = d_params["embed"]["embedding"]
     t_embed = t_params["embed"]["embedding"]
@@ -146,9 +150,11 @@ def _speculative_step(target_model, draft_model, gamma, t_params,
         [d_tok, jnp.zeros((d_tok.shape[0], 1), jnp.int32)], axis=1)
     block = jnp.where(js[None, :] < a_slot[:, None], d_pad,
                       t_tok)                               # [B, g+1]
-    rewind = jnp.where(active, gamma - a_slot, gamma + 1)
-    t_cache = inf._rewind_cache(t_cache, rewind)
-    d_cache = inf._rewind_cache(d_cache, rewind)
+    rewind = gamma - a_slot
+    t_cache = inf._park_idle_cursors(
+        inf._rewind_cache(t_cache, rewind), active)
+    d_cache = inf._park_idle_cursors(
+        inf._rewind_cache(d_cache, rewind), active)
     new_tok = jnp.take_along_axis(block, a_slot[:, None],
                                   axis=1)                  # [B, 1]
     new_tok = jnp.where(active[:, None], new_tok, tokens)
@@ -1216,15 +1222,25 @@ class ContinuousBatcher:
         reports. Pages are counted once however many slots read them.
         Safe to call from another thread than the stepping one (the
         snapshot may then straddle a step). The page keys are absent
-        from a dense engine."""
-        active = tokens = 0
-        for slot in self._slots:
-            req = slot.request
-            if req is not None:
-                active += 1
-                tokens += len(req.prompt) + len(slot.generated)
-        out = {"slots_active": active, "slots_total": self.num_slots,
-               "queued": len(self._queue), "live_tokens": tokens}
+        from a dense engine.
+
+        kv_blocks_attended is the work of ONE layer's paged decode
+        kernel in a decode step dispatched from this state, in its
+        own unit, the (slot, page) block: ceil(tokens / page) for a
+        slot with a request (its cursor as the kernel will see it,
+        the pending token's row written) and one for a slot without,
+        whose cursor the step programs park at 0 (a slot freed by the
+        last step attends over its old length once more, which the
+        host's books here do not follow). Beside live_tokens it says
+        what share of the kernel's blocks is required work:
+        ceil(live_tokens / page) is the least a kernel could compute,
+        kv_blocks_attended - (slots_total - slots_active) what the
+        requests take, the rest what idle slots cost."""
+        held = [len(slot.request.prompt) + len(slot.generated)
+                for slot in self._slots if slot.request is not None]
+        out = {"slots_active": len(held),
+               "slots_total": self.num_slots,
+               "queued": len(self._queue), "live_tokens": sum(held)}
         if self.paged:
             out["kv_pages_in_use"] = len(
                 {page for held in self._slot_pages + self._slot_shared
@@ -1233,6 +1249,9 @@ class ContinuousBatcher:
             out["kv_pages_lru"] = len(self._lru)
             out["kv_pages_total"] = self._total_pages
             out["prefix_index_pages"] = len(self._page_ref)
+            out["kv_blocks_attended"] = sum(
+                -(-tokens // self.page_size) for tokens in held
+            ) + self.num_slots - len(held)
         return out
 
     def step_stats(self) -> dict:

@@ -173,7 +173,13 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     int32 valid-key counts (INCLUDING the token written this step, so
     every attended slot has length >= 1 — a length-0 slot yields zeros
     here but softmax-of-all-masked garbage from the XLA path; the
-    decode contract never attends an unwritten slot).
+    decode contract never attends an unwritten slot). The grid is
+    (B, max_blocks) whatever the lengths: a slot computes
+    ceil(length / page) of its blocks and steps over the rest. A slot
+    that holds no request is still a row of the batch; the serving
+    step programs park its cursor at 0 (models/inference.
+    _park_idle_cursors), so it arrives here with length 1 and costs
+    one block of the scratch page, not its last request's length.
     k_scales/v_scales: [P, page, H] fp32 when the pages are int8
     (applied in-kernel per tile). Returns [B, 1, H, D] in q.dtype."""
     batch, seq, heads, depth = q.shape

@@ -113,13 +113,16 @@ def test_mixed_acceptance_matches_nonspeculative(params,
     for req in requests:
         engine.submit(serving.Request(req.request_id, req.prompt,
                                       req.max_new_tokens))
+    early = {}
     for _ in range(2):
-        engine.step()  # slots are mid-generation now
+        # Slots are mid-generation now; a draft that is accepted
+        # whole finishes an 8-token request in these two rounds.
+        early.update(engine.step())
     # Mid-flight admission: the free slot's target AND draft caches
     # prefill while the other slot keeps speculating.
     engine.submit(serving.Request(late.request_id, late.prompt,
                                   late.max_new_tokens))
-    results = _drain(engine)
+    results = {**early, **_drain(engine)}
     assert set(results) == (
         {r.request_id for r in requests} | {"late"})
     for req in requests + [late]:

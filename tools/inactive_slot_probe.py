@@ -1,14 +1,20 @@
 #!/usr/bin/env python3
-"""Probe, not a benchmark: what do INACTIVE slots cost the decode step?
+"""Probe, not a benchmark: what do IDLE slots cost the decode step?
 
-A freed slot keeps 'decoding' (masked) through the scratch page and
-its per-layer ``length`` keeps growing by one a step, so the paged
-kernel computes ceil(length / page) blocks of the scratch page for it.
-This builds the benchmark configuration's engine (the benchmark's own
-weights and engine shim), seats 15 requests, and times engine.step()
-while the other slots' lengths are ~0, half of max_decode_len, all of
-it, and ~0 again. One JSON line a case. PERF.md (section 5, PR 25) has
-the readings on a v5e: 21.5 / 28.9 / 35.9 / 22.1 ms.
+A slot without a request stays in the full-batch decode step, and the
+paged kernel computes ceil(cursor / page) blocks of the scratch page
+for it. Until PR 28 a freed slot's cursor kept its last request's
+length and grew by one a step (on a v5e, 15 live slots and 33 idle: a
+step of 21.5 ms with the idle cursors near 0, 28.9 at 1,024, 35.9 at
+2,048; PERF.md section 6, PR 25). Now every step program parks an idle
+slot's cursor at 0, and this is the after-picture: the benchmark
+configuration's engine (the benchmark's own weights and engine shim),
+15 requests seated, and for each of 0, half of and all of
+max_decode_len the idle slots' cursors are SET to that, one step is
+run and timed (the one step that still attends over what was set), the
+idle cursors are read back (0), and the steps after it are timed. One
+JSON line a case; the last line says whether every case read 0 one
+step later and how far the cases' step times lie apart.
 
     chiprun --chips 1 -- python3 tools/inactive_slot_probe.py
     JAX_PLATFORMS=cpu python tools/inactive_slot_probe.py --tiny
@@ -19,68 +25,94 @@ import sys
 import time
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(ROOT))
-from benchmark import flops, harness, spec, weights  # noqa: E402
-
-harness.place_compile_cache(ROOT)        # before anything imports jax
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from batch_shipyard_tpu.models.serving import Request  # noqa: E402
-from benchmark.drivers import serve as drv  # noqa: E402
-
-TINY = "--tiny" in sys.argv
-bench = spec.load_benchmark(ROOT)
-cell = spec.load_cell("baichuan7b.batch-offline", ROOT, bench)
-model = harness.merged(cell.config, TINY)
-dims = flops.model_dims(model)
-params = weights.make_params(dims, 12345, jnp.bfloat16)
-engine = drv.build_engine(None, model, params)
-ACTIVE = 1 if TINY else 15
-PROMPT, NEW = (20, 150) if TINY else (500, 260)
-BIG = engine.max_decode_len
-rng = np.random.RandomState(0)
-for i in range(ACTIVE):
-    engine.submit(Request(
-        f"r{i}", [int(t) for t in rng.randint(1, dims["vocab"], (PROMPT,))],
-        max_new_tokens=NEW))
-for _ in range(ACTIVE + 25):   # admit all (one prefill a slot), warm
-    engine.step()
-assert sum(s.request is not None for s in engine._slots) == ACTIVE
+CELL = "baichuan7b.batch-offline"
 
 
-def timed(label, n=10 if TINY else 50):
-    jax.block_until_ready(engine.cache)
-    times = []
-    for _ in range(n):
+def main(argv=None) -> int:
+    tiny = "--tiny" in (sys.argv[1:] if argv is None else argv)
+    sys.path.insert(0, str(ROOT))
+    from benchmark import harness, spec, weights
+    harness.place_compile_cache(ROOT)    # before anything imports jax
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from batch_shipyard_tpu.models.serving import Request
+    from benchmark.drivers import serve as drv
+
+    cell = spec.load_cell(CELL, ROOT, spec.load_benchmark(ROOT))
+    model = harness.merged(cell.config, tiny)
+    module = spec.load_model(model, ROOT)
+    dims = module.dims(model)
+    params = weights.make_params(module.param_leaves(dims), 12345,
+                                 jnp.bfloat16)
+    engine = drv.build_engine(module, model, params)
+    live, prompt, new, timed = (1, 20, 150, 5) if tiny else (
+        15, 500, 260, 30)
+    rng = np.random.RandomState(0)
+    for i in range(live):
+        engine.submit(Request(
+            f"r{i}", [int(t) for t in rng.randint(1, dims["vocab"],
+                                                  (prompt,))],
+            max_new_tokens=new))
+    for _ in range(live + 25):   # admit all (one prefill a slot), warm
+        engine.step()
+    idle = np.asarray([s.request is None for s in engine._slots])
+    assert int((~idle).sum()) == live
+
+    def cursors():
+        return np.asarray(engine.cache["layer_0"]["attn"]["length"])
+
+    def set_idle(value):
+        def fix(path, leaf):
+            if path[-1].key == "length":
+                return jnp.where(jnp.asarray(idle), jnp.int32(value),
+                                 leaf)
+            return leaf
+        engine.cache = jax.tree_util.tree_map_with_path(fix,
+                                                        engine.cache)
+
+    def step_ms():
         t0 = time.perf_counter()
         engine.step()
-        times.append((time.perf_counter() - t0) * 1e3)
-    lengths = np.asarray(jax.tree_util.tree_leaves(
-        engine.cache["layer_0"]["attn"]["length"])[0])
-    print(json.dumps({"case": label, "step_p50_ms": float(np.median(times)),
-                      "step_min_ms": float(min(times)),
-                      "inactive_length_now": int(lengths[-1]),
-                      "active_length_now": int(lengths[0])}), flush=True)
+        return (time.perf_counter() - t0) * 1e3
+
+    big = engine.max_decode_len
+    rows = []
+    for value in (0, big // 2, big, 0):
+        set_idle(value)
+        jax.block_until_ready(engine.cache)
+        # What the first step's kernel computes in a layer, by the
+        # device's cursors (the pending row written); the host's
+        # count knows nothing of cursors set behind its back, and is
+        # what every step after the first computes.
+        first_blocks = int(
+            (-(-(cursors() + 1) // engine.page_size)).sum())
+        blocks = engine.occupancy()["kv_blocks_attended"]
+        first = step_ms()
+        after = cursors()
+        times = [step_ms() for _ in range(timed)]
+        rows.append({
+            "idle_cursors_set_to": value,
+            "first_step_ms": first,
+            "first_step_kv_blocks": first_blocks,
+            "idle_cursor_max_one_step_later": int(after[idle].max()),
+            "step_p50_ms": float(np.median(times)),
+            "step_min_ms": float(min(times)),
+            "live_cursor_now": int(cursors()[~idle].max()),
+            "kv_blocks_attended": blocks})
+        print(json.dumps(rows[-1]), flush=True)
+    assert sum(s.request is not None for s in engine._slots) == live
+    p50 = [row["step_p50_ms"] for row in rows]
+    parked = all(row["idle_cursor_max_one_step_later"] == 0
+                 for row in rows)
+    print(json.dumps({
+        "live_slots": live, "idle_slots": int(idle.sum()),
+        "idle_cursors_parked": parked,
+        "step_p50_spread_pct": (max(p50) - min(p50)) / min(p50) * 100,
+        "device": jax.devices()[0].device_kind}), flush=True)
+    return 0 if parked else 1
 
 
-def set_inactive(value):
-    inactive = np.asarray([s.request is None for s in engine._slots])
-
-    def fix(path, leaf):
-        if path[-1].key == "length":
-            return jnp.where(jnp.asarray(inactive), jnp.int32(value), leaf)
-        return leaf
-    engine.cache = jax.tree_util.tree_map_with_path(fix, engine.cache)
-
-
-set_inactive(0)
-timed("inactive lengths 0..60")
-set_inactive(BIG // 2)
-timed(f"inactive lengths {BIG // 2}..")
-set_inactive(BIG)
-timed(f"inactive lengths {BIG}..")
-set_inactive(0)
-timed("inactive lengths 0..60 again")
+if __name__ == "__main__":
+    sys.exit(main())
