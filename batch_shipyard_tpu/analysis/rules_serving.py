@@ -1,16 +1,16 @@
 """Serving-engine invariant rules.
 
-The paged KV pool (models/serving.py) runs a page lifecycle —
+The paged KV pool (models/kv_pages.py) runs a page lifecycle —
 FREE -> OWNED -> PINNED (prefix-indexed, refcounted) -> LRU -> FREE —
 whose accounting invariant (`_avail_pages` = total - pinned -
-reservations) every admission decision trusts. The single release
-helper (`_release_pages`) is the only place a page may legally return
-to the free list, because it is the only code that also settles the
-refcount, the LRU membership, and the availability counter. A direct
-`_free_pages` mutation anywhere else frees a page without that
-settlement: the page can be handed to a new request while a shared
-prefix still references it — silent KV corruption that decodes
-plausible-but-wrong tokens.
+reservations) every admission decision trusts. `_release_pages` is
+the only place a page may legally return to the free list, called by
+the pool's release() and clear_unreferenced() once they have settled
+the refcount, the LRU membership, and the availability counter. A
+direct `_free_pages` mutation anywhere else, the engine included,
+frees a page without that settlement: the page can be handed to a new
+request while a shared prefix still references it — silent KV
+corruption that decodes plausible-but-wrong tokens.
 """
 
 from __future__ import annotations
@@ -20,10 +20,9 @@ import ast
 from batch_shipyard_tpu.analysis.core import (
     AnalysisContext, Finding, rule)
 
-# The only functions allowed to touch the free list directly:
-# construction seeds it, the allocator pops from it, and the release
-# helper returns pages to it (settling refcounts/LRU/avail as it
-# does).
+# The only functions allowed to touch the free list directly, all
+# PagePool's: construction seeds it, the allocator pops from it, and
+# the release helper returns pages its callers have settled.
 _ALLOWED_FUNCS = {"__init__", "_alloc_page", "_release_pages"}
 
 # list-mutating method calls on the attribute.
@@ -85,8 +84,9 @@ def check_serving_page_refcount(ctx: AnalysisContext) -> list[Finding]:
     """A direct mutation of ``*._free_pages`` (append/extend/pop/
     assignment/del/...) outside ``__init__``/``_alloc_page``/
     ``_release_pages``: freeing or reassigning KV pool pages must go
-    through the single release helper, which also settles the prefix
-    refcount, LRU membership, and the ``_avail_pages`` accounting.
+    through PagePool.release / clear_unreferenced, which settle the
+    prefix refcount, LRU membership, and the ``_avail_pages``
+    accounting before the single release helper extends the list.
     A bare free-list write skips that settlement, so a page still
     referenced by a cached prefix can be reissued to a new request —
     the decode then gathers another request's KV rows and emits
@@ -109,9 +109,10 @@ def check_serving_page_refcount(ctx: AnalysisContext) -> list[Finding]:
                     line=node.lineno,
                     message=(f"direct _free_pages mutation in "
                              f"{func_name or '<module>'}(); page "
-                             f"frees must go through _release_pages "
-                             f"(it settles refcounts, LRU membership "
-                             f"and _avail_pages — a bare free-list "
+                             f"frees must go through PagePool's "
+                             f"release / _release_pages (they settle "
+                             f"refcounts, LRU membership and "
+                             f"_avail_pages — a bare free-list "
                              f"write can reissue a page a cached "
                              f"prefix still references)")))
     return findings

@@ -20,11 +20,9 @@ host-side Python, compute is two compiled functions (prefill, step).
 
 from __future__ import annotations
 
-import collections
 import contextlib
 import dataclasses
 import functools
-import hashlib
 import itertools
 import random
 import time
@@ -36,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from batch_shipyard_tpu.models import inference as inf
+from batch_shipyard_tpu.models import kv_pages
 from batch_shipyard_tpu.models import transformer as tfm
 from batch_shipyard_tpu.trace import spans as trace_spans
 
@@ -523,32 +522,22 @@ class ContinuousBatcher:
         tables covering only their live tokens, so HBM is sized for
         aggregate active context instead of
         num_slots * max_decode_len. kv_num_pages defaults to the
-        no-deadlock capacity (num_slots * ceil(max_len/page)).
-
-        Admission policy for a smaller pool:
-          - overcommit=False (default): RESERVATION — admission takes
-            each request's worst-case page count (prompt +
-            max_new_tokens) up front, so decode can never exhaust the
-            pool, at the cost of admitting fewer concurrent requests
-            than actual usage would allow.
-          - overcommit=True: PREEMPTION — admission takes only the
-            prompt's pages (+1 headroom); when a decode step needs a
-            page and none is free, the active slot with the fewest
-            generated tokens is preempted (pages reclaimed, request
-            re-queued at the head) and later resumed by re-prefilling
-            prompt + already-generated tokens. Short actual
-            generations then share a pool far below worst-case.
+        no-deadlock capacity (num_slots * ceil(max_len/page)). The
+        pool's books are models/kv_pages.py, which has the two
+        admission policies for a smaller pool: RESERVATION of each
+        request's worst case (the default: decode can never exhaust
+        the pool) and, with overcommit=True, the prompt's pages alone
+        and PREEMPTION: a decode step that finds the pool dry
+        re-queues the active slot with the fewest generated tokens,
+        later resumed by re-prefilling prompt + generated tokens.
 
         prefix_cache (paged mode only) enables CROSS-REQUEST PREFIX
         REUSE: every full prompt page is indexed by a chained content
         hash at prefill, and a later request whose prompt starts with
-        the same pages pins them (refcounted) instead of recomputing
-        — its prefill runs only over the suffix
-        (_prefill_paged_shared). Unreferenced indexed pages park in
-        an LRU and are evicted only when the allocator runs dry, so
-        the reuse window is however much pool slack the workload
-        leaves. Greedy outputs are unchanged (the shared rows are the
-        bytes a cold prefill writes).
+        the same pages pins them instead of recomputing: its prefill
+        runs only over the suffix (_prefill_paged_shared). Greedy
+        outputs are unchanged (the shared rows are the bytes a cold
+        prefill writes).
 
         slo_shed_grace_ms, when set, arms overload shedding: a queued
         request whose TTFT deadline has been missed by more than the
@@ -616,66 +605,21 @@ class ContinuousBatcher:
         if overcommit and not self.paged:
             raise ValueError("overcommit requires the paged KV cache "
                              "(kv_page_size)")
+        # The page pool's books; None for a dense engine, and the
+        # one thing paged dispatch asks.
+        self.pages: Optional[kv_pages.PagePool] = None
+        self.prefix_cache = bool(prefix_cache) and self.paged
         if self.paged:
-            if max_decode_len % kv_page_size:
-                raise ValueError("max_decode_len must be a multiple "
-                                 "of kv_page_size")
-            if kv_num_pages is None:
-                kv_num_pages = num_slots * (
-                    max_decode_len // kv_page_size)
+            self.page_size = kv_page_size
+            self.pages = kv_pages.PagePool(
+                num_slots, kv_num_pages, kv_page_size, max_decode_len,
+                spec_window=self.gamma, overcommit=overcommit,
+                prefix_cache=self.prefix_cache)
+            # The pool's pages and, last, the scratch page.
             self.config = dataclasses.replace(
                 self.config, kv_page_size=kv_page_size,
-                kv_num_pages=kv_num_pages, spec_window=self.gamma)
-            self.page_size = kv_page_size
-            # spec_window widens the table so a speculative verify
-            # block starting near max_decode_len spills its tail
-            # writes onto scratch-backed entries instead of clamping
-            # onto a real page (transformer._decode_attend_paged).
-            self.max_blocks = (max_decode_len + self.gamma
-                               + kv_page_size - 1) // kv_page_size
-            self._free_pages = list(range(kv_num_pages))
-            # Reservation budget: admission reserves each request's
-            # WORST-CASE page count up front (prompt + max_new_tokens)
-            # so lazy growth during decode can never deadlock two
-            # half-grown slots against each other.
-            self._avail_pages = kv_num_pages
-            self._total_pages = kv_num_pages
-            self._slot_reserved = [0] * num_slots
-            # The decode step runs the full slot batch, so INACTIVE
-            # slots keep writing (masked-on-read) K/V through their
-            # block tables. Their tables must therefore never point at
-            # allocatable pages: one extra physical SCRATCH page (index
-            # kv_num_pages) absorbs those writes, and freed slots'
-            # table rows reset to it.
-            self._scratch_page = kv_num_pages
-            self.config = dataclasses.replace(
-                self.config, kv_num_pages=kv_num_pages + 1)
-            self._table = np.full((num_slots, self.max_blocks),
-                                  self._scratch_page, np.int32)
-            self._slot_pages: list[list[int]] = [
-                [] for _ in range(num_slots)]
-            # Prefix-cache state. Page lifecycle: FREE (_free_pages)
-            # -> OWNED (a slot's private _slot_pages) -> PINNED
-            # (indexed, refcount >= 1, referenced via _slot_shared)
-            # -> LRU (indexed, refcount 0, evictable) -> FREE.
-            # Accounting invariant: _avail_pages =
-            # total - pinned - sum(_slot_reserved) — LRU pages still
-            # count as available because _alloc_page can always evict
-            # them; pinned pages cannot be reclaimed while referenced.
-            self._slot_shared: list[list[int]] = [
-                [] for _ in range(num_slots)]
-            self._prefix_index: dict[bytes, int] = {}
-            self._page_key: dict[int, bytes] = {}
-            self._page_ref: dict[int, int] = {}
-            self._lru: "collections.OrderedDict[int, None]" = \
-                collections.OrderedDict()
-        self.prefix_cache = bool(prefix_cache) and self.paged
-        self.prefix_lookups = 0
-        self.prefix_hit_pages = 0
-        self.prefix_hit_tokens = 0
-        self.prefix_total_tokens = 0
-        self.prefix_published = 0
-        self.prefix_evictions = 0
+                kv_num_pages=self.pages.scratch_page + 1,
+                spec_window=self.gamma)
         # SLO scheduling state: live EWMA estimates of prefill cost
         # per bucket token and of the decode step feed admission's
         # stall prediction; sheds/deferrals are the overload
@@ -732,7 +676,7 @@ class ContinuousBatcher:
                  jnp.zeros((num_slots,), jnp.int32),
                  jnp.zeros((num_slots,), jnp.bool_),
                  jax.random.PRNGKey(seed)))
-        if self.paged:
+        if self.pages is not None:
             # Fresh caches default block tables to zeros (a REAL
             # page); point every slot at the scratch page before any
             # step runs.
@@ -781,6 +725,11 @@ class ContinuousBatcher:
                 _speculative_step, self.model, draft_model,
                 self.gamma)
 
+    # tests/benchmark/test_bench_reference.py reads these two off the
+    # engine to build a table row for _prefill_paged.
+    max_blocks = property(lambda self: self.pages.max_blocks)
+    _scratch_page = property(lambda self: self.pages.scratch_page)
+
     # ------------------------------ public -----------------------------
 
     def warmup_buckets(self) -> list[int]:
@@ -816,15 +765,15 @@ class ContinuousBatcher:
             lengths = [min(bucket,
                            self.max_decode_len - max_new_tokens)
                        for bucket in self.warmup_buckets()]
-            if self.paged:
+            if self.pages is not None:
                 # A deliberately tight page pool (overcommit sizing)
                 # cannot admit the longest buckets' worst case: skip
                 # them rather than fail startup — they compile on
                 # first real (admittable) use, as before.
                 lengths = [
                     length for length in lengths
-                    if -(-(length + max_new_tokens)
-                         // self.page_size) <= self._total_pages]
+                    if self.pages.pages_for(length + max_new_tokens)
+                    <= self.pages.num_pages]
         warmed: list[int] = []
 
         def drain(length: int) -> None:
@@ -866,12 +815,7 @@ class ContinuousBatcher:
             # the stats should describe real traffic only — not the
             # warm-up's synthetic lookups and publishes.
             self.prefix_cache_clear()
-            self.prefix_lookups = 0
-            self.prefix_hit_pages = 0
-            self.prefix_hit_tokens = 0
-            self.prefix_total_tokens = 0
-            self.prefix_published = 0
-            self.prefix_evictions = 0
+            self.pages.reset_stats()
         return warmed
 
     def precompile(self) -> int:
@@ -919,7 +863,7 @@ class ContinuousBatcher:
             for bucket in self.warmup_buckets():
                 prompt_abs = jax_mod.ShapeDtypeStruct((1, bucket),
                                                       jnp.int32)
-                if self.paged:
+                if self.pages is not None:
                     row_abs = jax_mod.ShapeDtypeStruct(
                         (self.max_blocks,), jnp.int32)
                     _prefill_paged.lower(
@@ -967,14 +911,14 @@ class ContinuousBatcher:
                 f"{request.request_id}: resumed tokens "
                 f"{len(resumed)} >= max_new_tokens "
                 f"{request.max_new_tokens} — nothing left to decode")
-        if self.paged:
-            worst = -(-(len(request.prompt) + request.max_new_tokens)
-                      // self.page_size)
-            if worst > self._total_pages:
+        if self.pages is not None:
+            worst = self.pages.pages_for(
+                len(request.prompt) + request.max_new_tokens)
+            if worst > self.pages.num_pages:
                 raise ValueError(
                     f"{request.request_id}: worst-case page need "
-                    f"{worst} exceeds the pool ({self._total_pages} "
-                    f"pages) — it could never admit")
+                    f"{worst} exceeds the pool ({self.pages.num_pages}"
+                    f" pages) — it could never admit")
         if len(request.prompt) + request.max_new_tokens > \
                 self.max_decode_len:
             raise ValueError(
@@ -1123,7 +1067,7 @@ class ContinuousBatcher:
             return emitted
         if self.speculative is not None:
             return emitted + self._step_speculative()
-        if self.paged:
+        if self.pages is not None:
             with phases("grow_pages"):
                 self._grow_pages()
         t0 = time.monotonic()
@@ -1170,7 +1114,7 @@ class ContinuousBatcher:
         each slot appends its own 1..gamma+1 committed tokens, with
         per-token eos/max_new checks so a slot can stop mid-block."""
         phases = self._phases
-        if self.paged:
+        if self.pages is not None:
             with phases("grow_pages"):
                 self._grow_pages(span=self.gamma)
         t0 = time.monotonic()
@@ -1221,37 +1165,16 @@ class ContinuousBatcher:
         serve_step row records as its step begins and what /stats
         reports. Pages are counted once however many slots read them.
         Safe to call from another thread than the stepping one (the
-        snapshot may then straddle a step). The page keys are absent
-        from a dense engine.
-
-        kv_blocks_attended is the work of ONE layer's paged decode
-        kernel in a decode step dispatched from this state, in its
-        own unit, the (slot, page) block: ceil(tokens / page) for a
-        slot with a request (its cursor as the kernel will see it,
-        the pending token's row written) and one for a slot without,
-        whose cursor the step programs park at 0 (a slot freed by the
-        last step attends over its old length once more, which the
-        host's books here do not follow). Beside live_tokens it says
-        what share of the kernel's blocks is required work:
-        ceil(live_tokens / page) is the least a kernel could compute,
-        kv_blocks_attended - (slots_total - slots_active) what the
-        requests take, the rest what idle slots cost."""
+        snapshot may then straddle a step). The page keys
+        (kv_pages.PagePool.occupancy) are absent from a dense
+        engine."""
         held = [len(slot.request.prompt) + len(slot.generated)
                 for slot in self._slots if slot.request is not None]
         out = {"slots_active": len(held),
                "slots_total": self.num_slots,
                "queued": len(self._queue), "live_tokens": sum(held)}
-        if self.paged:
-            out["kv_pages_in_use"] = len(
-                {page for held in self._slot_pages + self._slot_shared
-                 for page in held})
-            out["kv_pages_free"] = len(self._free_pages)
-            out["kv_pages_lru"] = len(self._lru)
-            out["kv_pages_total"] = self._total_pages
-            out["prefix_index_pages"] = len(self._page_ref)
-            out["kv_blocks_attended"] = sum(
-                -(-tokens // self.page_size) for tokens in held
-            ) + self.num_slots - len(held)
+        if self.pages is not None:
+            out.update(self.pages.occupancy(held))
         return out
 
     def step_stats(self) -> dict:
@@ -1286,101 +1209,17 @@ class ContinuousBatcher:
     def _free_slot(self, i: int) -> None:
         self._slots[i] = _Slot()
         self._active = self._active.at[i].set(False)
-        if self.paged:
-            self._release_pages(slot=i)
-            # The freed slot keeps decoding (masked) in the full-batch
-            # step: its table must stop referencing returned pages
-            # BEFORE they are reallocated.
-            self._table[i] = self._scratch_page
+        if self.pages is not None:
+            self.pages.release(i)
             self._push_tables()
 
-    def _alloc_page(self, grow_slot: Optional[int] = None) -> int:
-        """THE single page-allocation path: free list first, then
-        LRU-evict an unreferenced indexed page (dropping its index
-        entry — a pinned page is never evicted), then, in overcommit
-        mode during decode growth, preempt a victim slot. Every page
-        a slot's table comes to reference is handed out here;
-        _release_pages is the only way back (the serving-page-refcount
-        lint rule pins both)."""
-        while True:
-            if self._free_pages:
-                return self._free_pages.pop()
-            if self._lru:
-                pid, _ = self._lru.popitem(last=False)
-                key = self._page_key.pop(pid)
-                if self._prefix_index.get(key) == pid:
-                    del self._prefix_index[key]
-                del self._page_ref[pid]
-                self.prefix_evictions += 1
-                return pid
-            if not self.overcommit or grow_slot is None:
-                raise RuntimeError(
-                    "paged KV pool exhausted mid-decode; size "
-                    "kv_num_pages >= num_slots * max_decode_len / "
-                    "page_size to rule this out, or enable "
-                    "overcommit=True for preemption")
-            self._preempt(exclude=grow_slot)
-
-    def _release_pages(self, slot: Optional[int] = None,
-                       pages: Optional[list] = None) -> None:
-        """THE single page-release path (the serving-page-refcount
-        lint rule's counterpart to _alloc_page). slot=i returns slot
-        i's OWNED pages to the free list, drops its SHARED-page
-        references (a refcount reaching zero parks the page in the
-        LRU — never the free list, so no page is freed while another
-        slot's table still reads it), and releases its reservation.
-        pages=[...] frees already-unindexed pages directly
-        (prefix_cache_clear's evictions)."""
-        if pages:
-            self._free_pages.extend(pages)
-        if slot is None:
-            return
-        self._free_pages.extend(self._slot_pages[slot])
-        self._slot_pages[slot] = []
-        for pid in self._slot_shared[slot]:
-            self._page_ref[pid] -= 1
-            if self._page_ref[pid] == 0:
-                self._lru[pid] = None
-                self._avail_pages += 1
-        self._slot_shared[slot] = []
-        self._avail_pages += self._slot_reserved[slot]
-        self._slot_reserved[slot] = 0
-
     def prefix_cache_clear(self) -> int:
-        """Evict every UNREFERENCED indexed page back to the free
-        list (pinned pages stay — they are still read by active
-        slots). Returns the number of pages reclaimed."""
-        dropped = []
-        while self._lru:
-            pid, _ = self._lru.popitem(last=False)
-            key = self._page_key.pop(pid)
-            if self._prefix_index.get(key) == pid:
-                del self._prefix_index[key]
-            del self._page_ref[pid]
-            dropped.append(pid)
-        self._release_pages(pages=dropped)
-        return len(dropped)
+        """kv_pages.PagePool.clear_unreferenced: the pages reclaimed."""
+        return self.pages.clear_unreferenced()
 
     def prefix_stats(self) -> Optional[dict]:
-        """Prefix-cache counters, or None when disabled. hit_rate is
-        TOKEN-level: cached prompt tokens / total prompt tokens seen
-        by paged admission — the fraction of prefill work the index
-        converted into a gather."""
-        if not self.prefix_cache:
-            return None
-        return {
-            "lookups": self.prefix_lookups,
-            "hit_pages": self.prefix_hit_pages,
-            "hit_tokens": self.prefix_hit_tokens,
-            "total_prompt_tokens": self.prefix_total_tokens,
-            "hit_rate": (
-                self.prefix_hit_tokens / self.prefix_total_tokens
-                if self.prefix_total_tokens else 0.0),
-            "indexed_pages": len(self._page_ref),
-            "lru_pages": len(self._lru),
-            "published_pages": self.prefix_published,
-            "evictions": self.prefix_evictions,
-        }
+        """kv_pages.PagePool.stats, or None when disabled."""
+        return self.pages.stats() if self.prefix_cache else None
 
     def slo_stats(self) -> dict:
         """SLO scheduling counters + the live cost estimates
@@ -1394,37 +1233,22 @@ class ContinuousBatcher:
         }
 
     def _grow_pages(self, span: int = 0) -> None:
-        """Allocate pages so every active slot's table covers its next
-        write positions pos..min(pos+span, total-1) — span=0 is the
-        plain one-token decode step (at most one new block per slot);
-        span=gamma is the speculative verify block, which can cross
-        several page boundaries in one step. A slot's block count is
-        shared prefix pages + owned pages; growth only ever appends
-        OWNED pages (decode writes land strictly past the shared
-        prefix). Allocation is capped at the slot's worst-case commit
-        range (speculative tail writes past it land on the scratch
-        page via the table default), so it never exceeds the
-        admission reservation. Pushes the updated tables into every
-        layer's cache copy. In overcommit mode an empty free list
-        preempts a victim instead of raising (a preempted victim's
-        request empties, so the loop skips it)."""
+        """Have the pool cover every active slot's next write
+        positions pos..pos+span (PagePool.grow) and push the tables
+        if a row changed. Under overcommit a dry pool preempts a
+        victim (whose slot the loop then skips) and asks again."""
         positions = np.asarray(self._positions)
         changed = False
         for i in range(self.num_slots):
-            if self._slots[i].request is None:
-                continue
             req = self._slots[i].request
-            total = len(req.prompt) + req.max_new_tokens
-            pos = int(positions[i])
-            needed = min(pos + span, total - 1) // self.page_size + 1
-            while (len(self._slot_shared[i]) +
-                   len(self._slot_pages[i])) < needed:
-                block = (len(self._slot_shared[i]) +
-                         len(self._slot_pages[i]))
-                pagenum = self._alloc_page(grow_slot=i)
-                self._slot_pages[i].append(pagenum)
-                self._table[i, block] = pagenum
-                changed = True
+            while req is not None:
+                try:
+                    changed |= self.pages.grow(
+                        i, int(positions[i]), span,
+                        len(req.prompt) + req.max_new_tokens)
+                    break
+                except kv_pages.PoolDry:
+                    self._preempt(exclude=i)
         if changed:
             self._push_tables()
 
@@ -1472,7 +1296,7 @@ class ContinuousBatcher:
         layer's leaf would be donated once per layer ("Attempt to
         donate the same buffer twice"). One transfer and one small
         program (_table_per_layer), not a transfer per layer."""
-        tables = iter(_table_per_layer(self._put(self._table),
+        tables = iter(_table_per_layer(self._put(self.pages.table),
                                        self.config.n_layers))
 
         def push(leaf_dict):
@@ -1582,9 +1406,8 @@ class ContinuousBatcher:
         if self.prefix_cache:
             # Predict the POST-MATCH suffix cost: a cached prefix
             # pays a gather, not a prefill.
-            matched = self._match_prefix(self._page_keys(
-                entry.request.prompt + entry.resumed), tokens)
-            tokens -= len(matched) * self.page_size
+            tokens -= self.pages.cached_tokens(
+                entry.request.prompt + entry.resumed)
         stall = self._bucket_length(tokens) * \
             self._prefill_ms_per_token
         if stall <= min(targets) * self.tpot_stall_factor:
@@ -1594,69 +1417,6 @@ class ContinuousBatcher:
                 now + stall / 1000.0 >= deadline:
             return False
         return True
-
-    def _page_keys(self, tokens: list[int]) -> list[bytes]:
-        """Chained content hash per FULL page: key_b covers tokens
-        [0, (b+1)*page) via H(key_{b-1} || tokens of page b), so a
-        key identifies the entire prefix up to its page boundary —
-        matching never needs to compare token ids, and equal pages
-        under different prefixes never collide."""
-        keys: list[bytes] = []
-        prev = b""
-        page = self.page_size
-        for b in range(len(tokens) // page):
-            digest = hashlib.blake2b(
-                prev + np.asarray(tokens[b * page:(b + 1) * page],
-                                  np.int64).tobytes(),
-                digest_size=16).digest()
-            keys.append(digest)
-            prev = digest
-        return keys
-
-    def _match_prefix(self, keys: list[bytes],
-                      num_tokens: int) -> list[int]:
-        """Longest indexed page chain, capped so at least one suffix
-        token remains (the first sample needs real last-token logits
-        from a forward)."""
-        limit = (num_tokens - 1) // self.page_size
-        matched: list[int] = []
-        for b in range(min(len(keys), limit)):
-            pid = self._prefix_index.get(keys[b])
-            if pid is None:
-                break
-            matched.append(pid)
-        return matched
-
-    def _publish_pages(self, i: int, keys: list[bytes], m: int,
-                       row: np.ndarray, num_tokens: int) -> None:
-        """Index this admission's fresh FULL pages under their chain
-        keys so later same-prefix requests can share them. A
-        published page moves from the slot's OWNED list into its
-        SHARED set with refcount 1 (held by this slot until it
-        frees): pinned grows by one while the slot's reservation
-        shrinks by one, so availability is unchanged. Only full
-        pages publish — the partial tail stays owned (copy-on-extend:
-        decode keeps writing into it privately)."""
-        full = num_tokens // self.page_size
-        for b in range(m, full):
-            key = keys[b]
-            if key in self._prefix_index:
-                # Duplicate content (an exact-length twin admitted in
-                # the same drain could not match its own final full
-                # page): keep this copy private rather than aliasing
-                # two owners onto one index entry.
-                continue
-            pid = int(row[b])
-            self._slot_pages[i].remove(pid)
-            self._slot_shared[i].append(pid)
-            self._prefix_index[key] = pid
-            self._page_key[pid] = key
-            self._page_ref[pid] = 1
-            if self.overcommit:
-                self._avail_pages -= 1
-            else:
-                self._slot_reserved[i] -= 1
-            self.prefix_published += 1
 
     def _record_prefill_time(self, key, t0: float,
                              n_tokens: int) -> None:
@@ -1686,6 +1446,32 @@ class ContinuousBatcher:
         else:
             self._step_ms = 0.7 * self._step_ms + 0.3 * dt_ms
 
+    def _padded(self, tokens: list[int]):
+        """tokens, zero-padded to their compile bucket: [1, bucket]."""
+        pad = self._bucket_length(len(tokens)) - len(tokens)
+        return self._put(np.asarray([tokens + [0] * pad], np.int32))
+
+    def _prefill_call(self, slot: int, tokens: list[int], prompt,
+                      seat: Optional[kv_pages.Seat]) -> tuple:
+        """A seated request's path (a serve_step row's "path"),
+        compile bucket and tokens to prefill, then the program and
+        its arguments after (params, cache): dense without a pool,
+        shared over the suffix when the seat matched pages of the
+        index, else the cold paged one."""
+        if seat is None:
+            return ("dense", prompt.shape[1], len(tokens),
+                    self._prefill, (slot, prompt, len(tokens)))
+        if not seat.matched:
+            return ("cold", prompt.shape[1], len(tokens),
+                    self._prefill_paged,
+                    (slot, prompt, self._put(seat.row), len(tokens)))
+        suffix = self._padded(tokens[seat.prefix_len:])
+        return ("shared", suffix.shape[1],
+                len(tokens) - seat.prefix_len, self._prefill_shared,
+                (slot, suffix, self._put(seat.prefix_ids),
+                 self._put(seat.row), self._put(seat.suffix_row),
+                 seat.prefix_len, len(tokens)))
+
     def _admit(self) -> None:
         if self.draining:
             # Drain ladder: no new admissions once the preempt/evict
@@ -1700,10 +1486,10 @@ class ContinuousBatcher:
             if slot.request is not None or not self._queue:
                 continue
             # Per request: "admit" is the host's work to seat it
-            # (deferral, page keys, prefix match, allocation, the
-            # table row, the puts), "prefill" runs from the dispatch
-            # of the prefill program to its first token on the host,
-            # "slot_update" writes the slot's device-side state.
+            # (deferral, the pool's seat, the puts), "prefill" runs
+            # from the dispatch of the prefill program to its first
+            # token on the host, "slot_update" writes the slot's
+            # device-side state.
             with phases("admit"):
                 entry = self._queue[0]
                 req = entry.request
@@ -1713,120 +1499,31 @@ class ContinuousBatcher:
                     self.slo_deferrals += 1
                     break
                 # Resumed (preempted) requests re-prefill prompt +
-                # what they had already generated, in one batched
-                # pass.
+                # what they had already generated, in one pass.
                 tokens = req.prompt + entry.resumed
-                bucket = self._bucket_length(len(tokens))
-                padded = tokens + [0] * (bucket - len(tokens))
-                prompt = self._put(np.asarray([padded], np.int32))
+                prompt = self._padded(tokens)
                 t0 = time.monotonic()
-                timed_key = ("dense", bucket)
-                timed_tokens = bucket
-                prefilled = len(tokens)
-                prefill, prefill_args = self._prefill, (
-                    i, prompt, len(tokens))
-                keys: list[bytes] = []
-                m = 0
-                if self.paged:
-                    blocks_needed = -(-len(tokens) // self.page_size)
-                    remaining = req.max_new_tokens - len(entry.resumed)
-                    worst = -(-(len(tokens) + remaining)
-                              // self.page_size)
-                    matched: list[int] = []
-                    if self.prefix_cache:
-                        keys = self._page_keys(tokens)
-                        matched = self._match_prefix(keys, len(tokens))
-                    m = len(matched)
-                    lru_m = sum(1 for pid in matched
-                                if self._page_ref[pid] == 0)
-                    if self.overcommit:
-                        # Take only the prompt's pages (+1 block of
-                        # decode headroom against immediate
-                        # re-thrash); exhaustion during decode
-                        # preempts. Matched pages cost nothing fresh;
-                        # pinning an LRU-parked page consumes one
-                        # evictable unit.
-                        want = min(blocks_needed - m +
-                                   (1 if remaining else 0), worst - m)
-                        if (len(self._free_pages) + len(self._lru)
-                                - lru_m) < want:
-                            break
-                    else:
-                        if self._avail_pages < (worst - m) + lru_m:
-                            # Not enough budget for this request's
-                            # worst case: wait for frees rather than
-                            # risking a mid-decode exhaustion deadlock
-                            # between half-grown slots. The shared
-                            # prefix discounts the budget — reuse IS
-                            # admission headroom.
-                            break
-                        self._avail_pages -= worst - m
-                        self._slot_reserved[i] = worst - m
+                seat = None
+                if self.pages is not None:
+                    seat = self.pages.seat(
+                        i, tokens,
+                        req.max_new_tokens - len(entry.resumed))
+                    if seat is None:
+                        # No room yet: the head waits for frees.
+                        break
                 self._queue.pop(0)
                 if self.on_admit is not None:
                     self.on_admit(req.request_id)
-                if self.paged:
-                    # Pin the matched chain: shared pages are
-                    # immutable (decode writes land strictly past the
-                    # last full prompt page) and never evictable while
-                    # referenced.
-                    for pid in matched:
-                        if self._page_ref[pid] == 0:
-                            del self._lru[pid]
-                            self._avail_pages -= 1
-                        self._page_ref[pid] += 1
-                    self._slot_shared[i] = list(matched)
-                    if self.prefix_cache:
-                        self.prefix_lookups += 1
-                        self.prefix_hit_pages += m
-                        self.prefix_hit_tokens += m * self.page_size
-                        self.prefix_total_tokens += len(tokens)
-                    fresh = [self._alloc_page()
-                             for _ in range(blocks_needed - m)]
-                    self._slot_pages[i] = fresh
-                    row = np.full((self.max_blocks,),
-                                  self._scratch_page, np.int32)
-                    row[:m] = matched
-                    row[m:blocks_needed] = fresh
-                    self._table[i] = row
-                    if m:
-                        prefix_len = m * self.page_size
-                        suffix_tokens = tokens[prefix_len:]
-                        sbucket = self._bucket_length(
-                            len(suffix_tokens))
-                        timed_key = ("shared", sbucket)
-                        timed_tokens = sbucket
-                        prefilled = len(suffix_tokens)
-                        suffix = self._put(np.asarray(
-                            [suffix_tokens +
-                             [0] * (sbucket - len(suffix_tokens))],
-                            np.int32))
-                        prefix_ids = np.full(
-                            (self.max_decode_len // self.page_size,),
-                            self._scratch_page, np.int32)
-                        prefix_ids[:m] = matched
-                        suffix_row = np.full((self.max_blocks,),
-                                             self._scratch_page,
-                                             np.int32)
-                        suffix_row[:blocks_needed - m] = fresh
-                        prefill, prefill_args = self._prefill_shared, (
-                            i, suffix, self._put(prefix_ids),
-                            self._put(row), self._put(suffix_row),
-                            prefix_len, len(tokens))
-                    else:
-                        timed_key = ("paged", bucket)
-                        prefill, prefill_args = self._prefill_paged, (
-                            i, prompt, self._put(row), len(tokens))
+                path, bucket, prefilled, prefill, prefill_args = \
+                    self._prefill_call(i, tokens, prompt, seat)
                 self._admitted.append({
-                    "request_id": req.request_id,
-                    "path": ("cold" if timed_key[0] == "paged"
-                             else timed_key[0]),
-                    "bucket": timed_tokens, "tokens": prefilled})
+                    "request_id": req.request_id, "path": path,
+                    "bucket": bucket, "tokens": prefilled})
             with phases("prefill"):
                 self.cache, last_logits = prefill(
                     self.params, self.cache, *prefill_args)
-                if self.prefix_cache:
-                    self._publish_pages(i, keys, m, row, len(tokens))
+                if seat is not None:
+                    self.pages.publish(i, seat)
                 if self.speculative is not None:
                     # The draft cache must hold the same committed
                     # prefix (the spec-step invariant); its prefill
@@ -1856,4 +1553,4 @@ class ContinuousBatcher:
                 self._active = self._active.at[i].set(True)
                 # int(first[0]) above forced the prefill to complete,
                 # so t0..now is a faithful admission-stall sample.
-                self._record_prefill_time(timed_key, t0, timed_tokens)
+                self._record_prefill_time((path, bucket), t0, bucket)

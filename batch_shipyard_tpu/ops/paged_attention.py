@@ -25,8 +25,8 @@ which Mosaic refuses). That is also how the serving cache STORES the
 pool (models/transformer.py): the reshape from [P, page, H, D] is free
 only on paper — the TPU tiles the two shapes differently, so a pool
 kept [P, page, H, D] was relaid out whole (a read and a write of
-every page) on its way into every call. A 4-D pool is still accepted
-here and folded. Per-head scores come from ONE matmul against a
+every page) on its way into every call. Per-head scores come from
+ONE matmul against a
 block-diagonal query (row h holds q_h in columns h*D..(h+1)*D, zeros
 elsewhere): K[page, H*D] x q_bd[H, H*D]^T -> [page, H]. That puts
 positions on sublanes and heads on lanes, which is exactly the layout
@@ -168,8 +168,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                   lengths, k_scales=None,
                                   v_scales=None):
     """Pallas path. q: [B, 1, H, D]; k_pages/v_pages:
-    [P, page, H*D] (or [P, page, H, D], folded here at the cost of a
-    relayout); block_table: [B, max_blocks] int32; lengths: [B]
+    [P, page, H*D]; block_table: [B, max_blocks] int32; lengths: [B]
     int32 valid-key counts (INCLUDING the token written this step, so
     every attended slot has length >= 1 — a length-0 slot yields zeros
     here but softmax-of-all-masked garbage from the XLA path; the
@@ -184,7 +183,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     (applied in-kernel per tile). Returns [B, 1, H, D] in q.dtype."""
     batch, seq, heads, depth = q.shape
     assert seq == 1, "decode consumes one token per call"
-    num_pages, page = k_pages.shape[:2]
+    page = k_pages.shape[1]
     max_blocks = block_table.shape[1]
     width = heads * depth
     int8_pages = k_scales is not None
@@ -202,13 +201,12 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     page_spec = pl.BlockSpec((None, page, width), page_index)
     scale_spec = pl.BlockSpec((None, page, heads), page_index)
     in_specs = [row_spec, page_spec]
-    operands = [q.reshape(batch, 1, width),
-                k_pages.reshape(num_pages, page, width)]
+    operands = [q.reshape(batch, 1, width), k_pages]
     if int8_pages:
         in_specs.append(scale_spec)
         operands.append(k_scales)
     in_specs.append(page_spec)
-    operands.append(v_pages.reshape(num_pages, page, width))
+    operands.append(v_pages)
     if int8_pages:
         in_specs.append(scale_spec)
         operands.append(v_scales)

@@ -815,43 +815,56 @@ def test_shell_backtick_subst_fires():
 # ---------------------------- serving family ---------------------------
 
 def test_serving_page_refcount_fires_on_direct_free():
-    """Every direct `_free_pages` mutation shape outside the release
-    helper fires: mutating method calls, reassignment, item
+    """Every direct `_free_pages` mutation shape outside the pool's
+    release helper fires, in the pool's own module (a method that
+    frees without settling) and in the engine reaching through
+    `self.pages`: mutating method calls, reassignment, item
     assignment, augassign, and del."""
-    firing = {"batch_shipyard_tpu/models/mod.py": (
-        "class Pool:\n"
-        "    def _preempt(self, i):\n"
-        "        self._free_pages.extend(self._slot_pages[i])\n"
-        "    def reset(self):\n"
-        "        self._free_pages = []\n"
-        "    def patch(self, k, v):\n"
-        "        self._free_pages[k] = v\n"
-        "    def grow(self, pages):\n"
-        "        self._free_pages += pages\n"
-        "    def nuke(self):\n"
-        "        del self._free_pages[0]\n")}
+    firing = {
+        "batch_shipyard_tpu/models/kv_pages.py": (
+            "class PagePool:\n"
+            "    def release(self, slot):\n"
+            "        self._free_pages.extend(self._slot_pages[slot])\n"
+            "    def reset(self):\n"
+            "        self._free_pages = []\n"
+            "    def patch(self, k, v):\n"
+            "        self._free_pages[k] = v\n"),
+        "batch_shipyard_tpu/models/serving.py": (
+            "class ContinuousBatcher:\n"
+            "    def _preempt(self, pages):\n"
+            "        self.pages._free_pages += pages\n"
+            "    def _free_slot(self):\n"
+            "        del self.pages._free_pages[0]\n")}
     found = _rules_of(firing, "serving-page-refcount")
     assert len(found) == 5, [f.render() for f in found]
     assert "_release_pages" in found[0].message
+    assert {f.path for f in found} == set(firing)
 
 
 def test_serving_page_refcount_blessed_shapes_pass():
     """The allowed owners — __init__ seeding, the allocator popping,
-    the release helper returning — plus read-only uses stay silent;
-    module-level mutation outside a def still fires."""
-    blessed = {"batch_shipyard_tpu/models/mod.py": (
-        "class Pool:\n"
+    the release helper returning — plus read-only uses stay silent,
+    and so does models/kv_pages.py as committed; module-level
+    mutation outside a def still fires."""
+    blessed = {"batch_shipyard_tpu/models/kv_pages.py": (
+        "class PagePool:\n"
         "    def __init__(self, n):\n"
         "        self._free_pages = list(range(n))\n"
         "    def _alloc_page(self):\n"
         "        return self._free_pages.pop()\n"
         "    def _release_pages(self, pages):\n"
         "        self._free_pages.extend(pages)\n"
-        "    def stats(self):\n"
+        "    def release(self, slot):\n"
+        "        self._release_pages(self._slot_pages[slot])\n"
+        "    def occupancy(self):\n"
         "        return len(self._free_pages)\n"
-        "    def peek(self):\n"
+        "    def check(self):\n"
         "        return list(self._free_pages)\n")}
     assert not _rules_of(blessed, "serving-page-refcount")
+    committed = "batch_shipyard_tpu/models/kv_pages.py"
+    source = (core.repo_root() / committed).read_text()
+    assert source.count("self._free_pages") >= 4
+    assert not _rules_of({committed: source}, "serving-page-refcount")
     module_level = {"batch_shipyard_tpu/models/mod.py": (
         "pool._free_pages.clear()\n")}
     found = _rules_of(module_level, "serving-page-refcount")

@@ -545,6 +545,17 @@ def test_heimdall_exports_fed_elastic_lease_epoch():
 
 # --------------------------- adoption (unit) ---------------------------
 
+def _assert_slot_ledger_retired(work_dir, within=5.0):
+    """The agent retires the slot ledger LAST (a crash before that
+    leaves the task adoptable), so a moment after the entity's state
+    changed: wait that moment, then hold it to the removal."""
+    ledger = os.path.join(work_dir, "slots", "slot0.json")
+    deadline = time.monotonic() + within
+    while os.path.exists(ledger) and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert not os.path.exists(ledger)
+
+
 def test_adoption_classifies_exited_task_without_rerun(tmp_path):
     """The 'still-valid claim, process already exited' adoption leg:
     a restarted agent finds a slot ledger whose pid is dead but
@@ -624,9 +635,7 @@ def test_adoption_classifies_exited_task_without_rerun(tmp_path):
         kinds = [r["kind"] for r in store.query_entities(
             names.TABLE_GOODPUT, partition_key="adoptpool")]
         assert "adoption" in kinds, kinds
-        # The slot ledger was retired after classification.
-        assert not os.path.exists(
-            os.path.join(work_dir, "slots", "slot0.json"))
+        _assert_slot_ledger_retired(work_dir)
     finally:
         agent.stop()
         agent.join(timeout=5.0)
@@ -709,8 +718,7 @@ def test_adoption_unknowable_container_exit_hands_back_to_reclaim(
         node = store.get_entity(names.TABLE_NODES, "adoptpool",
                                 "n0")
         assert float(node.get("health", 1.0) or 1.0) >= 1.0
-        assert not os.path.exists(
-            os.path.join(work_dir, "slots", "slot0.json"))
+        _assert_slot_ledger_retired(work_dir)
     finally:
         agent.stop()
         agent.join(timeout=5.0)
@@ -793,8 +801,7 @@ def test_adopted_task_wedge_watchdog_enforced(tmp_path):
                     time.monotonic() < kill_deadline:
                 time.sleep(0.05)
             assert proc.poll() is not None
-            assert not os.path.exists(
-                os.path.join(work_dir, "slots", "slot0.json"))
+            _assert_slot_ledger_retired(work_dir)
         finally:
             agent.stop()
             agent.join(timeout=5.0)

@@ -18,13 +18,18 @@ def interpret_mode():
         yield
 
 
+def _folded(pool):
+    """[P, page, H, D] -> the pool's stored layout [P, page, H*D]."""
+    return pool.reshape(*pool.shape[:2], -1)
+
+
 def _random_case(rng, dtype, batch=4, heads=4, depth=64, page=8,
                  max_blocks=6, num_pages=32):
     q = jnp.asarray(rng.randn(batch, 1, heads, depth), dtype)
-    k_pages = jnp.asarray(rng.randn(num_pages, page, heads, depth),
-                          dtype)
-    v_pages = jnp.asarray(rng.randn(num_pages, page, heads, depth),
-                          dtype)
+    k_pages = _folded(jnp.asarray(
+        rng.randn(num_pages, page, heads, depth), dtype))
+    v_pages = _folded(jnp.asarray(
+        rng.randn(num_pages, page, heads, depth), dtype))
     # Distinct physical pages per slot (the allocator's invariant).
     table = jnp.asarray(
         rng.permutation(num_pages)[:batch * max_blocks].reshape(
@@ -144,6 +149,8 @@ def _int8_case(rng, batch=4, heads=4, depth=64, page=8,
                       jnp.float32)
     k_pages, k_scales = quantize_int8_rows(k_f)
     v_pages, v_scales = quantize_int8_rows(v_f)
+    k_pages, v_pages, k_f, v_f = map(
+        _folded, (k_pages, v_pages, k_f, v_f))
     table = jnp.asarray(
         rng.permutation(num_pages)[:batch * max_blocks].reshape(
             batch, max_blocks), jnp.int32)

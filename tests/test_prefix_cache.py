@@ -58,35 +58,6 @@ def _shared_prefix_requests(seed=0, base_pages=3, page=8, n=4):
     return reqs
 
 
-def _check_invariants(engine):
-    """The page lifecycle bookkeeping the prefix cache rests on:
-    FREE / LRU / OWNED / PINNED partition the pool exactly, refcounts
-    equal live slot references, and the availability counter matches
-    total - pinned - reservations."""
-    free = list(engine._free_pages)
-    lru = list(engine._lru)
-    owned = [p for pages in engine._slot_pages for p in pages]
-    pinned = [pid for pid, ref in engine._page_ref.items() if ref > 0]
-    assert set(lru) == {pid for pid, ref in engine._page_ref.items()
-                        if ref == 0}
-    everything = free + lru + owned + pinned
-    assert len(everything) == len(set(everything)), \
-        "a page appears in two lifecycle states at once"
-    assert len(everything) == engine._total_pages, \
-        "pages leaked or double-counted"
-    live_refs: dict = {}
-    for shared in engine._slot_shared:
-        for pid in shared:
-            live_refs[pid] = live_refs.get(pid, 0) + 1
-    assert live_refs == {pid: ref
-                         for pid, ref in engine._page_ref.items()
-                         if ref > 0}, \
-        "refcounts out of sync with slot references"
-    assert engine._avail_pages == (
-        engine._total_pages - len(pinned) -
-        sum(engine._slot_reserved))
-
-
 def test_shared_prefix_matches_cold_baseline(params):
     """Requests hitting a cached 3-page prefix produce EXACTLY the
     tokens cold batch-1 greedy decoding produces — and the shared
@@ -98,7 +69,7 @@ def test_shared_prefix_matches_cold_baseline(params):
     for r in reqs:
         engine.submit(r)
     results = _drain(engine)
-    assert engine.prefix_hit_pages >= 3 * (len(reqs) - 1), \
+    assert engine.pages.stats()["hit_pages"] >= 3 * (len(reqs) - 1), \
         "followers did not reuse the pilot's pages"
     stats = engine.prefix_stats()
     assert stats["hit_rate"] > 0.5
@@ -106,7 +77,7 @@ def test_shared_prefix_matches_cold_baseline(params):
     for r in reqs:
         want = reference_greedy(params, r.prompt, r.max_new_tokens)
         assert results[r.request_id] == want, r.request_id
-    _check_invariants(engine)
+    engine.pages.check()
 
 
 def test_prefix_cache_off_is_cold_path(params):
@@ -119,8 +90,8 @@ def test_prefix_cache_off_is_cold_path(params):
     for r in reqs:
         engine.submit(r)
     results = _drain(engine)
-    assert engine.prefix_hit_pages == 0
-    assert engine.prefix_published == 0
+    assert engine.pages.stats()["hit_pages"] == 0
+    assert engine.pages.stats()["published_pages"] == 0
     assert engine.prefix_stats() is None
     for r in reqs:
         assert results[r.request_id] == reference_greedy(
@@ -138,11 +109,11 @@ def test_shared_prefix_speculative_exact(params):
     for r in reqs:
         engine.submit(r)
     results = _drain(engine)
-    assert engine.prefix_hit_pages > 0
+    assert engine.pages.stats()["hit_pages"] > 0
     for r in reqs:
         assert results[r.request_id] == reference_greedy(
             params, r.prompt, r.max_new_tokens), r.request_id
-    _check_invariants(engine)
+    engine.pages.check()
 
 
 def test_shared_prefix_int8_pages_identical_to_cold(params):
@@ -159,7 +130,7 @@ def test_shared_prefix_int8_pages_identical_to_cold(params):
             engine.submit(r)
         outs[on] = _drain(engine)
         if on:
-            assert engine.prefix_hit_pages > 0
+            assert engine.pages.stats()["hit_pages"] > 0
     assert outs[True] == outs[False]
 
 
@@ -183,7 +154,7 @@ def test_refcount_invariants_under_churn(params):
     for step in range(600):
         for rid, toks in engine.step():
             results[rid] = toks
-        _check_invariants(engine)
+        engine.pages.check()
         if step == 5:
             # Mid-flight cancel: an active slot's pages (shared AND
             # owned) must release cleanly.
@@ -198,9 +169,10 @@ def test_refcount_invariants_under_churn(params):
         req = next(r for r in reqs if r.request_id == rid)
         assert results[rid] == reference_greedy(
             params, req.prompt, req.max_new_tokens), rid
-    assert all(ref == 0 for ref in engine._page_ref.values())
-    assert (len(engine._free_pages) + len(engine._lru)
-            == engine._total_pages)
+    occupancy = engine.occupancy()
+    assert occupancy["kv_pages_in_use"] == 0
+    assert (occupancy["kv_pages_free"] + occupancy["kv_pages_lru"]
+            == occupancy["kv_pages_total"])
 
 
 def test_lru_eviction_under_pool_pressure(params):
@@ -220,8 +192,8 @@ def test_lru_eviction_under_pool_pressure(params):
         results = _drain(engine)
         assert results[f"e{i}"] == reference_greedy(
             params, prompt, 4)
-        _check_invariants(engine)
-    assert engine.prefix_evictions > 0
+        engine.pages.check()
+    assert engine.pages.stats()["evictions"] > 0
 
 
 def test_prefix_cache_clear_and_rewarm(params):
@@ -234,18 +206,20 @@ def test_prefix_cache_clear_and_rewarm(params):
         CFG, params, num_slots=1, max_decode_len=64, kv_page_size=8)
     engine.submit(serving.Request("a", base + [3], max_new_tokens=3))
     _drain(engine)
-    published = engine.prefix_published
+    published = engine.pages.stats()["published_pages"]
     assert published >= 2
     cleared = engine.prefix_cache_clear()
-    assert cleared == len(engine._page_ref) == 0 or cleared >= 2
-    assert len(engine._prefix_index) == 0
-    hits_before = engine.prefix_hit_pages
+    assert cleared >= 2
+    assert engine.pages.stats()["indexed_pages"] == 0
+    assert engine.occupancy()["prefix_index_pages"] == 0
+    hits_before = engine.pages.stats()["hit_pages"]
     engine.submit(serving.Request("b", base + [9], max_new_tokens=3))
     results = _drain(engine)
-    assert engine.prefix_hit_pages == hits_before  # cold again
-    assert engine.prefix_published > published
+    # cold again
+    assert engine.pages.stats()["hit_pages"] == hits_before
+    assert engine.pages.stats()["published_pages"] > published
     assert results["b"] == reference_greedy(params, base + [9], 3)
-    _check_invariants(engine)
+    engine.pages.check()
 
 
 # ----------------------- bench phase (slow) ------------------------

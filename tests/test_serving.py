@@ -146,8 +146,11 @@ def test_paged_pool_overcommit_admission_waits(params):
     # All pages reclaimable after drain: free, or parked unreferenced
     # in the prefix-cache LRU (indexed for reuse, evictable on
     # demand) — none pinned.
-    assert len(engine._free_pages) + len(engine._lru) == 3
-    assert all(ref == 0 for ref in engine._page_ref.values())
+    engine.pages.check()
+    occupancy = engine.occupancy()
+    assert occupancy["kv_pages_in_use"] == 0
+    assert (occupancy["kv_pages_free"]
+            + occupancy["kv_pages_lru"]) == 3
 
 
 def test_paged_freed_slot_cannot_corrupt_recycled_pages(params):
@@ -290,8 +293,11 @@ def test_overcommit_preemption_matches_greedy(params):
     for r in reqs:
         assert results[r.request_id] == reference_greedy(
             params, r.prompt, r.max_new_tokens), r.request_id
-    assert len(engine._free_pages) + len(engine._lru) == 5
-    assert all(ref == 0 for ref in engine._page_ref.values())
+    engine.pages.check()
+    occupancy = engine.occupancy()
+    assert occupancy["kv_pages_in_use"] == 0
+    assert (occupancy["kv_pages_free"]
+            + occupancy["kv_pages_lru"]) == 5
 
 
 def test_overcommit_beats_reservation_when_generations_are_short():
@@ -472,11 +478,9 @@ def _outside_view(engine):
             len(slot.request.prompt) + len(slot.generated)
             for slot in engine._slots if slot.request is not None)}
     if engine.paged:
+        table = engine.pages.table
         view["kv_pages_in_use"] = len(
-            {page for i in range(engine.num_slots)
-             for held in (engine._slot_pages[i],
-                          engine._slot_shared[i])
-             for page in held})
+            set(table.ravel()) - {engine.pages.scratch_page})
     return view
 
 
@@ -513,11 +517,11 @@ def test_every_step_writes_one_row_that_agrees_with_the_private_lists(
         assert 0 < phase_ms <= (row["end"] - row["start"]) * 1e3 + 1e-6
         assert attrs["prefills"] == len(attrs["admitted"])
     if engine.paged:
-        assert all(r["attrs"]["kv_pages_total"] == engine._total_pages
+        assert all(r["attrs"]["kv_pages_total"] == engine.pages.num_pages
                    and r["attrs"]["kv_pages_free"]
                    + r["attrs"]["kv_pages_lru"]
                    + r["attrs"]["kv_pages_in_use"]
-                   <= engine._total_pages for r in rows)
+                   <= engine.pages.num_pages for r in rows)
     else:
         assert "kv_pages_in_use" not in rows[0]["attrs"]
     admitted = [a for r in rows for a in r["attrs"]["admitted"]]

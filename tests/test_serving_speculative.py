@@ -198,12 +198,13 @@ def test_paged_spec_crosses_pages_at_max_decode_len(params,
                                              max_decode_len=32)
     assert results["b2"] == reference_greedy(params, p2, 20,
                                              max_decode_len=32)
-    pool = list(engine._free_pages) + list(engine._lru)
-    assert len(pool) == len(set(pool))
     # All pages reclaimable after drain: free or parked unreferenced
-    # in the prefix-cache LRU.
-    assert len(pool) == 8
-    assert all(ref == 0 for ref in engine._page_ref.values())
+    # in the prefix-cache LRU, each once (check()).
+    engine.pages.check()
+    occupancy = engine.occupancy()
+    assert occupancy["kv_pages_in_use"] == 0
+    assert (occupancy["kv_pages_free"]
+            + occupancy["kv_pages_lru"]) == 8
 
 
 def test_overcommit_preemption_with_speculation(params, noisy_params):
@@ -228,8 +229,11 @@ def test_overcommit_preemption_with_speculation(params, noisy_params):
         assert results[r.request_id] == reference_greedy(
             params, r.prompt, r.max_new_tokens,
             max_decode_len=32), r.request_id
-    assert len(engine._free_pages) + len(engine._lru) == 5
-    assert all(ref == 0 for ref in engine._page_ref.values())
+    engine.pages.check()
+    occupancy = engine.occupancy()
+    assert occupancy["kv_pages_in_use"] == 0
+    assert (occupancy["kv_pages_free"]
+            + occupancy["kv_pages_lru"]) == 5
 
 
 def test_speculative_rejects_bad_configs(params, dparams):

@@ -156,6 +156,12 @@ _PAGED_CASES = (
 )
 
 
+def _folded(pool):
+    """[P, page, H, D] -> the pool as the serving cache stores it and
+    the kernel blocks it, [P, page, H*D]."""
+    return pool.reshape(*pool.shape[:2], -1)
+
+
 def _paged_case(rng, heads, page, batch=8, depth=64, max_blocks=8):
     num_pages = batch * max_blocks + 1
     q = jnp.asarray(rng.randn(batch, 1, heads, depth), jnp.float32)
@@ -183,7 +189,8 @@ def check_paged_attention() -> bool:
         with _exact_if_fp32(dtype):
             q, k_f, v_f, table, lengths = _paged_case(
                 np.random.RandomState(11), heads, page)
-            q, k_p, v_p = (x.astype(dtype) for x in (q, k_f, v_f))
+            q, k_p, v_p = (x.astype(dtype)
+                           for x in (q, _folded(k_f), _folded(v_f)))
             out_k = jax.jit(paged.paged_decode_attention_kernel)(
                 q, k_p, v_p, table, lengths)
             out_x = paged.paged_decode_attention_xla(
@@ -311,6 +318,7 @@ def check_paged_attention_int8() -> bool:
             q = q.astype(dtype)
             kp, ks = quantize_int8_rows(k_f)
             vp, vs = quantize_int8_rows(v_f)
+            kp, vp, k_f, v_f = map(_folded, (kp, vp, k_f, v_f))
             out_k = jax.jit(
                 lambda *a: paged.paged_decode_attention_kernel(
                     *a[:5], k_scales=a[5], v_scales=a[6]))(
