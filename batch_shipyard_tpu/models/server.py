@@ -1101,6 +1101,10 @@ class ServingFrontEnd:
         return {
             **occupancy,
             "steps": count,
+            "decode_steps": steps["decode_steps"],
+            "steps_overlapped": steps["steps_overlapped"],
+            "settles": steps["settles"],
+            "overshoot_tokens": steps["overshoot_tokens"],
             "step_ms_mean": steps["step_seconds"] * per_step,
             "phase_ms_mean": {
                 name: seconds * per_step

@@ -43,6 +43,11 @@ from batch_shipyard_tpu.trace import spans as trace_spans
 # profiler trace and a ``<phase>_ms`` attr of the step's row.
 STEP_PHASES = ("admit", "prefill", "slot_update", "grow_pages",
                "dispatch", "readback", "emit")
+# Why a decode step in flight was read back BEFORE its successor was
+# dispatched (ContinuousBatcher._settle), so that the successor did
+# not overlap it: a prefill about to block, a dry pool, a cancel, a
+# drain, or nothing left to dispatch.
+SETTLE_CAUSES = ("admit", "preempt", "cancel", "drain", "idle")
 
 
 @functools.partial(jax.jit, static_argnames=("model", "sampling"),
@@ -469,6 +474,37 @@ class SpeculativeConfig:
 class _Slot:
     request: Optional[Request] = None
     generated: list[int] = dataclasses.field(default_factory=list)
+    # Tokens of this request that a dispatched decode step computes
+    # and the host has not read yet: what the host's books are behind
+    # the device by (0 or 1 between step() calls).
+    in_flight: int = 0
+
+    def decoding(self) -> bool:
+        """Whether the next decode step advances this slot: seated
+        and, the token in flight counted, still short of
+        max_new_tokens. The host knows that finish before the token
+        is computed; an eos finish it learns from the token."""
+        return (self.request is not None and
+                len(self.generated) + self.in_flight
+                < self.request.max_new_tokens)
+
+    def held_tokens(self) -> int:
+        """Cached tokens the next decode step attends over, the row
+        it writes included: one more than its write position."""
+        return (len(self.request.prompt) + len(self.generated) +
+                self.in_flight)
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A decode step the device was handed whose tokens the host has
+    not read: the [B] token array (and the step's key, which dies
+    with it), the (slot, request) pairs the step advances, taken at
+    dispatch, and when that was."""
+    tokens: object
+    key: object
+    seated: list[tuple[int, Request]]
+    dispatched_at: float
 
 
 @dataclasses.dataclass
@@ -648,6 +684,16 @@ class ContinuousBatcher:
         self.steps_total = 0
         self.step_seconds_total = 0.0
         self.traced_steps = 0
+        # The one-step lookahead (_step): the decode step in flight,
+        # requests it finished that no step() has returned yet, and
+        # its counters (step_stats).
+        self._in_flight: Optional[_InFlight] = None
+        self._finished: list[tuple[str, list[int]]] = []
+        self._landed_at = 0.0
+        self.decode_steps = 0
+        self.steps_overlapped = 0
+        self.settles = dict.fromkeys(SETTLE_CAUSES, 0)
+        self.overshoot_tokens = 0
         # Row ids count up from a random start: a uuid4 a step costs
         # a getrandom() call, a third of a millisecond where that is
         # slow.
@@ -676,6 +722,8 @@ class ContinuousBatcher:
                  jnp.zeros((num_slots,), jnp.int32),
                  jnp.zeros((num_slots,), jnp.bool_),
                  jax.random.PRNGKey(seed)))
+        # _active as the host last pushed it (_push_active).
+        self._active_host = np.zeros((num_slots,), np.bool_)
         if self.pages is not None:
             # Fresh caches default block tables to zeros (a REAL
             # page); point every slot at the scratch page before any
@@ -929,7 +977,10 @@ class ContinuousBatcher:
                                   submitted_at=time.monotonic()))
 
     def pending(self) -> int:
-        return len(self._queue) + sum(
+        """Requests queued or seated, and finished ones the next
+        step() has yet to return (a cancel can land the step in
+        flight between two steps)."""
+        return len(self._queue) + len(self._finished) + sum(
             1 for s in self._slots if s.request is not None)
 
     def drain(self) -> list[str]:
@@ -940,6 +991,7 @@ class ContinuousBatcher:
         finish (or the front end's grace deadline cancels them).
         Idempotent; must be called from the engine's stepping
         thread — it mutates the queue like _admit does."""
+        self._settle("drain")
         self.draining = True
         evicted = [e.request.request_id for e in self._queue]
         self._queue.clear()
@@ -967,20 +1019,29 @@ class ContinuousBatcher:
     def cancel(self, request_id: str) -> bool:
         """Abort a queued or actively-decoding request (the vLLM-class
         abort operation). Queued entries are removed; an active slot
-        is freed immediately (its pages return to the pool). Must be
-        called from the engine's stepping thread — it mutates slot
-        state like step() does. Returns False when the id is unknown
-        (already finished)."""
+        is freed immediately (its pages return to the pool), after
+        the step in flight has been read back: every token computed
+        for the request before the cancel is delivered, and one that
+        ends it finishes it (False then, and the next step() returns
+        it). Must be called from the engine's stepping thread — it
+        mutates slot state like step() does. Returns False when the
+        id is unknown (already finished)."""
         for k, entry in enumerate(self._queue):
             if entry.request.request_id == request_id:
                 del self._queue[k]
                 return True
-        for i, slot in enumerate(self._slots):
-            if slot.request is not None and \
-                    slot.request.request_id == request_id:
-                self._free_slot(i)
-                return True
-        return False
+        seat = next((i for i, slot in enumerate(self._slots)
+                     if slot.request is not None and
+                     slot.request.request_id == request_id), None)
+        if seat is None:
+            return False
+        # The step in flight may hold the request's next token, or
+        # its last, which frees the slot by itself.
+        self._settle("cancel")
+        if self._slots[seat].request is None:
+            return False
+        self._free_slot(seat)
+        return True
 
     # Step-row head-sampling, the request spans' rule
     # (server.ServingFrontEnd._SPAN_HEAD): the first _STEP_HEAD steps
@@ -994,37 +1055,56 @@ class ContinuousBatcher:
         """Admit queued requests into free slots, decode for every
         active slot — one token per step, or a gamma-token
         draft/verify block per slot when speculative decoding is
-        configured — and emit finished requests. Every part of the
-        step runs inside one of STEP_PHASES; with the span recorder
-        on, a step that did anything also writes a serve_step row."""
+        configured — and return the requests that finished. Without
+        a draft model ONE DECODE STEP STAYS IN FLIGHT (_step): this
+        call dispatches the next one and only then reads back and
+        emits the tokens of the one before, so a request's last
+        token, and with it the request, comes back from the call
+        AFTER the one that dispatched it. Every part of the step
+        runs inside one of STEP_PHASES; with the span recorder on, a
+        step that did anything also writes a serve_step row."""
         phases = self._phases
         phases.reset()
         self._admitted.clear()
         self._step_tokens = 0
         traced = trace_spans.local_spans_path() is not None
         wall0, t0 = time.time(), time.monotonic()
-        before = compiles0 = None
+        before = compiles0 = lookahead0 = None
         if traced and (self.traced_steps < self._STEP_HEAD or
                        (self.traced_steps + 1)
                        % self._STEP_SAMPLE_EVERY == 0):
             before = self.occupancy()
             compiles0 = self._compiles.read()
-        emitted = self._step()
+            lookahead0 = self._lookahead_counts()
+        self._step()
         seconds = time.monotonic() - t0
-        if not self._admitted and "dispatch" not in phases.step:
-            return emitted      # nothing to seat, nothing to decode
+        finished, self._finished = self._finished, []
+        if not self._admitted and \
+                phases.step.keys().isdisjoint(("dispatch", "readback")):
+            return finished     # nothing to seat, decode or read back
         self.steps_total += 1
         self.step_seconds_total += seconds
         if traced:
             self.traced_steps += 1
         if before is not None:
             self._record_step_row(wall0, t0, seconds, before,
-                                  compiles0, len(emitted))
-        return emitted
+                                  compiles0, lookahead0, len(finished))
+        return finished
+
+    def _lookahead_counts(self) -> dict:
+        """The lookahead's cumulative counters: decode steps
+        dispatched, how many of them while their predecessor was
+        still unread, the settles by cause (SETTLE_CAUSES), and the
+        tokens computed for a request that had already ended."""
+        return {"decode_steps": self.decode_steps,
+                "steps_overlapped": self.steps_overlapped,
+                "settles": dict(self.settles),
+                "overshoot_tokens": self.overshoot_tokens}
 
     def _record_step_row(self, wall0: float, t0: float,
                          seconds: float, before: dict,
-                         compiles0: tuple, finished: int) -> None:
+                         compiles0: tuple, lookahead0: dict,
+                         finished: int) -> None:
         attrs = {"mono_start": t0}
         for name in STEP_PHASES:
             attrs[f"{name}_ms"] = self._phases.step.get(name,
@@ -1035,6 +1115,18 @@ class ContinuousBatcher:
         attrs["admitted"] = list(self._admitted)
         attrs["tokens_emitted"] = self._step_tokens
         attrs["finished"] = finished
+        # What this call added to the lookahead's counters: whether
+        # its decode step overlapped the one before, why not (the
+        # settles' causes), the overshoot it discarded.
+        now = self._lookahead_counts()
+        attrs["overlapped"] = (now["steps_overlapped"]
+                               - lookahead0["steps_overlapped"])
+        attrs["settles"] = [
+            cause for cause in SETTLE_CAUSES
+            for _ in range(now["settles"][cause]
+                           - lookahead0["settles"][cause])]
+        attrs["overshoot_tokens"] = (now["overshoot_tokens"]
+                                     - lookahead0["overshoot_tokens"])
         attrs.update(before)
         count, compile_s = self._compiles.read()
         if count > compiles0[0]:
@@ -1044,13 +1136,27 @@ class ContinuousBatcher:
             trace_spans.SPAN_SERVE_STEP, wall0, wall0 + seconds,
             span_id=f"{next(self._row_ids) & 0xffffffff:08x}", **attrs)
 
-    def _step(self) -> list[tuple[str, list[int]]]:
+    def _step(self) -> None:
+        """One call's work; what finished goes to self._finished.
+
+        Without a draft model one decode step stays in flight. Step
+        k's inputs (cache, tokens, positions) are device arrays that
+        step k-1 returned, so the device needs nothing from the host
+        to go from one to the next: this call grows the pages for
+        step k and dispatches it from the HOST's books (_Slot:
+        a slot's write position counts its token in flight, and a
+        finish by max_new_tokens is known before the token is), and
+        only then reads back and emits step k-1 (_land), while the
+        device computes step k. No phase before that readback reads
+        from the device. Whatever edits slots outside this order
+        (cancel, a dry pool's preemption, drain, a prefill about to
+        block) first lands the step in flight through _settle; an
+        idle engine has none."""
         phases = self._phases
         self._admit()
         # Slots whose prefill-sampled first token already satisfied the
         # request (max_new_tokens == 1 or immediate eos) emit without a
         # decode step.
-        emitted: list[tuple[str, list[int]]] = []
         with phases("emit"):
             for i, slot in enumerate(self._slots):
                 req = slot.request
@@ -1060,31 +1166,83 @@ class ContinuousBatcher:
                 if (len(slot.generated) >= req.max_new_tokens or
                         (req.eos_id is not None and
                          last == req.eos_id)):
-                    emitted.append((req.request_id,
-                                    list(slot.generated)))
+                    self._finished.append((req.request_id,
+                                           list(slot.generated)))
                     self._free_slot(i)
-        if not any(s.request is not None for s in self._slots):
-            return emitted
         if self.speculative is not None:
-            return emitted + self._step_speculative()
+            # Serial: every seated slot decodes, nothing is in flight.
+            seated = self._decoding()
+            if seated:
+                self._push_active(seated)
+                self._finished += self._step_speculative()
+            return
         if self.pages is not None:
             with phases("grow_pages"):
                 self._grow_pages()
+        seated = self._decoding()
+        if not seated:
+            # Every seated request waits for its last token only.
+            self._settle("idle")
+            return
+        previous = self._in_flight
         t0 = time.monotonic()
         with phases("dispatch"):
+            self._push_active(seated)
             self._key, step_key = jax.random.split(self._key)
             self.cache, self._tokens, self._positions, next_tok = \
                 self._decode_step(self.params, self.cache,
                                   self._tokens, self._positions,
                                   self._active, step_key)
+            self._in_flight = _InFlight(next_tok, step_key, seated, t0)
+            del next_tok, step_key      # _land lets the arrays die
+            for i, _ in seated:
+                self._slots[i].in_flight += 1
+        self.decode_steps += 1
+        if previous is None:
+            return
+        self.steps_overlapped += 1
+        self._land(previous)
+        if not any(s.request is not None for s in self._slots):
+            # An eos ended the last request: the step in flight
+            # computes overshoot alone.
+            self._settle("idle")
+
+    def _settle(self, cause: str) -> bool:
+        """Read back and emit the decode step in flight, if there is
+        one (True then), so that the host's books and the device
+        agree and nothing is owed to any request: what everything
+        that edits slots outside _step's own order calls first.
+        ``cause`` is one of SETTLE_CAUSES."""
+        step, self._in_flight = self._in_flight, None
+        if step is None:
+            return False
+        self.settles[cause] += 1
+        self._land(step)
+        return True
+
+    def _land(self, step: _InFlight) -> None:
+        """Wait for a dispatched step's tokens and emit them, each
+        only to the request its slot held at dispatch: a request that
+        ended on its eos_id one step earlier was still decoded, and
+        that token is dropped here (overshoot_tokens; its K/V row
+        lies past the request's last token, in a page no index
+        names, and the slot's next prefill is ordered behind the
+        step by the cache it consumes)."""
+        phases = self._phases
         with phases("readback"):
-            next_host = np.asarray(next_tok)
-        self._record_step_time(t0)
+            next_host = np.asarray(step.tokens)
+        # The step PERIOD: from the step before landing, unless this
+        # one was dispatched later than that.
+        self._record_step_time(max(step.dispatched_at,
+                                   self._landed_at))
+        self._landed_at = time.monotonic()
         with phases("emit"):
-            for i, slot in enumerate(self._slots):
-                req = slot.request
-                if req is None:
+            for i, req in step.seated:
+                slot = self._slots[i]
+                if slot.request is not req:
+                    self.overshoot_tokens += 1
                     continue
+                slot.in_flight -= 1
                 token = int(next_host[i])
                 slot.generated.append(token)
                 self._step_tokens += 1
@@ -1095,8 +1253,8 @@ class ContinuousBatcher:
                         (req.eos_id is not None and
                          token == req.eos_id))
                 if done:
-                    emitted.append((req.request_id,
-                                    list(slot.generated)))
+                    self._finished.append((req.request_id,
+                                           list(slot.generated)))
                     self._free_slot(i)
             # The step's device arrays die here, inside the phase:
             # their destructor releases the GIL, and that is when the
@@ -1104,8 +1262,7 @@ class ContinuousBatcher:
             # turn (a millisecond or two with a dozen streams). It is
             # emit's cost, so it is counted here and not after every
             # phase has ended.
-            del next_tok, step_key
-        return emitted
+            step.tokens = step.key = None
 
     def _step_speculative(self) -> list[tuple[str, list[int]]]:
         """One ragged draft/verify/commit round (see the spec_step
@@ -1168,8 +1325,8 @@ class ContinuousBatcher:
         snapshot may then straddle a step). The page keys
         (kv_pages.PagePool.occupancy) are absent from a dense
         engine."""
-        held = [len(slot.request.prompt) + len(slot.generated)
-                for slot in self._slots if slot.request is not None]
+        held = [slot.held_tokens() for slot in self._slots
+                if slot.decoding()]
         out = {"slots_active": len(held),
                "slots_total": self.num_slots,
                "queued": len(self._queue), "live_tokens": sum(held)}
@@ -1179,12 +1336,15 @@ class ContinuousBatcher:
 
     def step_stats(self) -> dict:
         """Cumulative step counters since the engine was built: steps
-        that admitted or decoded, their wall seconds, the seconds of
-        each phase, and the process's compile count (programs built
-        or loaded from the persistent cache, and their seconds)."""
+        that admitted, decoded or read a step back, their wall
+        seconds, the lookahead's counters (_lookahead_counts), the
+        seconds of each phase, and the process's compile count
+        (programs built or loaded from the persistent cache, and
+        their seconds)."""
         compiles, compile_seconds = self._compiles.read()
         return {"steps": self.steps_total,
                 "step_seconds": self.step_seconds_total,
+                **self._lookahead_counts(),
                 "phase_seconds": dict(self._phases.total),
                 "compiles": compiles,
                 "compile_seconds": compile_seconds}
@@ -1208,7 +1368,6 @@ class ContinuousBatcher:
 
     def _free_slot(self, i: int) -> None:
         self._slots[i] = _Slot()
-        self._active = self._active.at[i].set(False)
         if self.pages is not None:
             self.pages.release(i)
             self._push_tables()
@@ -1234,21 +1393,28 @@ class ContinuousBatcher:
 
     def _grow_pages(self, span: int = 0) -> None:
         """Have the pool cover every active slot's next write
-        positions pos..pos+span (PagePool.grow) and push the tables
-        if a row changed. Under overcommit a dry pool preempts a
-        victim (whose slot the loop then skips) and asks again."""
-        positions = np.asarray(self._positions)
+        positions pos..pos+span (PagePool.grow), by the host's books,
+        and push the tables if a row changed. Under overcommit a dry
+        pool first lands the step in flight, which may give pages
+        back, then preempts a victim (whose slot the loop then
+        skips), and asks again each time."""
         changed = False
         for i in range(self.num_slots):
-            req = self._slots[i].request
-            while req is not None:
+            # Read anew each time round: a settle can finish and a
+            # preemption evict whoever sat here.
+            while (slot := self._slots[i]).decoding():
+                req = slot.request
                 try:
                     changed |= self.pages.grow(
-                        i, int(positions[i]), span,
+                        i, slot.held_tokens() - 1, span,
                         len(req.prompt) + req.max_new_tokens)
                     break
                 except kv_pages.PoolDry:
-                    self._preempt(exclude=i)
+                    # The step in flight may finish a request and
+                    # give its pages back: land it, then ask again,
+                    # before anybody is evicted.
+                    if not self._settle("preempt"):
+                        self._preempt(exclude=i)
         if changed:
             self._push_tables()
 
@@ -1257,7 +1423,10 @@ class ContinuousBatcher:
         (cheapest re-prefill), reclaim its pages, and re-queue its
         request AT THE HEAD with its generated-so-far tokens so
         resumption re-prefills prompt+generated and continues — the
-        greedy continuation is unchanged. Returns the victim index."""
+        greedy continuation is unchanged. No step is in flight here
+        (_grow_pages settles before it evicts), so the victim's
+        generated list is all it was served. Returns the victim
+        index."""
         candidates = [
             j for j in range(self.num_slots)
             if j != exclude and self._slots[j].request is not None]
@@ -1288,6 +1457,23 @@ class ContinuousBatcher:
         """Host array -> the engine's device (the default device when
         the engine is not pinned)."""
         return jax.device_put(host_array, self.device)
+
+    def _decoding(self) -> list[tuple[int, Request]]:
+        """(slot, request) for every slot the next decode step
+        advances (_Slot.decoding), from the host's books."""
+        return [(i, slot.request)
+                for i, slot in enumerate(self._slots)
+                if slot.decoding()]
+
+    def _push_active(self, seated: list[tuple[int, Request]]) -> None:
+        """The step programs' ``active`` mask for a step that
+        advances ``seated``. Sent only when it differs from what the
+        device has."""
+        mask = np.zeros((self.num_slots,), np.bool_)
+        mask[[i for i, _ in seated]] = True
+        if not np.array_equal(mask, self._active_host):
+            self._active_host = mask
+            self._active = self._put(mask)
 
     def _push_tables(self) -> None:
         """Write the canonical block table into every layer's cache
@@ -1519,6 +1705,9 @@ class ContinuousBatcher:
                 self._admitted.append({
                     "request_id": req.request_id, "path": path,
                     "bucket": bucket, "tokens": prefilled})
+            # The prefill blocks on its first token: nobody's decoded
+            # token waits that out unread (it is ready long before).
+            self._settle("admit")
             with phases("prefill"):
                 self.cache, last_logits = prefill(
                     self.params, self.cache, *prefill_args)
@@ -1550,7 +1739,6 @@ class ContinuousBatcher:
                 self._tokens = self._tokens.at[i, 0].set(first[0])
                 self._positions = self._positions.at[i].set(
                     len(tokens))
-                self._active = self._active.at[i].set(True)
                 # int(first[0]) above forced the prefill to complete,
                 # so t0..now is a faithful admission-stall sample.
                 self._record_prefill_time((path, bucket), t0, bucket)

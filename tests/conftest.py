@@ -33,3 +33,27 @@ def tmp_statestore(tmp_path):
 def mem_statestore():
     from batch_shipyard_tpu.state.memory import MemoryStateStore
     return MemoryStateStore()
+
+
+@pytest.fixture()
+def recorder(tmp_path, monkeypatch):
+    """The process-local span recorder switched on, as the agent (or
+    the benchmark's traced run) switches it: by the environment.
+    -> a function that flushes and returns the rows written."""
+    import json
+
+    from batch_shipyard_tpu.trace import spans as trace_spans
+    path = tmp_path / "spans.jsonl"
+    trace_spans.flush()
+    monkeypatch.setenv("SHIPYARD_TRACE_FILE", str(path))
+    monkeypatch.setenv("SHIPYARD_TRACE_ID", "trace-1")
+    monkeypatch.setenv("SHIPYARD_TRACE_SPAN_ID", "run-1")
+
+    def rows():
+        trace_spans.flush()
+        if not path.exists():
+            return []
+        with open(path, encoding="utf-8") as fh:
+            return [json.loads(line) for line in fh]
+
+    return rows

@@ -2,14 +2,28 @@
 schedule of 60 requests (four shared prefixes, exact-length twins,
 cancels, one clear of the index) through a tiny CPU engine on a pool
 too small for its slots, so admission waits, the LRU evicts and, under
-overcommit, slots are preempted. The digests below were recorded on
-the commit BEFORE the pool left ContinuousBatcher for
-models/kv_pages.py (PR 29's parent, 4d9c6dd): every block-table row
-after every step, prefix_stats() and occupancy(). Which page a request
+overcommit, slots are preempted. The digests fold every block-table
+row after every step, prefix_stats() and occupancy(); they were first
+recorded on the commit BEFORE the pool left ContinuousBatcher for
+models/kv_pages.py (PR 29's parent, 4d9c6dd), which PR 29
+reproduced. Which page a request
 is handed decides which prefix pages survive under pressure, so a
 change of the pool that moves a digest has changed the hit rate. The
 served tokens are not part of it (no request has an eos, so nothing
-the books see depends on a token's value)."""
+the books see depends on a token's value).
+
+PR 30 moved the digests BY DESIGN and they were recorded anew, once,
+on its finished tree: the engine keeps one decode step in flight, so
+a request's last token is read back, and its pages come back, in the
+step() call AFTER the one that dispatched it (the table rows after
+that call still hold them), its successor is seated a call later, and
+occupancy() counts the token in flight. Under pressure that shifts
+which pages the LRU has to give when. What the lookahead must NOT
+shift is held beside the digests, on numbers recorded on PR 30's
+parent (72efe5f): with room for every page (no eviction, no wait, no
+cancel) the index's lookups, hits and published pages are a function
+of the order of admission alone, which is the queue's; and the pool's
+books balance (pages.check()) after every step of every run."""
 
 import hashlib
 import json
@@ -54,10 +68,11 @@ def _schedule(seed: int = 11) -> list[serving.Request]:
     return reqs
 
 
-def run_schedule(engine) -> tuple[str, dict]:
+def run_schedule(engine, press: bool = True) -> tuple[str, dict]:
     """Drive the schedule, folding the books into one digest after
     every step; returns it with the final counters (for a failure's
-    message)."""
+    message). ``press`` False leaves out the cancels and the clear,
+    whose victims depend on what is seated at that very step."""
     digest = hashlib.sha256()
     reqs = _schedule()
     finished = []
@@ -66,13 +81,14 @@ def run_schedule(engine) -> tuple[str, dict]:
             for req in reqs[:5]:
                 engine.submit(req)
             del reqs[:5]
-        if step in (9, 23, 37):
+        if press and step in (9, 23, 37):
             active = engine.active_request_ids()
             if active:
                 engine.cancel(sorted(active)[0])
-        if step == 30:
+        if press and step == 30:
             engine.prefix_cache_clear()
         finished += [rid for rid, _ in engine.step()]
+        engine.pages.check()
         digest.update(_table(engine).tobytes())
         digest.update(json.dumps(
             [engine.prefix_stats(), engine.occupancy(),
@@ -86,36 +102,56 @@ def run_schedule(engine) -> tuple[str, dict]:
         "preemptions": engine.preemptions}
 
 
-# Recorded on the parent commit (see the module docstring).
+# Recorded on PR 30's finished tree (see the module docstring).
 GOLDEN = {
     "reservation": (
-        "485d77cdf94e9d8018a529c9c756a9c9"
-        "f3a2874ae273ce1076fce2775a8e706b",
+        "c3d9f1ff2a3021262cb8968471edcf99"
+        "ffb6f1d7d557c7069a72b93126f2fe3b",
         dict(kv_num_pages=13)),
     "overcommit": (
-        "c258a3e3cf965c4913bde01f50934d63"
-        "b98bcbe3c248b7a7ee975f003bbe1892",
+        "2bbad565ce8124e9f05305cf96532a4a"
+        "dee10a10646fbd3a290754b312e31990",
         dict(kv_num_pages=9, overcommit=True)),
     "reservation-no-prefix-cache": (
-        "b62d6a0f9202447977d41f690026ec49"
-        "3ef8256ce7991f6b5c968248602c6d0a",
+        "2aa2a03585ab8d73397ef9f6d5afaecc"
+        "38b873398c24a20678edccc05f087320",
         dict(kv_num_pages=13, prefix_cache=False)),
     "overcommit-no-prefix-cache": (
-        "471671c38f76c5e7e94d6541c1688a1a"
-        "4775e9da00f2df40edabe5161ae3b47b",
+        "2b65b11e534061d43d6b35cd5bbc4db0"
+        "9d58aa5417f349d53241ee25afcb60a0",
         dict(kv_num_pages=9, overcommit=True, prefix_cache=False)),
 }
+
+
+def _engine(**kwargs):
+    model = tfm.TransformerLM(CFG)
+    params = model.init(jax.random.PRNGKey(7),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return serving.ContinuousBatcher(
+        CFG, params, num_slots=3, max_decode_len=64,
+        kv_page_size=PAGE, **kwargs)
+
+
+# prefix_stats() after the 60 requests on a pool of 400 pages, on PR
+# 30's parent (72efe5f), under either policy.
+NO_PRESSURE = {"lookups": 60, "hit_pages": 95, "hit_tokens": 760,
+               "total_prompt_tokens": 1299, "published_pages": 41,
+               "indexed_pages": 41, "evictions": 0}
+
+
+@pytest.mark.parametrize("overcommit", [False, True])
+def test_without_pressure_the_hits_are_the_parents(overcommit):
+    engine = _engine(kv_num_pages=400, overcommit=overcommit)
+    _digest, final = run_schedule(engine, press=False)
+    assert final["finished"] == 60 and final["preemptions"] == 0
+    assert {key: final["prefix"][key] for key in NO_PRESSURE} == \
+        NO_PRESSURE
 
 
 @pytest.mark.parametrize("policy", sorted(GOLDEN))
 def test_allocation_order_is_the_parents(policy):
     want, kwargs = GOLDEN[policy]
-    model = tfm.TransformerLM(CFG)
-    params = model.init(jax.random.PRNGKey(7),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-    engine = serving.ContinuousBatcher(
-        CFG, params, num_slots=3, max_decode_len=64,
-        kv_page_size=PAGE, **kwargs)
+    engine = _engine(**kwargs)
     got, final = run_schedule(engine)
     # The schedule is worth pinning only while it presses the pool.
     if final["prefix"] is not None:

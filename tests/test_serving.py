@@ -420,30 +420,9 @@ def test_chunked_prefill_greedy_equivalent(params):
 
 # ----------------------- step tracing (serve_step) ----------------------
 
-import json  # noqa: E402
 import os  # noqa: E402
 
 from batch_shipyard_tpu.trace import spans as trace_spans  # noqa: E402
-
-
-@pytest.fixture()
-def recorder(tmp_path, monkeypatch):
-    """The process-local span recorder switched on, as the agent (or
-    the benchmark's traced run) switches it: by the environment."""
-    path = tmp_path / "spans.jsonl"
-    trace_spans.flush()
-    monkeypatch.setenv("SHIPYARD_TRACE_FILE", str(path))
-    monkeypatch.setenv("SHIPYARD_TRACE_ID", "trace-1")
-    monkeypatch.setenv("SHIPYARD_TRACE_SPAN_ID", "run-1")
-
-    def rows():
-        trace_spans.flush()
-        if not path.exists():
-            return []
-        with open(path, encoding="utf-8") as fh:
-            return [json.loads(line) for line in fh]
-
-    return rows
 
 
 def _traced_engine(kind, params):
@@ -468,15 +447,21 @@ def _shared_prefix_requests(count=4):
 
 
 def _outside_view(engine):
-    """What benchmark/drivers/serve.py::StepRecorder reads off the
-    engine's private lists as a step starts."""
+    """What benchmark/drivers/serve.py::StepRecorder reads as a step
+    starts, from the engine's private lists: the slots the call's
+    decode step advances (a request whose last token is in flight
+    holds its slot and is not one) and the tokens they attend over,
+    the one in flight included."""
+    decoding = [slot for slot in engine._slots
+                if slot.request is not None
+                and len(slot.generated) + slot.in_flight
+                < slot.request.max_new_tokens]
     view = {
-        "slots_active": sum(1 for slot in engine._slots
-                            if slot.request is not None),
+        "slots_active": len(decoding),
         "queued": len(engine._queue),
         "live_tokens": sum(
             len(slot.request.prompt) + len(slot.generated)
-            for slot in engine._slots if slot.request is not None)}
+            + slot.in_flight for slot in decoding)}
     if engine.paged:
         table = engine.pages.table
         view["kv_pages_in_use"] = len(
@@ -599,12 +584,15 @@ def test_step_rows_are_head_sampled_and_the_counters_are_not(
     while engine.pending():
         engine.step()
         steps += 1
-    assert steps == 13 == engine.steps_total == engine.traced_steps
+    # 13 decode steps, and a 14th call that only reads the last back
+    assert steps == 14 == engine.steps_total == engine.traced_steps
+    assert engine.step_stats()["decode_steps"] == 13
     rows = recorder()
     # steps 1-3 in full, then every fourth: 4, 8, 12. A step adds a
-    # token; the first adds the prefill's too (3 + 2 live at step 2).
+    # token; the first adds the prefill's too (3 + 2 live at step 2,
+    # the token in flight counted).
     assert [r["attrs"]["live_tokens"] for r in rows] == \
         [0, 5, 6, 7, 11, 15]
     # an engine with nothing to seat or decode writes nothing
     engine.step()
-    assert engine.steps_total == 13 and len(recorder()) == 6
+    assert engine.steps_total == 14 and len(recorder()) == 6

@@ -73,10 +73,13 @@ def _cursors(engine):
 
 def _cached_tokens(engine):
     """Per slot what the host's books say the cache holds: every token
-    of the request but the pending one, 0 for a slot without one."""
+    of the request but the pending one (a token still in flight is
+    one the device has, and the host has not), 0 for a slot without
+    a request."""
     return np.asarray([
         0 if slot.request is None
-        else len(slot.request.prompt) + len(slot.generated) - 1
+        else (len(slot.request.prompt) + len(slot.generated)
+              + slot.in_flight - 1)
         for slot in engine._slots])
 
 
@@ -143,9 +146,12 @@ def test_idle_cursors_are_zero_and_live_ones_the_cached_tokens(
     """After every step program of a run that seats, frees and
     re-seats slots, every cursor leaf of every layer reads 0 exactly
     where the program's ``active`` was false (a slot never used, one
-    freed a step or more ago) and the tokens the slot has cached where
-    it was true; a slot the step freed after its program ran reads its
-    old length until the next step parks it."""
+    that has its last token) and the tokens the slot has cached where
+    it was true. The serial (speculative) engine frees a slot after
+    its last program ran, so it reads its old length until the next
+    step parks it; the engine with a step in flight knows a finish by
+    max_new_tokens beforehand, so the slot is idle in the very next
+    program, which parks it in the call that frees it."""
     rng = np.random.RandomState(3)
     engine = _engine(kind, params)
     seen = _watch_active(engine)
@@ -153,6 +159,7 @@ def test_idle_cursors_are_zero_and_live_ones_the_cached_tokens(
         engine.submit(req)
     idle_checked = freed_then_parked = 0
     lagging: set = set()
+    was_active: set = set()
     while engine.pending():
         programs = len(seen)
         engine.step()
@@ -168,10 +175,15 @@ def test_idle_cursors_are_zero_and_live_ones_the_cached_tokens(
         idle_checked += int((~active).sum())
         freed_then_parked += len(lagging - set(np.flatnonzero(active)))
         lagging = set(np.flatnonzero(active & ~held))
+        if engine.speculative is None:
+            assert not lagging
+            freed_then_parked += len(was_active
+                                     - set(np.flatnonzero(active)))
+        was_active = set(np.flatnonzero(active))
     assert idle_checked >= 5 and freed_then_parked >= 2
     # stepped on with nothing live: _active is false everywhere, and
     # so is every cursor of every layer, in both caches
-    assert lagging
+    assert lagging or engine.speculative is None
     engine.submit(_requests(rng, 1, 6, [2], name="last")[0])
     while engine.pending():
         engine.step()
@@ -229,16 +241,19 @@ def test_a_long_request_is_served_alike_beside_forty_freed_ones(
 def test_kv_blocks_attended_is_the_kernels_count(kind, params):
     """occupancy()'s kv_blocks_attended, from the host's books, against
     the device's own cursors as the decode kernel will see them (the
-    pending row written: cursor + 1): equal at every step but the one
-    after a slot was freed, when that slot attends over its old length
-    once more and the host already counts it as parked."""
+    pending row written: cursor + 1; the step in flight has advanced
+    them): equal at every step but the one after a slot's last, when
+    that slot (its last token in flight, the request still seated)
+    attends over its old length once more and the host already counts
+    it as idle."""
     rng = np.random.RandomState(9)
     engine = _engine(kind, params, num_slots=4)
     for req in _requests(rng, 6, 11, [4, 19, 33, 7]):
         engine.submit(req)
     exact = lagging = 0
-    freed: list = []
     while engine.pending():
+        freed = [i for i, slot in enumerate(engine._slots)
+                 if slot.request is not None and not slot.decoding()]
         length = next(leaf for path, leaf in _cursors(engine)
                       if "layer_0" in path)
         kernel = -(-(length + 1) // PAGE)
@@ -252,10 +267,7 @@ def test_kv_blocks_attended_is_the_kernels_count(kind, params):
             assert state["kv_blocks_attended"] >= -(
                 -state["live_tokens"] // PAGE) + (
                     state["slots_total"] - state["slots_active"])
-        before = [slot.request for slot in engine._slots]
         engine.step()
-        freed = [i for i, slot in enumerate(engine._slots)
-                 if slot.request is None and before[i] is not None]
     assert exact >= 20 and lagging >= 3
     assert engine.occupancy()["kv_blocks_attended"] == 4
     assert "kv_blocks_attended" not in _engine(
