@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from batch_shipyard_tpu.models import ssm
 from batch_shipyard_tpu.models import transformer as tfm
 
 
@@ -101,9 +102,7 @@ def generate(model: tfm.TransformerLM, params, cache, prompt,
         return_hidden=True, mutable=["cache"])
     cache = mutated["cache"]
     pos = jnp.int32(prompt_len)
-    embedding = params["embed"]["embedding"]
-    last_logits = jnp.dot(hidden[:, -1].astype(jnp.float32),
-                          embedding.astype(jnp.float32).T)
+    last_logits = tfm.output_logits(model.config, params, hidden[:, -1])
     key, sample_key = jax.random.split(key)
     first = _sample(last_logits, sample_key, sampling)
     (cache, _tok, _pos, _key), generated = jax.lax.scan(
@@ -139,6 +138,18 @@ def _park_idle_cursors(cache, active):
     the slot is read again. Per-slot state without a cursor key is
     left alone."""
     return _map_cursors(lambda leaf: jnp.where(active, leaf, 0), cache)
+
+
+# Cache leaves that are a layer's fixed-size state per slot: a slot
+# row, no cursor, no pages.
+SLOT_STATE_LEAVES = ssm.STATE_LEAVES
+
+
+def slot_state_bytes(cache) -> int:
+    """Bytes of per-slot state ONE slot holds, over all layers."""
+    leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
+    return sum(leaf.nbytes // leaf.shape[0] for path, leaf in leaves
+               if getattr(path[-1], "key", None) in SLOT_STATE_LEAVES)
 
 
 def _map_cursors(fn, cache):

@@ -420,3 +420,139 @@ def moe_param_specs():
         (r".*moe/(w_gate|w_up)$", P("ep", "fsdp", "tp")),
         (r".*moe/w_down$", P("ep", "tp", "fsdp")),
     ]
+
+
+# --------------------------------------------------------------------
+# Routed experts with NO capacity, for serving: a token's output never
+# depends on who shares its step.
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedConfig:
+    """Sigmoid-scored top-k experts beside one shared expert, and which
+    of the experts THIS chip holds: the router always scores all
+    ``n_experts`` and takes its ``top_k``; the layer computes the
+    choices that fall on experts first_expert .. first_expert +
+    experts_held - 1 and adds nothing for the others (in an
+    expert-parallel deployment the chips holding them add their part;
+    on one chip the layer runs without that exchange)."""
+    d_model: int = 512
+    n_experts: int = 8           # the router's outputs
+    top_k: int = 2
+    d_expert: int = 1024
+    d_shared: int = 2048
+    scale: float = 1.0           # on the normalised weights
+    experts_held: Optional[int] = None   # None = all of them
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return (self.n_experts if self.experts_held is None
+                else self.experts_held)
+
+
+def route_sigmoid(scores_in, bias, top_k: int, scale: float):
+    """scores_in [M, n] float32 router outputs -> (chosen [M, k] int32,
+    weights [M, k] float32): the k largest of sigmoid + bias, weighed
+    by their sigmoids alone, normalised to sum ``scale``."""
+    scores = jax.nn.sigmoid(scores_in)
+    _best, chosen = jax.lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True)
+                        + 1e-20) * scale
+    return chosen.astype(jnp.int32), weights
+
+
+def dense_experts(rows, chosen, weights, up, down, first: int):
+    """sum_i w_i Expert_i(row) over the chosen experts that are held,
+    as two matmuls over ALL the held experts: rows [M, d] against up
+    [E, d, f] gives every expert's hidden [M, E, f]; each is squared-
+    relu'd and multiplied by the row's weight for that expert (0 where
+    the row did not choose it); then ONE contraction over (E, f)
+    against down [E, f, d] sums the experts' outputs in the matmul's
+    own accumulator. Nothing is sorted, gathered or dropped, and a
+    row's output is a function of that row alone. Each expert's
+    weights are read once whatever the rows: the least a step can do
+    when nearly every held expert is hit (96 rows x 6 of 128 leave one
+    held expert in a hundred unchosen); the price is M x E x d x f
+    multiply-adds where k / E of them are wanted. The other road,
+    the (row, choice) pairs sorted by expert and one grouped matmul
+    (jax.lax.ragged_dot), was written first and measured on the v5e:
+    the compiler's grouped matmul takes 6.5 ms a call at 64 groups
+    whatever the rows and copies a whole expert stack for it, 3.8
+    times slower end to end in decode steps and in batch-1 prefills
+    alike (PERF.md, PR 31), so it is not kept; it is the road to take
+    again when rows far outnumber experts AND a fast grouped matmul
+    exists. rows [M, d]; chosen / weights [M, k]; up [E, d, f], down
+    [E, f, d] the held experts first .. first+E-1; Expert(x) =
+    down(relu(up x)^2). -> float32 [M, d]."""
+    held = up.shape[0]
+    local = chosen - first
+    # [M, E]: the row's weight for each held expert
+    weigh = jnp.sum(jnp.where(
+        local[:, :, None] == jnp.arange(held), weights[:, :, None], 0.0),
+        axis=1)
+    hidden = jax.lax.dot_general(
+        rows, up, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)              # [M, E, f]
+    hidden = (jnp.square(jax.nn.relu(hidden))
+              * weigh[:, :, None]).astype(rows.dtype)
+    return jax.lax.dot_general(
+        hidden, down, (((1, 2), (0, 1)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+class RoutedExperts(nn.Module):
+    """x -> sum_i w_i Expert_i(x) [held experts] + Shared(x), every
+    expert down(relu(up x)^2) without gate or bias, with no capacity
+    (dense_experts). The router runs in float32. The choices
+    [B, T, k] (indices over all n_experts) are sown into the
+    "decisions" collection, for a serving engine to hand to whoever
+    checks them (serving.ContinuousBatcher.take_decisions).
+    """
+    config: RoutedConfig
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        batch, length, d_model = x.shape
+        kernel = nn.initializers.lecun_normal()
+        stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                               batch_axis=(0,))
+        router = self.param("router_kernel", kernel,
+                            (d_model, cfg.n_experts), self.param_dtype)
+        bias = self.param("e_score_correction_bias",
+                          nn.initializers.zeros, (cfg.n_experts,),
+                          jnp.float32)
+        up = self.param("experts_up", stacked,
+                        (cfg.held, d_model, cfg.d_expert),
+                        self.param_dtype)
+        down = self.param("experts_down", stacked,
+                          (cfg.held, cfg.d_expert, d_model),
+                          self.param_dtype)
+        shared_up = self.param("shared_up", kernel,
+                               (d_model, cfg.d_shared), self.param_dtype)
+        shared_down = self.param("shared_down", kernel,
+                                 (cfg.d_shared, d_model),
+                                 self.param_dtype)
+        rows = x.reshape(batch * length, d_model).astype(self.dtype)
+        # bfloat16 operands multiply exactly into float32: the router
+        # is a float32 computation on the activations as they are
+        chosen, weights = route_sigmoid(
+            jnp.dot(rows, router.astype(self.dtype),
+                    preferred_element_type=jnp.float32),
+            bias, cfg.top_k, cfg.scale)
+        self.sow("decisions", "chosen",
+                 chosen.reshape(batch, length, cfg.top_k))
+        routed = dense_experts(
+            rows, chosen, weights, up.astype(self.dtype),
+            down.astype(self.dtype), cfg.first_expert)
+        hidden = jnp.dot(rows, shared_up.astype(self.dtype),
+                         preferred_element_type=jnp.float32)
+        hidden = jnp.square(jax.nn.relu(hidden)).astype(self.dtype)
+        shared = jnp.dot(hidden, shared_down.astype(self.dtype),
+                         preferred_element_type=jnp.float32)
+        return (routed + shared).astype(self.dtype).reshape(
+            batch, length, d_model)

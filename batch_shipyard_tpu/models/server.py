@@ -1105,6 +1105,10 @@ class ServingFrontEnd:
             "steps_overlapped": steps["steps_overlapped"],
             "settles": steps["settles"],
             "overshoot_tokens": steps["overshoot_tokens"],
+            # a routed model's expert counters (absent otherwise)
+            **{name: steps[name] for name in (
+                "expert_pairs_here", "expert_pairs_chosen",
+                "experts_hit") if name in steps},
             "step_ms_mean": steps["step_seconds"] * per_step,
             "phase_ms_mean": {
                 name: seconds * per_step

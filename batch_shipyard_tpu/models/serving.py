@@ -20,6 +20,7 @@ host-side Python, compute is two compiled functions (prefill, step).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -48,6 +49,9 @@ STEP_PHASES = ("admit", "prefill", "slot_update", "grow_pages",
 # not overlap it: a prefill about to block, a dry pool, a cancel, a
 # drain, or nothing left to dispatch.
 SETTLE_CAUSES = ("admit", "preempt", "cancel", "drain", "idle")
+# What a step program lets the model write: the cache, and the expert
+# choices a routed layer sows (nothing, for a model without one).
+_MUTABLE = ["cache", "decisions"]
 
 
 @functools.partial(jax.jit, static_argnames=("model", "sampling"),
@@ -67,10 +71,14 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
     The cache passed in is deleted by the call — rebind it from the
     result in the same statement, and read nothing through an older
     reference. Parameters are shared between replicas and are not
-    donated."""
+    donated.
+
+    A model with layers that choose experts (tfm.decision_layer_names)
+    gives one result more, behind the four: the step's choices, int32
+    [decision layers, B, k], each slot's at the position it fed."""
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
-        positions=positions[:, None], mutable=["cache"])
+        positions=positions[:, None], mutable=_MUTABLE)
     next_tok = inf._sample(logits[:, 0].astype(jnp.float32),
                            key, sampling)
     # Inactive slots DO write one garbage row a step, and that is
@@ -87,7 +95,11 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
     next_tok = jnp.where(active, next_tok, tokens[:, 0])
     positions = jnp.where(active, positions + 1, positions)
     cache = inf._park_idle_cursors(mutated["cache"], active)
-    return cache, next_tok[:, None], positions, next_tok
+    chosen = tfm.collect_decisions(mutated.get("decisions"),
+                                   model.config)
+    if chosen is None:
+        return cache, next_tok[:, None], positions, next_tok
+    return cache, next_tok[:, None], positions, next_tok, chosen[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -184,31 +196,60 @@ def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
 
     The last-token logits come from the final hidden state at
     prompt_len-1 (return_hidden + a [d, vocab] matvec) so the full
-    [L, vocab] fp32 logits tensor never materializes."""
-    small = inf.init_cache(model, params, 1)
-    total = prompt.shape[1]
+    [L, vocab] fp32 logits tensor never materializes.
+
+    What is NOT masked on read is a layer's running state
+    (models/ssm.py): the model is told how many of each segment's
+    tokens are the prompt's own (valid_len), and leaves the state of
+    position prompt_len-1. -> (cache, last logits, the routed layers'
+    choices [decision layers, L, k] or None)."""
+    return _prefill_segments(
+        model, prefill_chunk, params, inf.init_cache(model, params, 1),
+        prompt, 0, prompt_len)
+
+
+def _prefill_segments(model, prefill_chunk, params, cache, tokens,
+                      start, prompt_len):
+    """``tokens`` [1, S] at positions start.. through the batch-1
+    cache, in chunks; the logits at position prompt_len-1."""
+    total = tokens.shape[1]
     chunk = min(prefill_chunk or total, total)
-    hiddens = []
-    cache = small
+    hiddens, chosen = [], []
     for off in range(0, total, chunk):
-        seg = prompt[:, off:off + chunk]
+        seg = tokens[:, off:off + chunk]
         # Positions are GLOBAL offsets: RoPE for chunk c must match
         # the full-sequence pass exactly.
         h, mut = model.apply(
             {"params": params, "cache": cache}, seg,
             return_hidden=True,
-            positions=jnp.arange(
+            positions=start + jnp.arange(
                 off, off + seg.shape[1], dtype=jnp.int32),
-            mutable=["cache"])
+            valid_len=prompt_len - start - off, mutable=_MUTABLE)
         cache = mut["cache"]
         hiddens.append(h)
+        chosen.append(tfm.collect_decisions(mut.get("decisions"),
+                                            model.config))
     hidden = (hiddens[0] if len(hiddens) == 1
               else jnp.concatenate(hiddens, axis=1))
-    last_h = jnp.take(hidden[0], prompt_len - 1, axis=0)     # [d]
-    embedding = params["embed"]["embedding"]
-    last = jnp.dot(embedding.astype(jnp.float32),
-                   last_h.astype(jnp.float32))               # [vocab]
-    return cache, last
+    last_h = jnp.take(hidden[0], prompt_len - start - 1, axis=0)  # [d]
+    last = tfm.output_logits(model.config, params, last_h)   # [vocab]
+    if chosen[0] is not None:
+        chosen = jnp.concatenate(chosen, axis=2)[:, 0]
+    else:
+        chosen = None
+    return cache, last, chosen
+
+
+def _with_decisions(cache, last, chosen):
+    """A prefill program's results: the routed layers' choices behind
+    the two every model gives, for a model that has such layers."""
+    return (cache, last) if chosen is None else (cache, last, chosen)
+
+
+def _seat_state(big, small, slot):
+    """A per-slot state leaf [B, ...] with the batch-1 prefill's in
+    the slot's row: whatever the slot held before is gone."""
+    return big.at[slot].set(small[0].astype(big.dtype))
 
 
 def _pool_rows(rows, pool):
@@ -230,8 +271,8 @@ def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
     prompt_len. Module-level jit with a static model: same-config
     engines (fleet replicas, draft/target pairs) share one compile
     per length bucket."""
-    small, last = _dense_prefill(model, prefill_chunk, params, prompt,
-                                 prompt_len)
+    small, last, chosen = _dense_prefill(model, prefill_chunk, params,
+                                         prompt, prompt_len)
 
     def scatter(big, sm, path_key):
         if path_key == "index":
@@ -243,7 +284,7 @@ def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
             big, sm, kp[-1].key if hasattr(kp[-1], "key")
             else str(kp[-1])),
         cache, small)
-    return cache, last
+    return _with_decisions(cache, last, chosen)
 
 
 @functools.partial(jax.jit,
@@ -257,9 +298,10 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
     Full pages are written unconditionally: blocks past the
     allocation point at the scratch page (which absorbs
     padded-garbage writes), and partial-page garbage is
-    masked-on-read via the true length."""
-    small, last = _dense_prefill(model, prefill_chunk, params, prompt,
-                                 prompt_len)
+    masked-on-read via the true length. A layer's per-slot state
+    (a leaf with a slot row and no pages) is overwritten whole."""
+    small, last, chosen = _dense_prefill(model, prefill_chunk, params,
+                                         prompt, prompt_len)
     # Bucket blocks, static (ceil: a bucket smaller than one page
     # still needs its first page written; the small cache has
     # max_decode_len >= n_blocks*page rows).
@@ -294,9 +336,11 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
                 out["k_page_scales"] = ksc
                 out["v_page_scales"] = vsc
             return out
+        if not isinstance(big, dict):
+            return _seat_state(big, sm, slot)
         return {key: scatter(big[key], sm[key]) for key in big}
 
-    return scatter(cache, small), last
+    return _with_decisions(scatter(cache, small), last, chosen)
 
 
 @functools.partial(jax.jit,
@@ -331,6 +375,11 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
     small = inf.init_cache(model, params, 1)
 
     def seed(big, sm):
+        if not isinstance(big, dict):
+            # A per-slot state is of no page: the engine sends a
+            # model that has one down the whole-prompt path instead.
+            raise NotImplementedError(
+                "a shared-prefix prefill cannot seed a per-slot state")
         if isinstance(big, dict) and "k_pages" in big:
             rows = tfm.prefix_rows_from_pages(big, prefix_ids, page)
             nrows = rows["k"].shape[0]
@@ -351,26 +400,10 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
             return out
         return {key: seed(big[key], sm[key]) for key in sm}
 
-    small = seed(cache, small)
+    small, last, chosen = _prefill_segments(
+        model, prefill_chunk, params, seed(cache, small), suffix,
+        prefix_len, prompt_len)
     total = suffix.shape[1]
-    chunk = min(prefill_chunk or total, total)
-    hiddens = []
-    for off in range(0, total, chunk):
-        seg = suffix[:, off:off + chunk]
-        h, mut = model.apply(
-            {"params": params, "cache": small}, seg,
-            return_hidden=True,
-            positions=prefix_len + jnp.arange(
-                off, off + seg.shape[1], dtype=jnp.int32),
-            mutable=["cache"])
-        small = mut["cache"]
-        hiddens.append(h)
-    hidden = (hiddens[0] if len(hiddens) == 1
-              else jnp.concatenate(hiddens, axis=1))
-    last_h = jnp.take(hidden[0], prompt_len - prefix_len - 1, axis=0)
-    embedding = params["embed"]["embedding"]
-    last = jnp.dot(embedding.astype(jnp.float32),
-                   last_h.astype(jnp.float32))
     # Suffix rows live at SMALL-cache rows prefix_len.. — dynamic
     # slices per page. Starts are page-multiples (prefix_len is a
     # whole number of pages), so the only slices that can clamp at
@@ -412,7 +445,7 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
             return out
         return {key: scatter(big[key], sm[key]) for key in big}
 
-    return scatter(cache, small), last
+    return _with_decisions(scatter(cache, small), last, chosen)
 
 
 @functools.partial(jax.jit, static_argnames=("copies",))
@@ -500,11 +533,14 @@ class _InFlight:
     """A decode step the device was handed whose tokens the host has
     not read: the [B] token array (and the step's key, which dies
     with it), the (slot, request) pairs the step advances, taken at
-    dispatch, and when that was."""
+    dispatch, when that was, and what the step's routed layers chose."""
     tokens: object
     key: object
     seated: list[tuple[int, Request]]
     dispatched_at: float
+    # The routed layers' choices of this step, int32 [decision layers,
+    # B, k] on the device; None for a model without such layers.
+    chosen: object = None
 
 
 @dataclasses.dataclass
@@ -641,6 +677,27 @@ class ContinuousBatcher:
         if overcommit and not self.paged:
             raise ValueError("overcommit requires the paged KV cache "
                              "(kv_page_size)")
+        # A model whose layers keep a fixed-size state per slot
+        # (models/ssm.py) beside the K/V: the state rides in the cache
+        # tree with a slot row and no cursor, is overwritten when a
+        # request is seated and belongs to no page.
+        self.stateful = tfm.has_slot_state(config)
+        if speculative is not None and (
+                self.stateful or not config.tie_embeddings):
+            raise ValueError(
+                "speculative serving rewinds the cache by its cursor "
+                "and scores drafts through the tied embedding: not "
+                "for a model with a per-slot state or an lm_head")
+        # The layers that choose experts per position: their choices
+        # leave every step program with the tokens and are kept per
+        # request until take_decisions hands them over.
+        self._decision_layers = tfm.decision_layer_names(config)
+        self._decisions: dict[str, dict] = {}
+        self._decisions_done: collections.OrderedDict = \
+            collections.OrderedDict()
+        self.expert_pairs_here = 0
+        self.expert_pairs_chosen = 0
+        self.experts_hit = 0
         # The page pool's books; None for a dense engine, and the
         # one thing paged dispatch asks.
         self.pages: Optional[kv_pages.PagePool] = None
@@ -722,6 +779,7 @@ class ContinuousBatcher:
                  jnp.zeros((num_slots,), jnp.int32),
                  jnp.zeros((num_slots,), jnp.bool_),
                  jax.random.PRNGKey(seed)))
+        self._slot_state_bytes = inf.slot_state_bytes(self.cache)
         # _active as the host last pushed it (_push_active).
         self._active_host = np.zeros((num_slots,), np.bool_)
         if self.pages is not None:
@@ -1075,7 +1133,8 @@ class ContinuousBatcher:
                        % self._STEP_SAMPLE_EVERY == 0):
             before = self.occupancy()
             compiles0 = self._compiles.read()
-            lookahead0 = self._lookahead_counts()
+            lookahead0 = {**self._lookahead_counts(),
+                          **self._expert_counts()}
         self._step()
         seconds = time.monotonic() - t0
         finished, self._finished = self._finished, []
@@ -1127,6 +1186,10 @@ class ContinuousBatcher:
                            - lookahead0["settles"][cause])]
         attrs["overshoot_tokens"] = (now["overshoot_tokens"]
                                      - lookahead0["overshoot_tokens"])
+        if self._decision_layers:
+            # of the decode step this call landed (none: all 0)
+            for name, value in self._expert_counts().items():
+                attrs[name] = value - lookahead0[name]
         attrs.update(before)
         count, compile_s = self._compiles.read()
         if count > compiles0[0]:
@@ -1166,9 +1229,7 @@ class ContinuousBatcher:
                 if (len(slot.generated) >= req.max_new_tokens or
                         (req.eos_id is not None and
                          last == req.eos_id)):
-                    self._finished.append((req.request_id,
-                                           list(slot.generated)))
-                    self._free_slot(i)
+                    self._finish(i)
         if self.speculative is not None:
             # Serial: every seated slot decodes, nothing is in flight.
             seated = self._decoding()
@@ -1189,12 +1250,13 @@ class ContinuousBatcher:
         with phases("dispatch"):
             self._push_active(seated)
             self._key, step_key = jax.random.split(self._key)
-            self.cache, self._tokens, self._positions, next_tok = \
-                self._decode_step(self.params, self.cache,
-                                  self._tokens, self._positions,
-                                  self._active, step_key)
-            self._in_flight = _InFlight(next_tok, step_key, seated, t0)
-            del next_tok, step_key      # _land lets the arrays die
+            (self.cache, self._tokens, self._positions, next_tok,
+             *chosen) = self._decode_step(
+                self.params, self.cache, self._tokens,
+                self._positions, self._active, step_key)
+            self._in_flight = _InFlight(next_tok, step_key, seated, t0,
+                                        *chosen)
+            del next_tok, step_key, chosen  # _land lets the arrays die
             for i, _ in seated:
                 self._slots[i].in_flight += 1
         self.decode_steps += 1
@@ -1231,6 +1293,10 @@ class ContinuousBatcher:
         phases = self._phases
         with phases("readback"):
             next_host = np.asarray(step.tokens)
+            # the whole step's choices once; take_decisions cuts a
+            # request's column out of them, if anyone asks
+            chosen = (None if step.chosen is None
+                      else np.asarray(step.chosen).astype(np.int16))
         # The step PERIOD: from the step before landing, unless this
         # one was dispatched later than that.
         self._record_step_time(max(step.dispatched_at,
@@ -1244,6 +1310,11 @@ class ContinuousBatcher:
                     continue
                 slot.in_flight -= 1
                 token = int(next_host[i])
+                if chosen is not None:
+                    # the choices at the position the step FED: that
+                    # of the token before this one
+                    self._decisions[req.request_id]["steps"].append(
+                        (chosen, i))
                 slot.generated.append(token)
                 self._step_tokens += 1
                 if self.on_token is not None:
@@ -1253,16 +1324,17 @@ class ContinuousBatcher:
                         (req.eos_id is not None and
                          token == req.eos_id))
                 if done:
-                    self._finished.append((req.request_id,
-                                           list(slot.generated)))
-                    self._free_slot(i)
+                    self._finish(i)
+            if chosen is not None:
+                self._count_experts(
+                    chosen[:, [i for i, _ in step.seated]])
             # The step's device arrays die here, inside the phase:
             # their destructor releases the GIL, and that is when the
             # stream-writer threads on_token just woke take their
             # turn (a millisecond or two with a dozen streams). It is
             # emit's cost, so it is counted here and not after every
             # phase has ended.
-            step.tokens = step.key = None
+            step.tokens = step.key = step.chosen = None
 
     def _step_speculative(self) -> list[tuple[str, list[int]]]:
         """One ragged draft/verify/commit round (see the spec_step
@@ -1324,7 +1396,10 @@ class ContinuousBatcher:
         Safe to call from another thread than the stepping one (the
         snapshot may then straddle a step). The page keys
         (kv_pages.PagePool.occupancy) are absent from a dense
-        engine."""
+        engine; state_slots_in_use / state_bytes_held (slots seated,
+        and the per-slot state they hold) from a model without one;
+        experts_held (expert matrices on this chip, over all routed
+        layers) from a model without routed layers."""
         held = [slot.held_tokens() for slot in self._slots
                 if slot.decoding()]
         out = {"slots_active": len(held),
@@ -1332,19 +1407,30 @@ class ContinuousBatcher:
                "queued": len(self._queue), "live_tokens": sum(held)}
         if self.pages is not None:
             out.update(self.pages.occupancy(held))
+        if self.stateful:
+            seated = sum(slot.request is not None
+                         for slot in self._slots)
+            out["state_slots_in_use"] = seated
+            out["state_bytes_held"] = seated * self._slot_state_bytes
+        if self._decision_layers:
+            out["experts_held"] = (len(self._decision_layers)
+                                   * self.config.experts.held)
         return out
 
     def step_stats(self) -> dict:
         """Cumulative step counters since the engine was built: steps
         that admitted, decoded or read a step back, their wall
-        seconds, the lookahead's counters (_lookahead_counts), the
-        seconds of each phase, and the process's compile count
+        seconds, the lookahead's counters (_lookahead_counts), a
+        routed model's expert counters (_count_experts), the seconds
+        of each phase, and the process's compile count
         (programs built or loaded from the persistent cache, and
         their seconds)."""
         compiles, compile_seconds = self._compiles.read()
         return {"steps": self.steps_total,
                 "step_seconds": self.step_seconds_total,
                 **self._lookahead_counts(),
+                **(self._expert_counts() if self._decision_layers
+                   else {}),
                 "phase_seconds": dict(self._phases.total),
                 "compiles": compiles,
                 "compile_seconds": compile_seconds}
@@ -1366,11 +1452,76 @@ class ContinuousBatcher:
                 if self.spec_proposed else 0.0),
         }
 
+    def _finish(self, i: int) -> None:
+        """Slot i's request has its last token: it goes to the
+        finished list (its record of choices to where take_decisions
+        finds it) and the slot is free."""
+        slot = self._slots[i]
+        request_id = slot.request.request_id
+        self._finished.append((request_id, list(slot.generated)))
+        record = self._decisions.pop(request_id, None)
+        if record is not None:
+            self._decisions_done[request_id] = record
+            while len(self._decisions_done) > self._DECISIONS_KEPT:
+                self._decisions_done.popitem(last=False)
+        self._free_slot(i)
+
     def _free_slot(self, i: int) -> None:
+        """The slot gives back what it holds. Its per-slot state (a
+        stateful model's) stays where it is: the next seat overwrites
+        it, and until then no step reads it for anybody."""
+        request = self._slots[i].request
+        if request is not None:
+            # cancelled or preempted: a resumption records anew
+            self._decisions.pop(request.request_id, None)
         self._slots[i] = _Slot()
         if self.pages is not None:
             self.pages.release(i)
             self._push_tables()
+
+    # Finished requests whose record nobody has taken yet: the oldest
+    # are dropped beyond this many.
+    _DECISIONS_KEPT = 4096
+
+    def take_decisions(self, request_id: str) -> Optional[dict]:
+        """A FINISHED request's record of what the model's routed
+        layers chose, handed over once: {"first": p, "layers": {layer
+        name: int32 [m, k]}}, the expert indices (over all the
+        router's outputs, held here or not) that the steps which
+        served it computed at positions p .. p+m-1 of prompt + served
+        tokens: the prefill's for the positions it ran, each decode
+        step's for the position it fed (every position but the last
+        token's, which is never fed). ``first`` is past whatever
+        prefix came out of shared pages. None for an unknown id, a
+        second call, or a model without such layers."""
+        record = self._decisions_done.pop(request_id, None)
+        if record is None:
+            return None
+        rows = np.concatenate(
+            [record["prefill"]] + [step[:, i:i + 1]
+                                   for step, i in record["steps"]],
+            axis=1).astype(np.int32)
+        return {"first": record["first"],
+                "layers": dict(zip(self._decision_layers, rows))}
+
+    def _count_experts(self, chosen) -> None:
+        """A landed decode step's (row, choice) pairs, int [decision
+        layers, rows decoded, k]: how many there were, how many fell
+        on experts held here (and were computed), and how many held
+        experts had at least one."""
+        experts = self.config.experts
+        local = chosen.astype(np.int32) - experts.first_expert
+        here = (local >= 0) & (local < experts.held)
+        self.expert_pairs_chosen += int(chosen.size)
+        self.expert_pairs_here += int(here.sum())
+        # distinct (layer, held expert) pairs
+        layer = np.arange(len(local))[:, None, None] * experts.held
+        self.experts_hit += len(np.unique((local + layer)[here]))
+
+    def _expert_counts(self) -> dict:
+        return {"expert_pairs_here": self.expert_pairs_here,
+                "expert_pairs_chosen": self.expert_pairs_chosen,
+                "experts_hit": self.experts_hit}
 
     def prefix_cache_clear(self) -> int:
         """kv_pages.PagePool.clear_unreferenced: the pages reclaimed."""
@@ -1482,8 +1633,9 @@ class ContinuousBatcher:
         layer's leaf would be donated once per layer ("Attempt to
         donate the same buffer twice"). One transfer and one small
         program (_table_per_layer), not a transfer per layer."""
-        tables = iter(_table_per_layer(self._put(self.pages.table),
-                                       self.config.n_layers))
+        tables = iter(_table_per_layer(
+            self._put(self.pages.table),
+            tfm.paged_layer_count(self.config)))
 
         def push(leaf_dict):
             if isinstance(leaf_dict, dict) and \
@@ -1589,7 +1741,7 @@ class ContinuousBatcher:
         if not targets:
             return False
         tokens = len(entry.request.prompt) + len(entry.resumed)
-        if self.prefix_cache:
+        if self.prefix_cache and not self.stateful:
             # Predict the POST-MATCH suffix cost: a cached prefix
             # pays a gather, not a prefill.
             tokens -= self.pages.cached_tokens(
@@ -1643,7 +1795,8 @@ class ContinuousBatcher:
         compile bucket and tokens to prefill, then the program and
         its arguments after (params, cache): dense without a pool,
         shared over the suffix when the seat matched pages of the
-        index, else the cold paged one."""
+        index, else the cold paged one ("recomputed": a stateful
+        model's whole prompt over matched pages)."""
         if seat is None:
             return ("dense", prompt.shape[1], len(tokens),
                     self._prefill, (slot, prompt, len(tokens)))
@@ -1651,6 +1804,17 @@ class ContinuousBatcher:
             return ("cold", prompt.shape[1], len(tokens),
                     self._prefill_paged,
                     (slot, prompt, self._put(seat.row), len(tokens)))
+        if self.stateful:
+            # The state at the prompt's end is of no page, so the
+            # whole prompt runs again, down the cold program. Its row
+            # names the scratch page where the seat matched: what it
+            # computes for the shared pages (immutable) is dropped,
+            # and _admit then pushes the slot's true row.
+            write = seat.row.copy()
+            write[:seat.matched] = self.pages.scratch_page
+            return ("recomputed", prompt.shape[1], len(tokens),
+                    self._prefill_paged,
+                    (slot, prompt, self._put(write), len(tokens)))
         suffix = self._padded(tokens[seat.prefix_len:])
         return ("shared", suffix.shape[1],
                 len(tokens) - seat.prefix_len, self._prefill_shared,
@@ -1709,10 +1873,12 @@ class ContinuousBatcher:
             # token waits that out unread (it is ready long before).
             self._settle("admit")
             with phases("prefill"):
-                self.cache, last_logits = prefill(
+                self.cache, last_logits, *chosen = prefill(
                     self.params, self.cache, *prefill_args)
                 if seat is not None:
                     self.pages.publish(i, seat)
+                if path == "recomputed":
+                    self._push_tables()
                 if self.speculative is not None:
                     # The draft cache must hold the same committed
                     # prefix (the spec-step invariant); its prefill
@@ -1726,6 +1892,14 @@ class ContinuousBatcher:
                     last_logits[None].astype(jnp.float32), sample_key,
                     self.sampling)
                 first_token = int(first[0])
+                if chosen:
+                    # the positions the prefill ran, past any prefix
+                    # it took from shared pages
+                    self._decisions[req.request_id] = {
+                        "first": len(tokens) - prefilled,
+                        "prefill": np.asarray(chosen[0])[
+                            :, :prefilled].astype(np.int16),
+                        "steps": []}
             with phases("slot_update"):
                 # The prefill-sampled token IS the next generated
                 # token.

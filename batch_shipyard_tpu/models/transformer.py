@@ -110,6 +110,93 @@ class TransformerConfig:
     # global-view jit path leaves this None — there XLA inserts the
     # collectives from parameter shardings.
     tp_axis: Optional[str] = None
+    # Fewer K/V heads than query heads (grouped-query attention):
+    # n_heads // n_kv_heads query heads read each K/V head, and the
+    # cache (dense rows, paged pool [P, page, n_kv_heads * d_head])
+    # holds the K/V heads alone. None = n_heads.
+    n_kv_heads: Optional[int] = None
+    # False: attention applies no positional embedding (a stack whose
+    # state-space layers carry position).
+    use_rope: bool = True
+    # RMSNorm epsilon of every block norm and of the final norm.
+    norm_eps: float = 1e-6
+    # False: a separate lm_head [d_model, vocab] instead of the
+    # transposed embedding.
+    tie_embeddings: bool = True
+    # One kind per layer, in order (layer_kinds): "dense" (attention
+    # + SwiGLU MLP, each after a norm), "dense_moe" (its MLP replaced
+    # by ``moe``'s capacity-routed experts), or a block that is ONE
+    # mixer after ONE norm: "ssm" (models/ssm.py, sized by ``ssm``),
+    # "attn" (the attention above alone), "experts" (moe.RoutedExperts,
+    # sized by ``experts``). None = "dense" throughout, "dense_moe"
+    # at every moe_every-th layer when ``moe`` is set.
+    block_kinds: Optional[tuple] = None
+    ssm: Optional[Any] = None        # models.ssm.SSMConfig
+    experts: Optional[Any] = None    # models.moe.RoutedConfig
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+
+MIXER_KINDS = ("ssm", "attn", "experts")
+
+
+def layer_kinds(cfg: TransformerConfig) -> tuple:
+    """The kind of every layer, in order."""
+    if cfg.block_kinds is not None:
+        kinds = tuple(cfg.block_kinds)
+        if len(kinds) != cfg.n_layers or any(
+                kind not in MIXER_KINDS + ("dense", "dense_moe")
+                for kind in kinds):
+            raise ValueError(f"block_kinds {kinds!r} is not one known "
+                             f"kind for each of {cfg.n_layers} layers")
+        return kinds
+    stride = max(cfg.moe_every, 1)
+    return tuple(
+        "dense_moe" if cfg.moe is not None and i % stride == stride - 1
+        else "dense" for i in range(cfg.n_layers))
+
+
+def decision_layer_names(cfg: TransformerConfig) -> tuple:
+    """The layers that choose experts per position and record the
+    choice ("experts" blocks), by their names in the tree."""
+    return tuple(f"layer_{i}" for i, kind in enumerate(layer_kinds(cfg))
+                 if kind == "experts")
+
+
+def paged_layer_count(cfg: TransformerConfig) -> int:
+    """How many layers keep K/V (and so a block table)."""
+    return sum(kind in ("dense", "dense_moe", "attn")
+               for kind in layer_kinds(cfg))
+
+
+def has_slot_state(cfg: TransformerConfig) -> bool:
+    """Whether a slot holds a fixed-size state beside its K/V: one
+    that no page names, so a prefix's pages cannot stand in for it."""
+    return "ssm" in layer_kinds(cfg)
+
+
+def collect_decisions(sown, cfg: TransformerConfig):
+    """The "decisions" collection of one apply -> int32
+    [decision layers, B, T, k] in layer order, or None for a model
+    with no such layer."""
+    names = decision_layer_names(cfg)
+    if not names:
+        return None
+    return jnp.stack([sown[name]["experts"]["chosen"][0]
+                      for name in names])
+
+
+def output_logits(cfg: TransformerConfig, params, hidden):
+    """float32 logits of final-normed hidden states [..., d] through
+    the model's head: the transposed embedding, or lm_head."""
+    hidden = hidden.astype(jnp.float32)
+    if cfg.tie_embeddings:
+        return jnp.dot(hidden, params["embed"]["embedding"].astype(
+            jnp.float32).T)
+    return jnp.dot(hidden, params["lm_head"]["kernel"].astype(
+        jnp.float32))
 
 
 def rotary_embedding(x, positions, theta: float):
@@ -202,8 +289,17 @@ class Attention(nn.Module):
     def __call__(self, x, positions):
         cfg = self.config
         features = cfg.n_heads * cfg.d_head
+        kv_features = cfg.kv_heads * cfg.d_head
+        if cfg.n_heads % cfg.kv_heads:
+            raise ValueError(
+                f"n_heads {cfg.n_heads} is not a multiple of "
+                f"n_kv_heads {cfg.kv_heads}")
         dense = functools_partial_dense(cfg)
         if cfg.fused_norm:
+            if kv_features != features:
+                raise NotImplementedError(
+                    "fused_norm projects q, k and v as one [d, 3F] "
+                    "matmul: no grouped-query attention")
             # x arrives UN-normed; the block's attn RMSNorm is fused
             # into one [d, 3F] qkv projection (ops/fused_norm.py).
             from batch_shipyard_tpu.ops import fused_norm as fn_ops
@@ -223,14 +319,15 @@ class Attention(nn.Module):
             if cfg.tp_axis:
                 x = tp_region_input(x, cfg.tp_axis)
             q = dense(features, "q_proj")(x)
-            k = dense(features, "k_proj")(x)
-            v = dense(features, "v_proj")(x)
+            k = dense(kv_features, "k_proj")(x)
+            v = dense(kv_features, "v_proj")(x)
             batch, seq = x.shape[0], x.shape[1]
         q = q.reshape(batch, seq, cfg.n_heads, cfg.d_head)
-        k = k.reshape(batch, seq, cfg.n_heads, cfg.d_head)
-        v = v.reshape(batch, seq, cfg.n_heads, cfg.d_head)
-        q = rotary_embedding(q, positions, cfg.rope_theta)
-        k = rotary_embedding(k, positions, cfg.rope_theta)
+        k = k.reshape(batch, seq, cfg.kv_heads, cfg.d_head)
+        v = v.reshape(batch, seq, cfg.kv_heads, cfg.d_head)
+        if cfg.use_rope:
+            q = rotary_embedding(q, positions, cfg.rope_theta)
+            k = rotary_embedding(k, positions, cfg.rope_theta)
         if cfg.decode:
             if cfg.tp_axis:
                 raise NotImplementedError(
@@ -248,6 +345,12 @@ class Attention(nn.Module):
         attention_fn = cfg.attention_fn or (
             lambda q_, k_, v_, causal: attn_ops.attention(
                 q_, k_, v_, causal=causal))
+        if cfg.kv_heads != cfg.n_heads:
+            # The training-path kernels take one K/V head a query
+            # head: each K/V head repeated for its group.
+            group = cfg.n_heads // cfg.kv_heads
+            k = jnp.repeat(k, group, axis=2)
+            v = jnp.repeat(v, group, axis=2)
         out = attention_fn(q, k, v, causal=True)
         out = out.reshape(batch, seq, features)
         out = dense(cfg.d_model, "o_proj")(out)
@@ -272,21 +375,22 @@ class Attention(nn.Module):
         int8_kv = cfg.kv_cache_dtype == "int8"  # validated at dispatch
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
         batch, seq, heads, depth = q.shape
+        kv_heads = k.shape[2]
         cache_k = self.variable(
             "cache", "k", jnp.zeros,
-            (batch, cfg.max_decode_len, heads, depth), store_dtype)
+            (batch, cfg.max_decode_len, kv_heads, depth), store_dtype)
         cache_v = self.variable(
             "cache", "v", jnp.zeros,
-            (batch, cfg.max_decode_len, heads, depth), store_dtype)
+            (batch, cfg.max_decode_len, kv_heads, depth), store_dtype)
         if int8_kv:
             # Per-(position, head) absmax scales; fp32 so dequant
             # error is the int8 rounding alone.
             scale_k = self.variable(
                 "cache", "k_scale", jnp.zeros,
-                (batch, cfg.max_decode_len, heads), jnp.float32)
+                (batch, cfg.max_decode_len, kv_heads), jnp.float32)
             scale_v = self.variable(
                 "cache", "v_scale", jnp.zeros,
-                (batch, cfg.max_decode_len, heads), jnp.float32)
+                (batch, cfg.max_decode_len, kv_heads), jnp.float32)
 
         if int8_kv:
             from batch_shipyard_tpu.ops.quantization import (
@@ -330,7 +434,7 @@ class Attention(nn.Module):
             # causal prefix of this one.
             mask = (key_pos[None, None, :] <=
                     cols[:, :, None])[:, None, :, :]  # [B, 1, S, T]
-        if int8_kv and seq == 1:
+        if int8_kv and seq == 1 and kv_heads == heads:
             # Single-token decode dispatches through
             # ops/decode_attention: impl='kernel' dequantizes the
             # int8 rows + scales in VMEM tile by tile (no dequantized
@@ -360,16 +464,7 @@ class Attention(nn.Module):
                 scale_v.value[..., None]).astype(cfg.dtype)
         else:
             k_all, v_all = cache_k.value, cache_v.value
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k_all,
-            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(depth))
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum(
-            "bhqk,bkhd->bqhd", probs.astype(cfg.dtype), v_all,
-            preferred_element_type=jnp.float32)
-        return out.astype(cfg.dtype)
+        return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
 
     def _decode_attend_paged(self, q, k, v):
         """Paged decode attention (vLLM-style block tables): K/V live
@@ -412,7 +507,8 @@ class Attention(nn.Module):
                 f"entries instead of live pages")
         max_blocks = (cfg.max_decode_len + cfg.spec_window
                       + page - 1) // page
-        width = heads * depth
+        kv_heads = k.shape[2]
+        width = kv_heads * depth
         k_pages = self.variable(
             "cache", "k_pages", jnp.zeros,
             (cfg.kv_num_pages, page, width), store_dtype)
@@ -422,10 +518,10 @@ class Attention(nn.Module):
         if int8_kv:
             scale_k = self.variable(
                 "cache", "k_page_scales", jnp.zeros,
-                (cfg.kv_num_pages, page, heads), jnp.float32)
+                (cfg.kv_num_pages, page, kv_heads), jnp.float32)
             scale_v = self.variable(
                 "cache", "v_page_scales", jnp.zeros,
-                (cfg.kv_num_pages, page, heads), jnp.float32)
+                (cfg.kv_num_pages, page, kv_heads), jnp.float32)
         block_table = self.variable(
             "cache", "block_table",
             lambda: jnp.zeros((batch, max_blocks), jnp.int32))
@@ -470,33 +566,23 @@ class Attention(nn.Module):
         # freshly written this block, so scratch-page garbage only
         # ever feeds draft positions whose logits get discarded.
         k_all = k_pages.value[block_table.value].reshape(
-            batch, max_blocks * page, heads, depth)
+            batch, max_blocks * page, kv_heads, depth)
         v_all = v_pages.value[block_table.value].reshape(
-            batch, max_blocks * page, heads, depth)
+            batch, max_blocks * page, kv_heads, depth)
         if int8_kv:
             ks_all = scale_k.value[block_table.value].reshape(
-                batch, max_blocks * page, heads)
+                batch, max_blocks * page, kv_heads)
             vs_all = scale_v.value[block_table.value].reshape(
-                batch, max_blocks * page, heads)
+                batch, max_blocks * page, kv_heads)
             k_all = (k_all.astype(jnp.float32) *
                      ks_all[..., None]).astype(cfg.dtype)
             v_all = (v_all.astype(jnp.float32) *
                      vs_all[..., None]).astype(cfg.dtype)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k_all,
-            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(depth))
         key_pos = jax.lax.broadcasted_iota(
             jnp.int32, (max_blocks * page, 1), 0)[:, 0]
         mask = (key_pos[None, None, :] <=
                 cols[:, :, None])[:, None, :, :]      # [B, 1, S, T]
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum(
-            "bhqk,bkhd->bqhd", probs.astype(cfg.dtype), v_all,
-            preferred_element_type=jnp.float32)
-        return out.astype(cfg.dtype)
-
+        return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
 
 
 def prefix_rows_from_pages(layer_cache: dict, page_ids,
@@ -627,8 +713,10 @@ class Block(nn.Module):
             x = x + Attention(cfg, name="attn")(x, positions)
             return x + MLP(cfg, name="mlp")(x)
         x = x + Attention(cfg, name="attn")(
-            RMSNorm(dtype=cfg.dtype, name="attn_norm")(x), positions)
-        normed = RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
+            RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                    name="attn_norm")(x), positions)
+        normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                         name="mlp_norm")(x)
         if self.use_moe:
             from batch_shipyard_tpu.models.moe import MoEMLP
             out, aux = MoEMLP(cfg.moe, name="moe")(normed)
@@ -639,17 +727,48 @@ class Block(nn.Module):
         return x
 
 
+class MixerBlock(nn.Module):
+    """A block that is ONE mixer after ONE norm: x + Mixer(RMSNorm(x)),
+    the mixer of ``kind`` (MIXER_KINDS) and named by it, so that the
+    tree, the cache and a device trace's operation names all say which
+    kind a layer is (layer_3/ssm/..., layer_5/attn/...)."""
+    config: TransformerConfig
+    kind: str = "attn"
+
+    @nn.compact
+    def __call__(self, x, positions, valid_len=None):
+        cfg = self.config
+        normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                         name="norm")(x)
+        if self.kind == "ssm":
+            from batch_shipyard_tpu.models.ssm import Mamba2Mixer
+            out = Mamba2Mixer(cfg, name="ssm")(normed, valid_len)
+        elif self.kind == "experts":
+            from batch_shipyard_tpu.models.moe import RoutedExperts
+            out = RoutedExperts(cfg.experts, dtype=cfg.dtype,
+                                param_dtype=cfg.param_dtype,
+                                name="experts")(normed)
+        else:
+            out = Attention(cfg, name="attn")(normed, positions)
+        return x + out
+
+
 class TransformerLM(nn.Module):
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False,
-                 positions=None):
+                 positions=None, valid_len=None):
         """tokens: [B, T] int32 -> logits [B, T, vocab] (or the final
         hidden states [B, T, d_model] when return_hidden — used by the
         chunked-loss training path so the full fp32 logits tensor,
         B*T*vocab, never materializes in HBM). In decode mode pass
-        positions=[absolute position] for the current step."""
+        positions=[absolute position] for the current step.
+        ``valid_len`` (traced int, decode-mode prefill only): how many
+        leading tokens of this call are the sequence's own; the rest
+        is bucket padding, which a layer that keeps a running state
+        (models/ssm.py) must not let advance it. K/V rows are masked
+        on read and need no such care."""
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.d_model,
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -657,20 +776,26 @@ class TransformerLM(nn.Module):
         x = embed(tokens)
         if positions is None:
             positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-        block = Block
+        block, mixer_block = Block, MixerBlock
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
-        for idx in range(cfg.n_layers):
-            use_moe = (cfg.moe is not None and
-                       idx % max(cfg.moe_every, 1) == (
-                           max(cfg.moe_every, 1) - 1))
-            x = block(cfg, use_moe, name=f"layer_{idx}")(x, positions)
-        x = RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
+            mixer_block = nn.remat(MixerBlock, static_argnums=())
+        for idx, kind in enumerate(layer_kinds(cfg)):
+            if kind in MIXER_KINDS:
+                x = mixer_block(cfg, kind, name=f"layer_{idx}")(
+                    x, positions, valid_len)
+            else:
+                x = block(cfg, kind == "dense_moe",
+                          name=f"layer_{idx}")(x, positions)
+        x = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                    name="final_norm")(x)
         if return_hidden:
             return x
-        # Tied output projection via attend (embedding transpose).
-        logits = embed.attend(x.astype(jnp.float32))
-        return logits
+        if cfg.tie_embeddings:
+            # Tied output projection via attend (embedding transpose).
+            return embed.attend(x.astype(jnp.float32))
+        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype, name="lm_head")(x)
 
 
 def lm_loss(logits, targets, ignore_id: int = -1):

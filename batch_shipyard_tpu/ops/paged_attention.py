@@ -229,6 +229,37 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     return out.reshape(batch, 1, heads, depth)
 
 
+def masked_attention(q, k_all, v_all, mask, dtype):
+    """Softmax attention of q [B, S, H, D] over cached rows k_all /
+    v_all [B, T, Hkv, D] under mask [B, 1, S, T] (True = visible):
+    float32 scores and accumulation, the probabilities in ``dtype``.
+    With fewer K/V heads than query heads, H // Hkv query heads read
+    each K/V head and no K/V row is repeated."""
+    batch, seq, heads, depth = q.shape
+    kv_heads = k_all.shape[2]
+    scale = jnp.sqrt(jnp.float32(depth))
+    if kv_heads == heads:
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, k_all,
+            preferred_element_type=jnp.float32)
+        scores = jnp.where(mask, scores / scale, _NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum(
+            "bhqk,bkhd->bqhd", probs.astype(dtype), v_all,
+            preferred_element_type=jnp.float32)
+        return out.astype(dtype)
+    grouped = q.reshape(batch, seq, kv_heads, heads // kv_heads, depth)
+    scores = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", grouped, k_all,
+        preferred_element_type=jnp.float32)
+    scores = jnp.where(mask[:, :, None], scores / scale, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum(
+        "bhgqk,bkhd->bqhgd", probs.astype(dtype), v_all,
+        preferred_element_type=jnp.float32)
+    return out.reshape(batch, seq, heads, depth).astype(dtype)
+
+
 def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
                                lengths, k_scales=None,
                                v_scales=None):
@@ -236,35 +267,31 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
     slot's full logical [max_blocks*page, H, D] view, then one masked
     softmax. Same math as the kernel; reads the whole table width.
     With int8 pages, only the GATHERED slices dequantize — never the
-    whole pool."""
+    whole pool. The pool may hold FEWER K/V heads than q has query
+    heads (its rows are Hkv*D wide): masked_attention groups the
+    query heads over them."""
     batch, seq, heads, depth = q.shape
     assert seq == 1
     page = k_pages.shape[1]
     max_blocks = block_table.shape[1]
+    kv_heads = k_pages.shape[2] // depth
     k_all = k_pages[block_table].reshape(
-        batch, max_blocks * page, heads, depth)
+        batch, max_blocks * page, kv_heads, depth)
     v_all = v_pages[block_table].reshape(
-        batch, max_blocks * page, heads, depth)
+        batch, max_blocks * page, kv_heads, depth)
     if k_scales is not None:
         ks = k_scales[block_table].reshape(
-            batch, max_blocks * page, heads)
+            batch, max_blocks * page, kv_heads)
         vs = v_scales[block_table].reshape(
-            batch, max_blocks * page, heads)
+            batch, max_blocks * page, kv_heads)
         k_all = (k_all.astype(jnp.float32) *
                  ks[..., None]).astype(q.dtype)
         v_all = (v_all.astype(jnp.float32) *
                  vs[..., None]).astype(q.dtype)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_all,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(depth))
     key_pos = jax.lax.broadcasted_iota(
         jnp.int32, (max_blocks * page, 1), 0)[:, 0]
-    mask = key_pos[None, :] < lengths[:, None]
-    scores = jnp.where(mask[:, None, None, :], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v_all,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    mask = (key_pos[None, :] < lengths[:, None])[:, None, None, :]
+    return masked_attention(q, k_all, v_all, mask, q.dtype)
 
 
 def resolve_kernel_or_xla(impl: Optional[str], what: str) -> str:
@@ -285,9 +312,13 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            impl: Optional[str] = None,
                            k_scales=None, v_scales=None):
     """Dispatch: 'kernel' (Pallas) or 'xla' (resolve_paged_impl).
-    k_scales/v_scales switch both paths to int8-page dequant."""
+    k_scales/v_scales switch both paths to int8-page dequant. A pool
+    of fewer K/V heads than query heads takes the xla path whatever
+    ``impl`` says: the kernel's block-diagonal query is one K/V head
+    a query head."""
+    grouped = k_pages.shape[2] != q.shape[2] * q.shape[3]
     fn = (paged_decode_attention_kernel
-          if resolve_paged_impl(impl) == "kernel"
+          if resolve_paged_impl(impl) == "kernel" and not grouped
           else paged_decode_attention_xla)
     return fn(q, k_pages, v_pages, block_table, lengths,
               k_scales=k_scales, v_scales=v_scales)

@@ -1,0 +1,618 @@
+"""A stack of state-space, attention and routed-expert blocks, one
+mixer a block, through ContinuousBatcher: the paged pool with fewer
+K/V heads than query heads, a fixed-size state per slot beside it,
+experts with no dropped token and a record of their choices, all as
+one chip's share of a deployment. CPU, tiny sizes, seeded random
+weights, float32 on both sides so that a tolerance is rounding alone;
+LOGITS are compared, not tokens: every served token's logit has to lie
+within a tolerance of the plain reference's best at its position
+(benchmark/reference/hybrid_ssm_moe_plain.py, float32 "highest",
+sequential recurrence, no cache), the reference run on the engine's
+own expert choices, each of which has to be (within a tolerance) one
+the reference would have made."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from batch_shipyard_tpu.models import inference as inf
+from batch_shipyard_tpu.models import moe, serving, ssm
+from batch_shipyard_tpu.models import transformer as tfm
+from batch_shipyard_tpu.models.serving import Request
+from benchmark import spec, weights
+from benchmark.reference import hybrid_ssm_moe_plain as plain
+
+# The issue's tiny size: d 64, 4 SSM heads of 16, 2 groups, state 16,
+# 4 query / 2 KV heads, 8 experts top-2 of which 4 held, vocabulary
+# 512 of which 256 held, pattern MEM*EME.
+FILE = dict(
+    hidden_size=64, head_dim=16, num_attention_heads=4,
+    num_key_value_heads=2, mamba_num_heads=4, mamba_head_dim=16,
+    n_groups=2, ssm_state_size=16, conv_kernel=4, chunk_size=8,
+    moe_intermediate_size=32, moe_shared_expert_intermediate_size=64,
+    n_routed_experts=4, num_experts_per_tok=2, num_hidden_layers=7,
+    hybrid_override_pattern="MEM*EME", vocab_size=256,
+    layer_norm_epsilon=1e-5, routed_scaling_factor=2.5,
+    model_module="hybrid_ssm_moe",
+    share={"first_expert": 0, "experts_of": 8})
+ENGINE = {"num_slots": 4, "max_decode_len": 128}
+PAGE = 16
+# float32 program against the float32 reference: the two differ by
+# the order of their sums (a chunked scan against a recurrence, a
+# matmul over all held experts against one expert after another),
+# some 1e-5 of a logit of size 1 to 4 after seven blocks; 1e-3 leaves
+# two orders of room and is a
+# thirtieth of what bfloat16 alone explains (check.tail_from 0.03).
+GAP = 1e-3
+# ... and a selection score (a sigmoid plus 0) by some 1e-6.
+SLACK = 1e-4
+
+MODULE = spec.load_model(FILE)
+
+
+def _model(first_expert=0, held=4, dtype=jnp.float32):
+    file = dict(FILE, n_routed_experts=held,
+                share={"first_expert": first_expert, "experts_of": 8})
+    dims = MODULE.dims(file)
+    config = dataclasses.replace(
+        MODULE.program_model(file, dims, ENGINE), dtype=dtype,
+        param_dtype=dtype)
+    return file, dims, config
+
+
+@pytest.fixture(scope="module")
+def share():
+    """(file, dims, the program's config, seeded float32 weights)."""
+    file, dims, config = _model()
+    params = weights.make_params(MODULE.param_leaves(dims), 11,
+                                 jnp.float32)
+    # a routing bias that matters: selection by score + bias, weights
+    # by the score alone
+    for name, _k, _n in MODULE.decision_layers(file, dims):
+        params[name]["experts"]["e_score_correction_bias"] = \
+            0.05 * jax.random.normal(jax.random.PRNGKey(int(name[6:])),
+                                     (8,))
+    return file, dims, config, params
+
+
+def _engine(config, params, **kwargs):
+    kwargs.setdefault("num_slots", ENGINE["num_slots"])
+    return serving.ContinuousBatcher(
+        config, params, max_decode_len=ENGINE["max_decode_len"],
+        kv_page_size=PAGE, **kwargs)
+
+
+def _prompts(count, low=5, high=60, seed=0):
+    rng = np.random.default_rng(seed)
+    return {f"r{i}": [int(t) for t in rng.integers(
+        1, FILE["vocab_size"], rng.integers(low, high))]
+        for i in range(count)}
+
+
+def _serve(engine, prompts, new_tokens):
+    for request_id, prompt in prompts.items():
+        engine.submit(Request(request_id, prompt,
+                              max_new_tokens=new_tokens[request_id]))
+    done = {}
+    while engine.pending():
+        for request_id, tokens in engine.step():
+            done[request_id] = tokens
+    return done
+
+
+def _judged(share, prompt, served, record):
+    """(each served token's gap below the reference's best logit, the
+    slack of every recorded choice) with the reference run on the
+    record's choices."""
+    file, dims, _config, params = share
+    sequence = prompt + served[:-1]
+    handed = {name: jnp.asarray(rows) for name, rows in
+              record["layers"].items()}
+    logits, slacks = MODULE.teacher_forced_logits(
+        params, jnp.asarray(sequence, jnp.int32),
+        jnp.arange(len(prompt) - 1, len(sequence)), file, dims,
+        decisions=handed)
+    best = jnp.max(logits, axis=-1)
+    at = jnp.take_along_axis(logits, jnp.asarray(served)[:, None],
+                             axis=-1)[:, 0]
+    return np.asarray(best - at), np.concatenate(
+        [np.asarray(slack) for slack in slacks.values()])
+
+
+@pytest.fixture(scope="module")
+def served(share):
+    """Ten requests of different lengths through four slots: they
+    share decode steps, wait for slots and reuse them."""
+    _file, _dims, config, params = share
+    engine = _engine(config, params)
+    prompts = _prompts(10)
+    rng = np.random.default_rng(1)
+    new_tokens = {r: int(rng.integers(3, 14)) for r in prompts}
+    done = _serve(engine, prompts, new_tokens)
+    records = {r: engine.take_decisions(r) for r in done}
+    return engine, prompts, new_tokens, done, records
+
+
+# ------------------- (a) the engine against the reference
+
+
+def test_prefill_then_decode_agree_with_the_references_full_pass(
+        share, served):
+    _engine_, prompts, new_tokens, done, records = served
+    assert set(done) == set(prompts)
+    for request_id, tokens in done.items():
+        assert len(tokens) == new_tokens[request_id]
+        gaps, slack = _judged(share, prompts[request_id], tokens,
+                              records[request_id])
+        assert gaps.max() < GAP, (request_id, gaps.max())
+        assert slack.max() < SLACK, (request_id, slack.max())
+
+
+def test_the_reference_alone_picks_the_same_tokens(share, served):
+    """Without the record the reference makes its own choices: at
+    float32 they are the engine's, and so are the tokens."""
+    file, dims, _config, params = share
+    _engine_, prompts, _new, done, _records = served
+    request_id = max(done, key=lambda r: len(prompts[r]))
+    sequence = prompts[request_id] + done[request_id][:-1]
+    logits = MODULE.teacher_forced_logits(
+        params, jnp.asarray(sequence, jnp.int32),
+        jnp.arange(len(prompts[request_id]) - 1, len(sequence)),
+        file, dims)
+    assert [int(t) for t in jnp.argmax(logits, -1)] == done[request_id]
+
+
+# ------------------- (b) the shares add up
+
+
+def test_the_two_halves_experts_and_one_shared_expert_are_the_block(
+        share):
+    """Experts 0-3 and experts 4-7 of the router's 8, each half's
+    routed part, plus ONE shared expert, are what the uncut reference
+    gives for the whole E block; in the program and in the reference.
+    1e-5: float32 sums in another order."""
+    _file, dims, _config, params = share
+    d = dims["d_model"]
+    half = params["layer_1"]["experts"]
+    leaves = {leaf[0][-1]: leaf for leaf in MODULE.param_leaves(
+        dict(dims, experts_held=4)) if leaf[0][:2] == ("layer_1",
+                                                        "experts")}
+    key = jax.random.PRNGKey(5)
+    other = {name: jax.random.normal(
+        jax.random.fold_in(key, i), leaves[name][1]) / np.sqrt(
+            leaves[name][1][1])
+        for i, name in enumerate(("experts_up", "experts_down"))}
+    uncut = dict(half, experts_up=jnp.concatenate(
+        [half["experts_up"], other["experts_up"]]),
+        experts_down=jnp.concatenate(
+            [half["experts_down"], other["experts_down"]]))
+    h = jax.random.normal(jax.random.PRNGKey(6), (24, d))
+    own = jnp.full((24, 2), -1, jnp.int32)
+    sizes = {"top_k": 2, "scale": 2.5}
+    whole, _slack = plain.experts(h, uncut, own, first=0, **sizes)
+    shared = plain.matmul(plain.relu2(plain.matmul(
+        h, half["shared_up"])), half["shared_down"])
+    # the reference's halves
+    use, weigh, _ = plain.route(h, uncut, own, 2, 2.5)
+    parts = [plain.routed_part(h, dict(half, **tree), use, weigh, first)
+             for first, tree in ((0, {}), (4, other))]
+    np.testing.assert_allclose(parts[0] + parts[1] + shared, whole,
+                               atol=1e-5)
+    assert float(jnp.abs(parts[0]).max()) > 0.1 < float(
+        jnp.abs(parts[1]).max())
+    # the program's halves: each layer adds the shared expert, so one
+    # of the two is taken out again
+    outs = []
+    for first, tree in ((0, {}), (4, other)):
+        layer = moe.RoutedExperts(
+            moe.RoutedConfig(d_model=d, n_experts=8, top_k=2,
+                             d_expert=32, d_shared=64, scale=2.5,
+                             experts_held=4, first_expert=first),
+            dtype=jnp.float32)
+        out, sown = layer.apply({"params": dict(half, **tree)},
+                                h[None], mutable=["decisions"])
+        outs.append(out[0])
+        np.testing.assert_array_equal(
+            np.sort(sown["decisions"]["chosen"][0][0]), np.sort(use))
+    np.testing.assert_allclose(outs[0] + outs[1] - shared, whole,
+                               atol=1e-5)
+
+
+def test_the_two_vocabulary_halves_logits_concatenate(share):
+    """Rows 0-255 and rows 256-511 of an uncut lm_head: the program's
+    head over each half gives the uncut reference's logits side by
+    side (a matmul's columns are independent)."""
+    _file, dims, config, params = share
+    d = dims["d_model"]
+    uncut = jax.random.normal(jax.random.PRNGKey(8), (d, 512)) / 8.0
+    hidden = jax.random.normal(jax.random.PRNGKey(9), (5, d))
+    normed = plain.rmsnorm(hidden, params["final_norm"]["scale"], 1e-5)
+    halves = [tfm.output_logits(
+        config, {"lm_head": {"kernel": uncut[:, lo:lo + 256]}}, normed)
+        for lo in (0, 256)]
+    np.testing.assert_allclose(
+        jnp.concatenate(halves, axis=-1),
+        plain.head_logits(hidden, params["final_norm"], uncut, 1e-5),
+        atol=1e-5)
+
+
+# ------------------- (c) a prompt padded to a longer bucket
+
+
+@pytest.mark.parametrize("chunk", (None, 8))
+def test_bucket_padding_advances_neither_state_nor_tail(share, chunk):
+    """Eleven tokens in a bucket of 32 (prompt_len dynamic) leave the
+    state of position 10, the convolution's tail of positions 8-10 and
+    the first token's logits that the same eleven tokens leave with no
+    padding at all; a prefill in chunks of 8 (the last two all
+    padding) too. 1e-5: the chunked scan's sums fall otherwise."""
+    _file, _dims, config, params = share
+    model = tfm.TransformerLM(inf.decode_config(config, 128))
+    prompt = _prompts(1, 11, 12)["r0"]
+    assert len(prompt) == 11
+    exact, last, _chosen = serving._dense_prefill(
+        model, None, params, jnp.asarray([prompt]), 11)
+    padded, last_padded, _chosen = serving._dense_prefill(
+        model, chunk, params, jnp.asarray([prompt + [0] * 21]), 11)
+    np.testing.assert_allclose(last_padded, last, atol=1e-5)
+    assert int(jnp.argmax(last_padded)) == int(jnp.argmax(last))
+    states = 0
+    for name, kind in zip(sorted(exact), "MM*M"):
+        if kind != "M":
+            continue
+        for leaf in inf.SLOT_STATE_LEAVES:
+            states += 1
+            np.testing.assert_allclose(
+                padded[name]["ssm"][leaf], exact[name]["ssm"][leaf],
+                atol=1e-5, err_msg=f"{name} {leaf}")
+    assert states == 6
+    # ... and padding that DID advance it would show
+    wrong, _last, _chosen = serving._dense_prefill(
+        model, None, params, jnp.asarray([prompt + [0] * 21]), 32)
+    assert float(jnp.abs(wrong["layer_0"]["ssm"]["ssm_state"]
+                         - exact["layer_0"]["ssm"]["ssm_state"]).max()
+                 ) > 1e-2
+
+
+# ------------------- (d) a slot reused, with a step in flight
+
+
+def test_a_reused_slot_serves_what_a_fresh_engine_serves(share):
+    """Two slots, seven requests, some ending on an eos_id (so that
+    the step in flight computes one overshoot token and writes a state
+    nobody may read): every request gets the tokens a fresh engine
+    gives it alone, and its logits hold against the reference."""
+    _file, _dims, config, params = share
+    prompts = _prompts(7, seed=3)
+    alone = {}
+    for request_id, prompt in prompts.items():
+        alone.update(_serve(_engine(config, params, num_slots=2),
+                            {request_id: prompt}, {request_id: 12}))
+    engine = _engine(config, params, num_slots=2)
+    for i, (request_id, prompt) in enumerate(prompts.items()):
+        # every other request stops early on the token it would have
+        # produced fifth
+        engine.submit(Request(
+            request_id, prompt, max_new_tokens=12,
+            eos_id=alone[request_id][4] if i % 2 else None))
+    done = {}
+    while engine.pending():
+        for request_id, tokens in engine.step():
+            done[request_id] = tokens
+    assert engine.step_stats()["overshoot_tokens"] > 0
+    assert engine.step_stats()["steps_overlapped"] > 0
+    for i, request_id in enumerate(prompts):
+        want = alone[request_id]
+        if i % 2:
+            want = want[:want.index(want[4]) + 1]
+        assert done[request_id] == want, request_id
+        gaps, slack = _judged(share, prompts[request_id],
+                              done[request_id],
+                              engine.take_decisions(request_id))
+        assert gaps.max() < GAP and slack.max() < SLACK
+
+
+# ------------------- (e) no capacity: alone or in a full step
+
+
+def test_a_rows_experts_do_not_depend_on_its_batch_mates(share):
+    """dense_experts on 96 rows and on each row alone: the same output
+    to the last bits of a float32 sum (no capacity, nothing dropped),
+    and what the reference's routed part gives."""
+    _file, dims, _config, params = share
+    w = params["layer_4"]["experts"]
+    rows = jax.random.normal(jax.random.PRNGKey(2), (96, dims["d_model"]))
+    chosen, weigh = moe.route_sigmoid(
+        rows @ w["router_kernel"], w["e_score_correction_bias"], 2, 2.5)
+    full = moe.dense_experts(rows, chosen, weigh, w["experts_up"],
+                             w["experts_down"], 0)
+    for i in (0, 17, 95):
+        one = moe.dense_experts(rows[i:i + 1], chosen[i:i + 1],
+                                weigh[i:i + 1], w["experts_up"],
+                                w["experts_down"], 0)
+        np.testing.assert_allclose(one[0], full[i], rtol=0, atol=2e-6)
+    np.testing.assert_allclose(
+        full, plain.routed_part(rows, w, chosen, weigh, 0), atol=1e-5)
+    # every pair on a held expert was computed: none dropped
+    held = (chosen < 4).sum(axis=1)
+    assert float(jnp.abs(full[held == 0]).max()) == 0.0
+    assert float(jnp.abs(full[held > 0]).min(axis=0).max()) > 0.0
+
+
+def test_a_request_is_served_the_same_alone_and_in_a_full_step(
+        share, served):
+    _file, _dims, config, params = share
+    _engine_, prompts, new_tokens, done, _records = served
+    for request_id in ("r0", "r7"):
+        alone = _serve(_engine(config, params),
+                       {request_id: prompts[request_id]}, new_tokens)
+        assert alone[request_id] == done[request_id]
+
+
+# ------------------- (f) the record of choices
+
+
+def test_take_decisions_covers_every_position_once(served):
+    engine, prompts, _new, done, records = served
+    for request_id, record in records.items():
+        positions = len(prompts[request_id]) + len(done[request_id]) - 1
+        assert record["first"] == 0
+        assert list(record["layers"]) == ["layer_1", "layer_4",
+                                          "layer_6"]
+        for rows in record["layers"].values():
+            assert rows.shape == (positions, 2)
+            assert rows.dtype == np.int32
+            assert rows.min() >= 0 and rows.max() < 8     # of ALL 8
+            assert (rows[:, 0] != rows[:, 1]).all()
+        # handed over once
+        assert engine.take_decisions(request_id) is None
+    assert engine.take_decisions("nobody") is None
+    # experts held elsewhere are chosen too, and recorded
+    assert max(rows.max() for record in records.values()
+               for rows in record["layers"].values()) >= 4
+
+
+def test_a_cancelled_request_leaves_no_record(share):
+    _file, _dims, config, params = share
+    engine = _engine(config, params)
+    engine.submit(Request("gone", _prompts(1)["r0"], max_new_tokens=9))
+    engine.step()
+    engine.step()
+    assert engine.cancel("gone")
+    while engine.pending():
+        engine.step()
+    assert engine.take_decisions("gone") is None
+    assert not engine._decisions and not engine._decisions_done
+
+
+# ------------------- the state per slot, the pool, the counters
+
+
+def test_the_cache_holds_a_state_per_slot_beside_the_paged_kv(share,
+                                                             served):
+    _file, dims, config, _params = share
+    engine = served[0]
+    assert engine.stateful and engine.paged
+    layer = engine.cache["layer_0"]["ssm"]
+    assert layer["ssm_state"].shape == (4, 4, 16, 16)
+    assert layer["ssm_state"].dtype == jnp.float32
+    assert layer["conv_tail"].shape == (4, 3, dims["conv_dim"])
+    # two K/V heads of 16 in the pool's rows, not the four query heads
+    pool = engine.cache["layer_3"]["attn"]["k_pages"]
+    assert pool.shape[1:] == (PAGE, 2 * 16)
+    assert "index" not in layer and "length" not in layer
+    per_slot = 3 * (4 * 16 * 16 * 4 + 3 * dims["conv_dim"] * 4)
+    assert inf.slot_state_bytes(engine.cache) == per_slot
+    idle = engine.occupancy()
+    assert idle["state_slots_in_use"] == 0
+    assert idle["experts_held"] == 3 * 4
+    engine.submit(Request("x", [1, 2, 3], max_new_tokens=4))
+    engine.step()
+    busy = engine.occupancy()
+    assert busy["state_slots_in_use"] == 1
+    assert busy["state_bytes_held"] == per_slot
+    while engine.pending():
+        engine.step()
+
+
+def test_idle_slots_are_parked_and_their_state_is_left_alone():
+    """_park_idle_cursors touches cursors alone: a state leaf has
+    none."""
+    cache = {"layer_0": {"ssm": {"ssm_state": jnp.ones((3, 2, 2, 2)),
+                                 "conv_tail": jnp.ones((3, 3, 4))}},
+             "layer_1": {"attn": {"length": jnp.asarray([5, 6, 7])}}}
+    parked = inf._park_idle_cursors(
+        cache, jnp.asarray([True, False, True]))
+    assert parked["layer_1"]["attn"]["length"].tolist() == [5, 0, 7]
+    assert float(parked["layer_0"]["ssm"]["ssm_state"].min()) == 1.0
+
+
+def test_matched_pages_are_shared_and_the_whole_prompt_is_run(share):
+    """A model with a per-slot state never seats a K/V page for a
+    position whose state it does not hold: a request that matches
+    pages of the index shares them (one copy in the pool) and still
+    prefills its whole prompt, the matched pages' rows going to the
+    scratch page. Its tokens are those of an engine without the
+    index, and its record covers the prompt from position 0."""
+    _file, _dims, config, params = share
+    prefix = _prompts(1, 40, 41, seed=5)["r0"]
+    prompts = {f"s{i}": prefix + tail for i, tail in enumerate(
+        _prompts(3, 5, 20, seed=6).values())}
+    new_tokens = dict.fromkeys(prompts, 6)
+    shared = _engine(config, params)
+    done = {}
+    for request_id, prompt in prompts.items():   # one after another
+        done.update(_serve(shared, {request_id: prompt}, new_tokens))
+    stats = shared.prefix_stats()
+    assert stats["hit_pages"] == 2 * (40 // PAGE)
+    plain_engine = _engine(config, params, prefix_cache=False)
+    assert _serve(plain_engine, prompts, new_tokens) == done
+    for request_id in prompts:
+        record = shared.take_decisions(request_id)
+        assert record["first"] == 0
+        assert len(record["layers"]["layer_1"]) == \
+            len(prompts[request_id]) + 5
+
+
+def test_the_expert_counters_count_decode_steps_pairs(share):
+    _file, _dims, config, params = share
+    engine = _engine(config, params)
+    _serve(engine, _prompts(4), dict.fromkeys(_prompts(4), 6))
+    stats = engine.step_stats()
+    # 4 requests x 5 decoded tokens x 3 routed layers x top-2
+    assert stats["expert_pairs_chosen"] == 4 * 5 * 3 * 2
+    assert 0 < stats["expert_pairs_here"] < stats["expert_pairs_chosen"]
+    assert 0 < stats["experts_hit"] <= stats["decode_steps"] * 3 * 4
+
+
+def test_step_rows_carry_the_new_counters(share, recorder):
+    from batch_shipyard_tpu.trace import spans as trace_spans
+    _file, _dims, config, params = share
+    engine = _engine(config, params)
+    _serve(engine, _prompts(3), dict.fromkeys(_prompts(3), 5))
+    rows = [row["attrs"] for row in recorder()
+            if row["kind"] == trace_spans.SPAN_SERVE_STEP]
+    assert rows
+    for row in rows:
+        assert {"expert_pairs_here", "expert_pairs_chosen",
+                "experts_hit", "state_slots_in_use",
+                "state_bytes_held", "experts_held"} <= set(row)
+    assert sum(row["expert_pairs_chosen"] for row in rows) == \
+        engine.step_stats()["expert_pairs_chosen"]
+    landed = [row for row in rows if row["expert_pairs_chosen"]]
+    assert all(row["experts_hit"] <= 12 for row in landed)
+
+
+def test_each_block_kind_has_its_own_scope_in_the_step_program(share):
+    """layer_i/<kind>/... in the lowered decode step's operation
+    names, so that a device trace can be split by kind."""
+    _file, _dims, config, params = share
+    engine = _engine(config, params)
+    text = serving._decode_step.lower(
+        engine.model, engine.sampling, params, engine.cache,
+        engine._tokens, engine._positions, engine._active,
+        engine._key).as_text(debug_info=True)
+    for scope in ("layer_0/ssm", "layer_1/experts", "layer_3/attn"):
+        assert scope in text, scope
+
+
+def test_a_draft_model_is_refused_for_a_stateful_target(share):
+    _file, _dims, config, params = share
+    with pytest.raises(ValueError, match="per-slot state"):
+        _engine(config, params, speculative=serving.SpeculativeConfig(
+            draft_config=config, draft_params=params, gamma=2))
+
+
+# ------------------- fewer K/V heads than query heads
+
+
+def test_grouped_query_attention_is_repeated_kv_attention():
+    """masked_attention and the paged xla path with 2 K/V heads under
+    4 query heads against the same call with each K/V head repeated
+    for its group."""
+    from batch_shipyard_tpu.ops import paged_attention as paged
+    key = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(key[0], (3, 1, 4, 16))
+    pages_k = jax.random.normal(key[1], (7, 8, 2 * 16))
+    pages_v = jax.random.normal(key[2], (7, 8, 2 * 16))
+    table = jnp.asarray([[0, 1, 6], [2, 6, 6], [3, 4, 5]])
+    lengths = jnp.asarray([11, 3, 24])
+    grouped = paged.paged_decode_attention(q, pages_k, pages_v, table,
+                                           lengths, impl="kernel")
+
+    def repeat(pool):
+        return jnp.repeat(pool.reshape(7, 8, 2, 16), 2,
+                          axis=2).reshape(7, 8, 4 * 16)
+
+    want = paged.paged_decode_attention_xla(
+        q, repeat(pages_k), repeat(pages_v), table, lengths)
+    np.testing.assert_allclose(grouped, want, atol=1e-5)
+    k_all = jax.random.normal(key[3], (3, 9, 2, 16))
+    mask = jnp.tril(jnp.ones((9, 9), bool))[None, None, -1:, :]
+    got = paged.masked_attention(q, k_all, k_all, mask, jnp.float32)
+    want = paged.masked_attention(
+        q, jnp.repeat(k_all, 2, axis=2), jnp.repeat(k_all, 2, axis=2),
+        mask, jnp.float32)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_layer_kinds_from_the_list_and_from_the_stride():
+    dense = tfm.TransformerConfig(n_layers=4)
+    assert tfm.layer_kinds(dense) == ("dense",) * 4
+    strided = dataclasses.replace(dense, moe=moe.MoEConfig(),
+                                  moe_every=2)
+    assert tfm.layer_kinds(strided) == ("dense", "dense_moe") * 2
+    assert tfm.decision_layer_names(strided) == ()
+    assert not tfm.has_slot_state(strided)
+    with pytest.raises(ValueError, match="block_kinds"):
+        tfm.layer_kinds(dataclasses.replace(
+            dense, block_kinds=("ssm", "attn")))
+    mixed = dataclasses.replace(
+        dense, block_kinds=("ssm", "experts", "attn", "dense"),
+        ssm=ssm.SSMConfig())
+    assert tfm.decision_layer_names(mixed) == ("layer_1",)
+    assert tfm.paged_layer_count(mixed) == 2
+    assert tfm.has_slot_state(mixed)
+
+
+def test_the_chunked_scan_is_the_recurrence():
+    """ssd_scan over 37 tokens in chunks of 8 (the last one padded)
+    from a given state against 37 calls of ssd_step."""
+    key = jax.random.split(jax.random.PRNGKey(4), 6)
+    x = jax.random.normal(key[0], (2, 37, 4, 8))
+    dt = jax.nn.softplus(jax.random.normal(key[1], (2, 37, 4)))
+    a = -jnp.exp(0.3 * jax.random.normal(key[2], (4,)))
+    b = jax.random.normal(key[3], (2, 37, 2, 16))
+    c = jax.random.normal(key[4], (2, 37, 2, 16))
+    state = jax.random.normal(key[5], (2, 4, 8, 16))
+    y, last = ssm.ssd_scan(x, dt, a, b, c, state, 8)
+    rows = []
+    for t in range(37):
+        row, state = ssm.ssd_step(x[:, t], dt[:, t], a, b[:, t],
+                                  c[:, t], state)
+        rows.append(row)
+    np.testing.assert_allclose(y, jnp.stack(rows, 1), atol=2e-5)
+    np.testing.assert_allclose(last, state, atol=2e-5)
+
+
+def test_a_state_kept_in_bfloat16_is_rounded_once_a_token(share):
+    """SSMConfig.state_dtype, the program's lower-precision switch
+    (the benchmark's control turns it on): the state leaf is kept in
+    bfloat16 and still advanced in float32. One token in, the kept
+    state is the float32 one rounded once (2**-8 of each entry);
+    sixty-four tokens on, a head that remembers (A_log -8: no decay to
+    speak of) has gathered those roundings and reads beyond one
+    rounding of its own output, while the float32 engine's state is
+    untouched by the switch."""
+    _file, _dims, config, params = share
+    params = jax.tree_util.tree_map(lambda leaf: leaf, params)
+    params["layer_0"]["ssm"]["A_log"] = jnp.full((4,), -8.0)
+    prompt = _prompts(1, 70, 71, seed=9)["r0"]
+    states = {}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        kept = dataclasses.replace(config, ssm=dataclasses.replace(
+            config.ssm, state_dtype=dtype))
+        model = tfm.TransformerLM(inf.decode_config(kept, 128))
+        cache, _last, _chosen = serving._dense_prefill(
+            model, None, params, jnp.asarray([prompt[:6]]), 6)
+        trail = []
+        for position in range(6, 70):
+            _logits, mutated = model.apply(
+                {"params": params, "cache": cache},
+                jnp.asarray([[prompt[position]]]),
+                positions=jnp.asarray([[position]]),
+                mutable=serving._MUTABLE)
+            cache = mutated["cache"]
+            trail.append(cache["layer_0"]["ssm"]["ssm_state"])
+        assert trail[0].dtype == dtype
+        states[dtype] = [np.asarray(s, np.float32) for s in trail]
+    exact, rounded = states[jnp.float32], states[jnp.bfloat16]
+    size = np.abs(exact[-1]).max()
+    first = np.abs(rounded[0] - exact[0]).max() / np.abs(exact[0]).max()
+    last = np.abs(rounded[-1] - exact[-1]).max() / size
+    assert 0 < first <= 2.0 ** -8
+    assert last > 2.0 ** -8, last    # the roundings gathered ...
+    assert last < 0.1                # ... and it is still the state

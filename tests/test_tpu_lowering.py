@@ -369,3 +369,87 @@ def test_serving_steps_update_the_pool_in_place_on_v5e(v5e_devices,
     assert set(touching) <= {"parameter", "scatter", "fusion",
                              "dynamic-update-slice"}, touching
     assert touching.get("fusion", 0) == writes, touching
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
+        v5e_devices, program):
+    """The step programs of a stack of block kinds (one state-space,
+    one routed-expert and one attention block at the benchmark's
+    second configuration's widths: 64 state-space heads of 64 over a
+    state of 128, 32 query over 2 K/V heads of 128, 64 held experts of
+    1856 out of a router of 128; 96 slots, 2,400 pages of 64 tokens),
+    compiled for the v5e: every leaf of the donated cache, the
+    per-slot state included, is aliased input to output; the state
+    (201 MB a layer) is advanced where it lies, never copied, relaid
+    out or converted whole; and neither are the held experts' weights
+    (638 MB a matrix): the two matmuls over all of them read them
+    where they lie (the compiler's grouped matmul, the road tried
+    first, copied a stack whole for every call: PERF.md, PR 31)."""
+    import dataclasses
+    import re
+
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import moe, serving, ssm
+    from batch_shipyard_tpu.models import transformer as tfm
+
+    slots, max_len, page, pages = 96, 2048, 64, 2400
+    chip = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+    config = tfm.TransformerConfig(
+        vocab_size=65536, d_model=2688, n_layers=3, n_heads=32,
+        n_kv_heads=2, d_head=128, max_seq_len=max_len, dtype=bf16,
+        param_dtype=bf16, use_rope=False, tie_embeddings=False,
+        norm_eps=1e-5, block_kinds=("ssm", "experts", "attn"),
+        ssm=ssm.SSMConfig(),
+        experts=moe.RoutedConfig(
+            d_model=2688, n_experts=128, top_k=6, d_expert=1856,
+            d_shared=3712, scale=2.5, experts_held=64))
+    dense = tfm.TransformerLM(inf.decode_config(config, max_len))
+    paged = tfm.TransformerLM(dataclasses.replace(
+        dense.config, kv_page_size=page, kv_num_pages=pages + 1))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=chip), tree)
+
+    def arg(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: tfm.TransformerLM(config).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), i32))["params"]))
+    cache = on_chip(jax.eval_shape(
+        lambda: inf.init_cache(paged, None, slots)))
+    if program == "decode":
+        lowered = serving._decode_step.lower(
+            paged, inf.SamplingConfig(temperature=0.0), params, cache,
+            arg((slots, 1)), arg((slots,)), arg((slots,), jnp.bool_),
+            arg((2,), jnp.uint32))
+    else:
+        row = arg((max_len // page,))
+        lowered = serving._prefill_paged.lower(
+            dense, None, page, params, cache, 0, arg((1, 512)), row,
+            400)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    moved = re.findall(
+        r"= bf16\[64,(?:2688,1856|1856,2688)\]\S* "
+        r"(copy|transpose|copy-start|convert)\(", text)
+    assert not moved, moved
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - cache_bytes < 2 ** 20
+    # nothing the size of the state or of the pool sits in temp twice
+    assert memory.temp_size_in_bytes < 1.2e9
+    state = re.compile(
+        r"= f32\[96,(?:64,64|8,8,64),128\]\S* ([\w-]+)\(")
+    touching = {}
+    for op in state.findall(text):
+        touching[op] = touching.get(op, 0) + 1
+    # (inside a fusion's body the state meets elementwise operations;
+    # what may not appear is anything that MOVES a whole state)
+    assert touching and not set(touching) & {
+        "copy", "copy-start", "copy-done", "transpose", "reshape",
+        "convert", "gather"}, touching
