@@ -12,9 +12,11 @@ Architecture:
   - ONE engine thread owns the ContinuousBatcher: it drains the
     submission queue, calls engine.step() while work is active, and
     completes waiters — the engine is never touched from two threads;
-  - the engine's on_token hook timestamps each request's first token,
-    giving true TTFT (time-to-first-token) rather than
-    time-to-completion.
+  - the engine's on_tokens hook (one call a landed step) timestamps
+    each request's first token, giving true TTFT (time-to-first-token)
+    rather than time-to-completion, and hands the step's tokens to
+    ONE stream-writer thread (_StreamWriter) that sends every
+    stream's lines: a step wakes one thread, however many streams.
 
 Endpoints:
   POST /v1/generate   {"prompt": [ids], "max_new_tokens": n,
@@ -36,9 +38,11 @@ Endpoints:
 
 from __future__ import annotations
 
+import collections
 import json
 import math
 import queue
+import selectors
 import socket
 import threading
 import time
@@ -159,10 +163,255 @@ def prometheus_lines(prefix: str, values: dict,
     return out
 
 
+_STREAM_END = b"0\r\n\r\n"
+
+
+def _chunk(line: bytes) -> bytes:
+    """One NDJSON line in chunked-transfer framing."""
+    return b"%x\r\n%b\n\r\n" % (len(line) + 1, line)
+
+
+def _json_chunk(obj: dict) -> bytes:
+    return _chunk(json.dumps(obj).encode())
+
+
+class _Stream:
+    """What the stream writer keeps of one streaming reply. Only the
+    writer thread touches sock, unsent and stalled_at; the handler
+    thread of the connection waits on wake (the run is over, or the
+    stream was dropped) and then on done (everything it handed to
+    the writer's close() is on the socket, or the stream was
+    dropped)."""
+
+    __slots__ = ("sock", "unsent", "stalled_at", "dropped", "closing",
+                 "wake", "done")
+
+    def __init__(self) -> None:
+        self.sock: Optional[socket.socket] = None   # until registered
+        # Bytes not on the socket yet, in order: lines that came
+        # before the handler registered its connection, and the rest
+        # of a send the client's window did not take whole.
+        self.unsent = bytearray()
+        # When the socket last took nothing of what was owed to it.
+        self.stalled_at: Optional[float] = None
+        self.dropped: Optional[str] = None          # why, once dropped
+        self.closing = False                        # its last bytes are in
+        self.wake = threading.Event()
+        self.done = threading.Event()
+
+
+class _StreamWriter:
+    """ONE thread that writes every stream's lines.
+
+    The engine thread hands over a landed step's tokens with one
+    append and one byte on a wake-up socket (write), so a step wakes
+    one thread however many streams it feeds, and the writer sends
+    each stream its chunk-framed NDJSON line in the order handed over:
+    one ordered channel a stream, token lines, then whatever the
+    handler thread hands to close(). Sends never block: the
+    connection is non-blocking while it is registered here, what a
+    send leaves over is kept on the stream and sent when the socket
+    turns writable (the selector says when: no timer), and later
+    lines queue behind it. A stream that owes more than
+    BACKLOG_LIMIT bytes, or whose socket has taken nothing for
+    io_timeout_s where that is set, is dropped as a client that went
+    away is: the engine finishes the run, the handler retires the
+    registration and closes the connection."""
+
+    BACKLOG_LIMIT = 1 << 20
+
+    def __init__(self, io_timeout_s: Optional[float]) -> None:
+        self.io_timeout_s = io_timeout_s
+        self._inbox: collections.deque = collections.deque()
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._selector = selectors.DefaultSelector()
+        self._selector.register(self._wake_r, selectors.EVENT_READ)
+        self._stalled: set[_Stream] = set()     # in the selector
+        self._running = True
+        self._thread = threading.Thread(
+            target=self._run, name="serving-stream-writer", daemon=True)
+        # Written by the writer thread alone.
+        self.handovers = 0          # token batches taken
+        self.tokens_written = 0     # token lines sent or kept to send
+        self.sends_deferred = 0     # sends the socket took only part of
+        self.dropped_backlog = 0    # streams dropped for what they owed
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._hand(self._stop)
+        if self._thread.is_alive():
+            self._thread.join(timeout=10.0)
+
+    # ---- called from the engine thread and the handler threads
+
+    def write(self, lines: list) -> None:
+        """A step's [(stream, token, index), ...]: one hand-over."""
+        self._hand(self._tokens, lines)
+
+    def register(self, stream: _Stream, sock: socket.socket) -> None:
+        """The reply's headers are out: from here on the writer owns
+        the connection's writes, until close() returns."""
+        sock.setblocking(False)
+        self._hand(self._register, stream, sock)
+
+    def close(self, stream: _Stream, data: bytes) -> bool:
+        """Send the reply's last bytes (final line, end of the
+        chunked body) behind its token lines, wait until they are on
+        the socket, and give the connection back to its handler
+        thread as it was. -> whether the whole reply got there
+        (False: the stream was dropped)."""
+        self._hand(self._finish, stream, data)
+        stream.done.wait()
+        if stream.dropped is not None:
+            return False    # the handler closes the connection
+        try:
+            stream.sock.settimeout(self.io_timeout_s)
+        except OSError:
+            return False    # severed under us (kill())
+        return True
+
+    def discard(self, stream: _Stream) -> None:
+        """The client went away before its headers: keep nothing."""
+        self._hand(self._drop, stream,
+                   "client went away before its headers")
+
+    def _hand(self, take, *args) -> None:
+        self._inbox.append((take, args))
+        try:
+            self._wake_w.send(b"\0")
+        except OSError:
+            pass    # full: wake-ups enough are pending; or stopped
+
+    # ---- the writer thread
+
+    def _run(self) -> None:
+        while self._running:
+            try:
+                self._turn()
+            except Exception:  # one stream's fault is not all's
+                logger.exception("stream writer")
+
+    def _turn(self) -> None:
+        for key, _events in self._selector.select(self._patience()):
+            if key.data is None:
+                try:
+                    self._wake_r.recv(4096)
+                except BlockingIOError:
+                    pass
+            else:
+                self._flush(key.data)
+        while self._inbox:
+            take, args = self._inbox.popleft()
+            take(*args)
+        if self._stalled and self.io_timeout_s is not None:
+            late = time.monotonic() - self.io_timeout_s
+            for stream in [s for s in self._stalled
+                           if s.stalled_at <= late]:
+                self._drop(stream, "client stopped reading",
+                           backlog=True)
+
+    def _patience(self) -> Optional[float]:
+        """Seconds until the longest-stalled stream is dropped; None
+        where nothing is stalled or no deadline is set."""
+        if not self._stalled or self.io_timeout_s is None:
+            return None
+        oldest = min(s.stalled_at for s in self._stalled)
+        return max(0.0, oldest + self.io_timeout_s - time.monotonic())
+
+    def _tokens(self, lines: list) -> None:
+        self.handovers += 1
+        for stream, token, index in lines:
+            # not after the reply's last bytes (a run that outlived
+            # its reply's timeout): the connection is the handler's
+            if stream.dropped is None and not stream.closing:
+                self.tokens_written += 1
+                self._send(stream, _chunk(
+                    b'{"token": %d, "index": %d}' % (token, index)))
+
+    def _register(self, stream: _Stream, sock: socket.socket) -> None:
+        stream.sock = sock
+        if stream.dropped is None:
+            self._flush(stream)
+
+    def _finish(self, stream: _Stream, data: bytes) -> None:
+        if stream.dropped is None:
+            stream.closing = True
+            self._send(stream, data)
+
+    def _stop(self) -> None:
+        for stream in list(self._stalled):
+            self._drop(stream, "front end stopped")
+        self._selector.close()
+        self._wake_r.close()
+        self._wake_w.close()
+        self._running = False
+
+    def _send(self, stream: _Stream, data: bytes) -> None:
+        """Send, or queue behind what the stream already owes."""
+        stream.unsent += data
+        if stream.sock is None or stream in self._stalled:
+            if len(stream.unsent) > self.BACKLOG_LIMIT:
+                self._drop(stream, "backlog over the bound",
+                           backlog=True)
+            return
+        self._flush(stream)
+
+    def _flush(self, stream: _Stream) -> None:
+        unsent = stream.unsent
+        sent = 0
+        try:
+            if unsent:
+                try:
+                    sent = stream.sock.send(unsent)
+                except (BlockingIOError, InterruptedError):
+                    pass
+                del unsent[:sent]
+            if unsent:
+                self.sends_deferred += 1
+                if sent or stream.stalled_at is None:
+                    stream.stalled_at = time.monotonic()
+                if stream not in self._stalled:
+                    self._selector.register(
+                        stream.sock, selectors.EVENT_WRITE, stream)
+                    self._stalled.add(stream)
+                return
+            self._settle(stream)
+        except (OSError, ValueError) as exc:
+            # a reset; ValueError: closed under the writer (kill())
+            self._drop(stream, f"client went away: {exc}")
+            return
+        if stream.closing:
+            stream.done.set()
+
+    def _settle(self, stream: _Stream) -> None:
+        """Nothing is owed (or the stream is gone): out of the
+        selector."""
+        stream.stalled_at = None
+        if stream in self._stalled:
+            self._stalled.discard(stream)
+            self._selector.unregister(stream.sock)
+
+    def _drop(self, stream: _Stream, why: str,
+              backlog: bool = False) -> None:
+        if stream.dropped is not None:
+            return
+        self._settle(stream)
+        stream.dropped = why
+        stream.unsent.clear()
+        if backlog:
+            self.dropped_backlog += 1
+        stream.wake.set()
+        stream.done.set()
+
+
 class _Pending:
     __slots__ = ("request", "event", "submitted_at", "submitted_wall",
                  "admitted_at", "first_token_at",
-                 "finished_at", "tokens", "error", "token_queue",
+                 "finished_at", "tokens", "error", "stream",
                  "cancelled", "shed", "draining", "resumed",
                  "emitted")
 
@@ -194,10 +443,16 @@ class _Pending:
         # Highest emitted-token count (global index + 1): the
         # /v1/requests/<id> phase probe's progress source of truth.
         self.emitted = len(resumed) if resumed else 0
-        # Streaming mode: the engine thread feeds (index, token)
-        # pairs here as they decode; None terminates the stream.
-        self.token_queue: Optional["queue.Queue"] = (
-            queue.Queue() if stream else None)
+        # Streaming mode: what the stream writer keeps of this
+        # request's connection; the handler thread waits on it.
+        self.stream: Optional[_Stream] = _Stream() if stream else None
+
+    def finish(self) -> None:
+        """The run is over (tokens or an error are set): wake whoever
+        waits for it."""
+        self.event.set()
+        if self.stream is not None:
+            self.stream.wake.set()
 
 
 def percentile(values: list[float], pct: float) -> float:
@@ -250,7 +505,12 @@ class ServingFrontEnd:
         self._drain_reason = ""
         self._drain_engine_done = False
         self.drain_rejections = 0
+        # Both observers: an engine that hands over a step's tokens
+        # at once calls on_tokens, one that only knows on_token calls
+        # that; either way the lines go through the one writer.
         engine.on_token = self._on_token
+        engine.on_tokens = self._on_tokens
+        self._streams = _StreamWriter(io_timeout_s)
         engine.on_admit = self._on_admit
         engine.on_shed = self._on_shed
         # The engine's serve_step rows are head-sampled like the
@@ -283,7 +543,6 @@ class ServingFrontEnd:
         # percentiles come from the running counters + histograms
         # below, so a replica's memory/stats cost never grows with
         # lifetime traffic.
-        import collections
         self._completed: "collections.deque" = collections.deque(
             maxlen=2048)
         # Finished-result replay cache (bounded), written atomically
@@ -425,19 +684,21 @@ class ServingFrontEnd:
             def _stream_generate(self, spec: dict) -> None:
                 """Newline-delimited JSON token stream over chunked
                 transfer: the client sees each token the engine step
-                that produced it, then the final result object.
+                that produced it, then the final result object. This
+                thread sends the headers and then waits; the lines
+                are the stream writer's to send (serve_stream).
                 Validation errors before headers -> plain 400; errors
                 AFTER the 200/chunked headers are emitted as a final
                 {"error": ...} NDJSON line + clean terminating chunk
                 (a second HTTP response inside the open stream would
                 corrupt the framing)."""
-                stream = None
+                pending = None
                 try:
-                    request_id, stream = front.generate_stream(spec)
+                    pending = front.submit_stream(spec)
                 except CompletedReplay as exc:
                     # Replay the cached run as a stream: the router's
                     # index dedupe drops what the client already saw.
-                    result, request_id = exc.result, None
+                    result = exc.result
                 except RequestDraining as exc:
                     self._reply(503, {"error": str(exc),
                                       "draining": True},
@@ -462,60 +723,27 @@ class ServingFrontEnd:
                     self.send_header("Transfer-Encoding", "chunked")
                     self.end_headers()
                 except OSError:
-                    # Client vanished before headers: the iterator
-                    # never runs, so ITS cleanup never runs — drop
-                    # the front-end registration explicitly (the
+                    # Client vanished before headers: drop the
+                    # front-end registration explicitly (the
                     # engine-side guard still protects the id until
                     # decode completes).
-                    if request_id is not None:
-                        front.abandon(request_id)
+                    if pending is not None:
+                        front.abandon(pending)
                     return
-
-                def _chunk(obj: dict) -> None:
-                    line = json.dumps(obj).encode() + b"\n"
-                    self.wfile.write(
-                        f"{len(line):x}\r\n".encode() + line +
-                        b"\r\n")
-                    self.wfile.flush()
-
-                if stream is None:
+                if pending is None:
                     # CompletedReplay: token lines then the cached
                     # final result, same framing as a live stream.
-                    try:
-                        for i, token in enumerate(result["tokens"]):
-                            _chunk({"token": token, "index": i})
-                        _chunk(dict(result, cached=True))
-                        self.wfile.write(b"0\r\n\r\n")
-                    except (BrokenPipeError, ConnectionResetError):
-                        pass
-                    return
-                try:
-                    try:
-                        for event in stream:
-                            _chunk(event)
-                    except (BrokenPipeError, ConnectionResetError):
-                        # Client went away mid-relay: not a stream
-                        # failure — the outer handler ignores it and
-                        # the engine finishes the run on its own.
-                        raise
-                    except RequestDraining as exc:
-                        # Mid-stream drain-abandon: the marker tells
-                        # the router to resume on a sibling rather
-                        # than surface a failure.
-                        _chunk({"error": str(exc), "draining": True})
-                    except RequestShed as exc:
-                        _chunk({"error": str(exc), "shed": True})
-                    except (ValueError, TimeoutError,
-                            RequestCancelled) as exc:
-                        _chunk({"error": str(exc)})
-                    except Exception as exc:  # defensive
-                        logger.exception("stream failed")
-                        _chunk({"error": str(exc)})
-                    self.wfile.write(b"0\r\n\r\n")
-                except (BrokenPipeError, ConnectionResetError):
-                    pass  # client went away; engine finishes anyway
-                finally:
-                    stream.close()  # run the iterator's cleanup NOW
+                    whole = front.replay_stream(result,
+                                                self.connection)
+                else:
+                    whole = front.serve_stream(pending,
+                                               self.connection)
+                if not whole:
+                    # Client went away (or stopped reading) mid-
+                    # stream: the framing is cut, the connection
+                    # serves nothing more. The engine finishes the
+                    # run on its own.
+                    self.close_connection = True
 
         if io_timeout_s is not None:
             # socketserver applies Handler.timeout as the connection
@@ -539,6 +767,7 @@ class ServingFrontEnd:
         return f"http://{host}:{port}"
 
     def start(self) -> "ServingFrontEnd":
+        self._streams.start()
         self._engine_thread.start()
         self._http_thread.start()
         return self
@@ -548,6 +777,13 @@ class ServingFrontEnd:
         self._httpd.shutdown()
         self._httpd.server_close()
         self._engine_thread.join(timeout=10.0)
+        self._streams.stop()
+        writer = self._streams
+        logger.info(
+            "stream writer: %d handovers, %d token lines, %d sends "
+            "deferred, %d streams dropped for their backlog",
+            writer.handovers, writer.tokens_written,
+            writer.sends_deferred, writer.dropped_backlog)
         # Spans still buffered (trace/spans.py) reach the file before
         # whoever shut this down reads it.
         trace_spans.flush()
@@ -577,6 +813,7 @@ class ServingFrontEnd:
             except OSError:
                 pass
         self._engine_thread.join(timeout=10.0)
+        self._streams.stop()
 
     # ------------------------------ draining ---------------------------
 
@@ -709,9 +946,7 @@ class ServingFrontEnd:
             pending.tokens = list(resume)
             pending.finished_at = time.perf_counter()
             pending.first_token_at = pending.finished_at
-            if pending.token_queue is not None:
-                pending.token_queue.put(None)
-            pending.event.set()
+            pending.finish()
         return pending
 
     def _result(self, pending: _Pending) -> dict:
@@ -830,47 +1065,89 @@ class ServingFrontEnd:
             wall(pending.finished_at), parent_span_id=parent,
             **decode_attrs)
 
-    def generate_stream(self, spec: dict, timeout: float = 300.0):
-        """Streaming generate: yields {"token", "index"} per decoded
-        token, then the final result object (generate()'s payload).
-        Validation happens HERE (before any bytes hit the wire) — the
-        returned iterator only pulls tokens."""
+    def submit_stream(self, spec: dict) -> _Pending:
+        """Streaming generate, first half: validation happens HERE
+        (before any bytes hit the wire) and the request goes to the
+        engine; serve_stream() is the reply."""
         pending = self._make_pending(spec, stream=True)
         if not pending.event.is_set():  # pre-satisfied resumes skip
             self._submit_q.put(pending)
-        return (pending.request.request_id,
-                self._stream_tokens(pending, timeout))
+        return pending
 
-    def abandon(self, request_id: str) -> None:
-        """Drop the front-end registration of a request whose client
-        went away before its stream ever started (the engine keeps
-        decoding; _engine_active still blocks id reuse meanwhile)."""
+    def abandon(self, pending: _Pending) -> None:
+        """Drop the front-end registration of a stream whose client
+        went away before its headers (the engine keeps decoding;
+        _engine_active still blocks id reuse meanwhile) and what the
+        writer has kept for it."""
         with self._inflight_lock:
-            self._inflight.pop(request_id, None)
+            self._inflight.pop(pending.request.request_id, None)
+        self._streams.discard(pending.stream)
 
-    def _stream_tokens(self, pending: _Pending, timeout: float):
+    def serve_stream(self, pending: _Pending, conn: socket.socket,
+                     timeout: float = 300.0) -> bool:
+        """The reply of a streaming generate, on the handler thread
+        of ``conn`` after its headers: hand the connection to the
+        stream writer (which sends a {"token", "index"} line per
+        decoded token, the step it is decoded in), wait for the end
+        of the run, and hand the writer the last line: the final
+        result object (generate()'s payload) or the error / draining
+        / shed marker. -> whether the whole reply reached the socket
+        (False: the client went away or stopped reading, and the
+        stream was dropped)."""
+        stream = pending.stream
         request_id = pending.request.request_id
+        self._streams.register(stream, conn)
         try:
-            while True:
-                try:
-                    item = pending.token_queue.get(timeout=timeout)
-                except queue.Empty:
-                    raise TimeoutError(
-                        f"request {request_id} timed out after "
-                        f"{timeout}s")
-                if item is None:
-                    break
-                index, token = item
-                yield {"token": token, "index": index}
-            self._wait_complete(pending, timeout)
-        except BaseException:
-            # Error/cancel/close path retires the registration here;
-            # the success path retires it inside _result, atomically
-            # with the replay-cache publish (racing-resume guard).
-            with self._inflight_lock:
-                self._inflight.pop(request_id, None)
-            raise
-        yield self._result(pending)
+            try:
+                # ``timeout`` bounds the wait for the NEXT token, as
+                # the per-line wait did: a run that still emits is
+                # alive however long it is.
+                emitted = None
+                while not stream.wake.wait(timeout):
+                    if emitted == pending.emitted:
+                        raise TimeoutError(
+                            f"request {request_id} timed out after "
+                            f"{timeout}s")
+                    emitted = pending.emitted
+                if stream.dropped is not None:
+                    raise BrokenPipeError(stream.dropped)
+                self._raise_outcome(pending)
+            except BaseException:
+                # Error/cancel/vanished-client path retires the
+                # registration here; the success path retires it
+                # inside _result, atomically with the replay-cache
+                # publish (racing-resume guard).
+                with self._inflight_lock:
+                    self._inflight.pop(request_id, None)
+                raise
+            last = self._result(pending)
+        except BrokenPipeError:
+            return False
+        except RequestDraining as exc:
+            # Mid-stream drain-abandon: the marker tells the router
+            # to resume on a sibling rather than surface a failure.
+            last = {"error": str(exc), "draining": True}
+        except RequestShed as exc:
+            last = {"error": str(exc), "shed": True}
+        except (ValueError, TimeoutError, RequestCancelled) as exc:
+            last = {"error": str(exc)}
+        except Exception as exc:  # defensive
+            logger.exception("stream failed")
+            last = {"error": str(exc)}
+        return self._streams.close(stream,
+                                   _json_chunk(last) + _STREAM_END)
+
+    def replay_stream(self, result: dict,
+                      conn: socket.socket) -> bool:
+        """A finished run replayed as a stream (CompletedReplay):
+        its token lines and the cached final result, through the
+        same writer."""
+        stream = _Stream()
+        self._streams.register(stream, conn)
+        lines = [_json_chunk({"token": token, "index": i})
+                 for i, token in enumerate(result["tokens"])]
+        lines += [_json_chunk(dict(result, cached=True)), _STREAM_END]
+        return self._streams.close(stream, b"".join(lines))
 
     def _wait_complete(self, pending: _Pending,
                        timeout: float) -> None:
@@ -880,6 +1157,11 @@ class ServingFrontEnd:
             raise TimeoutError(
                 f"request {pending.request.request_id} timed out "
                 f"after {timeout}s")
+        self._raise_outcome(pending)
+
+    @staticmethod
+    def _raise_outcome(pending: _Pending) -> None:
+        """A finished run's engine-side error, as its exception."""
         if pending.draining:
             raise RequestDraining(pending.error)
         if pending.cancelled:
@@ -904,6 +1186,13 @@ class ServingFrontEnd:
             "engine_backlog": stats["engine_backlog"],
             "draining": 1.0 if stats["draining"] else 0.0,
             "drain_rejections_total": stats["drain_rejections"],
+            "stream_handovers_total": stats["stream_handovers"],
+            "stream_tokens_written_total":
+                stats["stream_tokens_written"],
+            "stream_sends_deferred_total":
+                stats["stream_sends_deferred"],
+            "streams_dropped_backlog_total":
+                stats["streams_dropped_backlog"],
         })
         engine = stats["engine"]
         lines.extend(prometheus_lines("shipyard_serving", {
@@ -1060,6 +1349,15 @@ class ServingFrontEnd:
             # failure.
             "draining": self._draining.is_set(),
             "drain_rejections": self.drain_rejections,
+            # The stream writer: token batches it took (one a landed
+            # step, one a first token), token lines it wrote, sends a
+            # client's window did not take whole, streams dropped for
+            # what they owed. tokens / handovers = streams fed a
+            # wake-up.
+            "stream_handovers": self._streams.handovers,
+            "stream_tokens_written": self._streams.tokens_written,
+            "stream_sends_deferred": self._streams.sends_deferred,
+            "streams_dropped_backlog": self._streams.dropped_backlog,
         }
         # Speculative-decode counters when the engine runs a draft
         # model (the measured acceptance rate is the tuning signal
@@ -1146,26 +1444,40 @@ class ServingFrontEnd:
         pending.error = f"request {request_id} shed: {reason}"
         pending.shed = True
         pending.finished_at = time.perf_counter()
-        if pending.token_queue is not None:
-            pending.token_queue.put(None)
-        pending.event.set()
+        pending.finish()
 
     def _on_token(self, request_id: str, token: int, index: int) -> None:
-        # _active_runs is engine-thread-owned and this hook runs on
-        # the engine thread (inside engine.step) — no lock needed,
-        # and completions can never be attributed to a retried
-        # request's NEW pending while the old run still decodes.
-        pending = self._active_runs.get(request_id)
-        if pending is None:
-            return
-        if pending.first_token_at is None:
-            # First token THIS replica produced — for a resumed run
-            # that is the re-prefill completion (index > 0), still
-            # the TTFT that matters here.
-            pending.first_token_at = time.perf_counter()
-        pending.emitted = max(pending.emitted, index + 1)
-        if pending.token_queue is not None:
-            pending.token_queue.put((index, token))
+        self._on_tokens([(request_id, token, index)])
+
+    def _on_tokens(self, batch: list) -> None:
+        # One call a landed step (or a prefill's first token) with
+        # its (request_id, token, index) triples. _active_runs is
+        # engine-thread-owned and this hook runs on the engine thread
+        # (inside engine.step) — no lock needed, and completions can
+        # never be attributed to a retried request's NEW pending
+        # while the old run still decodes.
+        runs = self._active_runs
+        lines = []
+        now = None
+        for request_id, token, index in batch:
+            pending = runs.get(request_id)
+            if pending is None:
+                continue
+            if pending.first_token_at is None:
+                # First token THIS replica produced — for a resumed
+                # run that is the re-prefill completion (index > 0),
+                # still the TTFT that matters here.
+                if now is None:
+                    now = time.perf_counter()
+                pending.first_token_at = now
+            if index >= pending.emitted:
+                pending.emitted = index + 1
+            stream = pending.stream
+            if stream is not None and stream.dropped is None:
+                lines.append((stream, token, index))
+        if lines:
+            # ONE wake-up for all the step's streams.
+            self._streams.write(lines)
 
     def _engine_loop(self) -> None:
         while not self._stop.is_set():
@@ -1221,9 +1533,7 @@ class ServingFrontEnd:
                     continue
                 pending.tokens = tokens
                 pending.finished_at = now
-                if pending.token_queue is not None:
-                    pending.token_queue.put(None)  # end of stream
-                pending.event.set()
+                pending.finish()
 
     def _drain_tick(self) -> None:
         # Engine-thread side of the drain ladder: evict the queue
@@ -1250,9 +1560,7 @@ class ServingFrontEnd:
         pending.error = f"request {request_id} draining: {why}"
         pending.draining = True
         pending.finished_at = time.perf_counter()
-        if pending.token_queue is not None:
-            pending.token_queue.put(None)
-        pending.event.set()
+        pending.finish()
 
     def _cancel(self, request_id: str,
                 draining: bool = False) -> None:
@@ -1272,9 +1580,7 @@ class ServingFrontEnd:
             pending.error = f"request {request_id} cancelled"
             pending.cancelled = True
         pending.finished_at = time.perf_counter()
-        if pending.token_queue is not None:
-            pending.token_queue.put(None)
-        pending.event.set()
+        pending.finish()
 
     def _submit(self, pending: _Pending) -> None:
         if self._draining.is_set() or self.engine.draining:
@@ -1286,9 +1592,7 @@ class ServingFrontEnd:
                              f"admitted, replica shutting down")
             pending.draining = True
             pending.finished_at = time.perf_counter()
-            if pending.token_queue is not None:
-                pending.token_queue.put(None)
-            pending.event.set()
+            pending.finish()
             return
         try:
             self.engine.submit(pending.request,
@@ -1296,9 +1600,7 @@ class ServingFrontEnd:
         except ValueError as exc:
             pending.error = str(exc)
             pending.finished_at = time.perf_counter()
-            if pending.token_queue is not None:
-                pending.token_queue.put(None)
-            pending.event.set()
+            pending.finish()
             return
         request_id = pending.request.request_id
         self._active_runs[request_id] = pending

@@ -643,6 +643,12 @@ class ContinuousBatcher:
         # token) — the TTFT/TPOT measurement point for serving front
         # ends. Runs on the engine's stepping thread.
         self.on_token = on_token
+        # The same observer, handed a landed step's tokens at once:
+        # ONE call a step with [(request_id, token, index), ...]
+        # (a prefill's first token: a batch of one). Where it is
+        # set, on_token is not called (_emit).
+        self.on_tokens: Optional[
+            Callable[[list[tuple[str, int, int]]], None]] = None
         # Observer called as (request_id,) the moment a queued
         # request wins a slot, just before its prefill runs — the
         # queued->prefill boundary of the request's trace span chain
@@ -1303,38 +1309,51 @@ class ContinuousBatcher:
                                    self._landed_at))
         self._landed_at = time.monotonic()
         with phases("emit"):
+            tokens = next_host.tolist()
+            batch = []
             for i, req in step.seated:
                 slot = self._slots[i]
                 if slot.request is not req:
                     self.overshoot_tokens += 1
                     continue
                 slot.in_flight -= 1
-                token = int(next_host[i])
+                token = tokens[i]
                 if chosen is not None:
                     # the choices at the position the step FED: that
                     # of the token before this one
                     self._decisions[req.request_id]["steps"].append(
                         (chosen, i))
                 slot.generated.append(token)
-                self._step_tokens += 1
-                if self.on_token is not None:
-                    self.on_token(req.request_id, token,
-                                  len(slot.generated) - 1)
+                batch.append((req.request_id, token,
+                              len(slot.generated) - 1))
                 done = (len(slot.generated) >= req.max_new_tokens or
                         (req.eos_id is not None and
                          token == req.eos_id))
                 if done:
                     self._finish(i)
+            self._emit(batch)
             if chosen is not None:
                 self._count_experts(
                     chosen[:, [i for i, _ in step.seated]])
             # The step's device arrays die here, inside the phase:
-            # their destructor releases the GIL, and that is when the
-            # stream-writer threads on_token just woke take their
-            # turn (a millisecond or two with a dozen streams). It is
-            # emit's cost, so it is counted here and not after every
-            # phase has ended.
+            # their destructor releases the GIL, and whoever the
+            # hand-over woke may take its turn then. It is emit's
+            # cost, so it is counted here and not after every phase
+            # has ended.
             step.tokens = step.key = step.chosen = None
+
+    def _emit(self, batch: list[tuple[str, int, int]]) -> None:
+        """Hand the (request_id, token, index) triples a step or a
+        prefill produced to the observer: on_tokens ONCE where it is
+        set, else on_token a triple."""
+        self._step_tokens += len(batch)
+        if not batch:
+            return
+        if self.on_tokens is not None:
+            self.on_tokens(batch)
+        elif self.on_token is not None:
+            for triple in batch:
+                self.on_token(*triple)
 
     def _step_speculative(self) -> list[tuple[str, list[int]]]:
         """One ragged draft/verify/commit round (see the spec_step
@@ -1358,6 +1377,7 @@ class ContinuousBatcher:
             self._record_step_time(t0)
             a_host = np.asarray(a_slot)
         emitted: list[tuple[str, list[int]]] = []
+        batch = []
         n_active = 0
         with phases("emit"):
             for i, slot in enumerate(self._slots):
@@ -1370,10 +1390,8 @@ class ContinuousBatcher:
                 for j in range(accepted + 1):
                     token = int(block_host[i, j])
                     slot.generated.append(token)
-                    self._step_tokens += 1
-                    if self.on_token is not None:
-                        self.on_token(req.request_id, token,
-                                      len(slot.generated) - 1)
+                    batch.append((req.request_id, token,
+                                  len(slot.generated) - 1))
                     if (len(slot.generated) >= req.max_new_tokens or
                             (req.eos_id is not None and
                              token == req.eos_id)):
@@ -1384,7 +1402,8 @@ class ContinuousBatcher:
                                         list(slot.generated)))
                         self._free_slot(i)
                         break
-            del block, a_slot       # as in _step: the writers' turn
+            self._emit(batch)
+            del block, a_slot       # as in _land: the woken's turn
         self.spec_rounds += 1
         self.spec_proposed += self.gamma * n_active
         return emitted
@@ -1906,10 +1925,8 @@ class ContinuousBatcher:
                 self._slots[i] = _Slot(
                     request=req,
                     generated=entry.resumed + [first_token])
-                self._step_tokens += 1
-                if self.on_token is not None:
-                    self.on_token(req.request_id, first_token,
-                                  len(entry.resumed))
+                self._emit([(req.request_id, first_token,
+                             len(entry.resumed))])
                 self._tokens = self._tokens.at[i, 0].set(first[0])
                 self._positions = self._positions.at[i].set(
                     len(tokens))

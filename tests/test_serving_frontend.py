@@ -545,3 +545,404 @@ def test_a_request_is_in_one_step_row_and_in_its_prefill_span(
         assert step["start"] <= span["start"] + 0.05
         assert span["end"] <= step["end"] + 0.05
     assert len(steps) == engine.steps_total == engine.traced_steps
+
+
+# ------- one writer for all token streams (ISSUE 32): the wire, the ------
+# ------- order, a client that stops reading, the counters ---------------
+
+import socket      # noqa: E402
+import sys         # noqa: E402
+import threading   # noqa: E402
+
+from batch_shipyard_tpu.models import server as server_mod  # noqa: E402
+
+
+def _open_stream(address, payload, rcvbuf=None):
+    """POST a streaming generate on a raw socket; the reply is left
+    unread."""
+    sock = socket.socket()
+    if rcvbuf is not None:      # before connect: it sets the window
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    sock.settimeout(120)
+    sock.connect(tuple(address))
+    body = json.dumps(dict(payload, stream=True)).encode()
+    sock.sendall(b"POST /v1/generate HTTP/1.1\r\nHost: test\r\n"
+                 b"Content-Type: application/json\r\n"
+                 b"Content-Length: %d\r\n\r\n" % len(body) + body)
+    return sock
+
+
+def _read_reply(sock):
+    """-> (head, raw chunked body) of one streamed reply."""
+    data = b""
+    while b"\r\n\r\n" not in data:
+        data += sock.recv(65536)
+    head, _, body = data.partition(b"\r\n\r\n")
+    while not body.endswith(b"0\r\n\r\n"):
+        more = sock.recv(65536)
+        if not more:
+            break
+        body += more
+    return head, body
+
+
+def _dechunk(body):
+    """The chunks of a chunked body, each as its raw bytes."""
+    lines, at = [], 0
+    while True:
+        eol = body.index(b"\r\n", at)
+        size = int(body[at:eol], 16)
+        at = eol + 2
+        if size == 0:
+            assert body[at:] == b"\r\n"
+            return lines
+        lines.append(body[at:at + size])
+        assert body[at + size:at + size + 2] == b"\r\n"
+        at += size + 2
+
+
+def _golden(objs):
+    """The body the per-stream loop wrote before there was a writer:
+    json.dumps of each line's object in _chunk's framing, then the
+    terminating chunk."""
+    out = b""
+    for obj in objs:
+        line = json.dumps(obj).encode() + b"\n"
+        out += f"{len(line):x}\r\n".encode() + line + b"\r\n"
+    return out + b"0\r\n\r\n"
+
+
+def _stream(address, payload):
+    """One streamed reply -> (head, raw body, its lines' objects)."""
+    sock = _open_stream(address, payload)
+    try:
+        head, body = _read_reply(sock)
+    finally:
+        sock.close()
+    return head, body, [json.loads(line) for line in _dechunk(body)]
+
+
+def _throttle(engine, seconds):
+    step = engine.step
+
+    def slow_step():
+        time.sleep(seconds)
+        return step()
+
+    engine.step = slow_step
+
+
+def _until(condition, what, seconds=60.0):
+    deadline = time.monotonic() + seconds
+    while not condition():
+        assert time.monotonic() < deadline, f"timed out: {what}"
+        time.sleep(0.01)
+
+
+FINAL_KEYS = ["request_id", "tokens", "num_tokens", "ttft_ms", "tpot_ms",
+              "latency_ms", "slo_class"]
+
+
+@pytest.mark.parametrize("kind", ["token", "final", "error", "draining",
+                                  "shed"])
+def test_a_streams_bytes_are_json_dumps_in_chunk_framing(kind, params):
+    """(a) Byte for byte the wire of the per-stream loop: every line
+    is json.dumps of its object + newline in chunked framing, then
+    0 CRLF CRLF; per kind of line, the run that brings it."""
+    engine = serving.ContinuousBatcher(
+        CFG, params, num_slots=1 if kind == "shed" else 2,
+        max_decode_len=64,
+        slo_shed_grace_ms=0.0 if kind == "shed" else None)
+    fe = ServingFrontEnd(engine, port=0).start()
+    hog = None
+    try:
+        payload = {"prompt": [5, 17, 31, 2], "max_new_tokens": 6,
+                   "request_id": "wire"}
+        if kind == "error":
+            payload["max_new_tokens"] = 100000
+        elif kind == "draining":
+            _post(fe.url, {"prompt": [1], "max_new_tokens": 2})
+            _throttle(engine, 0.02)
+            payload["max_new_tokens"] = 50
+            threading.Thread(target=lambda: (
+                _until(lambda: (fe.request_status("wire") or {}).get(
+                    "emitted_tokens", 0) >= 3, "three tokens"),
+                fe.drain(grace_s=0.0, reason="test")),
+                daemon=True).start()
+        elif kind == "shed":
+            hog = threading.Thread(target=_post, args=(fe.url, {
+                "request_id": "hog", "prompt": [7, 7],
+                "max_new_tokens": 48}), daemon=True)
+            hog.start()
+            _until(lambda: fe.knows("hog"), "the hog is seated")
+            payload["ttft_target_ms"] = 0.01
+        head, body, objs = _stream(fe.address, payload)
+        assert head.startswith(b"HTTP/1.1 200")
+        assert b"Transfer-Encoding: chunked" in head
+        assert b"Content-Type: application/x-ndjson" in head
+        assert body == _golden(objs)
+        last = objs[-1]
+        if kind in ("token", "final"):
+            assert list(last) == FINAL_KEYS and last["num_tokens"] == 6
+            assert objs[:-1] == [{"token": token, "index": i} for i, token
+                                 in enumerate(last["tokens"])]
+            assert all(list(obj) == ["token", "index"]
+                       for obj in objs[:-1])
+        elif kind == "error":
+            assert list(last) == ["error"] and len(objs) == 1
+            assert "max_decode_len" in last["error"]
+        elif kind == "draining":
+            assert list(last) == ["error", "draining"]
+            assert last["draining"] is True and "draining" in last["error"]
+            # the tokens served before the notice, in order, first
+            assert 3 <= len(objs) - 1 < 50
+            assert [obj["index"] for obj in objs[:-1]] == list(
+                range(len(objs) - 1))
+        else:
+            assert list(last) == ["error", "shed"] and len(objs) == 1
+            assert last["shed"] is True and "shed" in last["error"]
+        if hog is not None:
+            hog.join(120)
+    finally:
+        fe.shutdown()
+
+
+@pytest.mark.parametrize("n", [1, 8, 32])
+def test_concurrent_streams_get_every_line_once_in_order(n, params):
+    """(b) N streams at once: each gets every index once, in order,
+    the prefill's token first and the final line last; the writer took
+    at most one hand-over a landed step and one an admission, and
+    wrote as many token lines as tokens were served."""
+    engine = serving.ContinuousBatcher(CFG, params, num_slots=4,
+                                       max_decode_len=64)
+    fe = ServingFrontEnd(engine, port=0).start()
+    got: dict = {}
+
+    def client(k):
+        got[k] = _stream(fe.address, {
+            "request_id": f"s{k}", "prompt": [1 + k % 90] * (2 + k % 5),
+            "max_new_tokens": 3 + k % 9})[2]
+
+    interval = sys.getswitchinterval()
+    try:
+        # engine, writer, handlers and clients trade the GIL two
+        # hundred times as often: a lost hand-over or a line out of
+        # order would show
+        sys.setswitchinterval(interval / 200)
+        threads = [threading.Thread(target=client, args=(k,),
+                                    daemon=True) for k in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+        stats = fe.stats()
+    finally:
+        sys.setswitchinterval(interval)
+        fe.shutdown()
+    served = 0
+    for k in range(n):
+        *tokens, final = got[k]
+        assert [obj["index"] for obj in tokens] == list(
+            range(3 + k % 9)), k
+        assert final["request_id"] == f"s{k}"
+        assert final["tokens"] == [obj["token"] for obj in tokens]
+        served += len(tokens)
+    assert stats["stream_tokens_written"] == served
+    assert stats["generated_tokens"] == served
+    assert 0 < stats["stream_handovers"] <= (
+        stats["engine"]["decode_steps"] + n)
+    assert stats["streams_dropped_backlog"] == 0
+    if n > 4:   # four slots, all streaming: a step feeds several
+        assert stats["stream_handovers"] < served
+
+
+@pytest.mark.parametrize("how", ["bound", "io_timeout"])
+def test_a_client_that_never_reads_is_dropped_and_stalls_nobody(
+        how, params, monkeypatch):
+    """(c) A client with a tiny receive buffer that never reads: its
+    sends are deferred, the other streams and the engine go on as
+    usual, and once it owes more than the bound (or its socket has
+    taken nothing for io_timeout_s) the stream is dropped and its
+    registration retired, as a vanished client's is; the engine
+    finishes the run."""
+    if how == "bound":
+        monkeypatch.setattr(server_mod._StreamWriter, "BACKLOG_LIMIT",
+                            2048)
+    engine = serving.ContinuousBatcher(CFG, params, num_slots=3,
+                                       max_decode_len=1024)
+    fe = ServingFrontEnd(engine, port=0,
+                         io_timeout_s=0.5 if how == "io_timeout"
+                         else None)
+    # accepted connections inherit it: what the kernel holds for a
+    # client that does not read stays small
+    fe._httpd.socket.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF,
+                                4096)
+    fe.start()
+
+    def two_streams():
+        t0 = time.monotonic()
+        out: dict = {}
+        threads = [threading.Thread(
+            target=lambda k=k: out.update({k: _stream(fe.address, {
+                "prompt": [3 + k, 9], "max_new_tokens": 24})[2]}),
+            daemon=True) for k in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert sorted(out) == [0, 1]
+        for objs in out.values():
+            assert [obj["index"] for obj in objs[:-1]] == list(range(24))
+            assert objs[-1]["num_tokens"] == 24
+        return time.monotonic() - t0
+
+    stuck = None
+    try:
+        two_streams()               # compiles
+        usual = two_streams()
+        stuck = _open_stream(fe.address, {
+            "request_id": "stuck", "prompt": [2, 4, 6],
+            "max_new_tokens": 900}, rcvbuf=1)
+        _until(lambda: (fe.request_status("stuck") or {}).get(
+            "emitted_tokens", 0) >= 1, "the stuck run decodes")
+        beside = two_streams()
+        assert beside < 10 * usual + 5
+        _until(lambda: fe.stats()["streams_dropped_backlog"] == 1,
+               "the stream is dropped")
+        stats = fe.stats()
+        assert stats["stream_sends_deferred"] > 0
+        # the registration is retired at once; the engine finishes
+        _until(lambda: "stuck" not in fe._inflight, "retired")
+        _until(lambda: not fe.knows("stuck"), "the run ends", 120)
+        assert stats["stream_tokens_written"] < 2 * 2 * 24 + 900 + 24
+        assert not fe._active_runs and not fe._inflight
+        assert two_streams() < 10 * usual + 5
+    finally:
+        if stuck is not None:
+            stuck.close()
+        fe.shutdown()
+
+
+def test_a_client_that_closes_mid_stream_leaks_nothing(params):
+    """(d) The client goes away between two token lines: the writer
+    drops the stream on its next send, the handler retires the
+    registration, the engine finishes the run on its own."""
+    engine = serving.ContinuousBatcher(CFG, params, num_slots=2,
+                                       max_decode_len=64)
+    fe = ServingFrontEnd(engine, port=0).start()
+    try:
+        _post(fe.url, {"prompt": [1], "max_new_tokens": 2})
+        _throttle(engine, 0.01)
+        sock = _open_stream(fe.address, {
+            "request_id": "gone", "prompt": [4, 4],
+            "max_new_tokens": 50})
+        data = b""
+        while b'"index": 1}' not in data:
+            data += sock.recv(65536)
+        sock.close()
+        _until(lambda: "gone" not in fe._inflight, "retired")
+        _until(lambda: not fe.knows("gone"), "the run ends")
+        assert not fe._active_runs and not fe._engine_active
+        assert not fe._inflight
+        stats = fe.stats()
+        assert stats["streams_dropped_backlog"] == 0
+        assert 2 <= stats["stream_tokens_written"] < 2 + 50
+        # the id is free again and the server serves
+        out = _stream(fe.address, {"request_id": "gone",
+                                   "prompt": [4, 4],
+                                   "max_new_tokens": 5})[2]
+        assert out[-1]["num_tokens"] == 5
+    finally:
+        fe.shutdown()
+
+
+class OnTokenOnlyEngine:
+    """An engine that only knows on_token, called a token (as the
+    stand-in under tests/benchmark/drivers/ does): request k's i-th
+    token is 10 * k + i, one a step."""
+
+    draining = False
+
+    def __init__(self):
+        self.on_token = self.on_admit = self.on_shed = None
+        self.traced_steps = 0
+        self._runs: dict = {}
+
+    def submit(self, request, resumed=None):
+        self._runs[request.request_id] = (request, [])
+
+    def pending(self):
+        return len(self._runs)
+
+    def cancel(self, request_id):
+        return self._runs.pop(request_id, None) is not None
+
+    def step(self):
+        finished = []
+        for request_id, (request, out) in list(self._runs.items()):
+            out.append(10 * request.prompt[0] + len(out))
+            self.on_token(request_id, out[-1], len(out) - 1)
+            if len(out) == request.max_new_tokens:
+                del self._runs[request_id]
+                finished.append((request_id, out))
+        return finished
+
+
+def test_an_engine_that_only_calls_on_token_streams_through_the_writer():
+    """(e) on_token a token, never on_tokens: the same writer, a
+    batch of one a call."""
+    fe = ServingFrontEnd(OnTokenOnlyEngine(), port=0).start()
+    got: dict = {}
+    try:
+        threads = [threading.Thread(
+            target=lambda k=k: got.update({k: _stream(fe.address, {
+                "prompt": [k], "max_new_tokens": 4 + k})}),
+            daemon=True) for k in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        writer = fe._streams
+        assert writer.handovers == writer.tokens_written == 4 + 5 + 6
+        assert writer.sends_deferred == writer.dropped_backlog == 0
+    finally:
+        fe.shutdown()
+    for k in range(3):
+        _head, body, objs = got[k]
+        assert body == _golden(objs)
+        assert objs[:-1] == [{"token": 10 * k + i, "index": i}
+                             for i in range(4 + k)]
+        assert objs[-1]["tokens"] == [10 * k + i for i in range(4 + k)]
+
+
+def test_tokens_landed_before_the_handler_registered_are_flushed(
+        front):
+    """(f) The run is over before the connection is handed to the
+    writer: every line waited for it, and leaves in order at
+    registration."""
+    pending = front.submit_stream({
+        "prompt": [5, 17, 31, 2], "max_new_tokens": 7,
+        "request_id": "early"})
+    assert pending.event.wait(60)
+    _until(lambda: front._streams.tokens_written == 7, "lines kept")
+    assert pending.stream.sock is None and pending.stream.unsent
+    ours, theirs = socket.socketpair()
+    try:
+        assert front.serve_stream(pending, ours) is True
+        assert ours.gettimeout() is None        # blocking again
+        ours.close()
+        body = b""
+        while chunk := theirs.recv(65536):
+            body += chunk
+    finally:
+        theirs.close()
+    objs = [json.loads(line) for line in _dechunk(body)]
+    assert body == _golden(objs)
+    assert [obj["index"] for obj in objs[:-1]] == list(range(7))
+    assert objs[-1]["tokens"] == [obj["token"] for obj in objs[:-1]]
+    assert "early" not in front._inflight
+    # a non-streaming request never touches the writer
+    before = front._streams.handovers
+    _post(front.url, {"prompt": [5, 17], "max_new_tokens": 4})
+    assert front._streams.handovers == before == 1 + 6

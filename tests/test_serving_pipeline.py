@@ -43,14 +43,22 @@ def _config(kind):
 
 
 class Tokens:
-    """An on_token observer: (token, index) per request in arrival
-    order."""
+    """An observer of the engine's tokens: (token, index) per request
+    in arrival order, whichever hook brought them: on_token (called
+    a token) or on_tokens (called once with all a landed step or a
+    prefill produced; ``batches`` keeps each call's size)."""
 
     def __init__(self):
         self.seen: dict = {}
+        self.batches: list = []
 
     def __call__(self, request_id, token, index):
         self.seen.setdefault(request_id, []).append((token, index))
+
+    def batch(self, triples):
+        self.batches.append(len(triples))
+        for triple in triples:
+            self(*triple)
 
     def tokens(self, request_id):
         return [token for token, _ in self.seen.get(request_id, [])]
@@ -61,11 +69,25 @@ class Tokens:
         return indices == list(range(first, first + len(indices)))
 
 
-def _engine(kind, params, num_slots=3, **kwargs):
+HOOKS = ("on_token", "on_tokens")
+
+
+@pytest.fixture(params=HOOKS)
+def hook(request):
+    """Which of the engine's two token hooks the observer hangs on:
+    on_tokens where it is set, on_token (a call a token) as the
+    fallback for whoever sets only that."""
+    return request.param
+
+
+def _engine(kind, params, num_slots=3, hook="on_token", **kwargs):
     observer = Tokens()
     engine = serving.ContinuousBatcher(
         _config(kind), params, num_slots=num_slots, max_decode_len=64,
-        on_token=observer, **KINDS[kind][1], **kwargs)
+        on_token=observer if hook == "on_token" else None,
+        **KINDS[kind][1], **kwargs)
+    if hook == "on_tokens":
+        engine.on_tokens = observer.batch
     return engine, observer
 
 
@@ -123,8 +145,8 @@ SIZES = [(5, 9), (17, 1), (3, 14), (9, 2), (20, 7), (6, 12), (11, 3),
 
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_a_fixed_schedule_serves_the_unbatched_greedy_tokens(
-        kind, params):
-    engine, observer = _engine(kind, params)
+        kind, params, hook):
+    engine, observer = _engine(kind, params, hook=hook)
     requests = _requests(2, SIZES)
     waiting = list(requests)
     done: dict = {}
@@ -146,6 +168,11 @@ def test_a_fixed_schedule_serves_the_unbatched_greedy_tokens(
     assert stats["overshoot_tokens"] == 0
     assert 0 < stats["steps_overlapped"] < stats["decode_steps"]
     assert engine.occupancy()["slots_active"] == 0
+    if hook == "on_tokens":
+        # ONE hand-over a landed step and one a prefill's first token
+        assert len(observer.batches) == (stats["decode_steps"]
+                                         + len(requests))
+        assert max(observer.batches) == 3 and min(observer.batches) == 1
 
 
 # ------------------------------ (b) an eos finish ------------------------
@@ -165,11 +192,11 @@ def _eos_case(kind, params, seed):
 
 @pytest.mark.parametrize("kind", ["paged", "dense"])
 def test_an_eos_finish_discards_the_overshoot_and_frees_the_slot(
-        kind, params):
+        kind, params, hook):
     """The step in flight when the eos is read has decoded the
     request once more: that token reaches nobody, the counter has it,
     and the next tenant of the slot is served as if alone."""
-    engine, observer = _engine(kind, params, num_slots=2)
+    engine, observer = _engine(kind, params, num_slots=2, hook=hook)
     ender, want = _eos_case(kind, params, seed=4)
     beside, tenant = _requests(6, [(7, 20), (10, 6)], name="n")
     engine.submit(ender)
@@ -186,8 +213,8 @@ def test_an_eos_finish_discards_the_overshoot_and_frees_the_slot(
 
 
 def test_an_eos_that_ends_the_last_request_leaves_nothing_in_flight(
-        params):
-    engine, observer = _engine("paged", params, num_slots=2)
+        params, hook):
+    engine, observer = _engine("paged", params, num_slots=2, hook=hook)
     ender, want = _eos_case("paged", params, seed=8)
     engine.submit(ender)
     done = _drain(engine)
@@ -200,12 +227,17 @@ def test_an_eos_that_ends_the_last_request_leaves_nothing_in_flight(
     assert stats["decode_steps"] == len(want)
     assert engine._in_flight is None and not engine.pending()
     assert engine.occupancy()["kv_pages_in_use"] == 0
+    # the overshoot step lands no token: no hand-over for it
+    assert observer.tokens("eos0") == want
+    assert observer.batches == ([1] * len(want)
+                                if hook == "on_tokens" else [])
 
 
 # --------- (c) cancel, drain and preemption with a step in flight --------
 
-def test_a_cancel_settles_first_and_a_resume_loses_no_token(params):
-    engine, observer = _engine("paged", params, num_slots=2)
+def test_a_cancel_settles_first_and_a_resume_loses_no_token(
+        params, hook):
+    engine, observer = _engine("paged", params, num_slots=2, hook=hook)
     victim, beside = _requests(3, [(6, 14), (9, 5)], name="c")
     engine.submit(victim)
     engine.submit(beside)
@@ -236,8 +268,9 @@ def test_a_cancel_settles_first_and_a_resume_loses_no_token(params):
     assert [i for _, i in observer.seen["c0"]] == list(range(14))
 
 
-def test_a_drain_settles_first_and_the_active_requests_finish(params):
-    engine, observer = _engine("dense", params, num_slots=2)
+def test_a_drain_settles_first_and_the_active_requests_finish(
+        params, hook):
+    engine, observer = _engine("dense", params, num_slots=2, hook=hook)
     requests = _requests(5, [(5, 8), (8, 6), (4, 4), (7, 3)], name="d")
     for req in requests:
         engine.submit(req)
@@ -262,19 +295,24 @@ def test_a_drain_settles_first_and_the_active_requests_finish(params):
     assert engine.drain() == []         # idempotent
 
 
-def test_a_preemption_with_a_step_in_flight_loses_and_doubles_nothing(
-        params):
-    """Overcommit on a pool too small for its slots: a dry pool lands
-    the step in flight before it evicts anybody, so the victim goes
-    back to the queue with every token it was served, and resumes
-    after them."""
+def _preempted_run(params, hook):
     engine, observer = _engine("paged", params, num_slots=3,
-                               kv_num_pages=7, overcommit=True)
+                               kv_num_pages=7, overcommit=True,
+                               hook=hook)
     requests = _requests(9, [(14, 18), (9, 20), (12, 16), (6, 10),
                              (15, 12)], name="p")
     for req in requests:
         engine.submit(req)
-    done = _drain(engine)
+    return engine, observer, requests, _drain(engine)
+
+
+def test_a_preemption_with_a_step_in_flight_loses_and_doubles_nothing(
+        params, hook):
+    """Overcommit on a pool too small for its slots: a dry pool lands
+    the step in flight before it evicts anybody, so the victim goes
+    back to the queue with every token it was served, and resumes
+    after them."""
+    engine, observer, requests, done = _preempted_run(params, hook)
     stats = engine.step_stats()
     assert engine.preemptions >= 2
     assert stats["settles"]["preempt"] >= engine.preemptions
@@ -283,9 +321,23 @@ def test_a_preemption_with_a_step_in_flight_loses_and_doubles_nothing(
         want = _reference("paged", params, req.prompt,
                           req.max_new_tokens)
         assert done[req.request_id] == want, req.request_id
-        # on_token saw each index once, in order, across the re-queue
+        # the observer saw each index once, in order, across the
+        # re-queue
         assert observer.tokens(req.request_id) == want
         assert observer.in_order_from(req.request_id)
+
+
+def test_both_hooks_see_the_same_sequence_across_a_preemption(params):
+    """The same schedule once through on_token and once through
+    on_tokens: the same (token, index) sequence a request, the
+    re-queued ones among them."""
+    runs = {hook: _preempted_run(params, hook) for hook in HOOKS}
+    (one, by_one, _, _), (batched, by_batch, _, _) = (
+        runs["on_token"], runs["on_tokens"])
+    assert one.preemptions == batched.preemptions >= 2
+    assert by_one.seen == by_batch.seen and len(by_one.seen) == 5
+    assert by_one.batches == [] and sum(by_batch.batches) == sum(
+        len(seen) for seen in by_batch.seen.values())
 
 
 # -------- (d) nothing before the readback reads from the device ---------
