@@ -24,7 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from batch_shipyard_tpu.models import ssm
+from batch_shipyard_tpu.models import delta, ssm
 from batch_shipyard_tpu.models import transformer as tfm
 
 
@@ -141,8 +141,9 @@ def _park_idle_cursors(cache, active):
 
 
 # Cache leaves that are a layer's fixed-size state per slot: a slot
-# row, no cursor, no pages.
-SLOT_STATE_LEAVES = ssm.STATE_LEAVES
+# row, no cursor, no pages. Every stateful mixer declares its own
+# (transformer.STATEFUL_KINDS).
+SLOT_STATE_LEAVES = ssm.STATE_LEAVES + delta.STATE_LEAVES
 
 
 def slot_state_bytes(cache) -> int:

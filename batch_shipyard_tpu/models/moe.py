@@ -444,6 +444,9 @@ class RoutedConfig:
     scale: float = 1.0           # on the normalised weights
     experts_held: Optional[int] = None   # None = all of them
     first_expert: int = 0
+    # Expert(x) and Shared(x): False down(relu(up x)^2); True the gated
+    # down(silu(gate x) * up x), a third matrix an expert.
+    gated: bool = False
 
     @property
     def held(self) -> int:
@@ -463,11 +466,22 @@ def route_sigmoid(scores_in, bias, top_k: int, scale: float):
     return chosen.astype(jnp.int32), weights
 
 
-def dense_experts(rows, chosen, weights, up, down, first: int):
+def _expert_act(up, gate=None):
+    """An expert's hidden activation (float32) from its up
+    projection, and its gate projection where it has one."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
+def dense_experts(rows, chosen, weights, up, down, first: int,
+                  gate=None):
     """sum_i w_i Expert_i(row) over the chosen experts that are held,
-    as two matmuls over ALL the held experts: rows [M, d] against up
-    [E, d, f] gives every expert's hidden [M, E, f]; each is squared-
-    relu'd and multiplied by the row's weight for that expert (0 where
+    as two matmuls over ALL the held experts (three for gated ones):
+    rows [M, d] against up [E, d, f] gives every expert's hidden
+    [M, E, f]; each is squared-relu'd (with ``gate`` [E, d, f]:
+    multiplied by silu of the rows against it) and multiplied by the
+    row's weight for that expert (0 where
     the row did not choose it); then ONE contraction over (E, f)
     against down [E, f, d] sums the experts' outputs in the matmul's
     own accumulator. Nothing is sorted, gathered or dropped, and a
@@ -485,18 +499,23 @@ def dense_experts(rows, chosen, weights, up, down, first: int):
     again when rows far outnumber experts AND a fast grouped matmul
     exists. rows [M, d]; chosen / weights [M, k]; up [E, d, f], down
     [E, f, d] the held experts first .. first+E-1; Expert(x) =
-    down(relu(up x)^2). -> float32 [M, d]."""
+    down(relu(up x)^2), or down(silu(gate x) * up x) with ``gate``.
+    -> float32 [M, d]."""
     held = up.shape[0]
     local = chosen - first
     # [M, E]: the row's weight for each held expert
     weigh = jnp.sum(jnp.where(
         local[:, :, None] == jnp.arange(held), weights[:, :, None], 0.0),
         axis=1)
-    hidden = jax.lax.dot_general(
-        rows, up, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # [M, E, f]
-    hidden = (jnp.square(jax.nn.relu(hidden))
-              * weigh[:, :, None]).astype(rows.dtype)
+
+    def every(stack):
+        return jax.lax.dot_general(
+            rows, stack, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [M, E, f]
+
+    hidden = _expert_act(every(up),
+                         None if gate is None else every(gate))
+    hidden = (hidden * weigh[:, :, None]).astype(rows.dtype)
     return jax.lax.dot_general(
         hidden, down, (((1, 2), (0, 1)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -504,7 +523,8 @@ def dense_experts(rows, chosen, weights, up, down, first: int):
 
 class RoutedExperts(nn.Module):
     """x -> sum_i w_i Expert_i(x) [held experts] + Shared(x), every
-    expert down(relu(up x)^2) without gate or bias, with no capacity
+    expert down(relu(up x)^2), or with ``config.gated``
+    down(silu(gate x) * up x), without bias, with no capacity
     (dense_experts). The router runs in float32. The choices
     [B, T, k] (indices over all n_experts) are sown into the
     "decisions" collection, for a serving engine to hand to whoever
@@ -537,6 +557,14 @@ class RoutedExperts(nn.Module):
         shared_down = self.param("shared_down", kernel,
                                  (cfg.d_shared, d_model),
                                  self.param_dtype)
+        gate = shared_gate = None
+        if cfg.gated:
+            gate = self.param("experts_gate", stacked,
+                              (cfg.held, d_model, cfg.d_expert),
+                              self.param_dtype).astype(self.dtype)
+            shared_gate = self.param(
+                "shared_gate", kernel, (d_model, cfg.d_shared),
+                self.param_dtype).astype(self.dtype)
         rows = x.reshape(batch * length, d_model).astype(self.dtype)
         # bfloat16 operands multiply exactly into float32: the router
         # is a float32 computation on the activations as they are
@@ -548,10 +576,15 @@ class RoutedExperts(nn.Module):
                  chosen.reshape(batch, length, cfg.top_k))
         routed = dense_experts(
             rows, chosen, weights, up.astype(self.dtype),
-            down.astype(self.dtype), cfg.first_expert)
-        hidden = jnp.dot(rows, shared_up.astype(self.dtype),
-                         preferred_element_type=jnp.float32)
-        hidden = jnp.square(jax.nn.relu(hidden)).astype(self.dtype)
+            down.astype(self.dtype), cfg.first_expert, gate)
+
+        def shared_in(kernel):
+            return jnp.dot(rows, kernel.astype(self.dtype),
+                           preferred_element_type=jnp.float32)
+
+        hidden = _expert_act(
+            shared_in(shared_up), None if shared_gate is None
+            else shared_in(shared_gate)).astype(self.dtype)
         shared = jnp.dot(hidden, shared_down.astype(self.dtype),
                          preferred_element_type=jnp.float32)
         return (routed + shared).astype(self.dtype).reshape(

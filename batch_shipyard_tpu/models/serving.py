@@ -199,10 +199,10 @@ def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
     [L, vocab] fp32 logits tensor never materializes.
 
     What is NOT masked on read is a layer's running state
-    (models/ssm.py): the model is told how many of each segment's
-    tokens are the prompt's own (valid_len), and leaves the state of
-    position prompt_len-1. -> (cache, last logits, the routed layers'
-    choices [decision layers, L, k] or None)."""
+    (models/ssm.py, models/delta.py): the model is told how many of
+    each segment's tokens are the prompt's own (valid_len), and leaves
+    the state of position prompt_len-1. -> (cache, last logits, the
+    routed layers' choices [decision layers, L, k] or None)."""
     return _prefill_segments(
         model, prefill_chunk, params, inf.init_cache(model, params, 1),
         prompt, 0, prompt_len)
@@ -684,9 +684,9 @@ class ContinuousBatcher:
             raise ValueError("overcommit requires the paged KV cache "
                              "(kv_page_size)")
         # A model whose layers keep a fixed-size state per slot
-        # (models/ssm.py) beside the K/V: the state rides in the cache
-        # tree with a slot row and no cursor, is overwritten when a
-        # request is seated and belongs to no page.
+        # (models/ssm.py, models/delta.py) beside the K/V: the state
+        # rides in the cache tree with a slot row and no cursor, is
+        # overwritten when a request is seated and belongs to no page.
         self.stateful = tfm.has_slot_state(config)
         if speculative is not None and (
                 self.stateful or not config.tie_embeddings):

@@ -116,8 +116,12 @@ class TransformerConfig:
     # holds the K/V heads alone. None = n_heads.
     n_kv_heads: Optional[int] = None
     # False: attention applies no positional embedding (a stack whose
-    # state-space layers carry position).
+    # stateful layers carry position).
     use_rope: bool = True
+    # True: attention's output is gated elementwise before o_proj,
+    # o_proj(attn * sigmoid(gate_proj(x))), the gate a projection of
+    # its own to n_heads * d_head.
+    attn_output_gate: bool = False
     # RMSNorm epsilon of every block norm and of the final norm.
     norm_eps: float = 1e-6
     # False: a separate lm_head [d_model, vocab] instead of the
@@ -127,11 +131,13 @@ class TransformerConfig:
     # + SwiGLU MLP, each after a norm), "dense_moe" (its MLP replaced
     # by ``moe``'s capacity-routed experts), or a block that is ONE
     # mixer after ONE norm: "ssm" (models/ssm.py, sized by ``ssm``),
-    # "attn" (the attention above alone), "experts" (moe.RoutedExperts,
-    # sized by ``experts``). None = "dense" throughout, "dense_moe"
-    # at every moe_every-th layer when ``moe`` is set.
+    # "delta" (models/delta.py, sized by ``delta``), "attn" (the
+    # attention above alone), "experts" (moe.RoutedExperts, sized by
+    # ``experts``). None = "dense" throughout, "dense_moe" at every
+    # moe_every-th layer when ``moe`` is set.
     block_kinds: Optional[tuple] = None
     ssm: Optional[Any] = None        # models.ssm.SSMConfig
+    delta: Optional[Any] = None      # models.delta.DeltaConfig
     experts: Optional[Any] = None    # models.moe.RoutedConfig
 
     @property
@@ -139,7 +145,10 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
 
-MIXER_KINDS = ("ssm", "attn", "experts")
+MIXER_KINDS = ("ssm", "delta", "attn", "experts")
+# ... of which these keep a fixed-size state per slot in the cache
+# (the leaves their modules declare: inference.SLOT_STATE_LEAVES)
+STATEFUL_KINDS = ("ssm", "delta")
 
 
 def layer_kinds(cfg: TransformerConfig) -> tuple:
@@ -174,7 +183,7 @@ def paged_layer_count(cfg: TransformerConfig) -> int:
 def has_slot_state(cfg: TransformerConfig) -> bool:
     """Whether a slot holds a fixed-size state beside its K/V: one
     that no page names, so a prefix's pages cannot stand in for it."""
-    return "ssm" in layer_kinds(cfg)
+    return any(kind in STATEFUL_KINDS for kind in layer_kinds(cfg))
 
 
 def collect_decisions(sown, cfg: TransformerConfig):
@@ -340,8 +349,8 @@ class Attention(nn.Module):
                     f"'int8' (or None) is supported")
             attend = (self._decode_attend_paged
                       if cfg.kv_page_size else self._decode_attend)
-            return dense(cfg.d_model, "o_proj")(
-                attend(q, k, v).reshape(batch, seq, features))
+            return dense(cfg.d_model, "o_proj")(self._gated(
+                attend(q, k, v).reshape(batch, seq, features), x))
         attention_fn = cfg.attention_fn or (
             lambda q_, k_, v_, causal: attn_ops.attention(
                 q_, k_, v_, causal=causal))
@@ -352,12 +361,26 @@ class Attention(nn.Module):
             k = jnp.repeat(k, group, axis=2)
             v = jnp.repeat(v, group, axis=2)
         out = attention_fn(q, k, v, causal=True)
-        out = out.reshape(batch, seq, features)
+        out = self._gated(out.reshape(batch, seq, features), x)
         out = dense(cfg.d_model, "o_proj")(out)
         if cfg.tp_axis:
             # Row-sharded o_proj: each tp member holds a partial sum.
             out = tp_region_output(out, cfg.tp_axis)
         return out
+
+    def _gated(self, out, x):
+        """attn_output_gate: the heads' outputs [B, T, F] times
+        sigmoid(gate_proj(x)), elementwise, before o_proj."""
+        cfg = self.config
+        if not cfg.attn_output_gate:
+            return out
+        if cfg.fused_norm:
+            raise NotImplementedError(
+                "attn_output_gate reads the normed input, which "
+                "fused_norm never materializes")
+        gate = functools_partial_dense(cfg)(out.shape[-1], "gate_proj")(x)
+        return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            out.dtype)
 
     def _decode_attend(self, q, k, v):
         """Cache-writing decode attention. seq == 1 is the per-token
@@ -731,7 +754,8 @@ class MixerBlock(nn.Module):
     """A block that is ONE mixer after ONE norm: x + Mixer(RMSNorm(x)),
     the mixer of ``kind`` (MIXER_KINDS) and named by it, so that the
     tree, the cache and a device trace's operation names all say which
-    kind a layer is (layer_3/ssm/..., layer_5/attn/...)."""
+    kind a layer is (layer_3/ssm/..., layer_4/delta/...,
+    layer_5/attn/...)."""
     config: TransformerConfig
     kind: str = "attn"
 
@@ -743,6 +767,9 @@ class MixerBlock(nn.Module):
         if self.kind == "ssm":
             from batch_shipyard_tpu.models.ssm import Mamba2Mixer
             out = Mamba2Mixer(cfg, name="ssm")(normed, valid_len)
+        elif self.kind == "delta":
+            from batch_shipyard_tpu.models.delta import DeltaMixer
+            out = DeltaMixer(cfg, name="delta")(normed, valid_len)
         elif self.kind == "experts":
             from batch_shipyard_tpu.models.moe import RoutedExperts
             out = RoutedExperts(cfg.experts, dtype=cfg.dtype,
@@ -767,8 +794,8 @@ class TransformerLM(nn.Module):
         ``valid_len`` (traced int, decode-mode prefill only): how many
         leading tokens of this call are the sequence's own; the rest
         is bucket padding, which a layer that keeps a running state
-        (models/ssm.py) must not let advance it. K/V rows are masked
-        on read and need no such care."""
+        (models/ssm.py, models/delta.py) must not let advance it. K/V
+        rows are masked on read and need no such care."""
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.d_model,
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype,

@@ -1,15 +1,18 @@
-"""A stack of state-space, attention and routed-expert blocks, one
-mixer a block, through ContinuousBatcher: the paged pool with fewer
-K/V heads than query heads, a fixed-size state per slot beside it,
-experts with no dropped token and a record of their choices, all as
-one chip's share of a deployment. CPU, tiny sizes, seeded random
-weights, float32 on both sides so that a tolerance is rounding alone;
-LOGITS are compared, not tokens: every served token's logit has to lie
-within a tolerance of the plain reference's best at its position
-(benchmark/reference/hybrid_ssm_moe_plain.py, float32 "highest",
-sequential recurrence, no cache), the reference run on the engine's
-own expert choices, each of which has to be (within a tolerance) one
-the reference would have made."""
+"""A stack of stateful, attention and routed-expert blocks, one mixer
+a block, through ContinuousBatcher: the paged pool with fewer K/V
+heads than query heads, a fixed-size state per slot beside it, experts
+with no dropped token and a record of their choices, all as one chip's
+share of a deployment. Every case that is about HAVING A PER-SLOT
+STATE runs for both stateful kinds (STACKS): the state-space stack
+(``ssm``: Mamba-2, relu^2 experts) and the delta-rule stack
+(``delta``: KDA, gated attention, gated experts). CPU, tiny sizes,
+seeded random weights, float32 on both sides so that a tolerance is
+rounding alone; LOGITS are compared, not tokens: every served token's
+logit has to lie within a tolerance of the plain reference's best at
+its position (benchmark/reference/hybrid_*_plain.py, float32
+"highest", sequential recurrence, no cache), the reference run on the
+engine's own expert choices, each of which has to be (within a
+tolerance) one the reference would have made."""
 
 import dataclasses
 
@@ -19,15 +22,15 @@ import numpy as np
 import pytest
 
 from batch_shipyard_tpu.models import inference as inf
-from batch_shipyard_tpu.models import moe, serving, ssm
+from batch_shipyard_tpu.models import delta, moe, serving, ssm
 from batch_shipyard_tpu.models import transformer as tfm
 from batch_shipyard_tpu.models.serving import Request
 from benchmark import spec, weights
-from benchmark.reference import hybrid_ssm_moe_plain as plain
+from benchmark.reference import hybrid_delta_moe_plain, hybrid_ssm_moe_plain
 
-# The issue's tiny size: d 64, 4 SSM heads of 16, 2 groups, state 16,
-# 4 query / 2 KV heads, 8 experts top-2 of which 4 held, vocabulary
-# 512 of which 256 held, pattern MEM*EME.
+# The tiny sizes: d 64, 4 query / 2 KV heads of 16, 8 experts top-2 of
+# which 4 held, vocabulary 512 of which 256 held; 4 SSM heads of 16,
+# 2 groups, state 16, pattern MEM*EME ...
 FILE = dict(
     hidden_size=64, head_dim=16, num_attention_heads=4,
     num_key_value_heads=2, mamba_num_heads=4, mamba_head_dim=16,
@@ -37,6 +40,20 @@ FILE = dict(
     hybrid_override_pattern="MEM*EME", vocab_size=256,
     layer_norm_epsilon=1e-5, routed_scaling_factor=2.5,
     model_module="hybrid_ssm_moe",
+    share={"first_expert": 0, "experts_of": 8})
+# ... or one period of the delta stack: attention then three delta
+# layers of 4 heads of 16, each followed by its experts (8 blocks).
+DELTA_FILE = dict(
+    hidden_size=64, head_dim=16, num_attention_heads=4,
+    num_key_value_heads=2, linear_attn_config={
+        "short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4,
+        "num_kv_heads": None},
+    sizes_set={"kda_gate_rank": 16, "kda_chunk": 8},
+    moe_intermediate_size=32, n_shared_experts=1, n_routed_experts=4,
+    num_experts_per_tok=2, num_hidden_layers=4, gqa_layers=[0],
+    first_k_dense_replace=0, vocab_size=256, rms_norm_eps=1e-5,
+    routed_scaling_factor=1, use_rope=False, use_gqa_gate=True,
+    tie_word_embeddings=False, model_module="hybrid_delta_moe",
     share={"first_expert": 0, "experts_of": 8})
 ENGINE = {"num_slots": 4, "max_decode_len": 128}
 PAGE = 16
@@ -50,32 +67,64 @@ GAP = 1e-3
 # ... and a selection score (a sigmoid plus 0) by some 1e-6.
 SLACK = 1e-4
 
-MODULE = spec.load_model(FILE)
+# A stateful kind: its mixer's name in the tree, the cache leaves the
+# mixer's module declares (state first, then tail), its file and
+# reference.
+STACKS = {
+    "ssm": (FILE, hybrid_ssm_moe_plain, ssm.STATE_LEAVES),
+    "delta": (DELTA_FILE, hybrid_delta_moe_plain, delta.STATE_LEAVES)}
 
 
-def _model(first_expert=0, held=4, dtype=jnp.float32):
-    file = dict(FILE, n_routed_experts=held,
-                share={"first_expert": first_expert, "experts_of": 8})
-    dims = MODULE.dims(file)
+@dataclasses.dataclass
+class Share:
+    """One stack at its tiny size: unpacks as (file, dims, the
+    program's config, seeded float32 weights)."""
+    mixer: str
+    file: dict
+    dims: dict
+    config: tfm.TransformerConfig
+    params: dict
+    module: object
+    plain: object
+    leaves: tuple
+
+    def __iter__(self):
+        return iter((self.file, self.dims, self.config, self.params))
+
+    def layers(self, kind: str) -> list:
+        return [f"layer_{i}" for i, k in enumerate(
+            tfm.layer_kinds(self.config)) if k == kind]
+
+    @property
+    def tail_channels(self) -> int:
+        return self.dims["conv_dim"] if self.mixer == "ssm" \
+            else 3 * self.dims["d_inner"]
+
+    def kept_in(self, dtype) -> tfm.TransformerConfig:
+        """The config with the mixer's state kept in ``dtype``."""
+        sizes = getattr(self.config, self.mixer)
+        return dataclasses.replace(self.config, **{
+            self.mixer: dataclasses.replace(sizes, state_dtype=dtype)})
+
+
+@pytest.fixture(scope="module", params=sorted(STACKS))
+def share(request):
+    file, plain, leaves = STACKS[request.param]
+    module = spec.load_model(file)
+    dims = module.dims(file)
     config = dataclasses.replace(
-        MODULE.program_model(file, dims, ENGINE), dtype=dtype,
-        param_dtype=dtype)
-    return file, dims, config
-
-
-@pytest.fixture(scope="module")
-def share():
-    """(file, dims, the program's config, seeded float32 weights)."""
-    file, dims, config = _model()
-    params = weights.make_params(MODULE.param_leaves(dims), 11,
+        module.program_model(file, dims, ENGINE), dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    params = weights.make_params(module.param_leaves(dims), 11,
                                  jnp.float32)
     # a routing bias that matters: selection by score + bias, weights
     # by the score alone
-    for name, _k, _n in MODULE.decision_layers(file, dims):
+    for name, _k, _n in module.decision_layers(file, dims):
         params[name]["experts"]["e_score_correction_bias"] = \
             0.05 * jax.random.normal(jax.random.PRNGKey(int(name[6:])),
                                      (8,))
-    return file, dims, config, params
+    return Share(request.param, file, dims, config, params, module,
+                 plain, leaves)
 
 
 def _engine(config, params, **kwargs):
@@ -111,7 +160,7 @@ def _judged(share, prompt, served, record):
     sequence = prompt + served[:-1]
     handed = {name: jnp.asarray(rows) for name, rows in
               record["layers"].items()}
-    logits, slacks = MODULE.teacher_forced_logits(
+    logits, slacks = share.module.teacher_forced_logits(
         params, jnp.asarray(sequence, jnp.int32),
         jnp.arange(len(prompt) - 1, len(sequence)), file, dims,
         decisions=handed)
@@ -158,7 +207,7 @@ def test_the_reference_alone_picks_the_same_tokens(share, served):
     _engine_, prompts, _new, done, _records = served
     request_id = max(done, key=lambda r: len(prompts[r]))
     sequence = prompts[request_id] + done[request_id][:-1]
-    logits = MODULE.teacher_forced_logits(
+    logits = share.module.teacher_forced_logits(
         params, jnp.asarray(sequence, jnp.int32),
         jnp.arange(len(prompts[request_id]) - 1, len(sequence)),
         file, dims)
@@ -175,28 +224,27 @@ def test_the_two_halves_experts_and_one_shared_expert_are_the_block(
     gives for the whole E block; in the program and in the reference.
     1e-5: float32 sums in another order."""
     _file, dims, _config, params = share
+    plain, scale = share.plain, dims["scale"]
     d = dims["d_model"]
-    half = params["layer_1"]["experts"]
-    leaves = {leaf[0][-1]: leaf for leaf in MODULE.param_leaves(
-        dict(dims, experts_held=4)) if leaf[0][:2] == ("layer_1",
-                                                        "experts")}
+    half = params[share.layers("experts")[0]]["experts"]
     key = jax.random.PRNGKey(5)
     other = {name: jax.random.normal(
-        jax.random.fold_in(key, i), leaves[name][1]) / np.sqrt(
-            leaves[name][1][1])
-        for i, name in enumerate(("experts_up", "experts_down"))}
-    uncut = dict(half, experts_up=jnp.concatenate(
-        [half["experts_up"], other["experts_up"]]),
-        experts_down=jnp.concatenate(
-            [half["experts_down"], other["experts_down"]]))
+        jax.random.fold_in(key, i), stack.shape) / np.sqrt(
+            stack.shape[1])
+        for i, (name, stack) in enumerate(sorted(half.items()))
+        if name.startswith("experts_")}
+    uncut = dict(half, **{name: jnp.concatenate([half[name], stack])
+                          for name, stack in other.items()})
     h = jax.random.normal(jax.random.PRNGKey(6), (24, d))
     own = jnp.full((24, 2), -1, jnp.int32)
-    sizes = {"top_k": 2, "scale": 2.5}
+    sizes = {"top_k": 2, "scale": scale}
     whole, _slack = plain.experts(h, uncut, own, first=0, **sizes)
-    shared = plain.matmul(plain.relu2(plain.matmul(
-        h, half["shared_up"])), half["shared_down"])
+    # the shared expert alone: the block with no expert held
+    shared, _slack = plain.experts(
+        h, dict(half, **{name: stack[:0] for name, stack in
+                         other.items()}), own, first=0, **sizes)
     # the reference's halves
-    use, weigh, _ = plain.route(h, uncut, own, 2, 2.5)
+    use, weigh, _ = plain.route(h, uncut, own, 2, scale)
     parts = [plain.routed_part(h, dict(half, **tree), use, weigh, first)
              for first, tree in ((0, {}), (4, other))]
     np.testing.assert_allclose(parts[0] + parts[1] + shared, whole,
@@ -209,8 +257,10 @@ def test_the_two_halves_experts_and_one_shared_expert_are_the_block(
     for first, tree in ((0, {}), (4, other)):
         layer = moe.RoutedExperts(
             moe.RoutedConfig(d_model=d, n_experts=8, top_k=2,
-                             d_expert=32, d_shared=64, scale=2.5,
-                             experts_held=4, first_expert=first),
+                             d_expert=32, d_shared=dims["d_shared"],
+                             scale=scale, experts_held=4,
+                             first_expert=first,
+                             gated="experts_gate" in half),
             dtype=jnp.float32)
         out, sown = layer.apply({"params": dict(half, **tree)},
                                 h[None], mutable=["decisions"])
@@ -226,6 +276,7 @@ def test_the_two_vocabulary_halves_logits_concatenate(share):
     head over each half gives the uncut reference's logits side by
     side (a matmul's columns are independent)."""
     _file, dims, config, params = share
+    plain = share.plain
     d = dims["d_model"]
     uncut = jax.random.normal(jax.random.PRNGKey(8), (d, 512)) / 8.0
     hidden = jax.random.normal(jax.random.PRNGKey(9), (5, d))
@@ -259,22 +310,22 @@ def test_bucket_padding_advances_neither_state_nor_tail(share, chunk):
         model, chunk, params, jnp.asarray([prompt + [0] * 21]), 11)
     np.testing.assert_allclose(last_padded, last, atol=1e-5)
     assert int(jnp.argmax(last_padded)) == int(jnp.argmax(last))
+    mixer, (state, _tail) = share.mixer, share.leaves
+    assert set(share.leaves) <= set(inf.SLOT_STATE_LEAVES)
     states = 0
-    for name, kind in zip(sorted(exact), "MM*M"):
-        if kind != "M":
-            continue
-        for leaf in inf.SLOT_STATE_LEAVES:
+    for name in share.layers(mixer):
+        for leaf in share.leaves:
             states += 1
             np.testing.assert_allclose(
-                padded[name]["ssm"][leaf], exact[name]["ssm"][leaf],
+                padded[name][mixer][leaf], exact[name][mixer][leaf],
                 atol=1e-5, err_msg=f"{name} {leaf}")
     assert states == 6
     # ... and padding that DID advance it would show
     wrong, _last, _chosen = serving._dense_prefill(
         model, None, params, jnp.asarray([prompt + [0] * 21]), 32)
-    assert float(jnp.abs(wrong["layer_0"]["ssm"]["ssm_state"]
-                         - exact["layer_0"]["ssm"]["ssm_state"]).max()
-                 ) > 1e-2
+    first = share.layers(mixer)[0]
+    assert float(jnp.abs(wrong[first][mixer][state]
+                         - exact[first][mixer][state]).max()) > 1e-2
 
 
 # ------------------- (d) a slot reused, with a step in flight
@@ -323,19 +374,21 @@ def test_a_rows_experts_do_not_depend_on_its_batch_mates(share):
     to the last bits of a float32 sum (no capacity, nothing dropped),
     and what the reference's routed part gives."""
     _file, dims, _config, params = share
-    w = params["layer_4"]["experts"]
+    w = params[share.layers("experts")[1]]["experts"]
     rows = jax.random.normal(jax.random.PRNGKey(2), (96, dims["d_model"]))
     chosen, weigh = moe.route_sigmoid(
-        rows @ w["router_kernel"], w["e_score_correction_bias"], 2, 2.5)
-    full = moe.dense_experts(rows, chosen, weigh, w["experts_up"],
-                             w["experts_down"], 0)
+        rows @ w["router_kernel"], w["e_score_correction_bias"], 2,
+        dims["scale"])
+    stacks = (w["experts_up"], w["experts_down"], 0,
+              w.get("experts_gate"))
+    full = moe.dense_experts(rows, chosen, weigh, *stacks)
     for i in (0, 17, 95):
         one = moe.dense_experts(rows[i:i + 1], chosen[i:i + 1],
-                                weigh[i:i + 1], w["experts_up"],
-                                w["experts_down"], 0)
+                                weigh[i:i + 1], *stacks)
         np.testing.assert_allclose(one[0], full[i], rtol=0, atol=2e-6)
     np.testing.assert_allclose(
-        full, plain.routed_part(rows, w, chosen, weigh, 0), atol=1e-5)
+        full, share.plain.routed_part(rows, w, chosen, weigh, 0),
+        atol=1e-5)
     # every pair on a held expert was computed: none dropped
     held = (chosen < 4).sum(axis=1)
     assert float(jnp.abs(full[held == 0]).max()) == 0.0
@@ -355,13 +408,12 @@ def test_a_request_is_served_the_same_alone_and_in_a_full_step(
 # ------------------- (f) the record of choices
 
 
-def test_take_decisions_covers_every_position_once(served):
+def test_take_decisions_covers_every_position_once(share, served):
     engine, prompts, _new, done, records = served
     for request_id, record in records.items():
         positions = len(prompts[request_id]) + len(done[request_id]) - 1
         assert record["first"] == 0
-        assert list(record["layers"]) == ["layer_1", "layer_4",
-                                          "layer_6"]
+        assert list(record["layers"]) == share.layers("experts")
         for rows in record["layers"].values():
             assert rows.shape == (positions, 2)
             assert rows.dtype == np.int32
@@ -393,22 +445,22 @@ def test_a_cancelled_request_leaves_no_record(share):
 
 def test_the_cache_holds_a_state_per_slot_beside_the_paged_kv(share,
                                                              served):
-    _file, dims, config, _params = share
     engine = served[0]
     assert engine.stateful and engine.paged
-    layer = engine.cache["layer_0"]["ssm"]
-    assert layer["ssm_state"].shape == (4, 4, 16, 16)
-    assert layer["ssm_state"].dtype == jnp.float32
-    assert layer["conv_tail"].shape == (4, 3, dims["conv_dim"])
+    state, tail = share.leaves
+    layer = engine.cache[share.layers(share.mixer)[0]][share.mixer]
+    assert set(layer) == {state, tail}      # a slot row, no cursor
+    assert layer[state].shape == (4, 4, 16, 16)
+    assert layer[state].dtype == jnp.float32
+    assert layer[tail].shape == (4, 3, share.tail_channels)
     # two K/V heads of 16 in the pool's rows, not the four query heads
-    pool = engine.cache["layer_3"]["attn"]["k_pages"]
+    pool = engine.cache[share.layers("attn")[0]]["attn"]["k_pages"]
     assert pool.shape[1:] == (PAGE, 2 * 16)
-    assert "index" not in layer and "length" not in layer
-    per_slot = 3 * (4 * 16 * 16 * 4 + 3 * dims["conv_dim"] * 4)
+    per_slot = 3 * (4 * 16 * 16 * 4 + 3 * share.tail_channels * 4)
     assert inf.slot_state_bytes(engine.cache) == per_slot
     idle = engine.occupancy()
     assert idle["state_slots_in_use"] == 0
-    assert idle["experts_held"] == 3 * 4
+    assert idle["experts_held"] == len(share.layers("experts")) * 4
     engine.submit(Request("x", [1, 2, 3], max_new_tokens=4))
     engine.step()
     busy = engine.occupancy()
@@ -418,16 +470,19 @@ def test_the_cache_holds_a_state_per_slot_beside_the_paged_kv(share,
         engine.step()
 
 
-def test_idle_slots_are_parked_and_their_state_is_left_alone():
+@pytest.mark.parametrize("mixer", sorted(STACKS))
+def test_idle_slots_are_parked_and_their_state_is_left_alone(mixer):
     """_park_idle_cursors touches cursors alone: a state leaf has
     none."""
-    cache = {"layer_0": {"ssm": {"ssm_state": jnp.ones((3, 2, 2, 2)),
-                                 "conv_tail": jnp.ones((3, 3, 4))}},
+    state, tail = STACKS[mixer][2]
+    cache = {"layer_0": {mixer: {state: jnp.ones((3, 2, 2, 2)),
+                                 tail: jnp.ones((3, 3, 4))}},
              "layer_1": {"attn": {"length": jnp.asarray([5, 6, 7])}}}
     parked = inf._park_idle_cursors(
         cache, jnp.asarray([True, False, True]))
     assert parked["layer_1"]["attn"]["length"].tolist() == [5, 0, 7]
-    assert float(parked["layer_0"]["ssm"]["ssm_state"].min()) == 1.0
+    assert float(parked["layer_0"][mixer][state].min()) == 1.0
+    assert inf.slot_state_bytes(cache) == (2 * 2 * 2 + 3 * 4) * 4
 
 
 def test_matched_pages_are_shared_and_the_whole_prompt_is_run(share):
@@ -453,7 +508,7 @@ def test_matched_pages_are_shared_and_the_whole_prompt_is_run(share):
     for request_id in prompts:
         record = shared.take_decisions(request_id)
         assert record["first"] == 0
-        assert len(record["layers"]["layer_1"]) == \
+        assert len(record["layers"][share.layers("experts")[0]]) == \
             len(prompts[request_id]) + 5
 
 
@@ -462,10 +517,11 @@ def test_the_expert_counters_count_decode_steps_pairs(share):
     engine = _engine(config, params)
     _serve(engine, _prompts(4), dict.fromkeys(_prompts(4), 6))
     stats = engine.step_stats()
-    # 4 requests x 5 decoded tokens x 3 routed layers x top-2
-    assert stats["expert_pairs_chosen"] == 4 * 5 * 3 * 2
+    routed = len(share.layers("experts"))
+    # 4 requests x 5 decoded tokens x the routed layers x top-2
+    assert stats["expert_pairs_chosen"] == 4 * 5 * routed * 2
     assert 0 < stats["expert_pairs_here"] < stats["expert_pairs_chosen"]
-    assert 0 < stats["experts_hit"] <= stats["decode_steps"] * 3 * 4
+    assert 0 < stats["experts_hit"] <= stats["decode_steps"] * routed * 4
 
 
 def test_step_rows_carry_the_new_counters(share, recorder):
@@ -483,7 +539,8 @@ def test_step_rows_carry_the_new_counters(share, recorder):
     assert sum(row["expert_pairs_chosen"] for row in rows) == \
         engine.step_stats()["expert_pairs_chosen"]
     landed = [row for row in rows if row["expert_pairs_chosen"]]
-    assert all(row["experts_hit"] <= 12 for row in landed)
+    assert all(row["experts_hit"] <= 4 * len(share.layers("experts"))
+               for row in landed)
 
 
 def test_each_block_kind_has_its_own_scope_in_the_step_program(share):
@@ -495,8 +552,9 @@ def test_each_block_kind_has_its_own_scope_in_the_step_program(share):
         engine.model, engine.sampling, params, engine.cache,
         engine._tokens, engine._positions, engine._active,
         engine._key).as_text(debug_info=True)
-    for scope in ("layer_0/ssm", "layer_1/experts", "layer_3/attn"):
-        assert scope in text, scope
+    for kind in (share.mixer, "experts", "attn"):
+        for layer in share.layers(kind):
+            assert f"{layer}/{kind}" in text, (layer, kind)
 
 
 def test_a_draft_model_is_refused_for_a_stateful_target(share):
@@ -539,7 +597,8 @@ def test_grouped_query_attention_is_repeated_kv_attention():
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
-def test_layer_kinds_from_the_list_and_from_the_stride():
+@pytest.mark.parametrize("stateful", tfm.STATEFUL_KINDS)
+def test_layer_kinds_from_the_list_and_from_the_stride(stateful):
     dense = tfm.TransformerConfig(n_layers=4)
     assert tfm.layer_kinds(dense) == ("dense",) * 4
     strided = dataclasses.replace(dense, moe=moe.MoEConfig(),
@@ -549,13 +608,14 @@ def test_layer_kinds_from_the_list_and_from_the_stride():
     assert not tfm.has_slot_state(strided)
     with pytest.raises(ValueError, match="block_kinds"):
         tfm.layer_kinds(dataclasses.replace(
-            dense, block_kinds=("ssm", "attn")))
+            dense, block_kinds=(stateful, "attn")))
     mixed = dataclasses.replace(
-        dense, block_kinds=("ssm", "experts", "attn", "dense"),
-        ssm=ssm.SSMConfig())
+        dense, block_kinds=(stateful, "experts", "attn", "dense"))
     assert tfm.decision_layer_names(mixed) == ("layer_1",)
     assert tfm.paged_layer_count(mixed) == 2
     assert tfm.has_slot_state(mixed)
+    assert not tfm.has_slot_state(dataclasses.replace(
+        dense, block_kinds=("attn", "experts", "attn", "dense")))
 
 
 def test_the_chunked_scan_is_the_recurrence():
@@ -579,23 +639,24 @@ def test_the_chunked_scan_is_the_recurrence():
 
 
 def test_a_state_kept_in_bfloat16_is_rounded_once_a_token(share):
-    """SSMConfig.state_dtype, the program's lower-precision switch
-    (the benchmark's control turns it on): the state leaf is kept in
-    bfloat16 and still advanced in float32. One token in, the kept
-    state is the float32 one rounded once (2**-8 of each entry);
-    sixty-four tokens on, a head that remembers (A_log -8: no decay to
-    speak of) has gathered those roundings and reads beyond one
-    rounding of its own output, while the float32 engine's state is
-    untouched by the switch."""
-    _file, _dims, config, params = share
+    """``state_dtype`` of SSMConfig and of DeltaConfig, the program's
+    lower-precision switch (the benchmark's control turns it on): the
+    state leaf is kept in bfloat16 and still advanced in float32. One
+    token in, the kept state is the float32 one rounded once (2**-8 of
+    each entry); sixty-four tokens on, a head that remembers (A_log
+    -8: no decay to speak of) has gathered those roundings and reads
+    beyond one rounding of its own output, while the float32 engine's
+    state is untouched by the switch."""
+    _file, _dims, _config, params = share
+    mixer, (leaf, _tail) = share.mixer, share.leaves
+    first = share.layers(mixer)[0]
     params = jax.tree_util.tree_map(lambda leaf: leaf, params)
-    params["layer_0"]["ssm"]["A_log"] = jnp.full((4,), -8.0)
+    params[first][mixer]["A_log"] = jnp.full((4,), -8.0)
     prompt = _prompts(1, 70, 71, seed=9)["r0"]
     states = {}
     for dtype in (jnp.float32, jnp.bfloat16):
-        kept = dataclasses.replace(config, ssm=dataclasses.replace(
-            config.ssm, state_dtype=dtype))
-        model = tfm.TransformerLM(inf.decode_config(kept, 128))
+        model = tfm.TransformerLM(
+            inf.decode_config(share.kept_in(dtype), 128))
         cache, _last, _chosen = serving._dense_prefill(
             model, None, params, jnp.asarray([prompt[:6]]), 6)
         trail = []
@@ -606,7 +667,7 @@ def test_a_state_kept_in_bfloat16_is_rounded_once_a_token(share):
                 positions=jnp.asarray([[position]]),
                 mutable=serving._MUTABLE)
             cache = mutated["cache"]
-            trail.append(cache["layer_0"]["ssm"]["ssm_state"])
+            trail.append(cache[first][mixer][leaf])
         assert trail[0].dtype == dtype
         states[dtype] = [np.asarray(s, np.float32) for s in trail]
     exact, rounded = states[jnp.float32], states[jnp.bfloat16]
