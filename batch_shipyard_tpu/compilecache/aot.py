@@ -29,13 +29,18 @@ logger = util.get_logger(__name__)
 
 
 def abstractify(tree: Any) -> Any:
-    """Concrete array tree -> ShapeDtypeStruct tree (shardings kept),
-    for lowering without touching data."""
+    """Concrete array tree -> ShapeDtypeStruct tree, for lowering
+    without touching data. A COMMITTED array's sharding is kept; an
+    uncommitted one (made on the default device and never placed)
+    gives none, as ``jit`` treats it when it is called with the
+    array: a sharding named for it here would lower to another
+    program than the real call's, under another cache key."""
     import jax
 
     def leaf(x):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
-            sharding = getattr(x, "sharding", None)
+            sharding = (getattr(x, "sharding", None)
+                        if getattr(x, "committed", True) else None)
             return jax.ShapeDtypeStruct(x.shape, x.dtype,
                                         sharding=sharding)
         return x

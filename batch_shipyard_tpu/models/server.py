@@ -1401,6 +1401,8 @@ class ServingFrontEnd:
             "steps": count,
             "decode_steps": steps["decode_steps"],
             "steps_overlapped": steps["steps_overlapped"],
+            "prefills": steps["prefills"],
+            "prefills_overlapped": steps["prefills_overlapped"],
             "settles": steps["settles"],
             "overshoot_tokens": steps["overshoot_tokens"],
             # a routed model's expert counters (absent otherwise)

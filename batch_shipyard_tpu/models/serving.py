@@ -46,9 +46,10 @@ STEP_PHASES = ("admit", "prefill", "slot_update", "grow_pages",
                "dispatch", "readback", "emit")
 # Why a decode step in flight was read back BEFORE its successor was
 # dispatched (ContinuousBatcher._settle), so that the successor did
-# not overlap it: a prefill about to block, a dry pool, a cancel, a
-# drain, or nothing left to dispatch.
-SETTLE_CAUSES = ("admit", "preempt", "cancel", "drain", "idle")
+# not overlap it: a dry pool, a cancel, a drain, or nothing left to
+# dispatch. An admission is none: its prefill is dispatched behind
+# the step in flight (_admit).
+SETTLE_CAUSES = ("preempt", "cancel", "drain", "idle")
 # What a step program lets the model write: the cache, and the expert
 # choices a routed layer sows (nothing, for a model without one).
 _MUTABLE = ["cache", "decisions"]
@@ -456,6 +457,25 @@ def _table_per_layer(table, copies):
     return (table,) * copies
 
 
+@functools.partial(jax.jit, static_argnames=("sampling",))
+def _seat_first(sampling, last_logits, key, tokens, positions, slot,
+                prompt_len):
+    """A prefill's first token, sampled and seated ON THE DEVICE: the
+    engine's key split once (the admission's key, as the decode
+    program's caller splits the step's), the token drawn from the
+    prefill's last logits [vocab] and written with the prompt's
+    length into the slot's row of the decode step's inputs. -> (key,
+    tokens [B, 1], positions [B], the token int32 [1]). The host
+    reads the token when it likes (_land_first); the decode step
+    behind this program needs nothing from the host. No bucket in
+    its shapes, slot and length traced: ONE compilation an engine."""
+    key, sample_key = jax.random.split(key)
+    first = inf._sample(last_logits[None].astype(jnp.float32),
+                        sample_key, sampling)
+    return (key, tokens.at[slot, 0].set(first[0]),
+            positions.at[slot].set(prompt_len), first)
+
+
 @dataclasses.dataclass
 class Request:
     request_id: str
@@ -505,18 +525,24 @@ class SpeculativeConfig:
 
 @dataclasses.dataclass
 class _Slot:
+    """A slot's books on the host: who sits there, the tokens the
+    host has read for it, and how many more the device owes it."""
     request: Optional[Request] = None
     generated: list[int] = dataclasses.field(default_factory=list)
-    # Tokens of this request that a dispatched decode step computes
-    # and the host has not read yet: what the host's books are behind
-    # the device by (0 or 1 between step() calls).
+    # Tokens of this request that the device was handed the programs
+    # for and the host has not read yet: a prefill's first token, a
+    # dispatched decode step's. What the host's books are behind the
+    # device by: 0 or 1 between step() calls, 2 inside one while the
+    # first token and the decode step dispatched behind it are both
+    # unread.
     in_flight: int = 0
 
     def decoding(self) -> bool:
         """Whether the next decode step advances this slot: seated
-        and, the token in flight counted, still short of
-        max_new_tokens. The host knows that finish before the token
-        is computed; an eos finish it learns from the token."""
+        and, the tokens in flight counted (an unread first token
+        too), still short of max_new_tokens. The host knows that
+        finish before the token is computed; an eos finish it learns
+        from the token."""
         return (self.request is not None and
                 len(self.generated) + self.in_flight
                 < self.request.max_new_tokens)
@@ -527,13 +553,23 @@ class _Slot:
         return (len(self.request.prompt) + len(self.generated) +
                 self.in_flight)
 
+    def ended(self) -> bool:
+        """Whether the token read last is the request's last: the
+        max_new_tokens-th, or its eos_id."""
+        req = self.request
+        return (len(self.generated) >= req.max_new_tokens or
+                (req.eos_id is not None and
+                 self.generated[-1] == req.eos_id))
+
 
 @dataclasses.dataclass
 class _InFlight:
     """A decode step the device was handed whose tokens the host has
     not read: the [B] token array (and the step's key, which dies
     with it), the (slot, request) pairs the step advances, taken at
-    dispatch, when that was, and what the step's routed layers chose."""
+    dispatch, when that was, and what the step's routed layers chose.
+    It waits in ContinuousBatcher._unread, in dispatch order with the
+    first tokens of prefills (_FirstToken), to be landed by _land."""
     tokens: object
     key: object
     seated: list[tuple[int, Request]]
@@ -541,6 +577,27 @@ class _InFlight:
     # The routed layers' choices of this step, int32 [decision layers,
     # B, k] on the device; None for a model without such layers.
     chosen: object = None
+
+
+@dataclasses.dataclass
+class _FirstToken:
+    """A prefill the device was handed whose first token the host has
+    not read: the int32 [1] token array of the seat program
+    (_seat_first), the slot it was seated in (whose request stays
+    until the token has landed), the (path, bucket) its time is
+    recorded under, when it was dispatched, and what the prefill's
+    routed layers chose."""
+    token: object
+    slot: int
+    timed: tuple[str, int]
+    dispatched_at: float
+    # The prefill's choices, int32 [decision layers, bucket, k] on the
+    # device, of which the first ``prefilled`` positions are the
+    # prompt's own, from position ``first_position`` on (past a
+    # prefix taken from shared pages); None without routed layers.
+    chosen: object = None
+    prefilled: int = 0
+    first_position: int = 0
 
 
 @dataclasses.dataclass
@@ -747,14 +804,18 @@ class ContinuousBatcher:
         self.steps_total = 0
         self.step_seconds_total = 0.0
         self.traced_steps = 0
-        # The one-step lookahead (_step): the decode step in flight,
-        # requests it finished that no step() has returned yet, and
-        # its counters (step_stats).
-        self._in_flight: Optional[_InFlight] = None
+        # The lookahead (_step): what the device was handed and the
+        # host has not read, in dispatch order (a decode step in
+        # flight, prefills' first tokens), requests a landing
+        # finished that no step() has returned yet, when the last
+        # result landed, and the counters (step_stats).
+        self._unread: collections.deque = collections.deque()
         self._finished: list[tuple[str, list[int]]] = []
         self._landed_at = 0.0
         self.decode_steps = 0
         self.steps_overlapped = 0
+        self.prefills = 0
+        self.prefills_overlapped = 0
         self.settles = dict.fromkeys(SETTLE_CAUSES, 0)
         self.overshoot_tokens = 0
         # Row ids count up from a random start: a uuid4 a step costs
@@ -798,6 +859,8 @@ class ContinuousBatcher:
 
         self._decode_step = functools.partial(
             _decode_step, self.model, self.sampling)
+        self._seat_first = functools.partial(_seat_first,
+                                             self.sampling)
 
         # Prefill always runs on a DENSE batch-1 decode model sharing
         # the params; paged mode then scatters its rows into the
@@ -841,6 +904,13 @@ class ContinuousBatcher:
     # engine to build a table row for _prefill_paged.
     max_blocks = property(lambda self: self.pages.max_blocks)
     _scratch_page = property(lambda self: self.pages.scratch_page)
+
+    @property
+    def _in_flight(self) -> Optional[_InFlight]:
+        """The decode step the device was handed whose tokens the
+        host has not read (the newest, while _step holds two)."""
+        return next((result for result in reversed(self._unread)
+                     if isinstance(result, _InFlight)), None)
 
     # ------------------------------ public -----------------------------
 
@@ -932,9 +1002,10 @@ class ContinuousBatcher:
 
     def precompile(self) -> int:
         """AOT warm start from shapes — no throwaway requests: lower +
-        compile the decode step (or the speculative draft/verify step)
-        and every prefill bucket against ShapeDtypeStruct abstract
-        inputs. The executables are discarded; the value is the
+        compile the decode step (or the speculative draft/verify step),
+        every prefill bucket and the seat program behind a prefill
+        against ShapeDtypeStruct abstract inputs placed as the real
+        calls' arguments are (aot.abstractify). The executables are discarded; the value is the
         PERSISTENT compilation cache (compilecache/manager.py) they
         populate, which turns the first real request's jit compiles
         into fast deserializes — so enable the cache first, or this
@@ -952,12 +1023,15 @@ class ContinuousBatcher:
                 cc_manager.tracked(attrs, "serving_precompile"):
             params_abs = aot.abstractify(self.params)
             cache_abs = aot.abstractify(self.cache)
-            tokens_abs = jax_mod.ShapeDtypeStruct(
-                (self.num_slots, 1), jnp.int32)
-            pos_abs = jax_mod.ShapeDtypeStruct((self.num_slots,),
-                                               jnp.int32)
-            active_abs = jax_mod.ShapeDtypeStruct((self.num_slots,),
-                                                  jnp.bool_)
+            tokens_abs, pos_abs, active_abs, key_abs = aot.abstractify(
+                (self._tokens, self._positions, self._active,
+                 self._key))
+
+            def put_abs(shape):
+                # what _put gives: int32, on the engine's device if
+                # it is pinned to one
+                return jax_mod.ShapeDtypeStruct(
+                    shape, jnp.int32, sharding=active_abs.sharding)
             if self.speculative is not None:
                 _speculative_step.lower(
                     self.model, self._spec_step.args[1], self.gamma,
@@ -965,7 +1039,6 @@ class ContinuousBatcher:
                     cache_abs, aot.abstractify(self._draft_cache),
                     tokens_abs, pos_abs, active_abs).compile()
             else:
-                key_abs = aot.abstractify(self._key)
                 _decode_step.lower(
                     self.model, self.sampling, params_abs, cache_abs,
                     tokens_abs, pos_abs, active_abs,
@@ -973,19 +1046,18 @@ class ContinuousBatcher:
             count += 1
             dense_model = self._prefill.args[0]
             for bucket in self.warmup_buckets():
-                prompt_abs = jax_mod.ShapeDtypeStruct((1, bucket),
-                                                      jnp.int32)
+                prompt_abs = put_abs((1, bucket))
                 if self.pages is not None:
-                    row_abs = jax_mod.ShapeDtypeStruct(
-                        (self.max_blocks,), jnp.int32)
-                    _prefill_paged.lower(
+                    row_abs = put_abs((self.max_blocks,))
+                    lowered = _prefill_paged.lower(
                         dense_model, self.prefill_chunk,
                         self.page_size, params_abs, cache_abs, 0,
-                        prompt_abs, row_abs, bucket).compile()
+                        prompt_abs, row_abs, bucket)
                 else:
-                    _prefill_dense.lower(
+                    lowered = _prefill_dense.lower(
                         dense_model, self.prefill_chunk, params_abs,
-                        cache_abs, 0, prompt_abs, bucket).compile()
+                        cache_abs, 0, prompt_abs, bucket)
+                lowered.compile()
                 count += 1
                 if self.speculative is not None:
                     # _admit prefills the DRAFT cache too (the
@@ -998,6 +1070,15 @@ class ContinuousBatcher:
                         aot.abstractify(self._draft_cache), 0,
                         prompt_abs, bucket).compile()
                     count += 1
+            # The seat program behind every prefill (_seat_first): it
+            # takes a prefill's last logits, whatever the bucket.
+            logits = lowered.out_info[1]
+            _seat_first.lower(
+                self.sampling,
+                jax_mod.ShapeDtypeStruct(logits.shape, logits.dtype,
+                                         sharding=active_abs.sharding),
+                key_abs, tokens_abs, pos_abs, 0, 0).compile()
+            count += 1
         return count
 
     def submit(self, request: Request,
@@ -1048,8 +1129,9 @@ class ContinuousBatcher:
             1 for s in self._slots if s.request is not None)
 
     def drain(self) -> list[str]:
-        """Flip the engine into drain mode: _admit stops seating new
-        work, queued entries (which hold no pages) are evicted and
+        """Flip the engine into drain mode: what the device owes is
+        landed (_settle), _admit stops seating new work, queued
+        entries (which hold no pages) are evicted and
         their ids returned so the front end can 503 their waiters for
         router failover, and active decodes keep stepping until they
         finish (or the front end's grace deadline cancels them).
@@ -1084,10 +1166,10 @@ class ContinuousBatcher:
         """Abort a queued or actively-decoding request (the vLLM-class
         abort operation). Queued entries are removed; an active slot
         is freed immediately (its pages return to the pool), after
-        the step in flight has been read back: every token computed
-        for the request before the cancel is delivered, and one that
-        ends it finishes it (False then, and the next step() returns
-        it). Must be called from the engine's stepping thread — it
+        the step in flight and any first token still unread have
+        been read back: every token computed for the request before
+        the cancel is delivered, and one that ends it finishes it
+        (False then, and the next step() returns it). Must be called from the engine's stepping thread — it
         mutates slot state like step() does. Returns False when the
         id is unknown (already finished)."""
         for k, entry in enumerate(self._queue):
@@ -1099,8 +1181,9 @@ class ContinuousBatcher:
                      slot.request.request_id == request_id), None)
         if seat is None:
             return False
-        # The step in flight may hold the request's next token, or
-        # its last, which frees the slot by itself.
+        # What is unread (the step in flight, a first token) may
+        # hold the request's next token, or its last, which frees the
+        # slot by itself.
         self._settle("cancel")
         if self._slots[seat].request is None:
             return False
@@ -1159,10 +1242,14 @@ class ContinuousBatcher:
     def _lookahead_counts(self) -> dict:
         """The lookahead's cumulative counters: decode steps
         dispatched, how many of them while their predecessor was
-        still unread, the settles by cause (SETTLE_CAUSES), and the
+        still unread, prefill programs dispatched, how many of them
+        behind a decode step in flight or behind another prefill of
+        the same call, the settles by cause (SETTLE_CAUSES), and the
         tokens computed for a request that had already ended."""
         return {"decode_steps": self.decode_steps,
                 "steps_overlapped": self.steps_overlapped,
+                "prefills": self.prefills,
+                "prefills_overlapped": self.prefills_overlapped,
                 "settles": dict(self.settles),
                 "overshoot_tokens": self.overshoot_tokens}
 
@@ -1175,6 +1262,9 @@ class ContinuousBatcher:
             attrs[f"{name}_ms"] = self._phases.step.get(name,
                                                         0.0) * 1e3
         attrs["prefills"] = len(self._admitted)
+        attrs["prefills_overlapped"] = (
+            self.prefills_overlapped
+            - lookahead0["prefills_overlapped"])
         attrs["prefill_tokens"] = sum(a["tokens"]
                                       for a in self._admitted)
         attrs["admitted"] = list(self._admitted)
@@ -1208,36 +1298,32 @@ class ContinuousBatcher:
     def _step(self) -> None:
         """One call's work; what finished goes to self._finished.
 
-        Without a draft model one decode step stays in flight. Step
-        k's inputs (cache, tokens, positions) are device arrays that
-        step k-1 returned, so the device needs nothing from the host
-        to go from one to the next: this call grows the pages for
-        step k and dispatches it from the HOST's books (_Slot:
-        a slot's write position counts its token in flight, and a
+        Without a draft model the device is never waited for before
+        it has its next work. Step k's inputs (cache, tokens,
+        positions) are device arrays that step k-1, or a prefill and
+        its seat program behind it, returned, so the device needs
+        nothing from the host to go from one to the next. This call
+        dispatches the queue's prefills BEHIND the step in flight
+        (_admit: no settle; the first token is sampled and seated on
+        the device), grows the pages for step k and dispatches it
+        from the HOST's books (_Slot: a slot's write position counts
+        its tokens in flight, a prefill's first among them, and a
         finish by max_new_tokens is known before the token is), and
-        only then reads back and emits step k-1 (_land), while the
-        device computes step k. No phase before that readback reads
-        from the device. Whatever edits slots outside this order
-        (cancel, a dry pool's preemption, drain, a prefill about to
-        block) first lands the step in flight through _settle; an
-        idle engine has none."""
+        only then reads back, oldest first, what it owes: step k-1's
+        tokens (_land), then each prefill's first token
+        (_land_first), while the device computes step k. No phase
+        before that readback reads from the device. Whatever edits
+        slots outside this order (cancel, a dry pool's preemption,
+        drain) first lands everything unread through _settle; an
+        idle engine has nothing unread.
+
+        With a draft model the step is serial: the first tokens land
+        right after _admit, through the same code, and nothing is
+        unread when the draft/verify round starts."""
         phases = self._phases
         self._admit()
-        # Slots whose prefill-sampled first token already satisfied the
-        # request (max_new_tokens == 1 or immediate eos) emit without a
-        # decode step.
-        with phases("emit"):
-            for i, slot in enumerate(self._slots):
-                req = slot.request
-                if req is None or not slot.generated:
-                    continue
-                last = slot.generated[-1]
-                if (len(slot.generated) >= req.max_new_tokens or
-                        (req.eos_id is not None and
-                         last == req.eos_id)):
-                    self._finish(i)
         if self.speculative is not None:
-            # Serial: every seated slot decodes, nothing is in flight.
+            self._land_unread()
             seated = self._decoding()
             if seated:
                 self._push_active(seated)
@@ -1251,7 +1337,7 @@ class ContinuousBatcher:
             # Every seated request waits for its last token only.
             self._settle("idle")
             return
-        previous = self._in_flight
+        overlapped = self._in_flight is not None
         t0 = time.monotonic()
         with phases("dispatch"):
             self._push_active(seated)
@@ -1260,42 +1346,91 @@ class ContinuousBatcher:
              *chosen) = self._decode_step(
                 self.params, self.cache, self._tokens,
                 self._positions, self._active, step_key)
-            self._in_flight = _InFlight(next_tok, step_key, seated, t0,
-                                        *chosen)
+            self._unread.append(_InFlight(next_tok, step_key, seated,
+                                          t0, *chosen))
             del next_tok, step_key, chosen  # _land lets the arrays die
             for i, _ in seated:
                 self._slots[i].in_flight += 1
         self.decode_steps += 1
-        if previous is None:
-            return
-        self.steps_overlapped += 1
-        self._land(previous)
+        self.steps_overlapped += overlapped
+        self._land_unread(keep=1)
         if not any(s.request is not None for s in self._slots):
             # An eos ended the last request: the step in flight
             # computes overshoot alone.
             self._settle("idle")
 
     def _settle(self, cause: str) -> bool:
-        """Read back and emit the decode step in flight, if there is
-        one (True then), so that the host's books and the device
-        agree and nothing is owed to any request: what everything
-        that edits slots outside _step's own order calls first.
-        ``cause`` is one of SETTLE_CAUSES."""
-        step, self._in_flight = self._in_flight, None
-        if step is None:
+        """Read back and emit everything the device was handed and
+        the host has not read (True if there was anything: a decode
+        step in flight, prefills' first tokens), so that the host's
+        books and the device agree and nothing is owed to any
+        request: what everything that edits slots outside _step's
+        own order calls first. ``cause`` is one of SETTLE_CAUSES,
+        counted where a decode step was in flight (its successor
+        will not overlap it)."""
+        if not self._unread:
             return False
-        self.settles[cause] += 1
-        self._land(step)
+        if self._in_flight is not None:
+            self.settles[cause] += 1
+        self._land_unread()
         return True
+
+    def _land_unread(self, keep: int = 0) -> None:
+        """Land, in the order the device was handed them, all but the
+        newest ``keep`` of the unread results."""
+        while len(self._unread) > keep:
+            result = self._unread.popleft()
+            if isinstance(result, _InFlight):
+                self._land(result)
+            else:
+                self._land_first(result)
+
+    def _land_first(self, first: _FirstToken) -> None:
+        """Wait for a dispatched prefill's first token and hand it
+        over: the slot's books catch up (the token was counted in
+        flight since _admit), the prefill's choices go on record, a
+        request it ends (max_new_tokens, eos_id) is finished. The
+        wait is the "prefill" phase's, the books and the hand-over
+        "slot_update"'s, as when _admit did both."""
+        phases = self._phases
+        with phases("prefill"):
+            token = int(np.asarray(first.token)[0])
+            chosen = (None if first.chosen is None else np.asarray(
+                first.chosen)[:, :first.prefilled].astype(np.int16))
+        # The device's time for this prefill: from the landing before
+        # it, unless it was dispatched later than that.
+        self._record_prefill_time(
+            first.timed, max(first.dispatched_at, self._landed_at),
+            first.timed[1])
+        self._landed_at = time.monotonic()
+        with phases("slot_update"):
+            # Nobody else sits here: whatever frees a slot lands
+            # what is unread first.
+            slot = self._slots[first.slot]
+            request_id = slot.request.request_id
+            slot.in_flight -= 1
+            slot.generated.append(token)
+            if chosen is not None:
+                self._decisions[request_id] = {
+                    "first": first.first_position, "prefill": chosen,
+                    "steps": []}
+            if slot.ended():
+                # A decode step dispatched behind the prefill with
+                # this slot seated computes overshoot (_land).
+                self._finish(first.slot)
+            self._emit([(request_id, token, len(slot.generated) - 1)])
+            first.token = first.chosen = None   # as in _land
 
     def _land(self, step: _InFlight) -> None:
         """Wait for a dispatched step's tokens and emit them, each
         only to the request its slot held at dispatch: a request that
-        ended on its eos_id one step earlier was still decoded, and
-        that token is dropped here (overshoot_tokens; its K/V row
-        lies past the request's last token, in a page no index
-        names, and the slot's next prefill is ordered behind the
-        step by the cache it consumes)."""
+        ended on its eos_id one landing earlier (a step's token, or
+        a prefill's first) was still decoded, and that token is
+        dropped here (overshoot_tokens; its K/V row lies past the
+        request's last token, in a page no index names). Every
+        program is ordered behind the one before it by the cache it
+        consumes: a slot's next prefill behind this step, and the
+        step behind a prefill dispatched before it (_admit)."""
         phases = self._phases
         with phases("readback"):
             next_host = np.asarray(step.tokens)
@@ -1326,10 +1461,7 @@ class ContinuousBatcher:
                 slot.generated.append(token)
                 batch.append((req.request_id, token,
                               len(slot.generated) - 1))
-                done = (len(slot.generated) >= req.max_new_tokens or
-                        (req.eos_id is not None and
-                         token == req.eos_id))
-                if done:
+                if slot.ended():
                     self._finish(i)
             self._emit(batch)
             if chosen is not None:
@@ -1392,9 +1524,7 @@ class ContinuousBatcher:
                     slot.generated.append(token)
                     batch.append((req.request_id, token,
                                   len(slot.generated) - 1))
-                    if (len(slot.generated) >= req.max_new_tokens or
-                            (req.eos_id is not None and
-                             token == req.eos_id)):
+                    if slot.ended():
                         # Stopped mid-block: the remaining committed
                         # tokens are discarded (their cache rows
                         # recycle with the slot).
@@ -1565,7 +1695,8 @@ class ContinuousBatcher:
         """Have the pool cover every active slot's next write
         positions pos..pos+span (PagePool.grow), by the host's books,
         and push the tables if a row changed. Under overcommit a dry
-        pool first lands the step in flight, which may give pages
+        pool first lands what is unread (the step in flight, the
+        first tokens of this call's prefills), which may give pages
         back, then preempts a victim (whose slot the loop then
         skips), and asks again each time."""
         changed = False
@@ -1580,9 +1711,9 @@ class ContinuousBatcher:
                         len(req.prompt) + req.max_new_tokens)
                     break
                 except kv_pages.PoolDry:
-                    # The step in flight may finish a request and
-                    # give its pages back: land it, then ask again,
-                    # before anybody is evicted.
+                    # What is unread may finish a request and give
+                    # its pages back: land it, then ask again, before
+                    # anybody is evicted.
                     if not self._settle("preempt"):
                         self._preempt(exclude=i)
         if changed:
@@ -1593,10 +1724,10 @@ class ContinuousBatcher:
         (cheapest re-prefill), reclaim its pages, and re-queue its
         request AT THE HEAD with its generated-so-far tokens so
         resumption re-prefills prompt+generated and continues — the
-        greedy continuation is unchanged. No step is in flight here
-        (_grow_pages settles before it evicts), so the victim's
-        generated list is all it was served. Returns the victim
-        index."""
+        greedy continuation is unchanged. Nothing is unread here
+        (_grow_pages settles before it evicts: the step in flight and
+        every first token have landed), so the victim's generated
+        list is all it was served. Returns the victim index."""
         candidates = [
             j for j in range(self.num_slots)
             if j != exclude and self._slots[j].request is not None]
@@ -1842,6 +1973,16 @@ class ContinuousBatcher:
                  seat.prefix_len, len(tokens)))
 
     def _admit(self) -> None:
+        """Seat queued requests in free slots: for each, the pool's
+        seat and the puts ("admit"), the prefill program dispatched
+        onto the cache as it stands, which may be the result of a
+        decode step still in flight or of the prefill before this one
+        (the runtime orders them by the cache each consumes: no
+        settle), the seat program behind it (_seat_first), and the
+        slot record with the first token counted in flight. Nothing
+        here reads from the device: the first tokens are landed by
+        whoever lands the unread results next (_step after it has
+        dispatched the decode step, or a settle)."""
         if self.draining:
             # Drain ladder: no new admissions once the preempt/evict
             # notice lands — active slots finish, the queue was
@@ -1855,10 +1996,10 @@ class ContinuousBatcher:
             if slot.request is not None or not self._queue:
                 continue
             # Per request: "admit" is the host's work to seat it
-            # (deferral, the pool's seat, the puts), "prefill" runs
-            # from the dispatch of the prefill program to its first
-            # token on the host, "slot_update" writes the slot's
-            # device-side state.
+            # (deferral, the pool's seat, the puts), "prefill" the
+            # dispatch of the prefill program (and, in _land_first,
+            # the wait for its first token), "slot_update" the seat
+            # program's dispatch and the slot record.
             with phases("admit"):
                 entry = self._queue[0]
                 req = entry.request
@@ -1871,7 +2012,6 @@ class ContinuousBatcher:
                 # what they had already generated, in one pass.
                 tokens = req.prompt + entry.resumed
                 prompt = self._padded(tokens)
-                t0 = time.monotonic()
                 seat = None
                 if self.pages is not None:
                     seat = self.pages.seat(
@@ -1888,10 +2028,12 @@ class ContinuousBatcher:
                 self._admitted.append({
                     "request_id": req.request_id, "path": path,
                     "bucket": bucket, "tokens": prefilled})
-            # The prefill blocks on its first token: nobody's decoded
-            # token waits that out unread (it is ready long before).
-            self._settle("admit")
             with phases("prefill"):
+                self.prefills += 1
+                # behind a decode step in flight, or behind another
+                # prefill of this call
+                self.prefills_overlapped += bool(self._unread)
+                t0 = time.monotonic()
                 self.cache, last_logits, *chosen = prefill(
                     self.params, self.cache, *prefill_args)
                 if seat is not None:
@@ -1906,30 +2048,20 @@ class ContinuousBatcher:
                     self._draft_cache, _ = self._draft_prefill(
                         self._draft_params, self._draft_cache, i,
                         prompt, len(tokens))
-                self._key, sample_key = jax.random.split(self._key)
-                first = inf._sample(
-                    last_logits[None].astype(jnp.float32), sample_key,
-                    self.sampling)
-                first_token = int(first[0])
-                if chosen:
-                    # the positions the prefill ran, past any prefix
-                    # it took from shared pages
-                    self._decisions[req.request_id] = {
-                        "first": len(tokens) - prefilled,
-                        "prefill": np.asarray(chosen[0])[
-                            :, :prefilled].astype(np.int16),
-                        "steps": []}
             with phases("slot_update"):
                 # The prefill-sampled token IS the next generated
-                # token.
+                # token: the device seats it, the books count it in
+                # flight until _land_first has read it.
+                (self._key, self._tokens, self._positions,
+                 first) = self._seat_first(
+                    last_logits, self._key, self._tokens,
+                    self._positions, i, len(tokens))
                 self._slots[i] = _Slot(
-                    request=req,
-                    generated=entry.resumed + [first_token])
-                self._emit([(req.request_id, first_token,
-                             len(entry.resumed))])
-                self._tokens = self._tokens.at[i, 0].set(first[0])
-                self._positions = self._positions.at[i].set(
-                    len(tokens))
-                # int(first[0]) above forced the prefill to complete,
-                # so t0..now is a faithful admission-stall sample.
-                self._record_prefill_time((path, bucket), t0, bucket)
+                    request=req, generated=list(entry.resumed),
+                    in_flight=1)
+                self._unread.append(_FirstToken(
+                    first, i, (path, bucket), t0, *chosen,
+                    prefilled=prefilled,
+                    # the positions the prefill ran, past any prefix
+                    # it took from shared pages
+                    first_position=len(tokens) - prefilled))

@@ -57,3 +57,26 @@ def recorder(tmp_path, monkeypatch):
             return [json.loads(line) for line in fh]
 
     return rows
+
+
+@pytest.fixture()
+def serialised():
+    """-> a function that makes a ContinuousBatcher wait for every
+    result before the device is handed the next program: each call
+    lands what is unread, admits, lands the first tokens, and only
+    then dispatches its decode step (the order before a prefill went
+    behind the step in flight). The same programs with the same keys
+    in the same order; with a slot for every request the same
+    schedule too."""
+    def serialise(engine):
+        admit = engine._admit
+
+        def serial_admit():
+            engine._land_unread()
+            admit()
+            engine._land_unread()
+
+        engine._admit = serial_admit
+        return engine
+
+    return serialise

@@ -440,6 +440,100 @@ def test_a_cancelled_request_leaves_no_record(share):
     assert not engine._decisions and not engine._decisions_done
 
 
+# ------------------- (g) prefills behind the step in flight
+
+
+def _behind_a_step(share, sampling, late, prepare=None):
+    """One request decoding, then ``late`` more submitted before ONE
+    call, a slot for each. -> (engine, served, records)."""
+    _file, _dims, config, params = share
+    engine = _engine(config, params, seed=3, sampling=sampling)
+    if prepare is not None:
+        prepare(engine)
+    (first, prompt), *others = _prompts(1 + late, seed=5).items()
+    engine.submit(Request(first, prompt, max_new_tokens=11))
+    engine.step()
+    engine.step()
+    for n, (request_id, prompt) in enumerate(others):
+        engine.submit(Request(request_id, prompt,
+                              max_new_tokens=4 + 3 * n))
+    done = {}
+    while engine.pending():
+        for request_id, tokens in engine.step():
+            done[request_id] = tokens
+    return engine, done, {r: engine.take_decisions(r) for r in done}
+
+
+@pytest.mark.parametrize("late", [2, 3])
+@pytest.mark.parametrize("sampling", ["greedy", "sampled"])
+def test_prefills_behind_a_step_serve_the_serial_orders_tokens(
+        share, sampling, late, serialised):
+    """A stateful, routed engine: two or three prefills dispatched in
+    one call behind the decode step in flight, their first tokens
+    seated on the device, serve token for token what the serial order
+    serves, sampled streams too (the key is split in the same order),
+    and take_decisions hands over the same record, the prefill's
+    rows among it."""
+    config = inf.SamplingConfig() if sampling == "greedy" else \
+        inf.SamplingConfig(temperature=0.8, top_k=12)
+    engine, done, records = _behind_a_step(share, config, late)
+    serial, want, want_records = _behind_a_step(share, config, late,
+                                                serialised)
+    stats = engine.step_stats()
+    assert (stats["prefills"], stats["prefills_overlapped"]) == (
+        1 + late, late)
+    assert stats["steps_overlapped"] == stats["decode_steps"] - 1
+    assert sum(stats["settles"].values()) == 1 == \
+        stats["settles"]["idle"]
+    assert serial.step_stats()["steps_overlapped"] == 0
+    assert done == want and len(done) == 1 + late
+    prompts = _prompts(1 + late, seed=5)
+    for request_id, record in records.items():
+        twin = want_records[request_id]
+        assert record["first"] == twin["first"] == 0
+        assert list(record["layers"]) == share.layers("experts")
+        positions = len(prompts[request_id]) + len(done[request_id]) - 1
+        for name, rows in record["layers"].items():
+            assert rows.shape == (positions, 2)
+            np.testing.assert_array_equal(rows, twin["layers"][name])
+        if sampling == "greedy":
+            gaps, slack = _judged(share, prompts[request_id],
+                                  done[request_id], record)
+            assert gaps.max() < GAP and slack.max() < SLACK
+
+
+def test_a_first_token_that_ends_its_request_hands_over_its_record(
+        share):
+    """max_new_tokens 1 beside a request in flight: the prefill's
+    choices are on record when the first token lands and finishes the
+    request, the decode step behind it never seats the slot, and the
+    expert counters count landed decode steps only."""
+    _file, _dims, config, params = share
+    engine = _engine(config, params)
+    prompts = _prompts(2, seed=9)
+    engine.submit(Request("r0", prompts["r0"], max_new_tokens=8))
+    engine.step()
+    engine.step()
+    pairs = engine.step_stats()["expert_pairs_chosen"]
+    engine.submit(Request("r1", prompts["r1"], max_new_tokens=1))
+    (finished,) = engine.step()
+    assert finished[0] == "r1" and len(finished[1]) == 1
+    record = engine.take_decisions("r1")
+    assert record["first"] == 0
+    for rows in record["layers"].values():
+        assert rows.shape == (len(prompts["r1"]), 2)
+    # the call landed ONE decode step, with r0 alone in it
+    layers = len(share.layers("experts"))
+    assert engine.step_stats()["expert_pairs_chosen"] == \
+        pairs + 2 * layers
+    alone = _serve(_engine(config, params), {"r1": prompts["r1"]},
+                   {"r1": 1})
+    assert alone["r1"] == finished[1]
+    while engine.pending():
+        engine.step()
+    assert engine.step_stats()["overshoot_tokens"] == 0
+
+
 # ------------------- the state per slot, the pool, the counters
 
 

@@ -596,3 +596,82 @@ def test_step_rows_are_head_sampled_and_the_counters_are_not(
     # an engine with nothing to seat or decode writes nothing
     engine.step()
     assert engine.steps_total == 14 and len(recorder()) == 6
+
+
+# ------------- the first token, sampled and seated on the device ---------
+
+@pytest.mark.parametrize("sampling", [
+    inf.SamplingConfig(),
+    inf.SamplingConfig(temperature=0.9, top_k=20),
+    inf.SamplingConfig(temperature=1.3)])
+def test_the_seat_program_is_the_eager_sample_and_scatters(sampling):
+    """serving._seat_first against the eager ops it replaces: the key
+    split as _admit split it, the same token from the same sample key,
+    the same two scatters; the slot and the length are traced, so one
+    compilation serves every slot."""
+    rng = np.random.RandomState(3)
+    tokens = jnp.asarray(rng.randint(0, 97, (5, 1)), jnp.int32)
+    positions = jnp.asarray(rng.randint(0, 40, (5,)), jnp.int32)
+    key = jax.random.PRNGKey(11)
+    before = serving._seat_first._cache_size()
+    for slot, length in ((3, 17), (0, 4), (4, 33)):
+        logits = jnp.asarray(rng.randn(97), jnp.float32)
+        want_key, sample_key = jax.random.split(key)
+        want = inf._sample(logits[None], sample_key, sampling)
+        key, tokens_out, positions_out, first = serving._seat_first(
+            sampling, logits, key, tokens, positions, slot, length)
+        np.testing.assert_array_equal(key, want_key)
+        np.testing.assert_array_equal(first, want)
+        assert first.shape == (1,) and first.dtype == jnp.int32
+        np.testing.assert_array_equal(
+            tokens_out, tokens.at[slot, 0].set(want[0]))
+        np.testing.assert_array_equal(
+            positions_out, positions.at[slot].set(length))
+        tokens, positions = tokens_out, positions_out
+    assert serving._seat_first._cache_size() == before + 1
+
+
+def test_the_speculative_engine_lands_first_tokens_before_its_step(
+        params):
+    """No step is ever in flight with a draft model: the first tokens
+    of a call's admissions land right after _admit, through the same
+    code, before the serial draft/verify round, and nothing is unread
+    between calls."""
+    rng = np.random.RandomState(5)
+    requests = [
+        serving.Request(f"s{i}", list(rng.randint(0, 97, (4 + 3 * i,))),
+                        max_new_tokens=new)
+        for i, new in enumerate((7, 1, 5))]
+    engine = serving.ContinuousBatcher(
+        CFG, params, num_slots=2, max_decode_len=64,
+        speculative=serving.SpeculativeConfig(CFG, params, gamma=2))
+    landed: list = []
+    land_first = engine._land_first
+
+    def watched(first):
+        landed.append((engine._slots[first.slot].request.request_id,
+                       engine.spec_rounds))
+        return land_first(first)
+
+    engine._land_first = watched
+    for req in requests:
+        engine.submit(req)
+    results = {}
+    for _ in range(50):
+        for rid, toks in engine.step():
+            results[rid] = toks
+        assert not engine._unread
+        if not engine.pending():
+            break
+    for req in requests:
+        assert results[req.request_id] == reference_greedy(
+            params, req.prompt, req.max_new_tokens)
+    # s0 and s1 in the first call, before any round; s2 takes the
+    # slot s1's one token freed, a call later
+    assert [rid for rid, _ in landed] == ["s0", "s1", "s2"]
+    assert [rounds for _, rounds in landed][:2] == [0, 0]
+    stats = engine.step_stats()
+    assert stats["decode_steps"] == stats["steps_overlapped"] == 0
+    assert (stats["prefills"], stats["prefills_overlapped"]) == (3, 1)
+    assert not any(stats["settles"].values())
+    assert stats["overshoot_tokens"] == 0

@@ -371,6 +371,32 @@ def test_serving_steps_update_the_pool_in_place_on_v5e(v5e_devices,
     assert touching.get("fusion", 0) == writes, touching
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_the_seat_program_compiles_small_for_v5e(v5e_devices,
+                                                 temperature):
+    """serving._seat_first behind a prefill at the hybrid cells'
+    sizes (96 slots, 65,536 float32 logits), compiled for the v5e:
+    it holds the logits once more at most and touches nothing else
+    of size."""
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import serving
+
+    chip = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+
+    def arg(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = serving._seat_first.lower(
+        inf.SamplingConfig(temperature=temperature, top_k=40),
+        arg((65536,), jnp.float32), arg((2,), jnp.uint32),
+        arg((96, 1)), arg((96,)), 0, 0).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4 * 65536 * 4
+    key, tokens, positions, first = compiled.out_info
+    assert (key.shape, tokens.shape, positions.shape, first.shape) == (
+        (2,), (96, 1), (96,), (1,))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
         v5e_devices, program):
