@@ -449,6 +449,15 @@ class ServingRouter:
                     replica = to_replica
                     failed_urls.add(replica.url)
                     # loop: relay from the sibling
+                if outcome == "timeout":
+                    # The run may still be live on the (slow)
+                    # replica: keep ownership — duplicate gate +
+                    # sticky cancel stay correct — and let orphan
+                    # reconciliation release the id once the
+                    # replica forgets it (ADVICE r5). Before the
+                    # client is told: whoever sees the stream end
+                    # finds the id orphaned already.
+                    router._orphan_inflight(replica, request_id)
                 try:
                     if client_ok:
                         if outcome == "timeout":
@@ -459,14 +468,7 @@ class ServingRouter:
                         self.wfile.write(b"0\r\n\r\n")
                 except (BrokenPipeError, ConnectionResetError):
                     pass
-                if outcome == "timeout":
-                    # The run may still be live on the (slow)
-                    # replica: keep ownership — duplicate gate +
-                    # sticky cancel stay correct — and let orphan
-                    # reconciliation release the id once the
-                    # replica forgets it (ADVICE r5).
-                    router._orphan_inflight(replica, request_id)
-                elif outcome in ("final", "client_gone"):
+                if outcome in ("final", "client_gone"):
                     # A vanished client doesn't fail the replica —
                     # its engine finishes the run on its own.
                     if resumes and outcome == "final":

@@ -50,7 +50,8 @@ import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
-from batch_shipyard_tpu.models.serving import ContinuousBatcher, Request
+from batch_shipyard_tpu.models.serving import (
+    PHASE_PREFIX, ContinuousBatcher, Request)
 from batch_shipyard_tpu.trace import spans as trace_spans
 from batch_shipyard_tpu.trace.histogram import LatencyHistogram
 from batch_shipyard_tpu.utils import util
@@ -517,6 +518,10 @@ class ServingFrontEnd:
         # request spans below: the head starts over with this front
         # end.
         engine.traced_steps = 0
+        # ... and so does what shutdown() logs of the engine's launch
+        # counters (a warm-up's compiles are not this front end's;
+        # an engine that keeps no counters has none to log).
+        self._launches_before = getattr(engine, "step_stats", dict)()
         self._submit_q: "queue.Queue[_Pending]" = queue.Queue()
         self._inflight: dict[str, _Pending] = {}
         self._inflight_lock = threading.Lock()
@@ -532,6 +537,13 @@ class ServingFrontEnd:
         # is single-threaded by design; cancel mutates slot state).
         self._cancel_q: "queue.Queue[str]" = queue.Queue()
         self._stop = threading.Event()
+        # The engine thread's wait for work, in the engine's own
+        # annotation family (serve:no_work): on a device trace's
+        # clock the engine's dry spells have a name from inside the
+        # program. No phase of a step; the untraced twin is the
+        # engine's no_work_seconds.
+        self._waiting = trace_spans.PhaseTimer(PHASE_PREFIX,
+                                               ("no_work",))
         # Live client sockets (handler setup/finish): kill() severs
         # them all to reproduce the SIGKILL failure shape — streams
         # end in a reset/bare EOF with no drain marker and no final
@@ -784,9 +796,33 @@ class ServingFrontEnd:
             "deferred, %d streams dropped for their backlog",
             writer.handovers, writer.tokens_written,
             writer.sends_deferred, writer.dropped_backlog)
+        # The device's timeline as the engine's landings gave it,
+        # since this front end took the engine over: an untraced
+        # run's stderr keeps it (docs/32-tracing.md).
+        before = self._launches_before
+        if "launches" in before:
+            self._log_launches(self.engine.step_stats(), before)
         # Spans still buffered (trace/spans.py) reach the file before
         # whoever shut this down reads it.
         trace_spans.flush()
+
+    @staticmethod
+    def _log_launches(now: dict, before: dict) -> None:
+        def since(name, kind=None):
+            if kind is None:
+                return now[name] - before[name]
+            return now[name][kind] - before[name][kind]
+
+        logger.info(
+            "engine launches: %s; prefill tokens %d of %d padded; no "
+            "work %.3f s; %d stalls",
+            ", ".join(
+                f"{kind} {since('launches', kind)} "
+                f"({since('launch_seconds', kind):.3f} s, "
+                f"{since('landings_ready', kind)} found ready)"
+                for kind in now["launches"]),
+            since("prefill_tokens"), since("prefill_bucket_tokens"),
+            since("no_work_seconds"), since("stalls"))
 
     def kill(self) -> None:
         """The SIGKILL failure shape (chaos drills): stop the engine,
@@ -1208,6 +1244,24 @@ class ServingFrontEnd:
                 "shipyard_serving",
                 {"step_phase_seconds_total": seconds},
                 labels={"phase": phase}))
+        # The device's timeline beside the host's phases: launches
+        # landed, the seconds they held the device's queue and the
+        # landings that found the device ahead of the host, by kind.
+        for kind, count in engine["launches"].items():
+            lines.extend(prometheus_lines("shipyard_serving", {
+                "launches_total": count,
+                "launch_seconds_total":
+                    engine["launch_seconds"][kind],
+                "landings_ready_total":
+                    engine["landings_ready"][kind],
+            }, labels={"kind": kind}))
+        lines.extend(prometheus_lines("shipyard_serving", {
+            "prefill_bucket_tokens_total":
+                engine["prefill_bucket_tokens"],
+            "prefill_tokens_total": engine["prefill_tokens"],
+            "no_work_seconds_total": engine["no_work_seconds"],
+            "stalls_total": engine["stalls"],
+        }))
         for metric in ("ttft_ms", "tpot_ms"):
             for pct, value in stats[metric].items():
                 lines.extend(prometheus_lines(
@@ -1405,6 +1459,12 @@ class ServingFrontEnd:
             "prefills_overlapped": steps["prefills_overlapped"],
             "settles": steps["settles"],
             "overshoot_tokens": steps["overshoot_tokens"],
+            # the device's timeline from the engine's own landings
+            # (ContinuousBatcher._launch_counts)
+            **{name: steps[name] for name in (
+                "launches", "launch_seconds", "landings_ready",
+                "prefill_bucket_tokens", "prefill_tokens",
+                "no_work_seconds", "stalls")},
             # a routed model's expert counters (absent otherwise)
             **{name: steps[name] for name in (
                 "expert_pairs_here", "expert_pairs_chosen",
@@ -1488,7 +1548,9 @@ class ServingFrontEnd:
             # would throttle every active request's TPOT.
             if not self.engine.pending():
                 try:
-                    self._submit(self._submit_q.get(timeout=0.2))
+                    with self._waiting("no_work"):
+                        item = self._submit_q.get(timeout=0.2)
+                    self._submit(item)
                 except queue.Empty:
                     # Idle: the last rows of a burst would otherwise
                     # sit in the recorder's buffer until the next one.

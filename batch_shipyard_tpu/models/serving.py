@@ -24,11 +24,16 @@ import collections
 import contextlib
 import dataclasses
 import functools
+import gc
 import itertools
+import json
+import os
 import random
+import resource
+import threading
 import time
 import uuid
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,18 +43,37 @@ from batch_shipyard_tpu.models import inference as inf
 from batch_shipyard_tpu.models import kv_pages
 from batch_shipyard_tpu.models import transformer as tfm
 from batch_shipyard_tpu.trace import spans as trace_spans
+from batch_shipyard_tpu.utils import util
+
+logger = util.get_logger(__name__)
 
 # The leaf phases of one engine step, in the order a step runs them
 # (docs/32-tracing.md): each is a ``serve:<phase>`` annotation in a
 # profiler trace and a ``<phase>_ms`` attr of the step's row.
 STEP_PHASES = ("admit", "prefill", "slot_update", "grow_pages",
                "dispatch", "readback", "emit")
+# The annotations' common prefix. Whoever drives the engine puts its
+# own wait for work under it as ``serve:no_work`` (server.py), which
+# is no phase of a step.
+PHASE_PREFIX = "serve:"
 # Why a decode step in flight was read back BEFORE its successor was
 # dispatched (ContinuousBatcher._settle), so that the successor did
 # not overlap it: a dry pool, a cancel, a drain, or nothing left to
 # dispatch. An admission is none: its prefill is dispatched behind
 # the step in flight (_admit).
 SETTLE_CAUSES = ("preempt", "cancel", "drain", "idle")
+# The two kinds of launch the engine lands (Launch.kind): a decode
+# step (a draft/verify block of the speculative engine is one) and a
+# prefill with its seat program.
+LAUNCH_KINDS = ("decode", "prefill")
+# A landing whose launch held the head of the device's queue for
+# longer than this writes a serve_stall record (_stalled); a call of
+# step() of which more than a quarter of it lies under no landing
+# writes the host form. Sound steps and prefills take 10-100 ms, the
+# stalls this is for took 1.8-10 s (docs/32-tracing.md).
+STALL_MS = 1000.0
+# The launch records a stall record carries: the last this many.
+LAUNCH_RING = 64
 # What a step program lets the model write: the cache, and the expert
 # choices a routed layer sows (nothing, for a model without one).
 _MUTABLE = ["cache", "decisions"]
@@ -567,13 +591,15 @@ class _InFlight:
     """A decode step the device was handed whose tokens the host has
     not read: the [B] token array (and the step's key, which dies
     with it), the (slot, request) pairs the step advances, taken at
-    dispatch, when that was, and what the step's routed layers chose.
-    It waits in ContinuousBatcher._unread, in dispatch order with the
-    first tokens of prefills (_FirstToken), to be landed by _land."""
+    dispatch, when that was, how many results were unread then, and
+    what the step's routed layers chose. It waits in
+    ContinuousBatcher._unread, in dispatch order with the first
+    tokens of prefills (_FirstToken), to be landed by _land."""
     tokens: object
     key: object
     seated: list[tuple[int, Request]]
     dispatched_at: float
+    queued: int
     # The routed layers' choices of this step, int32 [decision layers,
     # B, k] on the device; None for a model without such layers.
     chosen: object = None
@@ -584,13 +610,15 @@ class _FirstToken:
     """A prefill the device was handed whose first token the host has
     not read: the int32 [1] token array of the seat program
     (_seat_first), the slot it was seated in (whose request stays
-    until the token has landed), the (path, bucket) its time is
-    recorded under, when it was dispatched, and what the prefill's
-    routed layers chose."""
+    until the token has landed), the path and bucket its launch is
+    recorded under, when it was dispatched, how many results were
+    unread then, and what the prefill's routed layers chose."""
     token: object
     slot: int
-    timed: tuple[str, int]
+    path: str
+    bucket: int
     dispatched_at: float
+    queued: int
     # The prefill's choices, int32 [decision layers, bucket, k] on the
     # device, of which the first ``prefilled`` positions are the
     # prompt's own, from position ``first_position`` on (past a
@@ -598,6 +626,97 @@ class _FirstToken:
     chosen: object = None
     prefilled: int = 0
     first_position: int = 0
+
+
+@dataclasses.dataclass(slots=True)
+class Launch:
+    """One landed launch, as the engine saw it: what _land,
+    _land_first and the speculative step hand to
+    ContinuousBatcher._landed, the one record the counters, the
+    admission estimates, a serve_step row's ``landed`` list and the
+    stall ring are made from (docs/32-tracing.md).
+
+    One launch is always in flight and the host blocks on the oldest
+    unread result, so ``period_ms``, the time from the later of the
+    launch's dispatch and the landing before it to its own landing,
+    is how long the launch held the head of the device's queue AS THE
+    HOST SAW IT: the device's time for it where the host was waiting
+    (``ready`` false), and that plus the host's lateness where the
+    device had finished before the host came to ask (``ready`` true,
+    asked of the result once, just before the blocking read).
+    ``behind_ms`` is how long the launch waited on the device's queue
+    behind what was dispatched before it, ``queued`` how many results
+    were unread when it was dispatched. Times are time.monotonic()."""
+    kind: str                   # one of LAUNCH_KINDS
+    dispatched_at: float
+    landed_at: float
+    period_ms: float
+    behind_ms: float
+    ready: bool
+    queued: int
+    rows: int = 0               # decode: the slots the step advanced
+    path: str = ""              # prefill: cold/shared/recomputed/dense
+    bucket: int = 0             # prefill: padded tokens
+    tokens: int = 0             # prefill: unpadded tokens
+    request_id: str = ""        # prefill
+
+    @classmethod
+    def landing(cls, kind: str, dispatched_at: float, previous: float,
+                ready: bool, queued: int, **what) -> "Launch":
+        """The record of a launch that lands now, ``previous`` being
+        when the launch before it landed."""
+        now = time.monotonic()
+        return cls(kind, dispatched_at, now,
+                   (now - max(dispatched_at, previous)) * 1e3,
+                   max(0.0, previous - dispatched_at) * 1e3,
+                   ready, queued, **what)
+
+    def entry(self) -> dict:
+        """What a row's ``landed`` list and a stall record's ring
+        hold of it."""
+        out = {"kind": self.kind, "landed_at": self.landed_at,
+               "period_ms": self.period_ms,
+               "behind_ms": self.behind_ms, "ready": self.ready,
+               "queued": self.queued}
+        if self.kind == "decode":
+            out["rows"] = self.rows
+        else:
+            out.update(path=self.path, bucket=self.bucket,
+                       tokens=self.tokens,
+                       request_id=self.request_id)
+        return out
+
+
+def _ewma(estimate: Optional[float], sample: float) -> float:
+    """The estimate moved three tenths of the way to the sample (the
+    sample itself where there was none)."""
+    return sample if estimate is None else \
+        0.7 * estimate + 0.3 * sample
+
+
+class _HostCounters(NamedTuple):
+    """What a stall record takes deltas of, read at every landing (a
+    microsecond each): the clock, the calling thread's CPU seconds,
+    the process's user and system seconds, involuntary context
+    switches and major faults, the collections of each gc generation
+    and the compile counter's count."""
+    at: float
+    thread_cpu_s: float
+    ru_utime_s: float
+    ru_stime_s: float
+    ru_nivcsw: int
+    ru_majflt: int
+    gc_collections: tuple
+    compiles: int
+
+    @classmethod
+    def read(cls, compiles) -> "_HostCounters":
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        return cls(time.monotonic(), time.thread_time(),
+                   usage.ru_utime, usage.ru_stime, usage.ru_nivcsw,
+                   usage.ru_majflt,
+                   tuple(gen["collections"] for gen in gc.get_stats()),
+                   compiles.read()[0])
 
 
 @dataclasses.dataclass
@@ -792,14 +911,14 @@ class ContinuousBatcher:
         self.draining = False
         self._prefill_ms_per_token: Optional[float] = None
         self._step_ms: Optional[float] = None
-        self._timed_buckets: set = set()
-        self._step_samples = 0
+        # the (path, bucket) whose first landing, a compile, is past
+        self._prefill_shapes: set = set()
         # Step tracing: per-phase seconds and step counts are always
         # on (a few float adds a step); a serve_step row is written
         # only while the process-local span recorder is switched on
         # ($SHIPYARD_TRACE_FILE), head-sampled by traced_steps, which
         # a front end resets when it takes the engine over.
-        self._phases = trace_spans.PhaseTimer("serve:", STEP_PHASES)
+        self._phases = trace_spans.PhaseTimer(PHASE_PREFIX, STEP_PHASES)
         self._compiles = trace_spans.compile_counter()
         self.steps_total = 0
         self.step_seconds_total = 0.0
@@ -811,7 +930,28 @@ class ContinuousBatcher:
         # result landed, and the counters (step_stats).
         self._unread: collections.deque = collections.deque()
         self._finished: list[tuple[str, list[int]]] = []
-        self._landed_at = 0.0
+        # The device's timeline from the engine's own landings
+        # (_landed), always on: when the last launch landed, the
+        # cumulative counters by kind, the last LAUNCH_RING records
+        # and the host's counters as of the last landing (for a
+        # stall record), the launches landed and the dry seconds
+        # ended since the last step() that did anything (for its
+        # row), and since when the device has had nothing to run
+        # (None while it has: _settle, _dispatching).
+        self._landed_at = time.monotonic()
+        self.launches = dict.fromkeys(LAUNCH_KINDS, 0)
+        self.launch_seconds = dict.fromkeys(LAUNCH_KINDS, 0.0)
+        self.landings_ready = dict.fromkeys(LAUNCH_KINDS, 0)
+        self.prefill_bucket_tokens = 0
+        self.prefill_tokens = 0
+        self.no_work_seconds = 0.0
+        self.stalls = 0
+        self._ring: collections.deque = collections.deque(
+            maxlen=LAUNCH_RING)
+        self._host_then = _HostCounters.read(self._compiles)
+        self._landed_call: list[Launch] = []
+        self._no_work_call = 0.0
+        self._dry_since: Optional[float] = self._landed_at
         self.decode_steps = 0
         self.steps_overlapped = 0
         self.prefills = 0
@@ -823,7 +963,6 @@ class ContinuousBatcher:
         # slow.
         self._row_ids = itertools.count(random.getrandbits(32))
         self._admitted: list[dict] = []
-        self._step_tokens = 0
         self.model = tfm.TransformerLM(self.config)
         self.device = device
         on_device = (jax.default_device(device) if device is not None
@@ -1213,9 +1352,11 @@ class ContinuousBatcher:
         phases = self._phases
         phases.reset()
         self._admitted.clear()
-        self._step_tokens = 0
         traced = trace_spans.local_spans_path() is not None
         wall0, t0 = time.time(), time.monotonic()
+        # for a stall record of the host form (_stalled)
+        landed0, stalls0 = len(self._landed_call), self.stalls
+        host_then = self._host_then
         before = compiles0 = lookahead0 = None
         if traced and (self.traced_steps < self._STEP_HEAD or
                        (self.traced_steps + 1)
@@ -1226,6 +1367,8 @@ class ContinuousBatcher:
                           **self._expert_counts()}
         self._step()
         seconds = time.monotonic() - t0
+        if seconds * 1e3 > STALL_MS / 4 and self.stalls == stalls0:
+            self._host_stalled(seconds * 1e3, landed0, host_then)
         finished, self._finished = self._finished, []
         if not self._admitted and \
                 phases.step.keys().isdisjoint(("dispatch", "readback")):
@@ -1237,6 +1380,10 @@ class ContinuousBatcher:
         if before is not None:
             self._record_step_row(wall0, t0, seconds, before,
                                   compiles0, lookahead0, len(finished))
+        # What a settle outside step() lands (cancel, drain) stays
+        # for the next row.
+        self._landed_call.clear()
+        self._no_work_call = 0.0
         return finished
 
     def _lookahead_counts(self) -> dict:
@@ -1265,10 +1412,13 @@ class ContinuousBatcher:
         attrs["prefills_overlapped"] = (
             self.prefills_overlapped
             - lookahead0["prefills_overlapped"])
-        attrs["prefill_tokens"] = sum(a["tokens"]
-                                      for a in self._admitted)
         attrs["admitted"] = list(self._admitted)
-        attrs["tokens_emitted"] = self._step_tokens
+        # The launches this call landed (and a cancel or a drain
+        # since the last row), oldest first, and the dry spell, if
+        # any, that its first dispatch ended.
+        attrs["landed"] = [launch.entry()
+                           for launch in self._landed_call]
+        attrs["no_work_seconds"] = self._no_work_call
         attrs["finished"] = finished
         # What this call added to the lookahead's counters: whether
         # its decode step overlapped the one before, why not (the
@@ -1327,7 +1477,9 @@ class ContinuousBatcher:
             seated = self._decoding()
             if seated:
                 self._push_active(seated)
-                self._finished += self._step_speculative()
+                self._finished += self._step_speculative(len(seated))
+            if not any(s.request is not None for s in self._slots):
+                self._settle("idle")    # nothing unread: a dry spell
             return
         if self.pages is not None:
             with phases("grow_pages"):
@@ -1339,6 +1491,7 @@ class ContinuousBatcher:
             return
         overlapped = self._in_flight is not None
         t0 = time.monotonic()
+        self._dispatching(t0)
         with phases("dispatch"):
             self._push_active(seated)
             self._key, step_key = jax.random.split(self._key)
@@ -1346,8 +1499,9 @@ class ContinuousBatcher:
              *chosen) = self._decode_step(
                 self.params, self.cache, self._tokens,
                 self._positions, self._active, step_key)
-            self._unread.append(_InFlight(next_tok, step_key, seated,
-                                          t0, *chosen))
+            self._unread.append(_InFlight(
+                next_tok, step_key, seated, t0, len(self._unread),
+                *chosen))
             del next_tok, step_key, chosen  # _land lets the arrays die
             for i, _ in seated:
                 self._slots[i].in_flight += 1
@@ -1367,13 +1521,26 @@ class ContinuousBatcher:
         request: what everything that edits slots outside _step's
         own order calls first. ``cause`` is one of SETTLE_CAUSES,
         counted where a decode step was in flight (its successor
-        will not overlap it)."""
-        if not self._unread:
-            return False
-        if self._in_flight is not None:
-            self.settles[cause] += 1
-        self._land_unread()
-        return True
+        will not overlap it). After an ``idle`` one the device has
+        nothing to run, from the last landing until something is
+        dispatched (_dispatching): no_work_seconds."""
+        unread = bool(self._unread)
+        if unread:
+            if self._in_flight is not None:
+                self.settles[cause] += 1
+            self._land_unread()
+        if cause == "idle" and self._dry_since is None:
+            self._dry_since = self._landed_at
+        return unread
+
+    def _dispatching(self, now: float) -> None:
+        """A program is about to be handed to the device: a dry
+        spell, if the device was in one, ends here."""
+        if self._dry_since is not None:
+            dry = now - self._dry_since
+            self.no_work_seconds += dry
+            self._no_work_call += dry
+            self._dry_since = None
 
     def _land_unread(self, keep: int = 0) -> None:
         """Land, in the order the device was handed them, all but the
@@ -1394,20 +1561,19 @@ class ContinuousBatcher:
         "slot_update"'s, as when _admit did both."""
         phases = self._phases
         with phases("prefill"):
+            ready = first.token.is_ready()
             token = int(np.asarray(first.token)[0])
             chosen = (None if first.chosen is None else np.asarray(
                 first.chosen)[:, :first.prefilled].astype(np.int16))
-        # The device's time for this prefill: from the landing before
-        # it, unless it was dispatched later than that.
-        self._record_prefill_time(
-            first.timed, max(first.dispatched_at, self._landed_at),
-            first.timed[1])
-        self._landed_at = time.monotonic()
+        # Nobody else sits here: whatever frees a slot lands what is
+        # unread first.
+        slot = self._slots[first.slot]
+        request_id = slot.request.request_id
+        self._landed(Launch.landing(
+            "prefill", first.dispatched_at, self._landed_at, ready,
+            first.queued, path=first.path, bucket=first.bucket,
+            tokens=first.prefilled, request_id=request_id))
         with phases("slot_update"):
-            # Nobody else sits here: whatever frees a slot lands
-            # what is unread first.
-            slot = self._slots[first.slot]
-            request_id = slot.request.request_id
             slot.in_flight -= 1
             slot.generated.append(token)
             if chosen is not None:
@@ -1433,16 +1599,15 @@ class ContinuousBatcher:
         step behind a prefill dispatched before it (_admit)."""
         phases = self._phases
         with phases("readback"):
+            ready = step.tokens.is_ready()
             next_host = np.asarray(step.tokens)
             # the whole step's choices once; take_decisions cuts a
             # request's column out of them, if anyone asks
             chosen = (None if step.chosen is None
                       else np.asarray(step.chosen).astype(np.int16))
-        # The step PERIOD: from the step before landing, unless this
-        # one was dispatched later than that.
-        self._record_step_time(max(step.dispatched_at,
-                                   self._landed_at))
-        self._landed_at = time.monotonic()
+        self._landed(Launch.landing(
+            "decode", step.dispatched_at, self._landed_at, ready,
+            step.queued, rows=len(step.seated)))
         with phases("emit"):
             tokens = next_host.tolist()
             batch = []
@@ -1478,7 +1643,6 @@ class ContinuousBatcher:
         """Hand the (request_id, token, index) triples a step or a
         prefill produced to the observer: on_tokens ONCE where it is
         set, else on_token a triple."""
-        self._step_tokens += len(batch)
         if not batch:
             return
         if self.on_tokens is not None:
@@ -1487,7 +1651,8 @@ class ContinuousBatcher:
             for triple in batch:
                 self.on_token(*triple)
 
-    def _step_speculative(self) -> list[tuple[str, list[int]]]:
+    def _step_speculative(self, rows: int
+                          ) -> list[tuple[str, list[int]]]:
         """One ragged draft/verify/commit round (see the spec_step
         docstring for the compute): slots advance by different amounts
         per step, so the host bookkeeping below is variable-stride —
@@ -1498,6 +1663,7 @@ class ContinuousBatcher:
             with phases("grow_pages"):
                 self._grow_pages(span=self.gamma)
         t0 = time.monotonic()
+        self._dispatching(t0)
         with phases("dispatch"):
             (self.cache, self._draft_cache, self._tokens,
              self._positions, block, a_slot) = self._spec_step(
@@ -1505,8 +1671,12 @@ class ContinuousBatcher:
                 self._draft_cache, self._tokens, self._positions,
                 self._active)
         with phases("readback"):
+            ready = block.is_ready()
             block_host = np.asarray(block)
-            self._record_step_time(t0)
+            # a draft/verify block is one launch of the ``rows``
+            # slots it advances
+            self._landed(Launch.landing(
+                "decode", t0, self._landed_at, ready, 0, rows=rows))
             a_host = np.asarray(a_slot)
         emitted: list[tuple[str, list[int]]] = []
         batch = []
@@ -1578,11 +1748,30 @@ class ContinuousBatcher:
         return {"steps": self.steps_total,
                 "step_seconds": self.step_seconds_total,
                 **self._lookahead_counts(),
+                **self._launch_counts(),
                 **(self._expert_counts() if self._decision_layers
                    else {}),
                 "phase_seconds": dict(self._phases.total),
                 "compiles": compiles,
                 "compile_seconds": compile_seconds}
+
+    def _launch_counts(self) -> dict:
+        """The device's timeline as the landings gave it (_landed),
+        cumulative: launches landed, the seconds they held the
+        device's queue and the landings that found their result
+        ready (the host, not the device, set their pace), each by
+        kind (LAUNCH_KINDS); the prefills' padded and unpadded
+        tokens; the seconds the device had nothing to run because
+        nothing was there (from an idle settle's last landing, or
+        the engine's construction, to the next dispatch); and the
+        stall records written."""
+        return {"launches": dict(self.launches),
+                "launch_seconds": dict(self.launch_seconds),
+                "landings_ready": dict(self.landings_ready),
+                "prefill_bucket_tokens": self.prefill_bucket_tokens,
+                "prefill_tokens": self.prefill_tokens,
+                "no_work_seconds": self.no_work_seconds,
+                "stalls": self.stalls}
 
     def spec_stats(self) -> Optional[dict]:
         """Speculative-decode counters, or None when no draft model
@@ -1906,33 +2095,94 @@ class ContinuousBatcher:
             return False
         return True
 
-    def _record_prefill_time(self, key, t0: float,
-                             n_tokens: int) -> None:
-        """EWMA prefill cost per bucket token; the first sample of
-        each compile bucket is discarded (it measures jit
-        compilation, not prefill)."""
-        dt_ms = (time.monotonic() - t0) * 1000.0
-        if key not in self._timed_buckets:
-            self._timed_buckets.add(key)
-            return
-        per_token = dt_ms / max(1, n_tokens)
-        if self._prefill_ms_per_token is None:
-            self._prefill_ms_per_token = per_token
-        else:
-            self._prefill_ms_per_token = (
-                0.7 * self._prefill_ms_per_token + 0.3 * per_token)
+    def _landed(self, launch: Launch) -> None:
+        """A launch has landed: the one place its record goes from.
+        The cumulative counters (step_stats), the estimates
+        admission decides with (slo_stats, _should_defer: an EWMA of
+        the decode step's period and one of the prefill's period per
+        bucket token, the first decode sample and the first of each
+        (path, bucket) left out: they measure a compile), the ring,
+        the list the call's row takes, and a stall record where the
+        launch held the device's queue for longer than STALL_MS."""
+        kind = launch.kind
+        self._landed_at = launch.landed_at
+        self.launches[kind] += 1
+        self.launch_seconds[kind] += launch.period_ms / 1e3
+        self.landings_ready[kind] += launch.ready
+        if kind == "prefill":
+            self.prefill_bucket_tokens += launch.bucket
+            self.prefill_tokens += launch.tokens
+            shape = (launch.path, launch.bucket)
+            if shape not in self._prefill_shapes:
+                self._prefill_shapes.add(shape)
+            else:
+                self._prefill_ms_per_token = _ewma(
+                    self._prefill_ms_per_token,
+                    launch.period_ms / max(1, launch.bucket))
+        elif self.launches[kind] > 1:
+            self._step_ms = _ewma(self._step_ms, launch.period_ms)
+        self._ring.append(launch)
+        self._landed_call.append(launch)
+        then, now = self._host_then, _HostCounters.read(self._compiles)
+        self._host_then = now
+        if launch.period_ms > STALL_MS:
+            self._stalled(
+                then, now, kind=kind, ready=launch.ready,
+                wait="prefill" if kind == "prefill" else "readback",
+                launch=launch.entry())
 
-    def _record_step_time(self, t0: float) -> None:
-        """EWMA decode-step wall time (the engine-side TPOT floor);
-        the first sample is discarded as compile."""
-        dt_ms = (time.monotonic() - t0) * 1000.0
-        self._step_samples += 1
-        if self._step_samples == 1:
+    def _host_stalled(self, call_ms: float, landed0: int,
+                      then: _HostCounters) -> None:
+        """The host form of a stall record, for a call of step()
+        that lasted more than STALL_MS / 4 and during which no launch
+        stalled: written where more than that much of the call lies
+        under no landing (a call that lands three long prefills is
+        the device's time, not the host's), with the phase that held
+        the most of it. ``landed0``: how many of _landed_call were
+        there when the call began; ``then``: the host's counters as
+        of the last landing before it."""
+        landed_ms = sum(launch.period_ms
+                        for launch in self._landed_call[landed0:])
+        if call_ms - landed_ms <= STALL_MS / 4:
             return
-        if self._step_ms is None:
-            self._step_ms = dt_ms
-        else:
-            self._step_ms = 0.7 * self._step_ms + 0.3 * dt_ms
+        phase_ms = {name: seconds * 1e3
+                    for name, seconds in self._phases.step.items()}
+        self._stalled(
+            then, _HostCounters.read(self._compiles), kind="host",
+            call_ms=call_ms, landed_ms=landed_ms,
+            phase=max(phase_ms, key=phase_ms.get, default=None),
+            phase_ms=phase_ms)
+
+    def _stalled(self, then: _HostCounters, now: _HostCounters,
+                 **what) -> None:
+        """Write one serve_stall record: ``what`` (the launch that
+        landed late and the wait that ended it, or the call the host
+        was late in), what the host did between ``then`` and ``now``,
+        and the ring. One JSON line to the logger at WARNING, a
+        serve_stall row where the recorder is on, and one more of
+        ``stalls``. An interval in which the process compiled a
+        program is no stall: ``compiles`` and ``compile_seconds``
+        have it. How to read one: docs/32-tracing.md."""
+        if now.compiles > then.compiles:
+            return
+        self.stalls += 1
+        interval_s = now.at - then.at
+        record = {
+            **what, "interval_ms": interval_s * 1e3,
+            # thread_cpu_s near 0: blocked on the runtime; near the
+            # interval: the engine thread was working or spinning
+            **{name: getattr(now, name) - getattr(then, name)
+               for name in ("thread_cpu_s", "ru_utime_s", "ru_stime_s",
+                            "ru_nivcsw", "ru_majflt")},
+            "gc_collections": [a - b for a, b in zip(
+                now.gc_collections, then.gc_collections)],
+            "loadavg": list(os.getloadavg()),
+            "threads": threading.active_count(),
+            "ring": [launch.entry() for launch in self._ring]}
+        logger.warning("serve_stall %s", json.dumps(record))
+        wall = time.time()
+        trace_spans.record(trace_spans.SPAN_SERVE_STALL,
+                           wall - interval_s, wall, **record)
 
     def _padded(self, tokens: list[int]):
         """tokens, zero-padded to their compile bucket: [1, bucket]."""
@@ -2032,8 +2282,10 @@ class ContinuousBatcher:
                 self.prefills += 1
                 # behind a decode step in flight, or behind another
                 # prefill of this call
-                self.prefills_overlapped += bool(self._unread)
+                queued = len(self._unread)
+                self.prefills_overlapped += bool(queued)
                 t0 = time.monotonic()
+                self._dispatching(t0)
                 self.cache, last_logits, *chosen = prefill(
                     self.params, self.cache, *prefill_args)
                 if seat is not None:
@@ -2060,7 +2312,7 @@ class ContinuousBatcher:
                     request=req, generated=list(entry.resumed),
                     in_flight=1)
                 self._unread.append(_FirstToken(
-                    first, i, (path, bucket), t0, *chosen,
+                    first, i, path, bucket, t0, queued, *chosen,
                     prefilled=prefilled,
                     # the positions the prefill ran, past any prefix
                     # it took from shared pages

@@ -56,6 +56,35 @@ def _track(row: dict) -> tuple[str, str]:
     return str(pid), str(tid)
 
 
+def _launch_events(row: dict, step_event: dict) -> list[dict]:
+    """The launches a serve_step row landed (its ``landed`` list), on
+    a track of their own beside the task's engine steps: each from
+    its landing back over its period, so the launches lie back to
+    back where the device was kept busy, as the engine saw it, with
+    no profiler capture. A landing's time.monotonic() is put on the
+    row's time.time() through the row's ``mono_start``."""
+    attrs = row.get("attrs") or {}
+    if "mono_start" not in attrs:
+        return []
+    offset = float(row.get("start", 0.0)) - attrs["mono_start"]
+    events = []
+    for launch in attrs.get("landed", ()):
+        name = launch["kind"] if launch["kind"] == "decode" else \
+            f"prefill {launch['path']} {launch['bucket']}"
+        events.append({
+            **step_event, "name": name,
+            "ts": (launch["landed_at"] + offset) * 1e6
+            - launch["period_ms"] * 1e3,
+            "dur": launch["period_ms"] * 1e3,
+            "tid": f"{row.get('task_id') or '-'} device (as the "
+                   f"engine saw it)",
+            "args": {"trace_id": row.get("trace_id"),
+                     "span_id": f"{row.get('span_id')}.{len(events)}",
+                     "parent_span_id": row.get("span_id"),
+                     **launch}})
+    return events
+
+
 def to_chrome_trace(rows: dict[str, list[dict]],
                     trace_id: str) -> dict[str, Any]:
     """Chrome trace-event JSON object for one trace."""
@@ -83,6 +112,8 @@ def to_chrome_trace(rows: dict[str, list[dict]],
                 },
             }
             events.append(event)
+            if row.get("kind") == trace_spans.SPAN_SERVE_STEP:
+                events.extend(_launch_events(row, event))
     events.sort(key=lambda e: e["ts"])
     return {
         "traceEvents": events,

@@ -105,6 +105,12 @@ SPAN_SERVE_STEP = "serve_step"           # one ContinuousBatcher.step:
                                          # requests it admitted and the
                                          # engine's occupancy as it
                                          # began (docs/32-tracing.md)
+SPAN_SERVE_STALL = "serve_stall"         # a launch whose result the
+                                         # engine got seconds late, or
+                                         # a step() call the host was
+                                         # late in: what the host did
+                                         # meanwhile and the last 64
+                                         # launches (docs/32-tracing.md)
 
 SPAN_KINDS = frozenset({
     SPAN_SUBMIT, SPAN_QUEUE_WAIT, SPAN_CLAIM, SPAN_BACKOFF_WAIT,
@@ -114,7 +120,7 @@ SPAN_KINDS = frozenset({
     SPAN_COMPILE, SPAN_CKPT_SNAPSHOT,
     SPAN_CKPT_PERSIST, SPAN_CKPT_RESTORE, SPAN_PROFILE,
     SPAN_SERVE_REQUEST, SPAN_SERVE_QUEUED, SPAN_SERVE_PREFILL,
-    SPAN_SERVE_DECODE, SPAN_SERVE_STEP,
+    SPAN_SERVE_DECODE, SPAN_SERVE_STEP, SPAN_SERVE_STALL,
 })
 
 
@@ -271,12 +277,14 @@ def flush() -> int:
     return written
 
 
-def record(kind: str, start: float, end: Optional[float] = None,
+def record(kind: str, start: float, /,
+           end: Optional[float] = None,
            parent_span_id: Optional[str] = None,
            span_id: Optional[str] = None,
            **attrs: Any) -> Optional[str]:
     """Process-local emit: one JSONL span for $SHIPYARD_TRACE_FILE
-    (buffered, see above). The trace id comes from the task context
+    (buffered, see above). ``kind`` and ``start`` are positional
+    only, so an attr may be called either. The trace id comes from the task context
     the agent exported; ``parent_span_id`` defaults to the task's own
     span (the run span), so flat program phases chain correctly with
     no caller plumbing. No-op when no sink or no context is

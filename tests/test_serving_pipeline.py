@@ -450,8 +450,10 @@ def test_every_decode_step_overlaps_but_the_first_and_the_settled(
     # a call emits the step BEFORE the one it dispatches and, behind
     # it, its own prefills' first tokens: call 2 has step 1's token,
     # call 3 step 2's and b's first, the last call the last step's two
-    assert [row["tokens_emitted"] for row in rows] == [
-        1, 1, 2, 2, 2, 2, 2, 2, 2, 2]
+    assert [sum(x.get("rows", 1) for x in row["landed"])
+            for row in rows] == [1, 1, 2, 2, 2, 2, 2, 2, 2, 2]
+    assert [[x["kind"] for x in row["landed"]] for row in rows][:3] == \
+        [["prefill"], ["decode"], ["decode", "prefill"]]
     assert [row["finished"] for row in rows] == [
         0, 0, 0, 0, 1, 0, 0, 0, 0, 2]
     assert rows[-1]["dispatch_ms"] == 0 < rows[-1]["readback_ms"]
@@ -719,24 +721,21 @@ def test_an_eos_on_the_first_token_costs_one_overshoot_step(
 
 
 def test_a_prefill_time_sample_is_the_devices_time_for_it(params):
-    """_record_prefill_time is fed from the later of the prefill's
-    dispatch and the landing before it to its token: the sample of a
-    prefill dispatched behind a step in flight does not hold that
-    step's wait."""
-    engine, _observer, _beside, _late, done = _one_decoding(params)
-    samples: list = []
-    record = engine._record_prefill_time
-
-    def watched(key, t0, n_tokens):
-        samples.append((key, t0, engine._landed_at))
-        return record(key, t0, n_tokens)
-
-    engine._record_prefill_time = watched
+    """The prefill's launch record (the sample _should_defer learns
+    from) runs from the later of its dispatch and the landing before
+    it to its token: the sample of a prefill dispatched behind a step
+    in flight does not hold that step's wait."""
+    engine, _observer, _beside, late, done = _one_decoding(params)
     _step(engine, done)
-    ((key, t0, landed_at),) = samples
-    assert key == ("cold", 16)
+    step, prefill = list(engine._ring)[-2:]
+    assert (step.kind, prefill.kind) == ("decode", "prefill")
+    assert (prefill.request_id, prefill.path, prefill.bucket) == \
+        (late.request_id, "cold", 16)
     # the step in flight landed first, after the prefill's dispatch
-    assert t0 == landed_at > 0
+    assert prefill.dispatched_at < step.landed_at < prefill.landed_at
+    assert prefill.period_ms == pytest.approx(
+        (prefill.landed_at - step.landed_at) * 1e3)
+    assert prefill.behind_ms > 0 and prefill.queued == 1
 
 
 # ------------- (h) nothing compiles for the first real admission ---------
