@@ -489,15 +489,12 @@ def dense_experts(rows, chosen, weights, up, down, first: int,
     weights are read once whatever the rows: the least a step can do
     when nearly every held expert is hit (96 rows x 6 of 128 leave one
     held expert in a hundred unchosen); the price is M x E x d x f
-    multiply-adds where k / E of them are wanted. The other road,
-    the (row, choice) pairs sorted by expert and one grouped matmul
-    (jax.lax.ragged_dot), was written first and measured on the v5e:
-    the compiler's grouped matmul takes 6.5 ms a call at 64 groups
-    whatever the rows and copies a whole expert stack for it, 3.8
-    times slower end to end in decode steps and in batch-1 prefills
-    alike (PERF.md, PR 31), so it is not kept; it is the road to take
-    again when rows far outnumber experts AND a fast grouped matmul
-    exists. rows [M, d]; chosen / weights [M, k]; up [E, d, f], down
+    multiply-adds where k / E of them are wanted, which hide under the
+    weights' read up to a few hundred rows and are the whole cost of
+    the layer beyond (PERF.md, PR 37). There the other road,
+    grouped_experts, computes the same sum; experts_road says which
+    one a layer traced with M rows takes. rows [M, d]; chosen /
+    weights [M, k]; up [E, d, f], down
     [E, f, d] the held experts first .. first+E-1; Expert(x) =
     down(relu(up x)^2), or down(silu(gate x) * up x) with ``gate``.
     -> float32 [M, d]."""
@@ -521,11 +518,96 @@ def dense_experts(rows, chosen, weights, up, down, first: int,
         preferred_element_type=jnp.float32)
 
 
+def grouped_experts(rows, chosen, weights, up, down, first: int,
+                    gate=None, interpret: bool = False):
+    """dense_experts' sum by its other road: only the wanted
+    multiply-adds. The M x k (row, choice) pairs are sorted by expert
+    (stable; pairs on experts this chip does not hold go behind the
+    last held group and are never computed), the rows gathered in that
+    order, and ``up`` (``gate``) and ``down`` are each ONE grouped
+    matmul over the held stack as stored
+    (ops/grouped_matmul.py: no copy of a stack); the pairs' outputs go
+    back to their rows through the inverse permutation and a row's k
+    are added. The rounding points are dense_experts': operands as
+    given (bfloat16 in serving), products accumulated in float32, the
+    activation in float32, a pair's hidden row weighed by its float32
+    router weight BEFORE its one rounding to the operands' type,
+    ``down`` accumulated in float32, a row's k outputs added in
+    float32. No capacity, nothing dropped: every pair on ONE expert is
+    one long group. A row's output is a function of that row alone.
+    What the kernel leaves unwritten behind the last group (garbage,
+    NaN included) is taken out by a select, never multiplied by 0.
+    Same arguments as dense_experts; ``interpret`` runs the kernel in
+    the Pallas interpreter (off the TPU). -> float32 [M, d]."""
+    from batch_shipyard_tpu.ops import grouped_matmul as gm
+    held = up.shape[0]
+    m, k = chosen.shape
+    pairs = m * k
+    local = (chosen - first).reshape(pairs)
+    here = (local >= 0) & (local < held)
+    # held pairs in expert order, then the rest: a stable sort keeps
+    # a group's pairs in row order
+    order = jnp.argsort(jnp.where(here, local, held), stable=True)
+    sizes = jnp.sum(local[:, None] == jnp.arange(held), axis=0,
+                    dtype=jnp.int32)
+    # the kernel walks whole row tiles: pad the ORDER (cheap), never a
+    # stack; the padding lies behind every group
+    padded = -(-pairs // gm.ROW_TILE) * gm.ROW_TILE
+    order = jnp.pad(order, (0, padded - pairs))
+    sorted_rows = jnp.take(rows, order // k, axis=0)
+
+    def every(stack):
+        return gm.grouped_matmul(sorted_rows, stack, sizes,
+                                 interpret=interpret)   # [P, f]
+
+    hidden = _expert_act(every(up),
+                         None if gate is None else every(gate))
+    hidden = (hidden * jnp.take(weights.reshape(pairs), order)[:, None]
+              ).astype(rows.dtype)
+    out = gm.grouped_matmul(hidden, down, sizes,
+                            interpret=interpret)        # [P, d]
+    # pair p's output is at sorted position inverse[p]
+    inverse = jnp.zeros((pairs,), jnp.int32).at[order[:pairs]].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    out = jnp.take(out, inverse, axis=0).reshape(m, k, -1)
+    return jnp.sum(jnp.where(here.reshape(m, k, 1), out, 0.0), axis=1)
+
+
+# Rows from which a layer takes the grouped road. dense_experts'
+# multiply-adds (rows x held x d x f) hide under the read of the held
+# stacks up to about 197e12 / 819e9 = 240 rows on a v5e. Timed there a
+# layer (tools/experts_road_timing.py; PERF.md, PR 37): at 256 rows
+# the two roads tie, one hybrid configuration a tenth faster grouped
+# and the other a tenth slower; at 384 both are a fifth faster
+# grouped, at 1,024 two and a half times. So one number serves both,
+# set where both are past the tie (prefill buckets are powers of two:
+# today it reads "from the 512 bucket").
+GROUPED_FROM_ROWS = 384
+
+
+def experts_road(rows: int, config: RoutedConfig) -> str:
+    """"dense" or "grouped": the road RoutedExperts takes when it is
+    traced with ``rows`` = batch x length rows. A function of that
+    static shape alone (and of the backend: the grouped road's kernel
+    is a TPU kernel, so off the TPU the answer is "dense"): a decode
+    step's slots and the short prefill buckets stay dense, where every
+    held expert is hit and its read hides the unwanted multiply-adds;
+    the long buckets go grouped. A serving engine asks the same
+    question to count its grouped prefills (serving.Launch.road)."""
+    del config      # one crossover serves every shape measured so far
+    if jax.default_backend() != "tpu":
+        return "dense"
+    return "grouped" if rows >= GROUPED_FROM_ROWS else "dense"
+
+
 class RoutedExperts(nn.Module):
     """x -> sum_i w_i Expert_i(x) [held experts] + Shared(x), every
     expert down(relu(up x)^2), or with ``config.gated``
-    down(silu(gate x) * up x), without bias, with no capacity
-    (dense_experts). The router runs in float32. The choices
+    down(silu(gate x) * up x), without bias, with no capacity, by
+    the road experts_road picks for the rows this call is traced with
+    (dense_experts for a decode step and a short prefill,
+    grouped_experts for a long one: the same sum). The router runs in
+    float32. The choices
     [B, T, k] (indices over all n_experts) are sown into the
     "decisions" collection, for a serving engine to hand to whoever
     checks them (serving.ContinuousBatcher.take_decisions).
@@ -574,9 +656,14 @@ class RoutedExperts(nn.Module):
             bias, cfg.top_k, cfg.scale)
         self.sow("decisions", "chosen",
                  chosen.reshape(batch, length, cfg.top_k))
-        routed = dense_experts(
-            rows, chosen, weights, up.astype(self.dtype),
-            down.astype(self.dtype), cfg.first_expert, gate)
+        stacks = (up.astype(self.dtype), down.astype(self.dtype),
+                  cfg.first_expert, gate)
+        if experts_road(batch * length, cfg) == "grouped":
+            routed = grouped_experts(
+                rows, chosen, weights, *stacks,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            routed = dense_experts(rows, chosen, weights, *stacks)
 
         def shared_in(kernel):
             return jnp.dot(rows, kernel.astype(self.dtype),

@@ -814,13 +814,14 @@ class ServingFrontEnd:
             return now[name][kind] - before[name][kind]
 
         logger.info(
-            "engine launches: %s; prefill tokens %d of %d padded; no "
-            "work %.3f s; %d stalls",
+            "engine launches: %s; prefills_grouped %d; prefill tokens "
+            "%d of %d padded; no work %.3f s; %d stalls",
             ", ".join(
                 f"{kind} {since('launches', kind)} "
                 f"({since('launch_seconds', kind):.3f} s, "
                 f"{since('landings_ready', kind)} found ready)"
                 for kind in now["launches"]),
+            since("prefills_grouped"),
             since("prefill_tokens"), since("prefill_bucket_tokens"),
             since("no_work_seconds"), since("stalls"))
 
@@ -1259,6 +1260,8 @@ class ServingFrontEnd:
             "prefill_bucket_tokens_total":
                 engine["prefill_bucket_tokens"],
             "prefill_tokens_total": engine["prefill_tokens"],
+            "prefills_total": engine["prefills"],
+            "prefills_grouped_total": engine["prefills_grouped"],
             "no_work_seconds_total": engine["no_work_seconds"],
             "stalls_total": engine["stalls"],
         }))
@@ -1464,7 +1467,7 @@ class ServingFrontEnd:
             **{name: steps[name] for name in (
                 "launches", "launch_seconds", "landings_ready",
                 "prefill_bucket_tokens", "prefill_tokens",
-                "no_work_seconds", "stalls")},
+                "prefills_grouped", "no_work_seconds", "stalls")},
             # a routed model's expert counters (absent otherwise)
             **{name: steps[name] for name in (
                 "expert_pairs_here", "expert_pairs_chosen",

@@ -41,6 +41,7 @@ import numpy as np
 
 from batch_shipyard_tpu.models import inference as inf
 from batch_shipyard_tpu.models import kv_pages
+from batch_shipyard_tpu.models import moe
 from batch_shipyard_tpu.models import transformer as tfm
 from batch_shipyard_tpu.trace import spans as trace_spans
 from batch_shipyard_tpu.utils import util
@@ -659,6 +660,10 @@ class Launch:
     bucket: int = 0             # prefill: padded tokens
     tokens: int = 0             # prefill: unpadded tokens
     request_id: str = ""        # prefill
+    # prefill: the road the bucket's program takes through its routed
+    # experts (moe.experts_road: "dense" / "grouped"); "" for a model
+    # without routed layers
+    road: str = ""
 
     @classmethod
     def landing(cls, kind: str, dispatched_at: float, previous: float,
@@ -683,7 +688,7 @@ class Launch:
         else:
             out.update(path=self.path, bucket=self.bucket,
                        tokens=self.tokens,
-                       request_id=self.request_id)
+                       request_id=self.request_id, road=self.road)
         return out
 
 
@@ -944,6 +949,7 @@ class ContinuousBatcher:
         self.landings_ready = dict.fromkeys(LAUNCH_KINDS, 0)
         self.prefill_bucket_tokens = 0
         self.prefill_tokens = 0
+        self.prefills_grouped = 0
         self.no_work_seconds = 0.0
         self.stalls = 0
         self._ring: collections.deque = collections.deque(
@@ -1572,7 +1578,8 @@ class ContinuousBatcher:
         self._landed(Launch.landing(
             "prefill", first.dispatched_at, self._landed_at, ready,
             first.queued, path=first.path, bucket=first.bucket,
-            tokens=first.prefilled, request_id=request_id))
+            tokens=first.prefilled, request_id=request_id,
+            road=self._experts_road(first.bucket)))
         with phases("slot_update"):
             slot.in_flight -= 1
             slot.generated.append(token)
@@ -1761,7 +1768,10 @@ class ContinuousBatcher:
         device's queue and the landings that found their result
         ready (the host, not the device, set their pace), each by
         kind (LAUNCH_KINDS); the prefills' padded and unpadded
-        tokens; the seconds the device had nothing to run because
+        tokens, and how many of them ran their routed experts by the
+        grouped road (Launch.road: every launch of a bucket above
+        moe.experts_road's crossover; 0 for a model without routed
+        layers); the seconds the device had nothing to run because
         nothing was there (from an idle settle's last landing, or
         the engine's construction, to the next dispatch); and the
         stall records written."""
@@ -1770,6 +1780,7 @@ class ContinuousBatcher:
                 "landings_ready": dict(self.landings_ready),
                 "prefill_bucket_tokens": self.prefill_bucket_tokens,
                 "prefill_tokens": self.prefill_tokens,
+                "prefills_grouped": self.prefills_grouped,
                 "no_work_seconds": self.no_work_seconds,
                 "stalls": self.stalls}
 
@@ -2112,6 +2123,7 @@ class ContinuousBatcher:
         if kind == "prefill":
             self.prefill_bucket_tokens += launch.bucket
             self.prefill_tokens += launch.tokens
+            self.prefills_grouped += launch.road == "grouped"
             shape = (launch.path, launch.bucket)
             if shape not in self._prefill_shapes:
                 self._prefill_shapes.add(shape)
@@ -2183,6 +2195,18 @@ class ContinuousBatcher:
         wall = time.time()
         trace_spans.record(trace_spans.SPAN_SERVE_STALL,
                            wall - interval_s, wall, **record)
+
+    def _experts_road(self, bucket: int) -> str:
+        """The road a prefill program of this bucket takes through
+        its routed experts: moe.experts_road asked as the layer asks
+        it while the program is traced, with the rows of one prefill
+        segment (_prefill_segments); "" for a model without routed
+        layers."""
+        if not self._decision_layers:
+            return ""
+        return moe.experts_road(
+            min(self.prefill_chunk or bucket, bucket),
+            self.config.experts)
 
     def _padded(self, tokens: list[int]):
         """tokens, zero-padded to their compile bucket: [1, bucket]."""

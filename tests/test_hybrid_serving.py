@@ -771,3 +771,71 @@ def test_a_state_kept_in_bfloat16_is_rounded_once_a_token(share):
     assert 0 < first <= 2.0 ** -8
     assert last > 2.0 ** -8, last    # the roundings gathered ...
     assert last < 0.1                # ... and it is still the state
+
+
+# ------------------- (h) the routed experts' two roads
+
+
+@pytest.fixture
+def grouped_road(monkeypatch):
+    """Every routed layer traced inside takes the grouped road (off
+    the TPU the kernel runs in the interpreter). The road is picked
+    while a program is traced, so what was traced under the other rule
+    is dropped before and after."""
+    jax.clear_caches()
+    monkeypatch.setattr(moe, "experts_road",
+                        lambda rows, config: "grouped")
+    yield
+    jax.clear_caches()
+
+
+def test_the_grouped_road_serves_the_same_tokens_and_choices(
+        share, served, grouped_road):
+    """The engine of ``served`` again with the grouped road forced in
+    every program, decode steps too: the same tokens, the same record
+    of choices for take_decisions, and the road on every prefill's
+    Launch record and in the counters; ``served``'s own engine, on
+    the CPU, took the dense road everywhere."""
+    _file, _dims, config, params = share
+    dense_engine, prompts, new_tokens, done, records = served
+    engine = _engine(config, params)
+    assert _serve(engine, prompts, new_tokens) == done
+    for request_id, record in records.items():
+        again = engine.take_decisions(request_id)
+        assert again["first"] == record["first"]
+        assert set(again["layers"]) == set(record["layers"])
+        for name, rows in record["layers"].items():
+            np.testing.assert_array_equal(again["layers"][name], rows)
+    for which, road in ((engine, "grouped"), (dense_engine, "dense")):
+        prefills = [launch for launch in which._ring
+                    if launch.kind == "prefill"]
+        # (other tests have served more through ``served``'s engine)
+        assert len(prefills) >= len(prompts)
+        assert {launch.road for launch in prefills} == {road}
+        assert all(launch.entry()["road"] == road
+                   for launch in prefills)
+        stats = which.step_stats()
+        assert stats["prefills_grouped"] == \
+            (stats["launches"]["prefill"] if road == "grouped" else 0)
+    assert engine.step_stats()["prefills"] == len(prompts)
+    # a decode launch's entry names no road
+    assert "road" not in next(
+        launch for launch in engine._ring
+        if launch.kind == "decode").entry()
+
+
+def test_the_engine_asks_the_layers_own_rule(share, monkeypatch):
+    """_experts_road hands moe.experts_road the rows ONE prefill
+    segment is traced with (the bucket, or the chunk of a chunked
+    prefill) and the model's RoutedConfig."""
+    _file, _dims, config, params = share
+    asked = []
+    monkeypatch.setattr(
+        moe, "experts_road",
+        lambda rows, cfg: asked.append((rows, cfg)) or "dense")
+    whole = _engine(config, params)
+    chunked = _engine(config, params, prefill_chunk=16)
+    asked.clear()       # the layer itself asked while the caches were made
+    assert whole._experts_road(64) == "dense"
+    assert chunked._experts_road(64) == "dense"
+    assert asked == [(64, config.experts), (16, config.experts)]

@@ -521,6 +521,9 @@ def test_the_front_end_reports_the_counters_and_names_its_wait(
                if r.getMessage().startswith("engine launches: ")]
     assert "prefill 1 (" in line and "decode 3 (" in line
     assert "prefill tokens 3 of 16 padded" in line
+    # a model without routed layers has no grouped prefill
+    assert "; prefills_grouped 0; " in line
+    assert block["prefills_grouped"] == 0
     assert line.endswith("0 stalls")
     assert block["no_work_seconds"] > 0 and block["stalls"] == 0
     values = {line.rsplit(" ", 1)[0]: float(line.rsplit(" ", 1)[1])
@@ -534,5 +537,8 @@ def test_the_front_end_reports_the_counters_and_names_its_wait(
         assert f"shipyard_serving_landings_ready_total{label}" in values
     assert values["shipyard_serving_prefill_bucket_tokens_total"] == 48
     assert values["shipyard_serving_prefill_tokens_total"] == 16
+    assert values["shipyard_serving_prefills_total"] == \
+        block["prefills"] == 3
+    assert values["shipyard_serving_prefills_grouped_total"] == 0
     assert values["shipyard_serving_no_work_seconds_total"] > 0
     assert values["shipyard_serving_stalls_total"] == 0

@@ -31,6 +31,7 @@ from batch_shipyard_tpu.ops import attention as attn
 from batch_shipyard_tpu.ops import chunked_loss as cl
 from batch_shipyard_tpu.ops import decode_attention as dd
 from batch_shipyard_tpu.ops import fused_norm as fn
+from batch_shipyard_tpu.ops import grouped_matmul as gm
 from batch_shipyard_tpu.ops import paged_attention as pa
 from batch_shipyard_tpu.ops import quantization as qz
 from batch_shipyard_tpu.ops import ring_collectives as rc
@@ -124,6 +125,17 @@ CASES = [
      [((2, 256, 128), f32), ((1024, 128), f32), ((2, 256), i32)]),
     ("xent_bwd_checks", jax.grad(_xent, (0, 1)),
      [((2, 256, 128), f32), ((1024, 128), f32), ((2, 256), i32)]),
+    # the routed experts' grouped road at both hybrid configurations'
+    # widths and the 1,024 bucket's (row, choice) pairs: [pairs, k]
+    # rows against [held, k, n] stacks (up, down)
+    ("grouped_matmul_nemotron_up", gm.grouped_matmul,
+     [((6144, 2688), bf16), ((64, 2688, 1856), bf16), ((64,), i32)]),
+    ("grouped_matmul_nemotron_down", gm.grouped_matmul,
+     [((6144, 1856), bf16), ((64, 1856, 2688), bf16), ((64,), i32)]),
+    ("grouped_matmul_solar_up", gm.grouped_matmul,
+     [((8192, 4096), bf16), ((40, 4096, 1280), bf16), ((40,), i32)]),
+    ("grouped_matmul_solar_down", gm.grouped_matmul,
+     [((8192, 1280), bf16), ((40, 1280, 4096), bf16), ((40,), i32)]),
     ("ring_all_gather_virtual", rc.ring_all_gather_virtual,
      [((4, 128, 128), f32)]),
     ("ring_reduce_scatter_virtual", rc.ring_reduce_scatter_virtual,
@@ -186,6 +198,25 @@ def test_mosaic_compiles_for_v5e(v5e_devices, name, fn_, args):
     """libtpu's compiler (Mosaic) takes the kernel for a v5e."""
     compiled = _compile_for(v5e_devices, fn_, args)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_GROUPED = [case for case in CASES
+            if case[0].startswith("grouped_matmul_")]
+
+
+@pytest.mark.parametrize("name,fn_,args", _GROUPED,
+                         ids=[case[0] for case in _GROUPED])
+def test_the_grouped_matmul_reads_a_stack_where_it_lies(
+        v5e_devices, name, fn_, args):
+    """No call copies, transposes or pads an expert stack: the device
+    keeps [64, 2688, 1856] with 2688 along the lanes, and a kernel
+    handed the logical shape instead of the stored view
+    (grouped_matmul._stored_k_minor) has all 638 MB of it copied into
+    the default layout first. The call's temporaries stay far under
+    one stack."""
+    stack = args[1][0]
+    memory = _compile_for(v5e_devices, fn_, args).memory_analysis()
+    assert memory.temp_size_in_bytes < stack[0] * stack[1] * stack[2]
 
 
 def test_mosaic_refusal_is_visible(v5e_devices):
@@ -397,9 +428,10 @@ def test_the_seat_program_compiles_small_for_v5e(v5e_devices,
         (2,), (96, 1), (96,), (1,))
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("program", ["decode", "prefill",
+                                     "prefill_grouped"])
 def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
-        v5e_devices, program):
+        v5e_devices, program, monkeypatch):
     """The step programs of a stack of block kinds (one state-space,
     one routed-expert and one attention block at the benchmark's
     second configuration's widths: 64 state-space heads of 64 over a
@@ -411,7 +443,14 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
     out or converted whole; and neither are the held experts' weights
     (638 MB a matrix): the two matmuls over all of them read them
     where they lie (the compiler's grouped matmul, the road tried
-    first, copied a stack whole for every call: PERF.md, PR 31)."""
+    first, copied a stack whole for every call: PERF.md, PR 31).
+    ``prefill_grouped``: the 1,024 bucket traced as the chip traces it
+    (the code that asks jax.default_backend() is told "tpu"), where
+    moe.experts_road takes the grouped road: its Pallas kernel
+    (ops/grouped_matmul.py) is in the program, and it too reads both
+    stacks where they lie, the one the device stores with d_model
+    along the lanes ([64, 2688, 1856]: 1856 = 14.5 x 128) through its
+    stored view."""
     import dataclasses
     import re
 
@@ -453,12 +492,19 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
             arg((slots, 1)), arg((slots,)), arg((slots,), jnp.bool_),
             arg((2,), jnp.uint32))
     else:
+        grouped = program == "prefill_grouped"
+        if grouped:
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        bucket = 1024 if grouped else 512
+        assert moe.experts_road(bucket, config.experts) == (
+            "grouped" if grouped else "dense")
         row = arg((max_len // page,))
         lowered = serving._prefill_paged.lower(
-            dense, None, page, params, cache, 0, arg((1, 512)), row,
+            dense, None, page, params, cache, 0, arg((1, bucket)), row,
             400)
     compiled = lowered.compile()
     text = compiled.as_text()
+    assert ("gmm" in text) == (program == "prefill_grouped")
     moved = re.findall(
         r"= bf16\[64,(?:2688,1856|1856,2688)\]\S* "
         r"(copy|transpose|copy-start|convert)\(", text)
