@@ -124,41 +124,34 @@ def fresh_out_dir(root: pathlib.Path) -> pathlib.Path:
 
 class ProfilerSlice:
     """jax.profiler around a steady slice of the window (traced runs
-    only), reduced with tracered once the window has closed."""
+    only), reduced with tracered once the window has closed. The
+    slice is marked in the trace by an annotation of its own, so that
+    the host's two stamps can be found on the trace's clock."""
 
     def __init__(self, ctx: RunContext) -> None:
+        self.ctx = ctx
         self.dir = str(ctx.out_dir / "profile")
         self.started: Optional[float] = None
         self.stopped: Optional[float] = None
 
-    def start(self) -> None:
-        import jax
-        jax.profiler.start_trace(self.dir)
-        self.started = time.monotonic()
-
-    def stop(self) -> None:
-        import jax
-        self.stopped = time.monotonic()
-        jax.profiler.stop_trace()
-
     def run_between(self, begin: float, end: float) -> None:
-        """Sleep until ``begin`` (monotonic), trace until ``end``."""
+        """Sleep until ``begin`` (monotonic), trace until ``end``.
+        Both stamps are taken with tracing on, inside the mark."""
+        import jax
         time.sleep(max(0.0, begin - time.monotonic()))
-        self.start()
-        time.sleep(max(0.0, end - time.monotonic()))
-        self.stop()
+        jax.profiler.start_trace(self.dir)
+        with jax.profiler.TraceAnnotation(
+                tracered.HOST_SPAN_PREFIX + tracered.SLICE_MARK):
+            self.started = time.monotonic()
+            time.sleep(max(0.0, end - time.monotonic()))
+            self.stopped = time.monotonic()
+        jax.profiler.stop_trace()
 
     def reduce(self) -> Optional[dict]:
         path = tracered.newest_xplane(self.dir)
         if path is None:
             return None
-        trace = tracered.from_xplane(path)
-        events = tracered.device_op_events(trace)
-        return {
-            "trace": trace, "events": events,
-            "busy_s": tracered.busy_seconds(events),
-            "window_s": self.stopped - self.started,
-            "breakdown": {
-                "device_ops": tracered.top_ops(events),
-                "idle_gaps": tracered.idle_gaps(
-                    events, tracered.host_spans(trace))}}
+        profile = tracered.reduce_slice(
+            tracered.from_xplane(path), self.stopped - self.started)
+        self.ctx.note(tracered.describe_slice(profile))
+        return profile

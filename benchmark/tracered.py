@@ -28,6 +28,10 @@ OP_LINES = ("XLA Ops",)
 # Operations whose event only spans the events of their body.
 CONTAINER = re.compile(r"^(while|conditional|call)([.\d]|$)")
 HOST_SPAN_PREFIX = "bench:"
+# The annotation (behind HOST_SPAN_PREFIX) that the harness holds open
+# from its slice's start stamp to its stop stamp: the host's slice on
+# the trace's clock.
+SLICE_MARK = "traced_slice"
 
 
 def short_name(name: str) -> str:
@@ -169,6 +173,73 @@ def busy_seconds(events_by_device: dict) -> float:
         total(merge([e[1], e[1] + e[2]] for e in events))
         for events in events_by_device.values()]
     return sum(per_device) / len(per_device) / 1e9
+
+
+def span_edges(events_by_device: dict) -> Optional[tuple[int, int]]:
+    """(earliest event's start, latest event's end) over the devices,
+    or None for no events."""
+    edges = [(e[1], e[1] + e[2])
+             for events in events_by_device.values() for e in events]
+    if not edges:
+        return None
+    return min(start for start, _ in edges), max(end for _, end in edges)
+
+
+def span_seconds(events_by_device: dict) -> float:
+    """Seconds from the earliest event's start to the latest event's
+    end over the devices: the least the traced interval can have
+    lasted, and no device's busy time can exceed it."""
+    edges = span_edges(events_by_device)
+    return (edges[1] - edges[0]) / 1e9 if edges else 0.0
+
+
+def reduce_slice(trace: dict, host_slice_s: float) -> dict:
+    """A traced run's account of its profiler slice: the trace, and
+    the seconds the host held the slice open (both ends stamped with
+    tracing on). The traced interval contains the host's slice AND
+    every device event (the device is traced until stop_trace takes
+    hold, and the two clocks skew by milliseconds), so ``window_s``
+    is the longer of the slice and the events' span, and ``busy_s``,
+    a union inside that span, cannot exceed it. Nothing is clipped,
+    subtracted or clamped: a trace whose busy time passes its own
+    span is wrong for another reason and has to show.
+
+    ``edges_s`` is [first event's start after the slice mark's start,
+    last event's end after the mark's end] on the trace's clock, None
+    without a mark or without events. The mark names no idle gap."""
+    events = device_op_events(trace)
+    spans = host_spans(trace)
+    mark = next((s for s in spans if s[0] == SLICE_MARK), None)
+    edges = span_edges(events)
+    span_s = span_seconds(events)
+    return {
+        "trace": trace, "events": events,
+        "busy_s": busy_seconds(events),
+        "window_s": max(host_slice_s, span_s),
+        "host_slice_s": host_slice_s, "span_s": span_s,
+        "edges_s": [(edges[0] - mark[1]) / 1e9,
+                    (edges[1] - mark[2]) / 1e9]
+        if mark and edges else None,
+        "breakdown": {
+            "device_ops": top_ops(events),
+            "idle_gaps": idle_gaps(
+                events, [s for s in spans if s[0] != SLICE_MARK])}}
+
+
+def describe_slice(profile: dict) -> str:
+    """One line of a traced run's account: the slice's edges, so that
+    a far-off idle share can be read off the run."""
+    busy, span = profile["busy_s"], profile["span_s"]
+    edges = profile["edges_s"]
+    return (
+        f"traced slice: host {profile['host_slice_s']:.9f} s, device "
+        f"events' span {span:.9f} s, window_s "
+        f"{profile['window_s']:.9f} s (the longer), busy {busy:.9f} s, "
+        f"gaps inside the span {(span - busy) * 1e3:.6f} ms, window_s "
+        f"- busy {(profile['window_s'] - busy) * 1e3:.6f} ms; "
+        + (f"first event {edges[0] * 1e3:+.3f} ms after the slice's "
+           f"start, last event's end {edges[1] * 1e3:+.3f} ms after "
+           f"its end" if edges else "no slice mark or no device event"))
 
 
 def top_ops(events_by_device: dict, n: int = 10) -> list:

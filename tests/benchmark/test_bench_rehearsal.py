@@ -59,6 +59,9 @@ def test_rehearsal_runs_end_to_end(workload, trace):
     assert result["device"]["count"] == devices
     assert any(l.startswith("check ") and "(limit" in l
                for l in lines)       # each number beside its limit
+    # a traced run prints its slice's edges once
+    assert sum(l.startswith("traced slice: host ")
+               for l in lines) == trace
     # ... as the last key of the result, and the last lines of stderr
     assert list(result)[-1] == "check"
     limits = spec.load_cell(cell).config["rehearse_tiny"]["check"][
