@@ -1,7 +1,13 @@
 """The paged KV cache's books, host side: which physical page of the
-pool ([P, page, H*D] in every layer, transformer._decode_attend_paged)
-belongs to which slot, what admission may still promise, and which
-full prompt pages a later request can share. The serving engine
+pool ([P, page, Hkv*D] in every layer that attends over its whole
+context, transformer._decode_attend_paged) belongs to which slot, what
+admission may still promise, and which full prompt pages a later
+request can share. A layer with a sliding window keeps no page of the
+pool: its newest keys sit in a ring of the slot's own
+(transformer.Attention._decode_attend_ring), fixed at
+ceil(window / page) + 1 pages a slot, which needs no allocation, no
+reservation and no table on the host; ring_occupancy below is all
+there is to account. The serving engine
 (models/serving.py) schedules and runs the device; this module
 accounts, and imports nothing of JAX or of the engine. ``table`` is
 the block table's host copy, which the engine pushes to the device.
@@ -28,6 +34,28 @@ import hashlib
 from typing import Optional
 
 import numpy as np
+
+
+def ring_occupancy(held_tokens: list[int], num_slots: int,
+                   page_size: int, ring_pages: int,
+                   window: int) -> dict:
+    """The window layers' page group, as ContinuousBatcher.occupancy
+    reports it beside the pool's: a slot's ring never holds more than
+    ``ring_pages`` pages whatever its length (the bound is the
+    ring's size, not a policy), so window_pages_in_use is the pages a
+    seated request's keys reach, min(ceil(tokens / page), ring_pages)
+    each, of window_pages_total = num_slots * ring_pages. Beside
+    them the keys ONE full and ONE window layer attend in the decode
+    step dispatched from this state: every held token, and each
+    slot's newest ``window`` at most."""
+    return {
+        "window_pages_in_use": sum(
+            min(-(-tokens // page_size), ring_pages)
+            for tokens in held_tokens),
+        "window_pages_total": num_slots * ring_pages,
+        "kv_tokens_full": sum(held_tokens),
+        "kv_tokens_window": sum(min(tokens, window)
+                                for tokens in held_tokens)}
 
 
 class PoolDry(Exception):

@@ -429,8 +429,8 @@ def moe_param_specs():
 
 @dataclasses.dataclass(frozen=True)
 class RoutedConfig:
-    """Sigmoid-scored top-k experts beside one shared expert, and which
-    of the experts THIS chip holds: the router always scores all
+    """Top-k routed experts beside one shared expert (or none), and
+    which of the experts THIS chip holds: the router always scores all
     ``n_experts`` and takes its ``top_k``; the layer computes the
     choices that fall on experts first_expert .. first_expert +
     experts_held - 1 and adds nothing for the others (in an
@@ -445,8 +445,20 @@ class RoutedConfig:
     experts_held: Optional[int] = None   # None = all of them
     first_expert: int = 0
     # Expert(x) and Shared(x): False down(relu(up x)^2); True the gated
-    # down(silu(gate x) * up x), a third matrix an expert.
+    # down(act(gate x) * up x), a third matrix an expert.
     gated: bool = False
+    # The gate's activation of a gated expert: "silu" (SwiGLU) or
+    # "relu" (ReGLU).
+    gate_act: str = "silu"
+    # How the router's outputs become choices and weights: "sigmoid"
+    # (route_sigmoid: a selection bias, weights normalised to
+    # ``scale``) or "softmax" (route_softmax: the k largest logits,
+    # softmax over those k alone; no bias leaf, no scale).
+    scoring: str = "sigmoid"
+    # The type the router's logits come out of their matmul in, and in
+    # which they are compared and weighed. bfloat16 is the nearest
+    # precision below: what a check's control switches on.
+    router_dtype: Any = jnp.float32
 
     @property
     def held(self) -> int:
@@ -466,16 +478,27 @@ def route_sigmoid(scores_in, bias, top_k: int, scale: float):
     return chosen.astype(jnp.int32), weights
 
 
-def _expert_act(up, gate=None):
+def route_softmax(logits, top_k: int):
+    """logits [M, n] float32 router outputs -> (chosen [M, k] int32,
+    weights [M, k] float32): the k largest logits, weighed by a
+    softmax over those k alone (they sum to 1)."""
+    best, chosen = jax.lax.top_k(logits, top_k)
+    return chosen.astype(jnp.int32), jax.nn.softmax(best, axis=-1)
+
+
+def _expert_act(up, gate=None, gate_act: str = "silu"):
     """An expert's hidden activation (float32) from its up
-    projection, and its gate projection where it has one."""
+    projection, and its gate projection where it has one (``gate_act``
+    of the gate, times up: silu or relu)."""
     if gate is None:
         return jnp.square(jax.nn.relu(up))
+    if gate_act == "relu":
+        return jax.nn.relu(gate) * up
     return jax.nn.silu(gate) * up
 
 
 def dense_experts(rows, chosen, weights, up, down, first: int,
-                  gate=None):
+                  gate=None, gate_act: str = "silu"):
     """sum_i w_i Expert_i(row) over the chosen experts that are held,
     as two matmuls over ALL the held experts (three for gated ones):
     rows [M, d] against up [E, d, f] gives every expert's hidden
@@ -511,7 +534,7 @@ def dense_experts(rows, chosen, weights, up, down, first: int,
             preferred_element_type=jnp.float32)          # [M, E, f]
 
     hidden = _expert_act(every(up),
-                         None if gate is None else every(gate))
+                         None if gate is None else every(gate), gate_act)
     hidden = (hidden * weigh[:, :, None]).astype(rows.dtype)
     return jax.lax.dot_general(
         hidden, down, (((1, 2), (0, 1)), ((), ())),
@@ -519,7 +542,8 @@ def dense_experts(rows, chosen, weights, up, down, first: int,
 
 
 def grouped_experts(rows, chosen, weights, up, down, first: int,
-                    gate=None, interpret: bool = False):
+                    gate=None, gate_act: str = "silu",
+                    interpret: bool = False):
     """dense_experts' sum by its other road: only the wanted
     multiply-adds. The M x k (row, choice) pairs are sorted by expert
     (stable; pairs on experts this chip does not hold go behind the
@@ -561,7 +585,7 @@ def grouped_experts(rows, chosen, weights, up, down, first: int,
                                  interpret=interpret)   # [P, f]
 
     hidden = _expert_act(every(up),
-                         None if gate is None else every(gate))
+                         None if gate is None else every(gate), gate_act)
     hidden = (hidden * jnp.take(weights.reshape(pairs), order)[:, None]
               ).astype(rows.dtype)
     out = gm.grouped_matmul(hidden, down, sizes,
@@ -603,11 +627,21 @@ def experts_road(rows: int, config: RoutedConfig) -> str:
 class RoutedExperts(nn.Module):
     """x -> sum_i w_i Expert_i(x) [held experts] + Shared(x), every
     expert down(relu(up x)^2), or with ``config.gated``
-    down(silu(gate x) * up x), without bias, with no capacity, by
+    down(act(gate x) * up x), act silu or relu (``config.gate_act``),
+    without bias, with no capacity, by
     the road experts_road picks for the rows this call is traced with
     (dense_experts for a decode step and a short prefill,
-    grouped_experts for a long one: the same sum). The router runs in
-    float32. The choices
+    grouped_experts for a long one: the same sum). ``config.d_shared``
+    0: no shared expert, no ``shared_*`` leaf, nothing added. The
+    router runs in float32, by one of two scoring rules
+    (``config.scoring``): "sigmoid" (route_sigmoid: sigmoid scores, a
+    selection bias, weights normalised to ``scale``) or "softmax"
+    (route_softmax: top-k on the logits, softmax over the chosen; no
+    bias leaf; ``config.router_dtype`` bfloat16 is the lower-precision
+    control). It reads ``router_input`` where one is handed (the
+    normed input of ANOTHER block: a model whose router is placed
+    before the token mixer), else x; the experts' matmuls read x. The
+    choices
     [B, T, k] (indices over all n_experts) are sown into the
     "decisions" collection, for a serving engine to hand to whoever
     checks them (serving.ContinuousBatcher.take_decisions).
@@ -617,53 +651,69 @@ class RoutedExperts(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         cfg = self.config
+        if cfg.scoring not in ("sigmoid", "softmax") or \
+                cfg.gate_act not in ("silu", "relu"):
+            raise ValueError(f"no scoring rule {cfg.scoring!r} or gate "
+                             f"activation {cfg.gate_act!r}")
         batch, length, d_model = x.shape
         kernel = nn.initializers.lecun_normal()
         stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                                batch_axis=(0,))
         router = self.param("router_kernel", kernel,
                             (d_model, cfg.n_experts), self.param_dtype)
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.zeros, (cfg.n_experts,),
-                          jnp.float32)
+        if cfg.scoring == "sigmoid":
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (cfg.n_experts,),
+                              jnp.float32)
         up = self.param("experts_up", stacked,
                         (cfg.held, d_model, cfg.d_expert),
                         self.param_dtype)
         down = self.param("experts_down", stacked,
                           (cfg.held, cfg.d_expert, d_model),
                           self.param_dtype)
-        shared_up = self.param("shared_up", kernel,
-                               (d_model, cfg.d_shared), self.param_dtype)
-        shared_down = self.param("shared_down", kernel,
-                                 (cfg.d_shared, d_model),
-                                 self.param_dtype)
+        if cfg.d_shared:
+            shared_up = self.param(
+                "shared_up", kernel, (d_model, cfg.d_shared),
+                self.param_dtype)
+            shared_down = self.param(
+                "shared_down", kernel, (cfg.d_shared, d_model),
+                self.param_dtype)
         gate = shared_gate = None
         if cfg.gated:
             gate = self.param("experts_gate", stacked,
                               (cfg.held, d_model, cfg.d_expert),
                               self.param_dtype).astype(self.dtype)
-            shared_gate = self.param(
-                "shared_gate", kernel, (d_model, cfg.d_shared),
-                self.param_dtype).astype(self.dtype)
+            if cfg.d_shared:
+                shared_gate = self.param(
+                    "shared_gate", kernel, (d_model, cfg.d_shared),
+                    self.param_dtype).astype(self.dtype)
         rows = x.reshape(batch * length, d_model).astype(self.dtype)
+        scored = rows if router_input is None else router_input.reshape(
+            batch * length, d_model).astype(self.dtype)
         # bfloat16 operands multiply exactly into float32: the router
         # is a float32 computation on the activations as they are
-        chosen, weights = route_sigmoid(
-            jnp.dot(rows, router.astype(self.dtype),
-                    preferred_element_type=jnp.float32),
-            bias, cfg.top_k, cfg.scale)
+        logits = jnp.dot(scored, router.astype(self.dtype),
+                         preferred_element_type=cfg.router_dtype)
+        if cfg.scoring == "sigmoid":
+            chosen, weights = route_sigmoid(logits, bias, cfg.top_k,
+                                            cfg.scale)
+        else:
+            chosen, weights = route_softmax(logits, cfg.top_k)
         self.sow("decisions", "chosen",
                  chosen.reshape(batch, length, cfg.top_k))
         stacks = (up.astype(self.dtype), down.astype(self.dtype),
-                  cfg.first_expert, gate)
+                  cfg.first_expert, gate, cfg.gate_act)
         if experts_road(batch * length, cfg) == "grouped":
             routed = grouped_experts(
                 rows, chosen, weights, *stacks,
                 interpret=jax.default_backend() != "tpu")
         else:
             routed = dense_experts(rows, chosen, weights, *stacks)
+        if not cfg.d_shared:
+            return routed.astype(self.dtype).reshape(
+                batch, length, d_model)
 
         def shared_in(kernel):
             return jnp.dot(rows, kernel.astype(self.dtype),
@@ -671,7 +721,7 @@ class RoutedExperts(nn.Module):
 
         hidden = _expert_act(
             shared_in(shared_up), None if shared_gate is None
-            else shared_in(shared_gate)).astype(self.dtype)
+            else shared_in(shared_gate), cfg.gate_act).astype(self.dtype)
         shared = jnp.dot(hidden, shared_down.astype(self.dtype),
                          preferred_element_type=jnp.float32)
         return (routed + shared).astype(self.dtype).reshape(

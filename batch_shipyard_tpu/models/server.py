@@ -1237,6 +1237,9 @@ class ServingFrontEnd:
             "queue_depth": engine["queued"],
             "kv_pages_in_use": engine.get("kv_pages_in_use"),
             "kv_pages_total": engine.get("kv_pages_total"),
+            # the window layers' page group (a model with such layers)
+            "window_pages_in_use": engine.get("window_pages_in_use"),
+            "window_pages_total": engine.get("window_pages_total"),
             "steps_total": engine["steps"],
             "compiles_total": engine["compiles"],
         }))

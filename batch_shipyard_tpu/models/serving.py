@@ -204,6 +204,27 @@ def _speculative_step(target_model, draft_model, gamma, t_params,
     return t_cache, d_cache, new_tok, new_pos, block, a_slot
 
 
+def window_segment(config) -> Optional[int]:
+    """The engine's own segment length where it is handed no
+    prefill_chunk: None (a bucket whole), but for a model with window
+    layers whose inserts attend in blocks (prefill_blocks) the least
+    power of two that holds its widest window, so that what a
+    segment's forward holds in HBM (its rows through every projection
+    and expert; the scores stay in VMEM) is bounded by the window
+    whatever the bucket, and the power-of-two buckets are equal
+    segments (one traced forward under a scan). Measured on a v5e at
+    a window of 4,096 beside 12.0 GB of weights and cache (PERF.md,
+    PR 40): segments of 2,048 serve 3 % fewer tokens a second; of
+    8,192 or a 16,384 bucket whole 2-3 % more in a run that goes
+    well, but with 1.9 / 2.6 GB of temporaries (1.2 at 4,096) every
+    such run had a decode step land 1 to 2 s late, which none at
+    4,096 had."""
+    windows = [w for w in tfm.attention_windows(config) if w]
+    if not (config.prefill_blocks and windows):
+        return None
+    return 1 << (max(windows) - 1).bit_length()
+
+
 def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
     """Batch-1 BATCHED prefill over the (bucket-padded) prompt
     [1, L]: the multi-token insert path of transformer._decode_attend
@@ -237,32 +258,57 @@ def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
 def _prefill_segments(model, prefill_chunk, params, cache, tokens,
                       start, prompt_len):
     """``tokens`` [1, S] at positions start.. through the batch-1
-    cache, in chunks; the logits at position prompt_len-1."""
+    cache, in chunks; the logits at position prompt_len-1. Several
+    chunks of one length are ONE traced forward under lax.scan (the
+    cache its carry), so that a long bucket compiles, and weighs, what
+    one chunk does and not chunks times that."""
     total = tokens.shape[1]
     chunk = min(prefill_chunk or total, total)
-    hiddens, chosen = [], []
-    for off in range(0, total, chunk):
-        seg = tokens[:, off:off + chunk]
+
+    def forward(cache, seg, off, positions):
         # Positions are GLOBAL offsets: RoPE for chunk c must match
         # the full-sequence pass exactly.
         h, mut = model.apply(
             {"params": params, "cache": cache}, seg,
-            return_hidden=True,
-            positions=start + jnp.arange(
-                off, off + seg.shape[1], dtype=jnp.int32),
+            return_hidden=True, positions=start + positions,
             valid_len=prompt_len - start - off, mutable=_MUTABLE)
-        cache = mut["cache"]
-        hiddens.append(h)
-        chosen.append(tfm.collect_decisions(mut.get("decisions"),
-                                            model.config))
-    hidden = (hiddens[0] if len(hiddens) == 1
-              else jnp.concatenate(hiddens, axis=1))
+        return mut["cache"], h, tfm.collect_decisions(
+            mut.get("decisions"), model.config)
+
+    if total > chunk and total % chunk == 0:
+        def step(cache, xs):
+            seg, off = xs
+            cache, h, chosen = forward(
+                cache, seg, off,
+                off + jnp.arange(chunk, dtype=jnp.int32))
+            return cache, (h, chosen)
+
+        count = total // chunk
+        cache, (hidden, chosen) = jax.lax.scan(
+            step, cache,
+            (tokens.reshape(count, 1, chunk),
+             jnp.arange(count, dtype=jnp.int32) * chunk))
+        hidden = hidden.reshape(1, total, hidden.shape[-1])
+        if chosen is not None:
+            # [chunks, layers, 1, chunk, k] -> [layers, S, k]
+            chosen = jnp.moveaxis(chosen[:, :, 0], 0, 1).reshape(
+                chosen.shape[1], total, chosen.shape[-1])
+    else:
+        hiddens, chosen = [], []
+        for off in range(0, total, chunk):
+            seg = tokens[:, off:off + chunk]
+            cache, h, picked = forward(
+                cache, seg, off, jnp.arange(
+                    off, off + seg.shape[1], dtype=jnp.int32))
+            hiddens.append(h)
+            chosen.append(picked)
+        hidden = (hiddens[0] if len(hiddens) == 1
+                  else jnp.concatenate(hiddens, axis=1))
     last_h = jnp.take(hidden[0], prompt_len - start - 1, axis=0)  # [d]
     last = tfm.output_logits(model.config, params, last_h)   # [vocab]
-    if chosen[0] is not None:
-        chosen = jnp.concatenate(chosen, axis=2)[:, 0]
-    else:
-        chosen = None
+    if isinstance(chosen, list):     # the loop's, a chunk an entry
+        chosen = (jnp.concatenate(chosen, axis=2)[:, 0]
+                  if chosen[0] is not None else None)
     return cache, last, chosen
 
 
@@ -276,6 +322,31 @@ def _seat_state(big, small, slot):
     """A per-slot state leaf [B, ...] with the batch-1 prefill's in
     the slot's row: whatever the slot held before is gone."""
     return big.at[slot].set(small[0].astype(big.dtype))
+
+
+def _seat_ring(big, small, slot, prompt_len, page: int) -> dict:
+    """A window layer's ring leaves (transformer.
+    Attention._decode_attend_ring: k_ring / v_ring [B * ring, page,
+    Hkv*D], length [B]) with the batch-1 prefill's rows in the slot's
+    ring: ring entry c takes the newest logical page p <= the
+    prompt's last with p % ring == c (one gather of ``ring`` pages
+    out of the dense rows, one update of the slot's stretch), and the
+    slot's length is the prompt's. An entry no page of the prompt
+    maps to yet takes page 0's rows: nothing reads it before the
+    decode steps that reach it have written it."""
+    ring = big["k_ring"].shape[0] // big["length"].shape[0]
+    last = (prompt_len - 1) // page
+    entry = jnp.arange(ring, dtype=jnp.int32)
+    logical = jnp.maximum(last - jnp.mod(last - entry, ring), 0)
+
+    def seated(pool, rows):
+        pages = rows[0].reshape(-1, *pool.shape[1:])[logical]
+        return jax.lax.dynamic_update_slice(
+            pool, pages.astype(pool.dtype), (slot * ring, 0, 0))
+
+    return {"k_ring": seated(big["k_ring"], small["k"]),
+            "v_ring": seated(big["v_ring"], small["v"]),
+            "length": big["length"].at[slot].set(prompt_len)}
 
 
 def _pool_rows(rows, pool):
@@ -325,7 +396,9 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
     allocation point at the scratch page (which absorbs
     padded-garbage writes), and partial-page garbage is
     masked-on-read via the true length. A layer's per-slot state
-    (a leaf with a slot row and no pages) is overwritten whole."""
+    (a leaf with a slot row and no pages) is overwritten whole, and a
+    window layer's ring (_seat_ring) filled with the prompt's newest
+    pages; neither reads ``table_row``."""
     small, last, chosen = _dense_prefill(model, prefill_chunk, params,
                                          prompt, prompt_len)
     # Bucket blocks, static (ceil: a bucket smaller than one page
@@ -334,13 +407,24 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
     n_blocks = -(-prompt.shape[1] // page)
 
     def scatter(big, sm):
+        if isinstance(big, dict) and "k_ring" in big:
+            return _seat_ring(big, sm, slot, prompt_len, page)
         if isinstance(big, dict) and "k_pages" in big:
             kp, vp = big["k_pages"], big["v_pages"]
-            for b in range(n_blocks):
-                krows = sm["k"][0, b * page:(b + 1) * page]
-                vrows = sm["v"][0, b * page:(b + 1) * page]
-                kp = kp.at[table_row[b]].set(_pool_rows(krows, kp))
-                vp = vp.at[table_row[b]].set(_pool_rows(vrows, vp))
+            if model.config.prefill_blocks and "k_page_scales" not in big:
+                # the dense rows ARE page rows ([T, Hkv*D]): every
+                # block in one scatter (a 16,384 bucket is 256 blocks)
+                def paged(rows, pool):
+                    return rows[0, :n_blocks * page].astype(
+                        pool.dtype).reshape(n_blocks, *pool.shape[1:])
+                kp = kp.at[table_row[:n_blocks]].set(paged(sm["k"], kp))
+                vp = vp.at[table_row[:n_blocks]].set(paged(sm["v"], vp))
+            else:
+                for b in range(n_blocks):
+                    krows = sm["k"][0, b * page:(b + 1) * page]
+                    vrows = sm["v"][0, b * page:(b + 1) * page]
+                    kp = kp.at[table_row[b]].set(_pool_rows(krows, kp))
+                    vp = vp.at[table_row[b]].set(_pool_rows(vrows, vp))
             out = {
                 "k_pages": kp, "v_pages": vp,
                 "block_table":
@@ -401,11 +485,13 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
     small = inf.init_cache(model, params, 1)
 
     def seed(big, sm):
-        if not isinstance(big, dict):
-            # A per-slot state is of no page: the engine sends a
-            # model that has one down the whole-prompt path instead.
+        if not isinstance(big, dict) or "k_ring" in big:
+            # A per-slot state, and a window layer's ring, are of no
+            # page: the engine sends a model that has one down the
+            # whole-prompt path instead.
             raise NotImplementedError(
-                "a shared-prefix prefill cannot seed a per-slot state")
+                "a shared-prefix prefill cannot seed a per-slot state "
+                "or a window layer's ring")
         if isinstance(big, dict) and "k_pages" in big:
             rows = tfm.prefix_rows_from_pages(big, prefix_ids, page)
             nrows = rows["k"].shape[0]
@@ -664,6 +750,9 @@ class Launch:
     # experts (moe.experts_road: "dense" / "grouped"); "" for a model
     # without routed layers
     road: str = ""
+    # prefill: the forwards the bucket's program runs (segments of at
+    # most the engine's prefill_chunk)
+    chunks: int = 0
 
     @classmethod
     def landing(cls, kind: str, dispatched_at: float, previous: float,
@@ -688,7 +777,8 @@ class Launch:
         else:
             out.update(path=self.path, bucket=self.bucket,
                        tokens=self.tokens,
-                       request_id=self.request_id, road=self.road)
+                       request_id=self.request_id, road=self.road,
+                       chunks=self.chunks)
         return out
 
 
@@ -815,7 +905,8 @@ class ContinuousBatcher:
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {prefill_chunk}")
-        self.prefill_chunk = prefill_chunk
+        self.prefill_chunk = (window_segment(config)
+                              if prefill_chunk is None else prefill_chunk)
         self.config = inf.decode_config(config, max_decode_len)
         self.paged = kv_page_size is not None
         self.overcommit = overcommit
@@ -869,12 +960,19 @@ class ContinuousBatcher:
         # rides in the cache tree with a slot row and no cursor, is
         # overwritten when a request is seated and belongs to no page.
         self.stateful = tfm.has_slot_state(config)
+        # The widest sliding window of the model's attention layers
+        # (0: none has one). A window layer of the paged cache keeps
+        # its newest keys in a ring a slot (transformer.ring_pages),
+        # which no page of the pool names either.
+        self.window = max(tfm.attention_windows(config), default=0)
         if speculative is not None and (
-                self.stateful or not config.tie_embeddings):
+                self.stateful or self.window
+                or not config.tie_embeddings):
             raise ValueError(
                 "speculative serving rewinds the cache by its cursor "
                 "and scores drafts through the tied embedding: not "
-                "for a model with a per-slot state or an lm_head")
+                "for a model with a per-slot state, a window layer or "
+                "an lm_head")
         # The layers that choose experts per position: their choices
         # leave every step program with the tokens and are kept per
         # request until take_decisions hands them over.
@@ -1044,6 +1142,14 @@ class ContinuousBatcher:
             self._spec_step = functools.partial(
                 _speculative_step, self.model, draft_model,
                 self.gamma)
+
+    @property
+    def _recomputes_matched(self) -> bool:
+        """Whether a request that matched pages of the prefix index
+        still prefills its whole prompt ("recomputed"): a per-slot
+        state and a window layer's ring are of no page, so a matched
+        prefix never stands in for them."""
+        return self.stateful or bool(self.window)
 
     # tests/benchmark/test_bench_reference.py reads these two off the
     # engine to build a table row for _prefill_paged.
@@ -1579,7 +1685,9 @@ class ContinuousBatcher:
             "prefill", first.dispatched_at, self._landed_at, ready,
             first.queued, path=first.path, bucket=first.bucket,
             tokens=first.prefilled, request_id=request_id,
-            road=self._experts_road(first.bucket)))
+            road=self._experts_road(first.bucket),
+            chunks=-(-first.bucket // (self.prefill_chunk
+                                       or first.bucket))))
         with phases("slot_update"):
             slot.in_flight -= 1
             slot.generated.append(token)
@@ -1725,7 +1833,14 @@ class ContinuousBatcher:
         engine; state_slots_in_use / state_bytes_held (slots seated,
         and the per-slot state they hold) from a model without one;
         experts_held (expert matrices on this chip, over all routed
-        layers) from a model without routed layers."""
+        layers) from a model without routed layers. A paged engine
+        whose model has window layers adds their page group beside
+        the pool's (kv_pages_* stay the FULL layers'):
+        window_pages_in_use / window_pages_total (kv_pages.
+        ring_occupancy), and the keys ONE full and ONE window layer's
+        decode kernel attends in the step dispatched from this state:
+        kv_tokens_full (= live_tokens) and kv_tokens_window (each
+        seated slot's newest ``window`` at most)."""
         held = [slot.held_tokens() for slot in self._slots
                 if slot.decoding()]
         out = {"slots_active": len(held),
@@ -1733,6 +1848,11 @@ class ContinuousBatcher:
                "queued": len(self._queue), "live_tokens": sum(held)}
         if self.pages is not None:
             out.update(self.pages.occupancy(held))
+            if self.window:
+                out.update(kv_pages.ring_occupancy(
+                    held, self.num_slots, self.page_size,
+                    tfm.ring_pages(self.config, self.window),
+                    self.window))
         if self.stateful:
             seated = sum(slot.request is not None
                          for slot in self._slots)
@@ -1977,8 +2097,10 @@ class ContinuousBatcher:
             self._active = self._put(mask)
 
     def _push_tables(self) -> None:
-        """Write the canonical block table into every layer's cache
-        copy — a device buffer of its own for each layer, because the
+        """Write the canonical block table into the cache copy of
+        every layer that reads the page pool through one (a window
+        layer's ring is the slot's own: no table, nothing to push) — a
+        device buffer of its own for each layer, because the
         step programs donate the cache and one buffer under every
         layer's leaf would be donated once per layer ("Attempt to
         donate the same buffer twice"). One transfer and one small
@@ -2091,7 +2213,7 @@ class ContinuousBatcher:
         if not targets:
             return False
         tokens = len(entry.request.prompt) + len(entry.resumed)
-        if self.prefix_cache and not self.stateful:
+        if self.prefix_cache and not self._recomputes_matched:
             # Predict the POST-MATCH suffix cost: a cached prefix
             # pays a gather, not a prefill.
             tokens -= self.pages.cached_tokens(
@@ -2228,8 +2350,9 @@ class ContinuousBatcher:
             return ("cold", prompt.shape[1], len(tokens),
                     self._prefill_paged,
                     (slot, prompt, self._put(seat.row), len(tokens)))
-        if self.stateful:
-            # The state at the prompt's end is of no page, so the
+        if self._recomputes_matched:
+            # The state at the prompt's end (a window layer's ring:
+            # its newest keys) is of no page, so the
             # whole prompt runs again, down the cold program. Its row
             # names the scratch page where the seat matched: what it
             # computes for the shared pages (immutable) is dropped,

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Optional
 
 import jax
@@ -139,6 +140,34 @@ class TransformerConfig:
     ssm: Optional[Any] = None        # models.ssm.SSMConfig
     delta: Optional[Any] = None      # models.delta.DeltaConfig
     experts: Optional[Any] = None    # models.moe.RoutedConfig
+    # One entry per layer, beside block_kinds (an entry of a layer
+    # without attention is ignored). layer_windows: the layer's
+    # sliding window, 0 = full attention: key j is visible to query i
+    # iff j <= i and (window == 0 or j > i - window), the query's own
+    # key counted. A window layer of a PAGED decode cache keeps a
+    # slot-owned ring of ring_pages(cfg, window) pages instead of a
+    # block table into the pool (Attention._decode_attend_ring).
+    # layer_rope: whether the layer rotates q and k. None = no window
+    # anywhere / use_rope everywhere.
+    layer_windows: Optional[tuple] = None
+    layer_rope: Optional[tuple] = None
+    # True: the router of an "experts" block reads the normed INPUT of
+    # the mixer block before it (a layer whose router is placed before
+    # its token mixer; the experts' matmuls still read their own
+    # norm's output).
+    router_before_mixer: bool = False
+    # True: a decode-mode multi-token insert (a serving prefill)
+    # attends in blocks over the keys its mask admits
+    # (ops/attention.cached_prefill_attention: bounded by the segment
+    # and the window, a Pallas kernel on a TPU) instead of one masked
+    # softmax over [chunk, max_decode_len] scores, and the dense cache
+    # keeps its rows as that kernel reads them, [B, T, Hkv*D].
+    prefill_blocks: bool = False
+    # The type a window or grouped-kernel layer's decode attention
+    # and a prefill_blocks insert keep their softmax's scores and
+    # running terms in (ops/attention.kept_in). bfloat16 is the
+    # nearest precision below: what a check's control switches on.
+    attn_softmax_dtype: Any = jnp.float32
 
     @property
     def kv_heads(self) -> int:
@@ -174,10 +203,50 @@ def decision_layer_names(cfg: TransformerConfig) -> tuple:
                  if kind == "experts")
 
 
+ATTENTION_KINDS = ("dense", "dense_moe", "attn")
+
+
+def layer_window(cfg: TransformerConfig, idx: int) -> int:
+    """Layer idx's sliding window (0 = full attention)."""
+    if cfg.layer_windows is None:
+        return 0
+    if len(cfg.layer_windows) != cfg.n_layers:
+        raise ValueError(f"layer_windows {cfg.layer_windows!r} is not "
+                         f"one entry for each of {cfg.n_layers} layers")
+    return int(cfg.layer_windows[idx])
+
+
+def layer_rope(cfg: TransformerConfig, idx: int) -> bool:
+    """Whether layer idx rotates q and k."""
+    if cfg.layer_rope is None:
+        return cfg.use_rope
+    if len(cfg.layer_rope) != cfg.n_layers:
+        raise ValueError(f"layer_rope {cfg.layer_rope!r} is not one "
+                         f"entry for each of {cfg.n_layers} layers")
+    return bool(cfg.layer_rope[idx])
+
+
+def attention_windows(cfg: TransformerConfig) -> tuple:
+    """The window of every layer that keeps K/V, in layer order."""
+    return tuple(layer_window(cfg, i)
+                 for i, kind in enumerate(layer_kinds(cfg))
+                 if kind in ATTENTION_KINDS)
+
+
+def ring_pages(cfg: TransformerConfig, window: int) -> int:
+    """Pages a slot's ring holds in a window layer of the paged
+    cache: ceil(window / page) + 1 (the window's keys straddle that
+    many pages at most), never more than a whole context's."""
+    page = cfg.kv_page_size
+    return min(-(-window // page) + 1,
+               -(-(cfg.max_decode_len + cfg.spec_window) // page))
+
+
 def paged_layer_count(cfg: TransformerConfig) -> int:
-    """How many layers keep K/V (and so a block table)."""
-    return sum(kind in ("dense", "dense_moe", "attn")
-               for kind in layer_kinds(cfg))
+    """How many layers keep K/V behind a block table into the page
+    pool: the attention layers without a window (a window layer's
+    ring is the slot's own and needs none)."""
+    return sum(not window for window in attention_windows(cfg))
 
 
 def has_slot_state(cfg: TransformerConfig) -> bool:
@@ -293,6 +362,11 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     config: TransformerConfig
+    # this layer's sliding window (0 = full attention) and whether it
+    # rotates q and k (None = config.use_rope): TransformerConfig's
+    # layer_windows / layer_rope, handed down by the block
+    window: int = 0
+    rope: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -334,7 +408,7 @@ class Attention(nn.Module):
         q = q.reshape(batch, seq, cfg.n_heads, cfg.d_head)
         k = k.reshape(batch, seq, cfg.kv_heads, cfg.d_head)
         v = v.reshape(batch, seq, cfg.kv_heads, cfg.d_head)
-        if cfg.use_rope:
+        if cfg.use_rope if self.rope is None else self.rope:
             q = rotary_embedding(q, positions, cfg.rope_theta)
             k = rotary_embedding(k, positions, cfg.rope_theta)
         if cfg.decode:
@@ -354,13 +428,19 @@ class Attention(nn.Module):
         attention_fn = cfg.attention_fn or (
             lambda q_, k_, v_, causal: attn_ops.attention(
                 q_, k_, v_, causal=causal))
-        if cfg.kv_heads != cfg.n_heads:
-            # The training-path kernels take one K/V head a query
-            # head: each K/V head repeated for its group.
-            group = cfg.n_heads // cfg.kv_heads
-            k = jnp.repeat(k, group, axis=2)
-            v = jnp.repeat(v, group, axis=2)
-        out = attention_fn(q, k, v, causal=True)
+        if self.window:
+            # the band, over grouped K/V as they are
+            out = attn_ops.blockwise_mha(
+                q, k, v, causal=True, window=self.window,
+                block_size=math.gcd(seq, 512))
+        else:
+            if cfg.kv_heads != cfg.n_heads:
+                # The training-path kernels take one K/V head a query
+                # head: each K/V head repeated for its group.
+                group = cfg.n_heads // cfg.kv_heads
+                k = jnp.repeat(k, group, axis=2)
+                v = jnp.repeat(v, group, axis=2)
+            out = attention_fn(q, k, v, causal=True)
         out = self._gated(out.reshape(batch, seq, features), x)
         out = dense(cfg.d_model, "o_proj")(out)
         if cfg.tp_axis:
@@ -399,12 +479,19 @@ class Attention(nn.Module):
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
         batch, seq, heads, depth = q.shape
         kv_heads = k.shape[2]
+        # prefill_blocks: the rows as the blockwise prefill's kernel
+        # reads them, heads folded into the lanes
+        folded = cfg.prefill_blocks
+        if int8_kv and (folded or self.window):
+            raise NotImplementedError(
+                "no int8 KV under prefill_blocks or a window")
+        row = (kv_heads * depth,) if folded else (kv_heads, depth)
         cache_k = self.variable(
             "cache", "k", jnp.zeros,
-            (batch, cfg.max_decode_len, kv_heads, depth), store_dtype)
+            (batch, cfg.max_decode_len) + row, store_dtype)
         cache_v = self.variable(
             "cache", "v", jnp.zeros,
-            (batch, cfg.max_decode_len, kv_heads, depth), store_dtype)
+            (batch, cfg.max_decode_len) + row, store_dtype)
         if int8_kv:
             # Per-(position, head) absmax scales; fp32 so dequant
             # error is the int8 rounding alone.
@@ -433,11 +520,14 @@ class Attention(nn.Module):
                 scale_k.value = scale_k.value.at[rows, idx].set(ks)
                 scale_v.value = scale_v.value.at[rows, idx].set(vs)
             cache_k.value = cache_k.value.at[rows, idx].set(
-                k_in.astype(store_dtype))
+                k_in.astype(store_dtype).reshape(batch, *row))
             cache_v.value = cache_v.value.at[rows, idx].set(
-                v_in.astype(store_dtype))
+                v_in.astype(store_dtype).reshape(batch, *row))
             index.value = idx + 1
-            mask = (key_pos[None, :] <= idx[:, None])[:, None, None, :]
+            visible = key_pos[None, :] <= idx[:, None]
+            if self.window:
+                visible &= key_pos[None, :] > idx[:, None] - self.window
+            mask = visible[:, None, None, :]
         else:
             rows = jnp.arange(batch)[:, None]                 # [B, 1]
             cols = idx[:, None] + jnp.arange(seq)[None, :]    # [B, S]
@@ -448,15 +538,25 @@ class Attention(nn.Module):
                 scale_k.value = scale_k.value.at[rows, cols].set(ks)
                 scale_v.value = scale_v.value.at[rows, cols].set(vs)
             cache_k.value = cache_k.value.at[rows, cols].set(
-                k_in.astype(store_dtype))
+                k_in.astype(store_dtype).reshape(batch, seq, *row))
             cache_v.value = cache_v.value.at[rows, cols].set(
-                v_in.astype(store_dtype))
+                v_in.astype(store_dtype).reshape(batch, seq, *row))
             index.value = idx + seq
+            if folded:
+                # in blocks, over the rows the mask admits alone
+                return attn_ops.cached_prefill_attention(
+                    q, cache_k.value, cache_v.value, idx,
+                    window=self.window,
+                    softmax_dtype=cfg.attn_softmax_dtype).astype(
+                        cfg.dtype)
             # Causal over absolute cache positions: query s (absolute
             # idx+s) sees keys <= idx+s — earlier chunks AND the
             # causal prefix of this one.
-            mask = (key_pos[None, None, :] <=
-                    cols[:, :, None])[:, None, :, :]  # [B, 1, S, T]
+            visible = key_pos[None, None, :] <= cols[:, :, None]
+            if self.window:
+                visible &= key_pos[None, None, :] > \
+                    cols[:, :, None] - self.window
+            mask = visible[:, None, :, :]             # [B, 1, S, T]
         if int8_kv and seq == 1 and kv_heads == heads:
             # Single-token decode dispatches through
             # ops/decode_attention: impl='kernel' dequantizes the
@@ -486,8 +586,53 @@ class Attention(nn.Module):
                 cache_v.value,
                 scale_v.value[..., None]).astype(cfg.dtype)
         else:
-            k_all, v_all = cache_k.value, cache_v.value
+            k_all, v_all = (cache.value.reshape(
+                batch, cfg.max_decode_len, kv_heads, depth)
+                for cache in (cache_k, cache_v))
         return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
+
+    def _decode_attend_ring(self, q, k, v):
+        """A WINDOW layer's paged decode attention: the slot keeps its
+        newest keys in a ring of its own, ring_pages(cfg, window)
+        pages of the layer's [B * ring, page, Hkv*D] leaves (slot b's
+        are b * ring .. (b + 1) * ring - 1; position p is row p % page
+        of ring page (p // page) % ring), never more however long the
+        context grows: no block table, no page of the shared pool, no
+        books on the host. The window's keys straddle ring - 1 pages
+        at most, so the row a step writes overwrites a key the window
+        has already left. One token a call (a prefill fills the ring
+        from its batch-1 cache: serving._prefill_paged)."""
+        cfg = self.config
+        batch, seq, heads, depth = q.shape
+        if seq != 1 or cfg.kv_cache_dtype is not None:
+            raise NotImplementedError(
+                "a window layer's ring takes one token a call, in the "
+                "served type (no speculative verify block, no int8)")
+        page = cfg.kv_page_size
+        ring = ring_pages(cfg, self.window)
+        width = k.shape[2] * depth
+        k_ring = self.variable(
+            "cache", "k_ring", jnp.zeros,
+            (batch * ring, page, width), cfg.dtype)
+        v_ring = self.variable(
+            "cache", "v_ring", jnp.zeros,
+            (batch * ring, page, width), cfg.dtype)
+        length = self.variable(
+            "cache", "length", lambda: jnp.zeros((batch,), jnp.int32))
+        idx = length.value                                   # [B]
+        table = jnp.arange(batch * ring, dtype=jnp.int32).reshape(
+            batch, ring)
+        page_idx = table[:, 0] + (idx // page) % ring
+        offset = idx % page
+        k_ring.value = k_ring.value.at[page_idx, offset].set(
+            k.astype(cfg.dtype).reshape(batch, width))
+        v_ring.value = v_ring.value.at[page_idx, offset].set(
+            v.astype(cfg.dtype).reshape(batch, width))
+        length.value = idx + 1
+        return paged_ops.paged_decode_attention(
+            q, k_ring.value, v_ring.value, table, length.value,
+            impl=cfg.paged_attention_impl, window=self.window,
+            softmax_dtype=cfg.attn_softmax_dtype).astype(cfg.dtype)
 
     def _decode_attend_paged(self, q, k, v):
         """Paged decode attention (vLLM-style block tables): K/V live
@@ -512,6 +657,8 @@ class Attention(nn.Module):
         identically (models/serving.py).
         """
         cfg = self.config
+        if self.window:
+            return self._decode_attend_ring(q, k, v)
         int8_kv = cfg.kv_cache_dtype == "int8"  # validated at dispatch
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
         batch, seq, heads, depth = q.shape
@@ -579,8 +726,8 @@ class Attention(nn.Module):
                 q, k_pages.value, v_pages.value, block_table.value,
                 length.value, impl=cfg.paged_attention_impl,
                 k_scales=scale_k.value if int8_kv else None,
-                v_scales=scale_v.value if int8_kv else None).astype(
-                    cfg.dtype)
+                v_scales=scale_v.value if int8_kv else None,
+                softmax_dtype=cfg.attn_softmax_dtype).astype(cfg.dtype)
         # Multi-token verify pass: gather the slot's full logical view
         # and attend causally over absolute cache positions (query s
         # at position idx+s sees keys <= idx+s) — the paged analog of
@@ -720,6 +867,8 @@ class MLP(nn.Module):
 class Block(nn.Module):
     config: TransformerConfig
     use_moe: bool = False
+    window: int = 0                 # Attention's, for this layer
+    rope: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -733,9 +882,10 @@ class Block(nn.Module):
                     "decode / moe)")
             # The norms live INSIDE Attention/MLP (fused into their
             # first projection); pass the raw residual stream.
-            x = x + Attention(cfg, name="attn")(x, positions)
+            x = x + Attention(cfg, self.window, self.rope,
+                              name="attn")(x, positions)
             return x + MLP(cfg, name="mlp")(x)
-        x = x + Attention(cfg, name="attn")(
+        x = x + Attention(cfg, self.window, self.rope, name="attn")(
             RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                     name="attn_norm")(x), positions)
         normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
@@ -755,12 +905,17 @@ class MixerBlock(nn.Module):
     the mixer of ``kind`` (MIXER_KINDS) and named by it, so that the
     tree, the cache and a device trace's operation names all say which
     kind a layer is (layer_3/ssm/..., layer_4/delta/...,
-    layer_5/attn/...)."""
+    layer_5/attn/...). -> (the block's output, its normed input:
+    what the NEXT block's router reads in a model whose router is
+    placed before the token mixer, ``router_input`` here)."""
     config: TransformerConfig
     kind: str = "attn"
+    window: int = 0                 # Attention's, for this layer
+    rope: Optional[bool] = None
 
     @nn.compact
-    def __call__(self, x, positions, valid_len=None):
+    def __call__(self, x, positions, valid_len=None,
+                 router_input=None):
         cfg = self.config
         normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                          name="norm")(x)
@@ -774,10 +929,11 @@ class MixerBlock(nn.Module):
             from batch_shipyard_tpu.models.moe import RoutedExperts
             out = RoutedExperts(cfg.experts, dtype=cfg.dtype,
                                 param_dtype=cfg.param_dtype,
-                                name="experts")(normed)
+                                name="experts")(normed, router_input)
         else:
-            out = Attention(cfg, name="attn")(normed, positions)
-        return x + out
+            out = Attention(cfg, self.window, self.rope,
+                            name="attn")(normed, positions)
+        return x + out, normed
 
 
 class TransformerLM(nn.Module):
@@ -807,12 +963,25 @@ class TransformerLM(nn.Module):
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
             mixer_block = nn.remat(MixerBlock, static_argnums=())
+        mixer_input = None      # the last token mixer's normed input
         for idx, kind in enumerate(layer_kinds(cfg)):
+            per_layer = (layer_window(cfg, idx), layer_rope(cfg, idx)) \
+                if kind in ATTENTION_KINDS else ()
             if kind in MIXER_KINDS:
-                x = mixer_block(cfg, kind, name=f"layer_{idx}")(
-                    x, positions, valid_len)
+                router_input = None
+                if kind == "experts" and cfg.router_before_mixer:
+                    if mixer_input is None:
+                        raise ValueError(
+                            "router_before_mixer: an experts block "
+                            "with no token mixer before it")
+                    router_input = mixer_input
+                x, normed = mixer_block(
+                    cfg, kind, *per_layer, name=f"layer_{idx}")(
+                        x, positions, valid_len, router_input)
+                if kind != "experts":
+                    mixer_input = normed
             else:
-                x = block(cfg, kind == "dense_moe",
+                x = block(cfg, kind == "dense_moe", *per_layer,
                           name=f"layer_{idx}")(x, positions)
         x = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                     name="final_norm")(x)

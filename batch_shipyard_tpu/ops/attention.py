@@ -51,51 +51,85 @@ def flash_shapes_ok(t_q: int, t_kv: int) -> bool:
     return ok(t_q, FLASH_BLOCK_Q) and ok(t_kv, FLASH_BLOCK_K)
 
 
-def _causal_mask(q_positions, k_positions):
-    """[Tq, Tk] True where attention is allowed (k <= q)."""
-    return q_positions[:, None] >= k_positions[None, :]
+def _causal_mask(q_positions, k_positions, window: int = 0):
+    """[Tq, Tk] True where attention is allowed: k <= q, and under a
+    ``window`` also k > q - window (the query's own key included, so a
+    query sees its ``window`` newest keys)."""
+    mask = q_positions[:, None] >= k_positions[None, :]
+    if window:
+        mask &= k_positions[None, :] > q_positions[:, None] - window
+    return mask
+
+
+def _grouped_scores(q, k):
+    """q [B, Tq, H, D] against k [B, Tk, Hkv, D] -> float32
+    [B, H, Tq, Tk]; H // Hkv query heads read each K/V head and no
+    K/V row is repeated."""
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if kv_heads == heads:
+        return jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                          preferred_element_type=jnp.float32)
+    grouped = q.reshape(*q.shape[:2], kv_heads, heads // kv_heads,
+                        q.shape[3])
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", grouped, k,
+                        preferred_element_type=jnp.float32)
+    return scores.reshape(q.shape[0], heads, q.shape[1], k.shape[1])
+
+
+def _grouped_values(p, v):
+    """p [B, H, Tq, Tk] against v [B, Tk, Hkv, D] -> float32
+    [B, Tq, H, D], the grouping of _grouped_scores."""
+    heads, kv_heads = p.shape[1], v.shape[2]
+    if kv_heads == heads:
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v,
+                          preferred_element_type=jnp.float32)
+    grouped = p.reshape(p.shape[0], kv_heads, heads // kv_heads,
+                        *p.shape[2:])
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", grouped, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(p.shape[0], p.shape[2], heads, v.shape[3])
 
 
 def mha_reference(q, k, v, causal: bool = True,
-                  q_offset: int = 0, kv_offset: int = 0):
-    """Plain attention; the numerics oracle for the fast paths."""
+                  q_offset: int = 0, kv_offset: int = 0,
+                  window: int = 0):
+    """Plain attention; the numerics oracle for the fast paths. k/v
+    may hold fewer heads than q (grouped-query); ``window`` (causal
+    only) bounds each query to its newest ``window`` keys."""
     depth = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
+    scores = _grouped_scores(q, k)
     scores = scores / math.sqrt(depth)
     if causal:
         q_pos = q_offset + jax.lax.broadcasted_iota(
             jnp.int32, (q.shape[1], 1), 0)[:, 0]
         k_pos = kv_offset + jax.lax.broadcasted_iota(
             jnp.int32, (k.shape[1], 1), 0)[:, 0]
-        mask = _causal_mask(q_pos, k_pos)
+        mask = _causal_mask(q_pos, k_pos, window)
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return _grouped_values(probs.astype(v.dtype), v).astype(q.dtype)
 
 
 # ----------------------- online-softmax accumulation -------------------
 
 def attention_block_update(q, k_blk, v_blk, o, m, l, *, causal: bool,
-                           q_offset, kv_offset, scale: float):
+                           q_offset, kv_offset, scale: float,
+                           window: int = 0):
     """One online-softmax accumulation step against a KV block.
 
-    q: [B, Tq, H, D]; k_blk/v_blk: [B, Tk, H, D]
+    q: [B, Tq, H, D]; k_blk/v_blk: [B, Tk, Hkv, D] (Hkv divides H)
     o: [B, Tq, H, D] float32 numerator
     m: [B, H, Tq] running max; l: [B, H, Tq] running denominator.
     q_offset/kv_offset: global positions (ints or traced scalars).
+    ``window`` (causal only): a query sees its newest ``window`` keys.
     """
-    depth = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk,
-                        preferred_element_type=jnp.float32) * scale
+    scores = _grouped_scores(q, k_blk) * scale
     if causal:
         q_pos = q_offset + jax.lax.broadcasted_iota(
             jnp.int32, (q.shape[1], 1), 0)[:, 0]
         k_pos = kv_offset + jax.lax.broadcasted_iota(
             jnp.int32, (k_blk.shape[1], 1), 0)[:, 0]
-        mask = _causal_mask(q_pos, k_pos)
+        mask = _causal_mask(q_pos, k_pos, window)
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     m_blk = jnp.max(scores, axis=-1)
     m_new = jnp.maximum(m, m_blk)
@@ -103,9 +137,12 @@ def attention_block_update(q, k_blk, v_blk, o, m, l, *, causal: bool,
     # contribute nothing (exp(-inf - -inf) handled via where).
     correction = jnp.exp(m - m_new)
     p = jnp.exp(scores - m_new[..., None])
+    if window:
+        # a block wholly behind the window leaves m at -inf: its
+        # exp(0) = 1 must not count
+        p = jnp.where(scores > _NEG_INF / 2, p, 0.0)
     l_new = l * correction + jnp.sum(p, axis=-1)
-    pv = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v_blk.dtype), v_blk,
-                    preferred_element_type=jnp.float32)
+    pv = _grouped_values(p.astype(v_blk.dtype), v_blk)
     o_new = o * correction.transpose(0, 2, 1)[..., None] + pv
     return o_new, m_new, l_new
 
@@ -124,8 +161,12 @@ def attention_finalize(q, o, m, l):
 
 
 def blockwise_mha(q, k, v, causal: bool = True, block_size: int = 512,
-                  q_offset: int = 0, kv_offset: int = 0):
-    """Memory-efficient attention: scan KV blocks with online softmax."""
+                  q_offset: int = 0, kv_offset: int = 0,
+                  window: int = 0):
+    """Memory-efficient attention: scan KV blocks with online softmax.
+    k/v may hold fewer heads than q (grouped-query: no row repeated);
+    ``window`` (causal only) is the band: a query sees its newest
+    ``window`` keys."""
     batch, t_kv = k.shape[0], k.shape[1]
     block_size = min(block_size, t_kv)
     if t_kv % block_size:
@@ -148,7 +189,8 @@ def blockwise_mha(q, k, v, causal: bool = True, block_size: int = 512,
         o, m, l = attention_block_update(
             q, k_blk, v_blk, o, m, l, causal=causal,
             q_offset=q_offset,
-            kv_offset=kv_offset + blk_idx * block_size, scale=scale)
+            kv_offset=kv_offset + blk_idx * block_size, scale=scale,
+            window=window)
         return (o, m, l), None
 
     carry = attention_init(q)
@@ -542,3 +584,243 @@ def attention(q, k, v, causal: bool = True,
     if impl == "reference":
         return mha_reference(q, k, v, causal)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+# ------------------- prefill over a cache, in blocks -------------------
+# A serving prefill (models/transformer.py, the multi-token insert of
+# a decode-mode model) has written a segment's K/V rows into a cache
+# [B, T, Hkv*D] and asks for the segment's queries against the rows
+# its mask admits: key j is visible to the query at position i iff
+# j <= i and (no window or j > i - window). Masked scores over the
+# whole cache width ([S, T] a head) are what that path materialised;
+# here the work is bounded by what is visible: the key blocks from the
+# first the window still touches to the last the segment reaches.
+
+PREFILL_BLOCK_Q = 256
+PREFILL_BLOCK_K = 512
+PREFILL_KERNEL_NAME = "flash_prefill_cached"
+
+
+def kept_in(x, dtype):
+    """A float32 term of a softmax (a score, the running maximum,
+    denominator or weighted sum) as it reads once KEPT in ``dtype``:
+    itself in float32; rounded to it and back in bfloat16, the
+    nearest precision below, which a check's control switches on."""
+    return x if jnp.dtype(dtype) == jnp.float32 else x.astype(
+        dtype).astype(jnp.float32)
+
+
+def _band_blocks(start, first_q: int, last_q: int, window: int,
+                 block_k: int, num_kb):
+    """(first, last) key block a query block at positions start +
+    first_q .. start + last_q reads (inclusive)."""
+    low = jnp.maximum(start + first_q - window + 1, 0) if window else 0
+    first = low // block_k
+    last = jnp.minimum((start + last_q) // block_k, num_kb - 1)
+    return first, last
+
+
+def cached_prefill_attention_xla(q, k_cache, v_cache, start,
+                                 window: int = 0,
+                                 block_k: int = PREFILL_BLOCK_K,
+                                 softmax_dtype=jnp.float32):
+    """q [B, S, H, D] at positions start[b] .. start[b] + S - 1
+    against cache rows k_cache / v_cache [B, T, Hkv*D] -> [B, S, H, D].
+    A loop (dynamic trip count) over the key blocks between the first
+    the window still touches for the segment's first query and the
+    last its last query reaches, online softmax across them (its
+    scores and running terms kept in ``softmax_dtype``:
+    kept_in): the XLA formulation, and the oracle
+    of the kernel below."""
+    batch, seq, heads, depth = q.shape
+    rows = k_cache.shape[1]
+    kv_heads = k_cache.shape[2] // depth
+    block_k = math.gcd(rows, block_k)
+    scale = 1.0 / math.sqrt(depth)
+    start = jnp.asarray(start, jnp.int32).reshape(-1)
+    # from the first block the earliest query's band touches to the
+    # last the latest query reaches
+    first, _ = _band_blocks(jnp.min(start), 0, 0, window, block_k,
+                            rows // block_k)
+    _, last = _band_blocks(jnp.max(start), 0, seq - 1, window, block_k,
+                           rows // block_k)
+    q_pos = start[:, None] + jnp.arange(seq, dtype=jnp.int32)[None]
+
+    def body(kb, carry):
+        k_blk, v_blk = (jax.lax.dynamic_slice_in_dim(
+            cache, kb * block_k, block_k, axis=1).reshape(
+                batch, block_k, kv_heads, depth)
+            for cache in (k_cache, v_cache))
+        k_pos = kb * block_k + jnp.arange(block_k, dtype=jnp.int32)
+        mask = k_pos[None, None, :] <= q_pos[:, :, None]
+        if window:
+            mask &= k_pos[None, None, :] > q_pos[:, :, None] - window
+        o, m, l = carry
+        scores = jnp.where(mask[:, None], kept_in(
+            _grouped_scores(q, k_blk) * scale, softmax_dtype), _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        correction = jnp.exp(m - m_new)
+        p = jnp.where(mask[:, None],
+                      jnp.exp(scores - m_new[..., None]), 0.0)
+        l = l * correction + jnp.sum(p, axis=-1)
+        o = o * correction.transpose(0, 2, 1)[..., None] + \
+            _grouped_values(p.astype(v_blk.dtype), v_blk)
+        return (kept_in(o, softmax_dtype), m_new,
+                kept_in(l, softmax_dtype))
+
+    o, m, l = jax.lax.fori_loop(first, last + 1, body, attention_init(q))
+    return attention_finalize(q, o, m, l)
+
+
+def _flash_prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
+                          o_acc, m_acc, l_acc, *, block_q: int,
+                          block_k: int, group: int, depth: int,
+                          window: int, num_kb: int, scale: float,
+                          softmax_dtype):
+    """One (batch, K/V head, query block, key step) program: the
+    query block's ``group`` heads, stacked along the rows, against one
+    key block; online softmax across the key steps in VMEM scratch.
+    Key step j reads block first + j; steps past the last block the
+    query block reaches compute nothing (their index map clamps, so
+    nothing is fetched for them either)."""
+    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    start = start_ref[b]
+    first, last = _band_blocks(start, qi * block_q,
+                               (qi + 1) * block_q - 1, window, block_k,
+                               num_kb)
+
+    @pl.when(j == 0)
+    def _init():
+        o_acc[...] = jnp.zeros_like(o_acc)
+        m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
+        l_acc[...] = jnp.zeros_like(l_acc)
+
+    @pl.when(first + j <= last)
+    def _accumulate():
+        q = q_ref[...]                               # [bq, G*D]
+        stacked = jnp.concatenate(
+            [q[:, g * depth:(g + 1) * depth] for g in range(group)],
+            axis=0)                                  # [G*bq, D]
+        scores = jax.lax.dot_general(
+            stacked, k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        scores = kept_in(scores, softmax_dtype)
+        q_pos = start + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = (first + j) * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        mask = k_pos <= q_pos
+        if window:
+            mask &= k_pos > q_pos - window
+        mask = jnp.concatenate([mask] * group, axis=0)
+        scores = jnp.where(mask, scores, _NEG_INF)
+        m_prev = m_acc[...]
+        m_new = jnp.maximum(m_prev,
+                            jnp.max(scores, axis=1, keepdims=True))
+        correction = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        l_acc[...] = kept_in(l_acc[...] * correction + jnp.sum(
+            p, axis=1, keepdims=True), softmax_dtype)
+        m_acc[...] = m_new
+        o_acc[...] = kept_in(
+            o_acc[...] * correction + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[...],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32), softmax_dtype)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _emit():
+        l_final = l_acc[...]
+        out = o_acc[...] / jnp.where(l_final == 0.0, 1.0, l_final)
+        o_ref[...] = jnp.concatenate(
+            [out[g * block_q:(g + 1) * block_q] for g in range(group)],
+            axis=1).astype(o_ref.dtype)
+
+
+def prefill_kernel_shapes_ok(seq: int, rows: int, depth: int) -> bool:
+    """Whether the kernel's blocks tile these shapes: lane-wide heads,
+    whole query and key blocks."""
+    return (depth % 128 == 0 and seq % min(PREFILL_BLOCK_Q, seq) == 0
+            and seq % 8 == 0
+            and rows % min(PREFILL_BLOCK_K, rows) == 0
+            and min(PREFILL_BLOCK_K, rows) % 128 == 0)
+
+
+def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
+                                    window: int = 0,
+                                    interpret: bool = False,
+                                    softmax_dtype=jnp.float32):
+    """cached_prefill_attention_xla as a Pallas kernel: scores stay in
+    VMEM, a K/V head's ``group`` query heads share each key block's
+    one read, and a query block visits only the key blocks its band
+    touches (``window`` > 0: ceil((window + block_q) / block_k) + 1 of
+    them whatever the cache's length)."""
+    batch, seq, heads, depth = q.shape
+    rows = k_cache.shape[1]
+    kv_heads = k_cache.shape[2] // depth
+    group = heads // kv_heads
+    block_q = min(PREFILL_BLOCK_Q, seq)
+    block_k = min(PREFILL_BLOCK_K, rows)
+    num_kb = rows // block_k
+    steps = num_kb if not window else min(
+        num_kb, -(-(window + block_q - 1) // block_k) + 1)
+
+    def q_map(b, h, qi, j, start_ref):
+        return (b, qi, h)
+
+    def kv_map(b, h, qi, j, start_ref):
+        first, last = _band_blocks(
+            start_ref[b], qi * block_q, (qi + 1) * block_q - 1, window,
+            block_k, num_kb)
+        return (b, jnp.minimum(first + j, last), h)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(batch, kv_heads, seq // block_q, steps),
+        in_specs=[pl.BlockSpec((None, block_q, group * depth), q_map),
+                  pl.BlockSpec((None, block_k, depth), kv_map),
+                  pl.BlockSpec((None, block_k, depth), kv_map)],
+        out_specs=pl.BlockSpec((None, block_q, group * depth), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((group * block_q, depth), jnp.float32),
+            pltpu.VMEM((group * block_q, 1), jnp.float32),
+            pltpu.VMEM((group * block_q, 1), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_prefill_kernel, block_q=block_q, block_k=block_k,
+            group=group, depth=depth, window=int(window),
+            num_kb=num_kb, scale=1.0 / math.sqrt(depth),
+            softmax_dtype=softmax_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, seq, heads * depth),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name=PREFILL_KERNEL_NAME, interpret=interpret,
+    )(jnp.asarray(start, jnp.int32).reshape(-1),
+      q.reshape(batch, seq, heads * depth), k_cache, v_cache)
+    return out.reshape(batch, seq, heads, depth)
+
+
+def cached_prefill_attention(q, k_cache, v_cache, start,
+                             window: int = 0,
+                             impl: Optional[str] = None,
+                             softmax_dtype=jnp.float32):
+    """Dispatch: 'kernel' (Pallas) on a TPU backend where its blocks
+    tile the shapes, else 'xla'; a named impl passes through."""
+    if impl is None:
+        impl = "kernel" if (
+            jax.default_backend() == "tpu" and prefill_kernel_shapes_ok(
+                q.shape[1], k_cache.shape[1], q.shape[3])) else "xla"
+    if impl == "kernel":
+        return cached_prefill_attention_kernel(
+            q, k_cache, v_cache, start, window,
+            softmax_dtype=softmax_dtype)
+    if impl != "xla":
+        raise ValueError(f"unknown prefill attention impl {impl!r}")
+    return cached_prefill_attention_xla(q, k_cache, v_cache, start,
+                                        window,
+                                        softmax_dtype=softmax_dtype)

@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from batch_shipyard_tpu.ops.attention import kept_in
+
 _NEG_INF = -1e30
 
 
@@ -229,10 +231,12 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     return out.reshape(batch, 1, heads, depth)
 
 
-def masked_attention(q, k_all, v_all, mask, dtype):
+def masked_attention(q, k_all, v_all, mask, dtype,
+                     softmax_dtype=jnp.float32):
     """Softmax attention of q [B, S, H, D] over cached rows k_all /
     v_all [B, T, Hkv, D] under mask [B, 1, S, T] (True = visible):
-    float32 scores and accumulation, the probabilities in ``dtype``.
+    float32 scores and accumulation (kept in ``softmax_dtype``:
+    kept_in), the probabilities in ``dtype``.
     With fewer K/V heads than query heads, H // Hkv query heads read
     each K/V head and no K/V row is repeated."""
     batch, seq, heads, depth = q.shape
@@ -242,8 +246,9 @@ def masked_attention(q, k_all, v_all, mask, dtype):
         scores = jnp.einsum(
             "bqhd,bkhd->bhqk", q, k_all,
             preferred_element_type=jnp.float32)
-        scores = jnp.where(mask, scores / scale, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
+        scores = jnp.where(mask, kept_in(scores / scale, softmax_dtype),
+                           _NEG_INF)
+        probs = kept_in(jax.nn.softmax(scores, axis=-1), softmax_dtype)
         out = jnp.einsum(
             "bhqk,bkhd->bqhd", probs.astype(dtype), v_all,
             preferred_element_type=jnp.float32)
@@ -252,8 +257,9 @@ def masked_attention(q, k_all, v_all, mask, dtype):
     scores = jnp.einsum(
         "bqhgd,bkhd->bhgqk", grouped, k_all,
         preferred_element_type=jnp.float32)
-    scores = jnp.where(mask[:, :, None], scores / scale, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    scores = jnp.where(mask[:, :, None],
+                       kept_in(scores / scale, softmax_dtype), _NEG_INF)
+    probs = kept_in(jax.nn.softmax(scores, axis=-1), softmax_dtype)
     out = jnp.einsum(
         "bhgqk,bkhd->bqhgd", probs.astype(dtype), v_all,
         preferred_element_type=jnp.float32)
@@ -294,6 +300,247 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
     return masked_attention(q, k_all, v_all, mask, q.dtype)
 
 
+# ---------------------------------------------------------------------
+# A pool of FEWER K/V heads than query heads (grouped-query attention),
+# a layer that sees only its newest ``window`` keys, a slot-owned RING
+# of pages: one kernel, named apart from the one above in a device
+# trace (gqa_paged_decode).
+#
+# The grid is one program a SLOT, not one a (slot, page): a context of
+# 16,384 tokens is 256 pages, and 48 x 256 grid steps a layer, nine in
+# ten of them dead, cost more than the pages' read. The program walks
+# the slot's LIVE pages alone, GQA_CHUNK_PAGES at a time, fetching
+# them from the pool in HBM itself (one DMA a page into a
+# double-buffered VMEM tile, the next chunk in flight while this one
+# is attended), from the first page the window still touches to the
+# last the length reaches. Scores are [H, keys] (heads on sublanes,
+# keys on lanes) from ONE matmul of the block-diagonal query against
+# the chunk: row h holds q_h in the D columns of ITS K/V head, h // G,
+# and zeros elsewhere, so G query heads read each K/V head and no K/V
+# row is repeated.
+
+GQA_CHUNK_PAGES = 8
+GQA_KERNEL_NAME = "gqa_paged_decode"
+
+
+def window_start(lengths, window: int):
+    """The first position a query at ``lengths - 1`` still sees: key j
+    is visible iff j < length and (no window or j > length - 1 -
+    window)."""
+    if not window:
+        return jnp.zeros_like(lengths)
+    return jnp.maximum(lengths - window, 0)
+
+
+def _group_block_mask(rows: int, heads: int, kv_heads: int,
+                      depth: int):
+    """[rows, Hkv*D] True where row h (a query head; rows past
+    ``heads`` are padding) meets the D columns of its K/V head."""
+    group = heads // kv_heads
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, kv_heads * depth), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, kv_heads * depth), 1)
+    mine = row // group
+    return (row < heads) & (col >= mine * depth) & (col < (mine + 1)
+                                                    * depth)
+
+
+def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
+                             o_ref, k_buf, v_buf, sems, *, page: int,
+                             chunk: int, heads: int, kv_heads: int,
+                             depth: int, window: int, scale: float,
+                             softmax_dtype):
+    """One slot: online softmax over its live pages, a chunk of
+    ``chunk`` pages a step of the inner loop, its scores and running
+    terms kept in ``softmax_dtype``."""
+    b = pl.program_id(0)
+    rows = q_ref.shape[0]
+    table_width = table_ref.shape[1]
+    span = chunk * page
+
+    @pl.when(b == 0)
+    def _clear():
+        # a chunk's tail past the last live page is never fetched:
+        # what lies there is masked by position, and must be finite
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    length = len_ref[b]
+    low = jnp.maximum(length - window, 0) if window else 0
+    first = low // page
+    last = (jnp.maximum(length, 1) - 1) // page
+    chunks = jnp.where(length > 0, (last - first) // chunk + 1, 0)
+
+    def copies(c, slot):
+        out = []
+        for i in range(chunk):
+            logical = first + c * chunk + i
+            # a table narrower than the context is a RING: logical
+            # page p lives in entry p % width
+            pid = table_ref[b, jax.lax.rem(
+                jnp.minimum(logical, last), table_width)]
+            dst = pl.ds(i * page, page)
+            out.append((logical <= last, (
+                pltpu.make_async_copy(
+                    k_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]),
+                pltpu.make_async_copy(
+                    v_hbm.at[pid], v_buf.at[slot, dst],
+                    sems.at[1, slot]))))
+        return out
+
+    def start(c, slot):
+        for live, pair in copies(c, slot):
+            @pl.when(live)
+            def _():
+                for copy in pair:
+                    copy.start()
+
+    def wait(c, slot):
+        for live, pair in copies(c, slot):
+            @pl.when(live)
+            def _():
+                for copy in pair:
+                    copy.wait()
+
+    @pl.when(chunks > 0)
+    def _first():
+        start(0, 0)
+
+    q = q_ref[...]                                       # [rows, D]
+    mask = _group_block_mask(rows, heads, kv_heads, depth)
+    q_bd = jnp.where(
+        mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
+                              axis=1), 0.0).astype(q.dtype)
+
+    def body(c, carry):
+        o, m, l = carry
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < chunks)
+        def _next():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        scores = jax.lax.dot_general(
+            q_bd, k_buf[slot].astype(q.dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [rows, span]
+        scores = kept_in(scores, softmax_dtype)
+        pos = (first + c * chunk) * page + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 1)
+        scores = jnp.where((pos >= low) & (pos < length), scores,
+                           _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+        correction = jnp.exp(m - m_new)
+        p = jnp.exp(scores - m_new)
+        l = l * correction + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(q.dtype), v_buf[slot].astype(q.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [rows, Hkv*D]
+        return (kept_in(o * correction + pv, softmax_dtype), m_new,
+                kept_in(l, softmax_dtype))
+
+    o, _m, l = jax.lax.fori_loop(
+        0, chunks, body,
+        (jnp.zeros((rows, kv_heads * depth), jnp.float32),
+         jnp.full((rows, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32)))
+    out = jnp.where(mask, o / jnp.where(l == 0.0, 1.0, l), 0.0)
+    # each row has one live block of D columns (its K/V head's): the
+    # sum over the blocks folds [rows, Hkv*D] into [rows, D]
+    o_ref[...] = sum(out[:, h * depth:(h + 1) * depth]
+                     for h in range(kv_heads)).astype(o_ref.dtype)
+
+
+def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
+                                      lengths, window: int = 0,
+                                      softmax_dtype=jnp.float32):
+    """Pallas path for a pool of Hkv <= H K/V heads. q: [B, 1, H, D];
+    k_pages/v_pages: [P, page, Hkv*D]; lengths: [B] valid-key counts
+    (the token written this step included). ``window`` > 0: a query
+    sees its newest ``window`` keys alone (positions length - window
+    .. length - 1), and no page wholly behind them is read.
+    block_table: [B, T] int32, entry p % T the page of logical page p:
+    a table as wide as the context is an ordinary block table, a
+    narrower one a RING (its T pages hold the newest T logical pages;
+    T >= ceil(window / page) + 1, so that no live key is overwritten).
+    A slot of length 0 yields zeros. ``softmax_dtype``: kept_in.
+    Returns [B, 1, H, D] in q.dtype."""
+    batch, seq, heads, depth = q.shape
+    assert seq == 1, "decode consumes one token per call"
+    page, width = k_pages.shape[1], k_pages.shape[2]
+    kv_heads = width // depth
+    if heads % kv_heads or kv_heads * depth != width:
+        raise ValueError(
+            f"{heads} query heads over a pool of {width} channels "
+            f"(heads of {depth})")
+    # whole sublane tiles of query heads (bfloat16 packs 16 a tile)
+    rows = -(-heads // 16) * 16
+    q_rows = jnp.pad(q.reshape(batch, heads, depth),
+                     ((0, 0), (0, rows - heads), (0, 0)))
+    chunk = min(GQA_CHUNK_PAGES, block_table.shape[1])
+    row_spec = pl.BlockSpec((None, rows, depth),
+                            lambda b, tbl, ln: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk * page, width), k_pages.dtype),
+            pltpu.VMEM((2, chunk * page, width), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2))],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _gqa_paged_decode_kernel, page=page, chunk=chunk,
+            heads=heads, kv_heads=kv_heads, depth=depth,
+            window=int(window), scale=1.0 / (depth ** 0.5),
+            softmax_dtype=softmax_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, rows, depth), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=GQA_KERNEL_NAME,
+    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      q_rows, k_pages, v_pages)
+    return out[:, :heads].reshape(batch, 1, heads, depth)
+
+
+def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
+                                        block_table, lengths,
+                                        window: int = 0,
+                                        softmax_dtype=jnp.float32):
+    """The kernel above as an XLA gather (the CPU/fallback path and
+    the tests' oracle): every table entry's page gathered, each row's
+    POSITION worked out from the entry it came through (entry c holds
+    the newest logical page p <= the last with p % T == c: the ring
+    rule, which for a table as wide as the context is p == c), and one
+    masked softmax over the positions the window admits."""
+    batch, seq, heads, depth = q.shape
+    assert seq == 1
+    page = k_pages.shape[1]
+    entries = block_table.shape[1]
+    kv_heads = k_pages.shape[2] // depth
+    k_all = k_pages[block_table].reshape(
+        batch, entries * page, kv_heads, depth)
+    v_all = v_pages[block_table].reshape(
+        batch, entries * page, kv_heads, depth)
+    last = (jnp.maximum(lengths, 1) - 1) // page            # [B]
+    entry = jnp.arange(entries, dtype=jnp.int32)
+    logical = last[:, None] - jnp.mod(last[:, None] - entry[None, :],
+                                      entries)              # [B, T]
+    pos = (logical[:, :, None] * page + jnp.arange(
+        page, dtype=jnp.int32)[None, None, :]).reshape(batch, -1)
+    low = window_start(lengths, window)
+    visible = (pos >= low[:, None]) & (pos < lengths[:, None]) & (
+        pos >= 0)
+    return masked_attention(q, k_all, v_all,
+                            visible[:, None, None, :], q.dtype,
+                            softmax_dtype)
+
+
 def resolve_kernel_or_xla(impl: Optional[str], what: str) -> str:
     """The decode kernels' selection rule: None -> 'kernel' (Pallas)
     on a TPU backend, 'xla' elsewhere; a named impl passes through."""
@@ -310,13 +557,34 @@ def resolve_paged_impl(impl: Optional[str] = None) -> str:
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            impl: Optional[str] = None,
-                           k_scales=None, v_scales=None):
-    """Dispatch: 'kernel' (Pallas) or 'xla' (resolve_paged_impl).
-    k_scales/v_scales switch both paths to int8-page dequant. A pool
-    of fewer K/V heads than query heads takes the xla path whatever
-    ``impl`` says: the kernel's block-diagonal query is one K/V head
-    a query head."""
+                           k_scales=None, v_scales=None,
+                           window: int = 0, softmax_dtype=jnp.float32):
+    """Dispatch: 'kernel' (Pallas) or 'xla'. k_scales/v_scales switch
+    both paths to int8-page dequant.
+
+    A pool of as many K/V heads as query heads: ``impl`` None is the
+    kernel on a TPU and xla elsewhere (resolve_paged_impl), a named
+    one passes through. A GROUPED pool (fewer K/V heads than query
+    heads): ``impl`` None is the xla gather on every backend, "kernel"
+    MEANS the grouped Pallas kernel
+    (gqa_paged_decode_attention_kernel; bfloat16/float32 pages), "xla"
+    the gather. ``window`` > 0 (a layer that sees its newest
+    ``window`` keys alone; its table may then be a RING narrower than
+    the context, entry p % T the page of logical page p) takes the
+    grouped kernel or its windowed gather whatever the grouping: the
+    kernel under "kernel", the gather otherwise; these two alone
+    keep their softmax in ``softmax_dtype`` (kept_in)."""
     grouped = k_pages.shape[2] != q.shape[2] * q.shape[3]
+    if window or (grouped and impl == "kernel"):
+        if k_scales is not None:
+            raise NotImplementedError(
+                "no int8 pages under a window or the grouped kernel")
+        if impl not in (None, "kernel", "xla"):
+            raise ValueError(f"unknown paged attention impl {impl!r}")
+        fn = (gqa_paged_decode_attention_kernel if impl == "kernel"
+              else paged_decode_attention_xla_windowed)
+        return fn(q, k_pages, v_pages, block_table, lengths,
+                  window=window, softmax_dtype=softmax_dtype)
     fn = (paged_decode_attention_kernel
           if resolve_paged_impl(impl) == "kernel" and not grouped
           else paged_decode_attention_xla)

@@ -300,3 +300,119 @@ def test_flash_ring_merge_gradients(scale):
         rel = (np.linalg.norm(gg - gr) /
                max(np.linalg.norm(gr), 1e-30))
         assert rel < 1e-4, f"relative grad error {rel:.2e}"
+
+
+# ---- the band, grouped K/V, and a prefill over a cache in blocks ----
+
+def _band_oracle(q, k, v, start, window):
+    """Masked softmax over the whole cache, written out."""
+    batch, seq, heads, depth = q.shape
+    rows = k.shape[1]
+    kv_heads = k.shape[2] // depth
+    q_pos = np.asarray(start)[:, None] + np.arange(seq)[None]
+    k_pos = np.arange(rows)
+    mask = k_pos[None, None, :] <= q_pos[:, :, None]
+    if window:
+        mask &= k_pos[None, None, :] > q_pos[:, :, None] - window
+    scores = attn._grouped_scores(
+        q, k.reshape(batch, rows, kv_heads, depth)) / np.sqrt(depth)
+    probs = jax.nn.softmax(
+        jnp.where(mask[:, None], scores, -1e30), axis=-1)
+    return attn._grouped_values(
+        probs, v.reshape(batch, rows, kv_heads, depth))
+
+
+@pytest.mark.parametrize("window", (0, 200))
+@pytest.mark.parametrize("start", ([0, 0], [256, 512], [300, 700]))
+def test_cached_prefill_attention_reads_what_the_mask_admits(
+        start, window, monkeypatch):
+    """A segment of 256 queries at positions start .. against a cache
+    of 1,024 rows (2 K/V heads under 4 query heads): the XLA loop over
+    the band's key blocks and the Pallas kernel (interpret mode,
+    blocks of 128) against one masked softmax over the whole cache."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.RandomState(len(start) + window)
+    q = jnp.asarray(rng.randn(2, 256, 4, 128), jnp.float32)
+    k = jnp.asarray(rng.randn(2, 1024, 256), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 1024, 256), jnp.float32)
+    start = jnp.asarray(start, jnp.int32)
+    want = np.asarray(_band_oracle(q, k, v, start, window))
+    got = attn.cached_prefill_attention_xla(q, k, v, start, window,
+                                            block_k=128)
+    # float32: the order of the sums alone
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-6)
+    monkeypatch.setattr(attn, "PREFILL_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "PREFILL_BLOCK_K", 128)
+    with pltpu.force_tpu_interpret_mode():
+        got = attn.cached_prefill_attention_kernel(q, k, v, start,
+                                                   window)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-6)
+
+
+def test_a_softmax_kept_in_bfloat16_is_the_lower_precision(monkeypatch):
+    """softmax_dtype bfloat16 (a check's control): the XLA loop and
+    the kernel round the same terms (scores, the running denominator
+    and weighted sum, once a key block), so they agree with each other
+    far closer than either does with the float32 softmax, and float32
+    is the identity."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(3 * rng.randn(1, 256, 4, 128), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 1024, 256), jnp.float32)
+    v = jnp.asarray(rng.randn(1, 1024, 256), jnp.float32)
+    start = jnp.asarray([700], jnp.int32)
+    x = jnp.asarray([1.00390625, -3.3], jnp.float32)
+    assert attn.kept_in(x, jnp.float32) is x
+    assert np.asarray(attn.kept_in(x, jnp.bfloat16)).tolist() == [
+        1.0, -3.296875]
+    monkeypatch.setattr(attn, "PREFILL_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "PREFILL_BLOCK_K", 128)
+    sound = np.asarray(attn.cached_prefill_attention_xla(
+        q, k, v, start, 300, block_k=128))
+    low = np.asarray(attn.cached_prefill_attention_xla(
+        q, k, v, start, 300, block_k=128, softmax_dtype=jnp.bfloat16))
+    with pltpu.force_tpu_interpret_mode():
+        kernel = np.asarray(attn.cached_prefill_attention_kernel(
+            q, k, v, start, 300, softmax_dtype=jnp.bfloat16))
+    rounding = np.abs(low - sound).max()
+    assert rounding > 3e-3
+    assert np.abs(kernel - low).max() < rounding / 4
+
+
+def test_prefill_attention_dispatch(monkeypatch):
+    assert attn.prefill_kernel_shapes_ok(4096, 16384, 128)
+    assert attn.prefill_kernel_shapes_ok(128, 512, 128)
+    assert not attn.prefill_kernel_shapes_ok(32, 256, 16)
+    q = jnp.zeros((1, 32, 4, 16))
+    cache = jnp.zeros((1, 64, 32))
+    with pytest.raises(ValueError, match="impl"):
+        attn.cached_prefill_attention(q, cache, cache, 0, impl="no")
+    assert attn.cached_prefill_attention(q, cache, cache,
+                                         0).shape == q.shape
+
+
+@pytest.mark.parametrize("window", (0, 10))
+def test_blockwise_and_reference_take_a_band_and_grouped_kv(window):
+    rng = np.random.RandomState(window)
+    q = jnp.asarray(rng.randn(1, 64, 4, 16), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 64, 2, 16), jnp.float32)
+    v = jnp.asarray(rng.randn(1, 64, 2, 16), jnp.float32)
+    want = attn.mha_reference(q, jnp.repeat(k, 2, axis=2),
+                              jnp.repeat(v, 2, axis=2), True,
+                              window=window)
+    np.testing.assert_allclose(
+        np.asarray(attn.mha_reference(q, k, v, True, window=window)),
+        np.asarray(want), atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(attn.blockwise_mha(q, k, v, True, block_size=16,
+                                      window=window)),
+        np.asarray(want), atol=1e-6)
+    if window:
+        # the band by hand: row 40 sees keys 31 .. 40 alone
+        scores = (np.asarray(k[0, 31:41, 0]) @ np.asarray(q[0, 40, 0])
+                  / 4.0)
+        probs = np.exp(scores - scores.max())
+        np.testing.assert_allclose(
+            np.asarray(want[0, 40, 0]),
+            (probs / probs.sum()) @ np.asarray(v[0, 31:41, 0]),
+            atol=1e-6)

@@ -662,9 +662,13 @@ def test_a_draft_model_is_refused_for_a_stateful_target(share):
 
 
 def test_grouped_query_attention_is_repeated_kv_attention():
-    """masked_attention and the paged xla path with 2 K/V heads under
-    4 query heads against the same call with each K/V head repeated
-    for its group."""
+    """masked_attention, the paged xla path (impl None: where a
+    grouped pool stays unless the kernel is asked for) and the grouped
+    Pallas kernel (impl "kernel" MEANS it since PR 40; interpret mode
+    here) with 2 K/V heads under 4 query heads against the same call
+    with each K/V head repeated for its group."""
+    from jax.experimental.pallas import tpu as pltpu
+
     from batch_shipyard_tpu.ops import paged_attention as paged
     key = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(key[0], (3, 1, 4, 16))
@@ -673,7 +677,10 @@ def test_grouped_query_attention_is_repeated_kv_attention():
     table = jnp.asarray([[0, 1, 6], [2, 6, 6], [3, 4, 5]])
     lengths = jnp.asarray([11, 3, 24])
     grouped = paged.paged_decode_attention(q, pages_k, pages_v, table,
-                                           lengths, impl="kernel")
+                                           lengths)
+    with pltpu.force_tpu_interpret_mode():
+        by_kernel = paged.paged_decode_attention(
+            q, pages_k, pages_v, table, lengths, impl="kernel")
 
     def repeat(pool):
         return jnp.repeat(pool.reshape(7, 8, 2, 16), 2,
@@ -682,6 +689,7 @@ def test_grouped_query_attention_is_repeated_kv_attention():
     want = paged.paged_decode_attention_xla(
         q, repeat(pages_k), repeat(pages_v), table, lengths)
     np.testing.assert_allclose(grouped, want, atol=1e-5)
+    np.testing.assert_allclose(by_kernel, want, atol=1e-5)
     k_all = jax.random.normal(key[3], (3, 9, 2, 16))
     mask = jnp.tril(jnp.ones((9, 9), bool))[None, None, -1:, :]
     got = paged.masked_attention(q, k_all, k_all, mask, jnp.float32)

@@ -183,3 +183,173 @@ def test_int8_xla_close_to_fp(interpret_mode):
     rel = (np.linalg.norm(np.asarray(got - ref)) /
            np.linalg.norm(np.asarray(ref)))
     assert rel < 0.02, rel
+
+
+# ---- the grouped, windowed kernel (gqa_paged_decode) ----
+
+def _grouped_case(rng, dtype, heads, kv_heads, batch=5, depth=64,
+                  page=8, entries=12, num_pages=64):
+    q = jnp.asarray(rng.randn(batch, 1, heads, depth), dtype)
+    k_pages = jnp.asarray(
+        rng.randn(num_pages, page, kv_heads * depth), dtype)
+    v_pages = jnp.asarray(
+        rng.randn(num_pages, page, kv_heads * depth), dtype)
+    table = jnp.asarray(
+        rng.permutation(num_pages)[:batch * entries].reshape(
+            batch, entries), jnp.int32)
+    return q, k_pages, v_pages, table
+
+
+def _masked_oracle(q, k_pages, v_pages, table, lengths, window):
+    """Softmax attention over the slot's keys [max(0, L - window), L)
+    written out with numpy, position by position through the table."""
+    batch, _one, heads, depth = q.shape
+    page = k_pages.shape[1]
+    kv_heads = k_pages.shape[2] // depth
+    out = np.zeros((batch, 1, heads, depth), np.float32)
+    for b in range(batch):
+        length = int(lengths[b])
+        low = max(0, length - window) if window else 0
+        if length == 0:
+            continue
+        rows = [(int(table[b, (p // page) % table.shape[1]]), p % page)
+                for p in range(low, length)]
+        keys = np.stack([np.asarray(k_pages[i, j], np.float32)
+                         for i, j in rows]).reshape(-1, kv_heads, depth)
+        values = np.stack([np.asarray(v_pages[i, j], np.float32)
+                           for i, j in rows]).reshape(-1, kv_heads,
+                                                      depth)
+        for h in range(heads):
+            kv = h // (heads // kv_heads)
+            scores = keys[:, kv] @ np.asarray(q[b, 0, h], np.float32) \
+                / np.sqrt(depth)
+            probs = np.exp(scores - scores.max())
+            out[b, 0, h] = (probs / probs.sum()) @ values[:, kv]
+    return out
+
+
+@pytest.mark.parametrize("window", (0, 20))
+@pytest.mark.parametrize("heads,kv_heads", ((4, 4), (14, 2), (4, 2)),
+                         ids=("mha", "7to1", "2to1"))
+def test_grouped_kernel_matches_the_masked_gather(interpret_mode,
+                                                  heads, kv_heads,
+                                                  window):
+    """MHA, 7 : 1 and 2 : 1 groupings; lengths 0, under, at, one over
+    and far over the window (20 keys over pages of 8: its edge lies
+    inside a page), the last crossing several chunks of pages."""
+    rng = np.random.RandomState(heads + window)
+    q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, heads,
+                                               kv_heads)
+    lengths = jnp.asarray([0, 5, 20, 21, 93], jnp.int32)
+    want = _masked_oracle(q, k_pages, v_pages, table, lengths, window)
+    xla = pa.paged_decode_attention_xla_windowed(
+        q, k_pages, v_pages, table, lengths, window=window)
+    got = pa.gqa_paged_decode_attention_kernel(
+        q, k_pages, v_pages, table, lengths, window=window)
+    # float32 throughout: the order of the sums alone
+    np.testing.assert_allclose(np.asarray(xla)[1:], want[1:],
+                               atol=2e-6, rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(got)[1:], want[1:],
+                               atol=2e-6, rtol=2e-6)
+    assert not np.asarray(got)[0].any()     # a slot at length 0: zeros
+    if not window:
+        plain = pa.paged_decode_attention_xla(q, k_pages, v_pages,
+                                              table, lengths)
+        np.testing.assert_allclose(np.asarray(xla)[1:],
+                                   np.asarray(plain)[1:], atol=1e-6)
+
+
+@pytest.mark.parametrize("lengths", ([3, 24, 25], [33, 57, 100]),
+                         ids=("unwrapped", "wrapped"))
+def test_a_ring_of_pages_holds_the_windows_keys(interpret_mode,
+                                                lengths):
+    """A table narrower than the context is a ring: 4 entries for a
+    window of 20 over pages of 8 (ceil(20 / 8) + 1). The oracle reads
+    position p through entry (p // page) % 4."""
+    rng = np.random.RandomState(3)
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.float32, 14, 2, batch=3, entries=4, num_pages=16)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    want = _masked_oracle(q, k_pages, v_pages, table, lengths, 20)
+    for fn in (pa.paged_decode_attention_xla_windowed,
+               pa.gqa_paged_decode_attention_kernel):
+        got = fn(q, k_pages, v_pages, table, lengths, window=20)
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-6,
+                                   rtol=2e-6)
+
+
+def test_grouped_kernel_bf16_is_close_to_the_gather(interpret_mode):
+    rng = np.random.RandomState(5)
+    q, k_pages, v_pages, table = _grouped_case(rng, jnp.bfloat16, 14, 2)
+    lengths = jnp.asarray([1, 9, 40, 64, 96], jnp.int32)
+    want = pa.paged_decode_attention_xla_windowed(
+        q, k_pages, v_pages, table, lengths, window=24)
+    got = pa.gqa_paged_decode_attention_kernel(
+        q, k_pages, v_pages, table, lengths, window=24)
+    # bfloat16 probabilities on both sides, rounded at other points
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=3e-2)
+
+
+def test_grouped_kernel_with_its_softmax_kept_in_bfloat16(
+        interpret_mode):
+    """softmax_dtype bfloat16 (a check's control) moves the kernel's
+    result by a rounding a chunk of pages, far more than the float32
+    kernel differs from the gather, and reaches the kernel through the
+    dispatch; the gather rounds its one-pass softmax's terms."""
+    rng = np.random.RandomState(7)
+    q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, 14, 2)
+    q = 3 * q
+    lengths = jnp.asarray([1, 9, 40, 64, 96], jnp.int32)
+    args = (q, k_pages, v_pages, table, lengths)
+    sound = np.asarray(pa.gqa_paged_decode_attention_kernel(
+        *args, window=24))
+    low = np.asarray(pa.gqa_paged_decode_attention_kernel(
+        *args, window=24, softmax_dtype=jnp.bfloat16))
+    assert 1e-3 < np.abs(low - sound).max() < 5e-2
+    np.testing.assert_array_equal(low, np.asarray(
+        pa.paged_decode_attention(*args, impl="kernel", window=24,
+                                  softmax_dtype=jnp.bfloat16)))
+    gather = np.asarray(pa.paged_decode_attention(
+        *args, impl="xla", window=24, softmax_dtype=jnp.bfloat16))
+    assert 1e-3 < np.abs(gather - sound).max() < 5e-2
+
+
+def test_what_kernel_means_for_a_grouped_pool(interpret_mode,
+                                              monkeypatch):
+    """impl None keeps a grouped pool on the XLA gather whatever the
+    backend; "kernel" MEANS the grouped Pallas kernel; a window takes
+    the windowed pair; int8 pages have neither."""
+    rng = np.random.RandomState(6)
+    q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, 4, 2)
+    lengths = jnp.asarray([1, 9, 40, 64, 96], jnp.int32)
+    called = []
+    for name in ("paged_decode_attention_kernel",
+                 "paged_decode_attention_xla",
+                 "gqa_paged_decode_attention_kernel",
+                 "paged_decode_attention_xla_windowed"):
+        monkeypatch.setattr(
+            pa, name, lambda *a, _name=name, **k: called.append(_name))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = (q, k_pages, v_pages, table, lengths)
+    pa.paged_decode_attention(*args)
+    pa.paged_decode_attention(*args, impl="kernel")
+    pa.paged_decode_attention(*args, impl="xla")
+    pa.paged_decode_attention(*args, window=8)
+    pa.paged_decode_attention(*args, impl="kernel", window=8)
+    assert called == ["paged_decode_attention_xla",
+                      "gqa_paged_decode_attention_kernel",
+                      "paged_decode_attention_xla",
+                      "paged_decode_attention_xla_windowed",
+                      "gqa_paged_decode_attention_kernel"]
+    with pytest.raises(NotImplementedError):
+        pa.paged_decode_attention(*args, impl="kernel", k_scales=1,
+                                  v_scales=1)
+    mha = (q, jnp.tile(k_pages, (1, 1, 2)), jnp.tile(v_pages, (1, 1, 2)),
+           table, lengths)
+    del called[:]
+    pa.paged_decode_attention(*mha)
+    pa.paged_decode_attention(*mha, window=8)
+    assert called == ["paged_decode_attention_kernel",
+                      "paged_decode_attention_xla_windowed"]

@@ -577,7 +577,7 @@ def test_owner_ttl_spares_live_decode(fleet):
     def _long():
         result["r"] = _post(router.url, {
             "request_id": "ttl-live", "prompt": [2, 2],
-            "max_new_tokens": 40}, timeout=240)
+            "max_new_tokens": 60}, timeout=240)
 
     t = threading.Thread(target=_long, daemon=True)
     t.start()
@@ -593,7 +593,7 @@ def test_owner_ttl_spares_live_decode(fleet):
                            "max_new_tokens": 1})
     assert exc.value.code == 400  # gate still shut
     t.join(120)
-    assert result["r"]["num_tokens"] == 40
+    assert result["r"]["num_tokens"] == 60
 
 
 def test_prefix_affinity_routes_to_same_replica(fleet):

@@ -84,6 +84,30 @@ def _qkv(shape, dtype):
     return [(shape, dtype)] * 3
 
 
+def _gqa_paged(window, softmax_dtype=f32):
+    return lambda q, k, v, table, lengths: \
+        pa.gqa_paged_decode_attention_kernel(
+            q, k, v, table, lengths, window=window,
+            softmax_dtype=softmax_dtype)
+
+
+def _gqa_args(pages, entries, dtype=bf16, batch=48, heads=28,
+              kv_heads=4, depth=128, page=64):
+    pool = ((pages, page, kv_heads * depth), dtype)
+    return [((batch, 1, heads, depth), dtype), pool, pool,
+            ((batch, entries), i32), ((batch,), i32)]
+
+
+def _flash_prefill(window, softmax_dtype=f32):
+    return lambda q, k, v, start: attn.cached_prefill_attention_kernel(
+        q, k, v, start, window, softmax_dtype=softmax_dtype)
+
+
+def _prefill_args(seq, rows=16384, heads=28, kv_heads=4, depth=128):
+    cache = ((1, rows, kv_heads * depth), bf16)
+    return [((1, seq, heads, depth), bf16), cache, cache, ((1,), i32)]
+
+
 # (id, fn, [(shape, dtype), ...]). "smoke" = chip_smoke.py's shapes
 # (bf16, 16 heads x 64, page 64, T 2048, d_model 1024, vocab 32000);
 # "checks" = tools/tpu_checks.py's.
@@ -136,6 +160,27 @@ CASES = [
      [((8192, 4096), bf16), ((40, 4096, 1280), bf16), ((40,), i32)]),
     ("grouped_matmul_solar_down", gm.grouped_matmul,
      [((8192, 1280), bf16), ((40, 1280, 4096), bf16), ((40,), i32)]),
+    # the grouped, windowed paged-decode kernel at the window
+    # configuration's published shapes (28 query over 4 K/V heads of
+    # 128, 48 slots, pages of 64): a full layer's pool of 6,144 pages
+    # behind a 256-entry table, and a window layer's ring of 65 pages
+    # a slot under a window of 4,096
+    ("gqa_paged_full", _gqa_paged(0), _gqa_args(6145, 256)),
+    ("gqa_paged_ring", _gqa_paged(4096), _gqa_args(48 * 65, 65)),
+    ("gqa_paged_checks", _gqa_paged(20),
+     _gqa_args(64, 12, dtype=f32, batch=4, heads=14, kv_heads=2,
+               depth=64, page=8)),
+    # the blockwise prefill over a cache: a segment of 4,096 queries
+    # against 16,384 rows, full and under the window
+    ("flash_prefill_full", _flash_prefill(0), _prefill_args(4096)),
+    ("flash_prefill_window", _flash_prefill(4096), _prefill_args(4096)),
+    ("flash_prefill_short", _flash_prefill(4096), _prefill_args(512)),
+    # ... and both with their softmax kept in bfloat16, as the window
+    # configuration's lower-precision control runs them on the chip
+    ("gqa_paged_ring_bf16_softmax", _gqa_paged(4096, bf16),
+     _gqa_args(48 * 65, 65)),
+    ("flash_prefill_window_bf16_softmax", _flash_prefill(4096, bf16),
+     _prefill_args(4096)),
     ("ring_all_gather_virtual", rc.ring_all_gather_virtual,
      [((4, 128, 128), f32)]),
     ("ring_reduce_scatter_virtual", rc.ring_reduce_scatter_virtual,
@@ -525,3 +570,198 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
     assert touching and not set(touching) & {
         "copy", "copy-start", "copy-done", "transpose", "reshape",
         "convert", "gather"}, touching
+
+
+# The TPU lowering (StableHLO) of one decode and one prefill program
+# of each configuration the benchmark had before the window
+# configuration, at its published sizes, as sha256 prefixes with the
+# serialized Mosaic kernels taken out (they carry source locations).
+# Recorded on the commit before TransformerConfig gained its per-layer
+# windows and rotations, the router's second input, prefill_blocks and
+# prefill_chunk, and RoutedConfig its second scoring rule and
+# activation: with those at their defaults the three configurations
+# lower to the programs they lowered to. A PR that MEANS to change one
+# of these programs re-records its line and says so.
+ACCEPTED_PROGRAMS = {
+    "baichuan-7b-serve-1chip/decode": "f4983349d90ff681",
+    "baichuan-7b-serve-1chip/prefill": "d778ca3089991697",
+    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "c4271df8de746936",
+    "nemotron-3-nano-30b-a3b-serve-1chip/prefill": "9494681929c5e555",
+    "solar-open2-250b-serve-1chip/decode": "87bf64f7c92c128c",
+    "solar-open2-250b-serve-1chip/prefill": "414a9d1036f22da3",
+}
+
+
+def _served_programs(config_name, monkeypatch):
+    """(module, dims, the program's config, dense model, paged model,
+    abstract params, abstract cache, the engine section) of a
+    benchmark configuration at its published sizes, traced as the
+    chip traces it."""
+    import dataclasses
+
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import transformer as tfm
+    from benchmark import spec, weights
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = {key: value for key, value in
+             spec.load_config(config_name).items()
+             if key != "rehearse_tiny"}
+    module = spec.load_model(model)
+    dims = module.dims(model)
+    engine = model["engine"]
+    config = module.program_model(model, dims, engine)
+    dense = tfm.TransformerLM(
+        inf.decode_config(config, engine["max_decode_len"]))
+    paged = tfm.TransformerLM(dataclasses.replace(
+        dense.config, kv_page_size=engine["kv_page_size"],
+        kv_num_pages=engine["kv_num_pages"] + 1))
+    params = weights.abstract_params(module.param_leaves(dims), bf16)
+    cache = jax.eval_shape(
+        lambda: inf.init_cache(paged, None, engine["num_slots"]))
+    return module, dims, config, dense, paged, params, cache, engine
+
+
+def _lower_step(kind, dense, paged, params, cache, engine, bucket=512,
+                chunk=None):
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import serving
+
+    def arg(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=getattr(
+                jax.tree_util.tree_leaves(cache)[0], "sharding", None))
+
+    slots = engine["num_slots"]
+    if kind == "decode":
+        return serving._decode_step.trace(
+            paged, inf.SamplingConfig(temperature=0.0), params, cache,
+            arg((slots, 1)), arg((slots,)), arg((slots,), jnp.bool_),
+            arg((2,), jnp.uint32)).lower(lowering_platforms=("tpu",))
+    return serving._prefill_paged.trace(
+        dense, chunk, engine["kv_page_size"], params, cache, 0,
+        arg((1, bucket)),
+        arg((engine["max_decode_len"] // engine["kv_page_size"],)),
+        bucket - 112).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("program", sorted(ACCEPTED_PROGRAMS))
+def test_the_accepted_configurations_programs_are_unchanged(
+        program, monkeypatch):
+    import hashlib
+    import re
+
+    config_name, kind = program.split("/")
+    _module, _dims, _config, dense, paged, params, cache, engine = \
+        _served_programs(config_name, monkeypatch)
+    text = _lower_step(kind, dense, paged, params, cache,
+                       engine).as_text()
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        ACCEPTED_PROGRAMS[program]
+
+
+def test_the_hybrids_grouped_pools_still_decode_by_the_gather(
+        monkeypatch):
+    """paged_attention_impl None keeps a grouped pool on the XLA
+    gather on a TPU: the two hybrid configurations' decode programs
+    hold no Mosaic call, and Baichuan's holds its MHA kernel."""
+    for config_name, kernels in (
+            ("nemotron-3-nano-30b-a3b-serve-1chip", False),
+            ("solar-open2-250b-serve-1chip", False),
+            ("baichuan-7b-serve-1chip", True)):
+        _module, _dims, config, dense, paged, params, cache, engine = \
+            _served_programs(config_name, monkeypatch)
+        assert config.paged_attention_impl is None
+        text = _lower_step("decode", dense, paged, params, cache,
+                           engine).as_text()
+        assert ("tpu_custom_call" in text) == kernels
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512",
+                                     "prefill_16384"])
+def test_the_window_stack_keeps_pool_and_rings_in_place_on_v5e(
+        v5e_devices, program, monkeypatch):
+    """The window configuration's step programs at its published
+    widths, pool and rings (28 query over 4 K/V heads of 128, 64 ReGLU
+    experts of 768 all held, the whole vocabulary of 151,936; 48 slots,
+    6,144 pages and rings of 65 pages of 64 tokens, contexts to
+    16,384), cut to its first TWO published layers (one full, one
+    window: four blocks) so that it compiles in seconds, compiled for
+    the v5e: every leaf of the donated cache is aliased input to
+    output, pool and ring alike; the decode step holds one grouped
+    kernel an attention layer and touches no whole leaf but by its
+    in-place row writes; a prefill holds the blockwise prefill kernel
+    (one call a layer; the four segments of the longest bucket are a
+    loop) and the grouped matmul, and its temporaries stay at what a
+    segment of the window's length holds, far under what a
+    [bucket, 16384] score tensor a head would take (28 x 16384 x
+    16384 x 4 bytes = 30 GB)."""
+    import dataclasses
+    import re
+
+    from benchmark import weights
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import serving
+    from batch_shipyard_tpu.models import transformer as tfm
+
+    module, dims, config, _dense, _paged, _params, _cache, engine = \
+        _served_programs("smallthinker-21b-a3b-serve-1chip", monkeypatch)
+    assert config.paged_attention_impl == "kernel"
+    config = dataclasses.replace(
+        config, n_layers=4, block_kinds=config.block_kinds[:4],
+        layer_windows=config.layer_windows[:4],
+        layer_rope=config.layer_rope[:4])
+    assert tfm.attention_windows(config) == (0, 4096)
+    chip = jax.sharding.SingleDeviceSharding(v5e_devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=chip), tree)
+
+    dense = tfm.TransformerLM(
+        inf.decode_config(config, engine["max_decode_len"]))
+    paged = tfm.TransformerLM(dataclasses.replace(
+        dense.config, kv_page_size=64,
+        kv_num_pages=engine["kv_num_pages"] + 1))
+    leaves = [leaf for leaf in module.param_leaves(dims)
+              if not leaf[0][0].startswith("layer_")
+              or int(leaf[0][0][6:]) < 4]
+    params = on_chip(weights.abstract_params(leaves, bf16))
+    cache = on_chip(jax.eval_shape(
+        lambda: inf.init_cache(paged, None, engine["num_slots"])))
+    assert cache["layer_2"]["attn"]["k_ring"].shape == (
+        48 * 65, 64, 512)
+    if program == "decode":
+        lowered = _lower_step("decode", dense, paged, params, cache,
+                              engine)
+    else:
+        lowered = _lower_step(
+            "prefill", dense, paged, params, cache, engine,
+            bucket=int(program.split("_")[1]),
+            chunk=serving.window_segment(config))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - cache_bytes < 2 ** 20
+    whole_leaf = re.compile(
+        r"= bf16\[(?:6145|3120),64,512\]\S* ([\w-]+)\(")
+    touching = {}
+    for op in whole_leaf.findall(text):
+        touching[op] = touching.get(op, 0) + 1
+    assert set(touching) <= {"parameter", "scatter", "fusion",
+                             "dynamic-update-slice"}, touching
+    if program == "decode":
+        assert len(re.findall(r"%gqa_paged_decode\S* = ", text)) == 2
+        assert touching["scatter"] == 4     # K and V of both layers
+        assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    else:
+        assert len(re.findall(r"%flash_prefill_cached\S* = ",
+                              text)) == 2
+        assert "gmm" in text
+        # one call a layer: the segments run as ONE traced forward
+        # in a loop; 0.99 GB where the bucket whole holds 2.45
+        assert memory.temp_size_in_bytes < 1.2e9
