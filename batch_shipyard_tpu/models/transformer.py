@@ -92,9 +92,10 @@ class TransformerConfig:
     # dense cache needs no margin (out-of-bounds scatters drop).
     spec_window: int = 0
     # Paged decode attention implementation: 'kernel' (Pallas, reads
-    # only live pages via scalar-prefetched block tables), 'xla'
-    # (gather over the full table width), or None = kernel on TPU and
-    # xla elsewhere (ops/paged_attention.py dispatch).
+    # only live pages), 'xla' (gather over the full table width), or
+    # None = kernel on TPU and xla elsewhere, for every pool; which
+    # kernel (MHA or grouped/windowed) the pool and the layer decide
+    # (ops/paged_attention.paged_decode_road).
     paged_attention_impl: Optional[str] = None
     # DENSE int8 decode attention implementation: 'kernel' (Pallas,
     # int8 cache + per-(position, head) scales dequantized in VMEM

@@ -452,6 +452,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
                      for h in range(kv_heads)).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("window", "softmax_dtype"),
+                   inline=True)
 def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                       lengths, window: int = 0,
                                       softmax_dtype=jnp.float32):
@@ -465,7 +467,15 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     narrower one a RING (its T pages hold the newest T logical pages;
     T >= ceil(window / page) + 1, so that no live key is overwritten).
     A slot of length 0 yields zeros. ``softmax_dtype``: kept_in.
-    Returns [B, 1, H, D] in q.dtype."""
+    Returns [B, 1, H, D] in q.dtype.
+
+    Jitted INLINE: the lowered program is what the plain function
+    gives, but the kernel's body (some fifty conditional DMA starts
+    and waits: about a second of Python on a serving host) is traced
+    ONCE for all layers of the same shapes and window and for every
+    program that holds them (the cache's initialisation and the decode
+    step), not once a call site a program; a program's set-up time
+    would otherwise grow with its attention layers (PERF.md, PR 41)."""
     batch, seq, heads, depth = q.shape
     assert seq == 1, "decode consumes one token per call"
     page, width = k_pages.shape[1], k_pages.shape[2]
@@ -555,38 +565,65 @@ def resolve_paged_impl(impl: Optional[str] = None) -> str:
     return resolve_kernel_or_xla(impl, "paged attention")
 
 
+def paged_decode_road(impl: Optional[str], *, grouped: bool,
+                      window: int = 0, int8: bool = False) -> str:
+    """Which of the four implementations a one-token paged decode call
+    runs: the dispatch below and a serving report
+    (workloads/serve.paged_decode_impl) both ask here, so the report
+    names what engaged. It reads the backend (through
+    resolve_paged_impl), whether the pool holds fewer K/V heads than
+    the query has (``grouped``), the layer's ``window`` and whether
+    the pages are int8 (scales handed in), and nothing else.
+
+    ``impl`` None is the Pallas kernel on a TPU and the XLA gather
+    elsewhere, for every pool; "kernel" and "xla" pass through. WHICH
+    kernel and which gather the pool and the layer decide:
+
+        pool, layer          bf16/f32 pages           int8 pages
+                             kernel      xla          kernel     xla
+        MHA, no window       kernel      xla          kernel     xla
+        grouped, no window   gqa_kernel  xla          (none)     xla
+        any pool, a window   gqa_kernel  xla_windowed (none)     (none)
+
+    kernel = paged_decode_attention_kernel, gqa_kernel =
+    gqa_paged_decode_attention_kernel, xla =
+    paged_decode_attention_xla, xla_windowed =
+    paged_decode_attention_xla_windowed. (none): the grouped kernel
+    and the windowed gather read no scales. Where the gather can serve
+    (a grouped int8 pool), None falls back to it on a TPU too and a
+    named "kernel" raises NotImplementedError; under a window every
+    int8 call does."""
+    want = resolve_paged_impl(impl)
+    if not (window or grouped):
+        return want
+    if int8 and (window or impl == "kernel"):
+        raise NotImplementedError(
+            "no int8 pages under a window or the grouped kernel")
+    if want == "kernel" and not int8:
+        return "gqa_kernel"
+    return "xla_windowed" if window else "xla"
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            impl: Optional[str] = None,
                            k_scales=None, v_scales=None,
                            window: int = 0, softmax_dtype=jnp.float32):
-    """Dispatch: 'kernel' (Pallas) or 'xla'. k_scales/v_scales switch
-    both paths to int8-page dequant.
-
-    A pool of as many K/V heads as query heads: ``impl`` None is the
-    kernel on a TPU and xla elsewhere (resolve_paged_impl), a named
-    one passes through. A GROUPED pool (fewer K/V heads than query
-    heads): ``impl`` None is the xla gather on every backend, "kernel"
-    MEANS the grouped Pallas kernel
-    (gqa_paged_decode_attention_kernel; bfloat16/float32 pages), "xla"
-    the gather. ``window`` > 0 (a layer that sees its newest
+    """Dispatch by paged_decode_road (the selection rule's one table).
+    k_scales/v_scales switch the MHA kernel and the plain gather to
+    int8-page dequant. ``window`` > 0: a layer that sees its newest
     ``window`` keys alone; its table may then be a RING narrower than
-    the context, entry p % T the page of logical page p) takes the
-    grouped kernel or its windowed gather whatever the grouping: the
-    kernel under "kernel", the gather otherwise; these two alone
-    keep their softmax in ``softmax_dtype`` (kept_in)."""
-    grouped = k_pages.shape[2] != q.shape[2] * q.shape[3]
-    if window or (grouped and impl == "kernel"):
-        if k_scales is not None:
-            raise NotImplementedError(
-                "no int8 pages under a window or the grouped kernel")
-        if impl not in (None, "kernel", "xla"):
-            raise ValueError(f"unknown paged attention impl {impl!r}")
-        fn = (gqa_paged_decode_attention_kernel if impl == "kernel"
+    the context, entry p % T the page of logical page p. The grouped
+    kernel and the windowed gather alone keep their softmax in
+    ``softmax_dtype`` (kept_in)."""
+    road = paged_decode_road(
+        impl, grouped=k_pages.shape[2] != q.shape[2] * q.shape[3],
+        window=window, int8=k_scales is not None)
+    if road in ("gqa_kernel", "xla_windowed"):
+        fn = (gqa_paged_decode_attention_kernel if road == "gqa_kernel"
               else paged_decode_attention_xla_windowed)
         return fn(q, k_pages, v_pages, block_table, lengths,
                   window=window, softmax_dtype=softmax_dtype)
-    fn = (paged_decode_attention_kernel
-          if resolve_paged_impl(impl) == "kernel" and not grouped
+    fn = (paged_decode_attention_kernel if road == "kernel"
           else paged_decode_attention_xla)
     return fn(q, k_pages, v_pages, block_table, lengths,
               k_scales=k_scales, v_scales=v_scales)

@@ -27,6 +27,7 @@ from batch_shipyard_tpu.models import inference as inf
 from batch_shipyard_tpu.models import serving
 from batch_shipyard_tpu.models import transformer as tfm
 from batch_shipyard_tpu.models.server import ServingFrontEnd
+from batch_shipyard_tpu.ops import paged_attention
 from batch_shipyard_tpu.workloads import distributed
 
 
@@ -166,6 +167,20 @@ def build_engine(args, config=None, params=None,
         slo_shed_grace_ms=slo.shed_grace_ms if slo else None,
         tpot_stall_factor=(slo.tpot_stall_factor if slo else 4.0),
         speculative=speculative, device=device)
+
+
+def paged_decode_impl(config: tfm.TransformerConfig) -> str:
+    """What the engine's one-token decode attention runs, as the
+    dispatch itself decides it (ops/paged_attention.paged_decode_road)
+    from the pool's grouping, each attention layer's window and the
+    pages' type: one name, or one a kind of layer joined by "+" where
+    full and window layers take different ones."""
+    return "+".join(sorted({
+        paged_attention.paged_decode_road(
+            config.paged_attention_impl,
+            grouped=config.kv_heads != config.n_heads, window=window,
+            int8=config.kv_cache_dtype == "int8")
+        for window in tfm.attention_windows(config)}))
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -379,9 +394,8 @@ def main(argv=None) -> int:
         str(f.engine.device or jax.devices()[0]) for f in fronts]
     report["hbm_in_use_mib"] = distributed.device_memory_mib()
     if args.kv_page_size:
-        from batch_shipyard_tpu.ops import paged_attention
-        report["paged_decode_impl"] = paged_attention.resolve_paged_impl(
-            fronts[0].engine.config.paged_attention_impl)
+        report["paged_decode_impl"] = paged_decode_impl(
+            fronts[0].engine.config)
     if router is not None:
         report["router"] = router.stats()
     prefix = [f.engine.prefix_stats() for f in fronts]

@@ -662,11 +662,11 @@ def test_a_draft_model_is_refused_for_a_stateful_target(share):
 
 
 def test_grouped_query_attention_is_repeated_kv_attention():
-    """masked_attention, the paged xla path (impl None: where a
-    grouped pool stays unless the kernel is asked for) and the grouped
-    Pallas kernel (impl "kernel" MEANS it since PR 40; interpret mode
-    here) with 2 K/V heads under 4 query heads against the same call
-    with each K/V head repeated for its group."""
+    """masked_attention, the paged xla path (impl None off the TPU,
+    as for every pool) and the grouped Pallas kernel (what "kernel",
+    and None on a TPU, MEAN for a grouped pool; interpret mode here)
+    with 2 K/V heads under 4 query heads against the same call with
+    each K/V head repeated for its group."""
     from jax.experimental.pallas import tpu as pltpu
 
     from batch_shipyard_tpu.ops import paged_attention as paged
@@ -697,6 +697,33 @@ def test_grouped_query_attention_is_repeated_kv_attention():
         q, jnp.repeat(k_all, 2, axis=2), jnp.repeat(k_all, 2, axis=2),
         mask, jnp.float32)
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_the_grouped_kernel_serves_the_gathers_tokens_and_choices(
+        share, served):
+    """What paged_attention_impl None means for these stacks' grouped
+    pools on a TPU (PR 41), named here as "kernel" and run in interpret
+    mode: the engine of ``served`` (on the CPU: the gather) serves the
+    same tokens and hands over the same record of choices through the
+    grouped Pallas kernel, beside a per-slot state and through slot
+    reuse."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from batch_shipyard_tpu.workloads import serve
+    _file, _dims, config, params = share
+    _engine_, prompts, new_tokens, done, records = served
+    assert config.paged_attention_impl is None
+    assert serve.paged_decode_impl(config) == "xla"
+    config = dataclasses.replace(config, paged_attention_impl="kernel")
+    assert serve.paged_decode_impl(config) == "gqa_kernel"
+    with pltpu.force_tpu_interpret_mode():
+        engine = _engine(config, params)
+        assert _serve(engine, prompts, new_tokens) == done
+    for request_id, record in records.items():
+        again = engine.take_decisions(request_id)
+        assert again["first"] == record["first"]
+        for name, rows in record["layers"].items():
+            np.testing.assert_array_equal(again["layers"][name], rows)
 
 
 @pytest.mark.parametrize("stateful", tfm.STATEFUL_KINDS)

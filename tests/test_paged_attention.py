@@ -228,19 +228,34 @@ def _masked_oracle(q, k_pages, v_pages, table, lengths, window):
     return out
 
 
+# (query heads, K/V heads, the pool's and table's shape, lengths).
+# The first three: MHA, 7 : 1 and 2 : 1 groupings over pages of 8;
+# lengths 0, under, at, one over and far over the window (20 keys: its
+# edge lies inside a page), the last crossing several chunks of pages.
+# The last two: the hybrid configurations' attention as they serve it
+# (64 query over 8 K/V heads and 32 over 2, heads of 128, pages of 64,
+# a table of 32 entries = 2,048 positions): a slot of length 0, one
+# key, a page's edge from both sides, the cell's mean context (one
+# whole chunk of GQA_CHUNK_PAGES and the head of a second), a full
+# table.
+_SMALL = dict(shape={}, lengths=[0, 5, 20, 21, 93])
+_SERVED = dict(shape=dict(batch=7, depth=128, page=64, entries=32,
+                          num_pages=224),
+               lengths=[0, 1, 63, 64, 65, 520, 2048])
+_GROUPINGS = {"mha": (4, 4, _SMALL), "7to1": (14, 2, _SMALL),
+              "2to1": (4, 2, _SMALL), "solaropen2": (64, 8, _SERVED),
+              "nemotron3nano": (32, 2, _SERVED)}
+
+
 @pytest.mark.parametrize("window", (0, 20))
-@pytest.mark.parametrize("heads,kv_heads", ((4, 4), (14, 2), (4, 2)),
-                         ids=("mha", "7to1", "2to1"))
+@pytest.mark.parametrize("grouping", sorted(_GROUPINGS))
 def test_grouped_kernel_matches_the_masked_gather(interpret_mode,
-                                                  heads, kv_heads,
-                                                  window):
-    """MHA, 7 : 1 and 2 : 1 groupings; lengths 0, under, at, one over
-    and far over the window (20 keys over pages of 8: its edge lies
-    inside a page), the last crossing several chunks of pages."""
+                                                  grouping, window):
+    heads, kv_heads, case = _GROUPINGS[grouping]
     rng = np.random.RandomState(heads + window)
-    q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, heads,
-                                               kv_heads)
-    lengths = jnp.asarray([0, 5, 20, 21, 93], jnp.int32)
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.float32, heads, kv_heads, **case["shape"])
+    lengths = jnp.asarray(case["lengths"], jnp.int32)
     want = _masked_oracle(q, k_pages, v_pages, table, lengths, window)
     xla = pa.paged_decode_attention_xla_windowed(
         q, k_pages, v_pages, table, lengths, window=window)
@@ -253,10 +268,14 @@ def test_grouped_kernel_matches_the_masked_gather(interpret_mode,
                                atol=2e-6, rtol=2e-6)
     assert not np.asarray(got)[0].any()     # a slot at length 0: zeros
     if not window:
+        # the gather the hybrid configurations decoded by until PR 41
         plain = pa.paged_decode_attention_xla(q, k_pages, v_pages,
                                               table, lengths)
         np.testing.assert_allclose(np.asarray(xla)[1:],
                                    np.asarray(plain)[1:], atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got)[1:],
+                                   np.asarray(plain)[1:], atol=2e-6,
+                                   rtol=2e-6)
 
 
 @pytest.mark.parametrize("lengths", ([3, 24, 25], [33, 57, 100]),
@@ -278,18 +297,30 @@ def test_a_ring_of_pages_holds_the_windows_keys(interpret_mode,
                                    rtol=2e-6)
 
 
-def test_grouped_kernel_bf16_is_close_to_the_gather(interpret_mode):
+@pytest.mark.parametrize("grouping,window,lengths", (
+    ("7to1", 24, [1, 9, 40, 64, 96]),
+    ("solaropen2", 0, _SERVED["lengths"]),
+    ("nemotron3nano", 0, _SERVED["lengths"])))
+def test_grouped_kernel_bf16_is_close_to_the_gather(interpret_mode,
+                                                    grouping, window,
+                                                    lengths):
+    """bfloat16 pages, as served: the kernel against the gather of
+    the same dispatch (without a window, the plain one the hybrid
+    configurations ran)."""
+    heads, kv_heads, case = _GROUPINGS[grouping]
     rng = np.random.RandomState(5)
-    q, k_pages, v_pages, table = _grouped_case(rng, jnp.bfloat16, 14, 2)
-    lengths = jnp.asarray([1, 9, 40, 64, 96], jnp.int32)
-    want = pa.paged_decode_attention_xla_windowed(
-        q, k_pages, v_pages, table, lengths, window=24)
-    got = pa.gqa_paged_decode_attention_kernel(
-        q, k_pages, v_pages, table, lengths, window=24)
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.bfloat16, heads, kv_heads, **case["shape"])
+    lengths = jnp.asarray(lengths, jnp.int32)
+    args = (q, k_pages, v_pages, table, lengths)
+    want = pa.paged_decode_attention(*args, impl="xla", window=window)
+    got = pa.paged_decode_attention(*args, impl="kernel", window=window)
+    live = np.asarray(lengths) > 0          # length 0: the contract's
     # bfloat16 probabilities on both sides, rounded at other points
     np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        atol=3e-2)
+        np.asarray(got, np.float32)[live],
+        np.asarray(want, np.float32)[live], atol=3e-2)
+    assert not np.asarray(got, np.float32)[~live].any()
 
 
 def test_grouped_kernel_with_its_softmax_kept_in_bfloat16(
@@ -316,40 +347,96 @@ def test_grouped_kernel_with_its_softmax_kept_in_bfloat16(
     assert 1e-3 < np.abs(gather - sound).max() < 5e-2
 
 
-def test_what_kernel_means_for_a_grouped_pool(interpret_mode,
-                                              monkeypatch):
-    """impl None keeps a grouped pool on the XLA gather whatever the
-    backend; "kernel" MEANS the grouped Pallas kernel; a window takes
-    the windowed pair; int8 pages have neither."""
+_K, _G, _X, _W = "kernel", "gqa_kernel", "xla", "xla_windowed"
+_NO = NotImplementedError
+_ROAD_FUNCTIONS = {_K: "paged_decode_attention_kernel",
+                   _G: "gqa_paged_decode_attention_kernel",
+                   _X: "paged_decode_attention_xla",
+                   _W: "paged_decode_attention_xla_windowed"}
+# The dispatch's whole table, written out: (pool, window, pages) ->
+# what runs under (tpu None, tpu "kernel", tpu "xla", cpu None,
+# cpu "kernel", cpu "xla"). impl None is the kernel on a TPU and the
+# gather elsewhere for EVERY pool; a grouped pool's and a window
+# layer's kernel is the grouped one; where it cannot serve (int8
+# pages) None falls back to the gather and "kernel" raises; the
+# windowed gather reads no scales either.
+_DISPATCH = {
+    ("mha", 0, "bf16"): (_K, _K, _X, _X, _K, _X),
+    ("mha", 0, "int8"): (_K, _K, _X, _X, _K, _X),
+    ("grouped", 0, "bf16"): (_G, _G, _X, _X, _G, _X),
+    ("grouped", 0, "int8"): (_X, _NO, _X, _X, _NO, _X),
+    ("mha", 8, "bf16"): (_G, _G, _W, _W, _G, _W),
+    ("mha", 8, "int8"): (_NO,) * 6,
+    ("grouped", 8, "bf16"): (_G, _G, _W, _W, _G, _W),
+    ("grouped", 8, "int8"): (_NO,) * 6,
+}
+_DISPATCH_CASES = [
+    (backend, pool, window, impl, pages, row[3 * b + i])
+    for (pool, window, pages), row in _DISPATCH.items()
+    for b, backend in enumerate(("tpu", "cpu"))
+    for i, impl in enumerate((None, "kernel", "xla"))]
+
+
+@pytest.mark.parametrize(
+    "backend,pool,window,impl,pages,want", _DISPATCH_CASES,
+    ids=[f"{b}-{pool}-w{w}-{impl}-{pages}"
+         for b, pool, w, impl, pages, _want in _DISPATCH_CASES])
+def test_what_kernel_means_for_a_grouped_pool(monkeypatch, backend,
+                                              pool, window, impl,
+                                              pages, want):
+    """One case of the selection rule's table: the implementation the
+    dispatch calls (or the error it raises), and the same answer from
+    the serving report's function for an engine's config of that
+    pool, which asks the same rule (paged_decode_road)."""
+    from batch_shipyard_tpu.models import transformer as tfm
+    from batch_shipyard_tpu.workloads import serve
+
     rng = np.random.RandomState(6)
-    q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, 4, 2)
+    kv_heads = 2 if pool == "grouped" else 4
+    q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, 4,
+                                               kv_heads)
     lengths = jnp.asarray([1, 9, 40, 64, 96], jnp.int32)
     called = []
-    for name in ("paged_decode_attention_kernel",
-                 "paged_decode_attention_xla",
-                 "gqa_paged_decode_attention_kernel",
-                 "paged_decode_attention_xla_windowed"):
+    for name in _ROAD_FUNCTIONS.values():
         monkeypatch.setattr(
             pa, name, lambda *a, _name=name, **k: called.append(_name))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    scales = {"k_scales": 1, "v_scales": 1} if pages == "int8" else {}
+    config = tfm.TransformerConfig(
+        vocab_size=32, d_model=16, n_layers=2, n_heads=4,
+        n_kv_heads=kv_heads, d_head=4, d_ff=32,
+        paged_attention_impl=impl, layer_windows=(window, window),
+        kv_cache_dtype="int8" if pages == "int8" else None)
+
+    def dispatch():
+        return pa.paged_decode_attention(
+            q, k_pages, v_pages, table, lengths, impl=impl,
+            window=window, **scales)
+
+    if want is _NO:
+        with pytest.raises(NotImplementedError):
+            dispatch()
+        with pytest.raises(NotImplementedError):
+            serve.paged_decode_impl(config)
+        assert not called
+        return
+    dispatch()
+    assert called == [_ROAD_FUNCTIONS[want]]
+    assert serve.paged_decode_impl(config) == want
+
+
+def test_the_report_names_each_kind_of_layer(monkeypatch):
+    """A stack of full and window layers over a grouped pool: one name
+    a kind of layer, as the dispatch decides each; an unknown impl is
+    refused by the rule itself."""
+    from batch_shipyard_tpu.models import transformer as tfm
+    from batch_shipyard_tpu.workloads import serve
+
+    config = tfm.TransformerConfig(
+        vocab_size=32, d_model=16, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_head=4, d_ff=32, layer_windows=(0, 8))
+    assert serve.paged_decode_impl(config) == "xla+xla_windowed"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    args = (q, k_pages, v_pages, table, lengths)
-    pa.paged_decode_attention(*args)
-    pa.paged_decode_attention(*args, impl="kernel")
-    pa.paged_decode_attention(*args, impl="xla")
-    pa.paged_decode_attention(*args, window=8)
-    pa.paged_decode_attention(*args, impl="kernel", window=8)
-    assert called == ["paged_decode_attention_xla",
-                      "gqa_paged_decode_attention_kernel",
-                      "paged_decode_attention_xla",
-                      "paged_decode_attention_xla_windowed",
-                      "gqa_paged_decode_attention_kernel"]
-    with pytest.raises(NotImplementedError):
-        pa.paged_decode_attention(*args, impl="kernel", k_scales=1,
-                                  v_scales=1)
-    mha = (q, jnp.tile(k_pages, (1, 1, 2)), jnp.tile(v_pages, (1, 1, 2)),
-           table, lengths)
-    del called[:]
-    pa.paged_decode_attention(*mha)
-    pa.paged_decode_attention(*mha, window=8)
-    assert called == ["paged_decode_attention_kernel",
-                      "paged_decode_attention_xla_windowed"]
+    assert serve.paged_decode_impl(config) == "gqa_kernel"
+    with pytest.raises(ValueError, match="unknown paged attention"):
+        pa.paged_decode_road("pallas", grouped=True)

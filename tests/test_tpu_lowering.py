@@ -532,6 +532,9 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
     cache = on_chip(jax.eval_shape(
         lambda: inf.init_cache(paged, None, slots)))
     if program == "decode":
+        # traced as the chip traces it: the attention block's grouped
+        # pool decodes through the grouped kernel (PR 41)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         lowered = serving._decode_step.lower(
             paged, inf.SamplingConfig(temperature=0.0), params, cache,
             arg((slots, 1)), arg((slots,)), arg((slots,), jnp.bool_),
@@ -550,6 +553,7 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
     compiled = lowered.compile()
     text = compiled.as_text()
     assert ("gmm" in text) == (program == "prefill_grouped")
+    assert ("gqa_paged_decode" in text) == (program == "decode")
     moved = re.findall(
         r"= bf16\[64,(?:2688,1856|1856,2688)\]\S* "
         r"(copy|transpose|copy-start|convert)\(", text)
@@ -582,13 +586,27 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
 # activation: with those at their defaults the three configurations
 # lower to the programs they lowered to. A PR that MEANS to change one
 # of these programs re-records its line and says so.
+# PR 41 re-recorded the two hybrid DECODE lines (c4271df8de746936 and
+# 87bf64f7c92c128c until then): paged_attention_impl None now means
+# the grouped Pallas kernel on a TPU for their grouped pools, where it
+# meant the XLA gather. The four other lines are as PR 40 recorded
+# them. (The grouped kernel is jitted inline since then, so that its
+# body is traced once for same-shaped call sites: two such sites lower
+# to the same text but for the numbering of jax's private helper
+# functions, `@_where_<n>`, which is why Nemotron's line, with two
+# attention blocks, is not what the plain function gave.)
 ACCEPTED_PROGRAMS = {
     "baichuan-7b-serve-1chip/decode": "f4983349d90ff681",
     "baichuan-7b-serve-1chip/prefill": "d778ca3089991697",
-    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "c4271df8de746936",
+    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "61b575fbb23a47df",
     "nemotron-3-nano-30b-a3b-serve-1chip/prefill": "9494681929c5e555",
-    "solar-open2-250b-serve-1chip/decode": "87bf64f7c92c128c",
+    "solar-open2-250b-serve-1chip/decode": "6624ed0450d5dfa9",
     "solar-open2-250b-serve-1chip/prefill": "414a9d1036f22da3",
+    # new in PR 41 (4cb7c1eba72ff012 at its parent: the same program
+    # but for that numbering, nine lines, with eight sites of the
+    # grouped kernel; compiled for the v5e the two were instruction
+    # for instruction the same, PERF.md section 6)
+    "smallthinker-21b-a3b-serve-1chip/decode": "bb97a066f9e4c91b",
 }
 
 
@@ -645,6 +663,39 @@ def _lower_step(kind, dense, paged, params, cache, engine, bucket=512,
         bucket - 112).lower(lowering_platforms=("tpu",))
 
 
+def _cut_on_chip(module, dims, config, engine, blocks, chip, **cut):
+    """(dense model, paged model, abstract params, abstract cache) of
+    ``config`` cut to its first ``blocks`` blocks (``cut``: the other
+    per-layer fields, cut alike), every leaf placed on ``chip``."""
+    import dataclasses
+
+    from benchmark import weights
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.models import transformer as tfm
+
+    config = dataclasses.replace(
+        config, n_layers=blocks,
+        block_kinds=config.block_kinds[:blocks], **cut)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=chip), tree)
+
+    dense = tfm.TransformerLM(
+        inf.decode_config(config, engine["max_decode_len"]))
+    paged = tfm.TransformerLM(dataclasses.replace(
+        dense.config, kv_page_size=engine["kv_page_size"],
+        kv_num_pages=engine["kv_num_pages"] + 1))
+    leaves = [leaf for leaf in module.param_leaves(dims)
+              if not leaf[0][0].startswith("layer_")
+              or int(leaf[0][0][6:]) < blocks]
+    params = on_chip(weights.abstract_params(leaves, bf16))
+    cache = on_chip(jax.eval_shape(
+        lambda: inf.init_cache(paged, None, engine["num_slots"])))
+    return dense, paged, params, cache
+
+
 @pytest.mark.parametrize("program", sorted(ACCEPTED_PROGRAMS))
 def test_the_accepted_configurations_programs_are_unchanged(
         program, monkeypatch):
@@ -661,21 +712,101 @@ def test_the_accepted_configurations_programs_are_unchanged(
         ACCEPTED_PROGRAMS[program]
 
 
-def test_the_hybrids_grouped_pools_still_decode_by_the_gather(
+def test_the_hybrids_grouped_pools_decode_by_the_grouped_kernel(
         monkeypatch):
-    """paged_attention_impl None keeps a grouped pool on the XLA
-    gather on a TPU: the two hybrid configurations' decode programs
-    hold no Mosaic call, and Baichuan's holds its MHA kernel."""
-    for config_name, kernels in (
-            ("nemotron-3-nano-30b-a3b-serve-1chip", False),
-            ("solar-open2-250b-serve-1chip", False),
-            ("baichuan-7b-serve-1chip", True)):
+    """paged_attention_impl None means the Pallas kernel on a TPU for
+    every pool (PR 41): each hybrid configuration's decode program
+    holds one grouped-kernel call an attention block (Nemotron's cut
+    has two, Solar-Open2's one) and no other Mosaic call, and
+    Baichuan's holds its MHA kernel, one call a layer."""
+    for config_name, grouped, mha in (
+            ("nemotron-3-nano-30b-a3b-serve-1chip", 2, 0),
+            ("solar-open2-250b-serve-1chip", 1, 0),
+            ("baichuan-7b-serve-1chip", 0, 16)):
         _module, _dims, config, dense, paged, params, cache, engine = \
             _served_programs(config_name, monkeypatch)
         assert config.paged_attention_impl is None
         text = _lower_step("decode", dense, paged, params, cache,
                            engine).as_text()
-        assert ("tpu_custom_call" in text) == kernels
+        assert text.count('kernel_name = "gqa_paged_decode"') == grouped
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == \
+            grouped + mha
+
+
+def test_the_grouped_kernels_body_is_traced_once_a_shape(monkeypatch):
+    """Nemotron's decode program has two attention blocks of the same
+    shapes, and building the engine's cache traces the same attention
+    once more: the grouped kernel is jitted inline, so its body (about
+    a second of Python on a serving host, which a cell's set-up pays)
+    is traced once for all of them, not once a call site a program."""
+    from batch_shipyard_tpu.models import inference as inf
+    from batch_shipyard_tpu.ops import paged_attention as pa
+
+    _module, _dims, _config, dense, paged, params, cache, engine = \
+        _served_programs("nemotron-3-nano-30b-a3b-serve-1chip",
+                         monkeypatch)
+    traced = []
+    body = pa._gqa_paged_decode_kernel
+    monkeypatch.setattr(
+        pa, "_gqa_paged_decode_kernel",
+        lambda *a, **k: traced.append(1) or body(*a, **k))
+    jax.clear_caches()
+    text = _lower_step("decode", dense, paged, params, cache,
+                       engine).as_text()
+    assert text.count('kernel_name = "gqa_paged_decode"') == 2
+    jax.eval_shape(
+        lambda: inf.init_cache(paged, None, engine["num_slots"]))
+    assert len(traced) == 1
+    jax.clear_caches()      # nothing traced through the counter stays
+
+
+@pytest.mark.parametrize("config_name,blocks", [
+    ("nemotron-3-nano-30b-a3b-serve-1chip", 6),
+    ("solar-open2-250b-serve-1chip", 4)])
+def test_a_hybrids_decode_step_gathers_no_table_on_v5e(
+        v5e_devices, config_name, blocks, monkeypatch):
+    """Each hybrid configuration's decode step at its published widths
+    and engine sizes (96 slots, tables of 32 pages of 64), cut to its
+    first ``blocks`` blocks (Nemotron's state-space, experts and
+    attention blocks 0-5: 32 query over 2 K/V heads of 128;
+    Solar-Open2's attention, experts, delta, experts: 64 over 8) so
+    that it compiles in seconds, traced as the chip traces it and
+    compiled for the v5e: every leaf of the donated cache is aliased
+    input to output; the attention is ONE grouped-kernel call reading
+    the pool where it lies; and nothing in the program has the shape
+    of a slot's gathered table ([96, 2048, ...] or, before its
+    reshape, [96, 32, 64, ...]): the gather of every slot's whole
+    table width, its layout copies and its masked softmax over 2,048
+    positions (PERF.md section 5: 5.4-5.7 ms of Solar-Open2's 20.3 ms
+    step until PR 41) are gone, not moved."""
+    import re
+
+    module, dims, config, _dense, _paged, _params, _cache, engine = \
+        _served_programs(config_name, monkeypatch)
+    assert config.paged_attention_impl is None
+    assert (engine["num_slots"], engine["max_decode_len"],
+            engine["kv_page_size"]) == (96, 2048, 64)
+    assert config.block_kinds[:blocks].count("attn") == 1
+    dense, paged, params, cache = _cut_on_chip(
+        module, dims, config, engine, blocks,
+        jax.sharding.SingleDeviceSharding(v5e_devices[0]))
+    compiled = _lower_step("decode", dense, paged, params, cache,
+                           engine).compile()
+    text = compiled.as_text()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - cache_bytes < 2 ** 20
+    assert len(re.findall(r"%gqa_paged_decode\S* = ", text)) == 1
+    gathered = re.findall(
+        r"= \w+\[96,(?:2048|32,64)[,\]]\S* ([\w-]+)\(", text)
+    assert not gathered, gathered
+    pool = re.compile(r"= bf16\[2401,64,(?:256|1024)\]\S* ([\w-]+)\(")
+    assert set(pool.findall(text)) <= {
+        "parameter", "scatter", "fusion", "dynamic-update-slice"}
+    # K and V of the one gathered table were 2 x 403 MB in
+    # Solar-Open2's step, and twice that with their layout copies
+    assert memory.temp_size_in_bytes < 256 * 2 ** 20
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_512",
@@ -697,40 +828,21 @@ def test_the_window_stack_keeps_pool_and_rings_in_place_on_v5e(
     segment of the window's length holds, far under what a
     [bucket, 16384] score tensor a head would take (28 x 16384 x
     16384 x 4 bytes = 30 GB)."""
-    import dataclasses
     import re
 
-    from benchmark import weights
-    from batch_shipyard_tpu.models import inference as inf
     from batch_shipyard_tpu.models import serving
     from batch_shipyard_tpu.models import transformer as tfm
 
     module, dims, config, _dense, _paged, _params, _cache, engine = \
         _served_programs("smallthinker-21b-a3b-serve-1chip", monkeypatch)
     assert config.paged_attention_impl == "kernel"
-    config = dataclasses.replace(
-        config, n_layers=4, block_kinds=config.block_kinds[:4],
+    assert engine["kv_page_size"] == 64
+    dense, paged, params, cache = _cut_on_chip(
+        module, dims, config, engine, 4,
+        jax.sharding.SingleDeviceSharding(v5e_devices[0]),
         layer_windows=config.layer_windows[:4],
         layer_rope=config.layer_rope[:4])
-    assert tfm.attention_windows(config) == (0, 4096)
-    chip = jax.sharding.SingleDeviceSharding(v5e_devices[0])
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=chip), tree)
-
-    dense = tfm.TransformerLM(
-        inf.decode_config(config, engine["max_decode_len"]))
-    paged = tfm.TransformerLM(dataclasses.replace(
-        dense.config, kv_page_size=64,
-        kv_num_pages=engine["kv_num_pages"] + 1))
-    leaves = [leaf for leaf in module.param_leaves(dims)
-              if not leaf[0][0].startswith("layer_")
-              or int(leaf[0][0][6:]) < 4]
-    params = on_chip(weights.abstract_params(leaves, bf16))
-    cache = on_chip(jax.eval_shape(
-        lambda: inf.init_cache(paged, None, engine["num_slots"])))
+    assert tfm.attention_windows(dense.config) == (0, 4096)
     assert cache["layer_2"]["attn"]["k_ring"].shape == (
         48 * 65, 64, 512)
     if program == "decode":
@@ -740,7 +852,7 @@ def test_the_window_stack_keeps_pool_and_rings_in_place_on_v5e(
         lowered = _lower_step(
             "prefill", dense, paged, params, cache, engine,
             bucket=int(program.split("_")[1]),
-            chunk=serving.window_segment(config))
+            chunk=serving.window_segment(dense.config))
     compiled = lowered.compile()
     text = compiled.as_text()
     cache_bytes = sum(leaf.size * leaf.dtype.itemsize
