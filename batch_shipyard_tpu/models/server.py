@@ -813,9 +813,13 @@ class ServingFrontEnd:
                 return now[name] - before[name]
             return now[name][kind] - before[name][kind]
 
+        # a model that carries its own drafter (absent otherwise)
+        drafts = "; mtp_drafted %d, mtp_accepted %d" % (
+            since("mtp_drafted"), since("mtp_accepted")) \
+            if "mtp_drafted" in now else ""
         logger.info(
             "engine launches: %s; prefills_grouped %d; prefill tokens "
-            "%d of %d padded; no work %.3f s; %d stalls",
+            "%d of %d padded; no work %.3f s; %d stalls" + drafts,
             ", ".join(
                 f"{kind} {since('launches', kind)} "
                 f"({since('launch_seconds', kind):.3f} s, "
@@ -1267,6 +1271,9 @@ class ServingFrontEnd:
             "prefills_grouped_total": engine["prefills_grouped"],
             "no_work_seconds_total": engine["no_work_seconds"],
             "stalls_total": engine["stalls"],
+            # a model that carries its own drafter (absent otherwise)
+            **{f"{name}_total": engine[name] for name in (
+                "mtp_drafted", "mtp_accepted") if name in engine},
         }))
         for metric in ("ttft_ms", "tpot_ms"):
             for pct, value in stats[metric].items():
@@ -1475,6 +1482,9 @@ class ServingFrontEnd:
             **{name: steps[name] for name in (
                 "expert_pairs_here", "expert_pairs_chosen",
                 "experts_hit") if name in steps},
+            # a drafting model's counters (absent otherwise)
+            **{name: steps[name] for name in (
+                "mtp_drafted", "mtp_accepted") if name in steps},
             "step_ms_mean": steps["step_seconds"] * per_step,
             "phase_ms_mean": {
                 name: seconds * per_step

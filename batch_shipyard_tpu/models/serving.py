@@ -83,7 +83,7 @@ _MUTABLE = ["cache", "decisions"]
 @functools.partial(jax.jit, static_argnames=("model", "sampling"),
                    donate_argnames=("cache",))
 def _decode_step(model, sampling, params, cache, tokens, positions,
-                 active, key):
+                 active, key, draft=None):
     """One token for every slot in one compiled call. MODULE-LEVEL
     with the model/sampling static so identical engines — fleet
     replicas sharing one param tree, or a test suite constructing
@@ -101,7 +101,15 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
 
     A model with layers that choose experts (tfm.decision_layer_names)
     gives one result more, behind the four: the step's choices, int32
-    [decision layers, B, k], each slot's at the position it fed."""
+    [decision layers, B, k], each slot's at the position it fed.
+
+    A model that carries its own drafter (TransformerConfig.
+    mtp_modules) is handed ``draft`` [B] too, and the SAME program
+    name then holds the verify-and-draft step (_verify_and_draft: one
+    or two tokens a slot; its results behind the same first four)."""
+    if model.config.mtp_modules:
+        return _verify_and_draft(model, params, cache, tokens, draft,
+                                 positions, active)
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
         positions=positions[:, None], mutable=_MUTABLE)
@@ -126,6 +134,72 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
     if chosen is None:
         return cache, next_tok[:, None], positions, next_tok
     return cache, next_tok[:, None], positions, next_tok, chosen[:, :, 0]
+
+
+def _verify_and_draft(model, params, cache, tokens, draft, positions,
+                      active):
+    """_decode_step for a model that carries its own drafter (a
+    multi-token-prediction module, transformer.MTPModule): ONE
+    program, dispatched by the lookahead as every decode step is, that
+    lands one or two tokens a slot. tokens [B, 1] is each slot's
+    pending token y (landed, not yet cached) at ``positions`` [B],
+    ``draft`` [B] the module's guess d of the token after it; every
+    cache leaf (the stack's pool and rings, the module's pool) holds
+    the committed positions before y's.
+
+      verify  [y, d] through the stack at positions p, p+1: the grouped
+              paged-decode kernel reads each live page once for both
+              query positions; v0, v1 = the greedy tokens after y and
+              after d
+      accept  a = (v0 == d): the slot lands v0 alone, or v0 and v1
+      module  over x_p = (Emb(v0), h_p) and x_{p+1} = (Emb(v1),
+              h_{p+1}): its K/V rows, its routed choices, and the next
+              draft, its greedy token at position p + a
+      rewind  every cursor by 1 - a: a rejected draft's rows (pool,
+              ring, the module's) lie beyond the cursor and are
+              overwritten by the next step
+
+    Greedy only, and lossless: a draft decides how many of the stack's
+    own tokens land, never which. Nothing here reads the host: step
+    k+1's y, d, positions and cursors are this program's results.
+    -> (cache, tokens [B, 1], positions [B], v0 [B]: _decode_step's
+    four, the token every slot lands (a caller that wraps the step and
+    reads only those four finds them as _decode_step gives them:
+    tests/benchmark/test_bench_rehearsal.py alters them); then v1 [B],
+    accepted [B] int32 (0 for an inactive slot), draft [B][, the
+    choices int32 [decision layers, B, 2, k] of the stack's routed
+    layers and, last, the module's, at positions p and p+1])."""
+    cfg = model.config
+    block_in = jnp.concatenate([tokens, draft[:, None]], axis=1)
+    pos_blk = positions[:, None] + jnp.arange(2, dtype=jnp.int32)[None]
+    (logits, hidden), mutated = model.apply(
+        {"params": params, "cache": cache}, block_in,
+        positions=pos_blk, stack_hidden=True, mutable=_MUTABLE)
+    verified = jnp.argmax(logits.astype(jnp.float32),
+                          axis=-1).astype(jnp.int32)         # [B, 2]
+    accepted = ((verified[:, 0] == draft) & active).astype(jnp.int32)
+    chosen = tfm.collect_decisions(mutated.get("decisions"), cfg)
+    mtp_logits, mtp_mutated = model.apply(
+        {"params": params, "cache": mutated["cache"]}, verified,
+        positions=pos_blk, mtp_hidden=hidden, mutable=_MUTABLE)
+    drafted = jnp.argmax(mtp_logits.astype(jnp.float32),
+                         axis=-1).astype(jnp.int32)          # [B, 2]
+    mtp_chosen = tfm.collect_decisions(mtp_mutated.get("decisions"),
+                                       cfg, mtp=True)
+    cache = inf._park_idle_cursors(
+        inf._rewind_cache(mtp_mutated["cache"], 1 - accepted), active)
+    landed = accepted[:, None]
+    new_tok = jnp.where(
+        active[:, None],
+        jnp.take_along_axis(verified, landed, axis=1), tokens)
+    new_draft = jnp.where(
+        active, jnp.take_along_axis(drafted, landed, axis=1)[:, 0],
+        draft)
+    positions = jnp.where(active, positions + 1 + accepted, positions)
+    out = (cache, new_tok, positions, verified[:, 0], verified[:, 1],
+           accepted, new_draft)
+    picked = [part for part in (chosen, mtp_chosen) if part is not None]
+    return out + (jnp.concatenate(picked),) if picked else out
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -204,11 +278,18 @@ def _speculative_step(target_model, draft_model, gamma, t_params,
     return t_cache, d_cache, new_tok, new_pos, block, a_slot
 
 
+# The shortest segment window_segment gives: a window of 128 keys
+# bounds nothing worth bounding, and a forward of 128 rows reads every
+# weight to use a sixteenth of the MXU's rows.
+SEGMENT_FLOOR = 2048
+
+
 def window_segment(config) -> Optional[int]:
     """The engine's own segment length where it is handed no
     prefill_chunk: None (a bucket whole), but for a model with window
     layers whose inserts attend in blocks (prefill_blocks) the least
-    power of two that holds its widest window, so that what a
+    power of two that holds its widest window (SEGMENT_FLOOR at
+    least), so that what a
     segment's forward holds in HBM (its rows through every projection
     and expert; the scores stay in VMEM) is bounded by the window
     whatever the bucket, and the power-of-two buckets are equal
@@ -222,7 +303,7 @@ def window_segment(config) -> Optional[int]:
     windows = [w for w in tfm.attention_windows(config) if w]
     if not (config.prefill_blocks and windows):
         return None
-    return 1 << (max(windows) - 1).bit_length()
+    return max(1 << (max(windows) - 1).bit_length(), SEGMENT_FLOOR)
 
 
 def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
@@ -249,7 +330,8 @@ def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
     (models/ssm.py, models/delta.py): the model is told how many of
     each segment's tokens are the prompt's own (valid_len), and leaves
     the state of position prompt_len-1. -> (cache, last logits, the
-    routed layers' choices [decision layers, L, k] or None)."""
+    routed layers' choices [decision layers, L, k] or None[, the first
+    draft: _prefill_segments])."""
     return _prefill_segments(
         model, prefill_chunk, params, inf.init_cache(model, params, 1),
         prompt, 0, prompt_len)
@@ -261,61 +343,116 @@ def _prefill_segments(model, prefill_chunk, params, cache, tokens,
     cache, in chunks; the logits at position prompt_len-1. Several
     chunks of one length are ONE traced forward under lax.scan (the
     cache its carry), so that a long bucket compiles, and weighs, what
-    one chunk does and not chunks times that."""
+    one chunk does and not chunks times that.
+
+    A model that carries a multi-token-prediction module runs it
+    behind the stack in every chunk, each position's NEXT token the
+    prompt's own, so that the module's K/V of the whole prompt is in
+    the cache; the prompt's last position, whose next token is the
+    one this prefill samples (greedy), is run once more with it (one
+    row, over the row the chunk wrote), and gives the FIRST DRAFT.
+    -> (cache, last logits, choices or None) and, of a model with a
+    module, the draft int32 [1] behind them."""
+    cfg = model.config
+    drafting = bool(cfg.mtp_modules)
     total = tokens.shape[1]
     chunk = min(prefill_chunk or total, total)
+    following = jnp.concatenate(
+        [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])],
+        axis=1) if drafting else tokens
 
-    def forward(cache, seg, off, positions):
+    def forward(cache, seg, nxt, off, positions):
         # Positions are GLOBAL offsets: RoPE for chunk c must match
         # the full-sequence pass exactly.
-        h, mut = model.apply(
-            {"params": params, "cache": cache}, seg,
-            return_hidden=True, positions=start + positions,
-            valid_len=prompt_len - start - off, mutable=_MUTABLE)
-        return mut["cache"], h, tfm.collect_decisions(
-            mut.get("decisions"), model.config)
+        at = dict(return_hidden=True, positions=start + positions,
+                  valid_len=prompt_len - start - off, mutable=_MUTABLE)
+        h, mut = model.apply({"params": params, "cache": cache}, seg,
+                             stack_hidden=drafting, **at)
+        chosen = tfm.collect_decisions(mut.get("decisions"), cfg)
+        if not drafting:
+            return mut["cache"], (h, None), (chosen, None)
+        h, stack_h = h
+        _, mtp_mut = model.apply(
+            {"params": params, "cache": mut["cache"]}, nxt,
+            mtp_hidden=stack_h, **at)
+        return mtp_mut["cache"], (h, stack_h), (
+            chosen, tfm.collect_decisions(
+                mtp_mut.get("decisions"), cfg, mtp=True))
 
     if total > chunk and total % chunk == 0:
         def step(cache, xs):
-            seg, off = xs
-            cache, h, chosen = forward(
-                cache, seg, off,
+            seg, nxt, off = xs
+            cache, hidden, chosen = forward(
+                cache, seg, nxt, off,
                 off + jnp.arange(chunk, dtype=jnp.int32))
-            return cache, (h, chosen)
+            return cache, (hidden, chosen)
 
         count = total // chunk
         cache, (hidden, chosen) = jax.lax.scan(
             step, cache,
             (tokens.reshape(count, 1, chunk),
+             following.reshape(count, 1, chunk),
              jnp.arange(count, dtype=jnp.int32) * chunk))
-        hidden = hidden.reshape(1, total, hidden.shape[-1])
-        if chosen is not None:
-            # [chunks, layers, 1, chunk, k] -> [layers, S, k]
-            chosen = jnp.moveaxis(chosen[:, :, 0], 0, 1).reshape(
-                chosen.shape[1], total, chosen.shape[-1])
+        hidden = tuple(None if h is None else h.reshape(
+            1, total, h.shape[-1]) for h in hidden)
+        # [chunks, layers, 1, chunk, k] -> [layers, S, k]
+        chosen = tuple(None if c is None else jnp.moveaxis(
+            c[:, :, 0], 0, 1).reshape(c.shape[1], total, c.shape[-1])
+            for c in chosen)
     else:
-        hiddens, chosen = [], []
+        hiddens, picked = [], []
         for off in range(0, total, chunk):
             seg = tokens[:, off:off + chunk]
-            cache, h, picked = forward(
-                cache, seg, off, jnp.arange(
-                    off, off + seg.shape[1], dtype=jnp.int32))
+            cache, h, c = forward(
+                cache, seg, following[:, off:off + chunk], off,
+                jnp.arange(off, off + seg.shape[1], dtype=jnp.int32))
             hiddens.append(h)
-            chosen.append(picked)
-        hidden = (hiddens[0] if len(hiddens) == 1
-                  else jnp.concatenate(hiddens, axis=1))
-    last_h = jnp.take(hidden[0], prompt_len - start - 1, axis=0)  # [d]
-    last = tfm.output_logits(model.config, params, last_h)   # [vocab]
+            picked.append(c)
+        hidden = tuple(
+            None if part[0] is None else part[0] if len(part) == 1
+            else jnp.concatenate(part, axis=1)
+            for part in zip(*hiddens))
+        chosen = picked
+    normed = hidden[0][0]
+    at = prompt_len - start - 1
+    last = tfm.output_logits(
+        cfg, params, jnp.take(normed, at, axis=0))           # [vocab]
     if isinstance(chosen, list):     # the loop's, a chunk an entry
-        chosen = (jnp.concatenate(chosen, axis=2)[:, 0]
-                  if chosen[0] is not None else None)
-    return cache, last, chosen
+        chosen = tuple(
+            None if part[0] is None
+            else jnp.concatenate(part, axis=2)[:, 0]
+            for part in zip(*chosen))
+    chosen, mtp_chosen = chosen
+    if not drafting:
+        return cache, last, chosen
+    first = jnp.argmax(last).astype(jnp.int32)
+    cache = {**cache, tfm.MTP_NAME: inf._map_cursors(
+        lambda leaf: jnp.full_like(leaf, prompt_len - 1),
+        cache[tfm.MTP_NAME])}
+    out, mut = model.apply(
+        {"params": params, "cache": cache}, first[None, None],
+        return_hidden=True,
+        positions=jnp.reshape(prompt_len - 1, (1, 1)),
+        mtp_hidden=jnp.take(hidden[1], jnp.reshape(at, (1,)), axis=1),
+        mutable=_MUTABLE)
+    draft = jnp.argmax(tfm.output_logits(
+        cfg, params, out[0, 0]))[None].astype(jnp.int32)
+    if mtp_chosen is not None:
+        fixed = tfm.collect_decisions(mut.get("decisions"), cfg,
+                                      mtp=True)
+        mtp_chosen = jax.lax.dynamic_update_slice(
+            mtp_chosen, fixed[:, 0], (0, at, 0))
+        chosen = mtp_chosen if chosen is None else jnp.concatenate(
+            [chosen, mtp_chosen])
+    return mut["cache"], last, chosen, draft
 
 
-def _with_decisions(cache, last, chosen):
-    """A prefill program's results: the routed layers' choices behind
-    the two every model gives, for a model that has such layers."""
-    return (cache, last) if chosen is None else (cache, last, chosen)
+def _with_decisions(cache, last, chosen, draft=None):
+    """A prefill program's results: behind the two every model gives,
+    the first draft (a model with a multi-token-prediction module),
+    then the routed layers' choices (a model that has such layers)."""
+    return (cache, last) + tuple(
+        part for part in (draft, chosen) if part is not None)
 
 
 def _seat_state(big, small, slot):
@@ -368,8 +505,8 @@ def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
     prompt_len. Module-level jit with a static model: same-config
     engines (fleet replicas, draft/target pairs) share one compile
     per length bucket."""
-    small, last, chosen = _dense_prefill(model, prefill_chunk, params,
-                                         prompt, prompt_len)
+    small, last, chosen, *draft = _dense_prefill(
+        model, prefill_chunk, params, prompt, prompt_len)
 
     def scatter(big, sm, path_key):
         if path_key == "index":
@@ -381,7 +518,7 @@ def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
             big, sm, kp[-1].key if hasattr(kp[-1], "key")
             else str(kp[-1])),
         cache, small)
-    return _with_decisions(cache, last, chosen)
+    return _with_decisions(cache, last, chosen, *draft)
 
 
 @functools.partial(jax.jit,
@@ -399,8 +536,8 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
     (a leaf with a slot row and no pages) is overwritten whole, and a
     window layer's ring (_seat_ring) filled with the prompt's newest
     pages; neither reads ``table_row``."""
-    small, last, chosen = _dense_prefill(model, prefill_chunk, params,
-                                         prompt, prompt_len)
+    small, last, chosen, *draft = _dense_prefill(
+        model, prefill_chunk, params, prompt, prompt_len)
     # Bucket blocks, static (ceil: a bucket smaller than one page
     # still needs its first page written; the small cache has
     # max_decode_len >= n_blocks*page rows).
@@ -450,7 +587,7 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
             return _seat_state(big, sm, slot)
         return {key: scatter(big[key], sm[key]) for key in big}
 
-    return _with_decisions(scatter(cache, small), last, chosen)
+    return _with_decisions(scatter(cache, small), last, chosen, *draft)
 
 
 @functools.partial(jax.jit,
@@ -512,7 +649,7 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
             return out
         return {key: seed(big[key], sm[key]) for key in sm}
 
-    small, last, chosen = _prefill_segments(
+    small, last, chosen, *draft = _prefill_segments(
         model, prefill_chunk, params, seed(cache, small), suffix,
         prefix_len, prompt_len)
     total = suffix.shape[1]
@@ -557,7 +694,7 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
             return out
         return {key: scatter(big[key], sm[key]) for key in big}
 
-    return _with_decisions(scatter(cache, small), last, chosen)
+    return _with_decisions(scatter(cache, small), last, chosen, *draft)
 
 
 @functools.partial(jax.jit, static_argnames=("copies",))
@@ -585,6 +722,13 @@ def _seat_first(sampling, last_logits, key, tokens, positions, slot,
                         sample_key, sampling)
     return (key, tokens.at[slot, 0].set(first[0]),
             positions.at[slot].set(prompt_len), first)
+
+
+@jax.jit
+def _seat_draft(drafts, slot, draft):
+    """A prefill's first draft [1] seated in the slot's row of the
+    drafting decode step's input [B], behind _seat_first."""
+    return drafts.at[slot].set(draft[0])
 
 
 @dataclasses.dataclass
@@ -641,26 +785,34 @@ class _Slot:
     request: Optional[Request] = None
     generated: list[int] = dataclasses.field(default_factory=list)
     # Tokens of this request that the device was handed the programs
-    # for and the host has not read yet: a prefill's first token, a
-    # dispatched decode step's. What the host's books are behind the
-    # device by: 0 or 1 between step() calls, 2 inside one while the
-    # first token and the decode step dispatched behind it are both
-    # unread.
+    # for and the host has not read yet, AT MOST: a prefill's first
+    # token, 1 + drafts for a dispatched decode step (a step of a
+    # model that drafts lands 1 to 1 + drafts tokens a slot, and the
+    # host learns how many when it lands: _land settles the count).
+    # What the host's books are behind the device by, the worst case:
+    # page growth and occupancy reckon with it.
     in_flight: int = 0
+    # The launches behind those tokens (a first token, a decode step):
+    # each lands one token AT LEAST. Equal to in_flight for a model
+    # that does not draft.
+    launches: int = 0
 
     def decoding(self) -> bool:
         """Whether the next decode step advances this slot: seated
-        and, the tokens in flight counted (an unread first token
-        too), still short of max_new_tokens. The host knows that
-        finish before the token is computed; an eos finish it learns
-        from the token."""
+        and, the least the launches in flight will land counted (an
+        unread first token too), still short of max_new_tokens. The
+        host knows that finish before the token is computed; an eos
+        finish, and a draft's acceptance that reaches max_new_tokens
+        a step early, it learns from the landing (the step dispatched
+        meanwhile computes overshoot: _land)."""
         return (self.request is not None and
-                len(self.generated) + self.in_flight
+                len(self.generated) + self.launches
                 < self.request.max_new_tokens)
 
     def held_tokens(self) -> int:
         """Cached tokens the next decode step attends over, the row
-        it writes included: one more than its write position."""
+        it writes included: one more than its write position (at
+        most, where drafts are in flight)."""
         return (len(self.request.prompt) + len(self.generated) +
                 self.in_flight)
 
@@ -688,8 +840,13 @@ class _InFlight:
     dispatched_at: float
     queued: int
     # The routed layers' choices of this step, int32 [decision layers,
-    # B, k] on the device; None for a model without such layers.
+    # B, k] on the device ([decision layers, B, 1 + drafts, k] of a
+    # drafting step); None for a model without such layers.
     chosen: object = None
+    # A drafting step (_verify_and_draft): drafts accepted a slot,
+    # int32 [B] on the device, ``tokens`` then being 1 + drafts
+    # arrays [B] of which a slot lands its first 1 + accepted.
+    accepted: object = None
 
 
 @dataclasses.dataclass
@@ -744,7 +901,12 @@ class Launch:
     rows: int = 0               # decode: the slots the step advanced
     path: str = ""              # prefill: cold/shared/recomputed/dense
     bucket: int = 0             # prefill: padded tokens
-    tokens: int = 0             # prefill: unpadded tokens
+    # prefill: unpadded tokens; decode: the tokens the step landed
+    # (rows + accepted)
+    tokens: int = 0
+    # decode: the drafts the step accepted over its rows (0 for a
+    # model that does not draft)
+    accepted: int = 0
     request_id: str = ""        # prefill
     # prefill: the road the bucket's program takes through its routed
     # experts (moe.experts_road: "dense" / "grouped"); "" for a model
@@ -773,7 +935,8 @@ class Launch:
                "behind_ms": self.behind_ms, "ready": self.ready,
                "queued": self.queued}
         if self.kind == "decode":
-            out["rows"] = self.rows
+            out.update(rows=self.rows, tokens=self.tokens,
+                       accepted=self.accepted)
         else:
             out.update(path=self.path, bucket=self.bucket,
                        tokens=self.tokens,
@@ -969,10 +1132,36 @@ class ContinuousBatcher:
                 self.stateful or self.window
                 or not config.tie_embeddings):
             raise ValueError(
-                "speculative serving rewinds the cache by its cursor "
-                "and scores drafts through the tied embedding: not "
-                "for a model with a per-slot state, a window layer or "
-                "an lm_head")
+                "the two-model speculative path (a separate draft "
+                "model with a dense cache, a serial step order) "
+                "rewinds the cache by its cursor and scores drafts "
+                "through the tied embedding: not for a model with a "
+                "per-slot state, a window layer or an lm_head. A "
+                "model that carries its own drafter "
+                "(TransformerConfig.mtp_modules) needs no "
+                "SpeculativeConfig and may have window layers and an "
+                "lm_head")
+        # A model that carries its own drafter (a multi-token-
+        # prediction module: transformer.MTPModule): every decode step
+        # verifies the module's draft and lands 1 to 1 + drafts tokens
+        # a slot, on the lookahead's step order (_verify_and_draft).
+        self.drafts = config.mtp_modules
+        self.mtp_drafted = 0
+        self.mtp_accepted = 0
+        if self.drafts:
+            if speculative is not None:
+                raise ValueError(
+                    "the model drafts by itself (mtp_modules): no "
+                    "SpeculativeConfig beside it")
+            if sampling.temperature > 0:
+                raise ValueError(
+                    "a model's own drafter is verified greedily "
+                    "(lossless): it requires temperature == 0 "
+                    "sampling")
+            if self.stateful:
+                raise NotImplementedError(
+                    "a rejected draft is un-committed by the cursor: "
+                    "not for a model with a per-slot state")
         # The layers that choose experts per position: their choices
         # leave every step program with the tokens and are kept per
         # request until take_decisions hands them over.
@@ -991,13 +1180,13 @@ class ContinuousBatcher:
             self.page_size = kv_page_size
             self.pages = kv_pages.PagePool(
                 num_slots, kv_num_pages, kv_page_size, max_decode_len,
-                spec_window=self.gamma, overcommit=overcommit,
-                prefix_cache=self.prefix_cache)
+                spec_window=max(self.gamma, self.drafts),
+                overcommit=overcommit, prefix_cache=self.prefix_cache)
             # The pool's pages and, last, the scratch page.
             self.config = dataclasses.replace(
                 self.config, kv_page_size=kv_page_size,
                 kv_num_pages=self.pages.scratch_page + 1,
-                spec_window=self.gamma)
+                spec_window=max(self.gamma, self.drafts))
         # SLO scheduling state: live EWMA estimates of prefill cost
         # per bucket token and of the decode step feed admission's
         # stall prediction; sheds/deferrals are the overload
@@ -1083,12 +1272,14 @@ class ContinuousBatcher:
         # too.
         with on_device:
             (self.cache, self._tokens, self._positions, self._active,
-             self._key) = self._put((
+             self._key, self._draft) = self._put((
                  inf.init_cache(self.model, params, num_slots),
                  jnp.zeros((num_slots, 1), jnp.int32),
                  jnp.zeros((num_slots,), jnp.int32),
                  jnp.zeros((num_slots,), jnp.bool_),
-                 jax.random.PRNGKey(seed)))
+                 jax.random.PRNGKey(seed),
+                 # each slot's draft of the token after its pending one
+                 jnp.zeros((num_slots,), jnp.int32)))
         self._slot_state_bytes = inf.slot_state_bytes(self.cache)
         # _active as the host last pushed it (_push_active).
         self._active_host = np.zeros((num_slots,), np.bool_)
@@ -1292,8 +1483,9 @@ class ContinuousBatcher:
             else:
                 _decode_step.lower(
                     self.model, self.sampling, params_abs, cache_abs,
-                    tokens_abs, pos_abs, active_abs,
-                    key_abs).compile()
+                    tokens_abs, pos_abs, active_abs, key_abs,
+                    *([aot.abstractify(self._draft)]
+                      if self.drafts else [])).compile()
             count += 1
             dense_model = self._prefill.args[0]
             for bucket in self.warmup_buckets():
@@ -1476,7 +1668,8 @@ class ContinuousBatcher:
             before = self.occupancy()
             compiles0 = self._compiles.read()
             lookahead0 = {**self._lookahead_counts(),
-                          **self._expert_counts()}
+                          **self._expert_counts(),
+                          **self._mtp_counts()}
         self._step()
         seconds = time.monotonic() - t0
         if seconds * 1e3 > STALL_MS / 4 and self.stalls == stalls0:
@@ -1548,6 +1741,10 @@ class ContinuousBatcher:
             # of the decode step this call landed (none: all 0)
             for name, value in self._expert_counts().items():
                 attrs[name] = value - lookahead0[name]
+        if self.drafts:
+            # of the decode step this call landed
+            for name, value in self._mtp_counts().items():
+                attrs[name] = value - lookahead0[name]
         attrs.update(before)
         count, compile_s = self._compiles.read()
         if count > compiles0[0]:
@@ -1595,7 +1792,8 @@ class ContinuousBatcher:
             return
         if self.pages is not None:
             with phases("grow_pages"):
-                self._grow_pages()
+                # a drafting step writes its draft's row too
+                self._grow_pages(span=self.drafts)
         seated = self._decoding()
         if not seated:
             # Every seated request waits for its last token only.
@@ -1606,17 +1804,31 @@ class ContinuousBatcher:
         self._dispatching(t0)
         with phases("dispatch"):
             self._push_active(seated)
-            self._key, step_key = jax.random.split(self._key)
-            (self.cache, self._tokens, self._positions, next_tok,
-             *chosen) = self._decode_step(
-                self.params, self.cache, self._tokens,
-                self._positions, self._active, step_key)
+            accepted = step_key = None
+            if self.drafts:
+                # greedy: the key goes unsplit and unused
+                (self.cache, self._tokens, self._positions, first,
+                 second, accepted, self._draft,
+                 *chosen) = self._decode_step(
+                    self.params, self.cache, self._tokens,
+                    self._positions, self._active, self._key,
+                    self._draft)
+                next_tok = (first, second)
+                del first, second
+            else:
+                self._key, step_key = jax.random.split(self._key)
+                (self.cache, self._tokens, self._positions, next_tok,
+                 *chosen) = self._decode_step(
+                    self.params, self.cache, self._tokens,
+                    self._positions, self._active, step_key)
             self._unread.append(_InFlight(
                 next_tok, step_key, seated, t0, len(self._unread),
-                *chosen))
-            del next_tok, step_key, chosen  # _land lets the arrays die
+                *chosen, accepted=accepted))
+            # _land lets the arrays die
+            del next_tok, step_key, chosen, accepted
             for i, _ in seated:
-                self._slots[i].in_flight += 1
+                self._slots[i].in_flight += 1 + self.drafts
+                self._slots[i].launches += 1
         self.decode_steps += 1
         self.steps_overlapped += overlapped
         self._land_unread(keep=1)
@@ -1690,6 +1902,7 @@ class ContinuousBatcher:
                                        or first.bucket))))
         with phases("slot_update"):
             slot.in_flight -= 1
+            slot.launches -= 1
             slot.generated.append(token)
             if chosen is not None:
                 self._decisions[request_id] = {
@@ -1708,51 +1921,78 @@ class ContinuousBatcher:
         ended on its eos_id one landing earlier (a step's token, or
         a prefill's first) was still decoded, and that token is
         dropped here (overshoot_tokens; its K/V row lies past the
-        request's last token, in a page no index names). Every
+        request's last token, in a page no index names). A drafting
+        step (step.accepted) lands 1 + accepted tokens a slot, cut
+        short where one of them is the request's last. Every
         program is ordered behind the one before it by the cache it
         consumes: a slot's next prefill behind this step, and the
         step behind a prefill dispatched before it (_admit)."""
         phases = self._phases
         with phases("readback"):
-            ready = step.tokens.is_ready()
-            next_host = np.asarray(step.tokens)
-            # the whole step's choices once; take_decisions cuts a
-            # request's column out of them, if anyone asks
-            chosen = (None if step.chosen is None
-                      else np.asarray(step.chosen).astype(np.int16))
+            # [B, 1 + drafts]: what each slot may land, in order
+            if step.accepted is None:
+                ready = step.tokens.is_ready()
+                block = np.asarray(step.tokens)[:, None]
+                accepted = None
+            else:
+                ready = step.tokens[0].is_ready()
+                block = np.stack([np.asarray(tokens)
+                                  for tokens in step.tokens], axis=1)
+                accepted = np.asarray(step.accepted)
+            # the whole step's choices once, [layers, B, 1 + drafts,
+            # k]; take_decisions cuts a request's positions out of
+            # them, if anyone asks
+            chosen = None
+            if step.chosen is not None:
+                chosen = np.asarray(step.chosen).astype(np.int16)
+                chosen = chosen.reshape(
+                    len(chosen), *block.shape, chosen.shape[-1])
+        rows = [i for i, _ in step.seated]
+        taken = 0 if accepted is None else int(accepted[rows].sum())
+        self.mtp_drafted += self.drafts * len(rows)
+        self.mtp_accepted += taken
         self._landed(Launch.landing(
             "decode", step.dispatched_at, self._landed_at, ready,
-            step.queued, rows=len(step.seated)))
+            step.queued, rows=len(rows), tokens=len(rows) + taken,
+            accepted=taken))
         with phases("emit"):
-            tokens = next_host.tolist()
+            tokens = block.tolist()
             batch = []
             for i, req in step.seated:
                 slot = self._slots[i]
+                count = 1 if accepted is None else 1 + int(accepted[i])
                 if slot.request is not req:
-                    self.overshoot_tokens += 1
+                    self.overshoot_tokens += count
                     continue
-                slot.in_flight -= 1
-                token = tokens[i]
+                slot.in_flight -= block.shape[1]
+                slot.launches -= 1
+                record = self._decisions.get(req.request_id)
+                served = 0
+                # max_new_tokens and eos_id cut a landing short
+                for token in tokens[i][:count]:
+                    slot.generated.append(token)
+                    served += 1
+                    batch.append((req.request_id, token,
+                                  len(slot.generated) - 1))
+                    if slot.ended():
+                        self.overshoot_tokens += count - served
+                        self._finish(i)
+                        break
                 if chosen is not None:
-                    # the choices at the position the step FED: that
-                    # of the token before this one
-                    self._decisions[req.request_id]["steps"].append(
-                        (chosen, i))
-                slot.generated.append(token)
-                batch.append((req.request_id, token,
-                              len(slot.generated) - 1))
-                if slot.ended():
-                    self._finish(i)
+                    # the choices at the positions the step FED and
+                    # committed: that of the token before each one
+                    # served
+                    record["steps"].append((chosen, i, served))
             self._emit(batch)
             if chosen is not None:
-                self._count_experts(
-                    chosen[:, [i for i, _ in step.seated]])
+                self._count_experts(chosen[:, rows].reshape(
+                    len(chosen), -1, chosen.shape[-1]))
             # The step's device arrays die here, inside the phase:
             # their destructor releases the GIL, and whoever the
             # hand-over woke may take its turn then. It is emit's
             # cost, so it is counted here and not after every phase
             # has ended.
-            step.tokens = step.key = step.chosen = None
+            step.tokens = step.key = step.chosen = step.accepted = None
 
     def _emit(self, batch: list[tuple[str, int, int]]) -> None:
         """Hand the (request_id, token, index) triples a step or a
@@ -1867,7 +2107,8 @@ class ContinuousBatcher:
         """Cumulative step counters since the engine was built: steps
         that admitted, decoded or read a step back, their wall
         seconds, the lookahead's counters (_lookahead_counts), a
-        routed model's expert counters (_count_experts), the seconds
+        routed model's expert counters (_count_experts), a drafting
+        model's mtp_drafted / mtp_accepted (_mtp_counts), the seconds
         of each phase, and the process's compile count
         (programs built or loaded from the persistent cache, and
         their seconds)."""
@@ -1878,6 +2119,7 @@ class ContinuousBatcher:
                 **self._launch_counts(),
                 **(self._expert_counts() if self._decision_layers
                    else {}),
+                **(self._mtp_counts() if self.drafts else {}),
                 "phase_seconds": dict(self._phases.total),
                 "compiles": compiles,
                 "compile_seconds": compile_seconds}
@@ -1960,15 +2202,19 @@ class ContinuousBatcher:
         served it computed at positions p .. p+m-1 of prompt + served
         tokens: the prefill's for the positions it ran, each decode
         step's for the position it fed (every position but the last
-        token's, which is never fed). ``first`` is past whatever
+        token's, which is never fed; of a drafting step the COMMITTED
+        positions alone, and under the name transformer.MTP_NAME the
+        module's own routed layer at the same positions). ``first``
+        is past whatever
         prefix came out of shared pages. None for an unknown id, a
         second call, or a model without such layers."""
         record = self._decisions_done.pop(request_id, None)
         if record is None:
             return None
         rows = np.concatenate(
-            [record["prefill"]] + [step[:, i:i + 1]
-                                   for step, i in record["steps"]],
+            [record["prefill"]] + [step[:, i, :served]
+                                   for step, i, served
+                                   in record["steps"]],
             axis=1).astype(np.int32)
         return {"first": record["first"],
                 "layers": dict(zip(self._decision_layers, rows))}
@@ -1986,6 +2232,14 @@ class ContinuousBatcher:
         # distinct (layer, held expert) pairs
         layer = np.arange(len(local))[:, None, None] * experts.held
         self.experts_hit += len(np.unique((local + layer)[here]))
+
+    def _mtp_counts(self) -> dict:
+        """A drafting engine's cumulative counters: drafts the landed
+        decode steps verified (drafts x the slots each advanced) and
+        how many of them the stack's own choice confirmed (each one
+        more token landed by the same step)."""
+        return {"mtp_drafted": self.mtp_drafted,
+                "mtp_accepted": self.mtp_accepted}
 
     def _expert_counts(self) -> dict:
         return {"expert_pairs_here": self.expert_pairs_here,
@@ -2435,6 +2689,8 @@ class ContinuousBatcher:
                 self._dispatching(t0)
                 self.cache, last_logits, *chosen = prefill(
                     self.params, self.cache, *prefill_args)
+                # a drafting model's first draft, before the choices
+                draft = chosen.pop(0) if self.drafts else None
                 if seat is not None:
                     self.pages.publish(i, seat)
                 if path == "recomputed":
@@ -2455,9 +2711,11 @@ class ContinuousBatcher:
                  first) = self._seat_first(
                     last_logits, self._key, self._tokens,
                     self._positions, i, len(tokens))
+                if draft is not None:
+                    self._draft = _seat_draft(self._draft, i, draft)
                 self._slots[i] = _Slot(
                     request=req, generated=list(entry.resumed),
-                    in_flight=1)
+                    in_flight=1, launches=1)
                 self._unread.append(_FirstToken(
                     first, i, path, bucket, t0, queued, *chosen,
                     prefilled=prefilled,

@@ -135,8 +135,10 @@ class TransformerConfig:
     # mixer after ONE norm: "ssm" (models/ssm.py, sized by ``ssm``),
     # "delta" (models/delta.py, sized by ``delta``), "attn" (the
     # attention above alone), "experts" (moe.RoutedExperts, sized by
-    # ``experts``). None = "dense" throughout, "dense_moe" at every
-    # moe_every-th layer when ``moe`` is set.
+    # ``experts``), "mlp" (the SwiGLU MLP above alone, d_ff wide: a
+    # dense feed-forward layer among routed ones). None = "dense"
+    # throughout, "dense_moe" at every moe_every-th layer when ``moe``
+    # is set.
     block_kinds: Optional[tuple] = None
     ssm: Optional[Any] = None        # models.ssm.SSMConfig
     delta: Optional[Any] = None      # models.delta.DeltaConfig
@@ -169,13 +171,29 @@ class TransformerConfig:
     # running terms in (ops/attention.kept_in). bfloat16 is the
     # nearest precision below: what a check's control switches on.
     attn_softmax_dtype: Any = jnp.float32
+    # True: q and k pass an RMSNorm over each head's d_head channels
+    # (one learned scale of d_head a layer each, eps norm_eps) after
+    # their projections and before any rotation.
+    qk_norm: bool = False
+    # Multi-token-prediction modules behind the stack (0 or 1; MTPModule
+    # below): at position i the module reads the embedding of token
+    # i+1 and the stack's last hidden state BEFORE the final norm and
+    # predicts token i+2 through the model's own embedding and head.
+    # Its parameters are the subtree "mtp" of the same tree, its K/V a
+    # subtree "mtp" of the same cache (a full layer: a block table into
+    # the same pool). A serving engine whose model has one drafts with
+    # it (models/serving.py); a forward that does not ask for it is
+    # what it was. mtp_rope: whether the module's attention rotates q
+    # and k (None = use_rope).
+    mtp_modules: int = 0
+    mtp_rope: Optional[bool] = None
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
 
-MIXER_KINDS = ("ssm", "delta", "attn", "experts")
+MIXER_KINDS = ("ssm", "delta", "attn", "experts", "mlp")
 # ... of which these keep a fixed-size state per slot in the cache
 # (the leaves their modules declare: inference.SLOT_STATE_LEAVES)
 STATEFUL_KINDS = ("ssm", "delta")
@@ -183,6 +201,9 @@ STATEFUL_KINDS = ("ssm", "delta")
 
 def layer_kinds(cfg: TransformerConfig) -> tuple:
     """The kind of every layer, in order."""
+    if cfg.mtp_modules not in (0, 1):
+        raise NotImplementedError(
+            f"mtp_modules {cfg.mtp_modules}: one module at most")
     if cfg.block_kinds is not None:
         kinds = tuple(cfg.block_kinds)
         if len(kinds) != cfg.n_layers or any(
@@ -197,11 +218,25 @@ def layer_kinds(cfg: TransformerConfig) -> tuple:
         else "dense" for i in range(cfg.n_layers))
 
 
+# The multi-token-prediction module's name: its subtree of the
+# parameters and of the cache, and, where its feed-forward is routed,
+# the name its choices are recorded under.
+MTP_NAME = "mtp"
+
+
 def decision_layer_names(cfg: TransformerConfig) -> tuple:
     """The layers that choose experts per position and record the
     choice ("experts" blocks), by their names in the tree."""
-    return tuple(f"layer_{i}" for i, kind in enumerate(layer_kinds(cfg))
-                 if kind == "experts")
+    names = tuple(f"layer_{i}" for i, kind in enumerate(layer_kinds(cfg))
+                  if kind == "experts")
+    return names + (MTP_NAME,) if mtp_routed(cfg) else names
+
+
+def mtp_routed(cfg: TransformerConfig) -> bool:
+    """Whether the model has a multi-token-prediction module whose
+    feed-forward chooses experts (``experts`` set: the module's layer
+    is attn + experts; else attn + mlp)."""
+    return bool(cfg.mtp_modules) and cfg.experts is not None
 
 
 ATTENTION_KINDS = ("dense", "dense_moe", "attn")
@@ -228,18 +263,26 @@ def layer_rope(cfg: TransformerConfig, idx: int) -> bool:
 
 
 def attention_windows(cfg: TransformerConfig) -> tuple:
-    """The window of every layer that keeps K/V, in layer order."""
+    """The window of every layer that keeps K/V, in layer order; a
+    multi-token-prediction module's attention (full) last."""
     return tuple(layer_window(cfg, i)
                  for i, kind in enumerate(layer_kinds(cfg))
-                 if kind in ATTENTION_KINDS)
+                 if kind in ATTENTION_KINDS) + (0,) * cfg.mtp_modules
 
 
 def ring_pages(cfg: TransformerConfig, window: int) -> int:
     """Pages a slot's ring holds in a window layer of the paged
-    cache: ceil(window / page) + 1 (the window's keys straddle that
-    many pages at most), never more than a whole context's."""
+    cache: ceil((window + drafts) / page) + 1, drafts being
+    cfg.spec_window, the tokens a step may write beyond the one it
+    commits (0 for a one-token step: ceil(window / page) + 1). The
+    ring then holds window + drafts keys whole, so that the keys the
+    FIRST position of a verify block still sees and the row its LAST
+    position writes straddle that many pages at most, and a rejected
+    draft's write lands on a key every query of the block, and the
+    rewound next step's, has already left. Never more than a whole
+    context's."""
     page = cfg.kv_page_size
-    return min(-(-window // page) + 1,
+    return min(-(-(window + cfg.spec_window) // page) + 1,
                -(-(cfg.max_decode_len + cfg.spec_window) // page))
 
 
@@ -256,11 +299,19 @@ def has_slot_state(cfg: TransformerConfig) -> bool:
     return any(kind in STATEFUL_KINDS for kind in layer_kinds(cfg))
 
 
-def collect_decisions(sown, cfg: TransformerConfig):
+def collect_decisions(sown, cfg: TransformerConfig, mtp: bool = False):
     """The "decisions" collection of one apply -> int32
     [decision layers, B, T, k] in layer order, or None for a model
-    with no such layer."""
-    names = decision_layer_names(cfg)
+    with no such layer. ``mtp``: of an apply of the multi-token-
+    prediction module alone ([1, B, T, k], or None where its
+    feed-forward is not routed); an apply of the stack gives the
+    stack's layers alone."""
+    if mtp:
+        if not mtp_routed(cfg):
+            return None
+        return sown[MTP_NAME]["layer_1"]["experts"]["chosen"][0][None]
+    names = [name for name in decision_layer_names(cfg)
+             if name != MTP_NAME]
     if not names:
         return None
     return jnp.stack([sown[name]["experts"]["chosen"][0]
@@ -409,6 +460,11 @@ class Attention(nn.Module):
         q = q.reshape(batch, seq, cfg.n_heads, cfg.d_head)
         k = k.reshape(batch, seq, cfg.kv_heads, cfg.d_head)
         v = v.reshape(batch, seq, cfg.kv_heads, cfg.d_head)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                        name="q_norm")(q)
+            k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                        name="k_norm")(k)
         if cfg.use_rope if self.rope is None else self.rope:
             q = rotary_embedding(q, positions, cfg.rope_theta)
             k = rotary_embedding(k, positions, cfg.rope_theta)
@@ -601,14 +657,17 @@ class Attention(nn.Module):
         context grows: no block table, no page of the shared pool, no
         books on the host. The window's keys straddle ring - 1 pages
         at most, so the row a step writes overwrites a key the window
-        has already left. One token a call (a prefill fills the ring
-        from its batch-1 cache: serving._prefill_paged)."""
+        has already left. One token a call, or a verify block of up to
+        spec_window + 1 (position r of it sees the keys up to its own
+        and, of them, its newest ``window``; ring_pages counts the
+        block in); a prefill fills the ring from its batch-1 cache
+        (serving._prefill_paged)."""
         cfg = self.config
         batch, seq, heads, depth = q.shape
-        if seq != 1 or cfg.kv_cache_dtype is not None:
+        if seq > cfg.spec_window + 1 or cfg.kv_cache_dtype is not None:
             raise NotImplementedError(
-                "a window layer's ring takes one token a call, in the "
-                "served type (no speculative verify block, no int8)")
+                "a window layer's ring takes spec_window + 1 tokens a "
+                "call at most, in the served type (no int8)")
         page = cfg.kv_page_size
         ring = ring_pages(cfg, self.window)
         width = k.shape[2] * depth
@@ -623,13 +682,22 @@ class Attention(nn.Module):
         idx = length.value                                   # [B]
         table = jnp.arange(batch * ring, dtype=jnp.int32).reshape(
             batch, ring)
-        page_idx = table[:, 0] + (idx // page) % ring
-        offset = idx % page
+        if seq == 1:
+            # (kept apart from the block's form below, which holds it:
+            # the one-token step's lowering stays the recorded one)
+            page_idx = table[:, 0] + (idx // page) % ring
+            offset = idx % page
+            rows = (batch, width)
+        else:
+            cols = idx[:, None] + jnp.arange(seq)[None, :]    # [B, S]
+            page_idx = table[:, :1] + (cols // page) % ring
+            offset = cols % page
+            rows = (batch, seq, width)
         k_ring.value = k_ring.value.at[page_idx, offset].set(
-            k.astype(cfg.dtype).reshape(batch, width))
+            k.astype(cfg.dtype).reshape(rows))
         v_ring.value = v_ring.value.at[page_idx, offset].set(
-            v.astype(cfg.dtype).reshape(batch, width))
-        length.value = idx + 1
+            v.astype(cfg.dtype).reshape(rows))
+        length.value = idx + seq
         return paged_ops.paged_decode_attention(
             q, k_ring.value, v_ring.value, table, length.value,
             impl=cfg.paged_attention_impl, window=self.window,
@@ -722,14 +790,19 @@ class Attention(nn.Module):
         v_pages.value = v_pages.value.at[page_idx, offset].set(
             v_in.astype(store_dtype).reshape(batch, seq, width))
         length.value = idx + seq
-        if seq == 1:
+        if seq == 1 or (kv_heads != heads and not int8_kv):
+            # one token, or a verify block over a grouped pool: the
+            # kernel (the windowed gather elsewhere) reads each live
+            # page once for all seq positions, position r masked to
+            # the keys up to its own
             return paged_ops.paged_decode_attention(
                 q, k_pages.value, v_pages.value, block_table.value,
                 length.value, impl=cfg.paged_attention_impl,
                 k_scales=scale_k.value if int8_kv else None,
                 v_scales=scale_v.value if int8_kv else None,
                 softmax_dtype=cfg.attn_softmax_dtype).astype(cfg.dtype)
-        # Multi-token verify pass: gather the slot's full logical view
+        # Multi-token verify pass over an MHA (or int8) pool: gather
+        # the slot's full logical view
         # and attend causally over absolute cache positions (query s
         # at position idx+s sees keys <= idx+s) — the paged analog of
         # the dense multi-token insert path above. Every key a
@@ -906,7 +979,8 @@ class MixerBlock(nn.Module):
     the mixer of ``kind`` (MIXER_KINDS) and named by it, so that the
     tree, the cache and a device trace's operation names all say which
     kind a layer is (layer_3/ssm/..., layer_4/delta/...,
-    layer_5/attn/...). -> (the block's output, its normed input:
+    layer_5/attn/..., layer_1/mlp/...). -> (the block's output, its
+    normed input:
     what the NEXT block's router reads in a model whose router is
     placed before the token mixer, ``router_input`` here)."""
     config: TransformerConfig
@@ -931,10 +1005,47 @@ class MixerBlock(nn.Module):
             out = RoutedExperts(cfg.experts, dtype=cfg.dtype,
                                 param_dtype=cfg.param_dtype,
                                 name="experts")(normed, router_input)
+        elif self.kind == "mlp":
+            out = MLP(cfg, name="mlp")(normed)
         else:
             out = Attention(cfg, self.window, self.rope,
                             name="attn")(normed, positions)
         return x + out, normed
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (the wiring DeepSeek-V3
+    published for ``num_nextn_predict_layers``): at position i,
+
+        x_i  = [embed_norm(Emb(t_{i+1})) ; hidden_norm(h_L,i)] W_proj
+        x'_i = one layer on x (attn, full, K/V of its own over the x
+               positions; then experts where the model has routed
+               experts, else mlp: two MixerBlocks, layer_0 and layer_1)
+        out  = norm(x'_i)
+
+    ``embedded`` [B, T, d]: the model's embedding of the NEXT tokens;
+    ``hidden`` [B, T, d]: the stack's last hidden state before the
+    final norm. -> the module's normed output [B, T, d], which the
+    model's own head turns into a prediction of t_{i+2}."""
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, embedded, hidden, positions, valid_len=None):
+        cfg = self.config
+
+        def norm(name):
+            return RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+        x = jnp.concatenate(
+            [norm("embed_norm")(embedded), norm("hidden_norm")(hidden)],
+            axis=-1)
+        x = functools_partial_dense(cfg)(cfg.d_model, "proj")(x)
+        x, _ = MixerBlock(cfg, "attn", 0, cfg.mtp_rope, name="layer_0")(
+            x, positions, valid_len)
+        x, _ = MixerBlock(
+            cfg, "experts" if cfg.experts is not None else "mlp",
+            name="layer_1")(x, positions, valid_len)
+        return norm("norm")(x)
 
 
 class TransformerLM(nn.Module):
@@ -942,7 +1053,8 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False,
-                 positions=None, valid_len=None):
+                 positions=None, valid_len=None,
+                 stack_hidden: bool = False, mtp_hidden=None):
         """tokens: [B, T] int32 -> logits [B, T, vocab] (or the final
         hidden states [B, T, d_model] when return_hidden — used by the
         chunked-loss training path so the full fp32 logits tensor,
@@ -952,7 +1064,17 @@ class TransformerLM(nn.Module):
         leading tokens of this call are the sequence's own; the rest
         is bucket padding, which a layer that keeps a running state
         (models/ssm.py, models/delta.py) must not let advance it. K/V
-        rows are masked on read and need no such care."""
+        rows are masked on read and need no such care.
+
+        A model with a multi-token-prediction module (mtp_modules):
+        ``stack_hidden`` True -> (the result as above, the stack's
+        last hidden state BEFORE the final norm [B, T, d]); and
+        ``mtp_hidden`` [B, T, d] (that state, of positions
+        ``positions``) runs THE MODULE ALONE, ``tokens`` then being
+        each position's NEXT token: its logits (its normed output
+        under return_hidden) through the model's own embedding and
+        head. Neither asked for, the forward is what it is without a
+        module."""
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.d_model,
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -960,6 +1082,24 @@ class TransformerLM(nn.Module):
         x = embed(tokens)
         if positions is None:
             positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+
+        def head(normed):
+            if return_hidden:
+                return normed
+            if cfg.tie_embeddings:
+                # Tied output projection via attend (embedding
+                # transpose).
+                return embed.attend(normed.astype(jnp.float32))
+            return nn.Dense(cfg.vocab_size, use_bias=False,
+                            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                            name="lm_head")(normed)
+
+        if mtp_hidden is not None:
+            if not cfg.mtp_modules:
+                raise ValueError("mtp_hidden: the model has no "
+                                 "multi-token-prediction module")
+            return head(MTPModule(cfg, name=MTP_NAME)(
+                x, mtp_hidden, positions, valid_len))
         block, mixer_block = Block, MixerBlock
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
@@ -979,20 +1119,20 @@ class TransformerLM(nn.Module):
                 x, normed = mixer_block(
                     cfg, kind, *per_layer, name=f"layer_{idx}")(
                         x, positions, valid_len, router_input)
-                if kind != "experts":
+                if kind not in ("experts", "mlp"):
                     mixer_input = normed
             else:
                 x = block(cfg, kind == "dense_moe", *per_layer,
                           name=f"layer_{idx}")(x, positions)
-        x = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
-                    name="final_norm")(x)
-        if return_hidden:
-            return x
-        if cfg.tie_embeddings:
-            # Tied output projection via attend (embedding transpose).
-            return embed.attend(x.astype(jnp.float32))
-        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=cfg.param_dtype, name="lm_head")(x)
+        last = x
+        out = head(RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                           name="final_norm")(x))
+        if cfg.mtp_modules and self.is_initializing():
+            # the module's parameters and cache leaves, made with the
+            # stack's (nothing of it reaches the result)
+            MTPModule(cfg, name=MTP_NAME)(last, last, positions,
+                                          valid_len)
+        return (out, last) if stack_hidden else out
 
 
 def lm_loss(logits, targets, ignore_id: int = -1):

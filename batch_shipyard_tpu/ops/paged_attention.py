@@ -333,25 +333,46 @@ def window_start(lengths, window: int):
 
 
 def _group_block_mask(rows: int, heads: int, kv_heads: int,
-                      depth: int):
+                      depth: int, positions: int = 1):
     """[rows, Hkv*D] True where row h (a query head; rows past
-    ``heads`` are padding) meets the D columns of its K/V head."""
+    ``heads`` are padding) meets the D columns of its K/V head. With
+    ``positions`` > 1 query positions a slot, row r * heads + h is
+    head h of position r."""
     group = heads // kv_heads
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, kv_heads * depth), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, kv_heads * depth), 1)
-    mine = row // group
-    return (row < heads) & (col >= mine * depth) & (col < (mine + 1)
-                                                    * depth)
+    if positions == 1:
+        mine = row // group
+        return (row < heads) & (col >= mine * depth) & (
+            col < (mine + 1) * depth)
+    mine = jax.lax.rem(row, heads) // group
+    return (row < positions * heads) & (col >= mine * depth) & (
+        col < (mine + 1) * depth)
+
+
+def _row_positions(shape: tuple, heads: int, positions: int):
+    """int32 ``shape`` ([rows, n]): the query position r of row
+    r * heads + h (padding rows: the last), by comparisons alone."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    out = jnp.zeros(shape, jnp.int32)
+    for r in range(1, positions):
+        out = out + (row >= r * heads).astype(jnp.int32)
+    return out
 
 
 def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
                              o_ref, k_buf, v_buf, sems, *, page: int,
                              chunk: int, heads: int, kv_heads: int,
                              depth: int, window: int, scale: float,
-                             softmax_dtype):
+                             softmax_dtype, positions: int = 1):
     """One slot: online softmax over its live pages, a chunk of
     ``chunk`` pages a step of the inner loop, its scores and running
-    terms kept in ``softmax_dtype``."""
+    terms kept in ``softmax_dtype``. ``positions`` > 1: the slot's
+    newest ``positions`` keys are query positions too (a verify
+    block): row r * heads + h is head h of the query at key position
+    length - positions + r, masked to the keys up to its own and, in
+    a window layer, to its own newest ``window``; every live page is
+    still read once."""
     b = pl.program_id(0)
     rows = q_ref.shape[0]
     table_width = table_ref.shape[1]
@@ -365,7 +386,9 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
         v_buf[...] = jnp.zeros_like(v_buf)
 
     length = len_ref[b]
-    low = jnp.maximum(length - window, 0) if window else 0
+    # the lowest key ANY query position sees (the first's)
+    low = jnp.maximum(length - (positions - 1 + window), 0) \
+        if window else 0
     first = low // page
     last = (jnp.maximum(length, 1) - 1) // page
     chunks = jnp.where(length > 0, (last - first) // chunk + 1, 0)
@@ -406,7 +429,7 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
         start(0, 0)
 
     q = q_ref[...]                                       # [rows, D]
-    mask = _group_block_mask(rows, heads, kv_heads, depth)
+    mask = _group_block_mask(rows, heads, kv_heads, depth, positions)
     q_bd = jnp.where(
         mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
                               axis=1), 0.0).astype(q.dtype)
@@ -427,8 +450,16 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
         scores = kept_in(scores, softmax_dtype)
         pos = (first + c * chunk) * page + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
-        scores = jnp.where((pos >= low) & (pos < length), scores,
-                           _NEG_INF)
+        if positions == 1:
+            visible = (pos >= low) & (pos < length)
+        else:
+            # row r * heads + h: keys below its own position + 1
+            upper = length - (positions - 1) + _row_positions(
+                scores.shape, heads, positions)
+            visible = pos < upper
+            if window:
+                visible &= pos >= upper - window
+        scores = jnp.where(visible, scores, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
         correction = jnp.exp(m - m_new)
         p = jnp.exp(scores - m_new)
@@ -457,17 +488,21 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
 def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                       lengths, window: int = 0,
                                       softmax_dtype=jnp.float32):
-    """Pallas path for a pool of Hkv <= H K/V heads. q: [B, 1, H, D];
+    """Pallas path for a pool of Hkv <= H K/V heads. q: [B, S, H, D];
     k_pages/v_pages: [P, page, Hkv*D]; lengths: [B] valid-key counts
-    (the token written this step included). ``window`` > 0: a query
-    sees its newest ``window`` keys alone (positions length - window
-    .. length - 1), and no page wholly behind them is read.
+    (the S tokens written this step included). S == 1 is the decode
+    step; S > 1 a verify block whose query r sits at key position
+    length - S + r and sees the keys up to its own (S * H query rows
+    a slot against each live page, read ONCE). ``window`` > 0: a query
+    sees its newest ``window`` keys alone (for S == 1 positions
+    length - window .. length - 1), and no page wholly behind them is
+    read.
     block_table: [B, T] int32, entry p % T the page of logical page p:
     a table as wide as the context is an ordinary block table, a
     narrower one a RING (its T pages hold the newest T logical pages;
-    T >= ceil(window / page) + 1, so that no live key is overwritten).
-    A slot of length 0 yields zeros. ``softmax_dtype``: kept_in.
-    Returns [B, 1, H, D] in q.dtype.
+    T >= ceil((window + S - 1) / page) + 1, so that no live key is
+    overwritten). A slot of length 0 yields zeros. ``softmax_dtype``:
+    kept_in. Returns [B, S, H, D] in q.dtype.
 
     Jitted INLINE: the lowered program is what the plain function
     gives, but the kernel's body (some fifty conditional DMA starts
@@ -477,17 +512,16 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     step), not once a call site a program; a program's set-up time
     would otherwise grow with its attention layers (PERF.md, PR 41)."""
     batch, seq, heads, depth = q.shape
-    assert seq == 1, "decode consumes one token per call"
     page, width = k_pages.shape[1], k_pages.shape[2]
     kv_heads = width // depth
     if heads % kv_heads or kv_heads * depth != width:
         raise ValueError(
             f"{heads} query heads over a pool of {width} channels "
             f"(heads of {depth})")
-    # whole sublane tiles of query heads (bfloat16 packs 16 a tile)
-    rows = -(-heads // 16) * 16
-    q_rows = jnp.pad(q.reshape(batch, heads, depth),
-                     ((0, 0), (0, rows - heads), (0, 0)))
+    # whole sublane tiles of query rows (bfloat16 packs 16 a tile)
+    rows = -(-seq * heads // 16) * 16
+    q_rows = jnp.pad(q.reshape(batch, seq * heads, depth),
+                     ((0, 0), (0, rows - seq * heads), (0, 0)))
     chunk = min(GQA_CHUNK_PAGES, block_table.shape[1])
     row_spec = pl.BlockSpec((None, rows, depth),
                             lambda b, tbl, ln: (b, 0, 0))
@@ -507,7 +541,8 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
             _gqa_paged_decode_kernel, page=page, chunk=chunk,
             heads=heads, kv_heads=kv_heads, depth=depth,
             window=int(window), scale=1.0 / (depth ** 0.5),
-            softmax_dtype=softmax_dtype),
+            softmax_dtype=softmax_dtype,
+            **({} if seq == 1 else {"positions": seq})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, rows, depth), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -515,7 +550,7 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
         name=GQA_KERNEL_NAME,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q_rows, k_pages, v_pages)
-    return out[:, :heads].reshape(batch, 1, heads, depth)
+    return out[:, :seq * heads].reshape(batch, seq, heads, depth)
 
 
 def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
@@ -527,9 +562,9 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
     POSITION worked out from the entry it came through (entry c holds
     the newest logical page p <= the last with p % T == c: the ring
     rule, which for a table as wide as the context is p == c), and one
-    masked softmax over the positions the window admits."""
+    masked softmax over the positions the window admits; query r of S
+    at key position length - S + r."""
     batch, seq, heads, depth = q.shape
-    assert seq == 1
     page = k_pages.shape[1]
     entries = block_table.shape[1]
     kv_heads = k_pages.shape[2] // depth
@@ -543,11 +578,21 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
                                       entries)              # [B, T]
     pos = (logical[:, :, None] * page + jnp.arange(
         page, dtype=jnp.int32)[None, None, :]).reshape(batch, -1)
-    low = window_start(lengths, window)
-    visible = (pos >= low[:, None]) & (pos < lengths[:, None]) & (
+    if seq == 1:
+        low = window_start(lengths, window)
+        visible = (pos >= low[:, None]) & (pos < lengths[:, None]) & (
+            pos >= 0)
+        return masked_attention(q, k_all, v_all,
+                                visible[:, None, None, :], q.dtype,
+                                softmax_dtype)
+    # [B, S]: the keys query r sees are those below upper[:, r]
+    upper = lengths[:, None] - (seq - 1) + jnp.arange(
+        seq, dtype=jnp.int32)[None, :]
+    low = window_start(upper, window)
+    pos = pos[:, None, :]
+    visible = (pos >= low[:, :, None]) & (pos < upper[:, :, None]) & (
         pos >= 0)
-    return masked_attention(q, k_all, v_all,
-                            visible[:, None, None, :], q.dtype,
+    return masked_attention(q, k_all, v_all, visible[:, None], q.dtype,
                             softmax_dtype)
 
 
@@ -566,7 +611,8 @@ def resolve_paged_impl(impl: Optional[str] = None) -> str:
 
 
 def paged_decode_road(impl: Optional[str], *, grouped: bool,
-                      window: int = 0, int8: bool = False) -> str:
+                      window: int = 0, int8: bool = False,
+                      positions: int = 1) -> str:
     """Which of the four implementations a one-token paged decode call
     runs: the dispatch below and a serving report
     (workloads/serve.paged_decode_impl) both ask here, so the report
@@ -592,16 +638,19 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
     and the windowed gather read no scales. Where the gather can serve
     (a grouped int8 pool), None falls back to it on a TPU too and a
     named "kernel" raises NotImplementedError; under a window every
-    int8 call does."""
+    int8 call does. ``positions`` > 1 (a verify block: several query
+    positions a slot) is the last row's for any pool: gqa_kernel and
+    xla_windowed alone mask by query position."""
     want = resolve_paged_impl(impl)
-    if not (window or grouped):
+    if not (window or grouped or positions > 1):
         return want
-    if int8 and (window or impl == "kernel"):
+    if int8 and (window or impl == "kernel" or positions > 1):
         raise NotImplementedError(
-            "no int8 pages under a window or the grouped kernel")
+            "no int8 pages under a window, the grouped kernel or a "
+            "verify block")
     if want == "kernel" and not int8:
         return "gqa_kernel"
-    return "xla_windowed" if window else "xla"
+    return "xla_windowed" if window or positions > 1 else "xla"
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
@@ -612,12 +661,15 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
     k_scales/v_scales switch the MHA kernel and the plain gather to
     int8-page dequant. ``window`` > 0: a layer that sees its newest
     ``window`` keys alone; its table may then be a RING narrower than
-    the context, entry p % T the page of logical page p. The grouped
+    the context, entry p % T the page of logical page p. q of S > 1
+    positions a slot is a verify block (the last S keys are the
+    queries' own). The grouped
     kernel and the windowed gather alone keep their softmax in
     ``softmax_dtype`` (kept_in)."""
     road = paged_decode_road(
         impl, grouped=k_pages.shape[2] != q.shape[2] * q.shape[3],
-        window=window, int8=k_scales is not None)
+        window=window, int8=k_scales is not None,
+        positions=q.shape[1])
     if road in ("gqa_kernel", "xla_windowed"):
         fn = (gqa_paged_decode_attention_kernel if road == "gqa_kernel"
               else paged_decode_attention_xla_windowed)

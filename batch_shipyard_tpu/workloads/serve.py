@@ -170,16 +170,19 @@ def build_engine(args, config=None, params=None,
 
 
 def paged_decode_impl(config: tfm.TransformerConfig) -> str:
-    """What the engine's one-token decode attention runs, as the
-    dispatch itself decides it (ops/paged_attention.paged_decode_road)
-    from the pool's grouping, each attention layer's window and the
-    pages' type: one name, or one a kind of layer joined by "+" where
-    full and window layers take different ones."""
+    """What the engine's decode attention runs (one token a slot, or
+    a verify block of 1 + mtp_modules where the model carries its own
+    drafter), as the dispatch itself decides it
+    (ops/paged_attention.paged_decode_road) from the pool's grouping,
+    each attention layer's window, the pages' type and the query
+    positions a slot: one name, or one a kind of layer joined by "+"
+    where full and window layers take different ones."""
     return "+".join(sorted({
         paged_attention.paged_decode_road(
             config.paged_attention_impl,
             grouped=config.kv_heads != config.n_heads, window=window,
-            int8=config.kv_cache_dtype == "int8")
+            int8=config.kv_cache_dtype == "int8",
+            positions=1 + config.mtp_modules)
         for window in tfm.attention_windows(config)}))
 
 
@@ -396,6 +399,9 @@ def main(argv=None) -> int:
     if args.kv_page_size:
         report["paged_decode_impl"] = paged_decode_impl(
             fronts[0].engine.config)
+    # the model's own drafter: multi-token-prediction modules verified
+    # in every decode step (0: one token a slot a step)
+    report["mtp_modules"] = fronts[0].engine.config.mtp_modules
     if router is not None:
         report["router"] = router.stats()
     prefix = [f.engine.prefix_stats() for f in fronts]

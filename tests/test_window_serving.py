@@ -226,11 +226,14 @@ def test_the_prefills_segments_do_not_move_the_result(stack, served,
 
 
 def test_the_engine_takes_its_segment_from_the_window(stack):
-    """The least power of two that holds the widest window (24: 32),
-    for a model that attends its inserts in blocks; a bucket whole
-    without windows or without prefill_blocks."""
+    """The least power of two that holds the widest window, and
+    serving.SEGMENT_FLOOR at least (a window of 24 bounds nothing
+    worth bounding), for a model that attends its inserts in blocks;
+    a bucket whole without windows or without prefill_blocks."""
     from batch_shipyard_tpu.models import serving
-    assert _engine(stack.config, stack.params).prefill_chunk == 32
+    floor = serving.SEGMENT_FLOOR
+    assert floor == 2048
+    assert _engine(stack.config, stack.params).prefill_chunk == floor
     for config, segment in (
             (dataclasses.replace(stack.config, layer_windows=None), None),
             (dataclasses.replace(stack.config, prefill_blocks=False),
@@ -239,8 +242,11 @@ def test_the_engine_takes_its_segment_from_the_window(stack):
                 4096 if w else 0
                 for w in stack.config.layer_windows)), 4096),
             (dataclasses.replace(stack.config, layer_windows=tuple(
+                5000 if w else 0
+                for w in stack.config.layer_windows)), 8192),
+            (dataclasses.replace(stack.config, layer_windows=tuple(
                 33 if w else 0
-                for w in stack.config.layer_windows)), 64)):
+                for w in stack.config.layer_windows)), floor)):
         assert serving.window_segment(config) == segment
 
 
@@ -275,7 +281,7 @@ def test_no_window_layer_ever_holds_more_than_its_ring(stack, recorder):
     the rows say is what the books say."""
     from batch_shipyard_tpu.trace import spans as trace_spans
     engine = _engine(stack.config, stack.params, kv_num_pages=12,
-                     overcommit=True)
+                     overcommit=True, prefill_chunk=32)
     seen = []
 
     def each_step(engine):
