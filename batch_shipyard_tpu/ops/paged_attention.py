@@ -1,24 +1,33 @@
-"""Pallas paged-attention decode kernel (vLLM-style block tables).
+"""Pallas paged-attention decode kernels (vLLM-style block tables).
 
 The XLA formulation of paged decode attention
-(models/transformer.py:_decode_attend_paged) gathers every slot's
-pages into a dense [B, max_blocks*page, H, D] view before the score
-matmul — it reads the full logical table width from HBM every step,
-even for slots holding ten tokens. Decode attention is HBM-bandwidth
-bound, so that gather IS the step time.
+(paged_decode_attention_xla) gathers every slot's pages into a dense
+[B, max_blocks*page, H, D] view before the score matmul — it reads the
+full logical table width from HBM every step, even for slots holding
+ten tokens. Decode attention is HBM-bandwidth bound, so that gather IS
+the step time. The kernels read only real pages, and which one a call
+runs is paged_decode_road's one table:
 
-This kernel reads only real pages: the block table rides Pallas scalar
-prefetch (pltpu.PrefetchScalarGridSpec), the k/v page BlockSpec index
-maps translate grid step j into the slot's j-th physical page id, and
-Mosaic DMAs exactly that page into VMEM. Pages past a slot's live
-length are skipped (the index map clamps to the slot's last live page
-so the prefetched DMA never fetches garbage, and @pl.when skips the
-compute). Online softmax accumulates across the (sequential) page grid
-dimension in VMEM scratch — the flash-attention recurrence over the
-page list.
+  * bf16/f32 pages of ANY pool (an MHA pool, fewer K/V heads than
+    query heads, a window layer's ring): ONE program a slot that walks
+    the slot's live pages a chunk at a time behind a double buffer
+    (gqa_paged_decode_attention_kernel, further down).
+  * int8 pages of an MHA pool: a program a (slot, table entry), the
+    only kernel that reads the pages' scales
+    (paged_decode_attention_kernel, next). The block table rides
+    Pallas scalar prefetch (pltpu.PrefetchScalarGridSpec), the k/v
+    page BlockSpec index maps translate grid step j into the slot's
+    j-th physical page id, and Mosaic DMAs exactly that page into
+    VMEM. Pages past a slot's live length are skipped (the index map
+    clamps to the slot's last live page so the prefetched DMA never
+    fetches garbage, and @pl.when skips the compute), but every
+    (slot, entry) is still a grid step: bf16 pages left this kernel
+    for that reason (PERF.md, PR 43). Online softmax accumulates
+    across the (sequential) page grid dimension in VMEM scratch — the
+    flash-attention recurrence over the page list.
 
-One program handles ALL heads of one page: the pool is blocked as
-[P, page, H*D], so the block's last two dims are (page, H*D) — lane
+Both handle ALL heads of a page at once: the pool is blocked as
+[P, page, H*D], so a tile's last two dims are (page, H*D) — lane
 dense, and legal under Mosaic's (8, 128) block rule for bf16 and int8
 alike (a per-head block would squeeze the second-minor H dimension,
 which Mosaic refuses). That is also how the serving cache STORES the
@@ -28,7 +37,8 @@ kept [P, page, H, D] was relaid out whole (a read and a write of
 every page) on its way into every call. Per-head scores come from
 ONE matmul against a
 block-diagonal query (row h holds q_h in columns h*D..(h+1)*D, zeros
-elsewhere): K[page, H*D] x q_bd[H, H*D]^T -> [page, H]. That puts
+elsewhere): in the int8 kernel K[page, H*D] x q_bd[H, H*D]^T ->
+[page, H]. That puts
 positions on sublanes and heads on lanes, which is exactly the layout
 of the int8 pool's [page, H] scale tile, so dequantization is an
 elementwise multiply on the scores (and on the probabilities for V)
@@ -77,12 +87,13 @@ def decode_block_step(length, q_ref, k_ref, ks_ref, v_ref, vs_ref,
                       o_ref, o_acc, m_acc, l_acc, *, block: int,
                       heads: int, depth: int, scale: float):
     """One (slot, key-block) program of single-token decode attention
-    over all heads — the body shared by the paged kernel here and the
-    dense int8 kernel (ops/decode_attention.py); a fix to the
-    mask/correction/denominator logic lands in both.
+    over all heads of int8 keys and values — the body shared by the
+    paged int8 kernel here and the dense int8 kernel
+    (ops/decode_attention.py); a fix to the mask/correction/denominator
+    logic lands in both.
 
-    q_ref/o_ref: [1, H*D]. k_ref/v_ref: [block, H*D] (fp or int8).
-    ks_ref/vs_ref: [block, H] fp32 scales for int8 tiles, else None.
+    q_ref/o_ref: [1, H*D]. k_ref/v_ref: [block, H*D] int8.
+    ks_ref/vs_ref: [block, H] fp32 scales of the tiles.
     Scratch persists across the sequential key-block grid dimension
     (program_id(1)): o_acc [H, H*D] fp32 numerator (only its block
     diagonal is meaningful), m_acc/l_acc [1, H] running max /
@@ -110,9 +121,7 @@ def decode_block_step(length, q_ref, k_ref, ks_ref, v_ref, vs_ref,
             k_ref[...].astype(q.dtype), q_bd,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [block, H]
-        if ks_ref is not None:
-            scores = scores * ks_ref[...]
-        scores = scores * scale
+        scores = scores * ks_ref[...] * scale
         pos = j * block + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 0)
         scores = jnp.where(pos < length, scores, _NEG_INF)
@@ -124,8 +133,7 @@ def decode_block_step(length, q_ref, k_ref, ks_ref, v_ref, vs_ref,
         l_acc[...] = (l_acc[...] * correction +
                       jnp.sum(p, axis=0, keepdims=True))
         m_acc[...] = m_new
-        if vs_ref is not None:
-            p = p * vs_ref[...]
+        p = p * vs_ref[...]
         pv = jax.lax.dot_general(
             p.astype(q.dtype), v_ref[...].astype(q.dtype),
             (((0,), (0,)), ((), ())),
@@ -153,12 +161,6 @@ def decode_scratch_shapes(heads: int, depth: int) -> list:
             pltpu.VMEM((1, heads), jnp.float32)]
 
 
-def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
-                         o_ref, *scratch, **static):
-    decode_block_step(len_ref[pl.program_id(0)], q_ref, k_ref, None,
-                      v_ref, None, o_ref, *scratch, **static)
-
-
 def _paged_decode_kernel_int8(table_ref, len_ref, q_ref, k_ref,
                               ks_ref, v_ref, vs_ref, o_ref, *scratch,
                               **static):
@@ -167,10 +169,12 @@ def _paged_decode_kernel_int8(table_ref, len_ref, q_ref, k_ref,
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
-                                  lengths, k_scales=None,
-                                  v_scales=None):
-    """Pallas path. q: [B, 1, H, D]; k_pages/v_pages:
-    [P, page, H*D]; block_table: [B, max_blocks] int32; lengths: [B]
+                                  lengths, k_scales, v_scales):
+    """Pallas path of an int8 MHA pool (bf16/f32 pages of any pool go
+    through gqa_paged_decode_attention_kernel below, which reads no
+    scales). q: [B, 1, H, D]; k_pages/v_pages: [P, page, H*D] int8;
+    k_scales/v_scales: [P, page, H] fp32 (applied in-kernel per
+    tile); block_table: [B, max_blocks] int32; lengths: [B]
     int32 valid-key counts (INCLUDING the token written this step, so
     every attended slot has length >= 1 — a length-0 slot yields zeros
     here but softmax-of-all-masked garbage from the XLA path; the
@@ -181,14 +185,12 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     step programs park its cursor at 0 (models/inference.
     _park_idle_cursors), so it arrives here with length 1 and costs
     one block of the scratch page, not its last request's length.
-    k_scales/v_scales: [P, page, H] fp32 when the pages are int8
-    (applied in-kernel per tile). Returns [B, 1, H, D] in q.dtype."""
+    Returns [B, 1, H, D] in q.dtype."""
     batch, seq, heads, depth = q.shape
     assert seq == 1, "decode consumes one token per call"
     page = k_pages.shape[1]
     max_blocks = block_table.shape[1]
     width = heads * depth
-    int8_pages = k_scales is not None
 
     def page_index(b, j, tbl, ln):
         # Clamp dead steps to the slot's LAST live page: the prefetch
@@ -202,32 +204,22 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                             lambda b, j, tbl, ln: (b, 0, 0))
     page_spec = pl.BlockSpec((None, page, width), page_index)
     scale_spec = pl.BlockSpec((None, page, heads), page_index)
-    in_specs = [row_spec, page_spec]
-    operands = [q.reshape(batch, 1, width), k_pages]
-    if int8_pages:
-        in_specs.append(scale_spec)
-        operands.append(k_scales)
-    in_specs.append(page_spec)
-    operands.append(v_pages)
-    if int8_pages:
-        in_specs.append(scale_spec)
-        operands.append(v_scales)
-    kern = (_paged_decode_kernel_int8 if int8_pages
-            else _paged_decode_kernel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(batch, max_blocks),
-        in_specs=in_specs,
+        in_specs=[row_spec, page_spec, scale_spec, page_spec,
+                  scale_spec],
         out_specs=row_spec,
         scratch_shapes=decode_scratch_shapes(heads, depth),
     )
     out = pl.pallas_call(
-        functools.partial(kern, block=page, heads=heads, depth=depth,
+        functools.partial(_paged_decode_kernel_int8, block=page,
+                          heads=heads, depth=depth,
                           scale=1.0 / (depth ** 0.5)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, 1, width), q.dtype),
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
+      q.reshape(batch, 1, width), k_pages, k_scales, v_pages, v_scales)
     return out.reshape(batch, 1, heads, depth)
 
 
@@ -301,16 +293,18 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
 
 
 # ---------------------------------------------------------------------
-# A pool of FEWER K/V heads than query heads (grouped-query attention),
-# a layer that sees only its newest ``window`` keys, a slot-owned RING
-# of pages: one kernel, named apart from the one above in a device
-# trace (gqa_paged_decode).
+# bf16/f32 pages of any pool: as many K/V heads as query heads (MHA) or
+# FEWER (grouped-query attention), a layer that sees only its newest
+# ``window`` keys, a slot-owned RING of pages: one kernel, named apart
+# from the one above in a device trace (gqa_paged_decode) but for an
+# MHA pool's one-token call, which keeps its caller's name there
+# (paged_decode_attention).
 #
 # The grid is one program a SLOT, not one a (slot, page): a context of
 # 16,384 tokens is 256 pages, and 48 x 256 grid steps a layer, nine in
 # ten of them dead, cost more than the pages' read. The program walks
-# the slot's LIVE pages alone, GQA_CHUNK_PAGES at a time, fetching
-# them from the pool in HBM itself (one DMA a page into a
+# the slot's LIVE pages alone, a chunk (gqa_chunk_pages) at a time,
+# fetching them from the pool in HBM itself (one DMA a page into a
 # double-buffered VMEM tile, the next chunk in flight while this one
 # is attended), from the first page the window still touches to the
 # last the length reaches. Scores are [H, keys] (heads on sublanes,
@@ -319,8 +313,25 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
 # and zeros elsewhere, so G query heads read each K/V head and no K/V
 # row is repeated.
 
+# A chunk is at most this many pages and at most the bytes one buffer
+# of them holds on the widest grouped pool served (8 pages of 64 keys x
+# 1,024 channels of bfloat16): K and V, double-buffered, are four such
+# buffers of the v5e's scoped VMEM.
 GQA_CHUNK_PAGES = 8
+GQA_CHUNK_BYTES = 8 * 64 * 1024 * 2
 GQA_KERNEL_NAME = "gqa_paged_decode"
+
+
+def gqa_chunk_pages(page: int, width: int, itemsize: int,
+                    table_width: int) -> int:
+    """Pages a step of the kernel's inner loop attends, from the
+    pool's shapes alone: GQA_CHUNK_PAGES where a buffer of them stays
+    within GQA_CHUNK_BYTES (every grouped pool served: 256 to 1,024
+    channels), fewer on a wider pool (an MHA pool of 4,096 channels
+    walks 2 pages a chunk), never more than the table has entries and
+    never less than one."""
+    return max(1, min(GQA_CHUNK_PAGES, table_width,
+                      GQA_CHUNK_BYTES // (page * width * itemsize)))
 
 
 def window_start(lengths, window: int):
@@ -483,11 +494,14 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
                      for h in range(kv_heads)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "softmax_dtype"),
+@functools.partial(jax.jit,
+                   static_argnames=("window", "softmax_dtype", "name"),
                    inline=True)
 def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                       lengths, window: int = 0,
-                                      softmax_dtype=jnp.float32):
+                                      softmax_dtype=jnp.float32,
+                                      name: Optional[str] =
+                                      GQA_KERNEL_NAME):
     """Pallas path for a pool of Hkv <= H K/V heads. q: [B, S, H, D];
     k_pages/v_pages: [P, page, Hkv*D]; lengths: [B] valid-key counts
     (the S tokens written this step included). S == 1 is the decode
@@ -522,7 +536,8 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     rows = -(-seq * heads // 16) * 16
     q_rows = jnp.pad(q.reshape(batch, seq * heads, depth),
                      ((0, 0), (0, rows - seq * heads), (0, 0)))
-    chunk = min(GQA_CHUNK_PAGES, block_table.shape[1])
+    chunk = gqa_chunk_pages(page, width, k_pages.dtype.itemsize,
+                            block_table.shape[1])
     row_spec = pl.BlockSpec((None, rows, depth),
                             lambda b, tbl, ln: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -547,7 +562,7 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
         out_shape=jax.ShapeDtypeStruct((batch, rows, depth), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name=GQA_KERNEL_NAME,
+        name=name,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q_rows, k_pages, v_pages)
     return out[:, :seq * heads].reshape(batch, seq, heads, depth)
@@ -610,6 +625,12 @@ def resolve_paged_impl(impl: Optional[str] = None) -> str:
     return resolve_kernel_or_xla(impl, "paged attention")
 
 
+def _plain_call(grouped: bool, window: int, positions: int) -> bool:
+    """An MHA pool's one-token call under no window: the one call
+    whose gather is the plain one and whose int8 pages have a kernel."""
+    return not (grouped or window or positions > 1)
+
+
 def paged_decode_road(impl: Optional[str], *, grouped: bool,
                       window: int = 0, int8: bool = False,
                       positions: int = 1) -> str:
@@ -623,17 +644,19 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
 
     ``impl`` None is the Pallas kernel on a TPU and the XLA gather
     elsewhere, for every pool; "kernel" and "xla" pass through. WHICH
-    kernel and which gather the pool and the layer decide:
+    kernel and which gather the pool, the layer and the pages decide:
 
         pool, layer          bf16/f32 pages           int8 pages
                              kernel      xla          kernel     xla
-        MHA, no window       kernel      xla          kernel     xla
+        MHA, no window       gqa_kernel  xla          kernel     xla
         grouped, no window   gqa_kernel  xla          (none)     xla
         any pool, a window   gqa_kernel  xla_windowed (none)     (none)
 
-    kernel = paged_decode_attention_kernel, gqa_kernel =
-    gqa_paged_decode_attention_kernel, xla =
-    paged_decode_attention_xla, xla_windowed =
+    gqa_kernel = gqa_paged_decode_attention_kernel (one program a
+    slot over its live pages; an MHA pool is its case of as many K/V
+    heads as query heads), kernel = paged_decode_attention_kernel (a
+    program a (slot, table entry), the only one that reads scales),
+    xla = paged_decode_attention_xla, xla_windowed =
     paged_decode_attention_xla_windowed. (none): the grouped kernel
     and the windowed gather read no scales. Where the gather can serve
     (a grouped int8 pool), None falls back to it on a TPU too and a
@@ -642,7 +665,8 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
     positions a slot) is the last row's for any pool: gqa_kernel and
     xla_windowed alone mask by query position."""
     want = resolve_paged_impl(impl)
-    if not (window or grouped or positions > 1):
+    if _plain_call(grouped, window, positions) and (
+            int8 or want == "xla"):
         return want
     if int8 and (window or impl == "kernel" or positions > 1):
         raise NotImplementedError(
@@ -658,24 +682,35 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            k_scales=None, v_scales=None,
                            window: int = 0, softmax_dtype=jnp.float32):
     """Dispatch by paged_decode_road (the selection rule's one table).
-    k_scales/v_scales switch the MHA kernel and the plain gather to
-    int8-page dequant. ``window`` > 0: a layer that sees its newest
-    ``window`` keys alone; its table may then be a RING narrower than
-    the context, entry p % T the page of logical page p. q of S > 1
-    positions a slot is a verify block (the last S keys are the
-    queries' own). The grouped
-    kernel and the windowed gather alone keep their softmax in
-    ``softmax_dtype`` (kept_in)."""
+    k_scales/v_scales switch an MHA pool to its int8 kernel and the
+    plain gather to int8-page dequant. ``window`` > 0: a layer that
+    sees its newest ``window`` keys alone; its table may then be a
+    RING narrower than the context, entry p % T the page of logical
+    page p. q of S > 1 positions a slot is a verify block (the last S
+    keys are the queries' own). The grouped kernel and the windowed
+    gather alone keep their softmax in ``softmax_dtype`` (kept_in)."""
+    grouped = k_pages.shape[2] != q.shape[2] * q.shape[3]
     road = paged_decode_road(
-        impl, grouped=k_pages.shape[2] != q.shape[2] * q.shape[3],
-        window=window, int8=k_scales is not None,
-        positions=q.shape[1])
-    if road in ("gqa_kernel", "xla_windowed"):
-        fn = (gqa_paged_decode_attention_kernel if road == "gqa_kernel"
-              else paged_decode_attention_xla_windowed)
-        return fn(q, k_pages, v_pages, block_table, lengths,
-                  window=window, softmax_dtype=softmax_dtype)
-    fn = (paged_decode_attention_kernel if road == "kernel"
-          else paged_decode_attention_xla)
-    return fn(q, k_pages, v_pages, block_table, lengths,
-              k_scales=k_scales, v_scales=v_scales)
+        impl, grouped=grouped, window=window,
+        int8=k_scales is not None, positions=q.shape[1])
+    if road == "gqa_kernel":
+        # An MHA pool's one-token call goes unnamed: its device events
+        # keep the name of the scope it is called in,
+        # attn._decode_attend_paged in the model, which is what a
+        # trace's reader knows them by.
+        plain = _plain_call(grouped, window, q.shape[1])
+        return gqa_paged_decode_attention_kernel(
+            q, k_pages, v_pages, block_table, lengths, window=window,
+            softmax_dtype=softmax_dtype,
+            name=None if plain else GQA_KERNEL_NAME)
+    if road == "xla_windowed":
+        return paged_decode_attention_xla_windowed(
+            q, k_pages, v_pages, block_table, lengths, window=window,
+            softmax_dtype=softmax_dtype)
+    if road == "kernel":
+        return paged_decode_attention_kernel(
+            q, k_pages, v_pages, block_table, lengths, k_scales,
+            v_scales)
+    return paged_decode_attention_xla(
+        q, k_pages, v_pages, block_table, lengths, k_scales=k_scales,
+        v_scales=v_scales)

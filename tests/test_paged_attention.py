@@ -37,48 +37,107 @@ def _random_case(rng, dtype, batch=4, heads=4, depth=64, page=8,
     return q, k_pages, v_pages, table
 
 
-def test_kernel_matches_xla_fp32(interpret_mode):
-    rng = np.random.RandomState(0)
-    q, k_pages, v_pages, table = _random_case(rng, jnp.float32)
-    lengths = jnp.asarray([1, 5, 23, 48], jnp.int32)
-    ref = pa.paged_decode_attention_xla(q, k_pages, v_pages, table,
-                                        lengths)
-    got = pa.paged_decode_attention_kernel(q, k_pages, v_pages, table,
-                                           lengths)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-6, rtol=2e-6)
+def _mha_road(q, k_pages, v_pages, table, lengths):
+    """An MHA pool's one-token call as the dispatch makes it on a TPU:
+    the one-program-a-slot kernel (gqa_kernel) at as many K/V heads as
+    query heads, its chunk of pages from the pool's width."""
+    assert pa.paged_decode_road("kernel", grouped=False) == "gqa_kernel"
+    return pa.paged_decode_attention(q, k_pages, v_pages, table,
+                                     lengths, impl="kernel")
 
 
-def test_kernel_matches_xla_bf16(interpret_mode):
-    rng = np.random.RandomState(1)
-    q, k_pages, v_pages, table = _random_case(rng, jnp.bfloat16)
-    lengths = jnp.asarray([3, 8, 17, 41], jnp.int32)
+def _served_mha_case(rng, lengths, heads=32, depth=128, page=64,
+                     entries=32):
+    """Baichuan's pool as it is served (32 heads of 128, pages of 64,
+    a table of 32 entries, bfloat16: 2 pages a chunk), each slot
+    holding exactly the pages its length needs and its table's dead
+    tail pointing at page 0, as a freed slot's does."""
+    need = [-(-length // page) for length in lengths]
+    q = jnp.asarray(rng.randn(len(lengths), 1, heads, depth),
+                    jnp.bfloat16)
+    pools = [jnp.asarray(
+        rng.randn(1 + sum(need), page, heads * depth), jnp.bfloat16)
+        for _ in range(2)]
+    table = np.zeros((len(lengths), entries), np.int32)
+    ids = iter(rng.permutation(sum(need)) + 1)
+    for b, pages in enumerate(need):
+        table[b, :pages] = [next(ids) for _ in range(pages)]
+    return q, pools[0], pools[1], jnp.asarray(table)
+
+
+# (dtype, the case's shape, lengths, tolerance). Pages of 8 and 4
+# heads of 64 in float32: a chunk is the whole table of 6 (ragged) or
+# 8 of its 20 entries (edges: one key, a chunk's edge at 64 and 128
+# keys from both sides, the full table). Then bfloat16 as served, and
+# Baichuan's served shape: parked slots of length 1, the edge of a
+# 2-page chunk at 128 keys from both sides, the cell's mean context,
+# the full 32-page table.
+_MHA_CASES = {
+    "f32-ragged": (jnp.float32, {}, [1, 5, 23, 48], 2e-6),
+    "f32-chunk-edges": (jnp.float32,
+                        dict(batch=7, max_blocks=20, num_pages=140),
+                        [1, 63, 64, 65, 128, 129, 160], 2e-6),
+    "bf16-ragged": (jnp.bfloat16, {}, [3, 8, 17, 41], 2e-2),
+    "bf16-served": (jnp.bfloat16, None,
+                    [1, 1, 127, 128, 129, 580, 2048], 3e-2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MHA_CASES))
+def test_mha_road_matches_the_gather(interpret_mode, case):
+    dtype, shape, lengths, tol = _MHA_CASES[case]
+    rng = np.random.RandomState(len(case))
+    if shape is None:
+        q, k_pages, v_pages, table = _served_mha_case(rng, lengths)
+        assert pa.gqa_chunk_pages(64, 4096, 2, 32) == 2
+    else:
+        q, k_pages, v_pages, table = _random_case(rng, dtype, **shape)
+    lengths = jnp.asarray(lengths, jnp.int32)
     ref = pa.paged_decode_attention_xla(q, k_pages, v_pages, table,
                                         lengths)
-    got = pa.paged_decode_attention_kernel(q, k_pages, v_pages, table,
-                                           lengths)
+    got = _mha_road(q, k_pages, v_pages, table, lengths)
+    assert got.shape == q.shape and got.dtype == q.dtype
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
-        atol=2e-2, rtol=2e-2)
+        atol=tol, rtol=tol)
 
 
-def test_kernel_ignores_dead_table_tail(interpret_mode):
+def test_mha_road_ignores_dead_table_tail(interpret_mode):
     """Stale ids in the dead tail of a table row must not affect the
-    output (the index map clamps to the last live page)."""
+    output (no page past the last live one is fetched)."""
     rng = np.random.RandomState(2)
     q, k_pages, v_pages, table = _random_case(rng, jnp.float32)
     lengths = jnp.asarray([4, 9, 12, 30], jnp.int32)
-    ref = pa.paged_decode_attention_kernel(q, k_pages, v_pages, table,
-                                           lengths)
+    ref = _mha_road(q, k_pages, v_pages, table, lengths)
     page = k_pages.shape[1]
     poisoned = np.asarray(table).copy()
     for b, ln in enumerate(np.asarray(lengths)):
         live = (int(ln) + page - 1) // page
         poisoned[b, live:] = 0  # stale/reused page ids
-    got = pa.paged_decode_attention_kernel(
-        q, k_pages, v_pages, jnp.asarray(poisoned), lengths)
+    got = _mha_road(q, k_pages, v_pages, jnp.asarray(poisoned),
+                    lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=0, rtol=0)
+
+
+# (page, channels, bytes a value, table entries) -> pages a chunk:
+# the grouped pools served (Nemotron 256, SmallThinker 512,
+# Solar-Open2 and K-EXAONE 1,024 channels) keep 8, Baichuan's 4,096
+# channels walk 2, a float32 pool half its bfloat16 twin's, a ring or
+# table narrower than the chunk bounds it, and one page always goes.
+@pytest.mark.parametrize("page,width,itemsize,entries,want", [
+    (64, 256, 2, 32, 8), (64, 512, 2, 256, 8), (64, 1024, 2, 32, 8),
+    (64, 4096, 2, 32, 2), (64, 4096, 4, 32, 1), (64, 2048, 2, 32, 4),
+    (64, 512, 2, 4, 4), (64, 4096, 2, 1, 1), (8, 256, 4, 6, 6),
+    (128, 8192, 4, 32, 1)])
+def test_the_chunk_comes_from_the_pools_width(page, width, itemsize,
+                                              entries, want):
+    chunk = pa.gqa_chunk_pages(page, width, itemsize, entries)
+    assert chunk == want
+    assert 1 <= chunk <= min(pa.GQA_CHUNK_PAGES, entries)
+    # K and V, double-buffered: four buffers within the v5e's 16 MiB
+    # of scoped VMEM wherever more than one page goes
+    assert chunk == 1 or 4 * chunk * page * width * itemsize <= 2 ** 22
 
 
 def test_dispatch_auto_is_xla_off_tpu():
@@ -356,12 +415,15 @@ _ROAD_FUNCTIONS = {_K: "paged_decode_attention_kernel",
 # The dispatch's whole table, written out: (pool, window, pages) ->
 # what runs under (tpu None, tpu "kernel", tpu "xla", cpu None,
 # cpu "kernel", cpu "xla"). impl None is the kernel on a TPU and the
-# gather elsewhere for EVERY pool; a grouped pool's and a window
-# layer's kernel is the grouped one; where it cannot serve (int8
-# pages) None falls back to the gather and "kernel" raises; the
+# gather elsewhere for EVERY pool; the kernel of bf16/f32 pages is
+# the one-program-a-slot one whatever the pool and the layer (an MHA
+# pool is its case of as many K/V heads as query heads); int8 pages
+# of an MHA pool keep the (slot, table entry) grid kernel, the only
+# one that reads scales; where no kernel can serve (a grouped int8
+# pool) None falls back to the gather and "kernel" raises; the
 # windowed gather reads no scales either.
 _DISPATCH = {
-    ("mha", 0, "bf16"): (_K, _K, _X, _X, _K, _X),
+    ("mha", 0, "bf16"): (_G, _G, _X, _X, _G, _X),
     ("mha", 0, "int8"): (_K, _K, _X, _X, _K, _X),
     ("grouped", 0, "bf16"): (_G, _G, _X, _X, _G, _X),
     ("grouped", 0, "int8"): (_X, _NO, _X, _X, _NO, _X),
@@ -396,10 +458,11 @@ def test_what_kernel_means_for_a_grouped_pool(monkeypatch, backend,
     q, k_pages, v_pages, table = _grouped_case(rng, jnp.float32, 4,
                                                kv_heads)
     lengths = jnp.asarray([1, 9, 40, 64, 96], jnp.int32)
-    called = []
+    called, named = [], []
     for name in _ROAD_FUNCTIONS.values():
         monkeypatch.setattr(
-            pa, name, lambda *a, _name=name, **k: called.append(_name))
+            pa, name, lambda *a, _name=name, **k: (
+                called.append(_name), named.append(k.get("name", ""))))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     scales = {"k_scales": 1, "v_scales": 1} if pages == "int8" else {}
     config = tfm.TransformerConfig(
@@ -423,6 +486,12 @@ def test_what_kernel_means_for_a_grouped_pool(monkeypatch, backend,
     dispatch()
     assert called == [_ROAD_FUNCTIONS[want]]
     assert serve.paged_decode_impl(config) == want
+    # the kernel's device events: an MHA pool's one-token call keeps
+    # its caller's scope for a name (a trace's reader knows Baichuan's
+    # by attn._decode_attend_paged), every other its own
+    if want == _G:
+        assert named == [None if (pool, window) == ("mha", 0)
+                         else pa.GQA_KERNEL_NAME]
 
 
 def test_the_report_names_each_kind_of_layer(monkeypatch):

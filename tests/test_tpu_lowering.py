@@ -48,8 +48,12 @@ def _flash(causal):
 
 
 def _paged(q, k, v, table, lengths, ks=None, vs=None):
-    return pa.paged_decode_attention_kernel(q, k, v, table, lengths,
-                                            k_scales=ks, v_scales=vs)
+    """The paged decode call as the dispatch makes it on a TPU: the
+    one-program-a-slot kernel for bf16/f32 pages, the (slot, table
+    entry) grid kernel for an int8 MHA pool."""
+    return pa.paged_decode_attention(q, k, v, table, lengths,
+                                     impl="kernel", k_scales=ks,
+                                     v_scales=vs)
 
 
 def _xent(h, e, t):
@@ -61,12 +65,12 @@ def _fused(x, s, w):
 
 
 def _paged_args(dtype, heads, page, int8=False, batch=8, depth=64,
-                max_blocks=8):
-    pages = batch * max_blocks + 1
+                max_blocks=8, pages=None, positions=1):
+    pages = pages or batch * max_blocks + 1
     # The pool as models/transformer.py stores it: heads folded into
     # the rows, the layout the kernel blocks.
     pool = ((pages, page, heads * depth), i8 if int8 else dtype)
-    args = [((batch, 1, heads, depth), dtype), pool, pool,
+    args = [((batch, positions, heads, depth), dtype), pool, pool,
             ((batch, max_blocks), i32), ((batch,), i32)]
     if int8:
         args += [((pages, page, heads), f32)] * 2
@@ -127,6 +131,15 @@ CASES = [
     ("paged_int8_smoke", _paged, _paged_args(bf16, 16, 64, int8=True)),
     ("paged_checks", _paged, _paged_args(f32, 4, 16)),
     ("paged_int8_checks", _paged, _paged_args(f32, 4, 16, int8=True)),
+    # an MHA pool at Baichuan's served shape (48 slots, 32 heads of
+    # 128, 193 pages of 64, a table of 32: 4,096 channels, so 2 pages
+    # a chunk), one token a slot and a verify block of two
+    ("paged_baichuan", _paged,
+     _paged_args(bf16, 32, 64, batch=48, depth=128, max_blocks=32,
+                 pages=193)),
+    ("paged_baichuan_verify", _paged,
+     _paged_args(bf16, 32, 64, batch=48, depth=128, max_blocks=32,
+                 pages=193, positions=2)),
     ("dense_int8_smoke", dd.dense_decode_attention_kernel,
      _dense_args(bf16, 16)),
     ("dense_int8_checks", dd.dense_decode_attention_kernel,
@@ -595,8 +608,13 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
 # to the same text but for the numbering of jax's private helper
 # functions, `@_where_<n>`, which is why Nemotron's line, with two
 # attention blocks, is not what the plain function gave.)
+# PR 43 re-recorded Baichuan's DECODE line alone (f4983349d90ff681
+# until then): its MHA pool's bf16 pages decode through the
+# one-program-a-slot kernel (sixteen unnamed calls of it, 2 pages a
+# chunk) where the (slot, table entry) grid kernel ran. The six other
+# lines stand: the grouped pools keep 8 pages a chunk and their name.
 ACCEPTED_PROGRAMS = {
-    "baichuan-7b-serve-1chip/decode": "f4983349d90ff681",
+    "baichuan-7b-serve-1chip/decode": "c6e4739630cf2ed5",
     "baichuan-7b-serve-1chip/prefill": "d778ca3089991697",
     "nemotron-3-nano-30b-a3b-serve-1chip/decode": "61b575fbb23a47df",
     "nemotron-3-nano-30b-a3b-serve-1chip/prefill": "9494681929c5e555",
@@ -715,10 +733,14 @@ def test_the_accepted_configurations_programs_are_unchanged(
 def test_the_hybrids_grouped_pools_decode_by_the_grouped_kernel(
         monkeypatch):
     """paged_attention_impl None means the Pallas kernel on a TPU for
-    every pool (PR 41): each hybrid configuration's decode program
-    holds one grouped-kernel call an attention block (Nemotron's cut
-    has two, Solar-Open2's one) and no other Mosaic call, and
-    Baichuan's holds its MHA kernel, one call a layer."""
+    every pool (PR 41), and since PR 43 the same one-program-a-slot
+    kernel for every pool of bf16 pages: each hybrid configuration's
+    decode program holds one call of it an attention block under its
+    own name (Nemotron's cut has two, Solar-Open2's one), Baichuan's
+    one a layer WITHOUT a name of its own (the kernel body's: its
+    device events keep the scope of attn._decode_attend_paged, which
+    paged_decode_roofline's pattern reads), and no other Mosaic
+    call."""
     for config_name, grouped, mha in (
             ("nemotron-3-nano-30b-a3b-serve-1chip", 2, 0),
             ("solar-open2-250b-serve-1chip", 1, 0),
@@ -729,22 +751,28 @@ def test_the_hybrids_grouped_pools_decode_by_the_grouped_kernel(
         text = _lower_step("decode", dense, paged, params, cache,
                            engine).as_text()
         assert text.count('kernel_name = "gqa_paged_decode"') == grouped
+        assert text.count(
+            'kernel_name = "_gqa_paged_decode_kernel"') == mha
         assert text.count("stablehlo.custom_call @tpu_custom_call") == \
             grouped + mha
 
 
-def test_the_grouped_kernels_body_is_traced_once_a_shape(monkeypatch):
+@pytest.mark.parametrize("config_name,sites", [
+    ("nemotron-3-nano-30b-a3b-serve-1chip", 2),
+    ("baichuan-7b-serve-1chip", 16)])
+def test_the_grouped_kernels_body_is_traced_once_a_shape(
+        monkeypatch, config_name, sites):
     """Nemotron's decode program has two attention blocks of the same
-    shapes, and building the engine's cache traces the same attention
-    once more: the grouped kernel is jitted inline, so its body (about
-    a second of Python on a serving host, which a cell's set-up pays)
-    is traced once for all of them, not once a call site a program."""
+    shapes, Baichuan's sixteen, and building the engine's cache traces
+    the same attention once more: the kernel is jitted inline, so its
+    body (about a second of Python on a serving host, which a cell's
+    set-up pays) is traced once for all of them, not once a call site
+    a program."""
     from batch_shipyard_tpu.models import inference as inf
     from batch_shipyard_tpu.ops import paged_attention as pa
 
     _module, _dims, _config, dense, paged, params, cache, engine = \
-        _served_programs("nemotron-3-nano-30b-a3b-serve-1chip",
-                         monkeypatch)
+        _served_programs(config_name, monkeypatch)
     traced = []
     body = pa._gqa_paged_decode_kernel
     monkeypatch.setattr(
@@ -753,7 +781,7 @@ def test_the_grouped_kernels_body_is_traced_once_a_shape(monkeypatch):
     jax.clear_caches()
     text = _lower_step("decode", dense, paged, params, cache,
                        engine).as_text()
-    assert text.count('kernel_name = "gqa_paged_decode"') == 2
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == sites
     jax.eval_shape(
         lambda: inf.init_cache(paged, None, engine["num_slots"]))
     assert len(traced) == 1

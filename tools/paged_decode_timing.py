@@ -1,18 +1,21 @@
 #!/usr/bin/env python3
-"""One call of the grouped paged-decode kernel beside the XLA gather it
-replaces, timed on the chip at the two hybrid configurations' attention
-shapes (PERF.md, PR 41): 96 slots, tables of 32 pages of 64 tokens,
-Solar-Open2 64 query over 8 K/V heads of 128, Nemotron 32 over 2. Not
-part of the benchmark. For each shape: the kernel and the gather (ms a
-call, the mean of ``--calls`` back to back) at contexts drawn like the
-batch-offline cell's (a log-normal prompt plus a uniform share of a
-log-normal output: about 520 tokens a slot), beside the live K/V's own
-read at the chip's 819 GB/s; the kernel alone at every slot one key
-(its fixed cost a slot), one whole chunk of pages (512), the head of a
-second chunk (520) and a full table (2,048); the largest difference of
-the two roads' results.
+"""One call of the one-program-a-slot paged-decode kernel beside the XLA
+gather, timed on the chip at three configurations' attention shapes
+(PERF.md, PR 41 and PR 43), tables of 32 pages of 64 tokens: the two
+hybrids at 96 slots (Solar-Open2 64 query over 8 K/V heads of 128,
+Nemotron 32 over 2: 8 pages a chunk) and Baichuan's MHA pool at 48
+slots of which 16 are seated and 32 parked at length 1, as its
+batch-offline cell holds them (32 heads of 128: 4,096 channels, 2
+pages a chunk). Not part of the benchmark. For each shape: the kernel
+and the gather (ms a call, the mean of ``--calls`` back to back) at
+contexts drawn like the batch-offline cell's (a log-normal prompt plus
+a uniform share of a log-normal output: about 520 tokens a seated
+slot), beside the live K/V's own read at the chip's 819 GB/s; the
+kernel alone at every slot one key (its fixed cost a slot), 512, 520
+(the head of one more chunk) and a full table (2,048); the largest
+difference of the two roads' results.
 
-    chiprun -- python3 tools/paged_decode_timing.py
+    chiprun -- python3 tools/paged_decode_timing.py [--shape baichuan7b]
 
 A chip run only: on another backend it says so and exits 2 (a CPU
 timing of a TPU kernel's interpreter is no number)."""
@@ -28,9 +31,11 @@ sys.path.insert(0, ".")
 from batch_shipyard_tpu.ops import paged_attention as pa  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9     # TPU v5e (benchmark/peaks.json)
-SLOTS, PAGE, ENTRIES, POOL, DEPTH = 96, 64, 32, 2401, 128
-# (query heads, K/V heads)
-SHAPES = {"solaropen2": (64, 8), "nemotron3nano": (32, 2)}
+PAGE, ENTRIES, DEPTH = 64, 32, 128
+# (query heads, K/V heads, slots, of them seated in the cell, pages)
+SHAPES = {"solaropen2": (64, 8, 96, 96, 2401),
+          "nemotron3nano": (32, 2, 96, 96, 2401),
+          "baichuan7b": (32, 32, 48, 16, 193)}
 
 
 def timed(fn, args, calls: int) -> float:
@@ -45,51 +50,61 @@ def timed(fn, args, calls: int) -> float:
     return (time.perf_counter() - t0) / calls * 1e3
 
 
-def cell_lengths(rng) -> np.ndarray:
-    """Contexts of 96 seated slots as traffic/batch-offline.json draws
+def cell_lengths(rng, slots: int, seated: int) -> np.ndarray:
+    """Contexts of ``seated`` slots as traffic/batch-offline.json draws
     them: a prompt (log-normal, median 384, sigma 0.6, 64-1,024) and
     the part of an output (median 192, sigma 0.5, 64-512) decoded so
-    far."""
-    prompt = np.clip(np.exp(rng.normal(np.log(384), 0.6, SLOTS)),
+    far; the other slots parked at length 1."""
+    prompt = np.clip(np.exp(rng.normal(np.log(384), 0.6, slots)),
                      64, 1024)
-    output = np.clip(np.exp(rng.normal(np.log(192), 0.5, SLOTS)),
+    output = np.clip(np.exp(rng.normal(np.log(192), 0.5, slots)),
                      64, 512)
-    return (prompt + rng.uniform(0, 1, SLOTS) * output).astype(np.int32)
+    drawn = (prompt + rng.uniform(0, 1, slots) * output).astype(np.int32)
+    drawn[seated:] = 1
+    return drawn
 
 
 def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--calls", type=int, default=50)
     parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--shape", choices=sorted(SHAPES), default=None,
+                        help="time this shape alone (default: all)")
     args = parser.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
         print(f"no chip here ({device.platform}): nothing timed")
         return 2
     print(f"device {device.device_kind} x{jax.device_count()}")
-    rng = np.random.RandomState(args.seed)
-    drawn = cell_lengths(rng)
-    # any page for any entry: a pool as fragmented as it gets
-    table = jnp.asarray(rng.randint(0, POOL - 1, (SLOTS, ENTRIES)),
-                        jnp.int32)
-    cases = {"cell": drawn, "1": np.full(SLOTS, 1), "512": np.full(
-        SLOTS, 512), "520": np.full(SLOTS, 520), "2048": np.full(
-            SLOTS, 2048)}
-    for name, (heads, kv_heads) in SHAPES.items():
+    for name, (heads, kv_heads, slots, seated, pool) in SHAPES.items():
+        if args.shape not in (None, name):
+            continue
+        rng = np.random.RandomState(args.seed)
+        drawn = cell_lengths(rng, slots, seated)
+        # any page for any entry: a pool as fragmented as it gets
+        table = jnp.asarray(rng.randint(0, pool - 1, (slots, ENTRIES)),
+                            jnp.int32)
+        cases = {"cell": drawn, **{str(n): np.full(slots, n)
+                                   for n in (1, 512, 520, 2048)}}
         keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
         width = kv_heads * DEPTH
-        q = jax.random.normal(keys[0], (SLOTS, 1, heads, DEPTH),
+        q = jax.random.normal(keys[0], (slots, 1, heads, DEPTH),
                               jnp.bfloat16)
-        k_pages = jax.random.normal(keys[1], (POOL, PAGE, width),
+        k_pages = jax.random.normal(keys[1], (pool, PAGE, width),
                                     jnp.bfloat16)
-        v_pages = jax.random.normal(keys[2], (POOL, PAGE, width),
+        v_pages = jax.random.normal(keys[2], (pool, PAGE, width),
                                     jnp.bfloat16)
         # the pool is an ARGUMENT, as a program's cache is
         gather = jax.jit(pa.paged_decode_attention_xla)
-        print(f"{name}: {heads} query over {kv_heads} K/V heads of "
-              f"{DEPTH}, a page {PAGE * width * 2 // 1024} KiB, mean "
-              f"context {drawn.mean():.0f} (max {drawn.max()})")
-        kernel = jax.jit(pa.gqa_paged_decode_attention_kernel)
+        print(f"{name}: {slots} slots ({seated} seated), {heads} query "
+              f"over {kv_heads} K/V heads of {DEPTH}, a page "
+              f"{PAGE * width * 2 // 1024} KiB, "
+              f"{pa.gqa_chunk_pages(PAGE, width, 2, ENTRIES)} pages a "
+              f"chunk, mean seated context {drawn[:seated].mean():.0f} "
+              f"(max {drawn.max()})")
+        assert pa.paged_decode_road(
+            None, grouped=kv_heads != heads) == "gqa_kernel"
+        kernel = jax.jit(pa.paged_decode_attention)
         for case, lengths in cases.items():
             lengths = jnp.asarray(lengths, jnp.int32)
             operands = (q, k_pages, v_pages, table, lengths)

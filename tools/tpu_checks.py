@@ -33,6 +33,7 @@ if str(REPO_ROOT) not in sys.path:
     sys.path.insert(0, str(REPO_ROOT))
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -178,23 +179,57 @@ def _paged_case(rng, heads, page, batch=8, depth=64, max_blocks=8):
     return q, k_f, v_f, table, lengths
 
 
+def _served_mha_case(rng, slots=48, heads=32, depth=128, page=64,
+                     entries=32, pool=193):
+    """Baichuan's decode attention as its cell serves it: 48 slots,
+    32 heads of 128, 193 pages of 64 behind tables of 32 entries,
+    bfloat16; a third of the slots seated at contexts of 1 to 2,048
+    mixed (a chunk's edge at 128 keys from both sides, the cell's mean,
+    a full table), the rest parked at length 1 on the scratch page."""
+    seated = [2048, 1337, 580, 129, 128, 127, 65, 64, 63, 2, 1, 911,
+              400, 257, 256, 33]
+    lengths = np.ones(slots, np.int32)
+    lengths[::3] = seated
+    q = jnp.asarray(rng.randn(slots, 1, heads, depth), jnp.bfloat16)
+    k_p = jnp.asarray(rng.randn(pool, page, heads * depth),
+                      jnp.bfloat16)
+    v_p = jnp.asarray(rng.randn(pool, page, heads * depth),
+                      jnp.bfloat16)
+    table = np.zeros((slots, entries), np.int32)
+    ids = iter(rng.permutation(pool - 1) + 1)
+    for b, length in enumerate(lengths):
+        if length > 1:
+            pages = -(-int(length) // page)
+            table[b, :pages] = [next(ids) for _ in range(pages)]
+    return q, k_p, v_p, jnp.asarray(table), jnp.asarray(lengths)
+
+
 def check_paged_attention() -> bool:
-    """Pallas paged-decode kernel vs the XLA gather oracle with random
-    block tables and ragged lengths — the serving engine's headline
-    kernel."""
+    """The paged-decode kernel of bf16/f32 pages as the dispatch picks
+    it on a TPU (one program a slot over its live pages: an MHA pool
+    is the grouped kernel's case of as many K/V heads as query heads)
+    vs the XLA gather oracle with random block tables and ragged
+    lengths — the serving engine's headline kernel — and at
+    Baichuan's served shape, 4,096 channels wide, 2 pages a chunk."""
     from batch_shipyard_tpu.ops import paged_attention as paged
 
+    kernel = jax.jit(functools.partial(paged.paged_decode_attention,
+                                       impl="kernel"))
     all_ok = True
+    cases = []
     for label, dtype, heads, page, tol in _PAGED_CASES:
+        q, k_f, v_f, table, lengths = _paged_case(
+            np.random.RandomState(11), heads, page)
+        q, k_p, v_p = (x.astype(dtype)
+                       for x in (q, _folded(k_f), _folded(v_f)))
+        cases.append((label, dtype, tol, (q, k_p, v_p, table, lengths)))
+    cases.append(("bf16 h32x128 page64 table32 slots48 (baichuan)",
+                  jnp.bfloat16, 2e-2,
+                  _served_mha_case(np.random.RandomState(12))))
+    for label, dtype, tol, operands in cases:
         with _exact_if_fp32(dtype):
-            q, k_f, v_f, table, lengths = _paged_case(
-                np.random.RandomState(11), heads, page)
-            q, k_p, v_p = (x.astype(dtype)
-                           for x in (q, _folded(k_f), _folded(v_f)))
-            out_k = jax.jit(paged.paged_decode_attention_kernel)(
-                q, k_p, v_p, table, lengths)
-            out_x = paged.paged_decode_attention_xla(
-                q, k_p, v_p, table, lengths)
+            out_k = kernel(*operands)
+            out_x = paged.paged_decode_attention_xla(*operands)
             rel = _rel(out_k, out_x)
             ok = rel < tol
             print(f"paged-attention kernel vs xla [{label}]: "
@@ -319,9 +354,7 @@ def check_paged_attention_int8() -> bool:
             kp, ks = quantize_int8_rows(k_f)
             vp, vs = quantize_int8_rows(v_f)
             kp, vp, k_f, v_f = map(_folded, (kp, vp, k_f, v_f))
-            out_k = jax.jit(
-                lambda *a: paged.paged_decode_attention_kernel(
-                    *a[:5], k_scales=a[5], v_scales=a[6]))(
+            out_k = jax.jit(paged.paged_decode_attention_kernel)(
                 q, kp, vp, table, lengths, ks, vs)
             out_x = paged.paged_decode_attention_xla(
                 q, kp, vp, table, lengths, k_scales=ks, v_scales=vs)
