@@ -133,10 +133,13 @@ def _park_idle_cursors(cache, active):
     step: left alone its cursor keeps the last request's length and
     grows by one a step, and the kernel attends over that many rows of
     the scratch page for nothing. Parked, the next step writes the
-    slot's one garbage row at offset 0 and the kernel sees length 1.
-    Admission's prefill sets the cursor to the prompt's length before
-    the slot is read again. Per-slot state without a cursor key is
-    left alone."""
+    slot's one garbage row at offset 0; a dense cache's kernel then
+    sees length 1, a paged pool's length 0 (the step's same mask
+    reaches its attention call as ``live``:
+    transformer.Attention._decode_attend_paged), which costs it
+    nothing. Admission's prefill sets the cursor to the prompt's
+    length before the slot is read again. Per-slot state without a
+    cursor key is left alone."""
     return _map_cursors(lambda leaf: jnp.where(active, leaf, 0), cache)
 
 

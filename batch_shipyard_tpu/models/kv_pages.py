@@ -282,9 +282,9 @@ class PagePool:
         each seated request holds. kv_blocks_attended is the work of
         ONE layer's paged decode kernel in a step dispatched from
         this state, in (slot, page) blocks: ceil(tokens / page) for a
-        seated slot and one for an idle one, whose cursor the step
-        programs park at 0 (a slot the last step freed attends over
-        its old length once more, which these books do not follow).
+        seated slot and none for an idle one, which the step's mask
+        hands the kernel as length 0 (slots_total - slots_active of
+        the engine's occupancy is the programs a layer skips).
         ceil(live_tokens / page) is the least a kernel could do."""
         return {
             "kv_pages_in_use": len(
@@ -295,8 +295,7 @@ class PagePool:
             "kv_pages_total": self.num_pages,
             "prefix_index_pages": len(self._page_ref),
             "kv_blocks_attended": sum(
-                self.pages_for(tokens) for tokens in held_tokens
-            ) + self.num_slots - len(held_tokens),
+                self.pages_for(tokens) for tokens in held_tokens),
         }
 
     def check(self) -> None:

@@ -112,7 +112,7 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
                                  positions, active)
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, tokens,
-        positions=positions[:, None], mutable=_MUTABLE)
+        positions=positions[:, None], live=active, mutable=_MUTABLE)
     next_tok = inf._sample(logits[:, 0].astype(jnp.float32),
                            key, sampling)
     # Inactive slots DO write one garbage row a step, and that is
@@ -121,11 +121,12 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
     # table points) and _admit's prefill rewrites the rows + cursor
     # before reuse — restoring the full K/V trees here would double
     # per-token HBM traffic for no observable effect. What needs
-    # masking is the cheap bookkeeping: token, position, and the
-    # cache cursor, which the decode kernels skip key blocks by. An
-    # idle slot leaves every step program with its cursor at 0, so it
-    # costs the next step one block instead of its last request's
-    # length plus one more row every step it sits idle.
+    # masking is the cheap bookkeeping: token, position, the cache
+    # cursor (an idle slot leaves every step program with its cursor
+    # at 0, so its garbage row stays at offset 0 of the scratch page)
+    # and, for a paged pool, the length its attention call is handed:
+    # ``live`` above makes it 0, and the paged decode kernel then
+    # fetches no page and computes no tile for the slot.
     next_tok = jnp.where(active, next_tok, tokens[:, 0])
     positions = jnp.where(active, positions + 1, positions)
     cache = inf._park_idle_cursors(mutated["cache"], active)
@@ -174,14 +175,16 @@ def _verify_and_draft(model, params, cache, tokens, draft, positions,
     pos_blk = positions[:, None] + jnp.arange(2, dtype=jnp.int32)[None]
     (logits, hidden), mutated = model.apply(
         {"params": params, "cache": cache}, block_in,
-        positions=pos_blk, stack_hidden=True, mutable=_MUTABLE)
+        positions=pos_blk, stack_hidden=True, live=active,
+        mutable=_MUTABLE)
     verified = jnp.argmax(logits.astype(jnp.float32),
                           axis=-1).astype(jnp.int32)         # [B, 2]
     accepted = ((verified[:, 0] == draft) & active).astype(jnp.int32)
     chosen = tfm.collect_decisions(mutated.get("decisions"), cfg)
     mtp_logits, mtp_mutated = model.apply(
         {"params": params, "cache": mutated["cache"]}, verified,
-        positions=pos_blk, mtp_hidden=hidden, mutable=_MUTABLE)
+        positions=pos_blk, mtp_hidden=hidden, live=active,
+        mutable=_MUTABLE)
     drafted = jnp.argmax(mtp_logits.astype(jnp.float32),
                          axis=-1).astype(jnp.int32)          # [B, 2]
     mtp_chosen = tfm.collect_decisions(mtp_mutated.get("decisions"),
@@ -233,7 +236,7 @@ def _speculative_step(target_model, draft_model, gamma, t_params,
         cache, tok, pos = carry
         hidden, mut = draft_model.apply(
             {"params": d_params, "cache": cache}, tok,
-            return_hidden=True, positions=pos[:, None],
+            return_hidden=True, positions=pos[:, None], live=active,
             mutable=["cache"])
         logits = jnp.dot(
             hidden[:, 0].astype(jnp.float32),
@@ -250,7 +253,7 @@ def _speculative_step(target_model, draft_model, gamma, t_params,
         gamma + 1, dtype=jnp.int32)[None, :]
     hidden, mut = target_model.apply(
         {"params": t_params, "cache": t_cache}, x_blk,
-        return_hidden=True, positions=pos_blk,
+        return_hidden=True, positions=pos_blk, live=active,
         mutable=["cache"])
     t_cache = mut["cache"]
     logits = jnp.einsum(

@@ -421,7 +421,7 @@ class Attention(nn.Module):
     rope: Optional[bool] = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, live=None):
         cfg = self.config
         features = cfg.n_heads * cfg.d_head
         kv_features = cfg.kv_heads * cfg.d_head
@@ -478,10 +478,10 @@ class Attention(nn.Module):
                 raise ValueError(
                     f"kv_cache_dtype={cfg.kv_cache_dtype!r}: only "
                     f"'int8' (or None) is supported")
-            attend = (self._decode_attend_paged
-                      if cfg.kv_page_size else self._decode_attend)
+            out = (self._decode_attend_paged(q, k, v, live)
+                   if cfg.kv_page_size else self._decode_attend(q, k, v))
             return dense(cfg.d_model, "o_proj")(self._gated(
-                attend(q, k, v).reshape(batch, seq, features), x))
+                out.reshape(batch, seq, features), x))
         attention_fn = cfg.attention_fn or (
             lambda q_, k_, v_, causal: attn_ops.attention(
                 q_, k_, v_, causal=causal))
@@ -648,7 +648,7 @@ class Attention(nn.Module):
                 for cache in (cache_k, cache_v))
         return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
 
-    def _decode_attend_ring(self, q, k, v):
+    def _decode_attend_ring(self, q, k, v, live=None):
         """A WINDOW layer's paged decode attention: the slot keeps its
         newest keys in a ring of its own, ring_pages(cfg, window)
         pages of the layer's [B * ring, page, Hkv*D] leaves (slot b's
@@ -661,7 +661,7 @@ class Attention(nn.Module):
         spec_window + 1 (position r of it sees the keys up to its own
         and, of them, its newest ``window``; ring_pages counts the
         block in); a prefill fills the ring from its batch-1 cache
-        (serving._prefill_paged)."""
+        (serving._prefill_paged). ``live``: _decode_attend_paged's."""
         cfg = self.config
         batch, seq, heads, depth = q.shape
         if seq > cfg.spec_window + 1 or cfg.kv_cache_dtype is not None:
@@ -699,11 +699,12 @@ class Attention(nn.Module):
             v.astype(cfg.dtype).reshape(rows))
         length.value = idx + seq
         return paged_ops.paged_decode_attention(
-            q, k_ring.value, v_ring.value, table, length.value,
+            q, k_ring.value, v_ring.value, table,
+            _live_lengths(length.value, live),
             impl=cfg.paged_attention_impl, window=self.window,
             softmax_dtype=cfg.attn_softmax_dtype).astype(cfg.dtype)
 
-    def _decode_attend_paged(self, q, k, v):
+    def _decode_attend_paged(self, q, k, v, live=None):
         """Paged decode attention (vLLM-style block tables): K/V live
         in a SHARED page pool [P, page, H*D]; each slot owns a row of
         page indices (block_table) covering only its actual length —
@@ -724,10 +725,21 @@ class Attention(nn.Module):
         so everything stays inside the flax cache collection; the
         serving engine's page allocator mutates every layer's copy
         identically (models/serving.py).
+
+        ``live`` ([B] bool, None = every slot): the serving step's
+        mask of the slots that hold a request. A slot without one
+        stays a row of the full-batch step: its row is written (the
+        scratch page, where its table points, absorbs it) and its
+        cursor advances like any other, but the attention call is
+        handed length 0 for it, which every road of
+        paged_ops.paged_decode_road answers with zeros and the kernels
+        with no page fetched and no tile computed. The mask, never
+        ``cursor == 0``, says which: a live slot decoding its first
+        key from an empty cache has that cursor and attends its key.
         """
         cfg = self.config
         if self.window:
-            return self._decode_attend_ring(q, k, v)
+            return self._decode_attend_ring(q, k, v, live)
         int8_kv = cfg.kv_cache_dtype == "int8"  # validated at dispatch
         store_dtype = jnp.int8 if int8_kv else cfg.dtype
         batch, seq, heads, depth = q.shape
@@ -797,7 +809,8 @@ class Attention(nn.Module):
             # the keys up to its own
             return paged_ops.paged_decode_attention(
                 q, k_pages.value, v_pages.value, block_table.value,
-                length.value, impl=cfg.paged_attention_impl,
+                _live_lengths(length.value, live),
+                impl=cfg.paged_attention_impl,
                 k_scales=scale_k.value if int8_kv else None,
                 v_scales=scale_v.value if int8_kv else None,
                 softmax_dtype=cfg.attn_softmax_dtype).astype(cfg.dtype)
@@ -827,6 +840,12 @@ class Attention(nn.Module):
         mask = (key_pos[None, None, :] <=
                 cols[:, :, None])[:, None, :, :]      # [B, 1, S, T]
         return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
+
+
+def _live_lengths(lengths, live):
+    """The key counts a paged decode call is handed: 0 for a slot the
+    step's ``live`` mask ([B] bool, None = every slot) leaves out."""
+    return lengths if live is None else jnp.where(live, lengths, 0)
 
 
 def prefix_rows_from_pages(layer_cache: dict, page_ids,
@@ -945,7 +964,7 @@ class Block(nn.Module):
     rope: Optional[bool] = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, live=None):
         cfg = self.config
         if cfg.fused_norm:
             if (cfg.tp_axis or cfg.quantize_matmuls or cfg.decode
@@ -961,7 +980,7 @@ class Block(nn.Module):
             return x + MLP(cfg, name="mlp")(x)
         x = x + Attention(cfg, self.window, self.rope, name="attn")(
             RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
-                    name="attn_norm")(x), positions)
+                    name="attn_norm")(x), positions, live)
         normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                          name="mlp_norm")(x)
         if self.use_moe:
@@ -990,7 +1009,7 @@ class MixerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, valid_len=None,
-                 router_input=None):
+                 router_input=None, live=None):
         cfg = self.config
         normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                          name="norm")(x)
@@ -1009,7 +1028,7 @@ class MixerBlock(nn.Module):
             out = MLP(cfg, name="mlp")(normed)
         else:
             out = Attention(cfg, self.window, self.rope,
-                            name="attn")(normed, positions)
+                            name="attn")(normed, positions, live)
         return x + out, normed
 
 
@@ -1030,7 +1049,8 @@ class MTPModule(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, embedded, hidden, positions, valid_len=None):
+    def __call__(self, embedded, hidden, positions, valid_len=None,
+                 live=None):
         cfg = self.config
 
         def norm(name):
@@ -1041,7 +1061,7 @@ class MTPModule(nn.Module):
             axis=-1)
         x = functools_partial_dense(cfg)(cfg.d_model, "proj")(x)
         x, _ = MixerBlock(cfg, "attn", 0, cfg.mtp_rope, name="layer_0")(
-            x, positions, valid_len)
+            x, positions, valid_len, live=live)
         x, _ = MixerBlock(
             cfg, "experts" if cfg.experts is not None else "mlp",
             name="layer_1")(x, positions, valid_len)
@@ -1054,7 +1074,8 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False,
                  positions=None, valid_len=None,
-                 stack_hidden: bool = False, mtp_hidden=None):
+                 stack_hidden: bool = False, mtp_hidden=None,
+                 live=None):
         """tokens: [B, T] int32 -> logits [B, T, vocab] (or the final
         hidden states [B, T, d_model] when return_hidden — used by the
         chunked-loss training path so the full fp32 logits tensor,
@@ -1065,6 +1086,9 @@ class TransformerLM(nn.Module):
         is bucket padding, which a layer that keeps a running state
         (models/ssm.py, models/delta.py) must not let advance it. K/V
         rows are masked on read and need no such care.
+        ``live`` ([B] bool, a paged decode step only; None = every
+        slot): the slots that hold a request; the others' attention is
+        skipped (Attention._decode_attend_paged).
 
         A model with a multi-token-prediction module (mtp_modules):
         ``stack_hidden`` True -> (the result as above, the stack's
@@ -1099,7 +1123,7 @@ class TransformerLM(nn.Module):
                 raise ValueError("mtp_hidden: the model has no "
                                  "multi-token-prediction module")
             return head(MTPModule(cfg, name=MTP_NAME)(
-                x, mtp_hidden, positions, valid_len))
+                x, mtp_hidden, positions, valid_len, live))
         block, mixer_block = Block, MixerBlock
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
@@ -1118,12 +1142,12 @@ class TransformerLM(nn.Module):
                     router_input = mixer_input
                 x, normed = mixer_block(
                     cfg, kind, *per_layer, name=f"layer_{idx}")(
-                        x, positions, valid_len, router_input)
+                        x, positions, valid_len, router_input, live)
                 if kind not in ("experts", "mlp"):
                     mixer_input = normed
             else:
                 x = block(cfg, kind == "dense_moe", *per_layer,
-                          name=f"layer_{idx}")(x, positions)
+                          name=f"layer_{idx}")(x, positions, live)
         last = x
         out = head(RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                            name="final_norm")(x))

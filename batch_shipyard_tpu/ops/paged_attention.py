@@ -175,16 +175,15 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     scales). q: [B, 1, H, D]; k_pages/v_pages: [P, page, H*D] int8;
     k_scales/v_scales: [P, page, H] fp32 (applied in-kernel per
     tile); block_table: [B, max_blocks] int32; lengths: [B]
-    int32 valid-key counts (INCLUDING the token written this step, so
-    every attended slot has length >= 1 — a length-0 slot yields zeros
-    here but softmax-of-all-masked garbage from the XLA path; the
-    decode contract never attends an unwritten slot). The grid is
-    (B, max_blocks) whatever the lengths: a slot computes
-    ceil(length / page) of its blocks and steps over the rest. A slot
-    that holds no request is still a row of the batch; the serving
-    step programs park its cursor at 0 (models/inference.
-    _park_idle_cursors), so it arrives here with length 1 and costs
-    one block of the scratch page, not its last request's length.
+    int32 valid-key counts (INCLUDING the token written this step). A
+    slot of length 0 yields zeros, as on every road of
+    paged_decode_road's table. The grid is (B, max_blocks) whatever
+    the lengths: a slot computes ceil(length / page) of its blocks
+    and steps over the rest. A slot that holds no request is still a
+    row of the batch; the serving step hands it here with length 0
+    (its ``live`` mask, Attention._decode_attend_paged), so it
+    computes no block: its grid steps fetch its table's first entry
+    (the scratch page) and skip.
     Returns [B, 1, H, D] in q.dtype."""
     batch, seq, heads, depth = q.shape
     assert seq == 1, "decode consumes one token per call"
@@ -258,6 +257,13 @@ def masked_attention(q, k_all, v_all, mask, dtype,
     return out.reshape(batch, seq, heads, depth).astype(dtype)
 
 
+def _zero_where_empty(out, lengths):
+    """The gathers' half of "a slot of length 0 yields zeros": a
+    softmax over no visible key is a mean of whatever the table
+    points at, where the kernels write zeros. out: [B, S, H, D]."""
+    return jnp.where((lengths > 0)[:, None, None, None], out, 0)
+
+
 def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
                                lengths, k_scales=None,
                                v_scales=None):
@@ -267,7 +273,7 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
     With int8 pages, only the GATHERED slices dequantize — never the
     whole pool. The pool may hold FEWER K/V heads than q has query
     heads (its rows are Hkv*D wide): masked_attention groups the
-    query heads over them."""
+    query heads over them. A slot of length 0 yields zeros."""
     batch, seq, heads, depth = q.shape
     assert seq == 1
     page = k_pages.shape[1]
@@ -289,7 +295,8 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table,
     key_pos = jax.lax.broadcasted_iota(
         jnp.int32, (max_blocks * page, 1), 0)[:, 0]
     mask = (key_pos[None, :] < lengths[:, None])[:, None, None, :]
-    return masked_attention(q, k_all, v_all, mask, q.dtype)
+    return _zero_where_empty(
+        masked_attention(q, k_all, v_all, mask, q.dtype), lengths)
 
 
 # ---------------------------------------------------------------------
@@ -383,7 +390,10 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
     block): row r * heads + h is head h of the query at key position
     length - positions + r, masked to the keys up to its own and, in
     a window layer, to its own newest ``window``; every live page is
-    still read once."""
+    still read once. A slot of length 0 (one without a request: the
+    serving step's ``live`` mask, Attention._decode_attend_paged)
+    writes its zero output block and does nothing else: no DMA is
+    started, so none is left to wait for."""
     b = pl.program_id(0)
     rows = q_ref.shape[0]
     table_width = table_ref.shape[1]
@@ -401,8 +411,9 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
     low = jnp.maximum(length - (positions - 1 + window), 0) \
         if window else 0
     first = low // page
-    last = (jnp.maximum(length, 1) - 1) // page
-    chunks = jnp.where(length > 0, (last - first) // chunk + 1, 0)
+    # (read under length > 0 alone)
+    last = (length - 1) // page
+    chunks = (last - first) // chunk + 1
 
     def copies(c, slot):
         out = []
@@ -435,63 +446,70 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
                 for copy in pair:
                     copy.wait()
 
-    @pl.when(chunks > 0)
-    def _first():
+    @pl.when(length == 0)
+    def _parked():
+        # a slot without a request (the step's mask hands it length 0):
+        # no page fetched, no tile through the MXU, nothing to wait for
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _attend():
         start(0, 0)
+        q = q_ref[...]                                   # [rows, D]
+        mask = _group_block_mask(rows, heads, kv_heads, depth,
+                                 positions)
+        q_bd = jnp.where(
+            mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
+                                  axis=1), 0.0).astype(q.dtype)
 
-    q = q_ref[...]                                       # [rows, D]
-    mask = _group_block_mask(rows, heads, kv_heads, depth, positions)
-    q_bd = jnp.where(
-        mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
-                              axis=1), 0.0).astype(q.dtype)
+        def body(c, carry):
+            o, m, l = carry
+            slot = jax.lax.rem(c, 2)
 
-    def body(c, carry):
-        o, m, l = carry
-        slot = jax.lax.rem(c, 2)
+            @pl.when(c + 1 < chunks)
+            def _next():
+                start(c + 1, 1 - slot)
 
-        @pl.when(c + 1 < chunks)
-        def _next():
-            start(c + 1, 1 - slot)
+            wait(c, slot)
+            scores = jax.lax.dot_general(
+                q_bd, k_buf[slot].astype(q.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            scores = kept_in(scores, softmax_dtype)  # [rows, span]
+            pos = (first + c * chunk) * page + \
+                jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            if positions == 1:
+                visible = (pos >= low) & (pos < length)
+            else:
+                # row r * heads + h: keys below its own position + 1
+                upper = length - (positions - 1) + _row_positions(
+                    scores.shape, heads, positions)
+                visible = pos < upper
+                if window:
+                    visible &= pos >= upper - window
+            scores = jnp.where(visible, scores, _NEG_INF)
+            m_new = jnp.maximum(
+                m, jnp.max(scores, axis=1, keepdims=True))
+            correction = jnp.exp(m - m_new)
+            p = jnp.exp(scores - m_new)
+            l = l * correction + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(q.dtype), v_buf[slot].astype(q.dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [rows, Hkv*D]
+            return (kept_in(o * correction + pv, softmax_dtype), m_new,
+                    kept_in(l, softmax_dtype))
 
-        wait(c, slot)
-        scores = jax.lax.dot_general(
-            q_bd, k_buf[slot].astype(q.dtype),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [rows, span]
-        scores = kept_in(scores, softmax_dtype)
-        pos = (first + c * chunk) * page + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        if positions == 1:
-            visible = (pos >= low) & (pos < length)
-        else:
-            # row r * heads + h: keys below its own position + 1
-            upper = length - (positions - 1) + _row_positions(
-                scores.shape, heads, positions)
-            visible = pos < upper
-            if window:
-                visible &= pos >= upper - window
-        scores = jnp.where(visible, scores, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        correction = jnp.exp(m - m_new)
-        p = jnp.exp(scores - m_new)
-        l = l * correction + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(q.dtype), v_buf[slot].astype(q.dtype),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [rows, Hkv*D]
-        return (kept_in(o * correction + pv, softmax_dtype), m_new,
-                kept_in(l, softmax_dtype))
-
-    o, _m, l = jax.lax.fori_loop(
-        0, chunks, body,
-        (jnp.zeros((rows, kv_heads * depth), jnp.float32),
-         jnp.full((rows, 1), _NEG_INF, jnp.float32),
-         jnp.zeros((rows, 1), jnp.float32)))
-    out = jnp.where(mask, o / jnp.where(l == 0.0, 1.0, l), 0.0)
-    # each row has one live block of D columns (its K/V head's): the
-    # sum over the blocks folds [rows, Hkv*D] into [rows, D]
-    o_ref[...] = sum(out[:, h * depth:(h + 1) * depth]
-                     for h in range(kv_heads)).astype(o_ref.dtype)
+        o, _m, l = jax.lax.fori_loop(
+            0, chunks, body,
+            (jnp.zeros((rows, kv_heads * depth), jnp.float32),
+             jnp.full((rows, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32)))
+        out = jnp.where(mask, o / jnp.where(l == 0.0, 1.0, l), 0.0)
+        # each row has one live block of D columns (its K/V head's):
+        # the sum over the blocks folds [rows, Hkv*D] into [rows, D]
+        o_ref[...] = sum(out[:, h * depth:(h + 1) * depth]
+                         for h in range(kv_heads)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -578,7 +596,7 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
     the newest logical page p <= the last with p % T == c: the ring
     rule, which for a table as wide as the context is p == c), and one
     masked softmax over the positions the window admits; query r of S
-    at key position length - S + r."""
+    at key position length - S + r. A slot of length 0 yields zeros."""
     batch, seq, heads, depth = q.shape
     page = k_pages.shape[1]
     entries = block_table.shape[1]
@@ -597,9 +615,9 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
         low = window_start(lengths, window)
         visible = (pos >= low[:, None]) & (pos < lengths[:, None]) & (
             pos >= 0)
-        return masked_attention(q, k_all, v_all,
-                                visible[:, None, None, :], q.dtype,
-                                softmax_dtype)
+        return _zero_where_empty(masked_attention(
+            q, k_all, v_all, visible[:, None, None, :], q.dtype,
+            softmax_dtype), lengths)
     # [B, S]: the keys query r sees are those below upper[:, r]
     upper = lengths[:, None] - (seq - 1) + jnp.arange(
         seq, dtype=jnp.int32)[None, :]
@@ -607,8 +625,9 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
     pos = pos[:, None, :]
     visible = (pos >= low[:, :, None]) & (pos < upper[:, :, None]) & (
         pos >= 0)
-    return masked_attention(q, k_all, v_all, visible[:, None], q.dtype,
-                            softmax_dtype)
+    return _zero_where_empty(masked_attention(
+        q, k_all, v_all, visible[:, None], q.dtype, softmax_dtype),
+        lengths)
 
 
 def resolve_kernel_or_xla(impl: Optional[str], what: str) -> str:
@@ -688,7 +707,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
     RING narrower than the context, entry p % T the page of logical
     page p. q of S > 1 positions a slot is a verify block (the last S
     keys are the queries' own). The grouped kernel and the windowed
-    gather alone keep their softmax in ``softmax_dtype`` (kept_in)."""
+    gather alone keep their softmax in ``softmax_dtype`` (kept_in).
+    On every road a slot of length 0 yields zeros, and on the kernels'
+    costs nothing: that is how a serving step hands over a slot
+    without a request (the ``live`` mask of
+    Attention._decode_attend_paged)."""
     grouped = k_pages.shape[2] != q.shape[2] * q.shape[3]
     road = paged_decode_road(
         impl, grouped=grouped, window=window,

@@ -32,7 +32,13 @@ pages and its slot back AFTER the call's admissions instead of
 between them (as a call that admits nobody has done since PR 30), and
 a request that its first token ends leaves at that token's landing,
 behind the call's decode dispatch. The numbers without pressure held
-as they were."""
+as they were.
+
+PR 44 did NOT move them: occupancy()'s kv_blocks_attended no longer
+counts a block for an idle slot (the decode kernel is handed length 0
+for it), and the digests fold that key with the idle term put back
+(_as_recorded), so that they stay PR 34's and go on saying what they
+are for: the pool hands out the same pages in the same order."""
 
 import hashlib
 import json
@@ -77,6 +83,14 @@ def _schedule(seed: int = 11) -> list[serving.Request]:
     return reqs
 
 
+def _as_recorded(occupancy: dict) -> dict:
+    """occupancy() as the digests were recorded: kv_blocks_attended
+    with one block for each idle slot, which it counted until PR 44."""
+    return dict(occupancy, kv_blocks_attended=(
+        occupancy["kv_blocks_attended"] + occupancy["slots_total"]
+        - occupancy["slots_active"]))
+
+
 def run_schedule(engine, press: bool = True) -> tuple[str, dict]:
     """Drive the schedule, folding the books into one digest after
     every step; returns it with the final counters (for a failure's
@@ -100,7 +114,7 @@ def run_schedule(engine, press: bool = True) -> tuple[str, dict]:
         engine.pages.check()
         digest.update(_table(engine).tobytes())
         digest.update(json.dumps(
-            [engine.prefix_stats(), engine.occupancy(),
+            [engine.prefix_stats(), _as_recorded(engine.occupancy()),
              engine.preemptions], sort_keys=True).encode())
         if not reqs and not engine.pending():
             break

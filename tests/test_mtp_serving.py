@@ -9,6 +9,7 @@ layer kind and the q/k norms, the books (tokens in flight as a count,
 page growth, preemption, max_new_tokens inside a landing), the
 counters and what take_decisions hands over."""
 
+import contextlib
 import dataclasses
 import http.client
 import json
@@ -184,6 +185,32 @@ def test_agreeing_and_disagreeing_slots_share_a_batch(params):
         assert set(per_request.values()) <= {1, 2}
         mixed += len(set(per_request.values())) == 2
     assert mixed > 0
+
+
+@pytest.mark.parametrize("impl,slots", [(None, 14), ("kernel", 6)],
+                         ids=["gather", "kernel"])
+def test_a_drafting_engine_serves_alike_beside_parked_slots(params,
+                                                            impl, slots):
+    """The verify-and-draft step hands its ``active`` mask to BOTH of
+    its forwards (the stack's two query positions and the module's):
+    two requests among parked slots, each handed to every pool's and
+    ring's two-position call at length 0 (zeros on the windowed gather
+    and, in interpret mode, on the kernel), land the tokens and the
+    accepted counts of an engine of just two slots."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    config = _config(paged_attention_impl=impl)
+    weights = _agreeing(params, 0.6)
+    requests = _requests(2, seed=4, new=(9, 16))
+    with (pltpu.force_tpu_interpret_mode() if impl
+          else contextlib.nullcontext()):
+        crowded, engine = _serve(config, weights, requests,
+                                 num_slots=slots)
+        alone, small = _serve(config, weights, requests, num_slots=2)
+    assert crowded == alone and len(crowded) == 2
+    for key in ("mtp_drafted", "mtp_accepted", "decode_steps"):
+        assert engine.step_stats()[key] == small.step_stats()[key]
+    assert 0 < engine.step_stats()["mtp_accepted"]
 
 
 @pytest.mark.parametrize("new_tokens", [1, 2, 3, 4, 5])

@@ -509,3 +509,203 @@ def test_the_report_names_each_kind_of_layer(monkeypatch):
     assert serve.paged_decode_impl(config) == "gqa_kernel"
     with pytest.raises(ValueError, match="unknown paged attention"):
         pa.paged_decode_road("pallas", grouped=True)
+
+
+# ---- a slot without a request: length 0 on every road ----
+
+def _mha_parked(rng, int8=False):
+    if int8:
+        q, kp, vp, table, _lengths, ks, vs, _kf, _vf = _int8_case(rng)
+        return (q, kp, vp, table), {"k_scales": ks, "v_scales": vs}
+    return _random_case(rng, jnp.float32), {}
+
+
+def _grouped_parked(rng, entries=12, positions=1):
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.float32, 14, 2, batch=4, entries=entries)
+    if positions > 1:
+        q = jnp.asarray(rng.randn(4, positions, 14, 64), jnp.float32)
+    return (q, k_pages, v_pages, table), {}
+
+
+def _served_parked(rng):
+    """Baichuan's served shape: four seated slots among four parked."""
+    return _served_mha_case(rng, [1, 1, 580, 1, 129, 1, 1, 64]), {}
+
+
+# case -> (the call's road, impl, window, how the case is made, the
+# lengths an UNMASKED call is handed, the step's live mask). Every
+# road of paged_decode_road's table, and the one-program-a-slot kernel
+# (interpret mode) at an MHA pool, a grouped pool, a ring (a table of
+# 4 entries under a window of 20) and a two-position verify block. A
+# parked slot's unmasked length is 1 (one token a step) or 2 (a verify
+# block): what the cursor parked at 0 plus the step's rows gives. In
+# every case one LIVE slot has just that length too (its first key
+# from an empty cache): the mask, not the length, says which is which.
+_PARKED_CASES = {
+    "xla-mha": ("xla", "xla", 0, _mha_parked,
+                [1, 1, 23, 1], [True, False, True, False]),
+    "xla-grouped": ("xla", "xla", 0, _grouped_parked,
+                    [1, 40, 1, 1], [False, True, True, False]),
+    "xla-int8": ("xla", "xla", 0,
+                 lambda rng: _mha_parked(rng, int8=True),
+                 [1, 1, 23, 48], [True, False, True, True]),
+    "int8-kernel": ("kernel", "kernel", 0,
+                    lambda rng: _mha_parked(rng, int8=True),
+                    [1, 1, 23, 48], [False, True, True, True]),
+    "xla_windowed-window": ("xla_windowed", "xla", 20, _grouped_parked,
+                            [1, 1, 93, 21], [False, True, True, True]),
+    "xla_windowed-ring": ("xla_windowed", "xla", 20,
+                          lambda rng: _grouped_parked(rng, entries=4),
+                          [1, 57, 1, 100], [True, True, False, True]),
+    "xla_windowed-verify": (
+        "xla_windowed", "xla", 0,
+        lambda rng: _grouped_parked(rng, positions=2),
+        [2, 2, 41, 2], [False, True, True, False]),
+    "gqa_kernel-mha": ("gqa_kernel", "kernel", 0, _mha_parked,
+                       [1, 1, 23, 1], [True, False, True, False]),
+    "gqa_kernel-mha-served": ("gqa_kernel", "kernel", 0, _served_parked,
+                              [1, 1, 580, 1, 129, 1, 1, 64],
+                              [False, True, True, False, True, False,
+                               False, True]),
+    "gqa_kernel-grouped": ("gqa_kernel", "kernel", 0, _grouped_parked,
+                           [1, 40, 1, 1], [False, True, True, False]),
+    "gqa_kernel-ring": ("gqa_kernel", "kernel", 20,
+                        lambda rng: _grouped_parked(rng, entries=4),
+                        [1, 57, 1, 100], [True, True, False, True]),
+    "gqa_kernel-verify": (
+        "gqa_kernel", "kernel", 0,
+        lambda rng: _grouped_parked(rng, positions=2),
+        [2, 2, 41, 2], [False, True, True, False]),
+    "gqa_kernel-verify-ring": (
+        "gqa_kernel", "kernel", 16,
+        lambda rng: _grouped_parked(rng, entries=4, positions=2),
+        [2, 2, 41, 2], [True, False, True, False]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PARKED_CASES))
+def test_a_parked_slot_yields_zeros_and_moves_no_live_row(
+        interpret_mode, case):
+    """The step's live mask reaches a paged decode call as length 0
+    (Attention._decode_attend_paged): the parked rows come back zero on
+    EVERY road, the kernels' and the gathers' alike, and the live rows
+    bit for bit what the call returns when the parked slots are handed
+    the length their parked cursor gives. A live slot whose cursor was
+    0 (length 1, or 2 in a verify block) still attends its own keys:
+    its newest query's output is not zero, and with one key it is that
+    key's value row."""
+    road, impl, window, make, lengths, live = _PARKED_CASES[case]
+    args, scales = make(np.random.RandomState(len(case)))
+    q, k_pages, v_pages, table = args
+    grouped = k_pages.shape[2] != q.shape[2] * q.shape[3]
+    assert pa.paged_decode_road(
+        impl, grouped=grouped, window=window, int8=bool(scales),
+        positions=q.shape[1]) == road
+    live = np.asarray(live)
+    lengths = np.asarray(lengths, np.int32)
+
+    def call(handed):
+        return np.asarray(pa.paged_decode_attention(
+            q, k_pages, v_pages, table, jnp.asarray(handed), impl=impl,
+            window=window, **scales), np.float32)
+
+    unmasked = call(lengths)
+    got = call(np.where(live, lengths, 0).astype(np.int32))
+    assert got.shape == q.shape
+    np.testing.assert_array_equal(got[live], unmasked[live])
+    assert not got[~live].any()
+    assert unmasked[~live].any()        # the parent's call did attend
+    # the live slots at a fresh cursor: their first key, attended
+    fresh = live & (lengths == q.shape[1])
+    assert fresh.any()
+    depth = q.shape[3]
+    kv_heads = k_pages.shape[2] // depth
+    for b in np.flatnonzero(fresh):
+        assert got[b, -1].any()
+        value = np.asarray(v_pages[int(table[b, 0]), 0], np.float32)
+        if scales:
+            value = value.reshape(kv_heads, depth) * np.asarray(
+                scales["v_scales"][int(table[b, 0]), 0])[:, None]
+        per_head = np.repeat(value.reshape(kv_heads, depth),
+                             q.shape[2] // kv_heads, axis=0)
+        # query position 0 of the slot sees key 0 alone
+        np.testing.assert_allclose(
+            got[b, 0], per_head, rtol=1e-2 if q.dtype == jnp.bfloat16
+            else 1e-5, atol=1e-2 if q.dtype == jnp.bfloat16 else 1e-5)
+
+
+# a model of each paged kind: an MHA pool, a grouped pool, a grouped
+# pool whose second layer is a window layer over a ring
+_LIVE_MODELS = {
+    "mha": dict(n_heads=2, d_head=32),
+    "grouped": dict(n_heads=4, n_kv_heads=2, d_head=16),
+    "ring": dict(n_heads=4, n_kv_heads=2, d_head=16,
+                 layer_windows=(0, 12)),
+    "int8": dict(n_heads=2, d_head=32, kv_cache_dtype="int8"),
+}
+
+
+@pytest.mark.parametrize("impl", ("xla", "kernel"))
+@pytest.mark.parametrize("kind", sorted(_LIVE_MODELS))
+def test_the_models_live_mask_parks_a_slot_at_any_cursor(
+        interpret_mode, kind, impl):
+    """model.apply(..., live=mask) from an EMPTY paged cache, every
+    cursor at 0: the live slots' logits are bit for bit the unmasked
+    call's (a live slot at cursor 0 attends its one key: parked is
+    what the mask says, never what the cursor reads), the parked
+    slot's are not (its attention came back zero), and the cache (rows
+    written, cursors advanced) is the same either way."""
+    from batch_shipyard_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=2, d_ff=128,
+        dtype=jnp.float32, decode=True, max_decode_len=32,
+        kv_page_size=8, kv_num_pages=16, paged_attention_impl=impl,
+        **_LIVE_MODELS[kind])
+    model = tfm.TransformerLM(cfg)
+    tokens = jnp.asarray([[5], [9], [17]], jnp.int32)
+    positions = jnp.zeros((3, 1), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens,
+                           positions=positions)
+    params = variables["params"]
+
+    def tables(node):
+        # (init ran one forward: the cursors back to an empty cache's)
+        if isinstance(node, dict) and "length" in node:
+            node = {**node, "length": jnp.zeros_like(node["length"])}
+        if isinstance(node, dict) and "block_table" in node:
+            return {**node, "block_table": jnp.asarray(
+                [[3, 7, 1, 2], [11, 5, 4, 6], [9, 8, 10, 12]],
+                jnp.int32)}
+        return node
+
+    cache = jax.tree_util.tree_map(
+        tables, variables["cache"],
+        is_leaf=lambda x: isinstance(x, dict) and "length" in x)
+    live = np.asarray([True, False, True])
+    outs = {}
+    for name, mask in (("unmasked", None), ("masked", jnp.asarray(live))):
+        stepped = cache
+        logits = []
+        for step in range(2):
+            out, mutated = model.apply(
+                {"params": params, "cache": stepped}, tokens + step,
+                positions=positions + step, live=mask,
+                mutable=["cache"])
+            stepped = mutated["cache"]
+            logits.append(np.asarray(out))
+        outs[name] = (np.stack(logits), stepped)
+    (plain, plain_cache), (masked, masked_cache) = (
+        outs["unmasked"], outs["masked"])
+    np.testing.assert_array_equal(masked[:, live], plain[:, live])
+    assert np.abs(masked[:, ~live] - plain[:, ~live]).max() > 1e-4
+    for (path, want), got in zip(
+            jax.tree_util.tree_leaves_with_path(plain_cache),
+            jax.tree_util.tree_leaves(masked_cache)):
+        key = path[-1].key
+        if key in ("length", "block_table"):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+            if key == "length":
+                assert list(np.asarray(got)) == [2, 2, 2]

@@ -2,7 +2,10 @@
 step program (models/serving._decode_step, _speculative_step) leaves
 an idle slot's per-layer cache cursor at 0, so the decode kernels,
 which skip key blocks by the cursor, stop attending over the scratch
-page (or a dense row nobody reads) at a freed slot's old length. A
+page (or a dense row nobody reads) at a freed slot's old length, and
+hands its ``active`` mask to the model, which hands a paged pool's
+attention call length 0 for every slot the mask leaves out: zeros on
+every road, no page fetched and no tile computed on the kernels'. A
 live slot's cursor, keys and tokens are what they were."""
 
 import dataclasses
@@ -237,39 +240,110 @@ def test_a_long_request_is_served_alike_beside_forty_freed_ones(
         assert done[req.request_id] == _serve_alone(kind, params, req)
 
 
+# kind -> (config fields, engine keywords, the decode road): every
+# paged kind of engine, on the CPU's gathers and, where a kernel
+# serves the pool's pages, on the kernel in interpret mode (fewer
+# slots there: the interpreter runs a program a slot a layer a step)
+_BESIDE = {
+    "paged": ({}, {}, None),
+    "paged-int8": ({"kv_cache_dtype": "int8"}, {}, None),
+    "grouped": ({"n_kv_heads": 1}, {}, None),
+    "window": ({"n_kv_heads": 1, "layer_windows": (0, 12)}, {}, None),
+    "speculative-grouped": ({"n_kv_heads": 1}, {"speculative": True},
+                            None),
+    "paged-kernel": ({}, {}, "kernel"),
+    "paged-int8-kernel": ({"kv_cache_dtype": "int8"}, {}, "kernel"),
+    "window-kernel": ({"n_kv_heads": 1, "layer_windows": (0, 12)}, {},
+                      "kernel"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_BESIDE))
+def test_two_requests_are_served_alike_beside_forty_parked_slots(
+        kind):
+    """Greedy tokens of two requests in an engine of 42 slots, forty of
+    which never hold a request and are handed to every layer's paged
+    decode call at length 0 (zeros, on every road), are those of an
+    engine of just two slots, where no slot is ever parked while both
+    decode. One of the two is seated again after the other has ended,
+    in a slot that was parked meanwhile."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    fields, kwargs, impl = _BESIDE[kind]
+    cfg = dataclasses.replace(CFG, paged_attention_impl=impl, **fields)
+    weights = tfm.TransformerLM(cfg).init(
+        jax.random.PRNGKey(11), jnp.zeros((1, 8), jnp.int32))["params"]
+    kwargs = dict(kwargs, kv_page_size=PAGE)
+    if kwargs.pop("speculative", False):
+        kwargs["speculative"] = serving.SpeculativeConfig(
+            dataclasses.replace(cfg, paged_attention_impl=None),
+            weights, gamma=2)
+    rng = np.random.RandomState(13)
+    first = _requests(rng, 2, 9, [5, 14], name="a")
+    second = _requests(rng, 1, 6, [6], name="b")
+
+    def serve(num_slots):
+        engine = serving.ContinuousBatcher(
+            cfg, weights, num_slots=num_slots, max_decode_len=96,
+            **kwargs)
+        done = {}
+        for batch in (first, second):
+            for req in batch:
+                engine.submit(serving.Request(
+                    req.request_id, req.prompt, req.max_new_tokens))
+            while engine.pending():
+                done.update(engine.step())
+        return done
+
+    slots = 42 if impl is None else 10
+    if impl is None:
+        crowded, alone = serve(slots), serve(2)
+    else:
+        with pltpu.force_tpu_interpret_mode():
+            crowded, alone = serve(slots), serve(2)
+    assert sorted(crowded) == ["a0", "a1", "b0"]
+    assert crowded == alone
+    assert [len(crowded[r]) for r in ("a0", "a1", "b0")] == [5, 14, 6]
+
+
 @pytest.mark.parametrize("kind", ["paged", "paged-int8"])
 def test_kv_blocks_attended_is_the_kernels_count(kind, params):
     """occupancy()'s kv_blocks_attended, from the host's books, against
-    the device's own cursors as the decode kernel will see them (the
-    pending row written: cursor + 1; the step in flight has advanced
-    them): equal at every step but the one after a slot's last, when
-    that slot (its last token in flight, the request still seated)
-    attends over its old length once more and the host already counts
-    it as idle."""
+    what the decode kernel is handed, read as each step program is
+    called: the device's own cursors (the row the step writes counted:
+    cursor + 1) under the ``active`` mask the program receives, a slot
+    it leaves out counting nothing whatever its cursor reads (one
+    without a request; one whose last token is in flight: its request
+    still seated, its cursor still at its old length, the mask already
+    false). Equal at EVERY step: sum(ceil(tokens / page)) over the
+    seated slots."""
     rng = np.random.RandomState(9)
     engine = _engine(kind, params, num_slots=4)
+    inner = engine._decode_step
+    seen = []
+
+    def program(params_, cache, tokens, positions, active, key):
+        seen.append((np.asarray(active),
+                     np.asarray(cache["layer_0"]["attn"]["length"]),
+                     engine.occupancy()))
+        return inner(params_, cache, tokens, positions, active, key)
+
+    engine._decode_step = program
     for req in _requests(rng, 6, 11, [4, 19, 33, 7]):
         engine.submit(req)
-    exact = lagging = 0
     while engine.pending():
-        freed = [i for i, slot in enumerate(engine._slots)
-                 if slot.request is not None and not slot.decoding()]
-        length = next(leaf for path, leaf in _cursors(engine)
-                      if "layer_0" in path)
-        kernel = -(-(length + 1) // PAGE)
-        state = engine.occupancy()
-        assert state["kv_blocks_attended"] == (
-            int(kernel.sum()) - sum(int(kernel[i]) - 1 for i in freed))
-        if freed:
-            lagging += 1
-        else:
-            exact += 1
-            assert state["kv_blocks_attended"] >= -(
-                -state["live_tokens"] // PAGE) + (
-                    state["slots_total"] - state["slots_active"])
         engine.step()
-    assert exact >= 20 and lagging >= 3
-    assert engine.occupancy()["kv_blocks_attended"] == 4
+    idle = freed_unparked = 0
+    for active, length, state in seen:
+        kernel = np.where(active, -(-(length + 1) // PAGE), 0)
+        assert state["kv_blocks_attended"] == int(kernel.sum())
+        assert state["slots_active"] == int(active.sum())
+        assert state["kv_blocks_attended"] >= -(
+            -state["live_tokens"] // PAGE)
+        idle += int((~active).sum())
+        freed_unparked += int((length[~active] > 0).sum())
+    assert len(seen) >= 20 and idle >= 20 and freed_unparked >= 3
+    assert engine.occupancy()["kv_blocks_attended"] == 0
     assert "kv_blocks_attended" not in _engine(
         "dense", params).occupancy()
 
@@ -291,11 +365,12 @@ def test_inactive_slot_probe_tiny_path():
         0, 128, 256, 0]
     assert all(row["idle_cursor_max_one_step_later"] == 0
                for row in cases)
-    # three idle slots of four, pages of 16: the first step attends
-    # over ceil((set + 1) / 16) blocks for each, every later one over 1
+    # three idle slots of four: whatever their cursors were set to,
+    # the step's mask hands them to the kernel at length 0, so the
+    # first step attends over the seated slot's blocks alone, as every
+    # later one does
     for row in cases:
-        extra = 3 * (-(-(row["idle_cursors_set_to"] + 1) // 16) - 1)
         assert (row["first_step_kv_blocks"]
-                == row["kv_blocks_attended"] + extra), row
+                == row["kv_blocks_attended"] > 0), row
     assert verdict["idle_cursors_parked"] is True
     assert (verdict["live_slots"], verdict["idle_slots"]) == (1, 3)

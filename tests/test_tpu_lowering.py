@@ -564,7 +564,9 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
             dense, None, page, params, cache, 0, arg((1, bucket)), row,
             400)
     compiled = lowered.compile()
-    text = compiled.as_text()
+    # (without the Mosaic kernels' serialized bodies: three letters
+    # turn up in a few hundred KB of base64 by chance, and did)
+    text = re.sub(r"[A-Za-z0-9+/=]{200,}", "", compiled.as_text())
     assert ("gmm" in text) == (program == "prefill_grouped")
     assert ("gqa_paged_decode" in text) == (program == "decode")
     moved = re.findall(
@@ -613,18 +615,30 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
 # one-program-a-slot kernel (sixteen unnamed calls of it, 2 pages a
 # chunk) where the (slot, table entry) grid kernel ran. The six other
 # lines stand: the grouped pools keep 8 pages a chunk and their name.
+# PR 44 re-recorded all four DECODE lines (Baichuan's c6e4739630cf2ed5,
+# Nemotron's 61b575fbb23a47df, Solar-Open2's 6624ed0450d5dfa9 and
+# SmallThinker's bb97a066f9e4c91b until then): the step's ``active``
+# mask reaches every paged decode call as
+# select(active, length, 0), one broadcast and one select on a
+# [slots] vector before each kernel call (Baichuan's text grows by 32
+# lines, two a layer), so that a slot without a request is handed to
+# the kernel at length 0. With that select taken out the four programs
+# hash to the lines they had: the kernel's own change (its body under
+# pl.when(length > 0)) lies in the serialized Mosaic body, which the
+# hash leaves out. The three PREFILL lines stand: a prefill runs a
+# batch-1 dense cache and no paged call.
 ACCEPTED_PROGRAMS = {
-    "baichuan-7b-serve-1chip/decode": "c6e4739630cf2ed5",
+    "baichuan-7b-serve-1chip/decode": "33c838760abd7f20",
     "baichuan-7b-serve-1chip/prefill": "d778ca3089991697",
-    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "61b575fbb23a47df",
+    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "e732c9766bb480d9",
     "nemotron-3-nano-30b-a3b-serve-1chip/prefill": "9494681929c5e555",
-    "solar-open2-250b-serve-1chip/decode": "6624ed0450d5dfa9",
+    "solar-open2-250b-serve-1chip/decode": "dcf86b2c17ec1406",
     "solar-open2-250b-serve-1chip/prefill": "414a9d1036f22da3",
     # new in PR 41 (4cb7c1eba72ff012 at its parent: the same program
     # but for that numbering, nine lines, with eight sites of the
     # grouped kernel; compiled for the v5e the two were instruction
     # for instruction the same, PERF.md section 6)
-    "smallthinker-21b-a3b-serve-1chip/decode": "bb97a066f9e4c91b",
+    "smallthinker-21b-a3b-serve-1chip/decode": "5b204e50838703f9",
 }
 
 

@@ -1,20 +1,30 @@
 #!/usr/bin/env python3
 """Probe, not a benchmark: what do IDLE slots cost the decode step?
 
-A slot without a request stays in the full-batch decode step, and the
-paged kernel computes ceil(cursor / page) blocks of the scratch page
-for it. Until PR 28 a freed slot's cursor kept its last request's
-length and grew by one a step (on a v5e, 15 live slots and 33 idle: a
-step of 21.5 ms with the idle cursors near 0, 28.9 at 1,024, 35.9 at
-2,048; PERF.md section 6, PR 25). Now every step program parks an idle
-slot's cursor at 0, and this is the after-picture: the benchmark
-configuration's engine (the benchmark's own weights and engine shim),
-15 requests seated, and for each of 0, half of and all of
-max_decode_len the idle slots' cursors are SET to that, one step is
-run and timed (the one step that still attends over what was set), the
-idle cursors are read back (0), and the steps after it are timed. One
-JSON line a case; the last line says whether every case read 0 one
-step later and how far the cases' step times lie apart.
+A slot without a request stays in the full-batch decode step. Until
+PR 28 a freed slot's cursor kept its last request's length and grew
+by one a step, and the paged kernel computed ceil(cursor / page)
+blocks of the scratch page for it (on a v5e, 15 live slots and 33
+idle: a step of 21.5 ms with the idle cursors near 0, 28.9 at 1,024,
+35.9 at 2,048; PERF.md section 6, PR 25). Since PR 28 every step
+program parks an idle slot's cursor at 0, which left it one block of
+the scratch page a layer (length 1: two page DMAs and a whole chunk
+through the MXU, 3.0 us a slot a layer at Baichuan's 4,096 channels;
+PERF.md section 6, PR 44). Since PR 44 the step's ``active`` mask
+reaches the kernel as length 0 whatever the cursor reads: an idle
+slot's program fetches no page and computes no tile and costs 0.28 us
+(a grid step, the query block in, a zero block out; same section),
+and the kernel's work a layer is occupancy()'s kv_blocks_attended,
+the seated slots' pages alone.
+This is the after-picture: the benchmark configuration's engine (the
+benchmark's own weights and engine shim), 15 requests seated, and for
+each of 0, half of and all of max_decode_len the idle slots' cursors
+are SET to that, one step is run and timed (its kernel is handed
+length 0 for them all the same; its row scatter still writes at what
+was set), the idle cursors are read back (0), and the steps after it
+are timed. One JSON line a case; the last line says whether every
+case read 0 one step later and how far the cases' step times lie
+apart.
 
     chiprun --chips 1 -- python3 tools/inactive_slot_probe.py
     JAX_PLATFORMS=cpu python tools/inactive_slot_probe.py --tiny
@@ -86,11 +96,12 @@ def main(argv=None) -> int:
         set_idle(value)
         jax.block_until_ready(engine.cache)
         # What the first step's kernel computes in a layer, by the
-        # device's cursors (the pending row written); the host's
-        # count knows nothing of cursors set behind its back, and is
-        # what every step after the first computes.
+        # device's cursors (the pending row written) of the slots the
+        # step's mask leaves live: whatever was set behind the host's
+        # back, the idle slots are handed over at length 0, so this IS
+        # the host's count.
         first_blocks = int(
-            (-(-(cursors() + 1) // engine.page_size)).sum())
+            (-(-(cursors() + 1) // engine.page_size))[~idle].sum())
         blocks = engine.occupancy()["kv_blocks_attended"]
         first = step_ms()
         after = cursors()

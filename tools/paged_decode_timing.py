@@ -1,19 +1,30 @@
 #!/usr/bin/env python3
 """One call of the one-program-a-slot paged-decode kernel beside the XLA
 gather, timed on the chip at three configurations' attention shapes
-(PERF.md, PR 41 and PR 43), tables of 32 pages of 64 tokens: the two
-hybrids at 96 slots (Solar-Open2 64 query over 8 K/V heads of 128,
-Nemotron 32 over 2: 8 pages a chunk) and Baichuan's MHA pool at 48
-slots of which 16 are seated and 32 parked at length 1, as its
-batch-offline cell holds them (32 heads of 128: 4,096 channels, 2
-pages a chunk). Not part of the benchmark. For each shape: the kernel
+(PERF.md, PR 41, PR 43 and PR 44), tables of 32 pages of 64 tokens:
+the two hybrids at 96 slots (Solar-Open2 64 query over 8 K/V heads of
+128, Nemotron 32 over 2: 8 pages a chunk) and Baichuan's MHA pool at
+48 slots of which 16 are seated and 32 parked, as its batch-offline
+cell holds them (32 heads of 128: 4,096 channels, 2 pages a chunk).
+Not part of the benchmark. For each shape: the kernel
 and the gather (ms a call, the mean of ``--calls`` back to back) at
 contexts drawn like the batch-offline cell's (a log-normal prompt plus
 a uniform share of a log-normal output: about 520 tokens a seated
 slot), beside the live K/V's own read at the chip's 819 GB/s; the
-kernel alone at every slot one key (its fixed cost a slot), 512, 520
-(the head of one more chunk) and a full table (2,048); the largest
-difference of the two roads' results.
+kernel alone at every slot NO key (a skipped program's cost), one key
+(its fixed cost a seated slot), 512, 520 (the head of one more chunk)
+and a full table (2,048); the largest difference of the two roads'
+results. Where the cell parks slots (Baichuan's): the cell's call
+with the parked slots at length 1 (what a parked cursor gave the
+kernel until PR 44) and at length 0 (what the step's live mask gives
+it since) beside each other, and beside the seated slots' call alone
+(a batch of 16): the difference over the 32 parked slots is what a
+parked slot costs, in us, each way. (The back-to-back mean cannot
+read under what the host takes to dispatch a call, about 0.2 ms: every
+slot at NO key reads 0.21 ms and every slot at one key 0.20, both the
+host's pace and not the kernel's, so PR 43's "4.7 us a slot at one
+key" was that floor over 48. The cell's cases are longer than the
+floor, and their differences are the device's.)
 
     chiprun -- python3 tools/paged_decode_timing.py [--shape baichuan7b]
 
@@ -54,7 +65,8 @@ def cell_lengths(rng, slots: int, seated: int) -> np.ndarray:
     """Contexts of ``seated`` slots as traffic/batch-offline.json draws
     them: a prompt (log-normal, median 384, sigma 0.6, 64-1,024) and
     the part of an output (median 192, sigma 0.5, 64-512) decoded so
-    far; the other slots parked at length 1."""
+    far; the other slots at length 1 (a parked cursor's garbage row:
+    the call as it was until the step's mask reached the kernel)."""
     prompt = np.clip(np.exp(rng.normal(np.log(384), 0.6, slots)),
                      64, 1024)
     output = np.clip(np.exp(rng.normal(np.log(192), 0.5, slots)),
@@ -84,8 +96,11 @@ def main() -> int:
         # any page for any entry: a pool as fragmented as it gets
         table = jnp.asarray(rng.randint(0, pool - 1, (slots, ENTRIES)),
                             jnp.int32)
-        cases = {"cell": drawn, **{str(n): np.full(slots, n)
-                                   for n in (1, 512, 520, 2048)}}
+        masked = np.where(np.arange(slots) < seated, drawn, 0)
+        cases = {"cell": drawn,
+                 **({"cell-masked": masked} if seated < slots else {}),
+                 **{str(n): np.full(slots, n)
+                    for n in (0, 1, 512, 520, 2048)}}
         keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
         width = kv_heads * DEPTH
         q = jax.random.normal(keys[0], (slots, 1, heads, DEPTH),
@@ -105,16 +120,17 @@ def main() -> int:
         assert pa.paged_decode_road(
             None, grouped=kv_heads != heads) == "gqa_kernel"
         kernel = jax.jit(pa.paged_decode_attention)
+        cell_ms = {}
         for case, lengths in cases.items():
             lengths = jnp.asarray(lengths, jnp.int32)
             operands = (q, k_pages, v_pages, table, lengths)
             pages = int(np.sum(-(-np.asarray(lengths) // PAGE)))
             read_ms = (2 * pages * PAGE * width * 2
                        / HBM_BYTES_PER_S * 1e3)
-            line = (f"  lengths {case}: kernel "
-                    f"{timed(kernel, operands, args.calls):.3f} ms "
+            cell_ms[case] = timed(kernel, operands, args.calls)
+            line = (f"  lengths {case}: kernel {cell_ms[case]:.3f} ms "
                     f"(live pages' read {read_ms:.3f} ms)")
-            if case == "cell":
+            if case.startswith("cell"):
                 diff = jnp.max(jnp.abs(
                     kernel(*operands).astype(jnp.float32)
                     - gather(*operands).astype(jnp.float32)))
@@ -122,6 +138,17 @@ def main() -> int:
                          f"{timed(gather, operands, args.calls):.3f} "
                          f"ms, max |kernel - gather| {float(diff):.4f}")
             print(line, flush=True)
+        if seated < slots:
+            alone = timed(kernel, (
+                q[:seated], k_pages, v_pages, table[:seated],
+                jnp.asarray(drawn[:seated], jnp.int32)), args.calls)
+            parked = slots - seated
+            print(f"  the {seated} seated slots alone: kernel "
+                  f"{alone:.3f} ms; a parked slot costs "
+                  f"{(cell_ms['cell'] - alone) / parked * 1e3:.2f} us "
+                  f"at length 1 and "
+                  f"{(cell_ms['cell-masked'] - alone) / parked * 1e3:.2f}"
+                  f" us masked to 0", flush=True)
     return 0
 
 

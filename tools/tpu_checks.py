@@ -180,15 +180,17 @@ def _paged_case(rng, heads, page, batch=8, depth=64, max_blocks=8):
 
 
 def _served_mha_case(rng, slots=48, heads=32, depth=128, page=64,
-                     entries=32, pool=193):
+                     entries=32, pool=193, parked=1):
     """Baichuan's decode attention as its cell serves it: 48 slots,
     32 heads of 128, 193 pages of 64 behind tables of 32 entries,
     bfloat16; a third of the slots seated at contexts of 1 to 2,048
     mixed (a chunk's edge at 128 keys from both sides, the cell's mean,
-    a full table), the rest parked at length 1 on the scratch page."""
+    a full table), the rest parked on the scratch page at length
+    ``parked``: 1 as a parked cursor left them until PR 44, 0 as the
+    step's live mask hands them over since."""
     seated = [2048, 1337, 580, 129, 128, 127, 65, 64, 63, 2, 1, 911,
               400, 257, 256, 33]
-    lengths = np.ones(slots, np.int32)
+    lengths = np.full(slots, parked, np.int32)
     lengths[::3] = seated
     q = jnp.asarray(rng.randn(slots, 1, heads, depth), jnp.bfloat16)
     k_p = jnp.asarray(rng.randn(pool, page, heads * depth),
@@ -198,7 +200,7 @@ def _served_mha_case(rng, slots=48, heads=32, depth=128, page=64,
     table = np.zeros((slots, entries), np.int32)
     ids = iter(rng.permutation(pool - 1) + 1)
     for b, length in enumerate(lengths):
-        if length > 1:
+        if b % 3 == 0:
             pages = -(-int(length) // page)
             table[b, :pages] = [next(ids) for _ in range(pages)]
     return q, k_p, v_p, jnp.asarray(table), jnp.asarray(lengths)
@@ -210,7 +212,9 @@ def check_paged_attention() -> bool:
     is the grouped kernel's case of as many K/V heads as query heads)
     vs the XLA gather oracle with random block tables and ragged
     lengths — the serving engine's headline kernel — and at
-    Baichuan's served shape, 4,096 channels wide, 2 pages a chunk."""
+    Baichuan's served shape, 4,096 channels wide, 2 pages a chunk,
+    its 32 parked slots at length 1 and masked to length 0 (zeros in
+    their rows on both roads)."""
     from batch_shipyard_tpu.ops import paged_attention as paged
 
     kernel = jax.jit(functools.partial(paged.paged_decode_attention,
@@ -223,15 +227,20 @@ def check_paged_attention() -> bool:
         q, k_p, v_p = (x.astype(dtype)
                        for x in (q, _folded(k_f), _folded(v_f)))
         cases.append((label, dtype, tol, (q, k_p, v_p, table, lengths)))
-    cases.append(("bf16 h32x128 page64 table32 slots48 (baichuan)",
-                  jnp.bfloat16, 2e-2,
-                  _served_mha_case(np.random.RandomState(12))))
+    for parked in (1, 0):
+        cases.append((
+            f"bf16 h32x128 page64 table32 slots48, 32 parked at "
+            f"{parked} (baichuan)", jnp.bfloat16, 2e-2,
+            _served_mha_case(np.random.RandomState(12), parked=parked)))
     for label, dtype, tol, operands in cases:
         with _exact_if_fp32(dtype):
             out_k = kernel(*operands)
             out_x = paged.paged_decode_attention_xla(*operands)
             rel = _rel(out_k, out_x)
-            ok = rel < tol
+            empty = np.asarray(operands[-1]) == 0
+            ok = rel < tol and not any(
+                np.asarray(out, np.float32)[empty].any()
+                for out in (out_k, out_x))
             print(f"paged-attention kernel vs xla [{label}]: "
                   f"rel={rel:.2e} {'OK' if ok else 'FAIL'}")
             all_ok = all_ok and ok
