@@ -38,7 +38,6 @@ _VAR_RE = re.compile(r"SHIPYARD_[A-Z0-9_]+")
 OPERATOR_ENV_VARS = frozenset({
     "SHIPYARD_CONFIGDIR",           # cli/main.py --configdir envvar
     "SHIPYARD_SECRETS_FILE",        # agent bootstrap secret source
-    "SHIPYARD_XLA_TUNING",          # XLA flag profile (parallel/tuning)
     "SHIPYARD_FORCE_TPU_PASSTHROUGH",  # docker device passthrough
 })
 

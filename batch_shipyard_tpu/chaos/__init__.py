@@ -11,8 +11,8 @@ fakepod pool through the schedule and asserts the self-healing
 invariants: every task completes, no orphaned gang rows or queue
 messages, and the goodput partition stays exact.
 
-Surfaces: `shipyard chaos plan|drill` (cli), tools/chaos_drill.py
-(standalone runner), and a silicon-proof dry-run phase.
+Surfaces: `shipyard chaos plan|drill` (cli) and tools/chaos_drill.py
+(standalone runner).
 """
 
 from batch_shipyard_tpu.chaos.plan import (  # noqa: F401

@@ -29,7 +29,7 @@ was non-vacuous (recoveries >= 1, resumed_tokens strictly inside
 (0, max_new_tokens)).
 
 Used by `shipyard chaos drill --serve-kill|--serve-drain|
---serve-router` and the serving_resilience bench phase.
+--serve-router` and tests/test_serving_resilience.py.
 """
 
 from __future__ import annotations

@@ -22,9 +22,8 @@ restart count. This package removes it three ways:
     other N-1 and every restart hit warm (the image-prefetch pattern,
     agent/cascade.py).
 
-Surfacing: ``shipyard pool cache stats|seed|prune`` (cli/main.py),
-the ``compile_warm`` bench phase (bench.py), and
-``goodput_compile_saved_seconds`` gauges (monitor/heimdall.py). See
+Surfacing: ``shipyard pool cache stats|seed|prune`` (cli/main.py)
+and ``goodput_compile_saved_seconds`` gauges (monitor/heimdall.py). See
 docs/29-compile-cache.md.
 """
 

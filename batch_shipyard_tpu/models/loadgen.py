@@ -196,9 +196,9 @@ def run_load(base_url: Union[str, Sequence[str]],
         "ttft_ms": merged["ttft_ms"].percentiles((50, 90, 99)),
         "tpot_ms": merged["tpot_ms"].percentiles((50, 90, 99)),
         # Exact mean/percentiles from the raw observations (the
-        # log-bucket histograms quantize to bucket edges; A/B deltas
-        # like BENCH_serving_slo need unbinned values so a real
-        # improvement can't vanish into a shared bucket).
+        # log-bucket histograms quantize to bucket edges; an A/B
+        # delta, prefix cache on against off, needs unbinned values so
+        # a real improvement can't vanish into a shared bucket).
         "ttft_mean_ms": (sum(r["ttft_ms"] for r in done) / len(done)
                          if done else 0.0),
         "tpot_mean_ms": (sum(r["tpot_ms"] for r in done) / len(done)

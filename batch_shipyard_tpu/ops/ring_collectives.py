@@ -30,8 +30,7 @@ Three kernel families:
     (see ring_attention.py), so these are what tier-1 exercises — and
     what tools/tpu_checks.py compiles on a single real chip to prove
     the Mosaic DMA/semaphore lowering before the multi-chip path is
-    allowed on 'auto' (KERNEL_VALIDATION.json, check name
-    ``ring_collectives``).
+    allowed on 'auto' (its check ``ring_collectives``).
 
 Shared schedule arithmetic lives in ``ag_source_shard`` /
 ``rs_chunk_index`` so the real and virtual kernels cannot drift.

@@ -1,4 +1,4 @@
-"""Model-FLOPs-utilization accounting for the bench pipeline.
+"""Model-FLOPs-utilization accounting for the training workloads.
 
 The reference publishes raw throughput only (BASELINE.md: images/sec on
 16xV100); a TPU framework must also answer "what fraction of the MXU's

@@ -119,10 +119,10 @@ def diurnal(seed: int, nodes: int, tasks: int) -> dict:
 
 
 def scheduler_scale(seed: int, nodes: int, tasks: int) -> dict:
-    """BENCH_scheduler_scale-shaped: one streamed bulk submission of
-    tiny identity-less tasks (10^6 by default at bench scale) — the
-    queueing/claim-throughput regime, no compile or checkpoint legs.
-    Deterministic regardless of seed."""
+    """Shaped like chaos/drill.py's scheduler scale drill: one
+    streamed bulk submission of tiny identity-less tasks (10^6 at
+    the drill's default) — the queueing/claim-throughput regime, no
+    compile or checkpoint legs. Deterministic regardless of seed."""
     del seed
     return {
         "trace": traces.scheduler_scale_trace(

@@ -12,7 +12,7 @@ Shapes:
 * ``diurnal_trace``          — sinusoidal day/night rate profile (the
                                autoscale provisioning-vs-queueing
                                trade only exists under load swings).
-* ``scheduler_scale_trace``  — BENCH_scheduler_scale-shaped: one
+* ``scheduler_scale_trace``  — the scheduler scale drill's shape: one
                                bulk submission of up to 10^6 tiny
                                tasks at t=0 (the PR-14 streaming
                                submission shape).
@@ -139,10 +139,11 @@ def scheduler_scale_trace(num_tasks: int = 1_000_000,
                           task_seconds: float = 1.0,
                           submit_rate: float = 50_000.0,
                           ) -> list[SimTask]:
-    """BENCH_scheduler_scale-shaped: up to 10^6 tiny identity-less
-    tasks streamed in one bulk submission (arrivals paced at the
-    measured streaming-submission rate). Deterministic without a
-    seed — the shape has no randomness to begin with."""
+    """The scheduler scale drill's shape (chaos/drill.py): up to
+    10^6 tiny identity-less tasks streamed in one bulk submission
+    (arrivals paced at the measured streaming-submission rate).
+    Deterministic without a seed — the shape has no randomness to
+    begin with."""
     return [SimTask(task_id=f"t{i:07d}",
                     arrival=i / submit_rate,
                     steps=1, step_seconds=task_seconds,

@@ -212,7 +212,7 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--no-prefix-cache", action="store_true",
                         help="Disable cross-request prefix/KV-cache "
                         "reuse in the paged pool (the control arm of "
-                        "BENCH_serving_slo)")
+                        "tests/test_prefix_cache.py)")
     parser.add_argument("--slo-config", default=None,
                         help="SLO scheduling config: 'default' for "
                         "the built-in class table, or a JSON config "

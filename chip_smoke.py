@@ -42,7 +42,7 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parent
 if str(REPO_ROOT) not in sys.path:
     sys.path.insert(0, str(REPO_ROOT))
 
-# The width bench.py and train_transformer.py default to.
+# The width train_transformer.py defaults to.
 FULL = {"d_model": 1024, "n_layers": 12, "n_heads": 16, "d_ff": 2816,
         "vocab": 32000, "max_decode_len": 512, "page": 64,
         "seq_len": 2048, "batch": 8}

@@ -367,7 +367,7 @@ def test_env_read_unexported_fires_and_knobs_pass():
     assert not _rules_of(exported, "env-read-unexported")
     knob = {"batch_shipyard_tpu/mod.py": (
         "import os\n"
-        "V = os.environ.get('SHIPYARD_XLA_TUNING')\n")}
+        "V = os.environ.get('SHIPYARD_SECRETS_FILE')\n")}
     assert not _rules_of(knob, "env-read-unexported")
 
 

@@ -10,7 +10,7 @@ Three contracts pinned here:
    seconds of wall time) shows warm-cache claim affinity beating the
    baseline bundle on the steady scenario, priced by the production
    goodput engine with an exact partition; a slow-marked sweep runs
-   the >=2,000-node shape the bench artifact commits.
+   the >=2,000-node shape.
 3. **No forked copies** — the sim prices the SAME pure functions
    (sched/policy.py) the live agent claim path, preemption sweep,
    and pool autoscaler import; the decision code is defined exactly

@@ -5,12 +5,17 @@ here are registered analyzer rules (batch_shipyard_tpu/analysis/,
 PR 11); each historical test keeps its name and coverage but runs
 the corresponding rule over the real tree, so tier-1 sees the same
 gates while the CLI (`shipyard lint`) and tests/test_analysis.py
-share one implementation. Checks with no analyzer analog (committed
-bench artifacts, tools/ cross-file wiring) stay native below.
+share one implementation. Checks with no analyzer analog (drill
+and docs wiring, what PR 45 deleted staying deleted) stay native below.
 """
 
 import ast
+import fnmatch
+import os
 import pathlib
+import re
+
+import pytest
 
 from batch_shipyard_tpu import analysis
 from batch_shipyard_tpu.state import names
@@ -205,33 +210,6 @@ def test_fleet_elasticity_chaos_kinds_wired():
         assert kind in rendered
 
 
-def test_fleet_elasticity_dispatched_and_rendered():
-    """The fleet-elasticity drills are wired end to end: bench.py
-    dispatches the fleet_elasticity workload, benchgen renders the
-    committed BENCH_fleet_elasticity.json artifact, and the artifact
-    records all three drills passing."""
-    import json
-    bench_src = (PACKAGE.parent / "bench.py").read_text(
-        encoding="utf-8")
-    assert '"fleet_elasticity" in workloads' in bench_src
-    benchgen_src = (PACKAGE.parent / "tools" / "benchgen.py"
-                    ).read_text(encoding="utf-8")
-    assert "BENCH_fleet_elasticity.json" in benchgen_src
-    artifact = PACKAGE.parent / "BENCH_fleet_elasticity.json"
-    assert artifact.exists(), (
-        "BENCH_fleet_elasticity.json not committed — run "
-        "`python bench.py --workloads fleet_elasticity`")
-    data = json.loads(artifact.read_text(
-        encoding="utf-8"))["fleet_elasticity"]
-    assert data["all_passed"] is True
-    assert set(data["drills"]) == {"eviction", "host_resize",
-                                   "migration"}
-    for entry in data["drills"].values():
-        assert entry["passed"] is True
-        assert entry["invariants_checked"]
-    assert data.get("cpu_marker") is True
-
-
 def test_control_plane_vocabulary_declared():
     """ISSUE 13's vocabulary: the STORE_OUTAGE / TASK_ADOPTION kinds
     are declared+registered (rule), priced as their own badput
@@ -309,34 +287,6 @@ def test_control_plane_chaos_kinds_wired():
         assert flag in rendered, f"drill flag {flag} not wired"
 
 
-def test_control_plane_dispatched_and_rendered():
-    """The control-plane drills are wired end to end: bench.py
-    dispatches the control_plane workload, benchgen renders the
-    committed BENCH_control_plane.json artifact, and the artifact
-    records all three drills passing."""
-    import json
-    bench_src = (PACKAGE.parent / "bench.py").read_text(
-        encoding="utf-8")
-    assert '"control_plane" in workloads' in bench_src
-    benchgen_src = (PACKAGE.parent / "tools" / "benchgen.py"
-                    ).read_text(encoding="utf-8")
-    assert "BENCH_control_plane.json" in benchgen_src
-    artifact = PACKAGE.parent / "BENCH_control_plane.json"
-    assert artifact.exists(), (
-        "BENCH_control_plane.json not committed — run "
-        "`python bench.py --workloads control_plane`")
-    data = json.loads(artifact.read_text(
-        encoding="utf-8"))["control_plane"]
-    assert data["all_passed"] is True
-    assert set(data["drills"]) == {"store_outage",
-                                   "leader_partition",
-                                   "agent_restart"}
-    for entry in data["drills"].values():
-        assert entry["passed"] is True
-        assert entry["invariants_checked"]
-    assert data.get("cpu_marker") is True
-
-
 def test_chaos_kinds_all_expressible_in_the_simulator():
     """ISSUE 17: every chaos injection kind maps to a simulator
     adapter (sim/scenarios.py KIND_ADAPTERS) or is explicitly listed
@@ -395,80 +345,6 @@ def test_policy_knobs_mirrored_in_settings_and_schema():
     assert defaults == sched_policy.PolicyKnobs()
 
 
-def test_fleet_sim_dispatched_and_rendered():
-    """The fleet-simulator policy proof is wired end to end: bench.py
-    dispatches the fleet_sim workload, benchgen renders the committed
-    BENCH_fleet_sim.json artifact, and the artifact records >=2,000
-    virtual nodes, >=10^5 tasks, every policy bundle on >=3 scenarios
-    (including the preemption-wave chaos scenario) with exact
-    partitions throughout and per-policy deltas vs baseline."""
-    import json
-
-    from batch_shipyard_tpu.sched import policy as sched_policy
-    bench_src = (PACKAGE.parent / "bench.py").read_text(
-        encoding="utf-8")
-    assert '"fleet_sim" in workloads' in bench_src
-    benchgen_src = (PACKAGE.parent / "tools" / "benchgen.py"
-                    ).read_text(encoding="utf-8")
-    assert "BENCH_fleet_sim.json" in benchgen_src
-    artifact = PACKAGE.parent / "BENCH_fleet_sim.json"
-    assert artifact.exists(), (
-        "BENCH_fleet_sim.json not committed — run "
-        "`python bench.py --workloads fleet_sim`")
-    data = json.loads(artifact.read_text(
-        encoding="utf-8"))["fleet_sim"]
-    assert data["nodes"] >= 2000
-    assert data["tasks"] >= 100_000
-    assert data["all_partitions_exact"] is True
-    assert data.get("cpu_marker") is True
-    assert set(data["policies"]) == set(sched_policy.POLICIES)
-    assert len(data["scenarios"]) >= 3
-    assert "preemption_wave" in data["scenarios"]
-    for scenario, section in data["scenarios"].items():
-        assert set(section) == set(sched_policy.POLICIES), scenario
-        for policy, row in section.items():
-            assert row["partition_exact"] is True, (scenario, policy)
-            assert row["fingerprint"]
-            if policy != "baseline":
-                assert "goodput_ratio_delta" in \
-                    row["delta_vs_baseline"], (scenario, policy)
-
-
-def test_serving_slo_dispatched_and_rendered():
-    """The prefix-cache/SLO proof is wired end to end: bench.py
-    dispatches the serving_slo workload, benchgen renders the
-    committed BENCH_serving_slo.json, and the artifact clears the
-    acceptance gates — prefix hit rate > 0.5, prefix-cache-on mean
-    AND p99 TTFT strictly below the cache-off control at the same
-    seed, and byte-identical greedy outputs between the two arms."""
-    import json
-
-    bench_src = (PACKAGE.parent / "bench.py").read_text(
-        encoding="utf-8")
-    assert '"serving_slo" in workloads' in bench_src
-    benchgen_src = (PACKAGE.parent / "tools" / "benchgen.py"
-                    ).read_text(encoding="utf-8")
-    assert "BENCH_serving_slo.json" in benchgen_src
-    artifact = PACKAGE.parent / "BENCH_serving_slo.json"
-    assert artifact.exists(), (
-        "BENCH_serving_slo.json not committed — run "
-        "`python bench.py --workloads serving_slo`")
-    data = json.loads(artifact.read_text(
-        encoding="utf-8"))["serving_slo"]
-    assert data.get("cpu_marker") is True
-    assert data["prefix_hit_rate"] > 0.5
-    assert data["outputs_identical"] is True
-    on, off = data["prefix_cache_on"], data["prefix_cache_off"]
-    assert on["completed"] == off["completed"] == \
-        data["num_requests"]
-    assert on["ttft_mean_ms"] < off["ttft_mean_ms"]
-    assert on["ttft_exact_ms"]["p99"] < off["ttft_exact_ms"]["p99"]
-    assert on["outputs_sha256"] == off["outputs_sha256"]
-    for arm in (on, off):
-        assert set(arm["slo_attainment"]) == {
-            "interactive", "standard", "batch"}
-
-
 def test_chaos_kinds_help_lists_node_preempt_notice():
     """The --kinds help derives from INJECTION_KINDS (analyzer rule
     wiring-kinds-help-stale) and the rendered help really names the
@@ -487,114 +363,9 @@ def test_chaos_kinds_help_lists_node_preempt_notice():
     assert "node_preempt_notice" in rendered
 
 
-def test_scheduler_scale_workload_dispatched_and_rendered():
-    """The 10^6 proof is wired end to end: bench.py dispatches the
-    scheduler_scale workload, benchgen reads the committed
-    BENCH_scheduler_scale.json artifact, and the artifact itself
-    records a complete, partition-exact 10^6-task run whose submit
-    leg (server-side expansion, streaming batched submission) is no
-    longer the dominant cost."""
-    import json
-    bench_src = (PACKAGE.parent / "bench.py").read_text(
-        encoding="utf-8")
-    assert '"scheduler_scale" in workloads' in bench_src
-    benchgen_src = (PACKAGE.parent / "tools" / "benchgen.py"
-                    ).read_text(encoding="utf-8")
-    assert "BENCH_scheduler_scale.json" in benchgen_src
-    artifact = PACKAGE.parent / "BENCH_scheduler_scale.json"
-    assert artifact.exists(), (
-        "BENCH_scheduler_scale.json not committed — run "
-        "`python bench.py --workloads scheduler_scale`")
-    data = json.loads(artifact.read_text(
-        encoding="utf-8"))["scheduler_scale"]
-    assert data["num_tasks"] >= 1_000_000
-    assert data["completed"] is True
-    assert data["goodput"]["partition_exact"] is True
-    assert data["server_side_expansion"] is True
-    # Submission must not dominate: the materialization leg is
-    # strictly cheaper than the drain, and >= 10x the pre-streaming
-    # submitter's 1648 tasks/s.
-    assert data["submit_seconds"] < data["run_seconds"]
-    assert data["submit_tasks_per_second"] >= 16_480
-
-
 def test_train_workloads_enable_the_compile_cache():
     findings = _run("wiring-compile-cache-optout")
     assert not findings, _fail_lines(findings)
-
-
-def test_benchgen_phase_and_workload_names_exist():
-    """Every silicon-proof phase name tools/benchgen.py binds to
-    (p.get("phase") == "X") must be record()-ed by
-    tools/silicon_proof.py, and every bench workload a silicon-proof
-    phase command invokes (--workloads X) must be dispatched by
-    bench.py ("X" in workloads) — a renamed phase cannot silently
-    turn a docs section or a pipeline phase into a no-op."""
-    tools = PACKAGE.parent / "tools"
-    benchgen_tree = ast.parse(
-        (tools / "benchgen.py").read_text(encoding="utf-8"))
-    proof_src = (tools / "silicon_proof.py").read_text(
-        encoding="utf-8")
-    proof_tree = ast.parse(proof_src)
-    bench_tree = ast.parse(
-        (PACKAGE.parent / "bench.py").read_text(encoding="utf-8"))
-
-    recorded = set()
-    workloads_invoked = set()
-    for node in ast.walk(proof_tree):
-        if isinstance(node, ast.Call) and \
-                isinstance(node.func, ast.Attribute) and \
-                node.func.attr == "record" and node.args and \
-                isinstance(node.args[0], ast.Constant):
-            recorded.add(node.args[0].value)
-        # ["...", "--workloads", "X", ...] command lists.
-        if isinstance(node, ast.List):
-            values = [e.value for e in node.elts
-                      if isinstance(e, ast.Constant) and
-                      isinstance(e.value, str)]
-            for i, value in enumerate(values[:-1]):
-                if value == "--workloads":
-                    workloads_invoked |= {
-                        w.strip() for w in values[i + 1].split(",")}
-
-    referenced = set()
-    for node in ast.walk(benchgen_tree):
-        # p.get("phase") == "X" comparisons.
-        if isinstance(node, ast.Compare) and \
-                isinstance(node.left, ast.Call) and \
-                isinstance(node.left.func, ast.Attribute) and \
-                node.left.func.attr == "get" and node.left.args and \
-                isinstance(node.left.args[0], ast.Constant) and \
-                node.left.args[0].value == "phase":
-            for comparator in node.comparators:
-                if isinstance(comparator, ast.Constant) and \
-                        isinstance(comparator.value, str):
-                    referenced.add(comparator.value)
-    assert referenced, "no phase references found in benchgen.py"
-    missing = referenced - recorded
-    assert not missing, (
-        f"benchgen.py binds to silicon-proof phases {sorted(missing)} "
-        f"that tools/silicon_proof.py never records")
-
-    dispatched = set()
-    for node in ast.walk(bench_tree):
-        # "X" in workloads dispatch checks.
-        if isinstance(node, ast.Compare) and \
-                isinstance(node.left, ast.Constant) and \
-                isinstance(node.left.value, str) and \
-                len(node.ops) == 1 and \
-                isinstance(node.ops[0], ast.In) and \
-                isinstance(node.comparators[0], ast.Name) and \
-                node.comparators[0].id == "workloads":
-            dispatched.add(node.left.value)
-    assert dispatched, "no workload dispatch found in bench.py"
-    missing = workloads_invoked - dispatched
-    assert not missing, (
-        f"silicon_proof.py invokes bench workloads {sorted(missing)} "
-        f"that bench.py never dispatches")
-    # The kernel phase is wired end to end.
-    assert "ring_collectives" in recorded
-    assert "ring_collectives" in dispatched
 
 
 def test_span_kinds_are_declared_in_trace_spans():
@@ -615,3 +386,136 @@ def test_trace_and_profile_fleet_actions_are_wired_in_cli():
 def test_train_loops_never_call_blocking_checkpoint_save():
     findings = _run("jax-blocking-save-in-train")
     assert not findings, _fail_lines(findings)
+
+
+# ------------- a drill has a command; the docs name what exists -------------
+
+# The one fakepod drill no flag selects: it injects no fault, and its
+# two callers are tests/test_preemption.py's scale tests.
+_NO_FLAG_DRILL = "drill.run_scheduler_scale_drill"
+
+
+def _drill_functions() -> list[str]:
+    """``module.function`` of every top-level ``run_*drill`` in
+    chaos/drill.py and chaos/serving_drill.py, read from the source
+    so collecting this file imports no model code."""
+    found = []
+    for module in ("drill", "serving_drill"):
+        tree = ast.parse((PACKAGE / "chaos" / f"{module}.py").read_text(
+            encoding="utf-8"))
+        found += [f"{module}.{node.name}" for node in tree.body
+                  if isinstance(node, ast.FunctionDef)
+                  and re.fullmatch(r"run_(\w+_)?drill", node.name)]
+    return found
+
+
+@pytest.fixture(scope="module")
+def drills_run_by_flag():
+    """`shipyard chaos drill` run once without a drill flag and once
+    with each on/off option of the click command, every drill function
+    replaced by a recorder: {flag or None: [module.function, ...]}."""
+    import importlib
+
+    from click.testing import CliRunner
+
+    from batch_shipyard_tpu.cli import main as cli_main
+    calls: list[str] = []
+    report = {"seed": 0, "fingerprint": "", "invariants": {},
+              "applied": []}
+    flags = [None] + [param.opts[0] for param in cli_main.chaos_drill.params
+                      if getattr(param, "is_flag", False)]
+    ran = {}
+    with pytest.MonkeyPatch.context() as patch:
+        for name in _drill_functions():
+            module, function = name.split(".")
+            patch.setattr(
+                importlib.import_module(
+                    f"batch_shipyard_tpu.chaos.{module}"), function,
+                lambda *args, _name=name, **kwargs:
+                    calls.append(_name) or report)
+        for flag in flags:
+            calls.clear()
+            result = CliRunner().invoke(
+                cli_main.cli, ["chaos", "drill"] + ([flag] if flag else []))
+            assert result.exit_code == 0, (flag, result.output)
+            ran[flag] = list(calls)
+    return ran
+
+
+@pytest.mark.parametrize(
+    "drill", [name for name in _drill_functions()
+              if name != _NO_FLAG_DRILL])
+def test_every_drill_is_reachable_from_the_cli(drill, drills_run_by_flag):
+    """A drill cannot become test-only: `shipyard chaos drill`, with
+    one of its flags or with none, hands the seed to
+    fleet.action_chaos_drill, which runs exactly this function."""
+    selects = [flag for flag, ran in drills_run_by_flag.items()
+               if ran == [drill]]
+    assert selects, (
+        f"no flag of `shipyard chaos drill` runs chaos/{drill} alone: "
+        f"{drills_run_by_flag}")
+
+
+def _nav_pages(nav) -> list[str]:
+    if isinstance(nav, str):
+        return [nav]
+    if isinstance(nav, dict):
+        nav = list(nav.values())
+    return [page for entry in nav for page in _nav_pages(entry)]
+
+
+def test_docs_nav_names_only_pages_that_exist():
+    import yaml
+    site = yaml.safe_load((PACKAGE.parent / "mkdocs.yml").read_text(
+        encoding="utf-8"))
+    pages = _nav_pages(site["nav"])
+    assert pages
+    missing = [page for page in pages
+               if not (PACKAGE.parent / "docs" / page).is_file()]
+    assert not missing, f"mkdocs.yml's nav names no file: {missing}"
+
+
+# What PR 45 deleted: the pre-chip measurement layer. The benchmark of
+# record is benchmark/run.py, BENCHMARK.json, PERF_LEDGER.jsonl, PERF.md.
+_DELETED_LAYER = re.compile(
+    r"(?<![A-Za-z0-9_])bench\.py|benchgen|silicon_proof"
+    r"|SHIPYARD_XLA_TUNING|26-benchmarks|BENCH_DETAILS|BENCH_GANTT"
+    r"|COMPILE_WARM_DETAILS|BENCH_[a-z_]+\.json")
+# The driver's and the seed's records, and the documents whose job is
+# to say what happened: they may name what is gone.
+_HISTORY = ("CHANGES.md", "PERF.md", "ROADMAP.md", "ISSUE.md", "REVIEW.md",
+            "PERF_LEDGER.jsonl", "VERDICT.md", "ADVICE.md", "SURVEY.md",
+            "PAPER*.md", "BASELINE.*", "BENCH_r[0-9]*.json",
+            "MULTICHIP_r[0-9]*.json", "tests/test_names_consistency.py")
+
+
+def _committed_files(root: pathlib.Path):
+    """The files git would commit, without asking git (the driver's
+    copy of the tree has no .git): a walk that leaves out `.git` and
+    whatever a line of the root's .gitignore names."""
+    ignored = [pattern.rstrip("/") for pattern in
+               (root / ".gitignore").read_text(encoding="utf-8").split()]
+
+    def skipped(name: str) -> bool:
+        return name == ".git" or any(
+            fnmatch.fnmatch(name, pattern) for pattern in ignored)
+
+    for folder, dirs, files in os.walk(root):
+        dirs[:] = [name for name in dirs if not skipped(name)]
+        for name in files:
+            if not skipped(name):
+                yield pathlib.Path(folder, name)
+
+
+def test_no_tracked_file_names_the_deleted_layer():
+    root = PACKAGE.parent
+    naming = []
+    for path in _committed_files(root):
+        relative = path.relative_to(root).as_posix()
+        if any(fnmatch.fnmatch(relative, pattern) for pattern in _HISTORY):
+            continue
+        hit = _DELETED_LAYER.search(
+            path.read_text(encoding="utf-8", errors="ignore"))
+        if hit:
+            naming.append(f"{relative}: {hit.group(0)}")
+    assert not naming, "\n".join(naming)

@@ -426,18 +426,14 @@ def test_inproc_runtime_end_to_end():
 
 
 def test_scheduler_scale_smoke():
-    """The scheduler_scale bench phase end-to-end at a tier-1-sized
+    """The scheduler scale drill end-to-end at a tier-1-sized
     count (10^4): every task completes through the real scheduling
     path — server-side expansion, streaming batched submission,
     batched claims, summary-based drain — throughput is reported, and
-    the goodput partition is exact. (The committed
-    BENCH_scheduler_scale.json artifact is the 10^6 run of exactly
-    this code.)"""
-    sys.path.insert(0, REPO_ROOT)
-    import bench
-    result = bench.bench_scheduler_scale(
-        num_tasks=10_000, nodes=2, slots=2, shards=2, timeout=240,
-        artifact=False)
+    the goodput partition is exact."""
+    from batch_shipyard_tpu.chaos import drill
+    result = drill.run_scheduler_scale_drill(
+        num_tasks=10_000, nodes=2, slots=2, shards=2, timeout=240)
     assert result["completed"], result
     assert result["by_state"] == {"completed": 10_000}
     assert result["goodput"]["partition_exact"], result
@@ -454,12 +450,9 @@ def test_scheduler_scale_smoke():
 
 @pytest.mark.slow
 def test_scheduler_scale_million():
-    """The full 10^6-task artifact run (slow phase): the committed
-    BENCH_scheduler_scale.json is regenerated by exactly this call
-    via `python bench.py --workloads scheduler_scale`."""
-    sys.path.insert(0, REPO_ROOT)
-    import bench
-    result = bench.bench_scheduler_scale(artifact=False)
+    """The same drill at its default 10^6 tasks (slow phase)."""
+    from batch_shipyard_tpu.chaos import drill
+    result = drill.run_scheduler_scale_drill()
     assert result["num_tasks"] == 1_000_000
     assert result["completed"], result
     assert result["goodput"]["partition_exact"], result

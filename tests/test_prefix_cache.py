@@ -221,28 +221,3 @@ def test_prefix_cache_clear_and_rewarm(params):
     assert results["b"] == reference_greedy(params, base + [9], 3)
     engine.pages.check()
 
-
-# ----------------------- bench phase (slow) ------------------------
-
-@pytest.mark.slow
-def test_bench_serving_slo_full_run():
-    """The full serving_slo A/B phase (slow tier): regenerates the
-    committed BENCH_serving_slo.json shape via exactly the call
-    `python bench.py --workloads serving_slo` makes, and asserts the
-    acceptance gates live — hit rate > 0.5, cache-on mean AND p99
-    TTFT strictly below the cache-off control at the same seed, and
-    byte-identical greedy outputs between the arms."""
-    import pathlib
-    import sys
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
-    import bench
-    result = bench.bench_serving_slo(artifact=False)
-    assert result["cpu_marker"] is True
-    assert result["prefix_hit_rate"] > 0.5
-    assert result["outputs_identical"] is True
-    on, off = result["prefix_cache_on"], result["prefix_cache_off"]
-    assert on["completed"] == off["completed"] == \
-        result["num_requests"]
-    assert on["shed"] == off["shed"] == 0
-    assert on["ttft_mean_ms"] < off["ttft_mean_ms"]
-    assert on["ttft_exact_ms"]["p99"] < off["ttft_exact_ms"]["p99"]

@@ -3,12 +3,9 @@
 slots advance 1..gamma+1 tokens per step — must stay greedy-exact
 against the non-speculative engine across mixed accept/reject slots,
 mid-draft stops, mid-flight admission, dense AND paged KV, plus the
-stats/plumbing and the serving_speculative bench phase."""
+stats/plumbing."""
 
 import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +15,6 @@ import pytest
 from batch_shipyard_tpu.models import inference as inf
 from batch_shipyard_tpu.models import serving
 from batch_shipyard_tpu.models import transformer as tfm
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CFG = tfm.TransformerConfig(
     vocab_size=97, d_model=32, n_layers=2, n_heads=2, d_head=16,
@@ -275,54 +270,6 @@ def test_frontend_exposes_acceptance_rate(params, noisy_params):
         assert "shipyard_serving_spec_proposed_tokens_total" in text
     finally:
         front.shutdown()
-
-
-@pytest.mark.slow
-def test_bench_serving_speculative_emits_metrics():
-    """The serving_speculative bench phase (bench.py) reports
-    tokens/s, TTFT/TPOT percentiles, and the measured acceptance
-    rate, for dense and paged KV."""
-    sys.path.insert(0, REPO_ROOT)
-    import bench
-    for page in (None, 8):
-        rep = bench.bench_serving_speculative(
-            num_requests=3, rate_hz=50.0, num_slots=2,
-            max_decode_len=64, d_model=32, n_layers=1, n_heads=2,
-            d_ff=64, draft_d_model=16, draft_n_layers=1, gamma=3,
-            vocab_size=97, kv_page_size=page)
-        assert rep["failed"] == 0
-        assert rep["tokens_per_second"] > 0
-        for key in ("ttft_ms", "tpot_ms"):
-            assert set(rep[key]) == {"p50", "p90", "p99"}
-        spec = rep["speculative"]
-        assert spec["proposed"] > 0
-        assert 0.0 <= spec["acceptance_rate"] <= 1.0
-        assert rep["kv_page_size"] == page
-
-
-def test_silicon_proof_dry_run_has_serving_speculative_phase(
-        tmp_path):
-    """The silicon-proof skeleton (CI path) records the
-    serving_speculative phase with the exact metric names it will
-    emit on the chip (dense + paged)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO_ROOT, "tools/silicon_proof.py"),
-         "--dry-run", "--out-dir", str(tmp_path)],
-        capture_output=True, text=True, timeout=120, env=env)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    report = json.loads(
-        (tmp_path / "SILICON_PROOF.json").read_text())
-    phases = {p["phase"]: p for p in report["phases"]}
-    spec = phases["serving_speculative"]
-    assert spec["status"] == "dry_run"
-    assert "bench.py" in spec["command"]
-    assert "serving_speculative" in spec["command"]
-    for variant in ("dense", "paged"):
-        assert set(spec["metrics"][variant]) == {
-            "tokens_per_second", "ttft_ms_p50", "tpot_ms_p50",
-            "acceptance_rate"}
 
 
 def test_paged_multitoken_insert_requires_spec_window(params):
