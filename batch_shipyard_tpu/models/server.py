@@ -51,7 +51,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from batch_shipyard_tpu.models.serving import (
-    PHASE_PREFIX, ContinuousBatcher, Request)
+    BLOCK_COUNTERS, PHASE_PREFIX, ContinuousBatcher, Request)
 from batch_shipyard_tpu.trace import spans as trace_spans
 from batch_shipyard_tpu.trace.histogram import LatencyHistogram
 from batch_shipyard_tpu.utils import util
@@ -817,9 +817,16 @@ class ServingFrontEnd:
         drafts = "; mtp_drafted %d, mtp_accepted %d" % (
             since("mtp_drafted"), since("mtp_accepted")) \
             if "mtp_drafted" in now else ""
+        # a model that generates by diffusion over blocks (absent
+        # otherwise)
+        blocks = ("; block passes: %d denoise, %d commit, %d positions "
+                  "unmasked, %d tokens landed" % tuple(
+                      since(name) for name in BLOCK_COUNTERS)) \
+            if BLOCK_COUNTERS[0] in now else ""
         logger.info(
             "engine launches: %s; prefills_grouped %d; prefill tokens "
-            "%d of %d padded; no work %.3f s; %d stalls" + drafts,
+            "%d of %d padded; no work %.3f s; %d stalls" + drafts
+            + blocks,
             ", ".join(
                 f"{kind} {since('launches', kind)} "
                 f"({since('launch_seconds', kind):.3f} s, "
@@ -1271,9 +1278,11 @@ class ServingFrontEnd:
             "prefills_grouped_total": engine["prefills_grouped"],
             "no_work_seconds_total": engine["no_work_seconds"],
             "stalls_total": engine["stalls"],
-            # a model that carries its own drafter (absent otherwise)
+            # a model that carries its own drafter, or generates by
+            # diffusion over blocks (absent otherwise)
             **{f"{name}_total": engine[name] for name in (
-                "mtp_drafted", "mtp_accepted") if name in engine},
+                "mtp_drafted", "mtp_accepted") + BLOCK_COUNTERS
+               if name in engine},
         }))
         for metric in ("ttft_ms", "tpot_ms"):
             for pct, value in stats[metric].items():
@@ -1482,9 +1491,11 @@ class ServingFrontEnd:
             **{name: steps[name] for name in (
                 "expert_pairs_here", "expert_pairs_chosen",
                 "experts_hit") if name in steps},
-            # a drafting model's counters (absent otherwise)
+            # a drafting model's counters, a block-diffusion model's
+            # (absent otherwise)
             **{name: steps[name] for name in (
-                "mtp_drafted", "mtp_accepted") if name in steps},
+                "mtp_drafted", "mtp_accepted") + BLOCK_COUNTERS
+               if name in steps},
             "step_ms_mean": steps["step_seconds"] * per_step,
             "phase_ms_mean": {
                 name: seconds * per_step

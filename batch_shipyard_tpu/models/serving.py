@@ -78,12 +78,24 @@ LAUNCH_RING = 64
 # What a step program lets the model write: the cache, and the expert
 # choices a routed layer sows (nothing, for a model without one).
 _MUTABLE = ["cache", "decisions"]
+# On a block-diffusion model's record (ContinuousBatcher.
+# _block_record): the name of the denoise pass that unmasked each
+# position, and of a routed layer's choices in its block's s-th pass.
+UNMASK_NAME = "unmask"
+# A block-diffusion engine's counters (ContinuousBatcher._block_counts),
+# in the order a reader lists them.
+BLOCK_COUNTERS = ("block_denoise_passes", "block_commit_passes",
+                  "block_positions_unmasked", "block_tokens_landed")
+
+
+def pass_layer_name(layer: str, s: int) -> str:
+    return f"{layer}.pass{s}"
 
 
 @functools.partial(jax.jit, static_argnames=("model", "sampling"),
                    donate_argnames=("cache",))
 def _decode_step(model, sampling, params, cache, tokens, positions,
-                 active, key, draft=None):
+                 active, key, draft=None, masked=None):
     """One token for every slot in one compiled call. MODULE-LEVEL
     with the model/sampling static so identical engines — fleet
     replicas sharing one param tree, or a test suite constructing
@@ -106,7 +118,16 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
     A model that carries its own drafter (TransformerConfig.
     mtp_modules) is handed ``draft`` [B] too, and the SAME program
     name then holds the verify-and-draft step (_verify_and_draft: one
-    or two tokens a slot; its results behind the same first four)."""
+    or two tokens a slot; its results behind the same first four).
+
+    A model that generates by diffusion over blocks (TransformerConfig.
+    block_diffusion) is handed ``masked`` [B, block] and tokens
+    [B, 1, block], and the SAME program name then holds the
+    denoise-or-commit pass (_denoise_or_commit: no token or a whole
+    block a slot; its results behind the same first four)."""
+    if model.config.block_diffusion is not None:
+        return _denoise_or_commit(model, params, cache, tokens, masked,
+                                  positions, active)
     if model.config.mtp_modules:
         return _verify_and_draft(model, params, cache, tokens, draft,
                                  positions, active)
@@ -203,6 +224,97 @@ def _verify_and_draft(model, params, cache, tokens, draft, positions,
            accepted, new_draft)
     picked = [part for part in (chosen, mtp_chosen) if part is not None]
     return out + (jnp.concatenate(picked),) if picked else out
+
+
+def _unmasked_by(confidence, masked, rule):
+    """The positions a denoise pass unmasks, bool [B, block], from the
+    log-confidence of every position's own best token [B, block] and
+    the mask before the pass: among the masked, the ``block // steps``
+    most confident (ties: the lowest index; all of them where fewer
+    are left), or under "low_confidence_dynamic" every one whose
+    confidence is above the threshold where those are at least as
+    many. An unmasked position is never chosen, so never masked
+    again."""
+    size = masked.shape[1]
+    least = size // rule.steps
+    score = jnp.where(masked, confidence, -jnp.inf)
+    index = jnp.arange(size)
+    # how many positions go before this one: a higher score, or the
+    # same at a lower index
+    ahead = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None])
+        & (index[None, :] < index[:, None])[None])
+    top = masked & (jnp.sum(ahead, axis=-1) < least)
+    if rule.remask != "low_confidence_dynamic":
+        return top
+    above = masked & (confidence > jnp.log(jnp.float32(rule.threshold)))
+    return jnp.where(
+        jnp.sum(above, axis=-1, keepdims=True) >= least, above, top)
+
+
+def _denoise_or_commit(model, params, cache, tokens, masked, positions,
+                       active):
+    """_decode_step for a model that generates by diffusion over blocks
+    (transformer.BlockDiffusion): ONE program for all slots, dispatched
+    by the lookahead as every decode step is, whose pass is per slot a
+    denoise pass or a commit pass, by the slot's own data. tokens
+    [B, 1, block] is each slot's open block at ``positions`` [B] (its
+    first position, a whole number of blocks: the cursor of every cache
+    leaf), ``masked`` [B, block] the positions of it that still read as
+    the mask token (masked-ness is this boolean alone, never ``token ==
+    mask_id``: a prompt may hold that id; what lies under a mask is
+    whatever the block held before and is read by nobody).
+
+      forward  the block, mask token where masked, at positions p ..
+               p + block - 1 through the stack against the cached
+               blocks before it: the grouped paged kernel reads each
+               live page once for all its query positions, which ALL
+               see all the keys, the block's own rows included
+      commit   a slot without a mask: the rows this pass wrote ARE the
+               block's K/V and are kept, the cursor moves on by a
+               block, the block's tokens land, the next block opens
+               all masked
+      denoise  else: float32 logits, each position's best token and
+               its confidence (that token's softmax probability, as
+               its logarithm), the pass's choice among the masked
+               (_unmasked_by) takes its token; the cursor is rewound by
+               the block (inference._rewind_cache), so that the rows
+               lie beyond it and the next pass overwrites them
+
+    Greedy only. Nothing here reads the host: pass k+1's block, mask,
+    positions and cursors are this program's results. -> (cache, tokens
+    [B, 1, block], positions [B], the block [B, block]: _decode_step's
+    four, the tokens a committing slot lands (a caller that wraps the
+    step and reads only those four finds them as _decode_step gives
+    them); then masked [B, block], flags int32 [B, block + 1] (the
+    positions this pass unmasked, then whether it committed; zeros for
+    an inactive slot)[, the routed layers' choices int32 [decision
+    layers, B, block, k]])."""
+    cfg = model.config
+    rule = cfg.block_diffusion
+    block = tokens[:, 0]                                  # [B, block]
+    size = block.shape[1]
+    pos_blk = positions[:, None] + jnp.arange(size, dtype=jnp.int32)[None]
+    logits, mutated = model.apply(
+        {"params": params, "cache": cache},
+        jnp.where(masked, jnp.int32(rule.mask_id), block),
+        positions=pos_blk, live=active, mutable=_MUTABLE)
+    logits = logits.astype(jnp.float32)
+    best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    confidence = jnp.max(logits, axis=-1) - jax.nn.logsumexp(
+        logits, axis=-1)
+    commit = active & ~jnp.any(masked, axis=1)
+    picked = _unmasked_by(confidence, masked, rule) & active[:, None]
+    block = jnp.where(picked, best, block)
+    masked = jnp.where(commit[:, None], True, masked & ~picked)
+    positions = jnp.where(commit, positions + size, positions)
+    cache = inf._park_idle_cursors(inf._rewind_cache(
+        mutated["cache"], jnp.where(commit, 0, size)), active)
+    flags = jnp.concatenate([picked, commit[:, None]],
+                            axis=1).astype(jnp.int32)
+    out = (cache, block[:, None], positions, block, masked, flags)
+    chosen = tfm.collect_decisions(mutated.get("decisions"), cfg)
+    return out if chosen is None else out + (chosen,)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -309,6 +421,16 @@ def window_segment(config) -> Optional[int]:
     return max(1 << (max(windows) - 1).bit_length(), SEGMENT_FLOOR)
 
 
+def _cached_len(config, prompt_len):
+    """The positions a prefill leaves in the cache: the prompt's, but
+    of a model that generates by diffusion over blocks the prompt's
+    WHOLE blocks alone (the rest of the prompt opens the first
+    generated block, whose rows the block's own passes write)."""
+    rule = config.block_diffusion
+    return prompt_len if rule is None else \
+        prompt_len - prompt_len % rule.block
+
+
 def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
     """Batch-1 BATCHED prefill over the (bucket-padded) prompt
     [1, L]: the multi-token insert path of transformer._decode_attend
@@ -343,7 +465,10 @@ def _dense_prefill(model, prefill_chunk, params, prompt, prompt_len):
 def _prefill_segments(model, prefill_chunk, params, cache, tokens,
                       start, prompt_len):
     """``tokens`` [1, S] at positions start.. through the batch-1
-    cache, in chunks; the logits at position prompt_len-1. Several
+    cache, in chunks; the logits at position prompt_len-1 (of a model
+    that generates by diffusion over blocks, which reads no token off
+    a prefill, that position's hidden row: the head stays out).
+    Several
     chunks of one length are ONE traced forward under lax.scan (the
     cache its carry), so that a long bucket compiles, and weighs, what
     one chunk does and not chunks times that.
@@ -418,8 +543,9 @@ def _prefill_segments(model, prefill_chunk, params, cache, tokens,
         chosen = picked
     normed = hidden[0][0]
     at = prompt_len - start - 1
-    last = tfm.output_logits(
-        cfg, params, jnp.take(normed, at, axis=0))           # [vocab]
+    last = jnp.take(normed, at, axis=0)
+    if cfg.block_diffusion is None:
+        last = tfm.output_logits(cfg, params, last)          # [vocab]
     if isinstance(chosen, list):     # the loop's, a chunk an entry
         chosen = tuple(
             None if part[0] is None
@@ -513,7 +639,7 @@ def _prefill_dense(model, prefill_chunk, params, cache, slot, prompt,
 
     def scatter(big, sm, path_key):
         if path_key == "index":
-            return big.at[slot].set(prompt_len)
+            return big.at[slot].set(_cached_len(model.config, prompt_len))
         return big.at[slot].set(sm[0])
 
     cache = jax.tree_util.tree_map_with_path(
@@ -569,8 +695,8 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
                 "k_pages": kp, "v_pages": vp,
                 "block_table":
                     big["block_table"].at[slot].set(table_row),
-                "length":
-                    big["length"].at[slot].set(prompt_len),
+                "length": big["length"].at[slot].set(
+                    _cached_len(model.config, prompt_len)),
             }
             if "k_page_scales" in big:
                 # int8 pool: the dense prefill cache is int8 too
@@ -678,8 +804,8 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
                 "k_pages": kp, "v_pages": vp,
                 "block_table":
                     big["block_table"].at[slot].set(table_row),
-                "length":
-                    big["length"].at[slot].set(prompt_len),
+                "length": big["length"].at[slot].set(
+                    _cached_len(model.config, prompt_len)),
             }
             if "k_page_scales" in big:
                 ksc = big["k_page_scales"]
@@ -732,6 +858,25 @@ def _seat_draft(drafts, slot, draft):
     """A prefill's first draft [1] seated in the slot's row of the
     drafting decode step's input [B], behind _seat_first."""
     return drafts.at[slot].set(draft[0])
+
+
+@jax.jit
+def _seat_block(last_logits, tokens, masked, positions, slot, first,
+                given, start):
+    """_seat_first for a model that generates by diffusion over blocks:
+    a prefill yields K/V and no token, and what is seated is the first
+    generated BLOCK: ``first`` int32 [block] (the ``given`` tokens of
+    the prompt past its whole blocks, then anything) into the slot's
+    row of the block step's inputs, masked from ``given`` on, at the
+    block's first position ``start``. -> (tokens [B, 1, block], masked
+    [B, block], positions [B], int32 [1] that is ready when the
+    prefill's ``last_logits`` (its last hidden row: _prefill_segments)
+    are: what the host waits for in _land_first). Slot, count and
+    position traced: ONE compilation an engine."""
+    return (tokens.at[slot, 0].set(first),
+            masked.at[slot].set(jnp.arange(first.shape[0]) >= given),
+            positions.at[slot].set(start),
+            jnp.isnan(last_logits[:1]).astype(jnp.int32))
 
 
 @dataclasses.dataclass
@@ -797,8 +942,13 @@ class _Slot:
     in_flight: int = 0
     # The launches behind those tokens (a first token, a decode step):
     # each lands one token AT LEAST. Equal to in_flight for a model
-    # that does not draft.
+    # that does not draft; 0 throughout for a model that generates by
+    # diffusion over blocks, whose pass lands no token or a block.
     launches: int = 0
+    # A block-diffusion model's open block: how many of its first
+    # positions hold the prompt's tokens past its whole blocks (the
+    # first generated block alone; they are given, not served).
+    given: int = 0
 
     def decoding(self) -> bool:
         """Whether the next decode step advances this slot: seated
@@ -827,6 +977,15 @@ class _Slot:
                 (req.eos_id is not None and
                  self.generated[-1] == req.eos_id))
 
+    def served_of(self, tokens: list) -> list:
+        """The leading tokens of a committed block that are served:
+        up to the request's max_new_tokens-th, or its eos_id."""
+        req = self.request
+        tokens = tokens[:req.max_new_tokens - len(self.generated)]
+        if req.eos_id is not None and req.eos_id in tokens:
+            tokens = tokens[:tokens.index(req.eos_id) + 1]
+        return tokens
+
 
 @dataclasses.dataclass
 class _InFlight:
@@ -850,6 +1009,11 @@ class _InFlight:
     # int32 [B] on the device, ``tokens`` then being 1 + drafts
     # arrays [B] of which a slot lands its first 1 + accepted.
     accepted: object = None
+    # A block pass (_denoise_or_commit): int32 [B, block + 1] on the
+    # device, the positions the pass unmasked and whether it
+    # committed, ``tokens`` then being the blocks [B, block], which a
+    # committing slot lands.
+    flags: object = None
 
 
 @dataclasses.dataclass
@@ -910,6 +1074,9 @@ class Launch:
     # decode: the drafts the step accepted over its rows (0 for a
     # model that does not draft)
     accepted: int = 0
+    # decode: the rows whose pass committed a block (a model that
+    # generates by diffusion over blocks; the others' denoised)
+    commits: int = 0
     request_id: str = ""        # prefill
     # prefill: the road the bucket's program takes through its routed
     # experts (moe.experts_road: "dense" / "grouped"); "" for a model
@@ -939,7 +1106,7 @@ class Launch:
                "queued": self.queued}
         if self.kind == "decode":
             out.update(rows=self.rows, tokens=self.tokens,
-                       accepted=self.accepted)
+                       accepted=self.accepted, commits=self.commits)
         else:
             out.update(path=self.path, bucket=self.bucket,
                        tokens=self.tokens,
@@ -1165,6 +1332,41 @@ class ContinuousBatcher:
                 raise NotImplementedError(
                     "a rejected draft is un-committed by the cursor: "
                     "not for a model with a per-slot state")
+        # A model that generates by diffusion over blocks
+        # (transformer.BlockDiffusion): every decode step is a denoise
+        # or a commit pass of each slot's open block and lands no
+        # token or a whole block, on the lookahead's step order
+        # (_denoise_or_commit). 0: one token a step.
+        rule = config.block_diffusion
+        self.block = rule.block if rule is not None else 0
+        self.block_denoise_passes = 0
+        self.block_commit_passes = 0
+        self.block_positions_unmasked = 0
+        self.block_tokens_landed = 0
+        if self.block:
+            if speculative is not None or self.drafts:
+                raise ValueError(
+                    "a block is denoised as one: no draft model and "
+                    "no multi-token-prediction module beside "
+                    "block_diffusion")
+            if sampling.temperature > 0:
+                raise NotImplementedError(
+                    "block diffusion unmasks each position's best "
+                    "token (greedy): it requires temperature == 0 "
+                    "sampling")
+            if self.stateful or self.window:
+                raise NotImplementedError(
+                    "a denoise pass is un-committed by the cursor and "
+                    "sees its whole block: not for a model with a "
+                    "per-slot state or a window layer")
+            if not self.paged or kv_page_size % self.block or (
+                    self.prefill_chunk or 0) % self.block:
+                raise ValueError(
+                    f"block diffusion needs the paged KV cache, pages "
+                    f"and prefill chunks of whole blocks of "
+                    f"{self.block} (kv_page_size {kv_page_size}, "
+                    f"prefill_chunk {self.prefill_chunk}): a block "
+                    f"then never straddles two pages or two chunks")
         # The layers that choose experts per position: their choices
         # leave every step program with the tokens and are kept per
         # request until take_decisions hands them over.
@@ -1181,15 +1383,19 @@ class ContinuousBatcher:
         self.prefix_cache = bool(prefix_cache) and self.paged
         if self.paged:
             self.page_size = kv_page_size
+            # what a step may write beyond the row it commits: the
+            # drafts, or a block (the pass dispatched behind a
+            # request's last commit writes one more)
+            margin = max(self.gamma, self.drafts, self.block)
             self.pages = kv_pages.PagePool(
                 num_slots, kv_num_pages, kv_page_size, max_decode_len,
-                spec_window=max(self.gamma, self.drafts),
+                spec_window=margin,
                 overcommit=overcommit, prefix_cache=self.prefix_cache)
             # The pool's pages and, last, the scratch page.
             self.config = dataclasses.replace(
                 self.config, kv_page_size=kv_page_size,
                 kv_num_pages=self.pages.scratch_page + 1,
-                spec_window=max(self.gamma, self.drafts))
+                spec_window=margin)
         # SLO scheduling state: live EWMA estimates of prefill cost
         # per bucket token and of the decode step feed admission's
         # stall prediction; sheds/deferrals are the overload
@@ -1275,14 +1481,18 @@ class ContinuousBatcher:
         # too.
         with on_device:
             (self.cache, self._tokens, self._positions, self._active,
-             self._key, self._draft) = self._put((
+             self._key, self._draft, self._masked) = self._put((
                  inf.init_cache(self.model, params, num_slots),
-                 jnp.zeros((num_slots, 1), jnp.int32),
+                 # each slot's pending token, or its open block
+                 jnp.zeros((num_slots, 1) + (
+                     (self.block,) if self.block else ()), jnp.int32),
                  jnp.zeros((num_slots,), jnp.int32),
                  jnp.zeros((num_slots,), jnp.bool_),
                  jax.random.PRNGKey(seed),
                  # each slot's draft of the token after its pending one
-                 jnp.zeros((num_slots,), jnp.int32)))
+                 jnp.zeros((num_slots,), jnp.int32),
+                 # the positions of each slot's open block still masked
+                 jnp.ones((num_slots, self.block), jnp.bool_)))
         self._slot_state_bytes = inf.slot_state_bytes(self.cache)
         # _active as the host last pushed it (_push_active).
         self._active_host = np.zeros((num_slots,), np.bool_)
@@ -1488,7 +1698,9 @@ class ContinuousBatcher:
                     self.model, self.sampling, params_abs, cache_abs,
                     tokens_abs, pos_abs, active_abs, key_abs,
                     *([aot.abstractify(self._draft)]
-                      if self.drafts else [])).compile()
+                      if self.drafts else []),
+                    **({"masked": aot.abstractify(self._masked)}
+                       if self.block else {})).compile()
             count += 1
             dense_model = self._prefill.args[0]
             for bucket in self.warmup_buckets():
@@ -1519,11 +1731,17 @@ class ContinuousBatcher:
             # The seat program behind every prefill (_seat_first): it
             # takes a prefill's last logits, whatever the bucket.
             logits = lowered.out_info[1]
-            _seat_first.lower(
-                self.sampling,
-                jax_mod.ShapeDtypeStruct(logits.shape, logits.dtype,
-                                         sharding=active_abs.sharding),
-                key_abs, tokens_abs, pos_abs, 0, 0).compile()
+            logits_abs = jax_mod.ShapeDtypeStruct(
+                logits.shape, logits.dtype, sharding=active_abs.sharding)
+            if self.block:
+                _seat_block.lower(
+                    logits_abs, tokens_abs,
+                    aot.abstractify(self._masked), pos_abs, 0,
+                    put_abs((self.block,)), 0, 0).compile()
+            else:
+                _seat_first.lower(
+                    self.sampling, logits_abs, key_abs, tokens_abs,
+                    pos_abs, 0, 0).compile()
             count += 1
         return count
 
@@ -1672,7 +1890,8 @@ class ContinuousBatcher:
             compiles0 = self._compiles.read()
             lookahead0 = {**self._lookahead_counts(),
                           **self._expert_counts(),
-                          **self._mtp_counts()}
+                          **self._mtp_counts(),
+                          **self._block_counts()}
         self._step()
         seconds = time.monotonic() - t0
         if seconds * 1e3 > STALL_MS / 4 and self.stalls == stalls0:
@@ -1748,6 +1967,10 @@ class ContinuousBatcher:
             # of the decode step this call landed
             for name, value in self._mtp_counts().items():
                 attrs[name] = value - lookahead0[name]
+        if self.block:
+            # of the block pass this call landed
+            for name, value in self._block_counts().items():
+                attrs[name] = value - lookahead0[name]
         attrs.update(before)
         count, compile_s = self._compiles.read()
         if count > compiles0[0]:
@@ -1795,8 +2018,10 @@ class ContinuousBatcher:
             return
         if self.pages is not None:
             with phases("grow_pages"):
-                # a drafting step writes its draft's row too
-                self._grow_pages(span=self.drafts)
+                # a drafting step writes its draft's row too; a block
+                # pass its whole block, a block further on if the
+                # pass in flight commits
+                self._grow_pages(span=self.block or self.drafts)
         seated = self._decoding()
         if not seated:
             # Every seated request waits for its last token only.
@@ -1807,8 +2032,15 @@ class ContinuousBatcher:
         self._dispatching(t0)
         with phases("dispatch"):
             self._push_active(seated)
-            accepted = step_key = None
-            if self.drafts:
+            accepted = step_key = flags = None
+            if self.block:
+                # greedy: the key goes unsplit and unused
+                (self.cache, self._tokens, self._positions, next_tok,
+                 self._masked, flags, *chosen) = self._decode_step(
+                    self.params, self.cache, self._tokens,
+                    self._positions, self._active, self._key,
+                    masked=self._masked)
+            elif self.drafts:
                 # greedy: the key goes unsplit and unused
                 (self.cache, self._tokens, self._positions, first,
                  second, accepted, self._draft,
@@ -1826,12 +2058,13 @@ class ContinuousBatcher:
                     self._positions, self._active, step_key)
             self._unread.append(_InFlight(
                 next_tok, step_key, seated, t0, len(self._unread),
-                *chosen, accepted=accepted))
+                *chosen, accepted=accepted, flags=flags))
             # _land lets the arrays die
-            del next_tok, step_key, chosen, accepted
+            del next_tok, step_key, chosen, accepted, flags
             for i, _ in seated:
-                self._slots[i].in_flight += 1 + self.drafts
-                self._slots[i].launches += 1
+                self._slots[i].in_flight += self.block or 1 + self.drafts
+                # (a block pass lands no token at least)
+                self._slots[i].launches += not self.block
         self.decode_steps += 1
         self.steps_overlapped += overlapped
         self._land_unread(keep=1)
@@ -1887,11 +2120,16 @@ class ContinuousBatcher:
         wait is the "prefill" phase's, the books and the hand-over
         "slot_update"'s, as when _admit did both."""
         phases = self._phases
+        # a block-diffusion model's prefill leaves its whole blocks
+        # alone in the cache, and its record holds those
+        kept = _cached_len(
+            self.config, first.first_position + first.prefilled) \
+            - first.first_position
         with phases("prefill"):
             ready = first.token.is_ready()
             token = int(np.asarray(first.token)[0])
             chosen = (None if first.chosen is None else np.asarray(
-                first.chosen)[:, :first.prefilled].astype(np.int16))
+                first.chosen)[:, :kept].astype(np.int16))
         # Nobody else sits here: whatever frees a slot lands what is
         # unread first.
         slot = self._slots[first.slot]
@@ -1904,18 +2142,22 @@ class ContinuousBatcher:
             chunks=-(-first.bucket // (self.prefill_chunk
                                        or first.bucket))))
         with phases("slot_update"):
-            slot.in_flight -= 1
-            slot.launches -= 1
-            slot.generated.append(token)
-            if chosen is not None:
+            if chosen is not None or self.block:
                 self._decisions[request_id] = {
                     "first": first.first_position, "prefill": chosen,
-                    "steps": []}
-            if slot.ended():
-                # A decode step dispatched behind the prefill with
-                # this slot seated computes overshoot (_land).
-                self._finish(first.slot)
-            self._emit([(request_id, token, len(slot.generated) - 1)])
+                    "steps": [],
+                    # (a block model's first generated position)
+                    "start": first.first_position + kept}
+            if not self.block:
+                slot.in_flight -= 1
+                slot.launches -= 1
+                slot.generated.append(token)
+                if slot.ended():
+                    # A decode step dispatched behind the prefill with
+                    # this slot seated computes overshoot (_land).
+                    self._finish(first.slot)
+                self._emit([(request_id, token,
+                             len(slot.generated) - 1)])
             first.token = first.chosen = None   # as in _land
 
     def _land(self, step: _InFlight) -> None:
@@ -1930,6 +2172,8 @@ class ContinuousBatcher:
         program is ordered behind the one before it by the cache it
         consumes: a slot's next prefill behind this step, and the
         step behind a prefill dispatched before it (_admit)."""
+        if step.flags is not None:
+            return self._land_blocks(step)
         phases = self._phases
         with phases("readback"):
             # [B, 1 + drafts]: what each slot may land, in order
@@ -1996,6 +2240,70 @@ class ContinuousBatcher:
             # cost, so it is counted here and not after every phase
             # has ended.
             step.tokens = step.key = step.chosen = step.accepted = None
+
+    def _land_blocks(self, step: _InFlight) -> None:
+        """_land for a block pass (_denoise_or_commit): a slot whose
+        pass committed lands its block (of the first generated block
+        the positions past the prompt's given tokens), cut short where
+        one of its tokens is the request's last (max_new_tokens, its
+        eos_id: the block was finished, what lies behind is dropped);
+        a slot whose pass denoised lands nothing. A request ends on a
+        landing alone, so the pass dispatched meanwhile with its slot
+        seated is overshoot, as after an eos. Every pass goes on the
+        request's record (take_decisions)."""
+        phases = self._phases
+        size = self.block
+        with phases("readback"):
+            ready = step.tokens.is_ready()
+            blocks = np.asarray(step.tokens)            # [B, block]
+            flags = np.asarray(step.flags)              # [B, block + 1]
+            chosen = None if step.chosen is None else np.asarray(
+                step.chosen).astype(np.int16)   # [layers, B, block, k]
+        rows = [i for i, _ in step.seated]
+        committed = flags[:, -1].astype(bool)
+        commits = int(committed[rows].sum())
+        tokens = blocks.tolist()
+        # what each committing slot serves of its block
+        serves = {i: self._slots[i].served_of(
+                      tokens[i][self._slots[i].given:])
+                  for i, req in step.seated
+                  if committed[i] and self._slots[i].request is req}
+        landed = sum(map(len, serves.values()))
+        self.block_denoise_passes += len(rows) - commits
+        self.block_commit_passes += commits
+        self.block_positions_unmasked += int(flags[rows, :-1].sum())
+        self.block_tokens_landed += landed
+        self._landed(Launch.landing(
+            "decode", step.dispatched_at, self._landed_at, ready,
+            step.queued, rows=len(rows), tokens=landed,
+            commits=commits))
+        with phases("emit"):
+            batch = []
+            for i, req in step.seated:
+                slot = self._slots[i]
+                if slot.request is not req:
+                    self.overshoot_tokens += size * bool(committed[i])
+                    continue
+                slot.in_flight -= size
+                self._decisions[req.request_id]["steps"].append(
+                    (chosen, i, flags[i],
+                     blocks[i] if committed[i] else None))
+                if not committed[i]:
+                    continue
+                given, slot.given = slot.given, 0
+                for token in serves[i]:
+                    slot.generated.append(token)
+                    batch.append((req.request_id, token,
+                                  len(slot.generated) - 1))
+                if slot.ended():
+                    self.overshoot_tokens += size - given - len(serves[i])
+                    self._finish(i)
+            self._emit(batch)
+            if chosen is not None:
+                self._count_experts(chosen[:, rows].reshape(
+                    len(chosen), -1, chosen.shape[-1]))
+            # as in _land: the arrays die inside the phase
+            step.tokens = step.key = step.chosen = step.flags = None
 
     def _emit(self, batch: list[tuple[str, int, int]]) -> None:
         """Hand the (request_id, token, index) triples a step or a
@@ -2111,7 +2419,8 @@ class ContinuousBatcher:
         that admitted, decoded or read a step back, their wall
         seconds, the lookahead's counters (_lookahead_counts), a
         routed model's expert counters (_count_experts), a drafting
-        model's mtp_drafted / mtp_accepted (_mtp_counts), the seconds
+        model's mtp_drafted / mtp_accepted (_mtp_counts), a
+        block-diffusion model's passes (_block_counts), the seconds
         of each phase, and the process's compile count
         (programs built or loaded from the persistent cache, and
         their seconds)."""
@@ -2123,6 +2432,7 @@ class ContinuousBatcher:
                 **(self._expert_counts() if self._decision_layers
                    else {}),
                 **(self._mtp_counts() if self.drafts else {}),
+                **(self._block_counts() if self.block else {}),
                 "phase_seconds": dict(self._phases.total),
                 "compiles": compiles,
                 "compile_seconds": compile_seconds}
@@ -2210,10 +2520,14 @@ class ContinuousBatcher:
         module's own routed layer at the same positions). ``first``
         is past whatever
         prefix came out of shared pages. None for an unknown id, a
-        second call, or a model without such layers."""
+        second call, or a model without such layers. A model that
+        generates by diffusion over blocks has a record whether it
+        routes or not, of another shape: _block_record."""
         record = self._decisions_done.pop(request_id, None)
         if record is None:
             return None
+        if self.block:
+            return self._block_record(record)
         rows = np.concatenate(
             [record["prefill"]] + [step[:, i, :served]
                                    for step, i, served
@@ -2221,6 +2535,65 @@ class ContinuousBatcher:
             axis=1).astype(np.int32)
         return {"first": record["first"],
                 "layers": dict(zip(self._decision_layers, rows))}
+
+    def _block_record(self, record: dict) -> dict:
+        """take_decisions of a model that generates by diffusion over
+        blocks: {"first": p, "layers": {...}, "block": its length,
+        "start": the first generated block's first position, "tokens":
+        int32, every token of the committed blocks from ``start`` on as
+        the program conditioned on them (the prompt's given ones and
+        those dropped behind the request's last included)}. ``layers``
+        holds, each int32 [m, .] over the positions p .. p+m-1 the
+        prefill kept and the commit passes wrote:
+
+          <routed layer>            the choices of the pass that wrote
+                                    the position's K/V (the prefill, a
+                                    commit pass)
+          <routed layer>.pass<s>    those of the block's s-th denoise
+                                    pass (s < steps); a pass the block
+                                    did not take saw the block without
+                                    a mask, as its commit pass did, and
+                                    holds that pass's; so do the
+                                    prefill's positions
+          unmask  [m, 1]            the denoise pass that unmasked the
+                                    position; ``steps`` where it never
+                                    was masked (the prompt's)
+
+        Passes behind the last commit (overshoot) are not on it."""
+        size, steps = self.block, self.config.block_diffusion.steps
+        prefill = record["prefill"]
+        kept = record["start"] - record["first"]
+        routed = prefill is not None
+        written = [prefill] if routed else []
+        passes = [[prefill] for _ in range(steps)] if routed else []
+        unmask = [np.full((kept,), steps, np.int32)]
+        tokens, denoised = [], []
+        for chosen, i, flags, block in record["steps"]:
+            mine = chosen[:, i] if routed else None  # [layers, block, k]
+            if block is None:
+                denoised.append((mine, flags[:-1].astype(bool)))
+                continue
+            at = np.full((size,), steps, np.int32)
+            for s, (_, picked) in enumerate(denoised):
+                at[picked] = s
+            unmask.append(at)
+            tokens.append(block)
+            if routed:
+                written.append(mine)
+                for s in range(steps):
+                    passes[s].append(denoised[s][0]
+                                     if s < len(denoised) else mine)
+            denoised = []
+        layers = {UNMASK_NAME: np.concatenate(unmask)[:, None]}
+        # (no routed layer: no entry but the unmask passes)
+        for s, parts in enumerate([written] + passes if routed else []):
+            for name, rows in zip(self._decision_layers, np.concatenate(
+                    parts, axis=1).astype(np.int32)):
+                layers[pass_layer_name(name, s - 1) if s else name] = rows
+        return {"first": record["first"], "layers": layers,
+                "block": size, "start": record["start"],
+                "tokens": np.concatenate(tokens).astype(np.int32)
+                if tokens else np.zeros((0,), np.int32)}
 
     def _count_experts(self, chosen) -> None:
         """A landed decode step's (row, choice) pairs, int [decision
@@ -2243,6 +2616,16 @@ class ContinuousBatcher:
         more token landed by the same step)."""
         return {"mtp_drafted": self.mtp_drafted,
                 "mtp_accepted": self.mtp_accepted}
+
+    def _block_counts(self) -> dict:
+        """A block-diffusion engine's cumulative counters, of the
+        landed block passes: the (slot, pass) pairs that denoised and
+        that committed, the positions the denoise passes unmasked, and
+        the tokens the commit passes landed (a first block's given
+        positions not counted). Tokens over passes is what the
+        schedule is worth: ``block`` over ``steps + 1`` at the
+        static rule's floor."""
+        return {name: getattr(self, name) for name in BLOCK_COUNTERS}
 
     def _expert_counts(self) -> dict:
         return {"expert_pairs_here": self.expert_pairs_here,
@@ -2707,18 +3090,36 @@ class ContinuousBatcher:
                         self._draft_params, self._draft_cache, i,
                         prompt, len(tokens))
             with phases("slot_update"):
-                # The prefill-sampled token IS the next generated
-                # token: the device seats it, the books count it in
-                # flight until _land_first has read it.
-                (self._key, self._tokens, self._positions,
-                 first) = self._seat_first(
-                    last_logits, self._key, self._tokens,
-                    self._positions, i, len(tokens))
-                if draft is not None:
-                    self._draft = _seat_draft(self._draft, i, draft)
-                self._slots[i] = _Slot(
-                    request=req, generated=list(entry.resumed),
-                    in_flight=1, launches=1)
+                if self.block:
+                    # No first token: the device seats the first
+                    # generated block, the prompt's tokens past its
+                    # whole blocks given and the rest masked (resumed
+                    # tokens count as the prompt's: a block they end
+                    # in is opened again with them given).
+                    start = _cached_len(self.config, len(tokens))
+                    opening = np.zeros((self.block,), np.int32)
+                    opening[:len(tokens) - start] = tokens[start:]
+                    (self._tokens, self._masked, self._positions,
+                     first) = _seat_block(
+                        last_logits, self._tokens, self._masked,
+                        self._positions, i, self._put(opening),
+                        len(tokens) - start, start)
+                    self._slots[i] = _Slot(
+                        request=req, generated=list(entry.resumed),
+                        given=len(tokens) - start)
+                else:
+                    # The prefill-sampled token IS the next generated
+                    # token: the device seats it, the books count it
+                    # in flight until _land_first has read it.
+                    (self._key, self._tokens, self._positions,
+                     first) = self._seat_first(
+                        last_logits, self._key, self._tokens,
+                        self._positions, i, len(tokens))
+                    if draft is not None:
+                        self._draft = _seat_draft(self._draft, i, draft)
+                    self._slots[i] = _Slot(
+                        request=req, generated=list(entry.resumed),
+                        in_flight=1, launches=1)
                 self._unread.append(_FirstToken(
                     first, i, path, bucket, t0, queued, *chosen,
                     prefilled=prefilled,

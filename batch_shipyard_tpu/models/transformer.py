@@ -33,6 +33,48 @@ from batch_shipyard_tpu.ops import attention as attn_ops
 from batch_shipyard_tpu.ops import paged_attention as paged_ops
 
 
+REMASK_RULES = ("low_confidence_static", "low_confidence_dynamic")
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """Generation by diffusion over blocks (TransformerConfig.
+    block_diffusion): the sequence is cut into blocks of ``block``
+    positions (a power of two), attention is BLOCK-causal (key j is
+    visible to query i iff j // block <= i // block: causal across
+    blocks, every key of the query's own block visible), the logit at
+    position i scores the token AT position i, and a block is generated
+    by iterated denoising: it opens as ``mask_id`` at every position
+    that is not given, each pass runs the whole block against the
+    cached blocks before it and unmasks, among the masked positions,
+    the ``block // steps`` most confident (``remask``
+    "low_confidence_static"), or every position whose confidence is
+    above ``threshold`` where those are at least as many
+    ("low_confidence_dynamic"); a block without a mask is committed by
+    one pass more, which keeps its K/V rows (models/serving.py:
+    _denoise_or_commit). Greedy: a position's token is its argmax, its
+    confidence that token's softmax probability.
+    ``bidirectional`` False keeps the plain causal mask inside a block
+    too, in the prefill and in the block step: not the model, but what
+    a check's control switches on."""
+    block: int = 4
+    steps: int = 4
+    remask: str = "low_confidence_static"
+    threshold: float = 0.9
+    mask_id: int = 0
+    bidirectional: bool = True
+
+    def __post_init__(self):
+        if self.block < 1 or self.block & (self.block - 1):
+            raise ValueError(f"block {self.block}: a power of two")
+        if not 1 <= self.steps <= self.block or self.block % self.steps:
+            raise ValueError(
+                f"steps {self.steps} does not divide block {self.block}")
+        if self.remask not in REMASK_RULES:
+            raise ValueError(f"remask {self.remask!r}: one of "
+                             f"{REMASK_RULES}")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -187,10 +229,25 @@ class TransformerConfig:
     # and k (None = use_rope).
     mtp_modules: int = 0
     mtp_rope: Optional[bool] = None
+    # Generation by diffusion over blocks (BlockDiffusion above): every
+    # attention layer's mask is block-causal, and a serving engine
+    # whose model has one generates a block of positions a slot by
+    # iterated denoising in place of one token a step
+    # (models/serving.py). None = autoregressive.
+    block_diffusion: Optional[BlockDiffusion] = None
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def attend_block(self) -> int:
+        """The block of a block-causal attention mask (0: plain
+        causal): block_diffusion's, unless its control keeps the mask
+        inside a block causal."""
+        diffusion = self.block_diffusion
+        return diffusion.block if diffusion is not None and \
+            diffusion.bidirectional else 0
 
 
 MIXER_KINDS = ("ssm", "delta", "attn", "experts", "mlp")
@@ -485,11 +542,12 @@ class Attention(nn.Module):
         attention_fn = cfg.attention_fn or (
             lambda q_, k_, v_, causal: attn_ops.attention(
                 q_, k_, v_, causal=causal))
-        if self.window:
-            # the band, over grouped K/V as they are
+        if self.window or cfg.attend_block:
+            # the band, or the block-causal mask, over grouped K/V as
+            # they are
             out = attn_ops.blockwise_mha(
                 q, k, v, causal=True, window=self.window,
-                block_size=math.gcd(seq, 512))
+                block_size=math.gcd(seq, 512), block=cfg.attend_block)
         else:
             if cfg.kv_heads != cfg.n_heads:
                 # The training-path kernels take one K/V head a query
@@ -604,12 +662,14 @@ class Attention(nn.Module):
                 return attn_ops.cached_prefill_attention(
                     q, cache_k.value, cache_v.value, idx,
                     window=self.window,
-                    softmax_dtype=cfg.attn_softmax_dtype).astype(
-                        cfg.dtype)
+                    softmax_dtype=cfg.attn_softmax_dtype,
+                    block=cfg.attend_block).astype(cfg.dtype)
             # Causal over absolute cache positions: query s (absolute
             # idx+s) sees keys <= idx+s — earlier chunks AND the
-            # causal prefix of this one.
-            visible = key_pos[None, None, :] <= cols[:, :, None]
+            # causal prefix of this one (of a block-diffusion model:
+            # the keys up to the end of its own block).
+            visible = key_pos[None, None, :] <= attn_ops.block_end(
+                cols, cfg.attend_block)[:, :, None]
             if self.window:
                 visible &= key_pos[None, None, :] > \
                     cols[:, :, None] - self.window
@@ -802,18 +862,26 @@ class Attention(nn.Module):
         v_pages.value = v_pages.value.at[page_idx, offset].set(
             v_in.astype(store_dtype).reshape(batch, seq, width))
         length.value = idx + seq
+        if seq > 1 and cfg.attend_block not in (0, seq):
+            raise ValueError(
+                f"a block-diffusion model's paged insert is one whole "
+                f"block of {cfg.attend_block} positions, not {seq}")
         if seq == 1 or (kv_heads != heads and not int8_kv):
             # one token, or a verify block over a grouped pool: the
             # kernel (the windowed gather elsewhere) reads each live
             # page once for all seq positions, position r masked to
-            # the keys up to its own
+            # the keys up to its own; or a block-diffusion model's
+            # block, whose seq positions (one whole block, the cursor
+            # on a block's edge) all see all the keys
             return paged_ops.paged_decode_attention(
                 q, k_pages.value, v_pages.value, block_table.value,
                 _live_lengths(length.value, live),
                 impl=cfg.paged_attention_impl,
                 k_scales=scale_k.value if int8_kv else None,
                 v_scales=scale_v.value if int8_kv else None,
-                softmax_dtype=cfg.attn_softmax_dtype).astype(cfg.dtype)
+                softmax_dtype=cfg.attn_softmax_dtype,
+                causal=not (seq > 1 and cfg.attend_block)).astype(
+                    cfg.dtype)
         # Multi-token verify pass over an MHA (or int8) pool: gather
         # the slot's full logical view
         # and attend causally over absolute cache positions (query s
@@ -837,8 +905,8 @@ class Attention(nn.Module):
                      vs_all[..., None]).astype(cfg.dtype)
         key_pos = jax.lax.broadcasted_iota(
             jnp.int32, (max_blocks * page, 1), 0)[:, 0]
-        mask = (key_pos[None, None, :] <=
-                cols[:, :, None])[:, None, :, :]      # [B, 1, S, T]
+        mask = (key_pos[None, None, :] <= attn_ops.block_end(
+            cols, cfg.attend_block)[:, :, None])[:, None, :, :]
         return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
 
 

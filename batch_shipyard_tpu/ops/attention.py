@@ -51,11 +51,27 @@ def flash_shapes_ok(t_q: int, t_kv: int) -> bool:
     return ok(t_q, FLASH_BLOCK_Q) and ok(t_kv, FLASH_BLOCK_K)
 
 
-def _causal_mask(q_positions, k_positions, window: int = 0):
+def block_end(positions, block: int):
+    """The last position of the block of ``block`` positions (a power
+    of two) that each of ``positions`` lies in: what a block-causal
+    mask compares a key with in place of the query's own position.
+    ``block`` 0: the positions themselves (plain causal)."""
+    if not block:
+        return positions
+    if block & (block - 1):
+        raise ValueError(f"block {block} is not a power of two")
+    return positions | (block - 1)
+
+
+def _causal_mask(q_positions, k_positions, window: int = 0,
+                 block: int = 0):
     """[Tq, Tk] True where attention is allowed: k <= q, and under a
     ``window`` also k > q - window (the query's own key included, so a
-    query sees its ``window`` newest keys)."""
-    mask = q_positions[:, None] >= k_positions[None, :]
+    query sees its ``window`` newest keys). ``block`` > 0 is the
+    BLOCK-causal mask: key j is visible to query i iff
+    j // block <= i // block (causal across blocks of ``block``
+    positions, every key of the query's own block visible)."""
+    mask = block_end(q_positions, block)[:, None] >= k_positions[None, :]
     if window:
         mask &= k_positions[None, :] > q_positions[:, None] - window
     return mask
@@ -92,10 +108,11 @@ def _grouped_values(p, v):
 
 def mha_reference(q, k, v, causal: bool = True,
                   q_offset: int = 0, kv_offset: int = 0,
-                  window: int = 0):
+                  window: int = 0, block: int = 0):
     """Plain attention; the numerics oracle for the fast paths. k/v
     may hold fewer heads than q (grouped-query); ``window`` (causal
-    only) bounds each query to its newest ``window`` keys."""
+    only) bounds each query to its newest ``window`` keys; ``block``
+    (causal only) is _causal_mask's block-causal form."""
     depth = q.shape[-1]
     scores = _grouped_scores(q, k)
     scores = scores / math.sqrt(depth)
@@ -104,7 +121,7 @@ def mha_reference(q, k, v, causal: bool = True,
             jnp.int32, (q.shape[1], 1), 0)[:, 0]
         k_pos = kv_offset + jax.lax.broadcasted_iota(
             jnp.int32, (k.shape[1], 1), 0)[:, 0]
-        mask = _causal_mask(q_pos, k_pos, window)
+        mask = _causal_mask(q_pos, k_pos, window, block)
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return _grouped_values(probs.astype(v.dtype), v).astype(q.dtype)
@@ -114,14 +131,15 @@ def mha_reference(q, k, v, causal: bool = True,
 
 def attention_block_update(q, k_blk, v_blk, o, m, l, *, causal: bool,
                            q_offset, kv_offset, scale: float,
-                           window: int = 0):
+                           window: int = 0, block: int = 0):
     """One online-softmax accumulation step against a KV block.
 
     q: [B, Tq, H, D]; k_blk/v_blk: [B, Tk, Hkv, D] (Hkv divides H)
     o: [B, Tq, H, D] float32 numerator
     m: [B, H, Tq] running max; l: [B, H, Tq] running denominator.
     q_offset/kv_offset: global positions (ints or traced scalars).
-    ``window`` (causal only): a query sees its newest ``window`` keys.
+    ``window`` (causal only): a query sees its newest ``window`` keys;
+    ``block`` (causal only): _causal_mask's block-causal form.
     """
     scores = _grouped_scores(q, k_blk) * scale
     if causal:
@@ -129,7 +147,7 @@ def attention_block_update(q, k_blk, v_blk, o, m, l, *, causal: bool,
             jnp.int32, (q.shape[1], 1), 0)[:, 0]
         k_pos = kv_offset + jax.lax.broadcasted_iota(
             jnp.int32, (k_blk.shape[1], 1), 0)[:, 0]
-        mask = _causal_mask(q_pos, k_pos, window)
+        mask = _causal_mask(q_pos, k_pos, window, block)
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     m_blk = jnp.max(scores, axis=-1)
     m_new = jnp.maximum(m, m_blk)
@@ -162,11 +180,12 @@ def attention_finalize(q, o, m, l):
 
 def blockwise_mha(q, k, v, causal: bool = True, block_size: int = 512,
                   q_offset: int = 0, kv_offset: int = 0,
-                  window: int = 0):
+                  window: int = 0, block: int = 0):
     """Memory-efficient attention: scan KV blocks with online softmax.
     k/v may hold fewer heads than q (grouped-query: no row repeated);
     ``window`` (causal only) is the band: a query sees its newest
-    ``window`` keys."""
+    ``window`` keys; ``block`` (causal only) the block-causal mask of
+    _causal_mask."""
     batch, t_kv = k.shape[0], k.shape[1]
     block_size = min(block_size, t_kv)
     if t_kv % block_size:
@@ -190,7 +209,7 @@ def blockwise_mha(q, k, v, causal: bool = True, block_size: int = 512,
             q, k_blk, v_blk, o, m, l, causal=causal,
             q_offset=q_offset,
             kv_offset=kv_offset + blk_idx * block_size, scale=scale,
-            window=window)
+            window=window, block=block)
         return (o, m, l), None
 
     carry = attention_init(q)
@@ -623,7 +642,8 @@ def _band_blocks(start, first_q: int, last_q: int, window: int,
 def cached_prefill_attention_xla(q, k_cache, v_cache, start,
                                  window: int = 0,
                                  block_k: int = PREFILL_BLOCK_K,
-                                 softmax_dtype=jnp.float32):
+                                 softmax_dtype=jnp.float32,
+                                 block: int = 0):
     """q [B, S, H, D] at positions start[b] .. start[b] + S - 1
     against cache rows k_cache / v_cache [B, T, Hkv*D] -> [B, S, H, D].
     A loop (dynamic trip count) over the key blocks between the first
@@ -631,7 +651,10 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
     last its last query reaches, online softmax across them (its
     scores and running terms kept in ``softmax_dtype``:
     kept_in): the XLA formulation, and the oracle
-    of the kernel below."""
+    of the kernel below. ``block`` > 0: the block-causal mask
+    (_causal_mask's: a query sees every key up to the end of its own
+    block of ``block`` positions; ``start`` is then a whole number of
+    blocks)."""
     batch, seq, heads, depth = q.shape
     rows = k_cache.shape[1]
     kv_heads = k_cache.shape[2] // depth
@@ -642,7 +665,8 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
     # last the latest query reaches
     first, _ = _band_blocks(jnp.min(start), 0, 0, window, block_k,
                             rows // block_k)
-    _, last = _band_blocks(jnp.max(start), 0, seq - 1, window, block_k,
+    _, last = _band_blocks(jnp.max(start), 0,
+                           block_end(seq - 1, block), window, block_k,
                            rows // block_k)
     q_pos = start[:, None] + jnp.arange(seq, dtype=jnp.int32)[None]
 
@@ -652,7 +676,7 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
                 batch, block_k, kv_heads, depth)
             for cache in (k_cache, v_cache))
         k_pos = kb * block_k + jnp.arange(block_k, dtype=jnp.int32)
-        mask = k_pos[None, None, :] <= q_pos[:, :, None]
+        mask = k_pos[None, None, :] <= block_end(q_pos, block)[:, :, None]
         if window:
             mask &= k_pos[None, None, :] > q_pos[:, :, None] - window
         o, m, l = carry
@@ -676,7 +700,7 @@ def _flash_prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
                           o_acc, m_acc, l_acc, *, block_q: int,
                           block_k: int, group: int, depth: int,
                           window: int, num_kb: int, scale: float,
-                          softmax_dtype):
+                          softmax_dtype, block: int = 0):
     """One (batch, K/V head, query block, key step) program: the
     query block's ``group`` heads, stacked along the rows, against one
     key block; online softmax across the key steps in VMEM scratch.
@@ -709,7 +733,7 @@ def _flash_prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, (block_q, block_k), 0)
         k_pos = (first + j) * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        mask = k_pos <= q_pos
+        mask = k_pos <= block_end(q_pos, block)
         if window:
             mask &= k_pos > q_pos - window
         mask = jnp.concatenate([mask] * group, axis=0)
@@ -749,13 +773,19 @@ def prefill_kernel_shapes_ok(seq: int, rows: int, depth: int) -> bool:
 def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
                                     window: int = 0,
                                     interpret: bool = False,
-                                    softmax_dtype=jnp.float32):
+                                    softmax_dtype=jnp.float32,
+                                    block: int = 0):
     """cached_prefill_attention_xla as a Pallas kernel: scores stay in
     VMEM, a K/V head's ``group`` query heads share each key block's
     one read, and a query block visits only the key blocks its band
     touches (``window`` > 0: ceil((window + block_q) / block_k) + 1 of
-    them whatever the cache's length)."""
+    them whatever the cache's length). ``block``: the XLA form's; a
+    query block is whole blocks of it, so the key blocks it visits
+    are the causal mask's."""
     batch, seq, heads, depth = q.shape
+    if block and min(PREFILL_BLOCK_Q, seq) % block:
+        raise ValueError(f"a query block of {min(PREFILL_BLOCK_Q, seq)} "
+                         f"rows is not whole blocks of {block}")
     rows = k_cache.shape[1]
     kv_heads = k_cache.shape[2] // depth
     group = heads // kv_heads
@@ -791,7 +821,8 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
             _flash_prefill_kernel, block_q=block_q, block_k=block_k,
             group=group, depth=depth, window=int(window),
             num_kb=num_kb, scale=1.0 / math.sqrt(depth),
-            softmax_dtype=softmax_dtype),
+            softmax_dtype=softmax_dtype,
+            **({"block": int(block)} if block else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, seq, heads * depth),
                                        q.dtype),
@@ -808,9 +839,11 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
 def cached_prefill_attention(q, k_cache, v_cache, start,
                              window: int = 0,
                              impl: Optional[str] = None,
-                             softmax_dtype=jnp.float32):
+                             softmax_dtype=jnp.float32,
+                             block: int = 0):
     """Dispatch: 'kernel' (Pallas) on a TPU backend where its blocks
-    tile the shapes, else 'xla'; a named impl passes through."""
+    tile the shapes, else 'xla'; a named impl passes through.
+    ``block`` > 0: block-causal (cached_prefill_attention_xla)."""
     if impl is None:
         impl = "kernel" if (
             jax.default_backend() == "tpu" and prefill_kernel_shapes_ok(
@@ -818,9 +851,10 @@ def cached_prefill_attention(q, k_cache, v_cache, start,
     if impl == "kernel":
         return cached_prefill_attention_kernel(
             q, k_cache, v_cache, start, window,
-            softmax_dtype=softmax_dtype)
+            softmax_dtype=softmax_dtype, block=block)
     if impl != "xla":
         raise ValueError(f"unknown prefill attention impl {impl!r}")
     return cached_prefill_attention_xla(q, k_cache, v_cache, start,
                                         window,
-                                        softmax_dtype=softmax_dtype)
+                                        softmax_dtype=softmax_dtype,
+                                        block=block)

@@ -382,7 +382,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
                              o_ref, k_buf, v_buf, sems, *, page: int,
                              chunk: int, heads: int, kv_heads: int,
                              depth: int, window: int, scale: float,
-                             softmax_dtype, positions: int = 1):
+                             softmax_dtype, positions: int = 1,
+                             causal: bool = True):
     """One slot: online softmax over its live pages, a chunk of
     ``chunk`` pages a step of the inner loop, its scores and running
     terms kept in ``softmax_dtype``. ``positions`` > 1: the slot's
@@ -390,7 +391,10 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
     block): row r * heads + h is head h of the query at key position
     length - positions + r, masked to the keys up to its own and, in
     a window layer, to its own newest ``window``; every live page is
-    still read once. A slot of length 0 (one without a request: the
+    still read once. ``causal`` False (``positions`` > 1, no window):
+    a block whose queries ALL see all ``length`` keys, its own
+    ``positions`` among them (a block denoised as one: nothing to mask
+    by row). A slot of length 0 (one without a request: the
     serving step's ``live`` mask, Attention._decode_attend_paged)
     writes its zero output block and does nothing else: no DMA is
     started, so none is left to wait for."""
@@ -478,7 +482,7 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
             scores = kept_in(scores, softmax_dtype)  # [rows, span]
             pos = (first + c * chunk) * page + \
                 jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            if positions == 1:
+            if positions == 1 or not causal:
                 visible = (pos >= low) & (pos < length)
             else:
                 # row r * heads + h: keys below its own position + 1
@@ -513,13 +517,15 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("window", "softmax_dtype", "name"),
+                   static_argnames=("window", "softmax_dtype", "name",
+                                    "causal"),
                    inline=True)
 def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                       lengths, window: int = 0,
                                       softmax_dtype=jnp.float32,
                                       name: Optional[str] =
-                                      GQA_KERNEL_NAME):
+                                      GQA_KERNEL_NAME,
+                                      causal: bool = True):
     """Pallas path for a pool of Hkv <= H K/V heads. q: [B, S, H, D];
     k_pages/v_pages: [P, page, Hkv*D]; lengths: [B] valid-key counts
     (the S tokens written this step included). S == 1 is the decode
@@ -528,7 +534,8 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     a slot against each live page, read ONCE). ``window`` > 0: a query
     sees its newest ``window`` keys alone (for S == 1 positions
     length - window .. length - 1), and no page wholly behind them is
-    read.
+    read. ``causal`` False (S > 1, no window): every query of the
+    block sees all ``lengths`` keys, the block's own S included.
     block_table: [B, T] int32, entry p % T the page of logical page p:
     a table as wide as the context is an ordinary block table, a
     narrower one a RING (its T pages hold the newest T logical pages;
@@ -546,6 +553,9 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     batch, seq, heads, depth = q.shape
     page, width = k_pages.shape[1], k_pages.shape[2]
     kv_heads = width // depth
+    if not causal and window:
+        raise NotImplementedError(
+            "a block whose queries see all keys, under a window")
     if heads % kv_heads or kv_heads * depth != width:
         raise ValueError(
             f"{heads} query heads over a pool of {width} channels "
@@ -575,7 +585,8 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
             heads=heads, kv_heads=kv_heads, depth=depth,
             window=int(window), scale=1.0 / (depth ** 0.5),
             softmax_dtype=softmax_dtype,
-            **({} if seq == 1 else {"positions": seq})),
+            **({} if seq == 1 else {"positions": seq}),
+            **({} if causal or seq == 1 else {"causal": False})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, rows, depth), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -589,17 +600,22 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
 def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
                                         block_table, lengths,
                                         window: int = 0,
-                                        softmax_dtype=jnp.float32):
+                                        softmax_dtype=jnp.float32,
+                                        causal: bool = True):
     """The kernel above as an XLA gather (the CPU/fallback path and
     the tests' oracle): every table entry's page gathered, each row's
     POSITION worked out from the entry it came through (entry c holds
     the newest logical page p <= the last with p % T == c: the ring
     rule, which for a table as wide as the context is p == c), and one
     masked softmax over the positions the window admits; query r of S
-    at key position length - S + r. A slot of length 0 yields zeros."""
+    at key position length - S + r (``causal`` False: every query r
+    sees all ``lengths`` keys). A slot of length 0 yields zeros."""
     batch, seq, heads, depth = q.shape
     page = k_pages.shape[1]
     entries = block_table.shape[1]
+    if not causal and window:
+        raise NotImplementedError(
+            "a block whose queries see all keys, under a window")
     kv_heads = k_pages.shape[2] // depth
     k_all = k_pages[block_table].reshape(
         batch, entries * page, kv_heads, depth)
@@ -621,6 +637,8 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
     # [B, S]: the keys query r sees are those below upper[:, r]
     upper = lengths[:, None] - (seq - 1) + jnp.arange(
         seq, dtype=jnp.int32)[None, :]
+    if not causal:
+        upper = jnp.broadcast_to(lengths[:, None], upper.shape)
     low = window_start(upper, window)
     pos = pos[:, None, :]
     visible = (pos >= low[:, :, None]) & (pos < upper[:, :, None]) & (
@@ -682,7 +700,10 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
     named "kernel" raises NotImplementedError; under a window every
     int8 call does. ``positions`` > 1 (a verify block: several query
     positions a slot) is the last row's for any pool: gqa_kernel and
-    xla_windowed alone mask by query position."""
+    xla_windowed alone mask by query position, and they alone take a
+    block whose queries all see all keys (the dispatch's ``causal``
+    False: a block denoised as one, Attention._decode_attend_paged
+    of a model with TransformerConfig.block_diffusion)."""
     want = resolve_paged_impl(impl)
     if _plain_call(grouped, window, positions) and (
             int8 or want == "xla"):
@@ -699,15 +720,19 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            impl: Optional[str] = None,
                            k_scales=None, v_scales=None,
-                           window: int = 0, softmax_dtype=jnp.float32):
+                           window: int = 0, softmax_dtype=jnp.float32,
+                           causal: bool = True):
     """Dispatch by paged_decode_road (the selection rule's one table).
     k_scales/v_scales switch an MHA pool to its int8 kernel and the
     plain gather to int8-page dequant. ``window`` > 0: a layer that
     sees its newest ``window`` keys alone; its table may then be a
     RING narrower than the context, entry p % T the page of logical
     page p. q of S > 1 positions a slot is a verify block (the last S
-    keys are the queries' own). The grouped kernel and the windowed
-    gather alone keep their softmax in ``softmax_dtype`` (kept_in).
+    keys are the queries' own; ``causal`` False: every query of the
+    block sees all ``lengths`` keys, where the verify block masks
+    query r to the keys up to its own). The grouped kernel and the
+    windowed gather alone keep their softmax in ``softmax_dtype``
+    (kept_in).
     On every road a slot of length 0 yields zeros, and on the kernels'
     costs nothing: that is how a serving step hands over a slot
     without a request (the ``live`` mask of
@@ -725,11 +750,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
         return gqa_paged_decode_attention_kernel(
             q, k_pages, v_pages, block_table, lengths, window=window,
             softmax_dtype=softmax_dtype,
-            name=None if plain else GQA_KERNEL_NAME)
+            name=None if plain else GQA_KERNEL_NAME, causal=causal)
     if road == "xla_windowed":
         return paged_decode_attention_xla_windowed(
             q, k_pages, v_pages, block_table, lengths, window=window,
-            softmax_dtype=softmax_dtype)
+            softmax_dtype=softmax_dtype, causal=causal)
     if road == "kernel":
         return paged_decode_attention_kernel(
             q, k_pages, v_pages, block_table, lengths, k_scales,

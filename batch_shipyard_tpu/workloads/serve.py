@@ -170,9 +170,10 @@ def build_engine(args, config=None, params=None,
 
 
 def paged_decode_impl(config: tfm.TransformerConfig) -> str:
-    """What the engine's decode attention runs (one token a slot, or
-    a verify block of 1 + mtp_modules where the model carries its own
-    drafter), as the dispatch itself decides it
+    """What the engine's decode attention runs (one token a slot, a
+    verify block of 1 + mtp_modules where the model carries its own
+    drafter, or a whole block where it generates by diffusion over
+    blocks), as the dispatch itself decides it
     (ops/paged_attention.paged_decode_road) from the pool's grouping,
     each attention layer's window, the pages' type and the query
     positions a slot: one name, or one a kind of layer joined by "+"
@@ -182,7 +183,8 @@ def paged_decode_impl(config: tfm.TransformerConfig) -> str:
             config.paged_attention_impl,
             grouped=config.kv_heads != config.n_heads, window=window,
             int8=config.kv_cache_dtype == "int8",
-            positions=1 + config.mtp_modules)
+            positions=config.block_diffusion.block
+            if config.block_diffusion else 1 + config.mtp_modules)
         for window in tfm.attention_windows(config)}))
 
 
@@ -402,6 +404,9 @@ def main(argv=None) -> int:
     # the model's own drafter: multi-token-prediction modules verified
     # in every decode step (0: one token a slot a step)
     report["mtp_modules"] = fronts[0].engine.config.mtp_modules
+    # generation by diffusion over blocks: the block a step denoises
+    # or commits a slot (0: one token a slot a step)
+    report["diffusion_block"] = fronts[0].engine.block
     if router is not None:
         report["router"] = router.stats()
     prefix = [f.engine.prefix_stats() for f in fronts]

@@ -416,3 +416,81 @@ def test_blockwise_and_reference_take_a_band_and_grouped_kv(window):
             np.asarray(want[0, 40, 0]),
             (probs / probs.sum()) @ np.asarray(v[0, 31:41, 0]),
             atol=1e-6)
+
+
+# ---- the block-causal mask (a model that generates by diffusion
+# ---- over blocks: TransformerConfig.block_diffusion) ----
+
+def _block_oracle(q, k, v, start, block):
+    """One masked softmax over the whole cache: key j is visible to
+    the query at position i iff j // block <= i // block."""
+    batch, seq, heads, depth = q.shape
+    rows = k.shape[1]
+    kv_heads = k.shape[2] // depth
+    q_pos = np.asarray(start)[:, None] + np.arange(seq)[None]
+    mask = (np.arange(rows)[None, None, :] // block
+            <= q_pos[:, :, None] // block)
+    scores = attn._grouped_scores(
+        q, k.reshape(batch, rows, kv_heads, depth)) / np.sqrt(depth)
+    probs = jax.nn.softmax(
+        jnp.where(mask[:, None], scores, -1e30), axis=-1)
+    return attn._grouped_values(
+        probs, v.reshape(batch, rows, kv_heads, depth))
+
+
+def test_the_block_causal_mask_by_hand():
+    got = np.asarray(attn._causal_mask(jnp.arange(6), jnp.arange(8),
+                                       block=4))
+    want = np.asarray([[j // 4 <= i // 4 for j in range(8)]
+                       for i in range(6)])
+    assert (got == want).all()
+    assert (np.asarray(attn.block_end(jnp.arange(9), 4))
+            == [3, 3, 3, 3, 7, 7, 7, 7, 11]).all()
+    assert (np.asarray(attn.block_end(jnp.arange(5), 0))
+            == np.arange(5)).all()          # 0: plain causal
+    with pytest.raises(ValueError, match="power of two"):
+        attn.block_end(jnp.arange(4), 3)
+
+
+@pytest.mark.parametrize("start", ([0, 0], [256, 512], [4, 700]))
+def test_cached_prefill_attention_under_the_block_causal_mask(
+        start, monkeypatch):
+    """A segment of 256 queries at positions start .. (whole blocks of
+    4) against a cache of 1,024 rows: the XLA loop and the Pallas
+    kernel (interpret mode, blocks of 128) against one masked softmax
+    over the whole cache; a query's block reaches up to three keys
+    past its own position, and past the segment's last key block where
+    the segment ends inside one."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.RandomState(len(start))
+    q = jnp.asarray(rng.randn(2, 256, 4, 128), jnp.float32)
+    k = jnp.asarray(rng.randn(2, 1024, 256), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 1024, 256), jnp.float32)
+    start = jnp.asarray(start, jnp.int32)
+    want = np.asarray(_block_oracle(q, k, v, start, 4))
+    causal = np.asarray(attn.cached_prefill_attention_xla(
+        q, k, v, start, block_k=128))
+    assert np.abs(causal - want).max() > 1e-2   # it is another mask
+    got = attn.cached_prefill_attention_xla(q, k, v, start, block_k=128,
+                                            block=4)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-6)
+    monkeypatch.setattr(attn, "PREFILL_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "PREFILL_BLOCK_K", 128)
+    with pltpu.force_tpu_interpret_mode():
+        got = attn.cached_prefill_attention_kernel(q, k, v, start,
+                                                   block=4)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-6)
+
+
+def test_blockwise_and_reference_take_the_block_causal_mask():
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(2, 64, 4, 32), jnp.float32)
+    k = jnp.asarray(rng.randn(2, 64, 2, 32), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 64, 2, 32), jnp.float32)
+    want = np.asarray(_block_oracle(
+        q, k.reshape(2, 64, 64), v.reshape(2, 64, 64),
+        jnp.zeros((2,), jnp.int32), 8))
+    np.testing.assert_allclose(np.asarray(attn.mha_reference(
+        q, k, v, block=8)), want, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(attn.blockwise_mha(
+        q, k, v, block_size=16, block=8)), want, atol=2e-6)

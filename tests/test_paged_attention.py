@@ -709,3 +709,65 @@ def test_the_models_live_mask_parks_a_slot_at_any_cursor(
                                           np.asarray(want))
             if key == "length":
                 assert list(np.asarray(got)) == [2, 2, 2]
+
+
+# ---- a block whose queries all see all keys (a model that generates
+# ---- by diffusion over blocks: paged_decode_attention's causal=False)
+
+@pytest.mark.parametrize("grouping", ("7to1", "mha", "sdar"))
+def test_a_block_whose_queries_all_see_all_keys(interpret_mode,
+                                                grouping):
+    """Four query positions a slot, every one of which sees all
+    ``length`` keys (the block's own four among them): the kernel and
+    its XLA twin against four ONE-position calls at the same length,
+    which is what such a block is; the verify block's mask (query r up
+    to its own key) is another result. "sdar": the cell's attention as
+    it serves it (32 query over 4 K/V heads of 128, pages of 64)."""
+    heads, kv_heads, case = {**_GROUPINGS, "sdar": (32, 4, _SERVED)}[
+        grouping]
+    rng = np.random.RandomState(heads)
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.float32, heads, kv_heads, **case["shape"])
+    q = jnp.asarray(rng.randn(q.shape[0], 4, heads, q.shape[3]),
+                    jnp.float32)
+    lengths = jnp.asarray([0] + [max(4, n) for n in case["lengths"][1:]],
+                          jnp.int32)
+    want = np.concatenate([np.asarray(
+        pa.paged_decode_attention_xla_windowed(
+            q[:, r:r + 1], k_pages, v_pages, table, lengths))
+        for r in range(4)], axis=1)
+    xla = pa.paged_decode_attention_xla_windowed(
+        q, k_pages, v_pages, table, lengths, causal=False)
+    got = pa.gqa_paged_decode_attention_kernel(
+        q, k_pages, v_pages, table, lengths, causal=False)
+    np.testing.assert_allclose(np.asarray(xla), want, atol=2e-6,
+                               rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-6,
+                               rtol=2e-6)
+    assert not np.asarray(got)[0].any()     # a slot at length 0: zeros
+    verify = pa.gqa_paged_decode_attention_kernel(
+        q, k_pages, v_pages, table, lengths)
+    assert np.abs(np.asarray(verify) - want)[1:, :3].max() > 1e-3
+    # the last position's mask is the same in both
+    np.testing.assert_allclose(np.asarray(verify)[:, 3], want[:, 3],
+                               atol=2e-6, rtol=2e-6)
+    # through the dispatch, by the road it names
+    assert pa.paged_decode_road("kernel", grouped=heads != kv_heads,
+                                positions=4) == "gqa_kernel"
+    np.testing.assert_allclose(np.asarray(pa.paged_decode_attention(
+        q, k_pages, v_pages, table, lengths, impl="kernel",
+        causal=False)), want, atol=2e-6, rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(pa.paged_decode_attention(
+        q, k_pages, v_pages, table, lengths, impl="xla",
+        causal=False)), want, atol=2e-6, rtol=2e-6)
+
+
+def test_a_block_of_all_keys_takes_no_window():
+    q = jnp.zeros((1, 4, 4, 64), jnp.float32)
+    pool = jnp.zeros((8, 8, 128), jnp.float32)
+    table = jnp.zeros((1, 4), jnp.int32)
+    for call in (pa.gqa_paged_decode_attention_kernel,
+                 pa.paged_decode_attention_xla_windowed):
+        with pytest.raises(NotImplementedError, match="window"):
+            call(q, pool, pool, table, jnp.asarray([8]), window=5,
+                 causal=False)

@@ -88,23 +88,24 @@ def _qkv(shape, dtype):
     return [(shape, dtype)] * 3
 
 
-def _gqa_paged(window, softmax_dtype=f32):
+def _gqa_paged(window, softmax_dtype=f32, causal=True):
     return lambda q, k, v, table, lengths: \
         pa.gqa_paged_decode_attention_kernel(
             q, k, v, table, lengths, window=window,
-            softmax_dtype=softmax_dtype)
+            softmax_dtype=softmax_dtype, causal=causal)
 
 
 def _gqa_args(pages, entries, dtype=bf16, batch=48, heads=28,
-              kv_heads=4, depth=128, page=64):
+              kv_heads=4, depth=128, page=64, positions=1):
     pool = ((pages, page, kv_heads * depth), dtype)
-    return [((batch, 1, heads, depth), dtype), pool, pool,
+    return [((batch, positions, heads, depth), dtype), pool, pool,
             ((batch, entries), i32), ((batch,), i32)]
 
 
-def _flash_prefill(window, softmax_dtype=f32):
+def _flash_prefill(window, softmax_dtype=f32, block=0):
     return lambda q, k, v, start: attn.cached_prefill_attention_kernel(
-        q, k, v, start, window, softmax_dtype=softmax_dtype)
+        q, k, v, start, window, softmax_dtype=softmax_dtype,
+        block=block)
 
 
 def _prefill_args(seq, rows=16384, heads=28, kv_heads=4, depth=128):
@@ -194,6 +195,17 @@ CASES = [
      _gqa_args(48 * 65, 65)),
     ("flash_prefill_window_bf16_softmax", _flash_prefill(4096, bf16),
      _prefill_args(4096)),
+    # the block-diffusion configuration's published shapes (32 query
+    # over 4 K/V heads of 128, 96 slots, a pool of 4,608 pages behind a
+    # 129-entry table): a block of 4 positions whose queries all see
+    # all keys, and its prefill under the block-causal mask
+    ("gqa_paged_block_all_keys", _gqa_paged(0, causal=False),
+     _gqa_args(4609, 129, batch=96, heads=32, positions=4)),
+    ("gqa_paged_block_all_keys_bf16_softmax",
+     _gqa_paged(0, bf16, causal=False),
+     _gqa_args(4609, 129, batch=96, heads=32, positions=4)),
+    ("flash_prefill_block_causal", _flash_prefill(0, block=4),
+     _prefill_args(2048, rows=8192, heads=32)),
     ("ring_all_gather_virtual", rc.ring_all_gather_virtual,
      [((4, 128, 128), f32)]),
     ("ring_reduce_scatter_virtual", rc.ring_reduce_scatter_virtual,
