@@ -285,7 +285,12 @@ class PagePool:
         seated slot and none for an idle one, which the step's mask
         hands the kernel as length 0 (slots_total - slots_active of
         the engine's occupancy is the programs a layer skips).
-        ceil(live_tokens / page) is the least a kernel could do."""
+        ceil(live_tokens / page) is the least a kernel could do.
+        kv_first_chunks_prefetched is how often that call's hand-over
+        engages: every seated slot but the first finds its first
+        chunk of pages fetched behind the last chunk of the seated
+        slot before it (ops/paged_attention._gqa_paged_decode_kernel),
+        however many idle slots lie between them."""
         return {
             "kv_pages_in_use": len(
                 {page for held in self._slot_pages + self._slot_shared
@@ -296,6 +301,7 @@ class PagePool:
             "prefix_index_pages": len(self._page_ref),
             "kv_blocks_attended": sum(
                 self.pages_for(tokens) for tokens in held_tokens),
+            "kv_first_chunks_prefetched": max(len(held_tokens) - 1, 0),
         }
 
     def check(self) -> None:

@@ -1248,6 +1248,10 @@ class ServingFrontEnd:
             "queue_depth": engine["queued"],
             "kv_pages_in_use": engine.get("kv_pages_in_use"),
             "kv_pages_total": engine.get("kv_pages_total"),
+            # the first chunks a layer's next paged decode call
+            # fetches behind another slot's last (a paged engine)
+            "kv_first_chunks_prefetched":
+                engine.get("kv_first_chunks_prefetched"),
             # the window layers' page group (a model with such layers)
             "window_pages_in_use": engine.get("window_pages_in_use"),
             "window_pages_total": engine.get("window_pages_total"),

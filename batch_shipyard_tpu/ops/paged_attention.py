@@ -378,12 +378,12 @@ def _row_positions(shape: tuple, heads: int, positions: int):
     return out
 
 
-def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
-                             o_ref, k_buf, v_buf, sems, *, page: int,
-                             chunk: int, heads: int, kv_heads: int,
-                             depth: int, window: int, scale: float,
-                             softmax_dtype, positions: int = 1,
-                             causal: bool = True):
+def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
+                             v_hbm, o_ref, k_buf, v_buf, sems, carry, *,
+                             page: int, chunk: int, heads: int,
+                             kv_heads: int, depth: int, window: int,
+                             scale: float, softmax_dtype,
+                             positions: int = 1, causal: bool = True):
     """One slot: online softmax over its live pages, a chunk of
     ``chunk`` pages a step of the inner loop, its scores and running
     terms kept in ``softmax_dtype``. ``positions`` > 1: the slot's
@@ -394,14 +394,27 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
     still read once. ``causal`` False (``positions`` > 1, no window):
     a block whose queries ALL see all ``length`` keys, its own
     ``positions`` among them (a block denoised as one: nothing to mask
-    by row). A slot of length 0 (one without a request: the
-    serving step's ``live`` mask, Attention._decode_attend_paged)
-    writes its zero output block and does nothing else: no DMA is
-    started, so none is left to wait for."""
+    by row).
+
+    The hand-over between programs: k_buf, v_buf, the semaphores and
+    ``carry`` (SMEM: [0] the buffer half the next seated slot's chunk
+    0 arrives in, [1] whether it is in flight) persist from program to
+    program. While a slot attends its LAST chunk it starts chunk 0 of
+    the next seated slot (``next_ref[b]``, the batch's size if there
+    is none) into the half that chunk does not hold; that slot's
+    program finds ``carry[1]`` set, starts nothing and waits for the
+    same copies, built from ITS table row and length on both sides
+    (``copies``). Only the first seated slot of a call starts its own
+    chunk 0, and the last starts nobody's: nothing is left in flight.
+    A slot of length 0 (one without a request: the serving step's
+    ``live`` mask, Attention._decode_attend_paged) writes its zero
+    output block and touches nothing else: no DMA started, none waited
+    for, ``carry`` and the semaphores as it found them, so a fetch in
+    flight passes over it to the slot it is for."""
     b = pl.program_id(0)
+    batch = pl.num_programs(0)
     rows = q_ref.shape[0]
     table_width = table_ref.shape[1]
-    span = chunk * page
 
     @pl.when(b == 0)
     def _clear():
@@ -409,42 +422,53 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
         # what lies there is masked by position, and must be finite
         k_buf[...] = jnp.zeros_like(k_buf)
         v_buf[...] = jnp.zeros_like(v_buf)
+        carry[0] = 0
+        carry[1] = 0
+
+    def live_pages(who):
+        """Of slot ``who``: the lowest key ANY of its query positions
+        sees (the first's), and the first and last logical page its
+        keys lie in (the last read under length > 0 alone)."""
+        upto = len_ref[who]
+        lowest = jnp.maximum(upto - (positions - 1 + window), 0) \
+            if window else 0
+        return lowest, (lowest // page, (upto - 1) // page)
 
     length = len_ref[b]
-    # the lowest key ANY query position sees (the first's)
-    low = jnp.maximum(length - (positions - 1 + window), 0) \
-        if window else 0
-    first = low // page
-    # (read under length > 0 alone)
-    last = (length - 1) // page
+    low, mine = live_pages(b)
+    first, last = mine
     chunks = (last - first) // chunk + 1
 
-    def copies(c, slot):
+    def copies(who, pages, c, half):
+        """Chunk ``c`` of slot ``who``, whose live pages are ``pages``,
+        into buffer half ``half``: the same descriptors for whoever
+        starts them and whoever waits."""
+        start_page, end = pages
         out = []
         for i in range(chunk):
-            logical = first + c * chunk + i
+            logical = start_page + c * chunk + i
             # a table narrower than the context is a RING: logical
             # page p lives in entry p % width
-            pid = table_ref[b, jax.lax.rem(
-                jnp.minimum(logical, last), table_width)]
+            pid = table_ref[who, jax.lax.rem(
+                jnp.minimum(logical, end), table_width)]
             dst = pl.ds(i * page, page)
-            out.append((logical <= last, (
+            out.append((logical <= end, (
                 pltpu.make_async_copy(
-                    k_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]),
+                    k_hbm.at[pid], k_buf.at[half, dst], sems.at[0, half]),
                 pltpu.make_async_copy(
-                    v_hbm.at[pid], v_buf.at[slot, dst],
-                    sems.at[1, slot]))))
+                    v_hbm.at[pid], v_buf.at[half, dst],
+                    sems.at[1, half]))))
         return out
 
-    def start(c, slot):
-        for live, pair in copies(c, slot):
+    def start(who, pages, c, half):
+        for live, pair in copies(who, pages, c, half):
             @pl.when(live)
             def _():
                 for copy in pair:
                     copy.start()
 
-    def wait(c, slot):
-        for live, pair in copies(c, slot):
+    def wait(c, half):
+        for live, pair in copies(b, mine, c, half):
             @pl.when(live)
             def _():
                 for copy in pair:
@@ -458,7 +482,14 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
 
     @pl.when(length > 0)
     def _attend():
-        start(0, 0)
+        base = carry[0]         # the half chunk 0 is in, or is to go in
+        heir = next_ref[b]      # the next seated slot
+
+        @pl.when(carry[1] == 0)
+        def _first_seated():
+            start(b, mine, 0, base)
+
+        carry[1] = 0
         q = q_ref[...]                                   # [rows, D]
         mask = _group_block_mask(rows, heads, kv_heads, depth,
                                  positions)
@@ -466,17 +497,23 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
             mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
                                   axis=1), 0.0).astype(q.dtype)
 
-        def body(c, carry):
-            o, m, l = carry
-            slot = jax.lax.rem(c, 2)
+        def body(c, carried):
+            o, m, l = carried
+            half = jax.lax.rem(base + c, 2)
 
             @pl.when(c + 1 < chunks)
             def _next():
-                start(c + 1, 1 - slot)
+                start(b, mine, c + 1, 1 - half)
 
-            wait(c, slot)
+            @pl.when((c + 1 == chunks) & (heir < batch))
+            def _hand_over():
+                start(heir, live_pages(heir)[1], 0, 1 - half)
+                carry[0] = 1 - half
+                carry[1] = 1
+
+            wait(c, half)
             scores = jax.lax.dot_general(
-                q_bd, k_buf[slot].astype(q.dtype),
+                q_bd, k_buf[half].astype(q.dtype),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             scores = kept_in(scores, softmax_dtype)  # [rows, span]
@@ -498,7 +535,7 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
             p = jnp.exp(scores - m_new)
             l = l * correction + jnp.sum(p, axis=1, keepdims=True)
             pv = jax.lax.dot_general(
-                p.astype(q.dtype), v_buf[slot].astype(q.dtype),
+                p.astype(q.dtype), v_buf[half].astype(q.dtype),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [rows, Hkv*D]
             return (kept_in(o * correction + pv, softmax_dtype), m_new,
@@ -514,6 +551,18 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm,
         # the sum over the blocks folds [rows, Hkv*D] into [rows, D]
         o_ref[...] = sum(out[:, h * depth:(h + 1) * depth]
                          for h in range(kv_heads)).astype(o_ref.dtype)
+
+
+def next_seated(lengths):
+    """[B] int32: for slot b the least b' > b with lengths[b'] > 0,
+    B where there is none: whose first chunk slot b's program fetches
+    behind its own last (a reverse cumulative minimum)."""
+    batch = lengths.shape[0]
+    index = jnp.arange(batch, dtype=jnp.int32)
+    seated = jnp.where(lengths > 0, index, batch)
+    after = jnp.concatenate(
+        [seated[1:], jnp.full((1,), batch, jnp.int32)])
+    return jax.lax.cummin(after, reverse=True)
 
 
 @functools.partial(jax.jit,
@@ -566,10 +615,11 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                      ((0, 0), (0, rows - seq * heads), (0, 0)))
     chunk = gqa_chunk_pages(page, width, k_pages.dtype.itemsize,
                             block_table.shape[1])
+    lengths = lengths.astype(jnp.int32)
     row_spec = pl.BlockSpec((None, rows, depth),
-                            lambda b, tbl, ln: (b, 0, 0))
+                            lambda b, tbl, ln, nxt: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(batch,),
         in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -577,7 +627,8 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
         scratch_shapes=[
             pltpu.VMEM((2, chunk * page, width), k_pages.dtype),
             pltpu.VMEM((2, chunk * page, width), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2))],
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32)],
     )
     out = pl.pallas_call(
         functools.partial(
@@ -592,7 +643,7 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=name,
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_table.astype(jnp.int32), lengths, next_seated(lengths),
       q_rows, k_pages, v_pages)
     return out[:, :seq * heads].reshape(batch, seq, heads, depth)
 

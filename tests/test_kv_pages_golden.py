@@ -38,7 +38,12 @@ PR 44 did NOT move them: occupancy()'s kv_blocks_attended no longer
 counts a block for an idle slot (the decode kernel is handed length 0
 for it), and the digests fold that key with the idle term put back
 (_as_recorded), so that they stay PR 34's and go on saying what they
-are for: the pool hands out the same pages in the same order."""
+are for: the pool hands out the same pages in the same order.
+
+PR 48 did NOT move them either: occupancy() gained
+kv_first_chunks_prefetched (seated slots less one: how often the
+decode kernel's hand-over engages), a key the digests were recorded
+without and fold without (_as_recorded)."""
 
 import hashlib
 import json
@@ -85,10 +90,14 @@ def _schedule(seed: int = 11) -> list[serving.Request]:
 
 def _as_recorded(occupancy: dict) -> dict:
     """occupancy() as the digests were recorded: kv_blocks_attended
-    with one block for each idle slot, which it counted until PR 44."""
-    return dict(occupancy, kv_blocks_attended=(
+    with one block for each idle slot, which it counted until PR 44,
+    and without kv_first_chunks_prefetched, a key since PR 48."""
+    recorded = dict(occupancy, kv_blocks_attended=(
         occupancy["kv_blocks_attended"] + occupancy["slots_total"]
         - occupancy["slots_active"]))
+    assert recorded.pop("kv_first_chunks_prefetched") == max(
+        occupancy["slots_active"] - 1, 0)
+    return recorded
 
 
 def run_schedule(engine, press: bool = True) -> tuple[str, dict]:

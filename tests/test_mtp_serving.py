@@ -213,6 +213,41 @@ def test_a_drafting_engine_serves_alike_beside_parked_slots(params,
     assert 0 < engine.step_stats()["mtp_accepted"]
 
 
+def test_a_drafting_engine_hands_first_chunks_on_past_parked_slots(
+        params):
+    """Five requests over four slots on the kernel (interpret mode):
+    seated, finished and seated again beside parked slots, every
+    pool's and ring's two-position call fetching each seated slot's
+    first chunk behind the last chunk of the seated slot before it.
+    The tokens are those each request lands ALONE in the same engine
+    (one seated slot starts its own first chunk: the kernel as it was
+    until PR 48), and occupancy() counts the hand-overs a call of the
+    next step makes: the decoding slots less one."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    config = _config(paged_attention_impl="kernel")
+    weights = _agreeing(params, 0.6)
+    requests = _requests(5, seed=9, new=(2, 14))
+    counted = []
+
+    def count(engine):
+        state = engine.occupancy()
+        assert state["kv_first_chunks_prefetched"] == max(
+            state["slots_active"] - 1, 0)
+        counted.append(state["kv_first_chunks_prefetched"])
+
+    with pltpu.force_tpu_interpret_mode():
+        together, engine = _serve(config, weights, requests,
+                                  each_step=count, num_slots=4)
+        for request in requests[:2]:
+            alone, _ = _serve(config, weights, [request], num_slots=4)
+            assert alone == {request.request_id:
+                             together[request.request_id]}
+    assert len(together) == 5 and max(counted) == 3
+    assert len(set(counted)) >= 3       # slots freed and seated again
+    assert engine.occupancy()["kv_first_chunks_prefetched"] == 0
+
+
 @pytest.mark.parametrize("new_tokens", [1, 2, 3, 4, 5])
 def test_max_new_tokens_cuts_a_landing_short(params, new_tokens):
     """Under agreeing weights every step could land two tokens: a

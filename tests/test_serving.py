@@ -502,6 +502,13 @@ def test_every_step_writes_one_row_that_agrees_with_the_private_lists(
         assert 0 < phase_ms <= (row["end"] - row["start"]) * 1e3 + 1e-6
         assert attrs["prefills"] == len(attrs["admitted"])
     if engine.paged:
+        # the first chunks the step's decode kernel hands on: one
+        # less than the slots the row found decoding
+        assert all(r["attrs"]["kv_first_chunks_prefetched"]
+                   == max(r["attrs"]["slots_active"] - 1, 0)
+                   for r in rows)
+        assert any(r["attrs"]["kv_first_chunks_prefetched"]
+                   for r in rows) or kind == "speculative"
         assert all(r["attrs"]["kv_pages_total"] == engine.pages.num_pages
                    and r["attrs"]["kv_pages_free"]
                    + r["attrs"]["kv_pages_lru"]
@@ -509,6 +516,7 @@ def test_every_step_writes_one_row_that_agrees_with_the_private_lists(
                    <= engine.pages.num_pages for r in rows)
     else:
         assert "kv_pages_in_use" not in rows[0]["attrs"]
+        assert "kv_first_chunks_prefetched" not in rows[0]["attrs"]
     admitted = [a for r in rows for a in r["attrs"]["admitted"]]
     assert sorted(a["request_id"] for a in admitted) == \
         sorted(r.request_id for r in requests)

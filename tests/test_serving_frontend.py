@@ -476,6 +476,7 @@ def test_stats_and_metrics_carry_the_engine_block(params):
     assert block["slots_total"] == 2 and block["kv_pages_total"] == 12
     assert block["slots_active"] == 0 and block["queued"] == 0
     assert block["kv_pages_in_use"] == 0
+    assert block["kv_first_chunks_prefetched"] == 0     # nobody seated
     # finished prompts' whole pages stay indexed, parked in the LRU
     assert block["kv_pages_lru"] == block["prefix_index_pages"] == 3
     assert block["kv_pages_free"] == 12 - 3
@@ -491,6 +492,7 @@ def test_stats_and_metrics_carry_the_engine_block(params):
     assert values["shipyard_serving_queue_depth"] == 0
     assert values["shipyard_serving_kv_pages_in_use"] == 0
     assert values["shipyard_serving_kv_pages_total"] == 12
+    assert values["shipyard_serving_kv_first_chunks_prefetched"] == 0
     assert values["shipyard_serving_steps_total"] == block["steps"]
     assert "shipyard_serving_compiles_total" in values
     for phase in serving.STEP_PHASES:

@@ -8,6 +8,7 @@ attention call length 0 for every slot the mask leaves out: zeros on
 every road, no page fetched and no tile computed on the kernels'. A
 live slot's cursor, keys and tokens are what they were."""
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -306,6 +307,25 @@ def test_two_requests_are_served_alike_beside_forty_parked_slots(
     assert [len(crowded[r]) for r in ("a0", "a1", "b0")] == [5, 14, 6]
 
 
+def _watch_handed(engine):
+    """-> a list that receives, as each decode step program of
+    ``engine`` is called: the ``active`` mask it is handed, the
+    device's cursors of the first layer (the row the step writes not
+    yet counted: the kernel is handed cursor + 1 under the mask) and
+    occupancy() at that moment."""
+    seen = []
+    inner = engine._decode_step
+
+    def program(params_, cache, tokens, positions, active, key):
+        seen.append((np.asarray(active),
+                     np.asarray(cache["layer_0"]["attn"]["length"]),
+                     engine.occupancy()))
+        return inner(params_, cache, tokens, positions, active, key)
+
+    engine._decode_step = program
+    return seen
+
+
 @pytest.mark.parametrize("kind", ["paged", "paged-int8"])
 def test_kv_blocks_attended_is_the_kernels_count(kind, params):
     """occupancy()'s kv_blocks_attended, from the host's books, against
@@ -319,16 +339,7 @@ def test_kv_blocks_attended_is_the_kernels_count(kind, params):
     seated slots."""
     rng = np.random.RandomState(9)
     engine = _engine(kind, params, num_slots=4)
-    inner = engine._decode_step
-    seen = []
-
-    def program(params_, cache, tokens, positions, active, key):
-        seen.append((np.asarray(active),
-                     np.asarray(cache["layer_0"]["attn"]["length"]),
-                     engine.occupancy()))
-        return inner(params_, cache, tokens, positions, active, key)
-
-    engine._decode_step = program
+    seen = _watch_handed(engine)
     for req in _requests(rng, 6, 11, [4, 19, 33, 7]):
         engine.submit(req)
     while engine.pending():
@@ -346,6 +357,66 @@ def test_kv_blocks_attended_is_the_kernels_count(kind, params):
     assert engine.occupancy()["kv_blocks_attended"] == 0
     assert "kv_blocks_attended" not in _engine(
         "dense", params).occupancy()
+
+
+@pytest.mark.parametrize("kind", ["paged", "paged-kernel",
+                                  "window-kernel"])
+def test_first_chunks_prefetched_is_the_kernels_hand_over_count(kind):
+    """occupancy()'s kv_first_chunks_prefetched against the lengths the
+    decode kernel is handed, read as each step program is called (the
+    device's cursors + 1 under the program's ``active`` mask): one
+    less than the slots of length > 0, never under 0, at EVERY step
+    of a run that seats, finishes and re-seats requests with parked
+    slots before, between and behind the seated ones. On the kernel
+    (interpret mode) every seated slot but the first takes its first
+    chunk from the seated slot before it, across the parked programs
+    between, and the tokens are those each request is served ALONE
+    (one seated slot: it starts its own first chunk, as every slot
+    did until PR 48)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    fields, kwargs, impl = _BESIDE[kind]
+    cfg = dataclasses.replace(CFG, paged_attention_impl=impl, **fields)
+    weights = tfm.TransformerLM(cfg).init(
+        jax.random.PRNGKey(11), jnp.zeros((1, 8), jnp.int32))["params"]
+    rng = np.random.RandomState(17)
+    requests = _requests(rng, 6, 9, [2, 25, 3, 14, 4, 6])
+
+    def serve(batch, watch=False):
+        engine = serving.ContinuousBatcher(
+            cfg, weights, num_slots=5, max_decode_len=96,
+            kv_page_size=PAGE, **kwargs)
+        seen = _watch_handed(engine) if watch else []
+        for req in batch:
+            engine.submit(serving.Request(
+                req.request_id, req.prompt, req.max_new_tokens))
+        done = {}
+        while engine.pending():
+            done.update(engine.step())
+        assert engine.occupancy()["kv_first_chunks_prefetched"] == 0
+        return done, seen
+
+    with (pltpu.force_tpu_interpret_mode() if impl
+          else contextlib.nullcontext()):
+        together, seen = serve(requests, watch=True)
+        if impl:
+            for req in requests[1:3]:
+                assert serve([req])[0][req.request_id] == together[
+                    req.request_id]
+    assert sorted(together) == sorted(r.request_id for r in requests)
+    handed_on = passed_on = 0
+    for active, length, state in seen:
+        handed = np.where(active, length + 1, 0)
+        seated = np.flatnonzero(handed > 0)
+        assert state["kv_first_chunks_prefetched"] == max(
+            len(seated) - 1, 0)
+        assert state["kv_first_chunks_prefetched"] == max(
+            state["slots_active"] - 1, 0)
+        handed_on += max(len(seated) - 1, 0)
+        # parked programs between two seated ones pass a fetch on
+        passed_on += len(seated) > 1 and (
+            seated[-1] - seated[0] + 1 > len(seated))
+    assert len(seen) >= 20 and handed_on >= 20 and passed_on >= 10
 
 
 def test_inactive_slot_probe_tiny_path():

@@ -206,6 +206,16 @@ CASES = [
      _gqa_args(4609, 129, batch=96, heads=32, positions=4)),
     ("flash_prefill_block_causal", _flash_prefill(0, block=4),
      _prefill_args(2048, rows=8192, heads=32)),
+    # the multi-token-prediction configuration's published shapes (64
+    # query over 8 K/V heads of 128, 96 slots, two query positions a
+    # slot): a full layer's pool of 4,608 pages behind a 128-entry
+    # table, and a window layer's ring of 4 pages a slot under a
+    # window of 128 (a slot's whole visit is one chunk: the hand-over
+    # between programs is most of such a call)
+    ("gqa_paged_verify_full", _gqa_paged(0),
+     _gqa_args(4609, 128, batch=96, heads=64, kv_heads=8, positions=2)),
+    ("gqa_paged_verify_ring", _gqa_paged(128),
+     _gqa_args(96 * 4, 4, batch=96, heads=64, kv_heads=8, positions=2)),
     ("ring_all_gather_virtual", rc.ring_all_gather_virtual,
      [((4, 128, 128), f32)]),
     ("ring_reduce_scatter_virtual", rc.ring_reduce_scatter_virtual,
@@ -639,18 +649,32 @@ def test_a_hybrid_stack_keeps_state_and_pool_in_place_on_v5e(
 # pl.when(length > 0)) lies in the serialized Mosaic body, which the
 # hash leaves out. The three PREFILL lines stand: a prefill runs a
 # batch-1 dense cache and no paged call.
+# PR 48 re-recorded all four DECODE lines again (Baichuan's
+# 33c838760abd7f20, Nemotron's e732c9766bb480d9, Solar-Open2's
+# dcf86b2c17ec1406 and SmallThinker's 5b204e50838703f9 until then):
+# the one-program-a-slot kernel's wrapper hands the kernel a third
+# scalar-prefetch operand, next_seated(lengths) (for each slot the
+# next one with a length above 0: whose first chunk its program
+# fetches behind its own last), eleven lines on a [slots] vector
+# before each kernel call (an iota, a compare, a select, a slice, a
+# concatenate and one call of jax's private @cummin, a reduce_window)
+# and that helper's ten lines once a program; the custom call takes
+# six operands where it took five. With the operand taken out the
+# four programs hash to the lines they had: what the kernel does with
+# it, and its SMEM scratch, lie in the serialized Mosaic body, which
+# the hash leaves out. The three PREFILL lines stand.
 ACCEPTED_PROGRAMS = {
-    "baichuan-7b-serve-1chip/decode": "33c838760abd7f20",
+    "baichuan-7b-serve-1chip/decode": "1efee61ea6336378",
     "baichuan-7b-serve-1chip/prefill": "d778ca3089991697",
-    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "e732c9766bb480d9",
+    "nemotron-3-nano-30b-a3b-serve-1chip/decode": "ab26c95c028da4f3",
     "nemotron-3-nano-30b-a3b-serve-1chip/prefill": "9494681929c5e555",
-    "solar-open2-250b-serve-1chip/decode": "dcf86b2c17ec1406",
+    "solar-open2-250b-serve-1chip/decode": "48f090f68262f1ff",
     "solar-open2-250b-serve-1chip/prefill": "414a9d1036f22da3",
     # new in PR 41 (4cb7c1eba72ff012 at its parent: the same program
     # but for that numbering, nine lines, with eight sites of the
     # grouped kernel; compiled for the v5e the two were instruction
     # for instruction the same, PERF.md section 6)
-    "smallthinker-21b-a3b-serve-1chip/decode": "5b204e50838703f9",
+    "smallthinker-21b-a3b-serve-1chip/decode": "d165891c52a028a6",
 }
 
 

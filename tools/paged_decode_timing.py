@@ -26,12 +26,36 @@ host's pace and not the kernel's, so PR 43's "4.7 us a slot at one
 key" was that floor over 48. The cell's cases are longer than the
 floor, and their differences are the device's.)
 
+``--shape kexaone`` and ``--shape sdar`` (since PR 48; not in the
+default set) time the calls of those configurations' decode step as it
+makes them, 96 slots all seated: K-EXAONE's two query positions a slot
+over a full layer's 128-entry table and over a window layer's ring of
+4 pages under a window of 128 (five of its six calls), SDAR's block of
+four positions that all see all keys over a 129-entry table. Their
+times are the DEVICE's (the profiler's events of ``--calls`` calls,
+summed: a call of 0.1 ms is far under what the host takes to dispatch
+one), and the reading is what a seated slot costs a call beyond its
+pages' read: every slot at ONE chunk of pages (a window layer's whole
+visit), the call's device time less the live pages' read at the
+chip's bandwidth, over the seated slots, in us. Until PR 48 that was
+the first chunk's DMA with nothing over it, once a slot; since, the
+seated slot before fetches it behind its own last chunk. ``--parent
+DIR`` loads DIR/batch_shipyard_tpu/ops/paged_attention.py (a checkout
+of another commit, say a ``git archive`` under .proof/) and prints
+its kernel's numbers beside this tree's, one process, one chip.
+
     chiprun -- python3 tools/paged_decode_timing.py [--shape baichuan7b]
+    chiprun -- python3 tools/paged_decode_timing.py --shape kexaone \\
+        --parent .proof/parent
 
 A chip run only: on another backend it says so and exits 2 (a CPU
 timing of a TPU kernel's interpreter is no number)."""
 import argparse
+import functools
+import importlib.util
+import os
 import sys
+import tempfile
 import time
 
 import jax
@@ -40,6 +64,7 @@ import numpy as np
 
 sys.path.insert(0, ".")
 from batch_shipyard_tpu.ops import paged_attention as pa  # noqa: E402
+from benchmark import tracered  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9     # TPU v5e (benchmark/peaks.json)
 PAGE, ENTRIES, DEPTH = 64, 32, 128
@@ -47,6 +72,120 @@ PAGE, ENTRIES, DEPTH = 64, 32, 128
 SHAPES = {"solaropen2": (64, 8, 96, 96, 2401),
           "nemotron3nano": (32, 2, 96, 96, 2401),
           "baichuan7b": (32, 32, 48, 16, 193)}
+
+
+# the calls of a decode step that seats every slot, as the step makes
+# them: (label, query heads, K/V heads, query positions a slot, table
+# entries, window, causal, calls of it a step)
+STEP_CALLS = {
+    "kexaone": [("full", 64, 8, 2, 128, 0, True, 1),
+                ("ring", 64, 8, 2, 4, 128, True, 5)],
+    "sdar": [("block", 32, 4, 4, 129, 0, False, 6)],
+}
+STEP_SLOTS = 96
+
+
+def device_ms(fn, args, calls: int) -> float:
+    """ms a call on the DEVICE: the profiler's operation events of
+    ``calls`` calls dispatched back to back, summed (the kernel and
+    what its wrapper computes before it)."""
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory(
+            prefix="paged_decode_timing_") as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            out = None
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        events = tracered.device_op_events(
+            tracered.from_xplane(tracered.newest_xplane(trace_dir)))
+    return sum(dur for ops in events.values()
+               for _name, _start, dur in ops) / calls / 1e6
+
+
+def load_kernels(parent: str) -> dict:
+    """{"parent": ``parent``'s module (if asked for), "change": this
+    tree's}."""
+    trees = {}
+    if parent:
+        spec = importlib.util.spec_from_file_location(
+            "parent_paged_attention", os.path.join(
+                parent, "batch_shipyard_tpu/ops/paged_attention.py"))
+        trees["parent"] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(trees["parent"])
+    return {**trees, "change": pa}
+
+
+def time_step_calls(name: str, args) -> None:
+    """One configuration's decode-step calls, every slot seated: the
+    device's time a call at one chunk a slot, at the cell's contexts
+    and with two slots in three parked, each tree beside the other."""
+    trees = load_kernels(args.parent)
+    rng = np.random.RandomState(args.seed)
+    step_ms = dict.fromkeys(trees, 0.0)
+    # the cell's contexts, the same for every call of the step
+    # (traffic/reason-offline.json: a prompt about 512 and the part of
+    # an answer about 1,024 decoded so far), a window layer reading
+    # its newest ``window`` of them
+    drawn = (np.clip(np.exp(rng.normal(np.log(512), 0.7, STEP_SLOTS)),
+                     128, 2048)
+             + rng.uniform(0, 1, STEP_SLOTS)
+             * np.clip(np.exp(rng.normal(np.log(1024), 0.5, STEP_SLOTS)),
+                       256, 3072)).astype(np.int32)
+    for label, heads, kv_heads, positions, entries, window, causal, \
+            per_step in STEP_CALLS[name]:
+        width = kv_heads * DEPTH
+        chunk = pa.gqa_chunk_pages(PAGE, width, 2, entries)
+        pool = STEP_SLOTS * min(entries, 48) + 1
+        keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+        q = jax.random.normal(
+            keys[0], (STEP_SLOTS, positions, heads, DEPTH), jnp.bfloat16)
+        k_pages = jax.random.normal(keys[1], (pool, PAGE, width),
+                                    jnp.bfloat16)
+        v_pages = jax.random.normal(keys[2], (pool, PAGE, width),
+                                    jnp.bfloat16)
+        table = jnp.asarray(
+            rng.randint(0, pool - 1, (STEP_SLOTS, entries)), jnp.int32)
+        one_chunk = np.full(
+            STEP_SLOTS, window + positions if window else chunk * PAGE)
+        cases = {"one chunk a slot": one_chunk, "cell": drawn,
+                 "cell, two in three parked": np.where(
+                     np.arange(STEP_SLOTS) % 3 == 0, drawn, 0)}
+        print(f"{name} {label}: {STEP_SLOTS} slots, {positions} query "
+              f"positions of {heads} heads over {kv_heads} K/V heads "
+              f"of {DEPTH}, a table of {entries}, window {window}, "
+              f"{chunk} pages a chunk, {per_step} such calls a step")
+        kernels = {tree: jax.jit(functools.partial(
+            module.gqa_paged_decode_attention_kernel, window=window,
+            causal=causal)) for tree, module in trees.items()}
+        for case, lengths in cases.items():
+            low = np.maximum(lengths - (positions - 1 + window), 0) \
+                if window else np.zeros_like(lengths)
+            pages = int(np.sum(np.where(
+                lengths > 0, (lengths - 1) // PAGE - low // PAGE + 1, 0)))
+            read_ms = (2 * pages * PAGE * width * 2
+                       / HBM_BYTES_PER_S * 1e3)
+            seated = int(np.sum(lengths > 0))
+            operands = (q, k_pages, v_pages, table,
+                        jnp.asarray(lengths, jnp.int32))
+            outs = {}
+            for tree, kernel in kernels.items():
+                outs[tree] = np.asarray(kernel(*operands), np.float32)
+                ms = device_ms(kernel, operands, args.calls)
+                if case == "cell":
+                    step_ms[tree] += per_step * ms
+                print(f"  {case}, {tree}: device {ms:.4f} ms a call "
+                      f"(live pages' read {read_ms:.4f} ms: "
+                      f"{100 * read_ms / ms:.1f} % of it), "
+                      f"{(ms - read_ms) / seated * 1e3:.2f} us a seated "
+                      f"slot beyond the read", flush=True)
+            if len(outs) == 2:
+                same = np.array_equal(outs["parent"], outs["change"])
+                print(f"  {case}: the two trees' results are "
+                      f"{'bit for bit the same' if same else 'DIFFERENT'}")
+    print(f"{name}: the step's paged decode calls at the cell's "
+          f"contexts, " + ", ".join(
+              f"{tree} {ms:.3f} ms" for tree, ms in step_ms.items()))
 
 
 def timed(fn, args, calls: int) -> float:
@@ -80,14 +219,24 @@ def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--calls", type=int, default=50)
     parser.add_argument("--seed", type=int, default=0)
-    parser.add_argument("--shape", choices=sorted(SHAPES), default=None,
-                        help="time this shape alone (default: all)")
+    parser.add_argument("--shape",
+                        choices=sorted(SHAPES) + sorted(STEP_CALLS),
+                        default=None,
+                        help="time this shape alone (default: the "
+                        "one-token shapes)")
+    parser.add_argument("--parent", default="",
+                        help="a checkout of another commit whose "
+                        "kernel is timed beside this tree's "
+                        "(--shape kexaone / sdar)")
     args = parser.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
         print(f"no chip here ({device.platform}): nothing timed")
         return 2
     print(f"device {device.device_kind} x{jax.device_count()}")
+    if args.shape in STEP_CALLS:
+        time_step_calls(args.shape, args)
+        return 0
     for name, (heads, kv_heads, slots, seated, pool) in SHAPES.items():
         if args.shape not in (None, name):
             continue

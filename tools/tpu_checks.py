@@ -247,6 +247,76 @@ def check_paged_attention() -> bool:
     return all_ok
 
 
+# (label, query heads, K/V heads, query positions a slot, table
+# entries, window, causal): the served calls whose programs hand a
+# first chunk on (tools/paged_decode_timing.py's STEP_CALLS beside
+# Baichuan's one-token MHA call), heads of 128, pages of 64, bfloat16
+_HANDOVER_CALLS = (
+    ("baichuan h32 mha", 32, 32, 1, 32, 0, True),
+    ("kexaone h64/8 x2 full", 64, 8, 2, 128, 0, True),
+    ("kexaone h64/8 x2 ring4 w128", 64, 8, 2, 4, 128, True),
+    ("sdar h32/4 x4 all keys", 32, 4, 4, 129, 0, False),
+)
+
+
+def check_paged_handover() -> bool:
+    """The one-program-a-slot kernel's hand-over between programs on
+    the chip: seated slots of one to five chunks of pages with runs
+    of parked slots (length 0) before, between and behind them. Each
+    seated slot's first chunk is started by the seated slot before it
+    and passed on by the parked programs between; its row must be BIT
+    FOR BIT what the slot gives alone (a batch of one starts its own
+    chunk 0, as every slot did until PR 48), the parked rows zero,
+    and the call within bfloat16's rounding of the XLA gather."""
+    from batch_shipyard_tpu.ops import paged_attention as paged
+
+    depth, page, all_ok = 128, 64, True
+    for label, heads, kv_heads, positions, entries, window, causal in \
+            _HANDOVER_CALLS:
+        rng = np.random.RandomState(13)
+        width = kv_heads * depth
+        keys = paged.gqa_chunk_pages(page, width, 2, entries) * page
+        # chunks a seated slot: 1, 2, 1, 3, 5, 1, 1, 2 (the buffer
+        # half flips between neighbours or does not)
+        lengths = np.asarray(
+            [0, 0, keys, 0, keys + 1, 9, 0, 0, 0, 2 * keys + 70,
+             5 * keys, keys - 1, 0, positions, 2 * keys, 0], np.int32)
+        need = np.minimum(-(-lengths // page), entries)
+        pool = 1 + int(need.sum())
+        q = jnp.asarray(rng.randn(len(lengths), positions, heads, depth),
+                        jnp.bfloat16)
+        k_p, v_p = (jnp.asarray(rng.randn(pool, page, width),
+                                jnp.bfloat16) for _ in range(2))
+        table = np.zeros((len(lengths), entries), np.int32)
+        ids = iter(rng.permutation(pool - 1) + 1)
+        for b, pages in enumerate(need):
+            table[b, :pages] = [next(ids) for _ in range(pages)]
+        table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+        kernel = jax.jit(functools.partial(
+            paged.paged_decode_attention, impl="kernel", window=window,
+            causal=causal))
+        out_k = kernel(q, k_p, v_p, table, lengths)
+        out_x = paged.paged_decode_attention(
+            q, k_p, v_p, table, lengths, impl="xla", window=window,
+            causal=causal)
+        rel = _rel(out_k, out_x)
+        seated = np.flatnonzero(np.asarray(lengths) > 0)
+        alone = all(
+            np.array_equal(
+                np.asarray(out_k[b], np.float32),
+                np.asarray(kernel(q[b:b + 1], k_p, v_p, table[b:b + 1],
+                                  lengths[b:b + 1])[0], np.float32))
+            for b in seated)
+        zeros = not np.delete(np.asarray(out_k, np.float32), seated,
+                              axis=0).any()
+        ok = rel < 2e-2 and alone and zeros
+        print(f"paged hand-over [{label}]: rel={rel:.2e} "
+              f"alone-bitwise={alone} parked-zero={zeros} "
+              f"{'OK' if ok else 'FAIL'}")
+        all_ok = all_ok and ok
+    return all_ok
+
+
 def check_int8_matmul() -> bool:
     """quantize_int8 + int8_matmul on the real MXU: the quantized
     product must sit within the per-element quantization error bound
@@ -296,6 +366,7 @@ CHECKS = {
     "flash_single_chip": check_flash_single_chip,
     "flash_ring": check_flash_ring_virtual_shards,
     "paged_attention": check_paged_attention,
+    "paged_handover": check_paged_handover,
     "int8_matmul": check_int8_matmul,
     "fused_norm": check_fused_norm,
     "chunked_cross_entropy": None,  # bound below (round-5 kernel)
