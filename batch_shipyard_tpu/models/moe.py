@@ -645,13 +645,19 @@ class RoutedExperts(nn.Module):
     [B, T, k] (indices over all n_experts) are sown into the
     "decisions" collection, for a serving engine to hand to whoever
     checks them (serving.ContinuousBatcher.take_decisions).
+    ``live_rows`` ([B, T] bool, None = all): the rows somebody reads.
+    The others' pairs fall on no held expert: on the grouped road they
+    lie behind the last group, make no visit to a stack and are never
+    computed (a row tile more is a read of a slab more:
+    ops/grouped_matmul.py), and their routed output is zeros; what
+    they chose is sown all the same.
     """
     config: RoutedConfig
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, router_input=None):
+    def __call__(self, x, router_input=None, live_rows=None):
         cfg = self.config
         if cfg.scoring not in ("sigmoid", "softmax") or \
                 cfg.gate_act not in ("silu", "relu"):
@@ -703,6 +709,8 @@ class RoutedExperts(nn.Module):
             chosen, weights = route_softmax(logits, cfg.top_k)
         self.sow("decisions", "chosen",
                  chosen.reshape(batch, length, cfg.top_k))
+        if live_rows is not None:
+            chosen = jnp.where(live_rows.reshape(-1, 1), chosen, -1)
         stacks = (up.astype(self.dtype), down.astype(self.dtype),
                   cfg.first_expert, gate, cfg.gate_act)
         if experts_road(batch * length, cfg) == "grouped":

@@ -820,7 +820,7 @@ class ServingFrontEnd:
         # a model that generates by diffusion over blocks (absent
         # otherwise)
         blocks = ("; block passes: %d denoise, %d commit, %d positions "
-                  "unmasked, %d tokens landed" % tuple(
+                  "unmasked, %d tokens landed, %d commits fused" % tuple(
                       since(name) for name in BLOCK_COUNTERS)) \
             if BLOCK_COUNTERS[0] in now else ""
         logger.info(

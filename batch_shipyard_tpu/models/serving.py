@@ -85,7 +85,8 @@ UNMASK_NAME = "unmask"
 # A block-diffusion engine's counters (ContinuousBatcher._block_counts),
 # in the order a reader lists them.
 BLOCK_COUNTERS = ("block_denoise_passes", "block_commit_passes",
-                  "block_positions_unmasked", "block_tokens_landed")
+                  "block_positions_unmasked", "block_tokens_landed",
+                  "block_commits_fused")
 
 
 def pass_layer_name(layer: str, s: int) -> str:
@@ -122,9 +123,9 @@ def _decode_step(model, sampling, params, cache, tokens, positions,
 
     A model that generates by diffusion over blocks (TransformerConfig.
     block_diffusion) is handed ``masked`` [B, block] and tokens
-    [B, 1, block], and the SAME program name then holds the
-    denoise-or-commit pass (_denoise_or_commit: no token or a whole
-    block a slot; its results behind the same first four)."""
+    [B, 1, 2 * block], and the SAME program name then holds the block
+    pass (_denoise_or_commit: no token or a whole block a slot; its
+    results behind the same first four)."""
     if model.config.block_diffusion is not None:
         return _denoise_or_commit(model, params, cache, tokens, masked,
                                   positions, active)
@@ -256,63 +257,83 @@ def _denoise_or_commit(model, params, cache, tokens, masked, positions,
                        active):
     """_decode_step for a model that generates by diffusion over blocks
     (transformer.BlockDiffusion): ONE program for all slots, dispatched
-    by the lookahead as every decode step is, whose pass is per slot a
-    denoise pass or a commit pass, by the slot's own data. tokens
-    [B, 1, block] is each slot's open block at ``positions`` [B] (its
-    first position, a whole number of blocks: the cursor of every cache
-    leaf), ``masked`` [B, block] the positions of it that still read as
-    the mask token (masked-ness is this boolean alone, never ``token ==
-    mask_id``: a prompt may hold that id; what lies under a mask is
-    whatever the block held before and is read by nobody).
+    by the lookahead as every decode step is, whose pass feeds TWO
+    blocks a slot from the slot's cursor on, and commits a finished
+    block in the pass that opens the next one. tokens [B, 1, 2 * block]
+    holds in its first half each slot's open block at ``positions`` [B]
+    (its first position, a whole number of blocks: the cursor of every
+    cache leaf), ``masked`` [B, block] the positions of it that still
+    read as the mask token (masked-ness is this boolean alone, never
+    ``token == mask_id``: a prompt may hold that id; what lies under a
+    mask is whatever the block held before and is read by nobody).
+    Which of two passes a slot's is, its own data say:
 
-      forward  the block, mask token where masked, at positions p ..
-               p + block - 1 through the stack against the cached
-               blocks before it: the grouped paged kernel reads each
-               live page once for all its query positions, which ALL
-               see all the keys, the block's own rows included
-      commit   a slot without a mask: the rows this pass wrote ARE the
-               block's K/V and are kept, the cursor moves on by a
-               block, the block's tokens land, the next block opens
-               all masked
-      denoise  else: float32 logits, each position's best token and
-               its confidence (that token's softmax probability, as
-               its logarithm), the pass's choice among the masked
-               (_unmasked_by) takes its token; the cursor is rewound by
-               the block (inference._rewind_cache), so that the rows
-               lie beyond it and the next pass overwrites them
+      closing  no mask left: the first half fed is the finished block
+               with its final tokens, the second the NEXT block, all
+               masked. The first half's rows ARE the block's K/V and
+               are kept (the cursor moves on by a block), the block's
+               tokens land, and the second half is the next block's
+               first denoise pass
+      plain    masks left (a first block just seated among them): the
+               first half is the open block, mask token where masked,
+               and is denoised; the second half is dead filler, whose
+               rows nobody reads: out of the attention's products
+               (``live`` below), the head, the counters and the record
 
-    Greedy only. Nothing here reads the host: pass k+1's block, mask,
+    Both halves go through the stack at positions p .. p + 2 * block
+    - 1 against the cached blocks before them: the grouped paged kernel
+    reads each live page once for all query positions, each of which
+    sees the keys up to its own block's end (so the first half nothing
+    of the second). The OPEN half alone (a closing slot's second, a
+    plain slot's first) goes through the head: float32 logits, each
+    position's best token and its confidence (that token's softmax
+    probability, as its logarithm), and the pass's choice among the
+    masked (_unmasked_by) takes its token. The cursor is rewound
+    (inference._rewind_cache) past every row but a closing slot's first
+    half, so that the rest lie beyond it and the next pass overwrites
+    them.
+
+    Greedy only. Nothing here reads the host: pass k+1's blocks, masks,
     positions and cursors are this program's results. -> (cache, tokens
-    [B, 1, block], positions [B], the block [B, block]: _decode_step's
-    four, the tokens a committing slot lands (a caller that wraps the
-    step and reads only those four finds them as _decode_step gives
-    them); then masked [B, block], flags int32 [B, block + 1] (the
-    positions this pass unmasked, then whether it committed; zeros for
-    an inactive slot)[, the routed layers' choices int32 [decision
-    layers, B, block, k]])."""
+    [B, 1, 2 * block], positions [B], the same tokens [B, 2 * block]:
+    _decode_step's four (a caller that wraps the step and reads only
+    those four finds them as _decode_step gives them), the open block
+    after the pass and, behind it, the block as the pass found it,
+    which a closing slot lands; then masked [B, block], flags int32
+    [B, block + 1] (the positions of the open half this pass unmasked,
+    then whether it closed a block; zeros for an inactive slot)[, the
+    routed layers' choices int32 [decision layers, B, 2 * block, k]])."""
     cfg = model.config
     rule = cfg.block_diffusion
-    block = tokens[:, 0]                                  # [B, block]
-    size = block.shape[1]
-    pos_blk = positions[:, None] + jnp.arange(size, dtype=jnp.int32)[None]
+    size = rule.block
+    block = tokens[:, 0, :size]                           # [B, block]
+    closing = active & ~jnp.any(masked, axis=1)
+    filler = jnp.full_like(block, rule.mask_id)
+    span = jnp.arange(2 * size, dtype=jnp.int32)[None]
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
-        jnp.where(masked, jnp.int32(rule.mask_id), block),
-        positions=pos_blk, live=active, mutable=_MUTABLE)
+        jnp.concatenate([jnp.where(masked, filler, block), filler],
+                        axis=1),
+        positions=positions[:, None] + span,
+        # the query positions somebody reads
+        live=jnp.where(active, jnp.where(closing, 2 * size, size), 0),
+        head_rows=jnp.where(closing, size, 0)[:, None] + span[:, :size],
+        mutable=_MUTABLE)
     logits = logits.astype(jnp.float32)
     best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     confidence = jnp.max(logits, axis=-1) - jax.nn.logsumexp(
         logits, axis=-1)
-    commit = active & ~jnp.any(masked, axis=1)
+    masked = masked | closing[:, None]      # of the open half
     picked = _unmasked_by(confidence, masked, rule) & active[:, None]
-    block = jnp.where(picked, best, block)
-    masked = jnp.where(commit[:, None], True, masked & ~picked)
-    positions = jnp.where(commit, positions + size, positions)
+    state = jnp.concatenate([jnp.where(picked, best, block), block],
+                            axis=1)
+    masked = masked & ~picked
+    positions = jnp.where(closing, positions + size, positions)
     cache = inf._park_idle_cursors(inf._rewind_cache(
-        mutated["cache"], jnp.where(commit, 0, size)), active)
-    flags = jnp.concatenate([picked, commit[:, None]],
+        mutated["cache"], jnp.where(closing, size, 2 * size)), active)
+    flags = jnp.concatenate([picked, closing[:, None]],
                             axis=1).astype(jnp.int32)
-    out = (cache, block[:, None], positions, block, masked, flags)
+    out = (cache, state[:, None], positions, state, masked, flags)
     chosen = tfm.collect_decisions(mutated.get("decisions"), cfg)
     return out if chosen is None else out + (chosen,)
 
@@ -867,14 +888,16 @@ def _seat_block(last_logits, tokens, masked, positions, slot, first,
     a prefill yields K/V and no token, and what is seated is the first
     generated BLOCK: ``first`` int32 [block] (the ``given`` tokens of
     the prompt past its whole blocks, then anything) into the slot's
-    row of the block step's inputs, masked from ``given`` on, at the
-    block's first position ``start``. -> (tokens [B, 1, block], masked
+    row of the block step's inputs (the open block's half), masked
+    from ``given`` on, at the block's first position ``start``. ->
+    (tokens [B, 1, 2 * block], masked
     [B, block], positions [B], int32 [1] that is ready when the
     prefill's ``last_logits`` (its last hidden row: _prefill_segments)
     are: what the host waits for in _land_first). Slot, count and
     position traced: ONE compilation an engine."""
-    return (tokens.at[slot, 0].set(first),
-            masked.at[slot].set(jnp.arange(first.shape[0]) >= given),
+    size = first.shape[0]
+    return (tokens.at[slot, 0, :size].set(first),
+            masked.at[slot].set(jnp.arange(size) >= given),
             positions.at[slot].set(start),
             jnp.isnan(last_logits[:1]).astype(jnp.int32))
 
@@ -1010,9 +1033,9 @@ class _InFlight:
     # arrays [B] of which a slot lands its first 1 + accepted.
     accepted: object = None
     # A block pass (_denoise_or_commit): int32 [B, block + 1] on the
-    # device, the positions the pass unmasked and whether it
-    # committed, ``tokens`` then being the blocks [B, block], which a
-    # committing slot lands.
+    # device, the positions the pass unmasked and whether it closed a
+    # block, ``tokens`` then being [B, 2 * block], whose second half a
+    # closing slot lands.
     flags: object = None
 
 
@@ -1074,8 +1097,8 @@ class Launch:
     # decode: the drafts the step accepted over its rows (0 for a
     # model that does not draft)
     accepted: int = 0
-    # decode: the rows whose pass committed a block (a model that
-    # generates by diffusion over blocks; the others' denoised)
+    # decode: the rows whose pass closed a block (a model that
+    # generates by diffusion over blocks; every row's denoised)
     commits: int = 0
     request_id: str = ""        # prefill
     # prefill: the road the bucket's program takes through its routed
@@ -1333,16 +1356,20 @@ class ContinuousBatcher:
                     "a rejected draft is un-committed by the cursor: "
                     "not for a model with a per-slot state")
         # A model that generates by diffusion over blocks
-        # (transformer.BlockDiffusion): every decode step is a denoise
-        # or a commit pass of each slot's open block and lands no
-        # token or a whole block, on the lookahead's step order
+        # (transformer.BlockDiffusion): every decode step is a pass
+        # over each slot's open block and the one behind it and lands
+        # no token or a whole block, on the lookahead's step order
         # (_denoise_or_commit). 0: one token a step.
         rule = config.block_diffusion
         self.block = rule.block if rule is not None else 0
         self.block_denoise_passes = 0
+        # a pass that does nothing but commit: this engine has none (a
+        # block is closed by the pass that opens the next), and the
+        # counter stays for its readers
         self.block_commit_passes = 0
         self.block_positions_unmasked = 0
         self.block_tokens_landed = 0
+        self.block_commits_fused = 0
         if self.block:
             if speculative is not None or self.drafts:
                 raise ValueError(
@@ -1384,9 +1411,10 @@ class ContinuousBatcher:
         if self.paged:
             self.page_size = kv_page_size
             # what a step may write beyond the row it commits: the
-            # drafts, or a block (the pass dispatched behind a
-            # request's last commit writes one more)
-            margin = max(self.gamma, self.drafts, self.block)
+            # drafts, or the two blocks of a block pass (the one
+            # dispatched behind a request's last commit writes both
+            # beyond it)
+            margin = max(self.gamma, self.drafts, 2 * self.block)
             self.pages = kv_pages.PagePool(
                 num_slots, kv_num_pages, kv_page_size, max_decode_len,
                 spec_window=margin,
@@ -1483,9 +1511,11 @@ class ContinuousBatcher:
             (self.cache, self._tokens, self._positions, self._active,
              self._key, self._draft, self._masked) = self._put((
                  inf.init_cache(self.model, params, num_slots),
-                 # each slot's pending token, or its open block
+                 # each slot's pending token, or its open block and
+                 # the one it landed last
                  jnp.zeros((num_slots, 1) + (
-                     (self.block,) if self.block else ()), jnp.int32),
+                     (2 * self.block,) if self.block else ()),
+                     jnp.int32),
                  jnp.zeros((num_slots,), jnp.int32),
                  jnp.zeros((num_slots,), jnp.bool_),
                  jax.random.PRNGKey(seed),
@@ -2019,9 +2049,9 @@ class ContinuousBatcher:
         if self.pages is not None:
             with phases("grow_pages"):
                 # a drafting step writes its draft's row too; a block
-                # pass its whole block, a block further on if the
-                # pass in flight commits
-                self._grow_pages(span=self.block or self.drafts)
+                # pass its two blocks, a block further on if the pass
+                # in flight closes one
+                self._grow_pages(span=2 * self.block or self.drafts)
         seated = self._decoding()
         if not seated:
             # Every seated request waits for its last token only.
@@ -2243,11 +2273,11 @@ class ContinuousBatcher:
 
     def _land_blocks(self, step: _InFlight) -> None:
         """_land for a block pass (_denoise_or_commit): a slot whose
-        pass committed lands its block (of the first generated block
+        pass closed a block lands it (of the first generated block
         the positions past the prompt's given tokens), cut short where
         one of its tokens is the request's last (max_new_tokens, its
         eos_id: the block was finished, what lies behind is dropped);
-        a slot whose pass denoised lands nothing. A request ends on a
+        a slot whose pass did not lands nothing. A request ends on a
         landing alone, so the pass dispatched meanwhile with its slot
         seated is overshoot, as after an eos. Every pass goes on the
         request's record (take_decisions)."""
@@ -2255,40 +2285,41 @@ class ContinuousBatcher:
         size = self.block
         with phases("readback"):
             ready = step.tokens.is_ready()
-            blocks = np.asarray(step.tokens)            # [B, block]
+            # [B, block]: each slot's block as the pass found it
+            blocks = np.asarray(step.tokens)[:, size:]
             flags = np.asarray(step.flags)              # [B, block + 1]
             chosen = None if step.chosen is None else np.asarray(
-                step.chosen).astype(np.int16)   # [layers, B, block, k]
+                step.chosen).astype(np.int16)  # [layers, B, 2 block, k]
         rows = [i for i, _ in step.seated]
-        committed = flags[:, -1].astype(bool)
-        commits = int(committed[rows].sum())
+        closed = flags[:, -1].astype(bool)
+        closing = [i for i in rows if closed[i]]
         tokens = blocks.tolist()
-        # what each committing slot serves of its block
+        # what each closing slot serves of its block
         serves = {i: self._slots[i].served_of(
                       tokens[i][self._slots[i].given:])
                   for i, req in step.seated
-                  if committed[i] and self._slots[i].request is req}
+                  if closed[i] and self._slots[i].request is req}
         landed = sum(map(len, serves.values()))
-        self.block_denoise_passes += len(rows) - commits
-        self.block_commit_passes += commits
+        self.block_denoise_passes += len(rows)
+        self.block_commits_fused += len(closing)
         self.block_positions_unmasked += int(flags[rows, :-1].sum())
         self.block_tokens_landed += landed
         self._landed(Launch.landing(
             "decode", step.dispatched_at, self._landed_at, ready,
             step.queued, rows=len(rows), tokens=landed,
-            commits=commits))
+            commits=len(closing)))
         with phases("emit"):
             batch = []
             for i, req in step.seated:
                 slot = self._slots[i]
                 if slot.request is not req:
-                    self.overshoot_tokens += size * bool(committed[i])
+                    self.overshoot_tokens += size * bool(closed[i])
                     continue
                 slot.in_flight -= size
                 self._decisions[req.request_id]["steps"].append(
                     (chosen, i, flags[i],
-                     blocks[i] if committed[i] else None))
-                if not committed[i]:
+                     blocks[i] if closed[i] else None))
+                if not closed[i]:
                     continue
                 given, slot.given = slot.given, 0
                 for token in serves[i]:
@@ -2300,8 +2331,13 @@ class ContinuousBatcher:
                     self._finish(i)
             self._emit(batch)
             if chosen is not None:
-                self._count_experts(chosen[:, rows].reshape(
-                    len(chosen), -1, chosen.shape[-1]))
+                # a closing slot's two blocks, a plain slot's first:
+                # the dead half's rows chose for nobody
+                self._count_experts(np.concatenate(
+                    [chosen[:, rows, :size].reshape(
+                        len(chosen), -1, chosen.shape[-1]),
+                     chosen[:, closing, size:].reshape(
+                         len(chosen), -1, chosen.shape[-1])], axis=1))
             # as in _land: the arrays die inside the phase
             step.tokens = step.key = step.chosen = step.flags = None
 
@@ -2544,22 +2580,26 @@ class ContinuousBatcher:
         the program conditioned on them (the prompt's given ones and
         those dropped behind the request's last included)}. ``layers``
         holds, each int32 [m, .] over the positions p .. p+m-1 the
-        prefill kept and the commit passes wrote:
+        prefill kept and the closing passes wrote:
 
           <routed layer>            the choices of the pass that wrote
                                     the position's K/V (the prefill, a
-                                    commit pass)
+                                    closing pass's first half)
           <routed layer>.pass<s>    those of the block's s-th denoise
-                                    pass (s < steps); a pass the block
-                                    did not take saw the block without
-                                    a mask, as its commit pass did, and
-                                    holds that pass's; so do the
+                                    pass (s < steps; pass 0 of every
+                                    block but a request's first is its
+                                    predecessor's closing pass's second
+                                    half); a pass the block did not
+                                    take saw the block without a mask,
+                                    as the half that closed it did, and
+                                    holds that half's; so do the
                                     prefill's positions
           unmask  [m, 1]            the denoise pass that unmasked the
                                     position; ``steps`` where it never
                                     was masked (the prompt's)
 
-        Passes behind the last commit (overshoot) are not on it."""
+        Passes behind the last closed block (the block its closing
+        pass opened, overshoot) are not on it."""
         size, steps = self.block, self.config.block_diffusion.steps
         prefill = record["prefill"]
         kept = record["start"] - record["first"]
@@ -2568,22 +2608,26 @@ class ContinuousBatcher:
         passes = [[prefill] for _ in range(steps)] if routed else []
         unmask = [np.full((kept,), steps, np.int32)]
         tokens, denoised = [], []
-        for chosen, i, flags, block in record["steps"]:
-            mine = chosen[:, i] if routed else None  # [layers, block, k]
-            if block is None:
-                denoised.append((mine, flags[:-1].astype(bool)))
-                continue
-            at = np.full((size,), steps, np.int32)
-            for s, (_, picked) in enumerate(denoised):
-                at[picked] = s
-            unmask.append(at)
-            tokens.append(block)
-            if routed:
-                written.append(mine)
-                for s in range(steps):
-                    passes[s].append(denoised[s][0]
-                                     if s < len(denoised) else mine)
-            denoised = []
+        for chosen, i, flags, closed in record["steps"]:
+            # [layers, 2 * block, k]: the open half is the second of a
+            # pass that closed a block, else the first
+            mine = chosen[:, i] if routed else None
+            if closed is not None:
+                at = np.full((size,), steps, np.int32)
+                for s, (_, picked) in enumerate(denoised):
+                    at[picked] = s
+                unmask.append(at)
+                tokens.append(closed)
+                if routed:
+                    written.append(mine[:, :size])
+                    for s in range(steps):
+                        passes[s].append(denoised[s][0]
+                                         if s < len(denoised)
+                                         else mine[:, :size])
+                    mine = mine[:, size:]
+                denoised = []
+            denoised.append((mine[:, :size] if routed else None,
+                             flags[:-1].astype(bool)))
         layers = {UNMASK_NAME: np.concatenate(unmask)[:, None]}
         # (no routed layer: no entry but the unmask passes)
         for s, parts in enumerate([written] + passes if routed else []):
@@ -2619,12 +2663,14 @@ class ContinuousBatcher:
 
     def _block_counts(self) -> dict:
         """A block-diffusion engine's cumulative counters, of the
-        landed block passes: the (slot, pass) pairs that denoised and
-        that committed, the positions the denoise passes unmasked, and
-        the tokens the commit passes landed (a first block's given
-        positions not counted). Tokens over passes is what the
-        schedule is worth: ``block`` over ``steps + 1`` at the
-        static rule's floor."""
+        landed block passes: the (slot, pass) pairs that denoised
+        (every one: a pass that closes a block denoises the next) and
+        that did nothing but commit (none), the positions the passes
+        unmasked, the tokens the closed blocks landed (a first block's
+        given positions not counted), and the blocks closed by a pass
+        that denoised the next. Tokens over passes is what the
+        schedule is worth: ``block`` over ``steps`` at the static
+        rule's floor."""
         return {name: getattr(self, name) for name in BLOCK_COUNTERS}
 
     def _expert_counts(self) -> dict:

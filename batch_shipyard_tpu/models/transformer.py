@@ -787,7 +787,11 @@ class Attention(nn.Module):
         identically (models/serving.py).
 
         ``live`` ([B] bool, None = every slot): the serving step's
-        mask of the slots that hold a request. A slot without one
+        mask of the slots that hold a request (int32 [B] from a step
+        that feeds two blocks a slot: how many of the slot's query
+        positions anybody reads, 0 for a slot without a request; the
+        kernel passes over a dead second block, paged_ops.
+        gqa_paged_decode_attention_kernel). A slot without one
         stays a row of the full-batch step: its row is written (the
         scratch page, where its table points, absorbs it) and its
         cursor advances like any other, but the attention call is
@@ -862,17 +866,21 @@ class Attention(nn.Module):
         v_pages.value = v_pages.value.at[page_idx, offset].set(
             v_in.astype(store_dtype).reshape(batch, seq, width))
         length.value = idx + seq
-        if seq > 1 and cfg.attend_block not in (0, seq):
+        if seq > 1 and cfg.attend_block and seq % cfg.attend_block:
             raise ValueError(
-                f"a block-diffusion model's paged insert is one whole "
-                f"block of {cfg.attend_block} positions, not {seq}")
+                f"a block-diffusion model's paged insert is a whole "
+                f"number of blocks of {cfg.attend_block} positions, "
+                f"not {seq}")
+        live_positions = None
+        if live is not None and live.dtype != jnp.bool_:
+            live_positions, live = live, live > 0
         if seq == 1 or (kv_heads != heads and not int8_kv):
             # one token, or a verify block over a grouped pool: the
             # kernel (the windowed gather elsewhere) reads each live
             # page once for all seq positions, position r masked to
             # the keys up to its own; or a block-diffusion model's
-            # block, whose seq positions (one whole block, the cursor
-            # on a block's edge) all see all the keys
+            # blocks (the cursor on a block's edge), whose positions
+            # see the keys up to their own block's end
             return paged_ops.paged_decode_attention(
                 q, k_pages.value, v_pages.value, block_table.value,
                 _live_lengths(length.value, live),
@@ -880,8 +888,8 @@ class Attention(nn.Module):
                 k_scales=scale_k.value if int8_kv else None,
                 v_scales=scale_v.value if int8_kv else None,
                 softmax_dtype=cfg.attn_softmax_dtype,
-                causal=not (seq > 1 and cfg.attend_block)).astype(
-                    cfg.dtype)
+                block=cfg.attend_block,
+                live_positions=live_positions).astype(cfg.dtype)
         # Multi-token verify pass over an MHA (or int8) pool: gather
         # the slot's full logical view
         # and attend causally over absolute cache positions (query s
@@ -908,6 +916,16 @@ class Attention(nn.Module):
         mask = (key_pos[None, None, :] <= attn_ops.block_end(
             cols, cfg.attend_block)[:, :, None])[:, None, :, :]
         return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
+
+
+def _live_rows(live, length: int):
+    """[B, length] bool, the query positions somebody reads, from an
+    int32 ``live`` [B] (how many of each slot's, from its first on:
+    Attention._decode_attend_paged); None (all of them) from a mask
+    or from none."""
+    if live is None or live.dtype == jnp.bool_:
+        return None
+    return jnp.arange(length)[None, :] < live[:, None]
 
 
 def _live_lengths(lengths, live):
@@ -1091,7 +1109,8 @@ class MixerBlock(nn.Module):
             from batch_shipyard_tpu.models.moe import RoutedExperts
             out = RoutedExperts(cfg.experts, dtype=cfg.dtype,
                                 param_dtype=cfg.param_dtype,
-                                name="experts")(normed, router_input)
+                                name="experts")(
+                normed, router_input, _live_rows(live, x.shape[1]))
         elif self.kind == "mlp":
             out = MLP(cfg, name="mlp")(normed)
         else:
@@ -1143,7 +1162,7 @@ class TransformerLM(nn.Module):
     def __call__(self, tokens, return_hidden: bool = False,
                  positions=None, valid_len=None,
                  stack_hidden: bool = False, mtp_hidden=None,
-                 live=None):
+                 live=None, head_rows=None):
         """tokens: [B, T] int32 -> logits [B, T, vocab] (or the final
         hidden states [B, T, d_model] when return_hidden — used by the
         chunked-loss training path so the full fp32 logits tensor,
@@ -1156,7 +1175,10 @@ class TransformerLM(nn.Module):
         rows are masked on read and need no such care.
         ``live`` ([B] bool, a paged decode step only; None = every
         slot): the slots that hold a request; the others' attention is
-        skipped (Attention._decode_attend_paged).
+        skipped (Attention._decode_attend_paged, which has what an
+        int32 ``live`` says).
+        ``head_rows`` (int32 [B, n]): the n positions of each row of
+        the batch that the head runs over, in place of all T.
 
         A model with a multi-token-prediction module (mtp_modules):
         ``stack_hidden`` True -> (the result as above, the stack's
@@ -1217,6 +1239,8 @@ class TransformerLM(nn.Module):
                 x = block(cfg, kind == "dense_moe", *per_layer,
                           name=f"layer_{idx}")(x, positions, live)
         last = x
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows[:, :, None], axis=1)
         out = head(RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                            name="final_norm")(x))
         if cfg.mtp_modules and self.is_initializing():

@@ -378,23 +378,33 @@ def _row_positions(shape: tuple, heads: int, positions: int):
     return out
 
 
-def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
-                             v_hbm, o_ref, k_buf, v_buf, sems, carry, *,
+def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
                              page: int, chunk: int, heads: int,
                              kv_heads: int, depth: int, window: int,
                              scale: float, softmax_dtype,
-                             positions: int = 1, causal: bool = True):
+                             positions: int = 1, block: int = 1,
+                             halves: bool = False):
     """One slot: online softmax over its live pages, a chunk of
     ``chunk`` pages a step of the inner loop, its scores and running
     terms kept in ``softmax_dtype``. ``positions`` > 1: the slot's
-    newest ``positions`` keys are query positions too (a verify
-    block): row r * heads + h is head h of the query at key position
-    length - positions + r, masked to the keys up to its own and, in
-    a window layer, to its own newest ``window``; every live page is
-    still read once. ``causal`` False (``positions`` > 1, no window):
-    a block whose queries ALL see all ``length`` keys, its own
-    ``positions`` among them (a block denoised as one: nothing to mask
-    by row).
+    newest ``positions`` keys are query positions too: row
+    r * heads + h is head h of the query at key position
+    length - positions + r; every live page is still read once. What a
+    query sees is the VISIBLE BLOCK's rule: position r sees the keys
+    below length - positions + block * (r // block + 1). ``block`` 1
+    is a verify block (each query the keys up to its own and, in a
+    window layer, its own newest ``window``); ``block`` == positions
+    a block whose queries ALL see all ``length`` keys (a block
+    denoised as one: nothing to mask by row); a ``block`` between
+    them (no window) is block-causal between the call's blocks.
+
+    ``halves`` (each half of the positions whole visible blocks: two
+    blocks a slot, or a control's eight causal positions): one scalar
+    more rides the prefetch, behind ``next_ref``: the slot's LIVE query
+    positions. A slot with no more than half of them live has a dead
+    second half: its program is the call's over the first half's rows
+    alone (no row of which sees a key of the second half's), and the
+    second half's output is zeros.
 
     The hand-over between programs: k_buf, v_buf, the semaphores and
     ``carry`` (SMEM: [0] the buffer half the next seated slot's chunk
@@ -411,6 +421,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
     output block and touches nothing else: no DMA started, none waited
     for, ``carry`` and the semaphores as it found them, so a fetch in
     flight passes over it to the slot it is for."""
+    live_ref, refs = (refs[0], refs[1:]) if halves else (None, refs)
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, carry = refs
     b = pl.program_id(0)
     batch = pl.num_programs(0)
     rows = q_ref.shape[0]
@@ -480,8 +492,9 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
         # no page fetched, no tile through the MXU, nothing to wait for
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(length > 0)
-    def _attend():
+    def attend(count):
+        """The slot's first ``count`` query rows against its live
+        pages, zeros for the rows behind them."""
         base = carry[0]         # the half chunk 0 is in, or is to go in
         heir = next_ref[b]      # the next seated slot
 
@@ -490,8 +503,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
             start(b, mine, 0, base)
 
         carry[1] = 0
-        q = q_ref[...]                                   # [rows, D]
-        mask = _group_block_mask(rows, heads, kv_heads, depth,
+        q = q_ref[...] if count == rows else q_ref[:count]   # [count, D]
+        mask = _group_block_mask(count, heads, kv_heads, depth,
                                  positions)
         q_bd = jnp.where(
             mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
@@ -516,15 +529,18 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
                 q_bd, k_buf[half].astype(q.dtype),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            scores = kept_in(scores, softmax_dtype)  # [rows, span]
+            scores = kept_in(scores, softmax_dtype)  # [count, span]
             pos = (first + c * chunk) * page + \
                 jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            if positions == 1 or not causal:
+            if positions == 1 or block == positions:
                 visible = (pos >= low) & (pos < length)
             else:
-                # row r * heads + h: keys below its own position + 1
-                upper = length - (positions - 1) + _row_positions(
-                    scores.shape, heads, positions)
+                # row r * heads + h: the keys below its block's end
+                # (block 1: its own position + 1)
+                upper = length - (positions - block)
+                ahead = _row_positions(scores.shape, heads * block,
+                                       positions // block)
+                upper = upper + (ahead if block == 1 else ahead * block)
                 visible = pos < upper
                 if window:
                     visible &= pos >= upper - window
@@ -537,20 +553,36 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, q_ref, k_hbm,
             pv = jax.lax.dot_general(
                 p.astype(q.dtype), v_buf[half].astype(q.dtype),
                 (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [rows, Hkv*D]
+                preferred_element_type=jnp.float32)  # [count, Hkv*D]
             return (kept_in(o * correction + pv, softmax_dtype), m_new,
                     kept_in(l, softmax_dtype))
 
         o, _m, l = jax.lax.fori_loop(
             0, chunks, body,
-            (jnp.zeros((rows, kv_heads * depth), jnp.float32),
-             jnp.full((rows, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((rows, 1), jnp.float32)))
+            (jnp.zeros((count, kv_heads * depth), jnp.float32),
+             jnp.full((count, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((count, 1), jnp.float32)))
         out = jnp.where(mask, o / jnp.where(l == 0.0, 1.0, l), 0.0)
         # each row has one live block of D columns (its K/V head's):
-        # the sum over the blocks folds [rows, Hkv*D] into [rows, D]
-        o_ref[...] = sum(out[:, h * depth:(h + 1) * depth]
-                         for h in range(kv_heads)).astype(o_ref.dtype)
+        # the sum over the blocks folds [count, Hkv*D] into [count, D]
+        out = sum(out[:, h * depth:(h + 1) * depth]
+                  for h in range(kv_heads)).astype(o_ref.dtype)
+        if count == rows:
+            o_ref[...] = out
+        else:
+            o_ref[:count] = out
+            o_ref[count:] = jnp.zeros_like(o_ref[count:])
+
+    if not halves:
+        pl.when(length > 0)(lambda: attend(rows))
+    else:
+        # all of the slot's query rows, or, its second half dead, the
+        # first half alone: by the same rule, which lets no row of the
+        # first half see a key of the second
+        both = live_ref[b] > positions // 2
+        pl.when((length > 0) & both)(lambda: attend(rows))
+        pl.when((length > 0) & jnp.logical_not(both))(
+            lambda: attend(rows // 2))
 
 
 def next_seated(lengths):
@@ -567,24 +599,33 @@ def next_seated(lengths):
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "softmax_dtype", "name",
-                                    "causal"),
+                                    "block"),
                    inline=True)
 def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
                                       lengths, window: int = 0,
                                       softmax_dtype=jnp.float32,
                                       name: Optional[str] =
                                       GQA_KERNEL_NAME,
-                                      causal: bool = True):
+                                      block: int = 0,
+                                      live_positions=None):
     """Pallas path for a pool of Hkv <= H K/V heads. q: [B, S, H, D];
     k_pages/v_pages: [P, page, Hkv*D]; lengths: [B] valid-key counts
     (the S tokens written this step included). S == 1 is the decode
-    step; S > 1 a verify block whose query r sits at key position
-    length - S + r and sees the keys up to its own (S * H query rows
-    a slot against each live page, read ONCE). ``window`` > 0: a query
-    sees its newest ``window`` keys alone (for S == 1 positions
-    length - window .. length - 1), and no page wholly behind them is
-    read. ``causal`` False (S > 1, no window): every query of the
-    block sees all ``lengths`` keys, the block's own S included.
+    step; S > 1 a call whose query r sits at key position
+    length - S + r (S * H query rows a slot against each live page,
+    read ONCE). ``window`` > 0: a query sees its newest ``window``
+    keys alone (for S == 1 positions length - window .. length - 1),
+    and no page wholly behind them is read. ``block`` is the VISIBLE
+    BLOCK (S a whole number of them; no window beside one over 1):
+    query r sees the keys below length - S + block * (r // block + 1).
+    0 or 1: the keys up to its own (a verify block); S: every query
+    all ``lengths`` keys, the call's own S included (a block denoised
+    as one); between them: block-causal between the call's blocks.
+    ``live_positions`` ([B] int32; each half of S whole visible
+    blocks: a block pass's two blocks a slot): how many of a slot's S
+    query positions anybody reads. A slot with no more than S / 2 of
+    them costs what a call of its first half alone costs, and has
+    zeros for its second half's rows.
     block_table: [B, T] int32, entry p % T the page of logical page p:
     a table as wide as the context is an ordinary block table, a
     narrower one a RING (its T pages hold the newest T logical pages;
@@ -602,13 +643,17 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     batch, seq, heads, depth = q.shape
     page, width = k_pages.shape[1], k_pages.shape[2]
     kv_heads = width // depth
-    if not causal and window:
-        raise NotImplementedError(
-            "a block whose queries see all keys, under a window")
+    block = _visible_block(block, seq, window)
+    halves = live_positions is not None
     if heads % kv_heads or kv_heads * depth != width:
         raise ValueError(
             f"{heads} query heads over a pool of {width} channels "
             f"(heads of {depth})")
+    if halves and (seq % (2 * block) or seq // 2 * heads % 16):
+        raise ValueError(
+            f"live_positions: two halves a slot, each whole visible "
+            f"blocks and whole sublane tiles of query rows ({seq} "
+            f"positions, blocks of {block}, {heads} heads)")
     # whole sublane tiles of query rows (bfloat16 packs 16 a tile)
     rows = -(-seq * heads // 16) * 16
     q_rows = jnp.pad(q.reshape(batch, seq * heads, depth),
@@ -616,10 +661,13 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     chunk = gqa_chunk_pages(page, width, k_pages.dtype.itemsize,
                             block_table.shape[1])
     lengths = lengths.astype(jnp.int32)
+    scalars = (block_table.astype(jnp.int32), lengths,
+               next_seated(lengths)) + (
+        (live_positions.astype(jnp.int32),) if halves else ())
     row_spec = pl.BlockSpec((None, rows, depth),
-                            lambda b, tbl, ln, nxt: (b, 0, 0))
+                            lambda b, *scalars: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(batch,),
         in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -637,36 +685,55 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
             window=int(window), scale=1.0 / (depth ** 0.5),
             softmax_dtype=softmax_dtype,
             **({} if seq == 1 else {"positions": seq}),
-            **({} if causal or seq == 1 else {"causal": False})),
+            **({} if block == 1 else {"block": block}),
+            **({"halves": True} if halves else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, rows, depth), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=name,
-    )(block_table.astype(jnp.int32), lengths, next_seated(lengths),
-      q_rows, k_pages, v_pages)
+    )(*scalars, q_rows, k_pages, v_pages)
     return out[:, :seq * heads].reshape(batch, seq, heads, depth)
+
+
+def _visible_block(block: int, seq: int, window: int) -> int:
+    """A call's visible block as its mask rule reads it: 1 for a call
+    of one position or with none given (each query the keys up to its
+    own); else ``block``, of which ``seq`` must be a whole number and
+    beside which there is no window."""
+    if seq == 1 or block <= 1:
+        return 1
+    if seq % block:
+        raise ValueError(
+            f"{seq} query positions are no whole number of visible "
+            f"blocks of {block}")
+    if window:
+        raise NotImplementedError(
+            "a visible block of several positions under a window")
+    return block
 
 
 def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
                                         block_table, lengths,
                                         window: int = 0,
                                         softmax_dtype=jnp.float32,
-                                        causal: bool = True):
+                                        block: int = 0,
+                                        live_positions=None):
     """The kernel above as an XLA gather (the CPU/fallback path and
     the tests' oracle): every table entry's page gathered, each row's
     POSITION worked out from the entry it came through (entry c holds
     the newest logical page p <= the last with p % T == c: the ring
     rule, which for a table as wide as the context is p == c), and one
     masked softmax over the positions the window admits; query r of S
-    at key position length - S + r (``causal`` False: every query r
-    sees all ``lengths`` keys). A slot of length 0 yields zeros."""
+    at key position length - S + r sees the keys below
+    length - S + block * (r // block + 1) (``block``: the kernel's
+    visible block; 0 or 1: the keys up to its own). A slot of length 0
+    yields zeros, and so do the query positions from a slot's
+    ``live_positions`` on (the kernel's; any S here)."""
     batch, seq, heads, depth = q.shape
     page = k_pages.shape[1]
     entries = block_table.shape[1]
-    if not causal and window:
-        raise NotImplementedError(
-            "a block whose queries see all keys, under a window")
+    block = _visible_block(block, seq, window)
     kv_heads = k_pages.shape[2] // depth
     k_all = k_pages[block_table].reshape(
         batch, entries * page, kv_heads, depth)
@@ -686,17 +753,22 @@ def paged_decode_attention_xla_windowed(q, k_pages, v_pages,
             q, k_all, v_all, visible[:, None, None, :], q.dtype,
             softmax_dtype), lengths)
     # [B, S]: the keys query r sees are those below upper[:, r]
-    upper = lengths[:, None] - (seq - 1) + jnp.arange(
-        seq, dtype=jnp.int32)[None, :]
-    if not causal:
-        upper = jnp.broadcast_to(lengths[:, None], upper.shape)
+    upper = lengths[:, None] - (seq - 1)
+    ends = jnp.arange(seq, dtype=jnp.int32)
+    if block > 1:
+        ends = (ends // block + 1) * block - 1
+    upper = upper + ends[None, :]
     low = window_start(upper, window)
     pos = pos[:, None, :]
     visible = (pos >= low[:, :, None]) & (pos < upper[:, :, None]) & (
         pos >= 0)
-    return _zero_where_empty(masked_attention(
+    out = _zero_where_empty(masked_attention(
         q, k_all, v_all, visible[:, None], q.dtype, softmax_dtype),
         lengths)
+    if live_positions is None:
+        return out
+    dead = jnp.arange(seq)[None, :] >= live_positions[:, None]
+    return jnp.where(dead[:, :, None, None], jnp.zeros_like(out), out)
 
 
 def resolve_kernel_or_xla(impl: Optional[str], what: str) -> str:
@@ -749,12 +821,11 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
     and the windowed gather read no scales. Where the gather can serve
     (a grouped int8 pool), None falls back to it on a TPU too and a
     named "kernel" raises NotImplementedError; under a window every
-    int8 call does. ``positions`` > 1 (a verify block: several query
-    positions a slot) is the last row's for any pool: gqa_kernel and
-    xla_windowed alone mask by query position, and they alone take a
-    block whose queries all see all keys (the dispatch's ``causal``
-    False: a block denoised as one, Attention._decode_attend_paged
-    of a model with TransformerConfig.block_diffusion)."""
+    int8 call does. ``positions`` > 1 (several query positions a slot:
+    a verify block, or the blocks of a model with TransformerConfig.
+    block_diffusion) is the last row's for any pool: gqa_kernel and
+    xla_windowed alone mask by query position, by the dispatch's
+    visible ``block``, and they alone take ``live_positions``."""
     want = resolve_paged_impl(impl)
     if _plain_call(grouped, window, positions) and (
             int8 or want == "xla"):
@@ -772,16 +843,20 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                            impl: Optional[str] = None,
                            k_scales=None, v_scales=None,
                            window: int = 0, softmax_dtype=jnp.float32,
-                           causal: bool = True):
+                           block: int = 0, live_positions=None):
     """Dispatch by paged_decode_road (the selection rule's one table).
     k_scales/v_scales switch an MHA pool to its int8 kernel and the
     plain gather to int8-page dequant. ``window`` > 0: a layer that
     sees its newest ``window`` keys alone; its table may then be a
     RING narrower than the context, entry p % T the page of logical
-    page p. q of S > 1 positions a slot is a verify block (the last S
-    keys are the queries' own; ``causal`` False: every query of the
-    block sees all ``lengths`` keys, where the verify block masks
-    query r to the keys up to its own). The grouped kernel and the
+    page p. q of S > 1 positions a slot: the last S keys are the
+    queries' own, and query r sees the keys below
+    length - S + block * (r // block + 1), ``block`` being the visible
+    block (0 or 1: a verify block, each query the keys up to its own;
+    S: a block denoised as one; between: block-causal between the
+    call's blocks, of which ``live_positions`` [B] says how many of a
+    slot's positions anybody reads:
+    gqa_paged_decode_attention_kernel). The grouped kernel and the
     windowed gather alone keep their softmax in ``softmax_dtype``
     (kept_in).
     On every road a slot of length 0 yields zeros, and on the kernels'
@@ -801,11 +876,13 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
         return gqa_paged_decode_attention_kernel(
             q, k_pages, v_pages, block_table, lengths, window=window,
             softmax_dtype=softmax_dtype,
-            name=None if plain else GQA_KERNEL_NAME, causal=causal)
+            name=None if plain else GQA_KERNEL_NAME, block=block,
+            live_positions=live_positions)
     if road == "xla_windowed":
         return paged_decode_attention_xla_windowed(
             q, k_pages, v_pages, block_table, lengths, window=window,
-            softmax_dtype=softmax_dtype, causal=causal)
+            softmax_dtype=softmax_dtype, block=block,
+            live_positions=live_positions)
     if road == "kernel":
         return paged_decode_attention_kernel(
             q, k_pages, v_pages, block_table, lengths, k_scales,

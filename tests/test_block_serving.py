@@ -2,11 +2,13 @@
 (TransformerConfig.block_diffusion; serving._denoise_or_commit), at a
 small size on the CPU with seeded weights, against the plain reference
 benchmark/reference/sdar_plain.py: the prefill of a prompt's whole
-blocks, then a block denoised pass by pass and committed, for every
-remainder of the prompt's length mod the block and both remasking
-rules; a request that ends inside a block, an eos inside one, two
-slots at different passes in one step, a slot preempted in mid-block,
-what streams; and the record the engine hands over."""
+blocks, then a block denoised pass by pass and committed by the pass
+that opens the next one, for every remainder of the prompt's length
+mod the block and both remasking rules; what the schedule costs; a
+request that ends inside a block, an eos inside one, a closing slot
+beside a plain one in one step, a first block closed at once, a slot
+preempted in mid-block, what streams; and the record the engine hands
+over."""
 
 import dataclasses
 
@@ -90,7 +92,8 @@ def served(request):
     """Six requests through one engine of three slots under one rule,
     with what every pass of slot 0 read (its block, its mask, its
     first position) and the logits the engine's own model gives for
-    it from the engine's own cache, taken before each step."""
+    the pass's two blocks from the engine's own cache, taken before
+    each step."""
     remask, gain = RULES[request.param]
     model, dims, params, config = _built(remask, gain)
     engine = _engine(config, params)
@@ -103,16 +106,21 @@ def served(request):
         slot = engine._slots[0]
         if slot.request is None or not slot.decoding():
             return
-        block = np.asarray(engine._tokens)[0, 0]
+        block = np.asarray(engine._tokens)[0, 0, :BLOCK]
         masked = np.asarray(engine._masked)[0]
         start = int(np.asarray(engine._positions)[0])
         # the pass the next step runs for slot 0, by the engine's model
-        # on the engine's cache (a copy: apply donates nothing)
+        # on the engine's cache (a copy: apply donates nothing): the
+        # open block, mask token where masked, and a block of masks
         logits, _ = engine.model.apply(
             {"params": params, "cache": engine.cache},
-            jnp.where(engine._masked, MASK, engine._tokens[:, 0]),
+            jnp.concatenate(
+                [jnp.where(engine._masked, MASK,
+                           engine._tokens[:, 0, :BLOCK]),
+                 jnp.full_like(engine._tokens[:, 0, BLOCK:], MASK)],
+                axis=1),
             positions=engine._positions[:, None]
-            + jnp.arange(BLOCK)[None], mutable=serving._MUTABLE)
+            + jnp.arange(2 * BLOCK)[None], mutable=serving._MUTABLE)
         passes.append((slot.request.request_id, start, masked.copy(),
                        block.copy(), np.asarray(logits[0], np.float32)))
 
@@ -166,32 +174,48 @@ def test_the_record_names_every_pass(served):
             assert set(passes) == set(range(passes.max() + 1))
             if rule != "dynamic_above_it":
                 assert len(passes) == len(set(passes))   # one a pass
-    # the counters: every committed block's passes, an overshoot pass
-    # a request at most (the one in flight when its last block landed)
-    assert stats["block_commit_passes"] >= blocks
-    assert stats["block_commit_passes"] <= blocks + len(records)
+    # the counters: every block is closed by a pass that denoises the
+    # next one and no pass does nothing but commit; behind a request's
+    # last block its closing pass opens one more and an overshoot pass
+    # (the one in flight when the last block landed) may denoise it
+    assert stats["block_commit_passes"] == 0
+    if rule == "dynamic_above_it":
+        # the overshoot pass may find the block behind the last
+        # unmasked whole, and close it
+        assert blocks <= stats["block_commits_fused"] \
+            <= blocks + len(records)
+    else:
+        assert stats["block_commits_fused"] == blocks
     assert generated <= stats["block_positions_unmasked"] \
-        <= generated + BLOCK * len(records)
+        <= generated + 2 * BLOCK * len(records)
     # the tokens served, not what was dropped behind a request's last
     assert stats["block_tokens_landed"] == \
         sum(map(len, done.values())) < generated
     if rule == "dynamic_above_it":
         # wide logits: confidences pass 0.9 and passes unmask several
-        assert stats["block_denoise_passes"] < 0.5 * generated
+        # (a request's last closing pass and its overshoot set apart)
+        assert stats["block_denoise_passes"] - 2 * len(records) \
+            < 0.5 * generated
     else:
-        assert stats["block_denoise_passes"] >= generated
+        # a pass a position, the closing pass behind a request's last
+        # block and an overshoot pass at most: nothing for the commits
+        assert generated + len(records) <= stats["block_denoise_passes"] \
+            <= generated + 2 * len(records)
 
 
 def test_engine_logits_agree_with_the_reference(served):
     """Each pass of slot 0's requests, as the engine's own model
     computes it from the engine's own cache (what the step program
     runs), against sdar_plain's noisy copy of the same block and pass
-    at every position: float32 on both sides, so the tolerance is the
-    order of the sums alone (a paged gather against one masked softmax
-    over the extended sequence), 2e-4 of the logits' scale."""
+    at every position: of a plain pass the open block's, of a closing
+    pass the finished block's clean copy and, where the record has the
+    block behind it, that block's first pass; float32 on both sides,
+    so the tolerance is the order of the sums alone (a paged gather
+    against one masked softmax over the extended sequence), 2e-4 of
+    the logits' scale."""
     _rule, _model, dims, params, _engine_, prompts, _done, records, \
         passes = served
-    compared = 0
+    compared = closing = 0
     for request_id, record in records.items():
         mine = [p for p in passes if p[0] == request_id]
         if not mine:
@@ -211,21 +235,28 @@ def test_engine_logits_agree_with_the_reference(served):
                 continue            # the overshoot pass behind the end
             mine_at = at[first - start:first - start + BLOCK]
             if not masked.any():
-                copy = plain.CLEAN      # a commit pass
+                # a closing pass: the finished block, then the next
+                # one's first pass, all masked
+                halves = [(first, plain.CLEAN, logits[:BLOCK])]
+                if first + 2 * BLOCK <= len(clean):
+                    halves.append((first + BLOCK, 0, logits[BLOCK:]))
+                    closing += 1
             else:
                 # the pass whose mask this is: the record's own
                 copy = int(mine_at[masked].min())
                 assert (masked == ((mine_at >= copy)
                                    & (mine_at < STEPS))).all()
-            rows = [plain.extended_row(first + i, copy, len(clean),
-                                       start) for i in range(BLOCK)]
-            want = np.asarray(plain.head_logits(
-                hidden[np.asarray(rows)], params["final_norm"],
-                params["lm_head"]["kernel"], dims["eps"]))
-            scale = np.abs(want).max()
-            np.testing.assert_allclose(logits, want, atol=2e-4 * scale)
-            compared += 1
-    assert compared >= 10
+                halves = [(first, copy, logits[:BLOCK])]
+            for at_first, copy, got in halves:
+                rows = [plain.extended_row(at_first + i, copy, len(clean),
+                                           start) for i in range(BLOCK)]
+                want = np.asarray(plain.head_logits(
+                    hidden[np.asarray(rows)], params["final_norm"],
+                    params["lm_head"]["kernel"], dims["eps"]))
+                scale = np.abs(want).max()
+                np.testing.assert_allclose(got, want, atol=2e-4 * scale)
+                compared += 1
+    assert compared >= 10 and closing >= 2
 
 
 def test_the_reference_judges_every_token_and_choice_as_its_own(served):
@@ -262,10 +293,53 @@ def test_a_record_that_does_not_hold_the_served_tokens_is_refused(served):
         params, prompts[0], done["r0"], short, model, dims) is None
 
 
+def test_the_schedule_costs_a_block_its_denoise_passes():
+    """One request of three blocks under the static rule (a position a
+    pass): a block costs its ``steps`` passes and no pass of its own
+    for the commit, which rides the next block's first; behind the
+    last block come its closing pass and, dispatched before that
+    landed, one overshoot pass."""
+    _model, _dims, params, config = _built()
+    engine = _engine(config, params, num_slots=1)
+    engine.submit(Request("s", _prompts((8,), seed=8)[0], 3 * BLOCK))
+    assert len(_drain(engine)["s"]) == 3 * BLOCK
+    stats = engine.step_stats()
+    assert engine.decode_steps == 3 * STEPS + 2
+    assert stats["block_denoise_passes"] == 3 * STEPS + 2
+    assert stats["block_commit_passes"] == 0
+    assert stats["block_commits_fused"] == 3
+    assert stats["block_tokens_landed"] == 3 * BLOCK
+    commits = [launch.commits for launch in engine._ring
+               if launch.kind == "decode"]
+    assert commits == [0] * STEPS + ([1] + [0] * (STEPS - 1)) * 2 + [1, 0]
+
+
+def test_a_first_block_of_given_tokens_is_closed_by_the_next_pass():
+    """A prompt that leaves one position of its last block open: the
+    pass behind the prefill (plain: the seated block has a mask) fills
+    it, the very next one closes the block and opens the second, and
+    the reference judges every token its own."""
+    model, dims, params, config = _built()
+    prompt = _prompts((7,), seed=9)[0]              # 3 given, 1 masked
+    engine = _engine(config, params, num_slots=1)
+    engine.submit(Request("g", prompt, 5))
+    done = _drain(engine)["g"]
+    commits = [launch.commits for launch in engine._ring
+               if launch.kind == "decode"]
+    assert commits[:2] == [0, 1]
+    record = engine.take_decisions("g")
+    assert record["layers"]["unmask"][4:8, 0].tolist() == [STEPS] * 3 + [0]
+    read = MODULE.request_readings(params, prompt, done, record, model,
+                                   dims)
+    assert len(read["gaps"]) == 5 and max(read["gaps"]) < 1e-4
+    assert max(read["unmask_slack"]) < 1e-4
+
+
 def test_two_slots_at_different_passes_in_one_step():
     """The second request joins while the first is in mid-block: one
-    launch then holds a committing slot beside a denoising one, and
-    both requests read what they read alone."""
+    launch then holds a closing slot (two live blocks) beside a plain
+    one (a dead second half), and both requests read what they read
+    alone."""
     _model, _dims, params, config = _built()
     prompts = _prompts((9, 6), seed=4)
     alone = {}
@@ -283,7 +357,7 @@ def test_two_slots_at_different_passes_in_one_step():
     mixed = [launch for launch in engine._ring
              if launch.kind == "decode" and launch.rows == 2
              and launch.commits == 1]
-    assert mixed, "no step held a commit pass beside a denoise pass"
+    assert mixed, "no step held a closing slot beside a plain one"
 
 
 def test_a_slot_preempted_in_mid_block_generates_its_block_again():

@@ -712,7 +712,7 @@ def test_the_models_live_mask_parks_a_slot_at_any_cursor(
 
 
 # ---- a block whose queries all see all keys (a model that generates
-# ---- by diffusion over blocks: paged_decode_attention's causal=False)
+# ---- by diffusion over blocks: paged_decode_attention's visible block)
 
 @pytest.mark.parametrize("grouping", ("7to1", "mha", "sdar"))
 def test_a_block_whose_queries_all_see_all_keys(interpret_mode,
@@ -737,9 +737,9 @@ def test_a_block_whose_queries_all_see_all_keys(interpret_mode,
             q[:, r:r + 1], k_pages, v_pages, table, lengths))
         for r in range(4)], axis=1)
     xla = pa.paged_decode_attention_xla_windowed(
-        q, k_pages, v_pages, table, lengths, causal=False)
+        q, k_pages, v_pages, table, lengths, block=4)
     got = pa.gqa_paged_decode_attention_kernel(
-        q, k_pages, v_pages, table, lengths, causal=False)
+        q, k_pages, v_pages, table, lengths, block=4)
     np.testing.assert_allclose(np.asarray(xla), want, atol=2e-6,
                                rtol=2e-6)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-6,
@@ -756,10 +756,10 @@ def test_a_block_whose_queries_all_see_all_keys(interpret_mode,
                                 positions=4) == "gqa_kernel"
     np.testing.assert_allclose(np.asarray(pa.paged_decode_attention(
         q, k_pages, v_pages, table, lengths, impl="kernel",
-        causal=False)), want, atol=2e-6, rtol=2e-6)
+        block=4)), want, atol=2e-6, rtol=2e-6)
     np.testing.assert_allclose(np.asarray(pa.paged_decode_attention(
         q, k_pages, v_pages, table, lengths, impl="xla",
-        causal=False)), want, atol=2e-6, rtol=2e-6)
+        block=4)), want, atol=2e-6, rtol=2e-6)
 
 
 def test_a_block_of_all_keys_takes_no_window():
@@ -770,4 +770,190 @@ def test_a_block_of_all_keys_takes_no_window():
                  pa.paged_decode_attention_xla_windowed):
         with pytest.raises(NotImplementedError, match="window"):
             call(q, pool, pool, table, jnp.asarray([8]), window=5,
-                 causal=False)
+                 block=4)
+        with pytest.raises(ValueError, match="whole number"):
+            call(q, pool, pool, table, jnp.asarray([8]), block=3)
+
+
+# ---- two blocks a slot (the block pass that commits a finished block
+# ---- beside the next one's first denoise pass): block-causal BETWEEN
+# ---- the call's blocks, and a dead second block passed over
+
+def _by_hand(q, k_pages, v_pages, table, lengths, block):
+    """Softmax attention with the mask written out: query r of S at
+    key position L - S + r sees key j iff j < L - S + block *
+    (r // block + 1); float64, a slot and a head at a time."""
+    batch, seq, heads, depth = q.shape
+    kv_heads = k_pages.shape[2] // depth
+    group = heads // kv_heads
+    out = np.zeros(q.shape, np.float64)
+    k_pages, v_pages, q = (np.asarray(x, np.float64)
+                           for x in (k_pages, v_pages, q))
+    for b in range(batch):
+        length = int(lengths[b])
+        if not length:
+            continue
+        keys = k_pages[np.asarray(table)[b]].reshape(
+            -1, kv_heads, depth)[:length]
+        values = v_pages[np.asarray(table)[b]].reshape(
+            -1, kv_heads, depth)[:length]
+        for r in range(seq):
+            upto = length - seq + block * (r // block + 1)
+            for h in range(heads):
+                scores = keys[:upto, h // group] @ q[b, r, h] / \
+                    np.sqrt(depth)
+                weights = np.exp(scores - scores.max())
+                out[b, r, h] = weights @ values[:upto, h // group] / \
+                    weights.sum()
+    return out
+
+
+@pytest.mark.parametrize("block", (1, 4, 8))
+@pytest.mark.parametrize("grouping", ("2to1", "mha"))
+def test_the_visible_block_of_a_call_of_eight_positions(
+        interpret_mode, grouping, block):
+    """Eight query positions a slot under a visible block of 1 (each
+    query the keys up to its own: a verify block), 4 (two blocks,
+    block-causal between them) and 8 (all see all): the kernel and its
+    XLA twin against the mask written out by hand, a slot of length 0
+    between seated ones, lengths on and across a chunk's edge (64
+    keys)."""
+    heads, kv_heads, case = _GROUPINGS[grouping]
+    rng = np.random.RandomState(heads + block)
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.float32, heads, kv_heads, **case["shape"])
+    q = jnp.asarray(rng.randn(q.shape[0], 8, heads, q.shape[3]),
+                    jnp.float32)
+    lengths = jnp.asarray([8, 64, 0, 68, 93], jnp.int32)
+    want = _by_hand(q, k_pages, v_pages, table, lengths, block)
+    for call in (pa.gqa_paged_decode_attention_kernel,
+                 pa.paged_decode_attention_xla_windowed):
+        got = np.asarray(call(q, k_pages, v_pages, table, lengths,
+                              block=block))
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+        assert not got[2].any()
+    if block == 1:      # no block given: the same call
+        np.testing.assert_array_equal(
+            np.asarray(pa.gqa_paged_decode_attention_kernel(
+                q, k_pages, v_pages, table, lengths)),
+            np.asarray(pa.gqa_paged_decode_attention_kernel(
+                q, k_pages, v_pages, table, lengths, block=1)))
+
+
+@pytest.mark.parametrize("block", (4, 1))
+@pytest.mark.parametrize("grouping", ("2to1", "mha", "sdar"))
+def test_a_slots_dead_second_block_is_passed_over(interpret_mode,
+                                                  grouping, block):
+    """Two blocks of four positions a slot and ``live_positions``: a
+    slot with all eight live is the block-causal call; one with four
+    (a plain slot of the block pass: its second block is filler) gives
+    for its first block what the ONE-block call gives four keys
+    earlier, and zeros for the rest, as a slot of length 0 does for
+    all; kernel and twin, and through the dispatch. ``block`` 1: the
+    same eight positions under the plain causal mask (the cell's
+    control that keeps the mask causal inside a block)."""
+    heads, kv_heads, case = {**_GROUPINGS, "sdar": (32, 4, _SERVED)}[
+        grouping]
+    rng = np.random.RandomState(heads)
+    q, k_pages, v_pages, table = _grouped_case(
+        rng, jnp.float32, heads, kv_heads, **case["shape"])
+    batch = q.shape[0]
+    q = jnp.asarray(rng.randn(batch, 8, heads, q.shape[3]), jnp.float32)
+    lengths = jnp.asarray(([8, 64, 0, 68, 93, 72, 520] if batch == 7
+                           else [8, 64, 0, 68, 93]), jnp.int32)
+    live = jnp.asarray([8, 4, 0, 4, 8, 4, 8][:batch], jnp.int32)
+    both = np.asarray(pa.paged_decode_attention_xla_windowed(
+        q, k_pages, v_pages, table, lengths, block=block))
+    first = np.asarray(pa.paged_decode_attention_xla_windowed(
+        q[:, :4], k_pages, v_pages, table,
+        jnp.maximum(lengths - 4, 0), block=block))
+    want = np.where((np.asarray(live) == 8)[:, None, None, None], both,
+                    np.concatenate([first, np.zeros_like(first)], axis=1))
+    want[np.asarray(live) == 0] = 0.0
+    for impl in ("kernel", "xla"):
+        got = np.asarray(pa.paged_decode_attention(
+            q, k_pages, v_pages, table, lengths, impl=impl,
+            block=block, live_positions=live))
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+        assert not got[np.asarray(live) == 4][:, 4:].any()
+
+
+def test_live_positions_are_of_two_halves_of_whole_blocks_and_tiles():
+    pool = jnp.zeros((8, 8, 128), jnp.float32)
+    table = jnp.zeros((1, 4), jnp.int32)
+    live = jnp.asarray([4], jnp.int32)
+    for positions, heads in ((4, 4), (8, 2)):
+        with pytest.raises(ValueError, match="two halves a slot"):
+            pa.gqa_paged_decode_attention_kernel(
+                jnp.zeros((1, positions, heads, 64), jnp.float32), pool,
+                pool, table, jnp.asarray([8]), block=4,
+                live_positions=live)
+
+
+@pytest.mark.parametrize("bidirectional", (True, False))
+@pytest.mark.parametrize("impl", ("xla", "kernel"))
+def test_the_models_two_block_insert_crosses_a_pages_edge(
+        interpret_mode, impl, bidirectional):
+    """A block-diffusion model's paged insert of TWO blocks from a
+    cursor one block short of a page's edge: the second block's rows
+    land at the head of the slot's next page, the first block sees
+    nothing of them (its logits are the one-block insert's), a slot
+    whose second block is dead (int32 ``live``: 4 of 8 positions) has
+    the same first block, and ``head_rows`` picks the rows the head
+    runs over. ``bidirectional`` False: the control's model, whose mask
+    stays causal inside a block, through the same calls."""
+    from batch_shipyard_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=2, d_ff=128, n_heads=4,
+        n_kv_heads=2, d_head=16, dtype=jnp.float32, decode=True,
+        max_decode_len=32, kv_page_size=8, kv_num_pages=16,
+        spec_window=8, paged_attention_impl=impl,
+        block_diffusion=tfm.BlockDiffusion(
+            block=4, steps=4, mask_id=127, bidirectional=bidirectional))
+    model = tfm.TransformerLM(cfg)
+    rng = np.random.RandomState(0)
+    tokens = jnp.asarray(rng.randint(1, 120, (2, 8)), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
+                           positions=jnp.zeros((2, 1), jnp.int32))
+    params = variables["params"]
+    table = jnp.asarray([[3, 7, 1, 2, 13], [11, 5, 4, 6, 14]], jnp.int32)
+
+    def seated(node):
+        if isinstance(node, dict) and "length" in node:
+            return {**node, "length": jnp.zeros_like(node["length"]),
+                    "block_table": table}
+        return node
+
+    cache = jax.tree_util.tree_map(
+        seated, variables["cache"],
+        is_leaf=lambda x: isinstance(x, dict) and "length" in x)
+
+    def fed(cache, tokens, start, **kwargs):
+        positions = start + jnp.arange(tokens.shape[1])[None] + \
+            jnp.zeros((2, 1), jnp.int32)
+        out, mutated = model.apply(
+            {"params": params, "cache": cache}, tokens,
+            positions=positions, mutable=["cache"], **kwargs)
+        return np.asarray(out), mutated["cache"]
+
+    # a block of context, then the cursor stands at 4: blocks at 4..7
+    # (page 0 of the slot) and 8..11 (its page 1)
+    _, cache = fed(cache, tokens[:, :4], 0)
+    one, _ = fed(cache, tokens[:, 4:], 4)
+    assert one.shape == (2, 4, 128)
+    two, after = fed(cache, jnp.concatenate(
+        [tokens[:, 4:], jnp.full((2, 4), 127, jnp.int32)], axis=1), 4)
+    np.testing.assert_allclose(two[:, :4], one, atol=1e-5)
+    layer = after["layer_0"]["attn"]
+    assert list(np.asarray(layer["length"])) == [12, 12]
+    for b in range(2):
+        # the slot's second page holds the second block's four rows
+        assert np.asarray(layer["k_pages"])[table[b, 1], :4].any()
+        assert not np.asarray(layer["k_pages"])[table[b, 1], 4:].any()
+    dead, _ = fed(cache, jnp.concatenate(
+        [tokens[:, 4:], jnp.full((2, 4), 127, jnp.int32)], axis=1), 4,
+        live=jnp.asarray([8, 4], jnp.int32),
+        head_rows=jnp.asarray([[4, 5, 6, 7], [0, 1, 2, 3]], jnp.int32))
+    np.testing.assert_allclose(dead[0], two[0, 4:], atol=1e-5)
+    np.testing.assert_allclose(dead[1], one[1], atol=1e-5)

@@ -44,7 +44,8 @@ def _seated_case(rng, dtype, heads, kv_heads, lengths, depth=64, page=8,
 # call, 2 pages = 128 keys a chunk), K-EXAONE's two query positions
 # over a full table (8 pages = 512 keys a chunk) and over its ring of
 # 4 pages under a window of 128, SDAR's block of four positions that
-# all see all keys.
+# all see all keys, and its two blocks of a block pass (block-causal
+# between them; every other seated slot's second block dead).
 _SMALL_ROAD = dict(dtype=jnp.float32, heads=14, kv_heads=2, tol=2e-6,
                    pool=1 + 8 * 24)
 _SERVED_ROAD = dict(dtype=jnp.bfloat16, depth=128, page=64, tol=3e-2)
@@ -55,14 +56,19 @@ _HANDOVER_ROADS = {
     "ring": dict(_SMALL_ROAD, window=20, entries=4),
     "verify": dict(_SMALL_ROAD, positions=2),
     "verify-ring": dict(_SMALL_ROAD, positions=2, window=16, entries=4),
-    "block": dict(_SMALL_ROAD, positions=4, causal=False),
+    "block": dict(_SMALL_ROAD, positions=4, block=4),
+    "two-blocks": dict(_SMALL_ROAD, heads=4, positions=8, block=4),
+    "two-blocks-some-dead": dict(_SMALL_ROAD, heads=4, positions=8,
+                                 block=4, live=True),
     "baichuan": dict(_SERVED_ROAD, heads=32, kv_heads=32, entries=32),
     "kexaone-full": dict(_SERVED_ROAD, heads=64, kv_heads=8,
                          entries=128, positions=2),
     "kexaone-ring": dict(_SERVED_ROAD, heads=64, kv_heads=8, entries=4,
                          positions=2, window=128),
     "sdar": dict(_SERVED_ROAD, heads=32, kv_heads=4, entries=129,
-                 positions=4, causal=False),
+                 positions=4, block=4),
+    "sdar-two-blocks": dict(_SERVED_ROAD, heads=32, kv_heads=4,
+                            entries=129, positions=8, block=4, live=True),
 }
 # arrangement -> lengths of EIGHT slots (one compiled program a road
 # for all of them), in keys at 64 a chunk (the served roads scale them
@@ -83,10 +89,12 @@ _ARRANGEMENTS = {
 _HANDOVER_CASES = (
     [("grouped", name) for name in sorted(_ARRANGEMENTS)] +
     [(road, name) for road in ("mha", "window", "ring", "verify",
-                               "verify-ring", "block")
+                               "verify-ring", "block", "two-blocks",
+                               "two-blocks-some-dead")
      for name in ("first-parked", "runs-parked", "odd-even")] +
     [(road, "runs-parked") for road in ("baichuan", "kexaone-full",
-                                        "kexaone-ring", "sdar")] +
+                                        "kexaone-ring", "sdar",
+                                        "sdar-two-blocks")] +
     [("baichuan", "odd-even"), ("kexaone-ring", "last-parked")])
 
 
@@ -109,7 +117,7 @@ def test_the_next_seated_slots_first_chunk_is_handed_over(
     reports."""
     spec = dict(_HANDOVER_ROADS[road])
     tol, window = spec.pop("tol"), spec.pop("window", 0)
-    causal = spec.pop("causal", True)
+    block, some_dead = spec.pop("block", 0), spec.pop("live", False)
     positions = spec.get("positions", 1)
     page = spec.get("page", 8)
     entries = spec.get("entries", 24)
@@ -125,13 +133,18 @@ def test_the_next_seated_slots_first_chunk_is_handed_over(
     q, k_pages, v_pages, table = _seated_case(
         rng, lengths=lengths, **spec)
     lengths = jnp.asarray(lengths, jnp.int32)
+    # every other slot's second block dead (a block pass's plain slot)
+    live = jnp.asarray([positions // (1 + b % 2)
+                        for b in range(len(lengths))], jnp.int32) \
+        if some_dead else None
     if road == "baichuan":
-        def call(*args):        # the unnamed call, by the dispatch
+        def call(*args, at=None):   # the unnamed call, by the dispatch
             return pa.paged_decode_attention(*args, impl="kernel")
     else:
-        def call(*args):
+        def call(*args, at=slice(None)):
             return pa.gqa_paged_decode_attention_kernel(
-                *args, window=window, causal=causal)
+                *args, window=window, block=block,
+                live_positions=None if live is None else live[at])
 
     assert list(np.asarray(pa.next_seated(lengths))) == [
         min([j for j in range(b + 1, len(lengths)) if lengths[j] > 0],
@@ -143,13 +156,13 @@ def test_the_next_seated_slots_first_chunk_is_handed_over(
     assert "non-zero count" not in capsys.readouterr().out
     want = np.asarray(pa.paged_decode_attention_xla_windowed(
         q, k_pages, v_pages, table, lengths, window=window,
-        causal=causal), np.float32)
+        block=block, live_positions=live), np.float32)
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
     seated = np.flatnonzero(np.asarray(lengths) > 0)
     assert not np.delete(got, seated, axis=0).any()
     with pltpu.force_tpu_interpret_mode():
         for b in seated:
             alone = call(q[b:b + 1], k_pages, v_pages, table[b:b + 1],
-                         lengths[b:b + 1])
+                         lengths[b:b + 1], at=slice(b, b + 1))
             np.testing.assert_array_equal(
                 got[b], np.asarray(alone, np.float32)[0])

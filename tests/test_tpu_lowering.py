@@ -88,11 +88,12 @@ def _qkv(shape, dtype):
     return [(shape, dtype)] * 3
 
 
-def _gqa_paged(window, softmax_dtype=f32, causal=True):
-    return lambda q, k, v, table, lengths: \
+def _gqa_paged(window, softmax_dtype=f32, block=0):
+    return lambda q, k, v, table, lengths, *live: \
         pa.gqa_paged_decode_attention_kernel(
             q, k, v, table, lengths, window=window,
-            softmax_dtype=softmax_dtype, causal=causal)
+            softmax_dtype=softmax_dtype, block=block,
+            live_positions=live[0] if live else None)
 
 
 def _gqa_args(pages, entries, dtype=bf16, batch=48, heads=28,
@@ -199,11 +200,24 @@ CASES = [
     # over 4 K/V heads of 128, 96 slots, a pool of 4,608 pages behind a
     # 129-entry table): a block of 4 positions whose queries all see
     # all keys, and its prefill under the block-causal mask
-    ("gqa_paged_block_all_keys", _gqa_paged(0, causal=False),
+    ("gqa_paged_block_all_keys", _gqa_paged(0, block=4),
      _gqa_args(4609, 129, batch=96, heads=32, positions=4)),
     ("gqa_paged_block_all_keys_bf16_softmax",
-     _gqa_paged(0, bf16, causal=False),
+     _gqa_paged(0, bf16, block=4),
      _gqa_args(4609, 129, batch=96, heads=32, positions=4)),
+    # ... and the block pass's call since PR 49: two blocks a slot,
+    # block-causal between them, the slot's live positions beside its
+    # length (a dead second block is passed over)
+    ("gqa_paged_two_blocks", _gqa_paged(0, block=4),
+     _gqa_args(4609, 129, batch=96, heads=32, positions=8)
+     + [((96,), i32)]),
+    ("gqa_paged_two_blocks_bf16_softmax", _gqa_paged(0, bf16, block=4),
+     _gqa_args(4609, 129, batch=96, heads=32, positions=8)
+     + [((96,), i32)]),
+    # (the cell's control that keeps the mask causal inside a block)
+    ("gqa_paged_two_halves_causal", _gqa_paged(0),
+     _gqa_args(4609, 129, batch=96, heads=32, positions=8)
+     + [((96,), i32)]),
     ("flash_prefill_block_causal", _flash_prefill(0, block=4),
      _prefill_args(2048, rows=8192, heads=32)),
     # the multi-token-prediction configuration's published shapes (64

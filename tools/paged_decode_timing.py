@@ -30,8 +30,12 @@ floor, and their differences are the device's.)
 default set) time the calls of those configurations' decode step as it
 makes them, 96 slots all seated: K-EXAONE's two query positions a slot
 over a full layer's 128-entry table and over a window layer's ring of
-4 pages under a window of 128 (five of its six calls), SDAR's block of
-four positions that all see all keys over a 129-entry table. Their
+4 pages under a window of 128 (five of its six calls), SDAR's block
+pass over a 129-entry table: the block of four positions that all see
+all keys (the pass's call until PR 49), two blocks a slot that are
+block-causal between them with every slot's eight positions live, and
+with a slot in four closing a block and the others' second block dead
+(the call since PR 49, each beside the first as a multiple of it). Their
 times are the DEVICE's (the profiler's events of ``--calls`` calls,
 summed: a call of 0.1 ms is far under what the host takes to dispatch
 one), and the reading is what a seated slot costs a call beyond its
@@ -42,7 +46,9 @@ the first chunk's DMA with nothing over it, once a slot; since, the
 seated slot before fetches it behind its own last chunk. ``--parent
 DIR`` loads DIR/batch_shipyard_tpu/ops/paged_attention.py (a checkout
 of another commit, say a ``git archive`` under .proof/) and prints
-its kernel's numbers beside this tree's, one process, one chip.
+its kernel's numbers beside this tree's, one process, one chip (of the
+calls its kernel can make: one that takes ``causal`` and no visible
+block makes no call of two blocks).
 
     chiprun -- python3 tools/paged_decode_timing.py [--shape baichuan7b]
     chiprun -- python3 tools/paged_decode_timing.py --shape kexaone \\
@@ -53,6 +59,7 @@ timing of a TPU kernel's interpreter is no number)."""
 import argparse
 import functools
 import importlib.util
+import inspect
 import os
 import sys
 import tempfile
@@ -76,11 +83,16 @@ SHAPES = {"solaropen2": (64, 8, 96, 96, 2401),
 
 # the calls of a decode step that seats every slot, as the step makes
 # them: (label, query heads, K/V heads, query positions a slot, table
-# entries, window, causal, calls of it a step)
+# entries, window, visible block, of how many slots one has every
+# position live (0: no live positions handed over), calls of it a step
+# (0: a call beside the step's, for comparison))
 STEP_CALLS = {
-    "kexaone": [("full", 64, 8, 2, 128, 0, True, 1),
-                ("ring", 64, 8, 2, 4, 128, True, 5)],
-    "sdar": [("block", 32, 4, 4, 129, 0, False, 6)],
+    "kexaone": [("full", 64, 8, 2, 128, 0, 1, 0, 1),
+                ("ring", 64, 8, 2, 4, 128, 1, 0, 5)],
+    "sdar": [("block", 32, 4, 4, 129, 0, 4, 0, 0),
+             ("two blocks, all live", 32, 4, 8, 129, 0, 4, 0, 0),
+             ("two blocks, one slot in four closing", 32, 4, 8, 129, 0,
+              4, 4, 6)],
 }
 STEP_SLOTS = 96
 
@@ -101,6 +113,13 @@ def device_ms(fn, args, calls: int) -> float:
             tracered.from_xplane(tracered.newest_xplane(trace_dir)))
     return sum(dur for ops in events.values()
                for _name, _start, dur in ops) / calls / 1e6
+
+
+def _block_call(call, window, block, *operands):
+    """``call`` of a tree whose kernel takes the visible block, the
+    slots' live positions behind the five operands if there are any."""
+    return call(*operands[:5], window=window, block=block,
+                live_positions=operands[5] if operands[5:] else None)
 
 
 def load_kernels(parent: str) -> dict:
@@ -132,8 +151,9 @@ def time_step_calls(name: str, args) -> None:
              + rng.uniform(0, 1, STEP_SLOTS)
              * np.clip(np.exp(rng.normal(np.log(1024), 0.5, STEP_SLOTS)),
                        256, 3072)).astype(np.int32)
-    for label, heads, kv_heads, positions, entries, window, causal, \
-            per_step in STEP_CALLS[name]:
+    first_ms = {}
+    for label, heads, kv_heads, positions, entries, window, block, \
+            closing, per_step in STEP_CALLS[name]:
         width = kv_heads * DEPTH
         chunk = pa.gqa_chunk_pages(PAGE, width, 2, entries)
         pool = STEP_SLOTS * min(entries, 48) + 1
@@ -155,9 +175,17 @@ def time_step_calls(name: str, args) -> None:
               f"positions of {heads} heads over {kv_heads} K/V heads "
               f"of {DEPTH}, a table of {entries}, window {window}, "
               f"{chunk} pages a chunk, {per_step} such calls a step")
-        kernels = {tree: jax.jit(functools.partial(
-            module.gqa_paged_decode_attention_kernel, window=window,
-            causal=causal)) for tree, module in trees.items()}
+        live = () if not closing else (jnp.where(
+            jnp.arange(STEP_SLOTS) % closing == 0, positions, block),)
+        kernels = {}
+        for tree, module in trees.items():
+            call = module.gqa_paged_decode_attention_kernel
+            if "block" in inspect.signature(call).parameters:
+                kernels[tree] = jax.jit(functools.partial(
+                    _block_call, call, window, block))
+            elif block in (1, positions) and not live:
+                kernels[tree] = jax.jit(functools.partial(
+                    call, window=window, causal=block == 1))
         for case, lengths in cases.items():
             low = np.maximum(lengths - (positions - 1 + window), 0) \
                 if window else np.zeros_like(lengths)
@@ -167,18 +195,20 @@ def time_step_calls(name: str, args) -> None:
                        / HBM_BYTES_PER_S * 1e3)
             seated = int(np.sum(lengths > 0))
             operands = (q, k_pages, v_pages, table,
-                        jnp.asarray(lengths, jnp.int32))
+                        jnp.asarray(lengths, jnp.int32)) + live
             outs = {}
             for tree, kernel in kernels.items():
                 outs[tree] = np.asarray(kernel(*operands), np.float32)
                 ms = device_ms(kernel, operands, args.calls)
                 if case == "cell":
                     step_ms[tree] += per_step * ms
+                beside = first_ms.setdefault((case, tree), ms)
                 print(f"  {case}, {tree}: device {ms:.4f} ms a call "
                       f"(live pages' read {read_ms:.4f} ms: "
                       f"{100 * read_ms / ms:.1f} % of it), "
                       f"{(ms - read_ms) / seated * 1e3:.2f} us a seated "
-                      f"slot beyond the read", flush=True)
+                      f"slot beyond the read, {ms / beside:.3f} times "
+                      f"the first call listed", flush=True)
             if len(outs) == 2:
                 same = np.array_equal(outs["parent"], outs["change"])
                 print(f"  {case}: the two trees' results are "

@@ -248,14 +248,16 @@ def check_paged_attention() -> bool:
 
 
 # (label, query heads, K/V heads, query positions a slot, table
-# entries, window, causal): the served calls whose programs hand a
-# first chunk on (tools/paged_decode_timing.py's STEP_CALLS beside
-# Baichuan's one-token MHA call), heads of 128, pages of 64, bfloat16
+# entries, window, visible block, whether every other slot's second
+# block is dead): the served calls whose programs hand a first chunk
+# on (tools/paged_decode_timing.py's STEP_CALLS beside Baichuan's
+# one-token MHA call), heads of 128, pages of 64, bfloat16
 _HANDOVER_CALLS = (
-    ("baichuan h32 mha", 32, 32, 1, 32, 0, True),
-    ("kexaone h64/8 x2 full", 64, 8, 2, 128, 0, True),
-    ("kexaone h64/8 x2 ring4 w128", 64, 8, 2, 4, 128, True),
-    ("sdar h32/4 x4 all keys", 32, 4, 4, 129, 0, False),
+    ("baichuan h32 mha", 32, 32, 1, 32, 0, 1, False),
+    ("kexaone h64/8 x2 full", 64, 8, 2, 128, 0, 1, False),
+    ("kexaone h64/8 x2 ring4 w128", 64, 8, 2, 4, 128, 1, False),
+    ("sdar h32/4 x4 all keys", 32, 4, 4, 129, 0, 4, False),
+    ("sdar h32/4 x8 two blocks, some dead", 32, 4, 8, 129, 0, 4, True),
 )
 
 
@@ -271,8 +273,8 @@ def check_paged_handover() -> bool:
     from batch_shipyard_tpu.ops import paged_attention as paged
 
     depth, page, all_ok = 128, 64, True
-    for label, heads, kv_heads, positions, entries, window, causal in \
-            _HANDOVER_CALLS:
+    for label, heads, kv_heads, positions, entries, window, block, \
+            some_dead in _HANDOVER_CALLS:
         rng = np.random.RandomState(13)
         width = kv_heads * depth
         keys = paged.gqa_chunk_pages(page, width, 2, entries) * page
@@ -292,20 +294,25 @@ def check_paged_handover() -> bool:
         for b, pages in enumerate(need):
             table[b, :pages] = [next(ids) for _ in range(pages)]
         table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+        live = jnp.where(jnp.arange(len(lengths)) % 2 == 0, positions,
+                         block) if some_dead else None
+        at = (lambda rows: {"live_positions": live[rows]}) \
+            if some_dead else (lambda rows: {})
         kernel = jax.jit(functools.partial(
             paged.paged_decode_attention, impl="kernel", window=window,
-            causal=causal))
-        out_k = kernel(q, k_p, v_p, table, lengths)
+            block=block))
+        out_k = kernel(q, k_p, v_p, table, lengths, **at(slice(None)))
         out_x = paged.paged_decode_attention(
             q, k_p, v_p, table, lengths, impl="xla", window=window,
-            causal=causal)
+            block=block, **at(slice(None)))
         rel = _rel(out_k, out_x)
         seated = np.flatnonzero(np.asarray(lengths) > 0)
         alone = all(
             np.array_equal(
                 np.asarray(out_k[b], np.float32),
                 np.asarray(kernel(q[b:b + 1], k_p, v_p, table[b:b + 1],
-                                  lengths[b:b + 1])[0], np.float32))
+                                  lengths[b:b + 1],
+                                  **at(slice(b, b + 1)))[0], np.float32))
             for b in seated)
         zeros = not np.delete(np.asarray(out_k, np.float32), seated,
                               axis=0).any()
