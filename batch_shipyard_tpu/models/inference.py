@@ -54,6 +54,18 @@ def init_cache(model: tfm.TransformerLM, params, batch_size: int):
     return jax.tree_util.tree_map(jnp.zeros_like, variables["cache"])
 
 
+@functools.partial(jax.jit, static_argnames=("model", "batch_size"))
+def empty_cache(model: tfm.TransformerLM, batch_size: int):
+    """init_cache as ONE compiled program (the model static): the same
+    zeros, without model.init's hundreds of eager operations, each of
+    which compiles by itself the first time a process meets its shape
+    (twelve seconds for a stack of ten blocks on a CPU, and a second
+    set of parameters and of the cache on the device before they are
+    thrown away). What a serving engine builds its cache with; the
+    prefill programs, which are traced already, keep init_cache."""
+    return init_cache(model, None, batch_size)
+
+
 def _sample(logits, key, sampling: SamplingConfig):
     """logits: [B, vocab] fp32 -> token ids [B]."""
     if sampling.temperature <= 0.0:
@@ -154,6 +166,24 @@ def slot_state_bytes(cache) -> int:
     leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
     return sum(leaf.nbytes // leaf.shape[0] for path, leaf in leaves
                if getattr(path[-1], "key", None) in SLOT_STATE_LEAVES)
+
+
+# Cache leaves whose FIRST axis is the pool's page ids: K and V pages
+# (and an int8 pool's scales) of an attention layer, the one leaf of
+# latent rows of a latent layer (transformer.LatentAttention).
+POOL_LEAVES = ("k_pages", "v_pages", "k_page_scales", "v_page_scales",
+               "kv_pages")
+
+
+def pool_page_bytes(cache) -> int:
+    """Bytes ONE page id names over all pooled layers, read off the
+    leaves as they are (their row's width and type, whatever kind of
+    attention wrote them: Hkv * D twice, a latent row once), the
+    scratch page counted as a page. A layer's ring and a per-slot
+    state are of no page and are not counted."""
+    leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
+    return sum(leaf.nbytes // leaf.shape[0] for path, leaf in leaves
+               if getattr(path[-1], "key", None) in POOL_LEAVES)
 
 
 def _map_cursors(fn, cache):
@@ -325,7 +355,7 @@ def make_decoder(config: tfm.TransformerConfig, params,
 
     def run(prompt, num_tokens, key,
             sampling: SamplingConfig = SamplingConfig()):
-        cache = init_cache(model, params, prompt.shape[0])
+        cache = empty_cache(model, prompt.shape[0])
         return generate(model, params, cache, prompt, num_tokens, key,
                         sampling)
 

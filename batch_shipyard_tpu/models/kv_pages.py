@@ -92,6 +92,10 @@ class PagePool:
         self.num_slots = num_slots
         self.num_pages = num_pages
         self.page_size = page_size
+        # bytes one page id names over all pooled layers: the engine
+        # sets it once its cache exists, from the leaves as they are
+        # (inference.pool_page_bytes); 0 until then
+        self.page_bytes = 0
         self.overcommit = overcommit
         self.prefix_cache = prefix_cache
         # spec_window widens the table so a speculative verify block
@@ -256,9 +260,13 @@ class PagePool:
 
     def stats(self) -> dict:
         """Prefix-cache counters. hit_rate is TOKEN-level: the share
-        of seated prompt tokens the index turned into a gather."""
+        of seated prompt tokens the index turned into a gather.
+        page_bytes / bytes_per_token: what a page id, and a cached
+        token, hold over all pooled layers (``page_bytes``)."""
         c = self._counts
         return {
+            "page_bytes": self.page_bytes,
+            "bytes_per_token": self.page_bytes // self.page_size,
             "lookups": c["lookups"],
             "hit_pages": c["hit_pages"],
             "hit_tokens": c["hit_tokens"],

@@ -425,7 +425,8 @@ def window_segment(config) -> Optional[int]:
     prefill_chunk: None (a bucket whole), but for a model with window
     layers whose inserts attend in blocks (prefill_blocks) the least
     power of two that holds its widest window (SEGMENT_FLOOR at
-    least), so that what a
+    least; SEGMENT_FLOOR for a model with latent attention, whose
+    inserts expand what they attend), so that what a
     segment's forward holds in HBM (its rows through every projection
     and expert; the scores stay in VMEM) is bounded by the window
     whatever the bucket, and the power-of-two buckets are equal
@@ -437,6 +438,12 @@ def window_segment(config) -> Optional[int]:
     such run had a decode step land 1 to 2 s late, which none at
     4,096 had."""
     windows = [w for w in tfm.attention_windows(config) if w]
+    if config.prefill_blocks and config.latent is not None:
+        # a latent layer's insert expands keys and values for every
+        # head from the rows it can see (H * (nope + rope + v) lanes a
+        # row, against the cache's kv_rank + rope): what a segment
+        # holds of that is bounded by the bucket, its queries by this
+        return SEGMENT_FLOOR
     if not (config.prefill_blocks and windows):
         return None
     return max(1 << (max(windows) - 1).bit_length(), SEGMENT_FLOOR)
@@ -509,12 +516,17 @@ def _prefill_segments(model, prefill_chunk, params, cache, tokens,
     following = jnp.concatenate(
         [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])],
         axis=1) if drafting else tokens
+    # a cold prefill (start the Python int 0) reaches no row beyond
+    # its bucket: what a latent layer expands of its cache
+    reach = {"key_reach": total} if cfg.latent is not None and \
+        isinstance(start, int) else {}
 
     def forward(cache, seg, nxt, off, positions):
         # Positions are GLOBAL offsets: RoPE for chunk c must match
         # the full-sequence pass exactly.
         at = dict(return_hidden=True, positions=start + positions,
-                  valid_len=prompt_len - start - off, mutable=_MUTABLE)
+                  valid_len=prompt_len - start - off, mutable=_MUTABLE,
+                  **reach)
         h, mut = model.apply({"params": params, "cache": cache}, seg,
                              stack_hidden=drafting, **at)
         chosen = tfm.collect_decisions(mut.get("decisions"), cfg)
@@ -584,7 +596,7 @@ def _prefill_segments(model, prefill_chunk, params, cache, tokens,
         return_hidden=True,
         positions=jnp.reshape(prompt_len - 1, (1, 1)),
         mtp_hidden=jnp.take(hidden[1], jnp.reshape(at, (1,)), axis=1),
-        mutable=_MUTABLE)
+        mutable=_MUTABLE, **reach)
     draft = jnp.argmax(tfm.output_logits(
         cfg, params, out[0, 0]))[None].astype(jnp.int32)
     if mtp_chosen is not None:
@@ -633,6 +645,19 @@ def _seat_ring(big, small, slot, prompt_len, page: int) -> dict:
 
     return {"k_ring": seated(big["k_ring"], small["k"]),
             "v_ring": seated(big["v_ring"], small["v"]),
+            "length": big["length"].at[slot].set(prompt_len)}
+
+
+def _seat_latent(big, rows, ids, slot, table_row, prompt_len) -> dict:
+    """A latent layer's paged leaves (transformer.LatentAttention:
+    kv_pages [P, page, lanes], block_table, length) with ``rows``
+    [n * page, lanes] of a batch-1 prefill written as the pages
+    ``ids`` [n] in ONE scatter (the dense rows ARE page rows), and the
+    slot's table row and length set."""
+    pool = big["kv_pages"]
+    return {"kv_pages": pool.at[ids].set(rows.astype(pool.dtype).reshape(
+                ids.shape[0], *pool.shape[1:])),
+            "block_table": big["block_table"].at[slot].set(table_row),
             "length": big["length"].at[slot].set(prompt_len)}
 
 
@@ -696,6 +721,10 @@ def _prefill_paged(model, prefill_chunk, page, params, cache, slot,
     def scatter(big, sm):
         if isinstance(big, dict) and "k_ring" in big:
             return _seat_ring(big, sm, slot, prompt_len, page)
+        if isinstance(big, dict) and "kv_pages" in big:
+            return _seat_latent(big, sm["kv"][0, :n_blocks * page],
+                                table_row[:n_blocks], slot, table_row,
+                                prompt_len)
         if isinstance(big, dict) and "k_pages" in big:
             kp, vp = big["k_pages"], big["v_pages"]
             if model.config.prefill_blocks and "k_page_scales" not in big:
@@ -779,6 +808,12 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
             raise NotImplementedError(
                 "a shared-prefix prefill cannot seed a per-slot state "
                 "or a window layer's ring")
+        if isinstance(big, dict) and "kv_pages" in big:
+            rows = big["kv_pages"][prefix_ids]
+            rows = rows.reshape(-1, rows.shape[-1])
+            return {"kv": sm["kv"].at[0, :rows.shape[0]].set(
+                        rows.astype(sm["kv"].dtype)),
+                    "index": jnp.full_like(sm["index"], prefix_len)}
         if isinstance(big, dict) and "k_pages" in big:
             rows = tfm.prefix_rows_from_pages(big, prefix_ids, page)
             nrows = rows["k"].shape[0]
@@ -811,6 +846,15 @@ def _prefill_paged_shared(model, prefill_chunk, page, params, cache,
     n_blocks = -(-total // page)
 
     def scatter(big, sm):
+        if isinstance(big, dict) and "kv_pages" in big:
+            # one slice of the suffix's rows (a start that runs past
+            # the cache is a padding block's, bound for the scratch
+            # page)
+            rows = jax.lax.dynamic_slice_in_dim(
+                jnp.pad(sm["kv"][0], ((0, n_blocks * page), (0, 0))),
+                prefix_len, n_blocks * page)
+            return _seat_latent(big, rows, suffix_row[:n_blocks], slot,
+                                table_row, prompt_len)
         if isinstance(big, dict) and "k_pages" in big:
             kp, vp = big["k_pages"], big["v_pages"]
             for b in range(n_blocks):
@@ -1510,7 +1554,7 @@ class ContinuousBatcher:
         with on_device:
             (self.cache, self._tokens, self._positions, self._active,
              self._key, self._draft, self._masked) = self._put((
-                 inf.init_cache(self.model, params, num_slots),
+                 inf.empty_cache(self.model, num_slots),
                  # each slot's pending token, or its open block and
                  # the one it landed last
                  jnp.zeros((num_slots, 1) + (
@@ -1524,6 +1568,8 @@ class ContinuousBatcher:
                  # the positions of each slot's open block still masked
                  jnp.ones((num_slots, self.block), jnp.bool_)))
         self._slot_state_bytes = inf.slot_state_bytes(self.cache)
+        if self.pages is not None:
+            self.pages.page_bytes = inf.pool_page_bytes(self.cache)
         # _active as the host last pushed it (_push_active).
         self._active_host = np.zeros((num_slots,), np.bool_)
         if self.pages is not None:
@@ -1569,8 +1615,8 @@ class ContinuousBatcher:
                 speculative.draft_params if device is None else
                 jax.device_put(speculative.draft_params, device))
             with on_device:
-                self._draft_cache = self._put(inf.init_cache(
-                    draft_model, self._draft_params, num_slots))
+                self._draft_cache = self._put(inf.empty_cache(
+                    draft_model, num_slots))
             self._draft_prefill = functools.partial(
                 _prefill_dense, draft_model, self.prefill_chunk)
             self._spec_step = functools.partial(
@@ -2426,7 +2472,8 @@ class ContinuousBatcher:
         window_pages_in_use / window_pages_total (kv_pages.
         ring_occupancy), and the keys ONE full and ONE window layer's
         decode kernel attends in the step dispatched from this state:
-        kv_tokens_full (= live_tokens) and kv_tokens_window (each
+        kv_tokens_full (= live_tokens; also of any engine whose model
+        drafts for itself) and kv_tokens_window (each
         seated slot's newest ``window`` at most)."""
         held = [slot.held_tokens() for slot in self._slots
                 if slot.decoding()]
@@ -2435,6 +2482,10 @@ class ContinuousBatcher:
                "queued": len(self._queue), "live_tokens": sum(held)}
         if self.pages is not None:
             out.update(self.pages.occupancy(held))
+            if self.drafts:
+                # the keys ONE full layer's verify call attends at its
+                # first position, window layers or none
+                out["kv_tokens_full"] = sum(held)
             if self.window:
                 out.update(kv_pages.ring_occupancy(
                     held, self.num_slots, self.page_size,

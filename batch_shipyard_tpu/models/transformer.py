@@ -76,6 +76,37 @@ class BlockDiffusion:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentKV:
+    """Multi-head latent attention (TransformerConfig.latent): the
+    query comes through a low rank with a norm of its own (``q_rank``),
+    and a token's keys and values for ALL heads come out of ONE
+    compressed vector c of ``kv_rank`` channels (normed) beside ONE
+    rotary key of ``rope_dim`` channels that every head shares. A
+    head's query and key are ``nope_dim`` unrotated channels followed
+    by the ``rope_dim`` rotated ones (scores scaled by
+    1 / sqrt(nope_dim + rope_dim)), its value ``v_dim`` channels; keys
+    and values are c times one up-projection [kv_rank,
+    H * (nope_dim + v_dim)]. The cache holds the ROW [c ; rotary key]
+    a token a layer and nothing a head (LatentAttention below), padded
+    with zeros to whole lane tiles (``row_lanes``: a TPU lays a row of
+    576 lanes out as 640 whatever it is told, and a copy of the 576
+    alone is one it refuses)."""
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def row_lanes(self) -> int:
+        return -(-(self.kv_rank + self.rope_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -235,6 +266,18 @@ class TransformerConfig:
     # iterated denoising in place of one token a step
     # (models/serving.py). None = autoregressive.
     block_diffusion: Optional[BlockDiffusion] = None
+    # Multi-head latent attention (LatentKV above) in every "attn"
+    # block and in a multi-token-prediction module's: the cache is one
+    # leaf of latent rows a layer (no V leaf, no head axis), a paged
+    # decode call computes the ABSORBED form over it, a prefill the
+    # expanded one. n_kv_heads, d_head and qk_norm are not read.
+    # None = the attention above.
+    latent: Optional[LatentKV] = None
+    # True: a block that is one mixer after one norm (MixerBlock, a
+    # multi-token-prediction module's two among them) norms the
+    # mixer's OUTPUT too before adding it, x + post(Mixer(norm(x))),
+    # "post_norm" beside "norm" in its tree.
+    sandwich_norm: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -918,6 +961,209 @@ class Attention(nn.Module):
         return paged_ops.masked_attention(q, k_all, v_all, mask, cfg.dtype)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (config.latent, a LatentKV), on the
+    normed input a [B, T, d], H = n_heads, every norm an RMSNorm with
+    a learned scale:
+
+        c_q    = q_norm(a W_dq)                      q_down, [q_rank]
+        q_h    = [q_h^N ; q_h^R] = (c_q W_uq)_h      q_up, nope + rope
+        [c;kR] = a W_dkv;  c = kv_norm(c)            kv_down
+        q_h^R, kR rotated at the token's position (theta rope_theta)
+        [k_h^N ; v_h] = (c W_ukv)_h                  kv_up, nope + v
+        s_hij  = (q_h^N . k_hj^N + q_h^R . kR_j) / sqrt(nope + rope)
+        out    = concat_h(sum_j softmax_j(s_hij) v_hj) W_o     o_proj
+
+    TWO paths through the same weights. EXPANDED (a forward without a
+    cache, and every insert into a DENSE cache: a serving prefill's
+    segments): keys and values of every cached row the segment can see
+    are expanded from the cache's latent rows (``latent_expand``) and
+    attended in blocks (ops/attention.cached_prefill_attention, q and
+    k nope + rope deep beside values of v_dim). ABSORBED (a PAGED
+    decode call: one token, or a verify block): with
+    q~_h = q_h^N W_uk,h^T the scores are (q~_h . c_j + q_h^R . kR_j)
+    and the output (sum_j p_hij c_j) W_uv,h, so the cache is read as
+    it lies, one row a token for all heads, and nothing is expanded
+    (ops/paged_attention.mla_paged_decode_attention; the absorbed
+    products accumulate in float32).
+
+    The cache: ONE leaf a layer, ``kv`` [B, T, row_lanes] dense or
+    ``kv_pages`` [P, page, row_lanes] paged (block_table and length
+    as Attention's), a row [c ; kR ; zeros to the lane tile]."""
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, live=None, key_reach=None):
+        cfg, lat = self.config, self.config.latent
+        if cfg.tp_axis or cfg.fused_norm or cfg.quantize_matmuls or \
+                cfg.kv_cache_dtype or cfg.attend_block or \
+                cfg.attn_output_gate:
+            raise NotImplementedError(
+                "latent attention: no tp_axis, fused_norm, "
+                "quantize_matmuls, int8 cache, block diffusion or "
+                "output gate")
+        dense = functools_partial_dense(cfg)
+        batch, seq = x.shape[0], x.shape[1]
+        heads = cfg.n_heads
+
+        def norm(name):
+            return RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+        q = dense(heads * lat.qk_dim, "q_up")(
+            norm("q_norm")(dense(lat.q_rank, "q_down")(x)))
+        q = q.reshape(batch, seq, heads, lat.qk_dim)
+        down = dense(lat.kv_rank + lat.rope_dim, "kv_down")(x)
+        c = norm("kv_norm")(down[..., :lat.kv_rank])
+        q_nope = q[..., :lat.nope_dim]
+        q_rope = rotary_embedding(q[..., lat.nope_dim:], positions,
+                                  cfg.rope_theta)
+        k_rope = rotary_embedding(down[..., None, lat.kv_rank:],
+                                  positions, cfg.rope_theta)[:, :, 0]
+        # [kv_rank, H, nope + v]: head h's key and value up-projection
+        kv_up = self.param(
+            "kv_up", nn.initializers.lecun_normal(),
+            (lat.kv_rank, heads * (lat.nope_dim + lat.v_dim)),
+            cfg.param_dtype).astype(cfg.dtype).reshape(
+                lat.kv_rank, heads, lat.nope_dim + lat.v_dim)
+        scale = 1.0 / math.sqrt(lat.qk_dim)
+        if not cfg.decode:
+            k, v = self._expand(c, k_rope, kv_up, lat.qk_dim)
+            visible = positions[..., :, None] >= positions[..., None, :]
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk",
+                jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                preferred_element_type=jnp.float32) * scale
+            probs = jax.nn.softmax(jnp.where(
+                visible if visible.ndim == 2 else visible[:, None],
+                scores, paged_ops._NEG_INF), axis=-1)
+            out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype),
+                             v, preferred_element_type=jnp.float32)
+        else:
+            row = self._row(c, k_rope)
+            if cfg.kv_page_size:
+                out = self._absorbed_paged(q_nope, q_rope, row, kv_up,
+                                           scale, live)
+            else:
+                out = self._expanded_dense(q_nope, q_rope, row, kv_up,
+                                           scale, key_reach)
+        return dense(cfg.d_model, "o_proj")(out.astype(cfg.dtype).reshape(
+            batch, seq, heads * lat.v_dim))
+
+    def _row(self, main, rope):
+        """[main (kv_rank) ; rope (rope_dim) ; zeros] over the last
+        axis, row_lanes wide, in the served type: a cached row from a
+        token's compressed vector and rotary key, and an absorbed
+        query row from a head's q~ and rotated lanes."""
+        lat = self.config.latent
+        fill = jnp.zeros(main.shape[:-1] + (
+            lat.row_lanes - lat.kv_rank - lat.rope_dim,), main.dtype)
+        return jnp.concatenate([main, rope.astype(main.dtype), fill],
+                               axis=-1).astype(self.config.dtype)
+
+    def _expand(self, c, k_rope, kv_up, key_depth: int):
+        """Latent rows c [B, R, kv_rank], k_rope [B, R, rope] ->
+        (keys [B, R, H, key_depth], values [B, R, H, v_dim]): each
+        head's unrotated key channels, then the one rotary key, then
+        zeros where ``key_depth`` is beyond nope + rope (a lane tile's
+        fill, ops/attention.prefill_key_depth)."""
+        lat = self.config.latent
+        with jax.named_scope("latent_expand"):
+            # (two products, each rounded to the served type as it
+            # leaves the MXU's float32 accumulator: the values are the
+            # second as it stands, and no float32 copy of either is
+            # held beside a segment's other temporaries)
+            k_nope = jnp.einsum("brc,chd->brhd", c,
+                                kv_up[..., :lat.nope_dim])
+            values = jnp.einsum("brc,chd->brhd", c,
+                                kv_up[..., lat.nope_dim:])
+            shared = jnp.broadcast_to(
+                k_rope[:, :, None, :].astype(c.dtype),
+                k_nope.shape[:3] + (lat.rope_dim,))
+            fill = jnp.zeros(
+                k_nope.shape[:3] + (key_depth - lat.qk_dim,), c.dtype)
+            return jnp.concatenate([k_nope, shared, fill],
+                                   axis=-1), values
+
+    def _expanded_dense(self, q_nope, q_rope, row, kv_up, scale,
+                        key_reach):
+        """An insert of S rows into the dense cache at each slot's
+        cursor, then the S queries against the cache's first
+        ``key_reach`` rows (None: all), keys and values expanded from
+        them. -> [B, S, H, v_dim]."""
+        cfg, lat = self.config, self.config.latent
+        batch, seq, heads, _ = q_nope.shape
+        cache = self.variable(
+            "cache", "kv", jnp.zeros,
+            (batch, cfg.max_decode_len, lat.row_lanes), cfg.dtype)
+        index = self.variable(
+            "cache", "index", lambda: jnp.zeros((batch,), jnp.int32))
+        idx = index.value
+        cols = idx[:, None] + jnp.arange(seq)[None, :]        # [B, S]
+        cache.value = cache.value.at[
+            jnp.arange(batch)[:, None], cols].set(row)
+        index.value = idx + seq
+        reach = min(key_reach or cfg.max_decode_len, cfg.max_decode_len)
+        depth = attn_ops.prefill_key_depth(lat.qk_dim, seq, reach)
+        rows = cache.value[:, :reach]
+        keys, values = self._expand(
+            rows[..., :lat.kv_rank],
+            rows[..., lat.kv_rank:lat.kv_rank + lat.rope_dim], kv_up,
+            depth)
+        q = jnp.concatenate(
+            [q_nope, q_rope, jnp.zeros(
+                (batch, seq, heads, depth - lat.qk_dim), q_nope.dtype)],
+            axis=-1)
+        return attn_ops.cached_prefill_attention(
+            q, keys.reshape(batch, reach, heads * depth),
+            values.reshape(batch, reach, heads * lat.v_dim), idx,
+            softmax_dtype=cfg.attn_softmax_dtype, v_depth=lat.v_dim,
+            scale=scale)
+
+    def _absorbed_paged(self, q_nope, q_rope, row, kv_up, scale, live):
+        """A paged decode call: the S rows written through the slot's
+        block table, then the absorbed form over the pool as it lies.
+        ``live``: Attention._decode_attend_paged's mask. -> [B, S, H,
+        v_dim]."""
+        cfg, lat = self.config, self.config.latent
+        batch, seq = q_nope.shape[:2]
+        page = cfg.kv_page_size
+        if seq > cfg.spec_window + 1:
+            raise ValueError(
+                f"paged decode insert of {seq} tokens needs "
+                f"spec_window >= {seq - 1} (got {cfg.spec_window})")
+        max_blocks = (cfg.max_decode_len + cfg.spec_window
+                      + page - 1) // page
+        pages = self.variable(
+            "cache", "kv_pages", jnp.zeros,
+            (cfg.kv_num_pages, page, lat.row_lanes), cfg.dtype)
+        block_table = self.variable(
+            "cache", "block_table",
+            lambda: jnp.zeros((batch, max_blocks), jnp.int32))
+        length = self.variable(
+            "cache", "length", lambda: jnp.zeros((batch,), jnp.int32))
+        idx = length.value
+        cols = idx[:, None] + jnp.arange(seq)[None, :]        # [B, S]
+        page_idx = jnp.take_along_axis(
+            block_table.value, cols // page, axis=1)
+        pages.value = pages.value.at[page_idx, cols % page].set(row)
+        length.value = idx + seq
+        if live is not None and live.dtype != jnp.bool_:
+            raise NotImplementedError(
+                "no live query positions over a latent pool")
+        absorbed = jnp.einsum(
+            "bshn,chn->bshc", q_nope, kv_up[..., :lat.nope_dim],
+            preferred_element_type=jnp.float32).astype(cfg.dtype)
+        summed = paged_ops.mla_paged_decode_attention(
+            self._row(absorbed, q_rope), pages.value, block_table.value,
+            _live_lengths(length.value, live),
+            value_lanes=lat.kv_rank, scale=scale,
+            impl=cfg.paged_attention_impl,
+            softmax_dtype=cfg.attn_softmax_dtype)
+        return jnp.einsum(
+            "bshc,chv->bshv", summed, kv_up[..., lat.nope_dim:],
+            preferred_element_type=jnp.float32)
+
+
 def _live_rows(live, length: int):
     """[B, length] bool, the query positions somebody reads, from an
     int32 ``live`` [B] (how many of each slot's, from its first on:
@@ -1095,7 +1341,7 @@ class MixerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, valid_len=None,
-                 router_input=None, live=None):
+                 router_input=None, live=None, key_reach=None):
         cfg = self.config
         normed = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
                          name="norm")(x)
@@ -1113,9 +1359,18 @@ class MixerBlock(nn.Module):
                 normed, router_input, _live_rows(live, x.shape[1]))
         elif self.kind == "mlp":
             out = MLP(cfg, name="mlp")(normed)
+        elif cfg.latent is not None:
+            if self.window:
+                raise NotImplementedError(
+                    "no sliding window over latent rows")
+            out = LatentAttention(cfg, name="attn")(
+                normed, positions, live, key_reach)
         else:
             out = Attention(cfg, self.window, self.rope,
                             name="attn")(normed, positions, live)
+        if cfg.sandwich_norm:
+            out = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype,
+                          name="post_norm")(out)
         return x + out, normed
 
 
@@ -1137,7 +1392,7 @@ class MTPModule(nn.Module):
 
     @nn.compact
     def __call__(self, embedded, hidden, positions, valid_len=None,
-                 live=None):
+                 live=None, key_reach=None):
         cfg = self.config
 
         def norm(name):
@@ -1148,7 +1403,7 @@ class MTPModule(nn.Module):
             axis=-1)
         x = functools_partial_dense(cfg)(cfg.d_model, "proj")(x)
         x, _ = MixerBlock(cfg, "attn", 0, cfg.mtp_rope, name="layer_0")(
-            x, positions, valid_len, live=live)
+            x, positions, valid_len, live=live, key_reach=key_reach)
         x, _ = MixerBlock(
             cfg, "experts" if cfg.experts is not None else "mlp",
             name="layer_1")(x, positions, valid_len)
@@ -1162,7 +1417,7 @@ class TransformerLM(nn.Module):
     def __call__(self, tokens, return_hidden: bool = False,
                  positions=None, valid_len=None,
                  stack_hidden: bool = False, mtp_hidden=None,
-                 live=None, head_rows=None):
+                 live=None, head_rows=None, key_reach=None):
         """tokens: [B, T] int32 -> logits [B, T, vocab] (or the final
         hidden states [B, T, d_model] when return_hidden — used by the
         chunked-loss training path so the full fp32 logits tensor,
@@ -1179,6 +1434,10 @@ class TransformerLM(nn.Module):
         int32 ``live`` says).
         ``head_rows`` (int32 [B, n]): the n positions of each row of
         the batch that the head runs over, in place of all T.
+        ``key_reach`` (a Python int, a decode-mode insert into a DENSE
+        cache of latent rows only; None = the whole cache): no query
+        of this call sits at or beyond that position, so a latent
+        layer expands its cache's first ``key_reach`` rows alone.
 
         A model with a multi-token-prediction module (mtp_modules):
         ``stack_hidden`` True -> (the result as above, the stack's
@@ -1213,7 +1472,7 @@ class TransformerLM(nn.Module):
                 raise ValueError("mtp_hidden: the model has no "
                                  "multi-token-prediction module")
             return head(MTPModule(cfg, name=MTP_NAME)(
-                x, mtp_hidden, positions, valid_len, live))
+                x, mtp_hidden, positions, valid_len, live, key_reach))
         block, mixer_block = Block, MixerBlock
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
@@ -1232,10 +1491,16 @@ class TransformerLM(nn.Module):
                     router_input = mixer_input
                 x, normed = mixer_block(
                     cfg, kind, *per_layer, name=f"layer_{idx}")(
-                        x, positions, valid_len, router_input, live)
+                        x, positions, valid_len, router_input, live,
+                        key_reach)
                 if kind not in ("experts", "mlp"):
                     mixer_input = normed
             else:
+                if cfg.latent is not None or cfg.sandwich_norm:
+                    raise NotImplementedError(
+                        "latent attention and sandwich norms are "
+                        "MixerBlock's (block_kinds of attn / mlp / "
+                        "experts)")
                 x = block(cfg, kind == "dense_moe", *per_layer,
                           name=f"layer_{idx}")(x, positions, live)
         last = x

@@ -643,9 +643,14 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
                                  window: int = 0,
                                  block_k: int = PREFILL_BLOCK_K,
                                  softmax_dtype=jnp.float32,
-                                 block: int = 0):
+                                 block: int = 0, v_depth: int = 0,
+                                 scale: Optional[float] = None):
     """q [B, S, H, D] at positions start[b] .. start[b] + S - 1
     against cache rows k_cache / v_cache [B, T, Hkv*D] -> [B, S, H, D].
+    ``v_depth`` > 0: values of another depth than q and k (v_cache
+    [B, T, Hkv*v_depth] -> [B, S, H, v_depth]); ``scale``: the scores'
+    factor where it is not 1 / sqrt(D) (keys that carry a lane tile's
+    fill: prefill_key_depth).
     A loop (dynamic trip count) over the key blocks between the first
     the window still touches for the segment's first query and the
     last its last query reaches, online softmax across them (its
@@ -659,7 +664,7 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
     rows = k_cache.shape[1]
     kv_heads = k_cache.shape[2] // depth
     block_k = math.gcd(rows, block_k)
-    scale = 1.0 / math.sqrt(depth)
+    scale = 1.0 / math.sqrt(depth) if scale is None else scale
     start = jnp.asarray(start, jnp.int32).reshape(-1)
     # from the first block the earliest query's band touches to the
     # last the latest query reaches
@@ -673,7 +678,7 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
     def body(kb, carry):
         k_blk, v_blk = (jax.lax.dynamic_slice_in_dim(
             cache, kb * block_k, block_k, axis=1).reshape(
-                batch, block_k, kv_heads, depth)
+                batch, block_k, kv_heads, -1)
             for cache in (k_cache, v_cache))
         k_pos = kb * block_k + jnp.arange(block_k, dtype=jnp.int32)
         mask = k_pos[None, None, :] <= block_end(q_pos, block)[:, :, None]
@@ -692,7 +697,9 @@ def cached_prefill_attention_xla(q, k_cache, v_cache, start,
         return (kept_in(o, softmax_dtype), m_new,
                 kept_in(l, softmax_dtype))
 
-    o, m, l = jax.lax.fori_loop(first, last + 1, body, attention_init(q))
+    o, m, l = jax.lax.fori_loop(
+        first, last + 1, body,
+        attention_init(q[..., :v_depth] if v_depth else q))
     return attention_finalize(q, o, m, l)
 
 
@@ -761,6 +768,18 @@ def _flash_prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
             axis=1).astype(o_ref.dtype)
 
 
+def prefill_key_depth(depth: int, seq: int, rows: int) -> int:
+    """The depth a caller whose q and k are ``depth`` deep (not whole
+    lane tiles: 192) lays them out at for cached_prefill_attention:
+    filled with zeros to whole tiles of 128 where the kernel will take
+    the call (a zero lane adds nothing to a score, and the MXU pads a
+    tile's rest itself), as they are elsewhere."""
+    padded = -(-depth // 128) * 128
+    return padded if (jax.default_backend() == "tpu"
+                      and prefill_kernel_shapes_ok(seq, rows, padded)) \
+        else depth
+
+
 def prefill_kernel_shapes_ok(seq: int, rows: int, depth: int) -> bool:
     """Whether the kernel's blocks tile these shapes: lane-wide heads,
     whole query and key blocks."""
@@ -774,7 +793,8 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
                                     window: int = 0,
                                     interpret: bool = False,
                                     softmax_dtype=jnp.float32,
-                                    block: int = 0):
+                                    block: int = 0, v_depth: int = 0,
+                                    scale: Optional[float] = None):
     """cached_prefill_attention_xla as a Pallas kernel: scores stay in
     VMEM, a K/V head's ``group`` query heads share each key block's
     one read, and a query block visits only the key blocks its band
@@ -792,6 +812,7 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
     block_q = min(PREFILL_BLOCK_Q, seq)
     block_k = min(PREFILL_BLOCK_K, rows)
     num_kb = rows // block_k
+    out_depth = v_depth or depth
     steps = num_kb if not window else min(
         num_kb, -(-(window + block_q - 1) // block_k) + 1)
 
@@ -809,10 +830,11 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
         grid=(batch, kv_heads, seq // block_q, steps),
         in_specs=[pl.BlockSpec((None, block_q, group * depth), q_map),
                   pl.BlockSpec((None, block_k, depth), kv_map),
-                  pl.BlockSpec((None, block_k, depth), kv_map)],
-        out_specs=pl.BlockSpec((None, block_q, group * depth), q_map),
+                  pl.BlockSpec((None, block_k, out_depth), kv_map)],
+        out_specs=pl.BlockSpec((None, block_q, group * out_depth),
+                               q_map),
         scratch_shapes=[
-            pltpu.VMEM((group * block_q, depth), jnp.float32),
+            pltpu.VMEM((group * block_q, out_depth), jnp.float32),
             pltpu.VMEM((group * block_q, 1), jnp.float32),
             pltpu.VMEM((group * block_q, 1), jnp.float32)],
     )
@@ -820,11 +842,12 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
         functools.partial(
             _flash_prefill_kernel, block_q=block_q, block_k=block_k,
             group=group, depth=depth, window=int(window),
-            num_kb=num_kb, scale=1.0 / math.sqrt(depth),
+            num_kb=num_kb,
+            scale=1.0 / math.sqrt(depth) if scale is None else scale,
             softmax_dtype=softmax_dtype,
             **({"block": int(block)} if block else {})),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, seq, heads * depth),
+        out_shape=jax.ShapeDtypeStruct((batch, seq, heads * out_depth),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -833,17 +856,20 @@ def cached_prefill_attention_kernel(q, k_cache, v_cache, start,
         name=PREFILL_KERNEL_NAME, interpret=interpret,
     )(jnp.asarray(start, jnp.int32).reshape(-1),
       q.reshape(batch, seq, heads * depth), k_cache, v_cache)
-    return out.reshape(batch, seq, heads, depth)
+    return out.reshape(batch, seq, heads, out_depth)
 
 
 def cached_prefill_attention(q, k_cache, v_cache, start,
                              window: int = 0,
                              impl: Optional[str] = None,
                              softmax_dtype=jnp.float32,
-                             block: int = 0):
+                             block: int = 0, v_depth: int = 0,
+                             scale: Optional[float] = None):
     """Dispatch: 'kernel' (Pallas) on a TPU backend where its blocks
     tile the shapes, else 'xla'; a named impl passes through.
-    ``block`` > 0: block-causal (cached_prefill_attention_xla)."""
+    ``block`` > 0: block-causal; ``v_depth`` / ``scale``: values of a
+    depth of their own, the scores' factor (both
+    cached_prefill_attention_xla's)."""
     if impl is None:
         impl = "kernel" if (
             jax.default_backend() == "tpu" and prefill_kernel_shapes_ok(
@@ -851,10 +877,12 @@ def cached_prefill_attention(q, k_cache, v_cache, start,
     if impl == "kernel":
         return cached_prefill_attention_kernel(
             q, k_cache, v_cache, start, window,
-            softmax_dtype=softmax_dtype, block=block)
+            softmax_dtype=softmax_dtype, block=block,
+            v_depth=v_depth, scale=scale)
     if impl != "xla":
         raise ValueError(f"unknown prefill attention impl {impl!r}")
     return cached_prefill_attention_xla(q, k_cache, v_cache, start,
                                         window,
                                         softmax_dtype=softmax_dtype,
-                                        block=block)
+                                        block=block, v_depth=v_depth,
+                                        scale=scale)

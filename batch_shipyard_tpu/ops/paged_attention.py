@@ -383,7 +383,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
                              kv_heads: int, depth: int, window: int,
                              scale: float, softmax_dtype,
                              positions: int = 1, block: int = 1,
-                             halves: bool = False):
+                             halves: bool = False,
+                             value_lanes: int = 0):
     """One slot: online softmax over its live pages, a chunk of
     ``chunk`` pages a step of the inner loop, its scores and running
     terms kept in ``softmax_dtype``. ``positions`` > 1: the slot's
@@ -420,9 +421,20 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
     ``live`` mask, Attention._decode_attend_paged) writes its zero
     output block and touches nothing else: no DMA started, none waited
     for, ``carry`` and the semaphores as it found them, so a fetch in
-    flight passes over it to the slot it is for."""
+    flight passes over it to the slot it is for.
+
+    ``value_lanes`` > 0: a pool of LATENT rows (one leaf, one row a
+    token for all heads: mla_paged_decode_attention_kernel): there are
+    no V pages and no V buffer, every query row reads the whole row
+    (``depth`` its width), and a key's value is the first
+    ``value_lanes`` lanes of its own row, taken from the tile the
+    scores were computed from: a page is fetched and read ONCE."""
     live_ref, refs = (refs[0], refs[1:]) if halves else (None, refs)
-    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, carry = refs
+    if value_lanes:
+        q_ref, k_hbm, o_ref, k_buf, sems, carry = refs
+        v_hbm = v_buf = None
+    else:
+        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, carry = refs
     b = pl.program_id(0)
     batch = pl.num_programs(0)
     rows = q_ref.shape[0]
@@ -433,7 +445,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
         # a chunk's tail past the last live page is never fetched:
         # what lies there is masked by position, and must be finite
         k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        if v_buf is not None:
+            v_buf[...] = jnp.zeros_like(v_buf)
         carry[0] = 0
         carry[1] = 0
 
@@ -464,12 +477,13 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
             pid = table_ref[who, jax.lax.rem(
                 jnp.minimum(logical, end), table_width)]
             dst = pl.ds(i * page, page)
-            out.append((logical <= end, (
-                pltpu.make_async_copy(
-                    k_hbm.at[pid], k_buf.at[half, dst], sems.at[0, half]),
-                pltpu.make_async_copy(
+            pair = (pltpu.make_async_copy(
+                k_hbm.at[pid], k_buf.at[half, dst], sems.at[0, half]),)
+            if v_hbm is not None:
+                pair += (pltpu.make_async_copy(
                     v_hbm.at[pid], v_buf.at[half, dst],
-                    sems.at[1, half]))))
+                    sems.at[1, half]),)
+            out.append((logical <= end, pair))
         return out
 
     def start(who, pages, c, half):
@@ -504,11 +518,15 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
 
         carry[1] = 0
         q = q_ref[...] if count == rows else q_ref[:count]   # [count, D]
-        mask = _group_block_mask(count, heads, kv_heads, depth,
-                                 positions)
-        q_bd = jnp.where(
-            mask, jnp.concatenate([q.astype(jnp.float32)] * kv_heads,
-                                  axis=1), 0.0).astype(q.dtype)
+        if value_lanes:
+            q_bd = q            # every head reads the one latent row
+        else:
+            mask = _group_block_mask(count, heads, kv_heads, depth,
+                                     positions)
+            q_bd = jnp.where(
+                mask, jnp.concatenate(
+                    [q.astype(jnp.float32)] * kv_heads, axis=1),
+                0.0).astype(q.dtype)
 
         def body(c, carried):
             o, m, l = carried
@@ -525,9 +543,9 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
                 carry[1] = 1
 
             wait(c, half)
+            tile = k_buf[half].astype(q.dtype)
             scores = jax.lax.dot_general(
-                q_bd, k_buf[half].astype(q.dtype),
-                (((1,), (1,)), ((), ())),
+                q_bd, tile, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             scores = kept_in(scores, softmax_dtype)  # [count, span]
             pos = (first + c * chunk) * page + \
@@ -551,7 +569,8 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
             p = jnp.exp(scores - m_new)
             l = l * correction + jnp.sum(p, axis=1, keepdims=True)
             pv = jax.lax.dot_general(
-                p.astype(q.dtype), v_buf[half].astype(q.dtype),
+                p.astype(q.dtype), tile[:, :value_lanes] if value_lanes
+                else v_buf[half].astype(q.dtype),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [count, Hkv*D]
             return (kept_in(o * correction + pv, softmax_dtype), m_new,
@@ -559,14 +578,20 @@ def _gqa_paged_decode_kernel(table_ref, len_ref, next_ref, *refs,
 
         o, _m, l = jax.lax.fori_loop(
             0, chunks, body,
-            (jnp.zeros((count, kv_heads * depth), jnp.float32),
+            (jnp.zeros((count, value_lanes or kv_heads * depth),
+                       jnp.float32),
              jnp.full((count, 1), _NEG_INF, jnp.float32),
              jnp.zeros((count, 1), jnp.float32)))
-        out = jnp.where(mask, o / jnp.where(l == 0.0, 1.0, l), 0.0)
-        # each row has one live block of D columns (its K/V head's):
-        # the sum over the blocks folds [count, Hkv*D] into [count, D]
-        out = sum(out[:, h * depth:(h + 1) * depth]
-                  for h in range(kv_heads)).astype(o_ref.dtype)
+        out = o / jnp.where(l == 0.0, 1.0, l)
+        if value_lanes:
+            out = out.astype(o_ref.dtype)
+        else:
+            out = jnp.where(mask, out, 0.0)
+            # each row has one live block of D columns (its K/V
+            # head's): the sum over the blocks folds [count, Hkv*D]
+            # into [count, D]
+            out = sum(out[:, h * depth:(h + 1) * depth]
+                      for h in range(kv_heads)).astype(o_ref.dtype)
         if count == rows:
             o_ref[...] = out
         else:
@@ -696,6 +721,122 @@ def gqa_paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
     return out[:, :seq * heads].reshape(batch, seq, heads, depth)
 
 
+# ---------------------------------------------------------------------
+# A pool of LATENT rows (multi-head latent attention, the absorbed
+# form): ONE leaf a layer, [P, page, W], a token's row the compressed
+# K/V vector c (its first ``value_lanes`` lanes) followed by the one
+# rotary key all heads share. A query row is a head's absorbed query
+# [q_h W_uk,h^T ; q_h^R] of the same W lanes; a key's score is its dot
+# with the whole row and its value the row's first ``value_lanes``
+# lanes: what the caller multiplies by W_uv,h afterwards. The kernel is
+# the one above with no V pages (``value_lanes``): one program a slot,
+# S * H query rows against each live page, fetched and read once, the
+# next seated slot's first chunk fetched behind this slot's last.
+
+MLA_KERNEL_NAME = "mla_paged_decode"
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_lanes", "scale",
+                                    "softmax_dtype"),
+                   inline=True)
+def mla_paged_decode_attention_kernel(q, kv_pages, block_table, lengths,
+                                      *, value_lanes: int, scale: float,
+                                      softmax_dtype=jnp.float32):
+    """Pallas path of a latent pool. q: [B, S, H, W] absorbed queries;
+    kv_pages: [P, page, W]; lengths: [B] valid-key counts (the S rows
+    written this step included); query r of S sits at key position
+    length - S + r and sees the keys up to its own (a verify block).
+    ``scale`` multiplies the scores (1 / sqrt of the EXPANDED query's
+    depth: the caller's to give, no shape here says it).
+    -> [B, S, H, value_lanes] in q.dtype: softmax-weighted sums of the
+    rows' first ``value_lanes`` lanes, accumulated in float32 (scores
+    and running terms kept in ``softmax_dtype``). A slot of length 0
+    yields zeros and costs nothing. Jitted inline, as the grouped
+    kernel is: one trace of the body for all layers."""
+    batch, seq, heads, depth = q.shape
+    page, width = kv_pages.shape[1], kv_pages.shape[2]
+    if depth != width or not 0 < value_lanes <= width:
+        raise ValueError(
+            f"absorbed queries of {depth} lanes over latent rows of "
+            f"{width} (values: the first {value_lanes})")
+    rows = -(-seq * heads // 16) * 16
+    q_rows = jnp.pad(q.reshape(batch, seq * heads, depth),
+                     ((0, 0), (0, rows - seq * heads), (0, 0)))
+    chunk = gqa_chunk_pages(page, width, kv_pages.dtype.itemsize,
+                            block_table.shape[1])
+    lengths = lengths.astype(jnp.int32)
+    scalars = (block_table.astype(jnp.int32), lengths,
+               next_seated(lengths))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(batch,),
+        in_specs=[pl.BlockSpec((None, rows, depth),
+                               lambda b, *scalars: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, rows, value_lanes),
+                               lambda b, *scalars: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk * page, width), kv_pages.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((2,), jnp.int32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _gqa_paged_decode_kernel, page=page, chunk=chunk,
+            heads=heads, kv_heads=1, depth=depth, window=0,
+            scale=float(scale), softmax_dtype=softmax_dtype,
+            positions=seq, value_lanes=int(value_lanes)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, rows, value_lanes),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=MLA_KERNEL_NAME,
+    )(*scalars, q_rows, kv_pages)
+    return out[:, :seq * heads].reshape(batch, seq, heads, value_lanes)
+
+
+def mla_paged_decode_attention_xla(q, kv_pages, block_table, lengths,
+                                   *, value_lanes: int, scale: float,
+                                   softmax_dtype=jnp.float32):
+    """The kernel above as an XLA gather (the CPU/fallback road and
+    the tests' oracle): every table entry's page gathered, one masked
+    softmax a query position, query r of S at key position
+    length - S + r seeing the keys up to its own. A slot of length 0
+    yields zeros."""
+    batch, seq, _heads, _depth = q.shape
+    rows = kv_pages[block_table].reshape(batch, -1, kv_pages.shape[2])
+    scores = jnp.einsum("bqhw,bkw->bqhk", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(rows.shape[1], dtype=jnp.int32)
+    upper = lengths[:, None] - (seq - 1) + jnp.arange(
+        seq, dtype=jnp.int32)[None, :]                       # [B, S]
+    visible = pos[None, None, :] < upper[:, :, None]
+    scores = jnp.where(visible[:, :, None],
+                       kept_in(scores, softmax_dtype), _NEG_INF)
+    probs = kept_in(jax.nn.softmax(scores, axis=-1), softmax_dtype)
+    out = jnp.einsum("bqhk,bkw->bqhw", probs.astype(q.dtype),
+                     rows[..., :value_lanes],
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    return _zero_where_empty(out, lengths)
+
+
+def mla_paged_decode_attention(q, kv_pages, block_table, lengths, *,
+                               value_lanes: int, scale: float,
+                               impl: Optional[str] = None,
+                               softmax_dtype=jnp.float32):
+    """Dispatch of a latent pool's decode call by paged_decode_road
+    (``latent``): the kernel on a TPU, the gather elsewhere."""
+    road = paged_decode_road(impl, grouped=True, latent=True,
+                             positions=q.shape[1])
+    call = mla_paged_decode_attention_kernel if road == "mla_kernel" \
+        else mla_paged_decode_attention_xla
+    return call(q, kv_pages, block_table, lengths,
+                value_lanes=value_lanes, scale=scale,
+                softmax_dtype=softmax_dtype)
+
+
 def _visible_block(block: int, seq: int, window: int) -> int:
     """A call's visible block as its mask rule reads it: 1 for a call
     of one position or with none given (each query the keys up to its
@@ -793,7 +934,7 @@ def _plain_call(grouped: bool, window: int, positions: int) -> bool:
 
 def paged_decode_road(impl: Optional[str], *, grouped: bool,
                       window: int = 0, int8: bool = False,
-                      positions: int = 1) -> str:
+                      positions: int = 1, latent: bool = False) -> str:
     """Which of the four implementations a one-token paged decode call
     runs: the dispatch below and a serving report
     (workloads/serve.paged_decode_impl) both ask here, so the report
@@ -825,8 +966,18 @@ def paged_decode_road(impl: Optional[str], *, grouped: bool,
     a verify block, or the blocks of a model with TransformerConfig.
     block_diffusion) is the last row's for any pool: gqa_kernel and
     xla_windowed alone mask by query position, by the dispatch's
-    visible ``block``, and they alone take ``live_positions``."""
+    visible ``block``, and they alone take ``live_positions``.
+
+    ``latent`` (a pool of latent rows, one leaf a layer:
+    mla_paged_decode_attention) has two roads of its own at any number
+    of positions, mla_kernel and mla_xla, and neither window nor int8
+    pages."""
     want = resolve_paged_impl(impl)
+    if latent:
+        if window or int8:
+            raise NotImplementedError(
+                "no window and no int8 pages over a latent pool")
+        return "mla_kernel" if want == "kernel" else "mla_xla"
     if _plain_call(grouped, window, positions) and (
             int8 or want == "xla"):
         return want

@@ -245,7 +245,7 @@ def test_dense_decode_kernel_through_transformer():
             inf.decode_config(cfg, 64), kv_cache_dtype="int8",
             decode_attention_impl=impl)
         model = tfm.TransformerLM(dcfg)
-        cache = inf.init_cache(model, params, 1)
+        cache = inf.empty_cache(model, 1)
         _, mutated = model.apply(
             {"params": params, "cache": cache}, prompt,
             return_hidden=True, mutable=["cache"])

@@ -139,10 +139,12 @@ def test_the_mixer_is_the_references_layer_in_one_call_and_by_steps():
     out, mutated = serving.apply({"params": params, "cache": cache},
                                  bucket, 11, mutable=["cache"])
     np.testing.assert_allclose(out[0, :11], want[:11], atol=1e-5)
+    # (the one-token step as ONE compiled program, not an eager
+    # operation at a time)
+    step = jax.jit(lambda cache, row: serving.apply(
+        {"params": params, "cache": cache}, row, mutable=["cache"]))
     for t in range(11, 37):
-        out, mutated = serving.apply(
-            {"params": params, "cache": mutated["cache"]},
-            x[:, t:t + 1], mutable=["cache"])
+        out, mutated = step(mutated["cache"], x[:, t:t + 1])
         np.testing.assert_allclose(out[0, 0], want[t], atol=1e-5)
     # three tails in one leaf: the last K-1 rows of q | k | v
     assert mutated["cache"]["qkv_tail"].shape == (1, 3, 3 * HEADS * WIDTH)

@@ -15,6 +15,7 @@ engine's own expert choices, each of which has to be (within a
 tolerance) one the reference would have made."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -158,12 +159,20 @@ def _judged(share, prompt, served, record):
     record's choices."""
     file, dims, _config, params = share
     sequence = prompt + served[:-1]
-    handed = {name: jnp.asarray(rows) for name, rows in
-              record["layers"].items()}
+    # padded at its end to a whole number of 64 (a row of -1: the
+    # reference's own choice): everything is causal, so no position
+    # that is read sees the padding, and the reference compiles once a
+    # bucket and not once a length
+    length = len(sequence)
+    fill = -length % 64
+    handed = {name: jnp.pad(jnp.asarray(rows)[:length],
+                            ((0, fill), (0, 0)), constant_values=-1)
+              for name, rows in record["layers"].items()}
     logits, slacks = share.module.teacher_forced_logits(
-        params, jnp.asarray(sequence, jnp.int32),
-        jnp.arange(len(prompt) - 1, len(sequence)), file, dims,
+        params, jnp.asarray(sequence + [0] * fill, jnp.int32),
+        jnp.arange(len(prompt) - 1, length), file, dims,
         decisions=handed)
+    slacks = {name: slack[:length] for name, slack in slacks.items()}
     best = jnp.max(logits, axis=-1)
     at = jnp.take_along_axis(logits, jnp.asarray(served)[:, None],
                              axis=-1)[:, 0]
@@ -304,10 +313,15 @@ def test_bucket_padding_advances_neither_state_nor_tail(share, chunk):
     model = tfm.TransformerLM(inf.decode_config(config, 128))
     prompt = _prompts(1, 11, 12)["r0"]
     assert len(prompt) == 11
-    exact, last, _chosen = serving._dense_prefill(
-        model, None, params, jnp.asarray([prompt]), 11)
-    padded, last_padded, _chosen = serving._dense_prefill(
-        model, chunk, params, jnp.asarray([prompt + [0] * 21]), 11)
+    # (each a compiled program, the prompt's length traced as the
+    # engine's prefill programs trace it)
+    def prefill(chunk, tokens, length):
+        return jax.jit(functools.partial(
+            serving._dense_prefill, model, chunk))(
+                params, jnp.asarray([tokens]), length)
+
+    exact, last, _chosen = prefill(None, prompt, 11)
+    padded, last_padded, _chosen = prefill(chunk, prompt + [0] * 21, 11)
     np.testing.assert_allclose(last_padded, last, atol=1e-5)
     assert int(jnp.argmax(last_padded)) == int(jnp.argmax(last))
     mixer, (state, _tail) = share.mixer, share.leaves
@@ -321,8 +335,7 @@ def test_bucket_padding_advances_neither_state_nor_tail(share, chunk):
                 atol=1e-5, err_msg=f"{name} {leaf}")
     assert states == 6
     # ... and padding that DID advance it would show
-    wrong, _last, _chosen = serving._dense_prefill(
-        model, None, params, jnp.asarray([prompt + [0] * 21]), 32)
+    wrong, _last, _chosen = prefill(None, prompt + [0] * 21, 32)
     first = share.layers(mixer)[0]
     assert float(jnp.abs(wrong[first][mixer][state]
                          - exact[first][mixer][state]).max()) > 1e-2
@@ -786,16 +799,19 @@ def test_a_state_kept_in_bfloat16_is_rounded_once_a_token(share):
     for dtype in (jnp.float32, jnp.bfloat16):
         model = tfm.TransformerLM(
             inf.decode_config(share.kept_in(dtype), 128))
-        cache, _last, _chosen = serving._dense_prefill(
-            model, None, params, jnp.asarray([prompt[:6]]), 6)
+        # (each a compiled program: eagerly, every operation of the
+        # stack compiles by itself)
+        cache, _last, _chosen = jax.jit(functools.partial(
+            serving._dense_prefill, model, None))(
+                params, jnp.asarray([prompt[:6]]), 6)
+        step = jax.jit(lambda cache, token, position, model=model:
+                       model.apply({"params": params, "cache": cache},
+                                   token, positions=position,
+                                   mutable=serving._MUTABLE)[1]["cache"])
         trail = []
         for position in range(6, 70):
-            _logits, mutated = model.apply(
-                {"params": params, "cache": cache},
-                jnp.asarray([[prompt[position]]]),
-                positions=jnp.asarray([[position]]),
-                mutable=serving._MUTABLE)
-            cache = mutated["cache"]
+            cache = step(cache, jnp.asarray([[prompt[position]]]),
+                         jnp.asarray([[position]]))
             trail.append(cache[first][mixer][leaf])
         assert trail[0].dtype == dtype
         states[dtype] = [np.asarray(s, np.float32) for s in trail]

@@ -32,14 +32,16 @@ def test_greedy_decode_matches_full_forward(setup):
     assert out.shape == (2, 16)
     np.testing.assert_array_equal(np.asarray(out[:, :6]),
                                   np.asarray(prompt))
-    # Reference: greedy rollout via repeated full forwards (no cache).
-    seq = prompt
-    for _ in range(10):
-        logits = model.apply({"params": params}, seq)
-        nxt = jnp.argmax(logits[:, -1].astype(jnp.float32),
-                         axis=-1).astype(jnp.int32)
-        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(seq))
+    # Reference: ONE full forward (no cache) over what was decoded.
+    # Attention is causal, so its logits at position i are those of a
+    # forward over the first i + 1 tokens alone: a greedy rollout by
+    # repeated full forwards picks token i + 1 from them, ten times.
+    logits = jax.jit(lambda seq: model.apply({"params": params}, seq))(
+        out[:, :-1])
+    greedy = jnp.argmax(logits[:, 5:].astype(jnp.float32),
+                        axis=-1).astype(jnp.int32)
+    np.testing.assert_array_equal(np.asarray(out[:, 6:]),
+                                  np.asarray(greedy))
 
 
 def test_sampling_temperature_and_topk(setup):
@@ -88,7 +90,7 @@ def test_multi_token_insert_matches_sequential(setup):
     tokens = jnp.asarray(rng.randint(0, 97, (2, 7)), jnp.int32)
 
     # Sequential: one token per apply.
-    cache_seq = inference.init_cache(dmodel, params, 2)
+    cache_seq = inference.empty_cache(dmodel, 2)
     outs = []
     for t in range(tokens.shape[1]):
         logits, mut = dmodel.apply(
@@ -100,7 +102,7 @@ def test_multi_token_insert_matches_sequential(setup):
     seq_logits = jnp.stack(outs, axis=1)        # [B, T, vocab]
 
     # Batched: one multi-token apply (positions default to arange).
-    cache_bat = inference.init_cache(dmodel, params, 2)
+    cache_bat = inference.empty_cache(dmodel, 2)
     bat_logits, mut = dmodel.apply(
         {"params": params, "cache": cache_bat}, tokens,
         mutable=["cache"])

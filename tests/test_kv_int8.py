@@ -34,7 +34,7 @@ def _decode_model(kv_dtype):
 
 def test_cache_leaves_are_int8_with_scales(params):
     model = _decode_model("int8")
-    cache = inf.init_cache(model, params, batch_size=2)
+    cache = inf.empty_cache(model, batch_size=2)
     leaves = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
         leaves[path[-1].key] = leaf
@@ -45,7 +45,7 @@ def test_cache_leaves_are_int8_with_scales(params):
     # Capacity claim measured on the ACTUAL arrays: int8 K + its
     # scales must be under half of what the fp cache stores.
     fp_model = _decode_model(None)
-    fp_cache = inf.init_cache(fp_model, params, batch_size=2)
+    fp_cache = inf.empty_cache(fp_model, batch_size=2)
     fp_k = [leaf for path, leaf in
             jax.tree_util.tree_leaves_with_path(fp_cache)
             if path[-1].key == "k"]
@@ -60,7 +60,7 @@ def test_int8_logits_within_quantization_noise(params):
 
     def last_logits(kv_dtype):
         model = _decode_model(kv_dtype)
-        cache = inf.init_cache(model, params, 1)
+        cache = inf.empty_cache(model, 1)
         hidden, _ = model.apply(
             {"params": params, "cache": cache}, prompt,
             return_hidden=True, mutable=["cache"])
@@ -84,7 +84,7 @@ def test_int8_generation_runs_and_mostly_agrees(params):
 
     def run(kv_dtype):
         model = _decode_model(kv_dtype)
-        cache = inf.init_cache(model, params, prompt.shape[0])
+        cache = inf.empty_cache(model, prompt.shape[0])
         tokens, _ = inf.generate(model, params, cache, prompt, 24,
                                  jax.random.PRNGKey(0))
         return np.asarray(tokens)
@@ -179,7 +179,7 @@ def test_unknown_kv_cache_dtype_rejected(params):
                               kv_cache_dtype="fp8")
     model = tfm.TransformerLM(cfg)
     with pytest.raises(ValueError):
-        inf.init_cache(model, params, 1)
+        inf.empty_cache(model, 1)
 
 
 def test_int8_kv_dequant_fusion_check_runs():

@@ -100,6 +100,21 @@ def _as_recorded(occupancy: dict) -> dict:
     return recorded
 
 
+def _stats_as_recorded(engine):
+    """prefix_stats() as the digests were recorded: without the bytes
+    a page and a token hold, keys since PR 50 (read off the cache's
+    leaves: inference.pool_page_bytes)."""
+    from batch_shipyard_tpu.models import inference as inf
+    if engine.prefix_stats() is None:       # the prefix cache is off
+        return None
+    recorded = dict(engine.prefix_stats())
+    assert recorded.pop("page_bytes") == inf.pool_page_bytes(
+        engine.cache) > 0
+    assert recorded.pop("bytes_per_token") * engine.page_size == \
+        engine.pages.page_bytes
+    return recorded
+
+
 def run_schedule(engine, press: bool = True) -> tuple[str, dict]:
     """Drive the schedule, folding the books into one digest after
     every step; returns it with the final counters (for a failure's
@@ -123,7 +138,8 @@ def run_schedule(engine, press: bool = True) -> tuple[str, dict]:
         engine.pages.check()
         digest.update(_table(engine).tobytes())
         digest.update(json.dumps(
-            [engine.prefix_stats(), _as_recorded(engine.occupancy()),
+            [_stats_as_recorded(engine),
+             _as_recorded(engine.occupancy()),
              engine.preemptions], sort_keys=True).encode())
         if not reqs and not engine.pending():
             break

@@ -282,7 +282,7 @@ def test_paged_multitoken_insert_requires_spec_window(params):
     cfg = dataclasses.replace(
         inf.decode_config(CFG, 32), kv_page_size=8, kv_num_pages=9)
     model = tfm.TransformerLM(cfg)
-    cache = inf.init_cache(model, params, 1)
+    cache = inf.empty_cache(model, 1)
     with pytest.raises(ValueError, match="spec_window"):
         model.apply({"params": params, "cache": cache},
                     jnp.zeros((1, 2), jnp.int32),

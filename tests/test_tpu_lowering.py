@@ -109,6 +109,18 @@ def _flash_prefill(window, softmax_dtype=f32, block=0):
         block=block)
 
 
+def _mla_paged(softmax_dtype=f32):
+    return lambda q, pool, table, lengths: \
+        pa.mla_paged_decode_attention_kernel(
+            q, pool, table, lengths, value_lanes=512,
+            scale=192 ** -0.5, softmax_dtype=softmax_dtype)
+
+
+def _latent_prefill(q, k, v, start):
+    return attn.cached_prefill_attention_kernel(
+        q, k, v, start, v_depth=128, scale=192 ** -0.5)
+
+
 def _prefill_args(seq, rows=16384, heads=28, kv_heads=4, depth=128):
     cache = ((1, rows, kv_heads * depth), bf16)
     return [((1, seq, heads, depth), bf16), cache, cache, ((1,), i32)]
@@ -230,6 +242,21 @@ CASES = [
      _gqa_args(4609, 128, batch=96, heads=64, kv_heads=8, positions=2)),
     ("gqa_paged_verify_ring", _gqa_paged(128),
      _gqa_args(96 * 4, 4, batch=96, heads=64, kv_heads=8, positions=2)),
+    # the latent configuration's published shapes (128 heads, a row of
+    # 512 + 64 lanes stored as 640, 128 slots, two query positions a
+    # slot: 256 query rows against each page read once): a pool of
+    # 9,216 pages behind a 193-entry table; and its prefill, a
+    # segment of 2,048 queries 256 lanes deep (192 of numbers) against
+    # the 8,192 bucket's expanded keys beside values of 128
+    ("mla_paged_verify", _mla_paged(),
+     [((128, 2, 128, 640), bf16), ((9217, 64, 640), bf16),
+      ((128, 193), i32), ((128,), i32)]),
+    ("mla_paged_verify_bf16_softmax", _mla_paged(bf16),
+     [((128, 2, 128, 640), bf16), ((9217, 64, 640), bf16),
+      ((128, 193), i32), ((128,), i32)]),
+    ("flash_prefill_latent", _latent_prefill,
+     [((1, 2048, 128, 256), bf16), ((1, 8192, 128 * 256), bf16),
+      ((1, 8192, 128 * 128), bf16), ((1,), i32)]),
     ("ring_all_gather_virtual", rc.ring_all_gather_virtual,
      [((4, 128, 128), f32)]),
     ("ring_reduce_scatter_virtual", rc.ring_reduce_scatter_virtual,

@@ -324,6 +324,58 @@ def check_paged_handover() -> bool:
     return all_ok
 
 
+def check_latent_paged_decode() -> bool:
+    """The latent pool's kernel (mla_paged_decode) on the chip at the
+    published row (640 lanes: 512 of compressed vector, 64 of rotary
+    key, zeros), 128 heads, one and two query positions a slot:
+    seated slots of one to five chunks of pages with parked slots
+    before, between and behind them. Each seated slot's rows BIT FOR
+    BIT what the slot gives alone (the hand-over starts its first
+    chunk behind another slot's last), the parked rows zero, and the
+    call within bfloat16's rounding of the XLA gather."""
+    from batch_shipyard_tpu.ops import paged_attention as paged
+
+    heads, lanes, value, page, entries, all_ok = 128, 640, 512, 64, 48, True
+    kw = dict(value_lanes=value, scale=192 ** -0.5)
+    for positions in (1, 2):
+        rng = np.random.RandomState(17)
+        keys = paged.gqa_chunk_pages(page, lanes, 2, entries) * page
+        lengths = np.asarray(
+            [0, keys, 0, keys + 1, 9, 0, 0, 2 * keys + 70, 5 * keys,
+             keys - 1, 0, positions, 0], np.int32)
+        need = np.minimum(-(-lengths // page), entries)
+        pool = 1 + int(need.sum())
+        q = jnp.asarray(0.1 * rng.randn(len(lengths), positions, heads,
+                                        lanes), jnp.bfloat16)
+        rows = jnp.asarray(rng.randn(pool, page, lanes), jnp.bfloat16)
+        table = np.zeros((len(lengths), entries), np.int32)
+        ids = iter(rng.permutation(pool - 1) + 1)
+        for b, pages in enumerate(need):
+            table[b, :pages] = [next(ids) for _ in range(pages)]
+        table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+        kernel = jax.jit(functools.partial(
+            paged.mla_paged_decode_attention, impl="kernel", **kw))
+        out_k = kernel(q, rows, table, lengths)
+        out_x = paged.mla_paged_decode_attention(
+            q, rows, table, lengths, impl="xla", **kw)
+        rel = _rel(out_k, out_x)
+        seated = np.flatnonzero(np.asarray(lengths) > 0)
+        alone = all(
+            np.array_equal(
+                np.asarray(out_k[b], np.float32),
+                np.asarray(kernel(q[b:b + 1], rows, table[b:b + 1],
+                                  lengths[b:b + 1])[0], np.float32))
+            for b in seated)
+        zeros = not np.delete(np.asarray(out_k, np.float32), seated,
+                              axis=0).any()
+        ok = rel < 2e-2 and alone and zeros
+        print(f"latent paged decode [{positions} positions]: "
+              f"rel={rel:.2e} alone-bitwise={alone} parked-zero={zeros} "
+              f"{'OK' if ok else 'FAIL'}")
+        all_ok = all_ok and ok
+    return all_ok
+
+
 def check_int8_matmul() -> bool:
     """quantize_int8 + int8_matmul on the real MXU: the quantized
     product must sit within the per-element quantization error bound
@@ -374,6 +426,7 @@ CHECKS = {
     "flash_ring": check_flash_ring_virtual_shards,
     "paged_attention": check_paged_attention,
     "paged_handover": check_paged_handover,
+    "latent_paged_decode": check_latent_paged_decode,
     "int8_matmul": check_int8_matmul,
     "fused_norm": check_fused_norm,
     "chunked_cross_entropy": None,  # bound below (round-5 kernel)
